@@ -56,15 +56,30 @@ class SlicePlacement:
     def spare_chips(self) -> int:
         return self.chips - self.mesh_chips
 
-    def env(self) -> Dict[str, str]:
+    def env(self, chips: Optional[Sequence[int]] = None
+            ) -> Dict[str, str]:
         """Replica process environment (how JAX discovers the slice —
-        the TPU analogue of the injected nodeSelector)."""
-        return {
+        the TPU analogue of the injected nodeSelector).
+
+        chips: this host's chip indices the process may open — one
+        process per chip is the runtime's rule, so replicas sharing a
+        host each get their own.  The installed libtpu honours
+        TPU_VISIBLE_CHIPS, and with per-process bounds it admits
+        several loads on one host (chip run, PR 21)."""
+        env = {
             "TPU_ACCELERATOR_TYPE": self.accelerator_type,
             "TPU_TOPOLOGY": self.topology,
             "TPU_CHIPS_PER_REPLICA": str(self.mesh_chips),
             "TPU_WORKER_HOSTS": str(self.hosts),
         }
+        if chips is not None:
+            bounds = (self.topology.split("x") + ["1", "1"])[:3]
+            env.update({
+                "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": ",".join(bounds),
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+            })
+        return env
 
 
 # Published slice shapes per generation: (topology, chips, hosts).

@@ -9,13 +9,15 @@ engine warmup tied into the readiness probe.
 
 import logging
 import os
-from typing import Optional
 
 logger = logging.getLogger("kfserving_tpu.compile_cache")
 
-DEFAULT_CACHE_DIR = os.path.expanduser("~/.cache/kfserving_tpu/xla")
-
-_active_dir: Optional[str] = None
+# Where JAX_COMPILATION_CACHE_DIR is unset: a fixed directory inside
+# the checkout (git-ignored).  The path is part of the cache's key, so
+# it is never built from a temp name, pid or time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".kfs_cache", "xla")
 
 
 def note_compilation(source: str, key) -> None:
@@ -39,29 +41,23 @@ def declare_warmup_complete(source: str) -> None:
     sanitizer.declare_warmup_complete(source)
 
 
-def enable(cache_dir: Optional[str] = None,
-           min_compile_time_secs: float = 0.5) -> str:
-    """Enable the JAX persistent compilation cache.
+def enable(min_compile_time_secs: float = 0.5) -> str:
+    """Enable the JAX persistent compilation cache; returns its
+    directory.
 
-    Idempotent for the same directory; a later call with a *different*
-    directory re-points the cache (and says so) rather than silently
-    returning an inactive path.
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX's own handling of it
+    stands and no directory is set in code (child processes inherit
+    the variable, so replicas share one cache).  Where it is not, the
+    cache lives at DEFAULT_CACHE_DIR.
     """
-    global _active_dir
-    cache_dir = cache_dir or os.environ.get(
-        "KFSERVING_TPU_COMPILE_CACHE", DEFAULT_CACHE_DIR)
-    if _active_dir == cache_dir:
-        return cache_dir
-    if _active_dir is not None:
-        logger.warning("re-pointing XLA compile cache %s -> %s",
-                       _active_dir, cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    cache_dir = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_time_secs)
-    _active_dir = cache_dir
     # Marker on the engine event timeline: compile-miss slices after
     # this point are persistent-cache loads, not fresh XLA compiles.
     from kfserving_tpu.observability.profiling import TIMELINE

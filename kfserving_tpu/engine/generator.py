@@ -45,8 +45,8 @@ is the TPU-first design for that:
 - **on-device sampling**: greedy, temperature (Gumbel trick), top-k
   and top-p (nucleus) per slot — the mask-then-sample runs on device,
   so only the [S] int32 token vector crosses the host boundary per
-  step — never the [S, V] logits (1.6 MB/step for a GPT-2 vocab; the
-  host link is the serving bottleneck, ROOFLINE.md).  Noise is keyed
+  step — never the [S, V] logits (1.6 MB/step for a GPT-2 vocab).
+  Noise is keyed
   per request from (seed, absolute position): a seeded request
   reproduces exactly no matter how it was scheduled.  Top-N logprobs
   are computed every step and fetched only when a request asks.
@@ -74,6 +74,7 @@ from kfserving_tpu.engine import compile_cache
 from kfserving_tpu.observability import attribution
 from kfserving_tpu.observability import metrics as obs
 from kfserving_tpu.observability.profiling import TIMELINE
+from kfserving_tpu.parallel.mesh import mesh_scope
 from kfserving_tpu.protocol.errors import InferenceError, InvalidInput
 from kfserving_tpu.reliability import sanitizer
 
@@ -868,7 +869,7 @@ class GenerationEngine:
         # position (QK^T and AV, 2 FLOPs per MAC each).  Counted over
         # LIVE slots only — garbage waves burn device time without
         # adding useful FLOPs, so decode_mfu is a goodput-weighted
-        # floor on chip utilization, matching ROOFLINE.md's framing.
+        # floor on chip utilization.
         self._n_params = int(sum(
             int(np.prod(x.shape))
             for x in self._jax.tree.leaves(variables)))
@@ -2458,16 +2459,19 @@ class GenerationEngine:
         self._note_program("chunk", nb)
         with self._block_lock:
             row = self._tables[slot:slot + 1, :nb].copy()
-        (first, self._caches, chosen_lp, top_ids, top_lps) = \
-            self._chunk_prefill(
-                self.variables, self._caches, jnp.asarray(row),
-                jnp.asarray(ids), jnp.asarray(qpos),
-                jnp.asarray(np.asarray([max(width - 1, 0)], np.int32)),
-                jnp.asarray(np.asarray([req.temperature], np.float32)),
-                jnp.asarray(np.asarray([req.top_k], np.int32)),
-                jnp.asarray(np.asarray([req.top_p], np.float32)),
-                jnp.asarray(np.asarray([req.seed], np.int32)),
-                jnp.asarray(np.asarray([n], np.int32)))
+        with mesh_scope(self.mesh):
+            (first, self._caches, chosen_lp, top_ids, top_lps) = \
+                self._chunk_prefill(
+                    self.variables, self._caches, jnp.asarray(row),
+                    jnp.asarray(ids), jnp.asarray(qpos),
+                    jnp.asarray(np.asarray([max(width - 1, 0)],
+                                           np.int32)),
+                    jnp.asarray(np.asarray([req.temperature],
+                                           np.float32)),
+                    jnp.asarray(np.asarray([req.top_k], np.int32)),
+                    jnp.asarray(np.asarray([req.top_p], np.float32)),
+                    jnp.asarray(np.asarray([req.seed], np.int32)),
+                    jnp.asarray(np.asarray([n], np.int32)))
         if final:
             self._feed_tokens, self._feed_positions = \
                 self._feed_update(
@@ -3078,12 +3082,14 @@ class GenerationEngine:
         self._note_program("decode", self.max_slots,
                            self.steps_per_call)
         temps, top_ks, top_ps, seeds, want_lp = self._sampling_arrays()
-        (toks, self._caches, self._feed_tokens, self._feed_positions,
-         chosen_lp, top_ids, top_lps) = self._decode(
-            self.variables, self._caches, self._table_device(),
-            self._feed_tokens, self._feed_positions,
-            jnp.asarray(temps), jnp.asarray(top_ks),
-            jnp.asarray(top_ps), jnp.asarray(seeds))
+        with mesh_scope(self.mesh):
+            (toks, self._caches, self._feed_tokens,
+             self._feed_positions, chosen_lp, top_ids, top_lps) = \
+                self._decode(
+                    self.variables, self._caches, self._table_device(),
+                    self._feed_tokens, self._feed_positions,
+                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(top_ps), jnp.asarray(seeds))
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         self.decode_steps += 1
         # Snapshot records mid-chunked-prefill slots as None: this
@@ -3170,11 +3176,13 @@ class GenerationEngine:
         rec[0] += sum(int(r.prompt_ids.size) for r in group)
         rec[1] += b_bucket * bucket
         self._note_program("prefill", b_bucket, bucket)
-        firsts, new_caches, chosen_lp, top_ids, top_lps = \
-            self._prefill(
-                self.variables, jnp.asarray(ids), jnp.asarray(lengths),
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), jnp.asarray(seeds))
+        with mesh_scope(self.mesh):
+            firsts, new_caches, chosen_lp, top_ids, top_lps = \
+                self._prefill(
+                    self.variables, jnp.asarray(ids),
+                    jnp.asarray(lengths), jnp.asarray(temps),
+                    jnp.asarray(top_ks), jnp.asarray(top_ps),
+                    jnp.asarray(seeds))
         if dest_rows is not None:
             # Paged: per-chunk destination blocks (-1 = shared prefix
             # hit or padding row — the scatter drops those chunks).
@@ -3468,12 +3476,13 @@ class GenerationEngine:
         else:
             draft_dev = jnp.asarray(ngram)
         self._note_program("spec_verify", S, K + 1)
-        (samples, draft_echo, self._caches, chosen_lp, top_ids,
-         top_lps) = self._spec_verify(
-            self.variables, self._caches, self._table_device(),
-            jnp.asarray(last), draft_dev, jnp.asarray(qpos),
-            jnp.asarray(temps), jnp.asarray(top_ks),
-            jnp.asarray(top_ps), jnp.asarray(seeds))
+        with mesh_scope(self.mesh):
+            (samples, draft_echo, self._caches, chosen_lp, top_ids,
+             top_lps) = self._spec_verify(
+                self.variables, self._caches, self._table_device(),
+                jnp.asarray(last), draft_dev, jnp.asarray(qpos),
+                jnp.asarray(temps), jnp.asarray(top_ks),
+                jnp.asarray(top_ps), jnp.asarray(seeds))
         self.decode_steps += 1
         self.spec_waves += 1
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None

@@ -47,12 +47,9 @@ def device_peak_hbm_bw() -> Optional[float]:
             return float(env)
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return None
+    kind = jax.devices()[0].device_kind.lower()
     for marker, bw in (("v5 lite", 819e9), ("v5e", 819e9),
                        ("v5p", 2765e9), ("v6", 1640e9),
                        ("v4", 1228e9), ("v3", 900e9), ("v2", 700e9)):

@@ -1,11 +1,13 @@
-"""Multi-head attention dispatch: Pallas flash kernel on TPU, XLA fallback.
+"""Multi-head attention dispatch: Pallas flash kernel on TPU, XLA otherwise.
 
 One public entry point, `dot_product_attention(q, k, v, mask=None)`, with
 shape [batch, len, heads, head_dim] (BLHD — flax linen convention).  On TPU
 backends with seq-len and head_dim meeting the kernel's tiling constraints it
 runs the fused Pallas kernel (kfserving_tpu/ops/pallas_attention.py);
 otherwise it lowers to the standard einsum formulation, which XLA fuses well
-on its own for short sequences.
+on its own for short sequences.  The choice is made from shapes and the
+backend at trace time and logged once per distinct program; a kernel the
+dispatcher chose either runs or the request fails.
 
 The kernel exists for the long-sequence serving configs (BERT seq-bucketed
 batching, BASELINE.json config #3): at seq >= 1024 the materialized
@@ -20,6 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 logger = logging.getLogger("kfserving_tpu.ops")
 
@@ -57,10 +60,48 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 @functools.lru_cache(maxsize=1)
 def _tpu_backend() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+@functools.lru_cache(maxsize=None)
+def log_dispatch(path: str, **shapes) -> None:
+    """One INFO line per distinct (path, shapes) program: which
+    attention formulation a traced program contains.  The dispatchers
+    run at trace time, so this is the record of what was compiled."""
+    logger.info("attention path=%s %s", path,
+                " ".join(f"{k}={v}" for k, v in shapes.items()))
+
+
+def mesh_axis(mesh, name: str, dim: int) -> Optional[str]:
+    """`name` when the ambient mesh can split `dim` over it."""
+    if name in mesh.axis_names and dim % mesh.shape[name] == 0:
+        return name
+    return None
+
+
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
+           lengths: Optional[jax.Array]) -> jax.Array:
+    """The Pallas flash kernel, under `shard_map` when the caller runs
+    inside a mesh (`jax.set_mesh`): Mosaic kernels cannot be
+    partitioned automatically, and per-(batch, head) attention needs
+    no collective — heads split over ``tp`` like the q/k/v projections
+    that feed it, batch over ``dp``, lengths follow the batch."""
+    from kfserving_tpu.ops.pallas_attention import flash_attention
+
+    def kernel(q, k, v, *lens):
+        return flash_attention(q, k, v, causal=causal,
+                               kv_lengths=lens[0] if lens else None)
+
+    args = (q, k, v) if lengths is None else (q, k, v, lengths)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return kernel(*args)
+    batch = mesh_axis(mesh, "dp", q.shape[0])
+    spec = P(batch, None, mesh_axis(mesh, "tp", q.shape[2]), None)
+    in_specs = (spec, spec, spec) + ((P(batch),) if lengths is not None
+                                     else ())
+    return jax.shard_map(kernel, in_specs=in_specs, out_specs=spec,
+                         check_vma=False)(*args)
 
 
 def _flash_eligible(q: jax.Array) -> bool:
@@ -144,13 +185,10 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         flash_ok = (mask is None or kv_lengths is not None
                     or derived_lengths is not None)
         lengths = kv_lengths if kv_lengths is not None else derived_lengths
-    if flash_ok and _flash_eligible(q):
-        try:
-            from kfserving_tpu.ops.pallas_attention import flash_attention
-
-            return flash_attention(q, k, v, causal=causal,
-                                   kv_lengths=lengths)
-        except Exception as exc:  # pragma: no cover - TPU-only path
-            logger.warning("pallas flash attention failed (%s); "
-                           "falling back to XLA", exc)
+    use_flash = flash_ok and _flash_eligible(q)
+    log_dispatch("pallas_flash" if use_flash else "xla",
+                 q=q.shape, k=k.shape, causal=causal,
+                 kv_lengths=kv_lengths is not None)
+    if use_flash:
+        return _flash(q, k, v, causal, lengths)
     return _xla_attention(q, k, v, mask)

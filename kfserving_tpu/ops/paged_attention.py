@@ -19,8 +19,8 @@ Two implementations with one contract:
 - a Pallas TPU kernel (paged_attention_tpu) that walks the block
   table with scalar prefetch and never materializes — only blocks
   holding valid tokens are read, so a short sequence in a long-context
-  pool costs its length, not the pool width.  (Added when measured;
-  the dispatcher falls back to XLA.)
+  pool costs its length, not the pool width.  The dispatcher picks it
+  from shapes and the backend; under a mesh it runs per heads shard.
 
 Contract (per layer):
     q           [B, 1, H, D]   current step's query
@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
 
@@ -171,18 +172,40 @@ def paged_attention(q, pool_k, pool_v, block_table, lengths):
     paths (same semantics as KFS_DISABLE_FLASH)."""
     import os
 
-    from kfserving_tpu.ops.attention import _tpu_backend
+    from kfserving_tpu.ops.attention import _tpu_backend, log_dispatch
 
     bs = pool_k.shape[1]
     d = q.shape[-1]
     h = q.shape[2]
-    if (_tpu_backend() and q.shape[1] == 1 and h <= 128
-            and bs % 128 == 0 and d % 64 == 0
-            and os.environ.get("KFS_DISABLE_PAGED_KERNEL", "")
-            in ("", "0", "false")):
-        return paged_attention_tpu(q, pool_k, pool_v, block_table,
-                                   lengths)
+    use_kernel = (_tpu_backend() and q.shape[1] == 1 and h <= 128
+                  and bs % 128 == 0 and d % 64 == 0
+                  and os.environ.get("KFS_DISABLE_PAGED_KERNEL", "")
+                  in ("", "0", "false"))
+    log_dispatch("pallas_paged" if use_kernel else "xla_paged",
+                 q=q.shape, pool=pool_k.shape, table=block_table.shape)
+    if use_kernel:
+        return paged_attention_sharded(q, pool_k, pool_v, block_table,
+                                       lengths)
     return paged_attention_xla(q, pool_k, pool_v, block_table, lengths)
+
+
+def paged_attention_sharded(q, pool_k, pool_v, block_table, lengths,
+                            interpret: bool = False):
+    """`paged_attention_tpu`, under `shard_map` when the caller runs
+    inside a mesh (`jax.set_mesh`): Mosaic kernels cannot be
+    partitioned automatically, and per-head attention needs no
+    collective — q and the pools split on heads over ``tp`` exactly as
+    the engine shards the pool; block table and lengths replicate."""
+    from kfserving_tpu.ops.attention import mesh_axis
+
+    kernel = functools.partial(paged_attention_tpu, interpret=interpret)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return kernel(q, pool_k, pool_v, block_table, lengths)
+    spec = P(None, None, mesh_axis(mesh, "tp", q.shape[2]), None)
+    return jax.shard_map(
+        kernel, in_specs=(spec, spec, spec, P(), P()), out_specs=spec,
+        check_vma=False)(q, pool_k, pool_v, block_table, lengths)
 
 
 def paged_attention_xla(q, pool_k, pool_v, block_table, lengths):

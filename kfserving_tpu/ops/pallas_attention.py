@@ -168,12 +168,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         pltpu.VMEM((block_q, D), jnp.float32),
     ]
     out_shape = jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype)
-    # jax renamed TPUCompilerParams -> CompilerParams across releases;
-    # accept either so the kernel (and its interpret-mode tests) track
-    # the installed version instead of one side of the rename.
-    _params_cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    params = _params_cls(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     if has_lengths:

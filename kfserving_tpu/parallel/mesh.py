@@ -15,6 +15,7 @@ Axis sizes are static per-deployment config (the control-plane spec's
 new mesh is a new model load, same as a replica restart in the reference.
 """
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence
 
@@ -70,3 +71,13 @@ def single_device_mesh(device=None):
     import jax
 
     return build_mesh(MeshConfig(), devices=[device or jax.devices()[0]])
+
+
+def mesh_scope(mesh):
+    """Context in which an engine traces and calls its model programs:
+    `jax.set_mesh(mesh)`, or nothing without a mesh.  The attention
+    dispatchers (ops/) read the ambient mesh at trace time to run their
+    Pallas kernels under `shard_map`."""
+    import jax
+
+    return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)

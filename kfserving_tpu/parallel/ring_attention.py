@@ -33,12 +33,7 @@ def _ring_attention_local(q, k, v, kv_mask, axis_name: str, causal: bool):
     The sp axis index orders blocks: device i holds positions
     [i*L_local, (i+1)*L_local).
     """
-    # jax.lax.axis_size arrived after 0.4.x; psum of a literal 1 is
-    # the historical spelling and is constant-folded to the same
-    # static axis size, so either works as a loop bound.
-    sp = (jax.lax.axis_size(axis_name)
-          if hasattr(jax.lax, "axis_size")
-          else jax.lax.psum(1, axis_name))
+    sp = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     B, Lq, H, D = q.shape
     scale = 1.0 / D ** 0.5
@@ -122,18 +117,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     mask_spec = P(batch_axis, axis_name)
     fn = functools.partial(
         _ring_attention_local, axis_name=axis_name, causal=causal)
-    try:
-        from jax import shard_map
-
-        sharded = shard_map(fn, mesh=mesh,
+    sharded = jax.shard_map(fn, mesh=mesh,
                             in_specs=(spec, spec, spec, mask_spec),
                             out_specs=spec, check_vma=False)
-    except (ImportError, TypeError):  # older jax spells it differently
-        from jax.experimental.shard_map import shard_map as shard_map_old
-
-        sharded = shard_map_old(fn, mesh=mesh,
-                                in_specs=(spec, spec, spec, mask_spec),
-                                out_specs=spec, check_rep=False)
     return sharded(q, k, v, kv_mask)
 
 

@@ -11,6 +11,7 @@ multi-model puller (--config_dir), and the TPU batching knobs.
 import argparse
 import logging
 
+from kfserving_tpu import startup
 from kfserving_tpu.engine.compile_cache import enable as enable_compile_cache
 from kfserving_tpu.predictors.jax_model import JaxModel
 from kfserving_tpu.predictors.jaxserver.repository import JaxModelRepository
@@ -83,16 +84,23 @@ if __name__ == "__main__":
     enable_compile_cache()
     server = build_server(args)
     if args.multi_model or args.config_dir:
-        server.start([])
-    elif os.environ.get("KFS_STANDBY"):
-        # Recycle fast-swap: imports and server setup are done, but the
-        # model load (device init + compile) waits for the orchestrator
-        # to POST /standby/activate once the predecessor releases the
-        # chip (subprocess_orchestrator recycle path).
-        model = JaxModel(args.model_name, args.model_dir)
-        server.standby_model(lambda: (model.load(), model)[1])
+        startup.report_device()
         server.start([])
     else:
         model = JaxModel(args.model_name, args.model_dir)
-        model.load()
-        server.start([model])
+
+        def load():
+            startup.report_device()  # first touch of the device
+            model.load()
+            return model
+
+        if os.environ.get("KFS_STANDBY"):
+            # Recycle fast-swap: imports and server setup are done, but
+            # the model load (device init + compile) waits for the
+            # orchestrator to POST /standby/activate once the
+            # predecessor releases the chip (subprocess_orchestrator
+            # recycle path).
+            server.standby_model(load)
+            server.start([])
+        else:
+            server.start([load()])

@@ -9,6 +9,7 @@ exactly these), serving :predict, :generate, and /generate_stream.
 import argparse
 import logging
 
+from kfserving_tpu import startup
 from kfserving_tpu.engine.compile_cache import enable as enable_compile_cache
 from kfserving_tpu.predictors.llm import GenerativeModel
 from kfserving_tpu.server.app import ModelServer, parser as server_parser
@@ -52,11 +53,16 @@ if __name__ == "__main__":
     enable_compile_cache()
     server = build_server(args)
     model = GenerativeModel(args.model_name, args.model_dir)
+
+    def load():
+        startup.report_device()  # first touch of the device
+        model.load()
+        return model
+
     if os.environ.get("KFS_STANDBY"):
         # Recycle fast-swap: load (device init + compile) deferred to
         # POST /standby/activate — see jaxserver/__main__.py.
-        server.standby_model(lambda: (model.load(), model)[1])
+        server.standby_model(load)
         server.start([])
     else:
-        model.load()
-        server.start([model])
+        server.start([load()])

@@ -30,6 +30,10 @@ _native = None
 
 
 def _load():
+    """Import the extension once; log once, at WARNING, which codec
+    serves and why — a machine built from what git holds has no .so
+    until `build()` runs, and the pure-Python codec is several times
+    slower on the V1 JSON wire."""
     global _native
     if _native is not None:
         return _native
@@ -44,26 +48,26 @@ def _load():
         # every hinted call, so refuse it.
         probe = _tensorjson.parse_v1(b'{"instances": [1], "x": 1}',
                                      "u1")
-        if len(probe) != 5:
-            logger.warning(
-                "stale _tensorjson extension (no extra-keys flag); "
-                "using pure-Python codec — rebuild with native.build(force=True)")
-            _native = False
-        else:
-            _native = _tensorjson
-            logger.info("native tensorjson codec loaded")
+        reason = None if len(probe) == 5 else \
+            "stale extension (no extra-keys flag)"
     except TypeError:
-        logger.warning(
-            "stale _tensorjson extension (no dtype-hint arg); using "
-            "pure-Python codec — rebuild with native.build(force=True)")
+        reason = "stale extension (no dtype-hint arg)"
+    except (ImportError, ValueError) as exc:
+        reason = f"extension not loadable ({exc})"
+    if reason is None:
+        _native = _tensorjson
+        logger.warning("tensorjson codec=native (%s)",
+                       _tensorjson.__file__)
+    else:
         _native = False
-    except (ImportError, ValueError):
-        _native = False
+        logger.warning("tensorjson codec=python: %s — build it with "
+                       "native.build(force=True)", reason)
     return _native
 
 
 def build(force: bool = False) -> bool:
-    """Compile the extension in-place (used by tests/deploy scripts)."""
+    """Compile the extension in-place (used by tests/deploy scripts
+    and chip_smoke.py); False when the compiler fails."""
     import glob
     import subprocess
 
@@ -73,12 +77,13 @@ def build(force: bool = False) -> bool:
         subprocess.run(
             [sys.executable, os.path.join(_CSRC, "setup.py")],
             cwd=_CSRC, check=True, capture_output=True, timeout=120)
-        global _native
-        _native = None  # re-probe
-        return bool(_load())
-    except Exception as e:
+    except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
+            OSError) as e:
         logger.warning("native build failed: %s", e)
         return False
+    global _native
+    _native = None  # re-probe
+    return bool(_load())
 
 
 def available() -> bool:

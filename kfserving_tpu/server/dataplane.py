@@ -54,11 +54,17 @@ class DataPlane:
         return [m.name for m in self.repository.get_models()]
 
     def server_metadata(self) -> Dict[str, Any]:
-        return {
+        from kfserving_tpu import startup
+
+        meta = {
             "name": SERVER_NAME,
             "version": SERVER_VERSION,
             "extensions": ["model_repository"],
         }
+        device = startup.device()
+        if device is not None:
+            meta["device"] = device
+        return meta
 
     def model_metadata(self, name: str) -> Dict[str, Any]:
         model = self.repository.get_model(name)

@@ -10,15 +10,17 @@ at GET /startup_phases; the recycling orchestrator attaches them to
 its swap_breakdown.
 """
 
+import json
 import logging
 import os
 import time
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 logger = logging.getLogger("kfserving_tpu.startup")
 
 _marks: Dict[str, float] = {}
 _birth: Optional[float] = None
+_device: Optional[Dict[str, Any]] = None
 
 
 def _process_birth_monotonic() -> float:
@@ -58,3 +60,50 @@ def phases() -> Dict[str, float]:
 # (interpreter start, sitecustomize, the importing module's own import
 # chain) lands in "interpreter_imports".
 mark("interpreter_imports")
+
+
+def report_device() -> Dict[str, Any]:
+    """Name the device(s) this process holds, as JAX reports them.
+
+    The chip-owning servers call this at start-up, BEFORE any model
+    loads: the record is logged as one line (so whoever started the
+    process can refuse a wrong platform in seconds) and served under
+    "device" by the server metadata route (GET /v2)."""
+    import jax
+
+    from kfserving_tpu.engine.hbm import device_hbm_bytes
+    from kfserving_tpu.engine.jax_engine import device_peak_flops
+    from kfserving_tpu.observability.profiling.roofline import (
+        device_peak_hbm_bw,
+    )
+
+    global _device
+    devices = jax.devices()
+    _device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "ids": [d.id for d in devices],
+        # Device ids are process-local (a process pinned to one chip of
+        # a four-chip host sees id 0): the pin itself tells replicas
+        # sharing a host apart (control/topology.py).
+        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        "hbm_bytes": [device_hbm_bytes(d) for d in devices],
+        "peak_flops": device_peak_flops(),
+        "peak_hbm_bw": device_peak_hbm_bw(),
+    }
+    logger.warning("device %s", json.dumps(_device))
+    return _device
+
+
+def device() -> Optional[Dict[str, Any]]:
+    """The start-up device record plus each device's HBM in use now;
+    None in a process that never reported one (CPU frameworks)."""
+    if _device is None:
+        return None
+    import jax
+
+    from kfserving_tpu.engine.hbm import device_hbm_in_use
+
+    return {**_device,
+            "hbm_in_use": [device_hbm_in_use(d) for d in jax.devices()]}

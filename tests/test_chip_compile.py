@@ -1,0 +1,303 @@
+"""What can be asked about the chip without the chip.
+
+- The Pallas kernels of the serving path compile for a *described* v5e at
+  the shapes `chip_smoke.py` serves — alone, and under a four-device mesh
+  through the dispatchers' `shard_map` wrappers.  A compile is not a chip
+  run; it catches what interpret mode cannot (tiling, VMEM, partitioning).
+- `chip_smoke.py`'s phases pass at toy size on the CPU when handed toy
+  configs, and its command line — fixed to "tpu" — fails here.
+- The pieces the smoke leans on: no XLA fallback behind a chosen kernel,
+  the sharded wrappers' numerics, one set of chips per replica.
+"""
+
+import functools
+import json
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+TOY_PREDICT = {
+    "architecture": "mlp",
+    "arch_kwargs": {"input_dim": 64, "features": [128], "num_classes": 10},
+    "max_batch_size": 16, "batch_buckets": [4, 16], "pipeline_depth": 3,
+    "max_latency_ms": 15.0, "warmup": True, "input_dtype": "uint8",
+    "scale": 1.0 / 255.0, "output": "logits",
+}
+TOY_DECODER = {
+    "architecture": "decoder_tiny",
+    "arch_kwargs": {"num_layers": 2, "hidden_size": 64, "num_heads": 4,
+                    "intermediate_size": 128, "max_seq": 256},
+    "max_slots": 4, "max_seq": 256, "prefill_buckets": [64, 256],
+    "block_size": 32, "cache_blocks": 24, "steps_per_call": 2,
+    "tokenizer": "byte",
+}
+TOY_BATCH = np.random.default_rng(0).integers(
+    0, 256, size=(4, 64)).astype(np.uint8)
+
+
+# -- compiles for the described chip -------------------------------------------
+@pytest.fixture(scope="module")
+def v5e():
+    """A described (not attached) v5e 2x2, with the persistent compile
+    cache off: such a compile is written to it but cannot be read back."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {exc}")
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
+def _tp4(devices) -> Mesh:
+    return Mesh(np.array(devices[:4]).reshape(1, 1, 4), ("dp", "sp", "tp"))
+
+
+def _kernel_case(kernel: str):
+    """(function, [(shape, dtype, spec under a tp mesh), ...]) of one kernel
+    at the shapes the smoke's generate phase serves.  The functions are
+    the ones the dispatchers call: bare kernel without a mesh, `shard_map`
+    over heads inside one."""
+    from kfserving_tpu.ops import attention, paged_attention
+
+    s = chip_smoke.kernel_shapes(chip_smoke.DECODER)
+    heads = P(None, None, "tp", None)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    if kernel == "paged":
+        pool = ((s["blocks"], s["block_size"], s["heads"], s["head_dim"]),
+                bf16, heads)
+        return paged_attention.paged_attention_sharded, [
+            ((s["slots"], 1, s["heads"], s["head_dim"]), bf16, heads),
+            pool, pool,
+            ((s["slots"], s["blocks_per_slot"]), i32, P()),
+            ((s["slots"],), i32, P())]
+    qkv = ((1, s["prefill"], s["heads"], s["head_dim"]), bf16, heads)
+    if kernel == "flash_causal":
+        return (lambda q, k, v: attention._flash(q, k, v, True, None),
+                [qkv, qkv, qkv])
+    return (lambda q, k, v, n: attention._flash(q, k, v, False, n),
+            [qkv, qkv, qkv, ((1,), i32, P())])
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one-chip", "tp4-mesh"])
+@pytest.mark.parametrize("kernel",
+                         ["paged", "flash_causal", "flash_kv_lengths"])
+def test_kernel_compiles_for_described_v5e(v5e, kernel, sharded):
+    from jax.sharding import SingleDeviceSharding
+
+    fn, args = _kernel_case(kernel)
+    if sharded:
+        mesh = _tp4(v5e.devices)
+        structs = [jax.ShapeDtypeStruct(shape, dtype,
+                                        sharding=NamedSharding(mesh, spec))
+                   for shape, dtype, spec in args]
+        with jax.set_mesh(mesh):
+            compiled = jax.jit(fn).lower(*structs).compile()
+    else:
+        one = SingleDeviceSharding(v5e.devices[0])
+        structs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+                   for shape, dtype, _ in args]
+        compiled = jax.jit(fn).lower(*structs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):
+    """Why the wrappers exist: the pool sharded on heads, as the engine
+    shards it under tp, and the kernel called bare."""
+    from kfserving_tpu.ops.paged_attention import paged_attention_tpu
+
+    _, args = _kernel_case("paged")
+    mesh = _tp4(v5e.devices)
+    structs = [jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+               for shape, dtype, spec in args]
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(paged_attention_tpu).lower(*structs).compile()
+
+
+# -- the wrappers' numerics, on virtual CPU devices ----------------------------
+@pytest.fixture
+def cpu_mesh():
+    return _tp4(jax.devices())
+
+
+def test_paged_kernel_under_mesh_matches_xla(cpu_mesh):
+    from kfserving_tpu.ops.paged_attention import (
+        paged_attention_sharded,
+        paged_attention_xla,
+    )
+
+    rng = np.random.default_rng(0)
+    b, h, d, nb, bs = 3, 8, 64, 10, 128
+    q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
+    pool_k = jnp.asarray(rng.standard_normal((nb, bs, h, d)), jnp.float32)
+    pool_v = jnp.asarray(rng.standard_normal((nb, bs, h, d)), jnp.float32)
+    table = jnp.asarray([[0, 1], [2, -1], [3, 4]], jnp.int32)
+    lengths = jnp.asarray([200, 7, 256], jnp.int32)
+    want = paged_attention_xla(q, pool_k, pool_v, table, lengths)
+    heads = NamedSharding(cpu_mesh, P(None, None, "tp", None))
+    with jax.set_mesh(cpu_mesh):
+        got = jax.jit(functools.partial(paged_attention_sharded,
+                                        interpret=True))(
+            jax.device_put(q, heads), jax.device_put(pool_k, heads),
+            jax.device_put(pool_v, heads), table, lengths)
+    assert len(got.sharding.device_set) == 4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_flash_kernel_under_mesh_matches_xla(cpu_mesh, monkeypatch):
+    from jax.experimental import pallas as pl
+
+    from kfserving_tpu.ops import attention
+
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    rng = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 64, 4, 64)), jnp.float32)
+               for _ in range(3))
+    lengths = jnp.asarray([64, 40], jnp.int32)
+    pad = (jnp.arange(64)[None, :] < lengths[:, None])[:, None, None, :]
+    with jax.set_mesh(cpu_mesh):
+        got = jax.jit(lambda q, k, v, n: attention._flash(q, k, v, False, n))(
+            q, k, v, lengths)
+    want = attention._xla_attention(q, k, v, pad)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_flash_dispatcher_propagates_kernel_errors(monkeypatch):
+    """A kernel the dispatcher chose runs or the call fails: no XLA
+    fallback behind it."""
+    from kfserving_tpu.ops import attention, pallas_attention
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("mosaic refused this kernel")
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    monkeypatch.setattr(pallas_attention, "flash_attention", broken)
+    q = jnp.ones((1, 1024, 2, 64), jnp.bfloat16)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        attention.dot_product_attention(q, q, q, causal=True)
+
+
+# -- chip_smoke.py: fails off the chip, phases pass at toy size ----------------
+def test_chip_smoke_fails_without_a_chip():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, timeout=60)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "expected 'tpu'" in proc.stderr
+
+
+def test_chip_smoke_fails_outside_a_checkout(tmp_path):
+    alone = tmp_path / "chip_smoke.py"
+    alone.write_bytes(open(os.path.join(REPO, "chip_smoke.py"), "rb").read())
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    proc = subprocess.run([sys.executable, str(alone)], cwd=tmp_path, env=env,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_smoke_parent_stays_off_jax():
+    """A parent that has touched JAX holds the chip, and its children
+    then fail: nothing the phases import in the parent may import jax."""
+    code = (
+        "import sys, chip_smoke\n"
+        "from kfserving_tpu.protocol import native, v2\n"
+        "from kfserving_tpu.control.controller import Controller\n"
+        "from kfserving_tpu.control.router import IngressRouter\n"
+        "from kfserving_tpu.control.spec import InferenceService\n"
+        "from kfserving_tpu.control.subprocess_orchestrator import (\n"
+        "    SubprocessOrchestrator)\n"
+        "assert 'jax' not in sys.modules, 'the smoke parent imported jax'\n")
+    subprocess.run([sys.executable, "-c", code], cwd=REPO, check=True,
+                   timeout=60)
+
+
+def test_predict_phase_at_toy_size():
+    device = chip_smoke.phase_predict(TOY_PREDICT, "cpu", TOY_BATCH)
+    assert device["platform"] == "cpu"
+
+
+def test_generate_phase_at_toy_size():
+    out = chip_smoke.phase_generate(TOY_DECODER, "cpu")
+    assert out["device"]["platform"] == "cpu"
+    assert len(out["short"]["ids"]) == len(out["long"]["ids"]) == 7
+    # Off the chip the dispatchers choose the XLA formulations, and say so.
+    assert {path for path, _, _ in out["paths"]} == {"xla", "xla_paged"}
+
+
+def test_kernels_phase_at_toy_size():
+    device = chip_smoke.phase_kernels(TOY_DECODER, "cpu")
+    assert device["platform"] == "cpu"
+
+
+def test_phase_refuses_the_wrong_platform():
+    with pytest.raises(chip_smoke.SmokeFailure, match="expected 'tpu'"):
+        chip_smoke.phase_generate(TOY_DECODER, "tpu")
+
+
+# -- one set of chips per replica ----------------------------------------------
+def test_replicas_claim_their_own_chips():
+    from kfserving_tpu.control.spec import PredictorSpec
+    from kfserving_tpu.control.subprocess_orchestrator import (
+        SubprocessOrchestrator,
+    )
+    from kfserving_tpu.control.topology import select_topology
+
+    class _Exited:
+        returncode = -9
+
+    orch = SubprocessOrchestrator()
+    first = orch._claim_chips(1)
+    assert first.chips == [0]
+    assert orch._claim_chips(2).chips == [1, 2]
+    first.process = _Exited()  # its process exited: the claim lapses
+    assert orch._claim_chips(1).chips == [0]
+
+    placement = select_topology(PredictorSpec(framework="jax",
+                                              storage_uri="file:///m"))
+    assert "TPU_VISIBLE_CHIPS" not in placement.env()
+    env = placement.env([3])
+    assert env["TPU_VISIBLE_CHIPS"] == "3"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+
+@pytest.mark.slow
+def test_four_chip_phases_at_toy_size():
+    """The --four-chips phases on virtual CPU devices: the decoder under
+    tp=4 against one device, and four replicas behind the router, each
+    handed its own chip index."""
+    device = chip_smoke.phase_sharded(TOY_DECODER, "cpu", tp=4)
+    assert device["count"] >= 4
+    records = chip_smoke.phase_replicas(TOY_PREDICT, "cpu", replicas=4,
+                                        instance=TOY_BATCH[0])
+    assert sorted(r["device"]["visible_chips"] for r in records) \
+        == ["0", "1", "2", "3"]
+    assert json.dumps(records)  # plain data, printable as observations

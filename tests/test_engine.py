@@ -220,35 +220,44 @@ class TestCompileCache:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           saved_min)
 
-    def test_enable_points_jax_at_dir(self, tmp_path, monkeypatch):
+    def test_env_var_is_left_to_jax(self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: JAX's own handling stands,
+        enable() sets no directory in code."""
         import jax
 
         from kfserving_tpu.engine import compile_cache
 
-        monkeypatch.setattr(compile_cache, "_active_dir", None)
-        d = str(tmp_path / "xla-cache")
-        out = compile_cache.enable(d, min_compile_time_secs=0.0)
-        assert out == d and os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
-        # idempotent for the same dir
-        assert compile_cache.enable(d) == d
-
-    def test_enable_repoints_with_warning(self, tmp_path, monkeypatch,
-                                          caplog):
-        from kfserving_tpu.engine import compile_cache
-
-        monkeypatch.setattr(compile_cache, "_active_dir", None)
-        a = str(tmp_path / "a")
-        b = str(tmp_path / "b")
-        compile_cache.enable(a)
-        with caplog.at_level("WARNING"):
-            assert compile_cache.enable(b) == b
-        assert any("re-pointing" in r.message for r in caplog.records)
-
-    def test_env_var_default(self, tmp_path, monkeypatch):
-        from kfserving_tpu.engine import compile_cache
-
-        monkeypatch.setattr(compile_cache, "_active_dir", None)
         d = str(tmp_path / "envcache")
-        monkeypatch.setenv("KFSERVING_TPU_COMPILE_CACHE", d)
-        assert compile_cache.enable() == d
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert compile_cache.enable() == "sentinel"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+
+    def test_unset_env_picks_fixed_checkout_path(self, monkeypatch):
+        import jax
+
+        from kfserving_tpu.engine import compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        expected = os.path.join(repo, ".kfs_cache", "xla")
+        assert compile_cache.enable(min_compile_time_secs=0.0) == expected
+        assert jax.config.jax_compilation_cache_dir == expected
+        assert os.path.isdir(expected)
+        # fixed: a second call (any process, any time) names the same dir
+        assert compile_cache.enable() == expected
+
+    def test_enable_sets_threshold_and_gauge(self, tmp_path, monkeypatch):
+        import jax
+
+        from kfserving_tpu.engine import compile_cache
+        from kfserving_tpu.observability import REGISTRY
+
+        d = str(tmp_path / "envcache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        jax.config.update("jax_compilation_cache_dir", d)
+        assert compile_cache.enable(min_compile_time_secs=0.25) == d
+        assert jax.config.jax_persistent_cache_min_compile_time_secs \
+            == 0.25
+        assert "kfserving_tpu_compile_cache_enabled" in \
+            REGISTRY.sample_names()

@@ -398,7 +398,7 @@ async def test_standby_activation_failure_keeps_incumbent(tmp_path):
     orch = SubprocessOrchestrator(
         env_overrides={"JAX_PLATFORMS": "cpu"},
         recycle=RecyclePolicy(max_requests=3, check_interval_s=0.3,
-                              min_age_s=0.0))
+                              min_age_s=0.0, exclusive_device=False))
     spec = PredictorSpec(framework="jax",
                          storage_uri=_write_mlp_dir(tmp_path))
     cid = "default/chaos/predictor"

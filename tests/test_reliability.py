@@ -669,11 +669,16 @@ class _Replica:
         self.server = None
         self.host = None
         self.heads = []  # raw request heads, for header assertions
+        self._handlers = set()  # open connection handler tasks
 
     async def start(self):
         async def handle(reader, writer):
-            self.heads.append(await reader.readuntil(b"\r\n\r\n"))
+            task = asyncio.current_task()
+            self._handlers.add(task)
+            task.add_done_callback(self._handlers.discard)
             try:
+                self.heads.append(
+                    await reader.readuntil(b"\r\n\r\n"))
                 while self.hanging:
                     await asyncio.sleep(0.02)
                 body = b'{"predictions": [1]}'
@@ -691,8 +696,14 @@ class _Replica:
         self.host = f"127.0.0.1:{port}"
 
     async def stop(self):
+        # wait_closed() waits for every open connection (Python 3.12),
+        # and a hang-mode handler never finishes on its own: release
+        # it and cancel whatever is still open before waiting.
+        self.hanging = False
         self.server.close()
-        await self.server.wait_closed()
+        for task in list(self._handlers):
+            task.cancel()
+        await asyncio.wait_for(self.server.wait_closed(), timeout=5.0)
 
 
 @pytest.mark.chaos
