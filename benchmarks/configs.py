@@ -52,8 +52,8 @@ async def _serve(models, **server_kwargs):
 def _reset_timeline() -> None:
     """Each generate config summarizes ITS OWN device timeline: the
     engine event ring is process-wide, and a previous config's waves
-    leaking into this config's dispatch-gap stats would corrupt the
-    committed summary."""
+    leaking into this config's counts would corrupt the committed
+    summary."""
     from kfserving_tpu.observability.profiling import TIMELINE
 
     TIMELINE.clear()
@@ -61,7 +61,7 @@ def _reset_timeline() -> None:
 
 def _timeline_summary() -> Dict[str, Any]:
     """Device-timeline summary for the committed bench record
-    (dispatch-gap p50/p99, HOLD time, suppressed-wave ratio) — the
+    (HOLD time, suppressed-wave ratio, slice counts) — the
     same events `GET /debug/profile` renders, so the BENCH JSON and
     the Perfetto view can never disagree.  Scope: the WHOLE config run
     since its `_reset_timeline()` (warmup and every interleaved A/B

@@ -1,0 +1,16 @@
+"""ttft_delivery_mean_ms: the `delivery` stage of time to first token,
+from prefill enqueued until the first token is emitted: the
+prefill's device time and the in-flight queue ahead of its fetch,
+mean over the requests first answered in the window:
+kfserving_tpu_generator_ttft_stage_ms{stage="delivery"} differenced between
+the window's edges.  The three stages sum to the engine's llm_ttft_ms."""
+
+from chipbench import engine_phases
+
+UNIT, LAYER, SOURCE = "ms", "GenerationEngine", "program_counter"
+MOVES = "request_mean_ms"
+
+
+def read(run):
+    return engine_phases.histogram_mean(
+        run, "kfserving_tpu_generator_ttft_stage_ms", stage="delivery")

@@ -41,6 +41,42 @@ def declare_warmup_complete(source: str) -> None:
     sanitizer.declare_warmup_complete(source)
 
 
+# JAX's monitoring events -> the `event` label of
+# kfserving_tpu_jax_compile_events_total.
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+_counting = False
+
+
+def _on_jax_event(event: str, *_, **__) -> None:
+    label = _JAX_EVENTS.get(event)
+    if label is not None:
+        from kfserving_tpu.observability import metrics as obs
+
+        obs.jax_compile_events().labels(event=label).inc()
+
+
+def count_jax_compile_events() -> None:
+    """Register, once per process, `jax.monitoring` listeners that
+    count every program JAX traces, lowers and compiles and every
+    persistent-cache hit and miss.  JAX itself does the counting, so
+    a retrace that `note_compilation`'s key set cannot see (a weak
+    type, a new static argument) is counted too."""
+    global _counting
+    if _counting:
+        return
+    _counting = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
 def enable(min_compile_time_secs: float = 0.5) -> str:
     """Enable the JAX persistent compilation cache; returns its
     directory.

@@ -60,6 +60,7 @@ params + cache against engine/hbm.py's budget.
 
 import asyncio
 import concurrent.futures
+import functools
 import itertools
 import logging
 import os
@@ -74,6 +75,11 @@ from kfserving_tpu.engine import compile_cache
 from kfserving_tpu.observability import attribution
 from kfserving_tpu.observability import metrics as obs
 from kfserving_tpu.observability.profiling import TIMELINE
+from kfserving_tpu.observability.profiling.timeline import (
+    FETCH,
+    HOST,
+    LAUNCH,
+)
 from kfserving_tpu.parallel.mesh import mesh_scope
 from kfserving_tpu.protocol.errors import InferenceError, InvalidInput
 from kfserving_tpu.reliability import sanitizer
@@ -84,6 +90,23 @@ logger = logging.getLogger("kfserving_tpu.engine.generator")
 # jax_engine._engine_seq): a model name alone would let a reloaded
 # engine inherit its predecessor's warmup declaration.
 _generator_seq = itertools.count()
+
+
+def _dispatch_timed(program: str):
+    """Observe the wall time of an enqueue callable (it runs on the
+    launching thread) as generator_dispatch_host_ms{program=}; a
+    call that raises dispatched nothing and is not observed."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def timed(self, *args, **kwargs):
+            t0 = time.perf_counter()
+            out = fn(self, *args, **kwargs)
+            obs.generator_dispatch_host_ms().labels(
+                program=program).observe(
+                    (time.perf_counter() - t0) * 1000.0)
+            return out
+        return timed
+    return wrap
 
 
 @dataclass
@@ -113,6 +136,12 @@ class _Request:
     trace_id: Optional[str] = None
     submit_t: float = 0.0
     last_emit_t: Optional[float] = None
+    # The hand-offs between submit and first emission (perf_counter):
+    # taken out of the pending queue, and its prefill (or first chunk)
+    # enqueued.  Re-stamped when a preempted request is admitted
+    # again; read once, at the first emission (ttft_stage_ms).
+    taken_t: float = 0.0
+    enqueued_t: float = 0.0
     # -- cost attribution (observability/attribution.py): accumulated
     # by the scheduler across the request's whole life (preemptions
     # included), finalized into ONE record at the terminal event.
@@ -1434,20 +1463,23 @@ class GenerationEngine:
             pending = self._spill_pending
             self._spill_pending = []
         jnp = self._jnp
-        for i in range(0, len(pending), 32):
-            grp = pending[i:i + 32]
-            padded = 1
-            while padded < len(grp):
-                padded *= 2
-            # Pad to a pow2 gather width (bounded compile count, same
-            # discipline as prefill row buckets); pad rows duplicate
-            # block 0 and are simply not written to the tier.
-            idx = np.asarray(
-                [b for _, b in grp]
-                + [grp[0][1]] * (padded - len(grp)), np.int32)
-            self._note_program("kv_gather", padded)
-            snap = self._gather_blocks(self._caches, jnp.asarray(idx))
-            self._executor.submit(self._spill_write, grp, snap)
+        with TIMELINE.span(LAUNCH, "engine.spill", blocks=len(pending)):
+            for i in range(0, len(pending), 32):
+                grp = pending[i:i + 32]
+                padded = 1
+                while padded < len(grp):
+                    padded *= 2
+                # Pad to a pow2 gather width (bounded compile count,
+                # same discipline as prefill row buckets); pad rows
+                # duplicate block 0 and are simply not written to the
+                # tier.
+                idx = np.asarray(
+                    [b for _, b in grp]
+                    + [grp[0][1]] * (padded - len(grp)), np.int32)
+                self._note_program("kv_gather", padded)
+                snap = self._gather_blocks(self._caches,
+                                           jnp.asarray(idx))
+                self._executor.submit(self._spill_write, grp, snap)
 
     def _spill_write(self, grp: List[Tuple[bytes, int]], snap) -> None:
         """Fetch-executor side of a spill: D2H the gathered snapshot
@@ -1522,6 +1554,13 @@ class GenerationEngine:
                 return True
             pending = self._faultback_pending
             self._faultback_pending = []
+        with TIMELINE.span(LAUNCH, "engine.faultback",
+                           blocks=len(pending)):
+            return self._land_faultbacks(pending)
+
+    def _land_faultbacks(self, pending) -> bool:
+        """The body of `_drain_faultbacks` once there is something to
+        land: read, insert, publish; False without a dispatch."""
         from kfserving_tpu.reliability import fault_sites
         from kfserving_tpu.reliability.faults import (
             FaultInjected,
@@ -2267,6 +2306,9 @@ class GenerationEngine:
                     break  # pool pressure: wait for released blocks
                 dest_rows.append(plan)
             group.append(self._pending.popleft())
+        now = time.perf_counter()
+        for req in group:
+            req.taken_t = now
         return group, free[:len(group)], bucket, dest_rows
 
     # -- chunked prefill ---------------------------------------------------
@@ -2303,9 +2345,11 @@ class GenerationEngine:
         slot = self._free_slot()
         req = self._pending[0]
         chunk_regs: Dict[int, Tuple[bytes, int]] = {}
-        dest = self._plan_prompt_blocks(req, slot,
-                                        chunk_regs=chunk_regs,
-                                        force_miss=force_miss)
+        with TIMELINE.span(HOST, "engine.admit", trace_id=req.trace_id,
+                           slot=slot, rows=1):
+            dest = self._plan_prompt_blocks(req, slot,
+                                            chunk_regs=chunk_regs,
+                                            force_miss=force_miss)
         if dest is None:
             return False
         if (self.kv_tier is not None and self._faultback_pending
@@ -2319,6 +2363,7 @@ class GenerationEngine:
             self._schedule_block_release(slot)
             return True
         self._pending.popleft()
+        req.taken_t = time.perf_counter()
         n = int(req.prompt_ids.size)
         act = _Active(req=req, length=n, last_token=-1, generated=0,
                       prefilling=True,
@@ -2327,6 +2372,7 @@ class GenerationEngine:
         self._slots[slot] = act
         self.chunked_admissions += 1
         await self._step_chunk(loop, inflight, slot, act)
+        req.enqueued_t = time.perf_counter()
         return True
 
     async def _step_chunk(self, loop, inflight: deque, slot: int,
@@ -2405,6 +2451,7 @@ class GenerationEngine:
                 self._prefix_index[chain] = blk
                 self._block_chain[blk] = chain
 
+    @_dispatch_timed("chunk")
     def _enqueue_chunk(self, slot: int, act: _Active, idx: int,
                        final: bool):
         """Runs on the enqueue executor: park the slot's feed row
@@ -2425,26 +2472,31 @@ class GenerationEngine:
         start = idx * C
         end = min(start + C, n)
         width = end - start
-        # Roofline accounting: this chunk's queries attend the whole
-        # resident prefix (positions start..end-1 attend up to their
-        # own index) — the same triangular term the monolithic path
-        # accrues, sliced per chunk.
-        self._prefill_flops += (
-            self._flops_matmul_per_token * width
-            + self._attn_flops_coeff * width * (start + end) / 2.0)
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :width] = req.prompt_ids[start:end]
-        # Padding queries of a partial final chunk park on the same
-        # out-of-range sentinel: their cache writes drop and their
-        # logits are never read (last_idx points at the last REAL
-        # token).
-        qpos = np.full((1, C), self.max_seq, np.int32)
-        qpos[0, :width] = np.arange(start, end, dtype=np.int32)
-        self._feed_tokens, self._feed_positions = self._feed_update(
-            self._feed_tokens, self._feed_positions,
-            jnp.asarray(np.asarray([slot], np.int32)),
-            jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), self.max_seq, jnp.int32))
+        with TIMELINE.span(LAUNCH, "engine.prep.chunk",
+                           trace_id=req.trace_id, slot=slot):
+            # Roofline accounting: this chunk's queries attend the
+            # whole resident prefix (positions start..end-1 attend up
+            # to their own index) — the same triangular term the
+            # monolithic path accrues, sliced per chunk.
+            self._prefill_flops += (
+                self._flops_matmul_per_token * width
+                + self._attn_flops_coeff * width * (start + end) / 2.0)
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :width] = req.prompt_ids[start:end]
+            # Padding queries of a partial final chunk park on the
+            # same out-of-range sentinel: their cache writes drop and
+            # their logits are never read (last_idx points at the last
+            # REAL token).
+            qpos = np.full((1, C), self.max_seq, np.int32)
+            qpos[0, :width] = np.arange(start, end, dtype=np.int32)
+            slot_d = jnp.asarray(np.asarray([slot], np.int32))
+            park = (jnp.zeros((1,), jnp.int32),
+                    jnp.full((1,), self.max_seq, jnp.int32))
+        with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=1):
+            self._feed_tokens, self._feed_positions = \
+                self._feed_update(
+                    self._feed_tokens, self._feed_positions,
+                    slot_d, *park)
         # Slice the table row to the blocks chunks 0..idx cover: the
         # chunk's per-query-causal attention never reads past its own
         # end, and gathering the full max_seq-wide row would make
@@ -2456,28 +2508,36 @@ class GenerationEngine:
         # drop via the block_idx >= mb guard in paged_write.
         bpc = C // self.block_size
         nb = min((idx + 1) * bpc, self._tables.shape[1])
-        self._note_program("chunk", nb)
-        with self._block_lock:
-            row = self._tables[slot:slot + 1, :nb].copy()
         with mesh_scope(self.mesh):
-            (first, self._caches, chosen_lp, top_ids, top_lps) = \
-                self._chunk_prefill(
-                    self.variables, self._caches, jnp.asarray(row),
-                    jnp.asarray(ids), jnp.asarray(qpos),
-                    jnp.asarray(np.asarray([max(width - 1, 0)],
-                                           np.int32)),
-                    jnp.asarray(np.asarray([req.temperature],
-                                           np.float32)),
-                    jnp.asarray(np.asarray([req.top_k], np.int32)),
-                    jnp.asarray(np.asarray([req.top_p], np.float32)),
-                    jnp.asarray(np.asarray([req.seed], np.int32)),
-                    jnp.asarray(np.asarray([n], np.int32)))
+            with TIMELINE.span(LAUNCH, "engine.prep.chunk",
+                               trace_id=req.trace_id, slot=slot):
+                self._note_program("chunk", nb)
+                with self._block_lock:
+                    row = self._tables[slot:slot + 1, :nb].copy()
+                n_d = jnp.asarray(np.asarray([n], np.int32))
+                args = [jnp.asarray(row), jnp.asarray(ids),
+                        jnp.asarray(qpos),
+                        jnp.asarray(np.asarray([max(width - 1, 0)],
+                                               np.int32)),
+                        jnp.asarray(np.asarray([req.temperature],
+                                               np.float32)),
+                        jnp.asarray(np.asarray([req.top_k], np.int32)),
+                        jnp.asarray(np.asarray([req.top_p],
+                                               np.float32)),
+                        jnp.asarray(np.asarray([req.seed], np.int32)),
+                        n_d]
+            with TIMELINE.span(LAUNCH, "engine.launch.chunk",
+                               trace_id=req.trace_id, slot=slot,
+                               rows=1, bucket=nb * self.block_size):
+                (first, self._caches, chosen_lp, top_ids, top_lps) = \
+                    self._chunk_prefill(self.variables, self._caches,
+                                        *args)
         if final:
-            self._feed_tokens, self._feed_positions = \
-                self._feed_update(
-                    self._feed_tokens, self._feed_positions,
-                    jnp.asarray(np.asarray([slot], np.int32)), first,
-                    jnp.asarray(np.asarray([n], np.int32)))
+            with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=1):
+                self._feed_tokens, self._feed_positions = \
+                    self._feed_update(
+                        self._feed_tokens, self._feed_positions,
+                        slot_d, first, n_d)
         lp_h = ((chosen_lp, top_ids, top_lps)
                 if req.logprobs > 0 else None)
         return first, lp_h
@@ -2613,8 +2673,9 @@ class GenerationEngine:
                         break  # pool pressure: wait for frees
                     admitted = True
                     continue
-                group, slots, bucket, dest_rows = \
-                    self._take_prefill_group(force_miss=force_miss)
+                with TIMELINE.span(HOST, "engine.admit"):
+                    group, slots, bucket, dest_rows = \
+                        self._take_prefill_group(force_miss=force_miss)
                 if not group:
                     break  # paged pool pressure: wait for frees
                 if (self.kv_tier is not None
@@ -2660,7 +2721,9 @@ class GenerationEngine:
                 # time, but the device feed arrays already carry them,
                 # so the very next decode wave includes these slots.
                 entries = []
+                enqueued_t = time.perf_counter()
                 for req, slot in zip(group, slots):
+                    req.enqueued_t = enqueued_t
                     # The prefill is enqueued: this slot's provisional
                     # prefix registrations are backed by dispatched
                     # writes (even for a cancelled row — its blocks
@@ -2710,8 +2773,13 @@ class GenerationEngine:
                     if admitted:
                         continue
                     try:
-                        await asyncio.wait_for(self._wakeup.wait(),
-                                               timeout=1.0)
+                        # Held across the await: the loop thread runs
+                        # other tasks meanwhile, and the trace's
+                        # reduction reads this interval by its name,
+                        # not by what nests in it.
+                        with TIMELINE.span(HOST, "engine.wait.request"):
+                            await asyncio.wait_for(self._wakeup.wait(),
+                                                   timeout=1.0)
                     except asyncio.TimeoutError:
                         if not self._pending and not any(
                                 s is not None for s in self._slots):
@@ -2754,7 +2822,8 @@ class GenerationEngine:
                        and s.chunk_next < s.chunk_total
                        and self._slots[slot_i] is s):
                     await self._step_chunk(loop, inflight, slot_i, s)
-            failed = self._ensure_block_capacity()
+            with TIMELINE.span(HOST, "engine.grow"):
+                failed = self._ensure_block_capacity()
             held = False
             if failed:
                 # Pool pressure: cold prompts MID-CHUNKED-PREFILL
@@ -2878,7 +2947,9 @@ class GenerationEngine:
             kind, fut, meta, t0 = inflight.popleft()
             t_await = time.perf_counter()
             try:
-                fetched, lp, _worker_span = await fut
+                # Held across the await, like engine.wait.request.
+                with TIMELINE.span(HOST, "engine.wait.fetch"):
+                    fetched, lp, _worker_span = await fut
                 # Host-blocked time is the LOOP-side await, not the
                 # worker's span: eager fetches overlap on the worker
                 # pool and their spans cover whole-wave latency — the
@@ -2962,10 +3033,13 @@ class GenerationEngine:
                                         trace_id=s.req.trace_id,
                                         slot=slot_i)
                 self._record_pool_sample()
-                self._distribute_spec(samples, draft, lp, entries,
-                                      device_ms=dev_dur * 1000.0,
-                                      draft_ms=draft_ms,
-                                      verify_ms=verify_ms)
+                with TIMELINE.span(HOST, "engine.deliver",
+                                   rows=len(entries),
+                                   steps=self.spec_tokens + 1):
+                    self._distribute_spec(samples, draft, lp, entries,
+                                          device_ms=dev_dur * 1000.0,
+                                          draft_ms=draft_ms,
+                                          verify_ms=verify_ms)
             elif kind == "decode":
                 self._decode_device_s += busy
                 self._decode_wait_s += wait_s
@@ -2980,8 +3054,11 @@ class GenerationEngine:
                                         trace_id=s.req.trace_id,
                                         slot=slot_i)
                 self._record_pool_sample()
-                self._distribute(fetched, lp, meta,
-                                 device_ms=dev_dur * 1000.0)
+                with TIMELINE.span(HOST, "engine.deliver",
+                                   rows=sum(s is not None for s in meta),
+                                   steps=self.steps_per_call):
+                    self._distribute(fetched, lp, meta,
+                                     device_ms=dev_dur * 1000.0)
             elif kind == "chunk":
                 self._prefill_device_s += busy
                 self._prefill_wait_s += wait_s
@@ -3015,7 +3092,10 @@ class GenerationEngine:
                         rec = (float(lp[0][0]),
                                [(int(t), float(p)) for t, p in
                                 zip(lp[1][0][:n_lp], lp[2][0][:n_lp])])
-                    self._emit(slot, int(fetched[0]), rec)
+                    with TIMELINE.span(HOST, "engine.deliver",
+                                       trace_id=act.req.trace_id,
+                                       slot=slot, rows=1):
+                        self._emit(slot, int(fetched[0]), rec)
             else:
                 self._prefill_device_s += busy
                 self._prefill_wait_s += wait_s
@@ -3028,8 +3108,10 @@ class GenerationEngine:
                                         dur_s=dev_dur, t_end=wall,
                                         trace_id=act.req.trace_id,
                                         slot=slot_i)
-                self._finish_prefill(fetched, lp, meta,
-                                     device_ms=dev_dur * 1000.0)
+                with TIMELINE.span(HOST, "engine.deliver",
+                                   rows=len(meta)):
+                    self._finish_prefill(fetched, lp, meta,
+                                         device_ms=dev_dur * 1000.0)
             self._process_deferred_frees()
 
     def _finish_prefill(self, firsts: np.ndarray, lp, entries,
@@ -3071,6 +3153,7 @@ class GenerationEngine:
             self._dispatched_programs.add(key)
             compile_cache.note_compilation(self.sanitize_source, key)
 
+    @_dispatch_timed("decode")
     def _enqueue_wave(self):
         """Dispatch one K-step decode wave (non-blocking: JAX async
         dispatch).  Consumes the device-resident caches + feed arrays
@@ -3079,17 +3162,24 @@ class GenerationEngine:
         # Slot growth for this wave may have evicted spill-pending
         # blocks the wave's decode writes will rewrite: gather first.
         self._drain_spills()
-        self._note_program("decode", self.max_slots,
-                           self.steps_per_call)
-        temps, top_ks, top_ps, seeds, want_lp = self._sampling_arrays()
         with mesh_scope(self.mesh):
-            (toks, self._caches, self._feed_tokens,
-             self._feed_positions, chosen_lp, top_ids, top_lps) = \
-                self._decode(
-                    self.variables, self._caches, self._table_device(),
-                    self._feed_tokens, self._feed_positions,
-                    jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), jnp.asarray(seeds))
+            with TIMELINE.span(LAUNCH, "engine.prep.decode"):
+                self._note_program("decode", self.max_slots,
+                                   self.steps_per_call)
+                temps, top_ks, top_ps, seeds, want_lp = \
+                    self._sampling_arrays()
+                table = self._table_device()
+                sampling = [jnp.asarray(a)
+                            for a in (temps, top_ks, top_ps, seeds)]
+            with TIMELINE.span(LAUNCH, "engine.launch.decode",
+                               rows=self.max_slots,
+                               steps=self.steps_per_call):
+                (toks, self._caches, self._feed_tokens,
+                 self._feed_positions, chosen_lp, top_ids, top_lps) = \
+                    self._decode(
+                        self.variables, self._caches, table,
+                        self._feed_tokens, self._feed_positions,
+                        *sampling)
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         self.decode_steps += 1
         # Snapshot records mid-chunked-prefill slots as None: this
@@ -3111,7 +3201,8 @@ class GenerationEngine:
         t0 = time.perf_counter()
         # THE sanctioned generation fetch: the one place device
         # handles become host arrays, on the fetch executor.
-        with sanitizer.sanctioned_fetch():
+        with TIMELINE.span(FETCH, "engine.fetch"), \
+                sanitizer.sanctioned_fetch():
             # kfslint: disable=host-sync — sanctioned fetch site: the
             # wave's D2H join, off-loop on the fetch executor.
             tokens = np.asarray(toks_h)
@@ -3122,6 +3213,7 @@ class GenerationEngine:
                 lp = tuple(np.asarray(h) for h in lp_h)
         return tokens, lp, time.perf_counter() - t0
 
+    @_dispatch_timed("prefill")
     def _enqueue_prefill_group(self, group: List[_Request],
                                slots: List[int],
                                bucket: int,
@@ -3144,67 +3236,81 @@ class GenerationEngine:
         b_bucket = 1
         while b_bucket < b:
             b_bucket *= 2
-        ids = np.zeros((b_bucket, bucket), np.int32)
-        lengths = np.ones(b_bucket, np.int32)  # dummy rows: length 1
-        temps = np.zeros(b_bucket, np.float32)
-        top_ks = np.zeros(b_bucket, np.int32)
-        top_ps = np.ones(b_bucket, np.float32)
-        seeds = np.zeros(b_bucket, np.int32)
-        slot_arr = np.full(b_bucket, self.max_slots, np.int32)  # OOB
-        want_lp = False
-        for i, (req, slot) in enumerate(zip(group, slots)):
-            n = req.prompt_ids.size
-            ids[i, :n] = req.prompt_ids
-            lengths[i] = n
-            temps[i] = req.temperature
-            top_ks[i] = req.top_k
-            top_ps[i] = req.top_p
-            seeds[i] = req.seed
-            slot_arr[i] = slot
-            want_lp = want_lp or req.logprobs > 0
-        # Roofline accounting: real-token FLOPs (2P matmul + causal
-        # attention's triangular sum) and the bucket's token padding —
-        # padded rows/positions burn device time without FLOPs that
-        # count, which is exactly what the padding-waste gauge shows.
-        for req in group:
-            n = int(req.prompt_ids.size)
-            self._prefill_flops += (
-                self._flops_matmul_per_token * n
-                + self._attn_flops_coeff * n * (n + 1) / 2.0)
-        rec = self._prefill_bucket_tokens.setdefault(bucket,
-                                                     [0.0, 0.0])
-        rec[0] += sum(int(r.prompt_ids.size) for r in group)
-        rec[1] += b_bucket * bucket
-        self._note_program("prefill", b_bucket, bucket)
-        with mesh_scope(self.mesh):
+        with mesh_scope(self.mesh), \
+                TIMELINE.span(LAUNCH, "engine.prep.prefill"):
+            ids = np.zeros((b_bucket, bucket), np.int32)
+            lengths = np.ones(b_bucket, np.int32)  # dummy rows: length 1
+            temps = np.zeros(b_bucket, np.float32)
+            top_ks = np.zeros(b_bucket, np.int32)
+            top_ps = np.ones(b_bucket, np.float32)
+            seeds = np.zeros(b_bucket, np.int32)
+            slot_arr = np.full(b_bucket, self.max_slots, np.int32)  # OOB
+            want_lp = False
+            for i, (req, slot) in enumerate(zip(group, slots)):
+                n = req.prompt_ids.size
+                ids[i, :n] = req.prompt_ids
+                lengths[i] = n
+                temps[i] = req.temperature
+                top_ks[i] = req.top_k
+                top_ps[i] = req.top_p
+                seeds[i] = req.seed
+                slot_arr[i] = slot
+                want_lp = want_lp or req.logprobs > 0
+            # Roofline accounting: real-token FLOPs (2P matmul + causal
+            # attention's triangular sum) and the bucket's token
+            # padding — padded rows/positions burn device time without
+            # FLOPs that count, which is exactly what the padding-waste
+            # gauge shows.
+            for req in group:
+                n = int(req.prompt_ids.size)
+                self._prefill_flops += (
+                    self._flops_matmul_per_token * n
+                    + self._attn_flops_coeff * n * (n + 1) / 2.0)
+            rec = self._prefill_bucket_tokens.setdefault(bucket,
+                                                         [0.0, 0.0])
+            rec[0] += sum(int(r.prompt_ids.size) for r in group)
+            rec[1] += b_bucket * bucket
+            self._note_program("prefill", b_bucket, bucket)
+            ids_d, lengths_d = jnp.asarray(ids), jnp.asarray(lengths)
+            sampling = [jnp.asarray(a)
+                        for a in (temps, top_ks, top_ps, seeds)]
+        # The launch's ring event carries the trace ids of its rows,
+        # so a request's spans share its identifier (the profiler's
+        # annotation takes the scalars alone).
+        with mesh_scope(self.mesh), \
+                TIMELINE.span(LAUNCH, "engine.launch.prefill", rows=b,
+                              bucket=bucket,
+                              trace_ids=[r.trace_id for r in group]):
             firsts, new_caches, chosen_lp, top_ids, top_lps = \
-                self._prefill(
-                    self.variables, jnp.asarray(ids),
-                    jnp.asarray(lengths), jnp.asarray(temps),
-                    jnp.asarray(top_ks), jnp.asarray(top_ps),
-                    jnp.asarray(seeds))
-        if dest_rows is not None:
-            # Paged: per-chunk destination blocks (-1 = shared prefix
-            # hit or padding row — the scatter drops those chunks).
-            chunks = bucket // self.block_size
-            dest = np.full((b_bucket, chunks), -1, np.int32)
-            for i, row in enumerate(dest_rows):
-                dest[i, :len(row)] = row
-            insert_arg = jnp.asarray(dest)
-        else:
-            insert_arg = jnp.asarray(slot_arr)
-        self._caches = self._insert(self._caches, new_caches,
-                                    insert_arg)
+                self._prefill(self.variables, ids_d, lengths_d,
+                              *sampling)
+        with TIMELINE.span(LAUNCH, "engine.prep.insert"):
+            slot_d = jnp.asarray(slot_arr)
+            if dest_rows is not None:
+                # Paged: per-chunk destination blocks (-1 = shared
+                # prefix hit or padding row — the scatter drops those
+                # chunks).
+                chunks = bucket // self.block_size
+                dest = np.full((b_bucket, chunks), -1, np.int32)
+                for i, row in enumerate(dest_rows):
+                    dest[i, :len(row)] = row
+                insert_arg = jnp.asarray(dest)
+            else:
+                insert_arg = slot_d
+        with TIMELINE.span(LAUNCH, "engine.launch.insert", rows=b):
+            self._caches = self._insert(self._caches, new_caches,
+                                        insert_arg)
         # The admitted slots' first feed token/position land in the
         # device-resident feed arrays; rows of slots NOT in this group
         # keep their device values (the last enqueued wave's outputs,
         # which the host may not have seen yet).  The next decode wave
         # therefore includes these slots before the host ever sees
         # their first token.
-        self._feed_tokens, self._feed_positions = self._feed_update(
-            self._feed_tokens, self._feed_positions,
-            jnp.asarray(slot_arr), firsts,
-            jnp.asarray(lengths))
+        with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=b):
+            self._feed_tokens, self._feed_positions = \
+                self._feed_update(
+                    self._feed_tokens, self._feed_positions,
+                    slot_d, firsts, lengths_d)
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         return firsts, lp_h
 
@@ -3247,9 +3353,19 @@ class GenerationEngine:
         # an exemplar so a slow tail links straight to its trace.
         now = time.perf_counter()
         if s.req.last_emit_t is None:
+            req = s.req
             obs.llm_ttft_ms().observe(
-                (now - s.req.submit_t) * 1000.0,
-                trace_id=s.req.trace_id)
+                (now - req.submit_t) * 1000.0, trace_id=req.trace_id)
+            # The same interval split at its two hand-offs; once per
+            # request, as above (a preempted request that had emitted
+            # keeps its last_emit_t through the re-admission).
+            stages = obs.generator_ttft_stage_ms()
+            stages.labels(stage="queued").observe(
+                (req.taken_t - req.submit_t) * 1000.0)
+            stages.labels(stage="dispatch").observe(
+                (req.enqueued_t - req.taken_t) * 1000.0)
+            stages.labels(stage="delivery").observe(
+                (now - req.enqueued_t) * 1000.0)
         else:
             obs.llm_inter_token_ms().observe(
                 (now - s.req.last_emit_t) * 1000.0,
@@ -3448,6 +3564,7 @@ class GenerationEngine:
             self.max_slots, [i for i, _s in eligible],
             self._draft_window)
 
+    @_dispatch_timed("spec")
     def _enqueue_spec_wave(self, eligible, ngram, windows,
                            host_draft_ms):
         """Runs on the enqueue executor: dispatch the draft proposer
@@ -3462,27 +3579,37 @@ class GenerationEngine:
         self._drain_spills()
         S = self.max_slots
         K = self.spec_tokens
-        last = np.zeros(S, np.int32)
-        qpos = np.full((S, K + 1), self.max_seq, np.int32)
-        for i, s in eligible:
-            last[i] = s.last_token
-            qpos[i] = s.length + np.arange(K + 1, dtype=np.int32)
-        temps, top_ks, top_ps, seeds, want_lp = \
-            self._sampling_arrays()
+        with TIMELINE.span(LAUNCH, "engine.prep.spec"):
+            last = np.zeros(S, np.int32)
+            qpos = np.full((S, K + 1), self.max_seq, np.int32)
+            for i, s in eligible:
+                last[i] = s.last_token
+                qpos[i] = s.length + np.arange(K + 1, dtype=np.int32)
+            temps, top_ks, top_ps, seeds, want_lp = \
+                self._sampling_arrays()
+            if windows is not None:
+                self._note_program("spec_draft", S, self._draft_window)
+                windows_d = jnp.asarray(windows)
+            else:
+                draft_dev = jnp.asarray(ngram)
         if windows is not None:
-            self._note_program("spec_draft", S, self._draft_window)
-            draft_dev = self._spec_draft_fn(self._draft_variables,
-                                            jnp.asarray(windows))
-        else:
-            draft_dev = jnp.asarray(ngram)
-        self._note_program("spec_verify", S, K + 1)
+            with TIMELINE.span(LAUNCH, "engine.launch.spec_draft",
+                               rows=len(eligible)):
+                draft_dev = self._spec_draft_fn(self._draft_variables,
+                                                windows_d)
         with mesh_scope(self.mesh):
-            (samples, draft_echo, self._caches, chosen_lp, top_ids,
-             top_lps) = self._spec_verify(
-                self.variables, self._caches, self._table_device(),
-                jnp.asarray(last), draft_dev, jnp.asarray(qpos),
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), jnp.asarray(seeds))
+            with TIMELINE.span(LAUNCH, "engine.prep.spec"):
+                self._note_program("spec_verify", S, K + 1)
+                table = self._table_device()
+                last_d, qpos_d = jnp.asarray(last), jnp.asarray(qpos)
+                sampling = [jnp.asarray(a)
+                            for a in (temps, top_ks, top_ps, seeds)]
+            with TIMELINE.span(LAUNCH, "engine.launch.spec",
+                               rows=len(eligible), steps=K + 1):
+                (samples, draft_echo, self._caches, chosen_lp, top_ids,
+                 top_lps) = self._spec_verify(
+                    self.variables, self._caches, table, last_d,
+                    draft_dev, qpos_d, *sampling)
         self.decode_steps += 1
         self.spec_waves += 1
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
@@ -3498,7 +3625,8 @@ class GenerationEngine:
         extra transfers: block_until_ready moves no data)."""
         samples_h, draft_h, timed_draft = handles
         t0 = time.perf_counter()
-        with sanitizer.sanctioned_fetch():
+        with TIMELINE.span(FETCH, "engine.fetch"), \
+                sanitizer.sanctioned_fetch():
             draft_ready_s = 0.0
             if timed_draft:
                 # kfslint: disable=host-sync — sanctioned fetch site:
@@ -3540,10 +3668,12 @@ class GenerationEngine:
                 toks[i] = s.last_token
                 pos[i] = s.length
         self._note_program("feed_resync", S)
-        self._feed_tokens, self._feed_positions = self._feed_update(
-            self._feed_tokens, self._feed_positions,
-            jnp.asarray(slot_arr), jnp.asarray(toks),
-            jnp.asarray(pos))
+        with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=S):
+            self._feed_tokens, self._feed_positions = \
+                self._feed_update(
+                    self._feed_tokens, self._feed_positions,
+                    jnp.asarray(slot_arr), jnp.asarray(toks),
+                    jnp.asarray(pos))
         return self._enqueue_wave()
 
     def _distribute_spec(self, samples: np.ndarray,

@@ -26,25 +26,22 @@ from kfserving_tpu.observability import metrics as obs
 logger = logging.getLogger("kfserving_tpu.hbm")
 
 
+def device_hbm_stat(key: str, device=None) -> Optional[int]:
+    """One entry of the device's `memory_stats()` (`bytes_limit`,
+    `bytes_in_use`, `peak_bytes_in_use`); None where the backend
+    reports no stats or not this one."""
+    import jax
+
+    device = device or jax.devices()[0]
+    stats = getattr(device, "memory_stats", lambda: None)()
+    if stats:
+        return stats.get(key)
+    return None
+
+
 def device_hbm_bytes(device=None) -> Optional[int]:
     """Total HBM of the serving device, when the backend reports it."""
-    import jax
-
-    device = device or jax.devices()[0]
-    stats = getattr(device, "memory_stats", lambda: None)()
-    if stats:
-        return stats.get("bytes_limit")
-    return None
-
-
-def device_hbm_in_use(device=None) -> Optional[int]:
-    import jax
-
-    device = device or jax.devices()[0]
-    stats = getattr(device, "memory_stats", lambda: None)()
-    if stats:
-        return stats.get("bytes_in_use")
-    return None
+    return device_hbm_stat("bytes_limit", device)
 
 
 def host_memory_bytes() -> int:

@@ -73,11 +73,40 @@ def compile_cache_events():
         "means the shape was already compiled; miss paid a compile)")
 
 
+def jax_compile_events():
+    return REGISTRY.counter(
+        "kfserving_tpu_jax_compile_events_total",
+        "Programs traced, lowered and compiled, and persistent-cache "
+        "lookups, as JAX's own monitoring events count them "
+        "(event=trace|lower|backend_compile|cache_hit|cache_miss); "
+        "trace moving after warm-up is a retrace")
+
+
 # -- LLM generation -----------------------------------------------------
 def llm_ttft_ms():
     return REGISTRY.histogram(
         "kfserving_tpu_llm_ttft_ms",
         "Time from generation submit to the first emitted token")
+
+
+def generator_ttft_stage_ms():
+    return REGISTRY.histogram(
+        "kfserving_tpu_generator_ttft_stage_ms",
+        "Time to first token split at the engine's hand-offs, "
+        "observed once per request beside llm_ttft_ms and summing to "
+        "it (stage=queued: submit until taken out of the pending "
+        "queue; dispatch: taken until its prefill, or first chunk, "
+        "has been enqueued, the wait for the one launching thread "
+        "included; delivery: enqueued until the first token is "
+        "emitted)")
+
+
+def generator_dispatch_host_ms():
+    return REGISTRY.histogram(
+        "kfserving_tpu_generator_dispatch_host_ms",
+        "Wall time of one enqueue call on the generator's launching "
+        "thread, host arrays to the last chained launch "
+        "(program=decode|prefill|chunk|spec)")
 
 
 def llm_inter_token_ms():

@@ -12,7 +12,7 @@ running server exposes continuously:
 - `trace_export` — Chrome-trace/Perfetto rendering of the ring
                    (`GET /debug/profile?window_s=&format=trace_json`),
                    fleet merge for the router's federated view, and
-                   the bench-side dispatch-gap/HOLD summary;
+                   the bench-side HOLD / suppressed-wave summary;
 - `roofline`     — promotion of the engines' FLOP / bucket-waste /
                    bandwidth accounting into registry gauges
                    (`kfserving_tpu_engine_mfu`, padding-waste and

@@ -13,8 +13,15 @@ per-slot), and the owning request's trace id, so:
   (trace_export.py);
 - pinned flight-recorder entries embed the engine events overlapping
   the request's span (monitoring/__init__.py);
-- bench runs derive dispatch-gap / HOLD / suppressed-wave summaries
-  from it (trace_export.summarize).
+- bench runs derive HOLD / suppressed-wave summaries from it
+  (trace_export.summarize).
+
+`span()` is the one way to record a block the engine is in: on exit
+it records the ring event above, and while a profiler capture is
+active (`tracing.ProfilerControl` installs `annotate`) the same block
+is also held in the profiler's own trace, on the profiler's clock and
+on the thread that did the work, so device idle gaps can be put to an
+engine phase.  This module imports no JAX: the factory is handed in.
 
 Hot-path contract (the generator records from its scheduler loop and
 its enqueue/fetch executor threads):
@@ -37,15 +44,18 @@ minutes of steady decode).
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 DEFAULT_CAPACITY = 8192
 
-# Track names.  "host" and "device" are the two shared tracks; slot
-# events carry track="slot" plus the slot index; "counter" events are
-# point-in-time occupancy samples the exporter renders as Chrome
-# counter series.
+# Track names.  "host" (the scheduler loop and everything recorded
+# after the fact), "launch" (the generator's one launching thread)
+# and "fetch" (its fetch workers) are the host-side tracks, "device"
+# is shared; slot events carry track="slot" plus the slot index;
+# "counter" events are point-in-time occupancy samples the exporter
+# renders as Chrome counter series.
 HOST, DEVICE, SLOT, COUNTER = "host", "device", "slot", "counter"
+LAUNCH, FETCH = "launch", "fetch"
 
 # Event tuple layout (immutable — readers copy references, writers
 # never mutate a published event):
@@ -60,6 +70,10 @@ class EngineTimeline:
         self._ring: List[Optional[Event]] = [None] * self.capacity
         self._next = 0          # total events ever recorded
         self._lock = threading.Lock()
+        # `annotate(name, **attrs)` -> context manager that writes the
+        # block into the profiler's trace; None outside a capture
+        # (ProfilerControl.start/stop set and clear it).
+        self.annotate: Optional[Callable[..., Any]] = None
 
     @classmethod
     def from_env(cls) -> "EngineTimeline":
@@ -84,6 +98,17 @@ class EngineTimeline:
         with self._lock:
             self._ring[self._next % self.capacity] = event
             self._next += 1
+
+    def span(self, track: str, name: str,
+             trace_id: Optional[str] = None, slot: int = -1,
+             **attrs: Any) -> "_Span":
+        """Context manager around a block of engine work: records the
+        ring event (start, duration) on exit and, during a profiler
+        capture, holds the profiler's annotation for the block.  With
+        no capture active it costs one small object and two clock
+        reads over `record()`.  A failing annotation never reaches the
+        engine: the ring event is recorded all the same."""
+        return _Span(self, track, name, trace_id, slot, attrs)
 
     def counter(self, name: str, values: Dict[str, Any]) -> None:
         """Point-in-time occupancy sample (free blocks, active slots,
@@ -148,6 +173,51 @@ class EngineTimeline:
         with self._lock:
             self._ring = [None] * self.capacity
             self._next = 0
+
+
+class _Span:
+    """One `EngineTimeline.span()` block (a class, not a generator:
+    it sits on the engine's hot path)."""
+
+    __slots__ = ("_timeline", "_track", "_name", "_trace_id", "_slot",
+                 "_attrs", "_annotation", "_t0")
+
+    def __init__(self, timeline, track, name, trace_id, slot, attrs):
+        self._timeline = timeline
+        self._track = track
+        self._name = name
+        self._trace_id = trace_id
+        self._slot = slot
+        self._attrs = attrs
+        self._annotation = None
+
+    def __enter__(self) -> "_Span":
+        factory = self._timeline.annotate
+        if factory is not None:
+            try:
+                # The profiler's annotation takes scalars; a list (a
+                # launch's trace ids) stays in the ring event alone.
+                annotation = factory(self._name, **{
+                    k: v for k, v in self._attrs.items()
+                    if isinstance(v, (int, float, str))})
+                annotation.__enter__()
+                self._annotation = annotation
+            except Exception:  # tracing must never fail the engine
+                self._annotation = None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dur_s = time.perf_counter() - self._t0
+        self._timeline.record(self._track, self._name, dur_s=dur_s,
+                              trace_id=self._trace_id, slot=self._slot,
+                              attrs=self._attrs or None)
+        if self._annotation is not None:
+            try:
+                self._annotation.__exit__(exc_type, exc, tb)
+            except Exception:  # as above
+                pass
+        return False
 
 
 # The process timeline: one serving process = one device path = one

@@ -7,6 +7,7 @@ Perfetto (ui.perfetto.dev) or chrome://tracing:
 - one *process* per replica (pid; the router's federated view re-pids
   each replica's trace and names the process after the replica host);
 - *threads* are the timeline tracks: host (tid 1), device (tid 2),
+  the generator's launching thread (tid 3) and fetch workers (tid 4),
   and one per engine slot (tid 10+slot) so concurrent streams render
   as parallel lanes;
 - complete events (`ph: "X"`, microsecond ts/dur) for spans, instant
@@ -18,34 +19,38 @@ Perfetto (ui.perfetto.dev) or chrome://tracing:
   or a flight-recorder pin lands on the exact wave/chunk slices that
   served it.
 
-`summarize()` is the bench-side consumer: dispatch-gap percentiles
-(device idle between consecutive device slices), total growth-HOLD
-time, and the suppressed-wave ratio, derived from the same events the
-trace renders — the committed BENCH record and the Perfetto view can
-never disagree.
+`summarize()` is the bench-side consumer: total growth-HOLD time, the
+suppressed-wave ratio and slice counts, derived from the same events
+the trace renders — the committed BENCH record and the Perfetto view
+can never disagree.  It says nothing about device idle time: the
+"device" track's slices are host clocks around dispatch→fetch, and
+the gaps between them are not the device's (that is read from the
+profiler's trace, where the engine's spans share the device's
+clock).
 """
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 from kfserving_tpu.observability.profiling.timeline import (
     COUNTER,
     DEVICE,
+    FETCH,
     HOST,
+    LAUNCH,
     SLOT,
     Event,
 )
 
 _TID_HOST = 1
-_TID_DEVICE = 2
 _TID_SLOT_BASE = 10
+_TRACK_TIDS = {HOST: _TID_HOST, DEVICE: 2, LAUNCH: 3, FETCH: 4}
+_TID_NAMES = {tid: track for track, tid in _TRACK_TIDS.items()}
 
 
 def _tid(track: str, slot: int) -> int:
-    if track == DEVICE:
-        return _TID_DEVICE
     if track == SLOT and slot >= 0:
         return _TID_SLOT_BASE + slot
-    return _TID_HOST
+    return _TRACK_TIDS.get(track, _TID_HOST)
 
 
 def to_chrome_trace(events: List[Event], pid: int = 1,
@@ -70,10 +75,8 @@ def to_chrome_trace(events: List[Event], pid: int = 1,
             continue
         tid = _tid(track, slot)
         if tid not in tids_seen:
-            tids_seen[tid] = (
-                "host" if tid == _TID_HOST else
-                "device" if tid == _TID_DEVICE else
-                f"slot {tid - _TID_SLOT_BASE}")
+            tids_seen[tid] = _TID_NAMES.get(
+                tid, f"slot {tid - _TID_SLOT_BASE}")
         args: Dict[str, Any] = dict(attrs) if attrs else {}
         if trace_id is not None:
             args["trace_id"] = trace_id
@@ -115,32 +118,14 @@ def merge_traces(traces: List[Tuple[str, Dict[str, Any]]]
     return {"traceEvents": merged, "displayTimeUnit": "ms"}
 
 
-def _percentile(values: List[float], q: float) -> float:
-    ordered = sorted(values)
-    idx = min(len(ordered) - 1, int(len(ordered) * q))
-    return ordered[idx]
-
-
 def summarize(events: List[Event]) -> Dict[str, Any]:
     """Timeline-derived device-path summary for bench records:
 
-    - dispatch_gap p50/p99: idle ms between consecutive device-track
-      slices — the stat ROADMAP item 1's arithmetic needs (how much of
-      wall clock the device actually sat waiting on the host);
     - hold_ms: total growth-starvation HOLD window time;
     - suppressed_wave_ratio: waves the adaptive governor refused vs
       dispatched decode waves;
     - slice counts per kind (waves, chunks, prefills, preemptions).
     """
-    device = sorted(
-        ((start, dur) for start, dur, track, *_ in events
-         if track == DEVICE and dur > 0))
-    gaps_ms: List[float] = []
-    prev_end: Optional[float] = None
-    for start, dur in device:
-        if prev_end is not None:
-            gaps_ms.append(max(0.0, (start - prev_end) * 1000.0))
-        prev_end = max(prev_end or 0.0, start + dur)
     waves = sum(1 for _, _, t, n, *_ in events
                 if t == DEVICE and n == "decode.wave")
     chunks = sum(1 for _, _, t, n, *_ in events
@@ -153,7 +138,7 @@ def summarize(events: List[Event]) -> Dict[str, Any]:
                      if t == HOST and n == "wave.suppressed")
     hold_ms = sum(dur for _, dur, t, n, *_ in events
                   if t == HOST and n == "hold") * 1000.0
-    out: Dict[str, Any] = {
+    return {
         "decode_waves": waves,
         "prefill_chunks": chunks,
         "prefill_dispatches": prefills,
@@ -164,9 +149,3 @@ def summarize(events: List[Event]) -> Dict[str, Any]:
         if suppressed + waves else 0.0,
         "hold_ms": round(hold_ms, 3),
     }
-    if gaps_ms:
-        out["dispatch_gap_p50_ms"] = round(_percentile(gaps_ms, 0.50),
-                                           3)
-        out["dispatch_gap_p99_ms"] = round(_percentile(gaps_ms, 0.99),
-                                           3)
-    return out

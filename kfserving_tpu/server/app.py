@@ -77,6 +77,12 @@ def _json(data: Any, status: int = 200) -> Response:
                     status=status)
 
 
+def _python_tracer(body: Dict[str, Any]) -> bool:
+    """The capture option both profiler endpoints take: only a JSON
+    `"python_tracer": false` switches the Python tracer off."""
+    return body.get("python_tracer") is not False
+
+
 def _np_default(obj):
     tolist = getattr(obj, "tolist", None)
     if tolist is not None:
@@ -1216,7 +1222,8 @@ class ModelServer:
         duration_s = max(0.1, min(duration_s, 60.0))
         log_dir = body.get("log_dir", "/tmp/kfs-profile")
         try:
-            started = profiler.start(log_dir)
+            started = profiler.start(
+                log_dir, python_tracer=_python_tracer(body))
         except Exception as e:
             return _json({"error": f"profiler start failed: {e}"},
                          status=500)
@@ -1430,7 +1437,8 @@ class ModelServer:
         except ValueError:
             body = {}
         log_dir = body.get("log_dir", "/tmp/kfs-profile")
-        if not profiler.start(log_dir):
+        if not profiler.start(log_dir,
+                              python_tracer=_python_tracer(body)):
             return _json({"error": "profiler already active",
                           "log_dir": profiler.active_dir}, status=409)
         return _json({"profiling": True, "log_dir": log_dir})

@@ -77,7 +77,10 @@ def report_device() -> Dict[str, Any]:
         device_peak_hbm_bw,
     )
 
+    from kfserving_tpu.engine import compile_cache
+
     global _device
+    compile_cache.count_jax_compile_events()
     devices = jax.devices()
     _device = {
         "platform": devices[0].platform,
@@ -97,13 +100,19 @@ def report_device() -> Dict[str, Any]:
 
 
 def device() -> Optional[Dict[str, Any]]:
-    """The start-up device record plus each device's HBM in use now;
-    None in a process that never reported one (CPU frameworks)."""
+    """The start-up device record plus each device's HBM in use now
+    and the most it has held since start (None where the backend
+    reports none); None in a process that never reported one (CPU
+    frameworks)."""
     if _device is None:
         return None
     import jax
 
-    from kfserving_tpu.engine.hbm import device_hbm_in_use
+    from kfserving_tpu.engine.hbm import device_hbm_stat
 
+    devices = jax.devices()
     return {**_device,
-            "hbm_in_use": [device_hbm_in_use(d) for d in jax.devices()]}
+            "hbm_in_use": [device_hbm_stat("bytes_in_use", d)
+                           for d in devices],
+            "hbm_peak": [device_hbm_stat("peak_bytes_in_use", d)
+                         for d in devices]}

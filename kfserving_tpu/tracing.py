@@ -188,7 +188,16 @@ def ensure_request_id(headers: Dict[str, str]) -> str:
 
 
 class ProfilerControl:
-    """On-demand jax.profiler trace capture (SURVEY §5.1)."""
+    """On-demand jax.profiler trace capture (SURVEY §5.1).  Both
+    debug endpoints (`/debug/profiler/start|stop`, the bounded
+    `/debug/profile/capture`) go through this one object and share
+    its options.
+
+    While a capture is active the engine timeline's spans are also
+    written into the profiler's trace: `start` hands
+    `jax.profiler.TraceAnnotation` to `TIMELINE.annotate` and `stop`
+    takes it away, so outside a capture the engines call nothing of
+    `jax.profiler`."""
 
     def __init__(self):
         self._active_dir: Optional[str] = None
@@ -198,13 +207,26 @@ class ProfilerControl:
     def active_dir(self) -> Optional[str]:
         return self._active_dir
 
-    def start(self, log_dir: str) -> bool:
+    def start(self, log_dir: str, python_tracer: bool = True) -> bool:
+        """`python_tracer=False` switches the profiler's Python
+        tracer off (one event per Python call: most of a trace's
+        events and of its cost to the host); engine spans, XLA's own
+        host events and the device planes stay."""
         import jax
+
+        from kfserving_tpu.observability.profiling import TIMELINE
 
         with self._lock:
             if self._active_dir is not None:
                 return False
-            jax.profiler.start_trace(log_dir)
+            if python_tracer:
+                jax.profiler.start_trace(log_dir)
+            else:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(log_dir,
+                                         profiler_options=options)
+            TIMELINE.annotate = jax.profiler.TraceAnnotation
             self._active_dir = log_dir
             logger.info("jax.profiler trace -> %s", log_dir)
             return True
@@ -212,9 +234,12 @@ class ProfilerControl:
     def stop(self) -> Optional[str]:
         import jax
 
+        from kfserving_tpu.observability.profiling import TIMELINE
+
         with self._lock:
             if self._active_dir is None:
                 return None
+            TIMELINE.annotate = None
             jax.profiler.stop_trace()
             out, self._active_dir = self._active_dir, None
             logger.info("jax.profiler trace stopped (%s)", out)

@@ -55,3 +55,24 @@ async def http_json(port: int, method: str, path: str,
         return status, json.loads(raw)
     except ValueError:
         return status, raw
+
+
+def engine_span_lines(trace_dir: str) -> Dict[str, set]:
+    """{event name: set of (plane, line) indices that hold it} over the
+    `engine.*` events of the one profiler trace under `trace_dir`: which
+    thread's line of the trace each engine span landed on."""
+    import glob
+    import os
+
+    import jax
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    found: Dict[str, set] = {}
+    data = jax.profiler.ProfileData.from_file(path)
+    for p, plane in enumerate(data.planes):
+        for i, line in enumerate(plane.lines):
+            for event in line.events:
+                if event.name.startswith("engine."):
+                    found.setdefault(event.name, set()).add((p, i))
+    return found
