@@ -211,7 +211,9 @@ class GenerationEngine:
     module: a DecoderLM-contract Flax module (models/decoder.py): full
         forward with `return_cache=True` and decode with `kv_cache` +
         `positions`.
-    variables: initialized/restored model variables.
+    variables: initialized/restored model variables.  Host leaves
+        (np arrays, param_cache's memmap views) are placed on the
+        engine's device once, here; device arrays are kept as given.
     """
 
     def __init__(self, module, variables, *,
@@ -484,7 +486,7 @@ class GenerationEngine:
                 speculative = {"tokens": env_spec}
         self.spec_tokens = 0
         self._draft_module = None
-        self._draft_variables = None
+        self.draft_variables = None
         self._draft_window = 0
         if speculative:
             self.spec_tokens = int(speculative.get("tokens", 0))
@@ -493,7 +495,7 @@ class GenerationEngine:
                     "speculative tokens must be >= 0")
             if self.spec_tokens > 0:
                 self._draft_module = speculative.get("draft_module")
-                self._draft_variables = speculative.get(
+                self.draft_variables = speculative.get(
                     "draft_variables")
                 if self._draft_module is not None:
                     from kfserving_tpu.engine.speculative import (
@@ -503,15 +505,33 @@ class GenerationEngine:
                     self._draft_window = int(speculative.get(
                         "draft_window", DEFAULT_DRAFT_WINDOW))
 
+        # Parameters are resident from here on, like the pool: a host
+        # leaf handed to a jitted call is transferred again on every
+        # launch (3.1 GB a launch for gpt2-large, ROADMAP A9).  Under
+        # a mesh, leaves that arrive sharded (shard_params) keep their
+        # shardings and whatever is still on the host is replicated.
+        from kfserving_tpu import startup
+        from kfserving_tpu.engine import param_cache
+
+        replicated = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            replicated = NamedSharding(mesh, PartitionSpec())
+        self.variables, self.draft_variables = \
+            param_cache.place_on_device(
+                (self.variables, self.draft_variables), replicated)
+        self._params_resident_bytes = param_cache.device_resident_bytes(
+            (self.variables, self.draft_variables))
+        startup.mark("params_device")
+
         if mesh is not None:
             # Tensor parallelism: the cache shards on the heads axis,
             # exactly like the q/k/v projections that fill it
             # (parallel/sharding.py transformer_rules) — cache writes
             # and decode attention stay device-local per head group;
             # the per-layer psum after the out-projection is the only
-            # collective.  Callers pass variables already sharded.
-            from jax.sharding import NamedSharding, PartitionSpec
-
+            # collective.
             tp = mesh.shape.get("tp", 1)
             heads_axis = "tp" if cfg.num_heads % max(tp, 1) == 0 else None
             sharding = NamedSharding(
@@ -884,6 +904,9 @@ class GenerationEngine:
         self._spec_verify_s = 0.0
         self._occupied_slot_steps = 0
         self._wasted_token_steps = 0  # garbage steps past a finish
+        # The row count prefill dispatches are held to once the runtime
+        # has refused one for memory (see _prefill_refused).
+        self._prefill_rows_cap: Optional[int] = None
         # Union of enqueue->fetch intervals (overlap-corrected at
         # depth >= 2, so the stat stays <= wall clock).
         self._decode_device_s = 0.0
@@ -901,7 +924,7 @@ class GenerationEngine:
         # floor on chip utilization.
         self._n_params = int(sum(
             int(np.prod(x.shape))
-            for x in self._jax.tree.leaves(variables)))
+            for x in self._jax.tree.leaves(self.variables)))
         self._param_read_bytes = self.param_bytes()
         self._flops_matmul_per_token = 2.0 * self._n_params
         self._attn_flops_coeff = (4.0 * n_layers * cfg.num_heads
@@ -1136,7 +1159,9 @@ class GenerationEngine:
             "depth_effective": self._depth_effective,
             "suppressed_waves": self.suppressed_waves,
             "wasted_token_steps": self._wasted_token_steps,
+            "prefill_rows_cap": self._prefill_rows_cap or 0,
             "cache_bytes": self.cache_bytes(),
+            "params_resident_bytes": self._params_resident_bytes,
             "decode_device_s": round(self._decode_device_s, 4),
             "decode_wait_s": round(self._decode_wait_s, 4),
             "prefill_wait_s": round(self._prefill_wait_s, 4),
@@ -2278,14 +2303,18 @@ class GenerationEngine:
     def _take_prefill_group(self, force_miss: bool = False):
         """Pop the front run of pending requests that share a prefill
         bucket, up to the free slot count — they ride ONE prefill
-        dispatch.  Strict FIFO: a different-bucket request at the front
-        is never jumped.  In paged mode each taken request's prompt
+        dispatch (or up to the row count the runtime has shown it can
+        hold, see _prefill_refused).  Strict FIFO: a different-bucket
+        request at the front is never jumped.  In paged mode each
+        taken request's prompt
         blocks are planned (allocated/prefix-shared) HERE on the loop
         thread; a request the pool cannot hold yet stays pending (it
         admits when slots release blocks).  Returns
         (group, slots, bucket, dest_rows) — dest_rows is None for
         dense mode."""
         free = [i for i, s in enumerate(self._slots) if s is None]
+        if self._prefill_rows_cap is not None:
+            free = free[:self._prefill_rows_cap]
         group: List[_Request] = []
         bucket = 0
         dest_rows: Optional[List[List[int]]] = (
@@ -2310,6 +2339,43 @@ class GenerationEngine:
         for req in group:
             req.taken_t = now
         return group, free[:len(group)], bucket, dest_rows
+
+    def _requeue_group(self, group: List[_Request],
+                       slots: List[int]) -> None:
+        """Nothing of this taken group was dispatched: roll its plans
+        back and put its requests back at the front of the queue."""
+        for slot in slots:
+            self._deregister_plan(slot)
+            self._schedule_block_release(slot)
+        for req in reversed(group):
+            self._pending.appendleft(req)
+
+    def _prefill_refused(self, exc: Exception, rows: int) -> bool:
+        """True when the runtime refused a prefill launch of more than
+        one row for memory; later dispatches are then held to half the
+        refused row count.
+
+        The prefill program is the one launch that needs fresh HBM for
+        its outputs (every layer's k/v for rows x bucket; the others
+        write the donated pool), and nothing was donated or ran, so
+        the group can simply be taken again in smaller dispatches.
+        Seen on the v5e once launches stopped waiting on a parameter
+        transfer: with gpt2-large's parameters and pool resident (6.0
+        GiB) and the 16-step decode program's 9.2 GiB of temporaries
+        reserved, 0.5 of the chip's 15.75 GiB are left, which hold a
+        (4, 512) dispatch's outputs and not an (8, 512) one's."""
+        if "RESOURCE_EXHAUSTED" not in str(exc) or rows < 2:
+            return False
+        padded = 1 << (rows - 1).bit_length()  # the launch's row bucket
+        self._prefill_rows_cap = padded // 2
+        from kfserving_tpu.engine.hbm import device_hbm_stat
+
+        logger.warning(
+            "prefill launch of %d rows refused for memory (%s bytes in "
+            "use of %s): dispatches held to %d rows from here on: %s",
+            padded, device_hbm_stat("bytes_in_use"),
+            device_hbm_stat("bytes_limit"), self._prefill_rows_cap, exc)
+        return True
 
     # -- chunked prefill ---------------------------------------------------
     # A COLD prompt (longer than prefill_chunk_tokens, paged mode)
@@ -2690,11 +2756,7 @@ class GenerationEngine:
                     # and re-queue the requests at the front.  Their
                     # replans MISS the tier (the failed chains were
                     # dropped) and fall through to plain re-prefill.
-                    for req, slot in zip(group, slots):
-                        self._deregister_plan(slot)
-                        self._schedule_block_release(slot)
-                    for req in reversed(group):
-                        self._pending.appendleft(req)
+                    self._requeue_group(group, slots)
                     continue
                 try:
                     firsts_h, lp_h = await loop.run_in_executor(
@@ -2702,6 +2764,9 @@ class GenerationEngine:
                         self._enqueue_prefill_group,
                         group, slots, bucket, dest_rows)
                 except Exception as e:
+                    if self._prefill_refused(e, len(group)):
+                        self._requeue_group(group, slots)
+                        continue
                     # An enqueue-time failure (e.g. OOM compiling a
                     # new bucket) fails THAT group; in-flight slots
                     # keep decoding.  Planned blocks release AND their
@@ -3311,6 +3376,13 @@ class GenerationEngine:
                 self._feed_update(
                     self._feed_tokens, self._feed_positions,
                     slot_d, firsts, lengths_d)
+        if self._prefill_rows_cap is not None:
+            # Memory is that tight (see _prefill_refused): nothing else
+            # is launched until the insert has consumed this dispatch's
+            # k/v, so that two dispatches' outputs are never held at
+            # once.
+            with TIMELINE.span(LAUNCH, "engine.wait.insert"):
+                self._jax.block_until_ready(self._caches[0])
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         return firsts, lp_h
 
@@ -3595,7 +3667,7 @@ class GenerationEngine:
         if windows is not None:
             with TIMELINE.span(LAUNCH, "engine.launch.spec_draft",
                                rows=len(eligible)):
-                draft_dev = self._spec_draft_fn(self._draft_variables,
+                draft_dev = self._spec_draft_fn(self.draft_variables,
                                                 windows_d)
         with mesh_scope(self.mesh):
             with TIMELINE.span(LAUNCH, "engine.prep.spec"):
@@ -3777,11 +3849,11 @@ class GenerationEngine:
     def draft_param_bytes(self) -> int:
         """HBM ledger contribution of the configured draft model (0
         when speculation runs the n-gram head or is off)."""
-        if self._draft_variables is None:
+        if self.draft_variables is None:
             return 0
         jax = self._jax
         return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
-                   for x in jax.tree.leaves(self._draft_variables))
+                   for x in jax.tree.leaves(self.draft_variables))
 
 
 def _pow2_buckets(max_seq: int) -> List[int]:

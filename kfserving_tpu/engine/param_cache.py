@@ -253,9 +253,11 @@ def load_or_materialize(architecture: str, arch_kwargs: Optional[Dict],
     entirely), "checkpoint" (init + restore, then stored), or "init"
     (random weights, then stored).
 
-    On a hit the arrays are read-only memmap views; jit/device_put
-    consume them directly, so the host cost of a successor's param
-    phase collapses to page-cache reads feeding the device transfer.
+    On a hit the arrays are read-only memmap views: HOST arrays.  A
+    jitted call transfers a host argument on every launch, so the
+    consumer places them once (`place_on_device`, below) and launches
+    with the device tree; the host cost of a successor's param phase
+    collapses to page-cache reads feeding that one transfer.
     """
     from kfserving_tpu import startup
     from kfserving_tpu.models import init_params
@@ -297,3 +299,35 @@ def load_or_materialize(architecture: str, arch_kwargs: Optional[Dict],
         if mapped is not None:
             return mapped, source
     return variables, source
+
+
+def place_on_device(tree: Any, sharding=None) -> Any:
+    """`tree` with every host leaf (`np.ndarray`, so the memmap views
+    above too) put on the device in ONE `jax.device_put`, waited for
+    so the caller's time-to-ready includes the transfer.  Leaves that
+    are already `jax.Array`s are returned as they are, shardings
+    included (a `shard_params` tree passes through untouched).
+
+    sharding: where host leaves go; None is the default device,
+        uncommitted, like a `jnp.zeros` allocation.
+    """
+    import jax
+
+    leaves, treedef = jax.tree.flatten(tree)
+    host = [i for i, leaf in enumerate(leaves)
+            if isinstance(leaf, np.ndarray)]
+    if host:
+        placed = jax.block_until_ready(
+            jax.device_put([leaves[i] for i in host], sharding))
+        for i, leaf in zip(host, placed):
+            leaves[i] = leaf
+    return jax.tree.unflatten(treedef, leaves)
+
+
+def device_resident_bytes(tree: Any) -> int:
+    """Bytes of `tree`'s leaves that are device arrays (global shapes:
+    a sharded leaf counts once)."""
+    import jax
+
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(tree)
+               if isinstance(leaf, jax.Array))

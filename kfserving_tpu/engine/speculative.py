@@ -171,9 +171,9 @@ class DraftModel:
             "engine serves")
 
     def fault_in(self) -> None:
-        """Nothing to restore: draft params live wherever the target
-        engine placed them (they were admitted with the target's
-        load)."""
+        """Nothing to restore: `variables` is the tree the target
+        engine placed on its device when it was built (admitted with
+        the target's load)."""
 
     def host_bytes(self) -> int:
         return self.param_bytes()
@@ -185,5 +185,8 @@ class DraftModel:
     def release(self) -> None:
         """Unpin on target unload: the handle stops claiming an
         engine, so a lingering registration becomes evictable and
-        `deregister` leaves no dangling veto."""
+        `deregister` leaves no dangling veto.  It also lets go of the
+        engine's device tree, which a lingering handle would otherwise
+        keep in HBM."""
         self.engine = None
+        self.variables = None

@@ -182,14 +182,20 @@ def _clamp01(value: float) -> float:
 
 
 def publish_cache_gauges(model: str, stats: Dict[str, Any]) -> Set[str]:
-    """Promote an engine stats dict's paged-pool ratios into registry
-    gauges at /metrics scrape time (the roofline.publish_gauges shape).
-    Returns the consumed TOP-LEVEL stat keys — none today: the `paged`
-    dict keeps its legacy per-key export (tests and dashboards read
+    """Promote an engine stats dict's paged-pool ratios and resident
+    parameter bytes into registry gauges at /metrics scrape time (the
+    roofline.publish_gauges shape).  Returns the consumed TOP-LEVEL
+    stat keys — `params_resident_bytes` alone: the `paged` dict keeps
+    its legacy per-key export (tests and dashboards read
     `kfserving_tpu_engine_paged{bucket=...}`), the ratio gauges are
     published IN ADDITION so the `_ratio` unit contract holds."""
     consumed: Set[str] = set()
     try:
+        resident = stats.get("params_resident_bytes")
+        if isinstance(resident, (int, float)):
+            obs.generator_params_resident_bytes().labels(
+                model=model).set(float(resident))
+            consumed.add("params_resident_bytes")
         paged = stats.get("paged")
         if isinstance(paged, dict):
             occ = paged.get("pool_occupancy_ratio")

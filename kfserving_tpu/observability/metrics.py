@@ -355,6 +355,14 @@ def generator_pool_fragmentation_ratio():
         "allocated for growth but not yet holding k/v")
 
 
+def generator_params_resident_bytes():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_params_resident_bytes",
+        "Bytes of the generator's parameter leaves (target and draft "
+        "model) that are device arrays: placed once when the engine "
+        "was built, not handed over from the host with every launch")
+
+
 # -- HBM residency (engine/hbm.py accountant) ---------------------------
 def hbm_resident_bytes():
     return REGISTRY.gauge(

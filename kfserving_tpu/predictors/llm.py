@@ -489,7 +489,9 @@ class GenerativeModel(Model):
         spec = create_model(cfg.architecture, **cfg.arch_kwargs)
         # mmap-first materialization (shared with JaxModel): a standby
         # successor maps the predecessor's persisted host params and
-        # its activation cost collapses to the device transfer.
+        # its activation cost collapses to the device transfer, which
+        # the engine makes once when it is built (or shard_params
+        # below, under a mesh).
         variables, self.param_source = param_cache.load_or_materialize(
             cfg.architecture, cfg.arch_kwargs, spec, local)
 
@@ -537,7 +539,7 @@ class GenerativeModel(Model):
                 })
                 if window:
                     speculative["draft_window"] = window
-                draft_meta = (draft_spec.module, draft_vars, window)
+                draft_meta = (draft_spec.module, window)
 
         engine = GenerationEngine(
             spec.module, variables,
@@ -569,10 +571,13 @@ class GenerativeModel(Model):
                 DraftModel,
             )
 
-            module_d, vars_d, window = draft_meta
+            module_d, window = draft_meta
+            # The engine's placed tree, not the host views it was
+            # built from: nothing reads those once they are on the
+            # device.
             self._draft_handle = DraftModel(
-                f"{self.name}:draft", module_d, vars_d, engine,
-                window=window or DEFAULT_DRAFT_WINDOW)
+                f"{self.name}:draft", module_d, engine.draft_variables,
+                engine, window=window or DEFAULT_DRAFT_WINDOW)
             if self.residency is not None:
                 # Registers directly as resident (ready + engine set)
                 # and PINNED: the manager must never evict the draft

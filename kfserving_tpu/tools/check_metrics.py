@@ -188,6 +188,8 @@ async def smoke() -> List[str]:
         model="metrics-probe").set(0.62)
     obs.generator_pool_fragmentation_ratio().labels(
         model="metrics-probe").set(0.18)
+    obs.generator_params_resident_bytes().labels(
+        model="metrics-probe").set(3.1e9)
     obs.hbm_resident_bytes().labels(model="metrics-probe").set(2.1e9)
     obs.hbm_budget_bytes().set(12.0 * 1024**3)
     obs.hbm_evictions_total().labels(model="metrics-probe").inc()

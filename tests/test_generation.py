@@ -314,6 +314,105 @@ def test_cache_bytes_accounting(tiny):
     assert eng.param_bytes() > 0
 
 
+# ------------------------------------------------ parameter residency
+
+
+def _host_tree(variables, kind, tmp_path):
+    """`variables` as host arrays: plain np arrays, or read-only
+    memmap views like a param_cache hit serves."""
+    if kind == "ndarray":
+        return jax.tree.map(np.asarray, variables)
+    leaves, treedef = jax.tree.flatten(variables)
+    views = []
+    for i, leaf in enumerate(leaves):
+        path = tmp_path / f"leaf{i}.bin"
+        np.asarray(leaf).tofile(path)
+        views.append(np.memmap(path, dtype=leaf.dtype, mode="r",
+                               shape=leaf.shape))
+    return jax.tree.unflatten(treedef, views)
+
+
+@pytest.mark.parametrize("kind", ["ndarray", "memmap"])
+def test_host_parameters_are_placed_at_construction(tiny, kind,
+                                                    tmp_path):
+    """A host tree handed to the engine is on the engine's device
+    when the constructor returns: a jitted call would otherwise
+    transfer every host leaf again with every launch."""
+    module, variables, _ = tiny
+    host = _host_tree(variables, kind, tmp_path)
+    eng = GenerationEngine(module, host, max_slots=4, max_seq=MAX_SEQ)
+    pool_devices = eng._caches[0][0].devices()
+    leaves = jax.tree.leaves(eng.variables)
+    assert len(leaves) == len(jax.tree.leaves(host))
+    for leaf in leaves:
+        assert isinstance(leaf, jax.Array), type(leaf)
+        assert not isinstance(leaf, np.ndarray)
+        assert leaf.devices() == pool_devices
+    for placed, given in zip(leaves, jax.tree.leaves(host)):
+        assert placed.dtype == given.dtype  # stored precision kept
+        np.testing.assert_array_equal(np.asarray(placed), given)
+    assert eng.stats()["params_resident_bytes"] == eng.param_bytes()
+
+
+def test_device_parameters_are_kept_as_given(tiny):
+    """Leaves that are already device arrays pass through the
+    constructor untouched (the very same arrays: no copy)."""
+    module, variables, _ = tiny
+    eng = make_engine(tiny)
+    for kept, given in zip(jax.tree.leaves(eng.variables),
+                           jax.tree.leaves(variables)):
+        assert kept is given
+    assert eng.stats()["params_resident_bytes"] == eng.param_bytes()
+
+
+def test_draft_parameters_are_placed_with_the_target(tiny):
+    """The draft tree goes through the same placement, and the
+    resident-bytes figure counts both models."""
+    module, variables, _ = tiny
+    host = jax.tree.map(np.asarray, variables)
+    eng = make_engine(tiny, block_size=16,
+                      prefill_buckets=[16, MAX_SEQ], speculative={
+        "tokens": 2, "draft_module": module,
+        "draft_variables": host, "draft_window": 8})
+    pool_devices = eng._caches[0][0].devices()
+    for leaf in jax.tree.leaves(eng.draft_variables):
+        assert isinstance(leaf, jax.Array), type(leaf)
+        assert leaf.devices() == pool_devices
+    assert eng.draft_param_bytes() == eng.param_bytes() > 0
+    assert (eng.stats()["params_resident_bytes"]
+            == eng.param_bytes() + eng.draft_param_bytes())
+    assert (eng.stats()["speculative"]["draft_param_bytes"]
+            == eng.draft_param_bytes())
+
+
+@pytest.mark.parametrize("paged", [False, True],
+                         ids=["dense", "paged"])
+async def test_host_and_placed_trees_generate_identically(tiny, paged):
+    """Placement moves bytes, not mathematics: an engine built from
+    the host tree and one built from a pre-placed tree give bit-equal
+    greedy tokens and log-probabilities."""
+    module, variables, _ = tiny
+    kw = {"block_size": 16} if paged else {}
+    host = jax.tree.map(np.asarray, variables)
+    placed = jax.device_put(host)
+    outs = []
+    for tree in (host, placed):
+        eng = GenerationEngine(module, tree, max_slots=2,
+                               max_seq=MAX_SEQ,
+                               prefill_buckets=[16, MAX_SEQ], **kw)
+        try:
+            req = eng.submit([5, 9, 2, 7, 11], max_new_tokens=8,
+                             logprobs=3)
+            tokens = [t async for t, _ in eng.stream(req)
+                      if t is not None]
+        finally:
+            await eng.close()
+        outs.append((tokens, list(req.lp_chosen),
+                     [list(top) for top in req.lp_top]))
+    assert len(outs[0][0]) == 8
+    assert outs[0] == outs[1]
+
+
 async def test_decode_failure_fails_all_inflight(tiny):
     """A device failure mid-decode must surface as InferenceError on
     every in-flight request — never a hung awaiter (code-review r4)."""
@@ -371,6 +470,79 @@ async def test_prefill_failure_fails_only_that_group(tiny):
         tokens, _ = await asyncio.wait_for(
             eng.complete([5, 5], max_new_tokens=4), timeout=30)
         assert tokens == want
+    finally:
+        await eng.close()
+
+
+_REFUSAL = ("RESOURCE_EXHAUSTED: Error allocating device buffer: "
+            "Attempting to allocate 10.00M. That was not possible. "
+            "There are 3.29M free.; (0x0x0_HBM0)")
+
+
+@pytest.mark.parametrize("paged", [False, True],
+                         ids=["dense", "paged"])
+async def test_prefill_refused_for_memory_is_taken_again_smaller(
+        tiny, paged):
+    """The runtime refuses a prefill launch's output buffers when the
+    chip is full (seen on the v5e once launches stopped waiting on a
+    parameter transfer).  Nothing ran, so no request fails: the group
+    goes back to the queue and rides dispatches of half the refused
+    row count from then on."""
+    module, variables, _ = tiny
+    prompts = [[5, 5], [7, 1, 3], [2], [9, 9, 4]]
+    wants = [ref_greedy(module, variables, p, 4) for p in prompts]
+    kw = {"block_size": 16} if paged else {}
+    eng = make_engine(tiny, max_slots=4,
+                      prefill_buckets=[16, MAX_SEQ], **kw)
+    try:
+        real = eng._prefill
+        rows_seen = []
+
+        def refusing(variables, ids, *rest):
+            rows_seen.append(ids.shape[0])
+            if ids.shape[0] > 2:
+                raise ValueError(_REFUSAL)
+            return real(variables, ids, *rest)
+
+        eng._prefill = refusing
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        outs = await asyncio.wait_for(
+            asyncio.gather(*(_drain(eng, r) for r in reqs)), timeout=60)
+        assert outs == wants
+        assert rows_seen[0] == 4 and set(rows_seen[1:]) <= {1, 2}
+        stats = eng.stats()
+        assert stats["prefill_rows_cap"] == 2
+        assert stats["requests_finished"] == 4
+    finally:
+        await eng.close()
+
+
+async def test_prefill_of_one_row_refused_fails_that_request(tiny):
+    """One row cannot be made smaller: its refusal is that request's
+    failure, as any other enqueue failure, and the engine goes on."""
+    from kfserving_tpu.protocol.errors import InferenceError
+
+    module, variables, _ = tiny
+    want = ref_greedy(module, variables, [5, 5], 4)
+    eng = make_engine(tiny, max_slots=2)
+    try:
+        real = eng._prefill
+        left = {"n": 1}
+
+        def refusing(*args):
+            if left["n"]:
+                left["n"] -= 1
+                raise ValueError(_REFUSAL)
+            return real(*args)
+
+        eng._prefill = refusing
+        with pytest.raises(InferenceError, match="prefill failed"):
+            await asyncio.wait_for(
+                eng.complete([9, 9], max_new_tokens=4), timeout=10)
+        tokens, _ = await asyncio.wait_for(
+            eng.complete([5, 5], max_new_tokens=4), timeout=30)
+        assert tokens == want
+        assert eng.stats()["prefill_rows_cap"] == 0
     finally:
         await eng.close()
 

@@ -993,3 +993,102 @@ async def test_startup_phases_reported(tmp_path):
         assert phases["interpreter_imports"] > 0
     finally:
         await server.stop_async()
+
+
+# ------------------------------------------------ parameter residency
+
+_DRAFT = {"tokens": 2, "draft": {
+    "architecture": "decoder_tiny",
+    "arch_kwargs": {"num_layers": 1, "hidden_size": 32, "num_heads": 2,
+                    "intermediate_size": 64, "max_seq": 64},
+    "window": 8}}
+
+
+@pytest.mark.parametrize("speculative", [None, _DRAFT],
+                         ids=["target", "target+draft"])
+async def test_param_cache_hit_is_placed_on_device(tmp_path,
+                                                   speculative):
+    """A param_cache hit hands over read-only memmap views; after
+    load() no parameter leaf of the engine (or of its draft) is a host
+    array and each sits where the engine's pool sits."""
+    import jax
+
+    overrides = {"block_size": 16, "prefill_buckets": [16, 32, 64]}
+    if speculative:
+        overrides["speculative"] = speculative
+    model_dir = _write_model_dir(tmp_path, **overrides)
+    first = GenerativeModel("first", model_dir)
+    first.load()          # materializes and stores the entry
+    await first.close()
+    model = GenerativeModel("gen", model_dir)
+    model.load()
+    try:
+        assert model.param_source == "mmap"
+        engine = model.engine
+        pool_devices = engine._caches[0][0].devices()
+        trees = [engine.variables]
+        if speculative:
+            trees.append(engine.draft_variables)
+            # The residency handle reads the placed tree too: no
+            # second (host) tree stays alive beside it.
+            assert model._draft_handle.variables \
+                is engine.draft_variables
+            assert (model._draft_handle.param_bytes()
+                    == engine.draft_param_bytes() > 0)
+        else:
+            assert engine.draft_variables is None
+        for tree in trees:
+            leaves = jax.tree.leaves(tree)
+            assert leaves
+            for leaf in leaves:
+                assert isinstance(leaf, jax.Array), type(leaf)
+                assert not isinstance(leaf, np.ndarray)
+                assert leaf.devices() == pool_devices
+        assert (engine.stats()["params_resident_bytes"]
+                == engine.param_bytes() + engine.draft_param_bytes())
+        out = await model.predict({"instances": ["resident"]})
+        assert out["predictions"][0]["token_count"] > 0
+    finally:
+        await model.close()
+
+
+async def test_params_resident_bytes_on_metrics_and_startup_phases(
+        tmp_path):
+    """The mechanism says it engaged: `params_device` follows
+    `params_mmap` under /startup_phases, and /metrics carries the
+    resident bytes as a family of the generator's own (once: the
+    generic per-key engine export leaves it out)."""
+    import aiohttp
+
+    from kfserving_tpu.server.app import ModelServer
+
+    model_dir = _write_model_dir(tmp_path, speculative=_DRAFT,
+                                 block_size=16)
+    first = GenerativeModel("first", model_dir)
+    first.load()
+    await first.close()
+    model = GenerativeModel("gen", model_dir)
+    model.load()
+    server = ModelServer(http_port=0)
+    await server.start_async([model], host="127.0.0.1")
+    base = f"http://127.0.0.1:{server.http_port}"
+    try:
+        async with aiohttp.ClientSession() as s:
+            async with s.get(f"{base}/startup_phases") as r:
+                phases = await r.json()
+            async with s.get(f"{base}/metrics") as r:
+                text = await r.text()
+        assert phases["params_mmap"] <= phases["params_device"] \
+            <= phases["serving"], phases
+        want = (model.engine.param_bytes()
+                + model.engine.draft_param_bytes())
+        lines = [ln for ln in text.splitlines()
+                 if "params_resident_bytes" in ln
+                 and not ln.startswith("#")]
+        assert len(lines) == 1, lines
+        name, value = lines[0].rsplit(" ", 1)
+        assert name == ('kfserving_tpu_generator_params_resident_bytes'
+                        '{model="gen"}')
+        assert float(value) == want
+    finally:
+        await server.stop_async()

@@ -16,6 +16,9 @@ The sharding assertions inspect the engine's live params: if the
 spec-mesh -> engine wiring silently breaks (jax_model.py mesh block),
 the device_set checks fail even though numerics would still pass on a
 single device.
+
+The served tests are `slow` (each marked); the generator's placement
+test at the end builds no server and runs in the fast tier.
 """
 
 import json
@@ -26,8 +29,6 @@ import numpy as np
 import pytest
 
 
-
-pytestmark = pytest.mark.slow
 def _write_model_dir(tmp_path, mesh=None, name="m"):
     d = tmp_path / name
     d.mkdir()
@@ -84,6 +85,7 @@ async def _predict_http(port: int, model: str, ids: np.ndarray):
 
 @pytest.mark.parametrize("mesh", [{"tp": 2}, {"dp": 2, "tp": 2},
                                   {"sp": 2}, {"dp": 2, "sp": 2}])
+@pytest.mark.slow
 async def test_mesh_sharded_model_serves_with_parity(tmp_path, mesh):
     """A config-mesh JaxModel serves through ModelServer with numeric
     parity against the unsharded model (same seed-0 init).  sp meshes
@@ -129,6 +131,7 @@ async def test_mesh_sharded_model_serves_with_parity(tmp_path, mesh):
         ref.unload()
 
 
+@pytest.mark.slow
 async def test_spec_parallelism_reaches_served_engine(tmp_path):
     """ParallelismSpec{tp:2} on an InferenceService must produce a
     served replica whose engine params span 2 devices, reachable
@@ -171,6 +174,7 @@ async def test_spec_parallelism_reaches_served_engine(tmp_path):
         await orch.shutdown()
 
 
+@pytest.mark.slow
 async def test_sp_mesh_injects_ring_attention(tmp_path):
     """The sp path swaps the serving module's attention for the
     ring-sharded closure — observable via the module config hook."""
@@ -186,6 +190,7 @@ async def test_sp_mesh_injects_ring_attention(tmp_path):
         model.unload()
 
 
+@pytest.mark.slow
 async def test_sp_mesh_rejects_non_pluggable_arch(tmp_path):
     """sp>1 on an architecture without an attention hook must fail at
     load with a clear error, never silently serve unsharded."""
@@ -202,3 +207,44 @@ async def test_sp_mesh_rejects_non_pluggable_arch(tmp_path):
     model = JaxModel("m", str(d))
     with pytest.raises(InvalidInput, match="sequence parallelism"):
         model.load()
+
+
+def test_generator_keeps_shard_params_shardings():
+    """The generate path under a mesh: `shard_params` output goes
+    through GenerationEngine's constructor leaf for leaf (the same
+    arrays, so every sharding survives), while a collection still on
+    the host is replicated over the mesh, not left to be sent with
+    every launch."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from kfserving_tpu.engine.generator import GenerationEngine
+    from kfserving_tpu.models.decoder import DecoderLM, decoder_tiny
+    from kfserving_tpu.parallel import build_mesh, shard_params
+    from kfserving_tpu.parallel.mesh import MeshConfig
+
+    cfg = decoder_tiny(num_layers=2, hidden_size=64, num_heads=2,
+                       intermediate_size=128, max_seq=64, vocab_size=96)
+    module = DecoderLM(cfg)
+    host = jax.tree.map(np.asarray, module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    mesh = build_mesh(MeshConfig(tp=2))
+    sharded = shard_params(host["params"], mesh)
+    given = jax.tree.leaves(sharded)
+    assert any(any(axis is not None for axis in leaf.sharding.spec)
+               for leaf in given), "nothing partitioned: vacuous test"
+    extra = np.arange(4, dtype=np.float32)
+    eng = GenerationEngine(
+        module, {"params": sharded, "aux": {"host_leaf": extra}},
+        max_slots=2, max_seq=64, mesh=mesh)
+    kept = jax.tree.leaves(eng.variables["params"])
+    assert len(kept) == len(given)
+    for got, want in zip(kept, given):
+        assert got is want
+        assert got.sharding == want.sharding
+    placed = eng.variables["aux"]["host_leaf"]
+    assert isinstance(placed, jax.Array)
+    assert placed.sharding == NamedSharding(mesh, PartitionSpec())
+    assert len(placed.sharding.device_set) == 2
+    assert eng.stats()["params_resident_bytes"] == eng.param_bytes()
