@@ -544,6 +544,28 @@ class GenerationEngine:
         base_key = self._rng
         lp_n = self.logprob_topk
 
+        # A model with routed experts (models/olmoe.py) also reports
+        # what its routers chose; a dense decoder's programs and
+        # fetches are what they were.
+        self._moe = None
+        if getattr(cfg, "num_experts", 0):
+            from kfserving_tpu.engine.moe_counters import (
+                MoeCounters,
+                decode_call_stats,
+            )
+
+            self._moe = MoeCounters(name, cfg.num_experts)
+        routed = self._moe is not None
+
+        def apply(variables, ids, **kw):
+            """(module.apply's outputs, routed pairs [layers, experts]
+            of an expert model or None)."""
+            if not routed:
+                return module.apply(variables, ids, **kw), None
+            out, state = module.apply(variables, ids, mutable=["moe"],
+                                      **kw)
+            return out, module.routed_pairs(state)
+
         def mask_to_support(logits, top_ks, top_ps):
             """Restrict logits to the top-k / nucleus support.  Both
             knobs are per-row; 0 / 1.0 disable them.  One sort serves
@@ -622,7 +644,7 @@ class GenerationEngine:
                 caches, tokens, positions = carry
                 kv = ([(k, v, table) for k, v in caches] if paged
                       else caches)
-                logits, new_caches = module.apply(
+                (logits, new_caches), pairs = apply(
                     variables, tokens[:, None], positions=positions,
                     kv_cache=kv)
                 lg = logits[:, 0]
@@ -633,16 +655,18 @@ class GenerationEngine:
                 nxt = sample(lg, temps, top_ks, top_ps, seeds,
                              positions + 1)
                 lp = logprob_of(lg, nxt)
-                return (new_caches, nxt, positions + 1), (nxt, lp)
+                return (new_caches, nxt, positions + 1), (nxt, lp, pairs)
 
-            (caches, next_tokens, next_positions), (toks, lps) = \
+            (caches, next_tokens, next_positions), (toks, lps, pairs) = \
                 jax.lax.scan(step, (caches, tokens, positions),
                              None, length=k_steps)
             chosen_lp, top_ids, top_lps = lps
             # scan stacks on axis 0: [K, S, ...] -> [S, K, ...]
-            return (toks.T, caches, next_tokens, next_positions,
-                    chosen_lp.T, jnp.swapaxes(top_ids, 0, 1),
-                    jnp.swapaxes(top_lps, 0, 1))
+            out = (toks.T, caches, next_tokens, next_positions,
+                   chosen_lp.T, jnp.swapaxes(top_ids, 0, 1),
+                   jnp.swapaxes(top_lps, 0, 1))
+            # Expert models: the call's routing, reduced on the device.
+            return out + (decode_call_stats(pairs),) if routed else out
 
         # Donate caches AND the feed arrays: in-place HBM update, one
         # resident pool; the feed tokens/positions chain wave-to-wave
@@ -679,16 +703,17 @@ class GenerationEngine:
             # per row to slicing the full cube (norm + head are
             # per-position), so the chunked path (which uses the same
             # sliced head) samples the same first token.
-            logits, caches = module.apply(variables, ids,
-                                          kv_lengths=lengths,
-                                          return_cache=True,
-                                          logit_positions=lengths - 1)
+            (logits, caches), pairs = apply(variables, ids,
+                                            kv_lengths=lengths,
+                                            return_cache=True,
+                                            logit_positions=lengths - 1)
             last = logits[:, 0]
             first_tokens = sample(last, temps, top_ks, top_ps, seeds,
                                   lengths)
             chosen_lp, top_ids, top_lps = logprob_of(last,
                                                      first_tokens)
-            return first_tokens, caches, chosen_lp, top_ids, top_lps
+            out = (first_tokens, caches, chosen_lp, top_ids, top_lps)
+            return out + ({"pairs": pairs},) if routed else out
 
         # One executable per prompt bucket (jit caches by shape).
         self._prefill = jax.jit(prefill_fn)
@@ -926,7 +951,19 @@ class GenerationEngine:
             int(np.prod(x.shape))
             for x in self._jax.tree.leaves(self.variables)))
         self._param_read_bytes = self.param_bytes()
-        self._flops_matmul_per_token = 2.0 * self._n_params
+        self._active_params = self._n_params
+        self._expert_read_bytes = 0.0
+        if routed:
+            # A token multiplies by its own experts alone, and a step
+            # reads the experts its rows touched (counted on the
+            # device, added when the counters arrive) beside what
+            # every step reads.
+            counts = cfg.param_counts()
+            per_param = self._param_read_bytes / self._n_params
+            self._active_params = int(counts["active"])
+            self._param_read_bytes = counts["always_read"] * per_param
+            self._expert_read_bytes = counts["per_expert"] * per_param
+        self._flops_matmul_per_token = 2.0 * self._active_params
         self._attn_flops_coeff = (4.0 * n_layers * cfg.num_heads
                                   * cfg.head_dim)
         self._kv_bytes_per_token = (2 * n_layers * cfg.num_heads
@@ -1162,6 +1199,7 @@ class GenerationEngine:
             "prefill_rows_cap": self._prefill_rows_cap or 0,
             "cache_bytes": self.cache_bytes(),
             "params_resident_bytes": self._params_resident_bytes,
+            "active_params": self._active_params,
             "decode_device_s": round(self._decode_device_s, 4),
             "decode_wait_s": round(self._decode_wait_s, 4),
             "prefill_wait_s": round(self._prefill_wait_s, 4),
@@ -1187,8 +1225,13 @@ class GenerationEngine:
                 self.tokens_generated
                 / (self.tokens_generated + self._wasted_token_steps),
                 4)
-        if self._decode_hbm_bytes > 0 and self._decode_device_s > 0:
-            rate = self._decode_hbm_bytes / self._decode_device_s
+        decode_hbm_bytes = self._decode_hbm_bytes
+        if self._moe is not None:
+            out.update(self._moe.stats())
+            decode_hbm_bytes += (self._moe.touched
+                                 * self._expert_read_bytes)
+        if decode_hbm_bytes > 0 and self._decode_device_s > 0:
+            rate = decode_hbm_bytes / self._decode_device_s
             out["decode_hbm_gb_s"] = round(rate / 1e9, 3)
             if self._peak_hbm_bw:
                 out["hbm_bw_util"] = round(
@@ -3239,12 +3282,16 @@ class GenerationEngine:
             with TIMELINE.span(LAUNCH, "engine.launch.decode",
                                rows=self.max_slots,
                                steps=self.steps_per_call):
+                out = self._decode(
+                    self.variables, self._caches, table,
+                    self._feed_tokens, self._feed_positions, *sampling)
                 (toks, self._caches, self._feed_tokens,
-                 self._feed_positions, chosen_lp, top_ids, top_lps) = \
-                    self._decode(
-                        self.variables, self._caches, table,
-                        self._feed_tokens, self._feed_positions,
-                        *sampling)
+                 self._feed_positions, chosen_lp, top_ids,
+                 top_lps) = out[:7]
+                if self._moe is not None:
+                    self._moe.note(
+                        "decode", out[7], layer_steps=(
+                            self.steps_per_call * len(self._caches)))
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         self.decode_steps += 1
         # Snapshot records mid-chunked-prefill slots as None: this
@@ -3276,6 +3323,8 @@ class GenerationEngine:
                 # kfslint: disable=host-sync — sanctioned fetch site:
                 # logprob handles fetched beside their wave's tokens.
                 lp = tuple(np.asarray(h) for h in lp_h)
+            if self._moe is not None:
+                self._moe.drain()
         return tokens, lp, time.perf_counter() - t0
 
     @_dispatch_timed("prefill")
@@ -3346,9 +3395,11 @@ class GenerationEngine:
                 TIMELINE.span(LAUNCH, "engine.launch.prefill", rows=b,
                               bucket=bucket,
                               trace_ids=[r.trace_id for r in group]):
-            firsts, new_caches, chosen_lp, top_ids, top_lps = \
-                self._prefill(self.variables, ids_d, lengths_d,
-                              *sampling)
+            out = self._prefill(self.variables, ids_d, lengths_d,
+                                *sampling)
+            firsts, new_caches, chosen_lp, top_ids, top_lps = out[:5]
+            if self._moe is not None:
+                self._moe.note("prefill", out[5])
         with TIMELINE.span(LAUNCH, "engine.prep.insert"):
             slot_d = jnp.asarray(slot_arr)
             if dest_rows is not None:

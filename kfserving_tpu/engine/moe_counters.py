@@ -1,0 +1,89 @@
+"""Routing counters of an expert model, from the device to /metrics.
+
+The programs of a model with routed experts return, beside their tokens,
+what the routers chose: per decode call `pairs` [layers, experts] (token,
+expert) pairs summed over the call's steps, `touched` (distinct experts
+read, summed over the call's layer-steps) and `load_max` (the busiest
+expert's pairs, summed likewise); per prefill dispatch `pairs` alone.  The
+counts are over the rows a program computed: a parked decode row's garbage
+step reads its experts too.
+
+The launching thread `note`s the handles; a fetch worker `drain`s those
+that are ready after its own wave's fetch, so no launch and no fetch waits
+for them.  A dense model's engine holds no `MoeCounters`.
+"""
+
+import threading
+from collections import deque
+from typing import Any, Dict
+
+import numpy as np
+
+from kfserving_tpu.observability import metrics as obs
+
+
+def decode_call_stats(pairs) -> Dict[str, Any]:
+    """Device side: `pairs` [steps, layers, experts] of one decode call."""
+    return {"pairs": pairs.sum(axis=0),
+            "touched": (pairs > 0).sum(),
+            "load_max": pairs.max(axis=-1).sum()}
+
+
+class MoeCounters:
+    def __init__(self, model: str, experts: int):
+        self.model = model
+        self.experts = experts
+        self.pairs = {"decode": 0, "prefill": 0}  # routed, by program
+        self.touched = 0       # distinct experts read, over layer-steps
+        self.layer_steps = 0   # decode layer-steps counted
+        self.load_max = 0      # busiest expert's pairs, over layer-steps
+        self._pending: deque = deque()
+        self._lock = threading.Lock()
+
+    def note(self, program: str, handles: Dict[str, Any],
+             layer_steps: int = 0) -> None:
+        self._pending.append((program, handles, layer_steps))
+
+    def drain(self) -> None:
+        """Fetch and count every noted record whose arrays are ready
+        (device order: all those launched before a fetched wave are)."""
+        while True:
+            try:
+                record = self._pending.popleft()
+            except IndexError:
+                return
+            program, handles, layer_steps = record
+            if not all(h.is_ready() for h in handles.values()):
+                self._pending.appendleft(record)
+                return
+            # kfslint: disable=host-sync — ready arrays, on a fetch
+            # worker inside the sanctioned fetch.
+            host = {k: np.asarray(h) for k, h in handles.items()}
+            pairs = int(host["pairs"].sum())
+            with self._lock:
+                self.pairs[program] += pairs
+                obs.generator_moe_routed_pairs_total().labels(
+                    model=self.model, program=program).inc(pairs)
+                if layer_steps:
+                    self.touched += int(host["touched"])
+                    self.load_max += int(host["load_max"])
+                    self.layer_steps += layer_steps
+                    obs.generator_moe_experts_touched_total().labels(
+                        model=self.model).inc(int(host["touched"]))
+                    obs.generator_moe_expert_load_max_total().labels(
+                        model=self.model).inc(int(host["load_max"]))
+                    obs.generator_moe_layer_steps_total().labels(
+                        model=self.model).inc(layer_steps)
+
+    def stats(self) -> Dict[str, float]:
+        with self._lock:
+            if not self.layer_steps:
+                return {}
+            mean_load = (self.pairs["decode"] / self.experts
+                         / self.layer_steps)
+            return {
+                "moe_experts_touched_mean": round(
+                    self.touched / self.layer_steps, 4),
+                "moe_load_max_over_mean": round(
+                    self.load_max / self.layer_steps / mean_load, 4),
+            }

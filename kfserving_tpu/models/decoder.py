@@ -65,6 +65,89 @@ class DecoderConfig:
         return self.hidden_size // self.num_heads
 
 
+def cached_attention(q, k, v, *, cache=None, positions=None,
+                     kv_lengths=None, attn_fn=None):
+    """Attention of one block in whichever serving mode `cache` selects,
+    shared by every decoder block of the zoo (GPT-2's here, OLMoE's in
+    models/olmoe.py): q, k, v are [B, L, H, D] as projected (and, for
+    rotary models, rotated — the pool stores what attention reads).
+    Returns (out [B, L, H, D], new_cache)."""
+    lq = q.shape[1]
+    new_cache = None
+    if cache is not None and len(cache) == 3:
+        # Paged cache: cache = (pool_k, pool_v, block_table) —
+        # shared block pools [NB, BS, H, D] plus this batch's
+        # [B, MB] table (engine/generator.py paged mode; the
+        # static 3-vs-2 tuple arity picks the branch at trace
+        # time).  The table flows in per dispatch and is not
+        # returned — only the written pools are.  Lq == 1 is the
+        # decode step; Lq > 1 is a CHUNK PREFILL: the chunk's
+        # tokens write through the table, then attend over the
+        # pool with per-query causal masking (earlier chunks are
+        # already resident — cross-chunk attention comes from the
+        # pool, exactly like decode).
+        from kfserving_tpu.ops.paged_attention import (
+            paged_attention,
+            paged_prefill_attention_xla,
+            paged_write,
+        )
+
+        pool_k, pool_v, table = cache
+        if lq == 1:
+            pool_k, pool_v = paged_write(pool_k, pool_v, k[:, 0],
+                                         v[:, 0], table,
+                                         positions[:, 0])
+            new_cache = (pool_k, pool_v)
+            out = paged_attention(q, pool_k, pool_v, table,
+                                  positions[:, 0] + 1)
+        else:
+            pool_k, pool_v = paged_write(pool_k, pool_v, k, v,
+                                         table, positions)
+            new_cache = (pool_k, pool_v)
+            out = paged_prefill_attention_xla(q, pool_k, pool_v,
+                                              table, positions)
+    elif cache is not None:
+        k_cache, v_cache = cache
+        b = k_cache.shape[0]
+        rows = jnp.arange(b)[:, None]
+        # mode="drop": positions carry an out-of-range sentinel
+        # for rows the engine parked (freed / mid-prefill slots) —
+        # a clamped write would corrupt the row's last position.
+        k_cache = k_cache.at[rows, positions].set(
+            k.astype(k_cache.dtype), mode="drop")
+        v_cache = v_cache.at[rows, positions].set(
+            v.astype(v_cache.dtype), mode="drop")
+        new_cache = (k_cache, v_cache)
+        # Valid keys are exactly positions <= the query's own
+        # position (per query — Lq > 1 is a chunk prefill).
+        max_seq = k_cache.shape[1]
+        attn_mask = (jnp.arange(max_seq)[None, None, :]
+                     <= positions[:, :, None])[:, None]
+        out = dot_product_attention(q, k_cache, v_cache,
+                                    mask=attn_mask)
+    elif attn_fn is not None:
+        attn_mask = None
+        lq = q.shape[1]
+        causal = jnp.tril(jnp.ones((lq, lq), jnp.bool_))[None, None]
+        if kv_lengths is not None:
+            pad = (jnp.arange(lq)[None, :]
+                   < kv_lengths[:, None])[:, None, None, :]
+            attn_mask = causal & pad
+        else:
+            attn_mask = causal
+        out = attn_fn(q, k, v, attn_mask)
+        # The k/v projections are already materialized; without
+        # this a prefill with return_cache=True under a pluggable
+        # attn_fn returned caches=[None, ...] and crashed deep in
+        # the engine's insert scatter instead of working.
+        new_cache = (k, v)
+    else:
+        out = dot_product_attention(q, k, v, causal=True,
+                                    kv_lengths=kv_lengths)
+        new_cache = (k, v)
+    return out, new_cache
+
+
 class DecoderBlock(nn.Module):
     config: DecoderConfig
 
@@ -85,79 +168,9 @@ class DecoderBlock(nn.Module):
         q = proj("query")(x)
         k = proj("key")(x)
         v = proj("value")(x)
-        lq = q.shape[1]
-        new_cache = None
-        if cache is not None and len(cache) == 3:
-            # Paged cache: cache = (pool_k, pool_v, block_table) —
-            # shared block pools [NB, BS, H, D] plus this batch's
-            # [B, MB] table (engine/generator.py paged mode; the
-            # static 3-vs-2 tuple arity picks the branch at trace
-            # time).  The table flows in per dispatch and is not
-            # returned — only the written pools are.  Lq == 1 is the
-            # decode step; Lq > 1 is a CHUNK PREFILL: the chunk's
-            # tokens write through the table, then attend over the
-            # pool with per-query causal masking (earlier chunks are
-            # already resident — cross-chunk attention comes from the
-            # pool, exactly like decode).
-            from kfserving_tpu.ops.paged_attention import (
-                paged_attention,
-                paged_prefill_attention_xla,
-                paged_write,
-            )
-
-            pool_k, pool_v, table = cache
-            if lq == 1:
-                pool_k, pool_v = paged_write(pool_k, pool_v, k[:, 0],
-                                             v[:, 0], table,
-                                             positions[:, 0])
-                new_cache = (pool_k, pool_v)
-                out = paged_attention(q, pool_k, pool_v, table,
-                                      positions[:, 0] + 1)
-            else:
-                pool_k, pool_v = paged_write(pool_k, pool_v, k, v,
-                                             table, positions)
-                new_cache = (pool_k, pool_v)
-                out = paged_prefill_attention_xla(q, pool_k, pool_v,
-                                                  table, positions)
-        elif cache is not None:
-            k_cache, v_cache = cache
-            b = k_cache.shape[0]
-            rows = jnp.arange(b)[:, None]
-            # mode="drop": positions carry an out-of-range sentinel
-            # for rows the engine parked (freed / mid-prefill slots) —
-            # a clamped write would corrupt the row's last position.
-            k_cache = k_cache.at[rows, positions].set(
-                k.astype(k_cache.dtype), mode="drop")
-            v_cache = v_cache.at[rows, positions].set(
-                v.astype(v_cache.dtype), mode="drop")
-            new_cache = (k_cache, v_cache)
-            # Valid keys are exactly positions <= the query's own
-            # position (per query — Lq > 1 is a chunk prefill).
-            max_seq = k_cache.shape[1]
-            attn_mask = (jnp.arange(max_seq)[None, None, :]
-                         <= positions[:, :, None])[:, None]
-            out = dot_product_attention(q, k_cache, v_cache,
-                                        mask=attn_mask)
-        elif cfg.attn_fn is not None:
-            attn_mask = None
-            lq = q.shape[1]
-            causal = jnp.tril(jnp.ones((lq, lq), jnp.bool_))[None, None]
-            if kv_lengths is not None:
-                pad = (jnp.arange(lq)[None, :]
-                       < kv_lengths[:, None])[:, None, None, :]
-                attn_mask = causal & pad
-            else:
-                attn_mask = causal
-            out = cfg.attn_fn(q, k, v, attn_mask)
-            # The k/v projections are already materialized; without
-            # this a prefill with return_cache=True under a pluggable
-            # attn_fn returned caches=[None, ...] and crashed deep in
-            # the engine's insert scatter instead of working.
-            new_cache = (k, v)
-        else:
-            out = dot_product_attention(q, k, v, causal=True,
-                                        kv_lengths=kv_lengths)
-            new_cache = (k, v)
+        out, new_cache = cached_attention(
+            q, k, v, cache=cache, positions=positions,
+            kv_lengths=kv_lengths, attn_fn=cfg.attn_fn)
         out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1),
                               dtype=cfg.dtype, name="out")(out)
         hidden = hidden + out

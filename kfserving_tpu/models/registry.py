@@ -32,6 +32,8 @@ _LAZY_BUILTINS: Dict[str, Tuple[str, str]] = {
     "decoder": ("kfserving_tpu.models.decoder", "_create_decoder_small"),
     "decoder_tiny": ("kfserving_tpu.models.decoder",
                      "_create_decoder_tiny"),
+    "olmoe": ("kfserving_tpu.models.olmoe", "_create_olmoe"),
+    "olmoe_tiny": ("kfserving_tpu.models.olmoe", "_create_olmoe_tiny"),
 }
 
 

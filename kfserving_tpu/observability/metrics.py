@@ -363,6 +363,39 @@ def generator_params_resident_bytes():
         "was built, not handed over from the host with every launch")
 
 
+def generator_moe_routed_pairs_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_routed_pairs_total",
+        "(token, expert) pairs the routers of an expert model chose, "
+        "over every row the program computed (program=decode|prefill; "
+        "a parked decode row's garbage step counts, bucket padding of a "
+        "prefill does not)")
+
+
+def generator_moe_experts_touched_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_experts_touched_total",
+        "Distinct experts given at least one pair, summed over the "
+        "decode layer-steps counted by "
+        "generator_moe_layer_steps_total: their quotient is the "
+        "experts a decode step reads per layer")
+
+
+def generator_moe_layer_steps_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_layer_steps_total",
+        "Decode layer-steps (calls x steps per call x expert layers) "
+        "whose routing has been counted")
+
+
+def generator_moe_expert_load_max_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_expert_load_max_total",
+        "Pairs given to the busiest expert, summed over decode "
+        "layer-steps: against routed pairs over experts it says how "
+        "uneven the routing is")
+
+
 # -- HBM residency (engine/hbm.py accountant) ---------------------------
 def hbm_resident_bytes():
     return REGISTRY.gauge(

@@ -7,6 +7,8 @@ regex → PartitionSpec rules over flattened Flax param paths:
   device computes its own heads, no communication.
 - attention output and MLP down projections shard the *input* dimension on
   ``tp``: XLA inserts the single per-layer psum over ICI.
+- routed experts ([experts, in, out]) shard the expert *width* the same
+  way; no expert kernel is replicated.
 - embeddings/layernorms/heads replicate (serving batch sizes keep them
   cheap; vocab-sharded embeddings only pay off at training scale).
 
@@ -37,6 +39,12 @@ def transformer_rules() -> Sequence[Tuple[str, P]]:
         (r".*(intermediate|mlp_in)/bias$", P("tp")),
         # MLP down: [intermediate, hidden]
         (r".*(output|mlp_out)/kernel$", P("tp", None)),
+        # Routed experts (models/olmoe.py), stacked [experts, in, out]:
+        # the expert width splits as the MLP's, every device keeps all
+        # experts, and the down projection's psum is the layer's one
+        # collective.  The router stays whole.
+        (r".*experts/(gate|up)$", P(None, None, "tp")),
+        (r".*experts/down$", P(None, "tp", None)),
         # Everything else (embeddings, norms, heads, convs): replicated
         (r".*", P()),
     )

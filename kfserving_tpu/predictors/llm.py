@@ -14,7 +14,13 @@ Model directory layout (the `storage_uri` artifact):
 
 config.json schema:
     {
-      "architecture": "decoder" | "decoder_tiny" | <registered>,
+      "architecture": "decoder" | "decoder_tiny"    # GPT-2 block
+                    | "olmoe" | "olmoe_tiny"        # RoPE, RMSNorm,
+                    | <registered>,                 #   QK-norm, SwiGLU,
+                                                    #   64 routed experts
+                                                    #   (models/olmoe.py);
+                                                    #   same engine, pool
+                                                    #   and decode kernel
       "arch_kwargs": {...},
       "max_slots": 8,              # continuous-batching slot count
       "max_seq": 512,              # KV-cache capacity per slot

@@ -190,6 +190,15 @@ async def smoke() -> List[str]:
         model="metrics-probe").set(0.18)
     obs.generator_params_resident_bytes().labels(
         model="metrics-probe").set(3.1e9)
+    for program in ("decode", "prefill"):
+        obs.generator_moe_routed_pairs_total().labels(
+            model="metrics-probe", program=program).inc(3072)
+    obs.generator_moe_experts_touched_total().labels(
+        model="metrics-probe").inc(7800)
+    obs.generator_moe_layer_steps_total().labels(
+        model="metrics-probe").inc(128)
+    obs.generator_moe_expert_load_max_total().labels(
+        model="metrics-probe").inc(900)
     obs.hbm_resident_bytes().labels(model="metrics-probe").set(2.1e9)
     obs.hbm_budget_bytes().set(12.0 * 1024**3)
     obs.hbm_evictions_total().labels(model="metrics-probe").inc()

@@ -122,6 +122,118 @@ def test_kernel_compiles_for_described_v5e(v5e, kernel, sharded):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# -- OLMoE at the benchmarked configuration's shapes ----------------------------
+def _olmoe_serving() -> dict:
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "olmoe-1b-7b-8l.json")) as f:
+        return json.load(f)["serving"]
+
+
+def test_grouped_expert_matmuls_compile_for_described_v5e(v5e):
+    """The prefill path of ops/moe.py at 4 rows x 1024 tokens: XLA's own
+    grouped-matmul kernel (`lax.ragged_dot`), three a layer, routed rows
+    only (a dense evaluation would be 8x the operations)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from kfserving_tpu.ops import moe
+
+    kw = _olmoe_serving()["arch_kwargs"]
+    e, h, f = kw["num_experts"], kw["hidden_size"], kw["intermediate_size"]
+    k, tokens = kw["experts_per_token"], 4 * 1024
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(moe.routed_experts).lower(
+        arg((tokens, h), jnp.bfloat16), arg((e, h, f), jnp.bfloat16),
+        arg((e, h, f), jnp.bfloat16), arg((e, f, h), jnp.bfloat16),
+        arg((tokens, k), jnp.float32), arg((tokens, k), jnp.int32),
+        arg((tokens,), jnp.bool_)).compile()
+    assert compiled.as_text().count("ragged-dot") >= 3
+    routed = 2 * 3 * tokens * k * h * f
+    assert routed <= compiled.cost_analysis()["flops"] < 1.5 * routed
+
+
+@pytest.mark.parametrize("tokens", [24, 256])
+def test_touched_experts_kernel_compiles_for_described_v5e(v5e, tokens):
+    """The decode path of ops/moe.py at the configuration's widths: the
+    Pallas kernel that streams each touched expert's three 4-MiB
+    matrices through VMEM, at a decode wave's 24 rows and at the most
+    tokens it serves."""
+    from jax.sharding import SingleDeviceSharding
+
+    from kfserving_tpu.ops import moe
+
+    kw = _olmoe_serving()["arch_kwargs"]
+    e, h, f = kw["num_experts"], kw["hidden_size"], kw["intermediate_size"]
+    k = kw["experts_per_token"]
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(moe.experts_touched).lower(
+        arg((tokens, h), jnp.bfloat16), arg((e, h, f), jnp.bfloat16),
+        arg((e, h, f), jnp.bfloat16), arg((e, f, h), jnp.bfloat16),
+        arg((tokens, k), jnp.float32), arg((tokens, k), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "ragged-dot" not in compiled.as_text()
+
+
+def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
+    """The 16-step decode program of `olmoe-1b-7b-8l` (24 slots, 288
+    blocks of 128, bfloat16 parameters) with the Pallas paged kernel, as
+    the chip's compiler sees it: what it needs beside its arguments, and
+    that arguments, outputs and temporaries fit 15.75 GiB."""
+    from jax.sharding import SingleDeviceSharding
+
+    from kfserving_tpu.engine.generator import GenerationEngine
+    from kfserving_tpu.models import create_model
+    from kfserving_tpu.ops import attention
+
+    # The dispatchers ask the attached backend, which is the CPU here.
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    serving = _olmoe_serving()
+    spec = create_model(serving["architecture"], **serving["arch_kwargs"])
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    shapes = jax.eval_shape(
+        lambda: spec.module.init(jax.random.PRNGKey(0), spec.example))
+    assert {x.dtype.name for x in jax.tree.leaves(shapes)} == {"bfloat16"}
+    engine = GenerationEngine(
+        spec.module, shapes, max_slots=serving["max_slots"],
+        max_seq=serving["max_seq"],
+        prefill_buckets=serving["prefill_buckets"],
+        block_size=serving["block_size"],
+        cache_blocks=serving["cache_blocks"],
+        steps_per_call=serving["steps_per_call"])
+    try:
+        s = serving["max_slots"]
+
+        def arg(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        i32, f32 = jnp.int32, jnp.float32
+        compiled = engine._decode.lower(
+            on_chip(shapes), on_chip(engine._caches),
+            arg(i32, s, engine.blocks_per_slot), arg(i32, s), arg(i32, s),
+            arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s)).compile()
+    finally:
+        engine.shutdown_nowait()
+    assert "tpu_custom_call" in compiled.as_text()  # the paged kernel
+    memory = compiled.memory_analysis()
+    print(f"olmoe-1b-7b-8l decode program: {memory}")
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert memory.argument_size_in_bytes > 9.4e9  # 7.13 GB + 2.4 GB pool
+    assert total < 15.75 * 2**30, memory
+
+
 def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):
     """Why the wrappers exist: the pool sharded on heads, as the engine
     shards it under tp, and the kernel called bare."""

@@ -1,0 +1,348 @@
+"""OLMoE (models/olmoe.py, ops/moe.py) against the plain reference
+(tests/olmoe_reference.py) at `olmoe_tiny` size on seeded weights.
+
+Everything compares logits or log-probabilities, never sampled tokens
+alone: with random weights the largest logit changes on rounding.  Both
+sides compute in float32 on the CPU, so they differ by the order of their
+sums only: a few 1e-6 on logits of magnitude 4 here.  The tolerance, 1e-4,
+is forty times that and a hundredth of what leaving out any term of the
+layer would move (dropping the QK-norm's scale, the rotation or one expert
+of the top two moves logits by 1e-2 and more).
+"""
+
+import asyncio
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax.traverse_util import flatten_dict
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import olmoe_reference as reference  # noqa: E402
+
+from kfserving_tpu.engine.generator import GenerationEngine  # noqa: E402
+from kfserving_tpu.models import create_model, init_params  # noqa: E402
+from kfserving_tpu.models.olmoe import OlmoeLM, olmoe_tiny  # noqa: E402
+from kfserving_tpu.ops import moe  # noqa: E402
+
+TOL = 1e-4
+MAX_SEQ = 128
+BS = 16
+MODEL = dict(experts_per_token=2, rope_theta=10000.0)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    spec = create_model("olmoe_tiny", max_seq=MAX_SEQ)
+    variables = init_params(spec, seed=3)
+    flat = {"/".join(k): np.asarray(v)
+            for k, v in flatten_dict(variables).items()}
+    return spec.module, variables, flat
+
+
+def prompt_of(n, stride=7):
+    return [(i * stride) % 250 + 1 for i in range(n)]
+
+
+def ref_log_probs(flat, ids):
+    return np.asarray(reference.log_probs(flat, ids, 2, 1e-5, **MODEL))
+
+
+async def served(engine, prompt, steps):
+    """(tokens, chosen log-probabilities, top-5 records) of one greedy
+    request through the engine."""
+    req = engine.submit(prompt, steps, logprobs=5)
+    tokens = [t async for t, _ in engine.stream(req) if t is not None]
+    return tokens, req.lp_chosen, req.lp_top
+
+
+def assert_matches_reference(flat, prompt, tokens, chosen, top):
+    """Teacher forcing: the reference's row after the prompt's last token
+    scores the first served token, the next row the second, ..."""
+    rows = ref_log_probs(flat, prompt + tokens[:-1])[len(prompt) - 1:]
+    assert len(tokens) == len(chosen) == len(top) == len(rows)
+    for row, token, lp, record in zip(rows, tokens, chosen, top):
+        assert token == int(np.argmax(row))
+        assert abs(lp - row[token]) < TOL
+        for tid, tlp in record:
+            assert abs(tlp - row[tid]) < TOL
+
+
+def engine_of(tiny, **kw):
+    module, variables, _ = tiny
+    kw.setdefault("max_slots", 4)
+    kw.setdefault("max_seq", MAX_SEQ)
+    kw.setdefault("prefill_buckets", [32, 64, MAX_SEQ])
+    kw.setdefault("block_size", BS)
+    return GenerationEngine(module, variables, name="olmoe-test", **kw)
+
+
+# -- the model against the reference -----------------------------------------
+def test_full_forward_logits(tiny):
+    module, variables, flat = tiny
+    ids = prompt_of(48)
+    want = np.asarray(reference.logits(flat, ids, 2, 1e-5, **MODEL))
+    got = np.asarray(module.apply(variables, jnp.asarray([ids])))[0]
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def test_bucket_padding_changes_nothing_and_is_routed_nowhere(tiny):
+    module, variables, flat = tiny
+    ids = prompt_of(40)
+    want = np.asarray(reference.logits(flat, ids, 2, 1e-5, **MODEL))
+    padded = jnp.asarray([ids + [0] * 24, prompt_of(64, 5)])
+    (got, _), state = module.apply(
+        variables, padded, kv_lengths=jnp.asarray([40, 64]),
+        return_cache=True, mutable=["moe"])
+    np.testing.assert_allclose(np.asarray(got)[0, :40], want, atol=TOL,
+                               rtol=0)
+    pairs = np.asarray(module.routed_pairs(state))
+    assert pairs.shape == (2, 8)
+    assert (pairs.sum(axis=1) == (40 + 64) * 2).all()
+
+
+def test_router_chooses_the_references_experts(tiny):
+    module, variables, flat = tiny
+    ids = prompt_of(48)
+    routing = []
+    reference.logits(flat, ids, 2, 1e-5, routing=routing, **MODEL)
+    _, state = module.apply(
+        variables, jnp.asarray([ids]), capture_intermediates=(
+            lambda mdl, _: mdl.name == "router"), mutable=["intermediates"])
+    for i, want in enumerate(routing):
+        logits = state["intermediates"][f"layer_{i}"]["experts"]["router"][
+            "__call__"][0]
+        _, got = moe.route(logits, MODEL["experts_per_token"])
+        assert [set(r) for r in np.asarray(got).tolist()] \
+            == [set(r) for r in want.tolist()]
+
+
+@pytest.mark.parametrize("tokens", [24, 300])
+def test_grouped_and_streamed_expert_paths_agree(tokens):
+    rng = np.random.default_rng(tokens)
+    e, h, f, k = 8, 32, 48, 3
+    x = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
+    gate, up = (jnp.asarray(rng.standard_normal((e, h, f)) / h ** 0.5,
+                            jnp.float32) for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((e, f, h)) / f ** 0.5,
+                       jnp.float32)
+    probs, experts = moe.route(
+        jnp.asarray(rng.standard_normal((tokens, e)), jnp.float32), k)
+    valid = jnp.asarray(rng.random(tokens) < 0.8)
+    streamed = moe.experts_streamed(
+        x, gate, up, down, jnp.where(valid[:, None], probs, 0.0), experts)
+    grouped = moe.experts_grouped(x, gate, up, down, probs, experts, valid)
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(streamed),
+                               atol=1e-5, rtol=0)
+    assert not np.asarray(grouped)[~np.asarray(valid)].any()
+    # the dispatcher takes the path that fits the token count
+    chosen = moe.routed_experts(x, gate, up, down, probs, experts, valid)
+    np.testing.assert_allclose(np.asarray(chosen), np.asarray(streamed),
+                               atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("tokens,valid_share", [(24, None), (5, None),
+                                                (40, 0.6)])
+def test_touched_experts_kernel_agrees_with_the_grouped_path(tokens,
+                                                             valid_share):
+    """The Pallas kernel that serves a decode wave on the chip, run by the
+    interpreter here: lane-aligned widths, a third of the experts chosen
+    by no token (their blocks are never addressed), with and without
+    padding tokens.  float32 on both sides, so 1e-5 as above."""
+    rng = np.random.default_rng(tokens)
+    e, h, f, k = 12, 256, 128, 3
+    x = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
+    gate, up = (jnp.asarray(rng.standard_normal((e, h, f)) / h ** 0.5,
+                            jnp.float32) for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((e, f, h)) / f ** 0.5,
+                       jnp.float32)
+    logits = rng.standard_normal((tokens, e))
+    logits[:, ::3] -= 100.0
+    probs, experts = moe.route(jnp.asarray(logits, jnp.float32), k)
+    assert len(set(np.asarray(experts).reshape(-1).tolist())) == 8
+    valid = (None if valid_share is None
+             else jnp.asarray(rng.random(tokens) < valid_share))
+    grouped = moe.experts_grouped(x, gate, up, down, probs, experts, valid)
+    touched = moe.experts_touched(x, gate, up, down, probs, experts, valid,
+                                  interpret=True)
+    np.testing.assert_allclose(np.asarray(touched), np.asarray(grouped),
+                               atol=1e-5, rtol=0)
+    if valid is not None:
+        assert not np.asarray(touched)[~np.asarray(valid)].any()
+
+
+def test_the_kernel_serves_few_tokens_on_a_tpu_outside_a_mesh(monkeypatch):
+    """What `routed_experts` asks at trace time, and nothing else: the
+    backend, the ambient mesh and the shapes."""
+    from kfserving_tpu.ops import attention
+
+    x = jnp.zeros((24, 2048), jnp.bfloat16)
+    gate = jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16)
+    assert not moe._touched_kernel_serves(x, gate)  # the CPU
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    assert moe._touched_kernel_serves(x, gate)
+    assert not moe._touched_kernel_serves(
+        x, jax.ShapeDtypeStruct((8, 128, 64), jnp.float32))  # olmoe_tiny
+    assert not moe._touched_kernel_serves(
+        x, jax.ShapeDtypeStruct((8, 8192, 4096), jnp.bfloat16))  # VMEM
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("tp",))
+    with jax.set_mesh(mesh):
+        assert not moe._touched_kernel_serves(x, gate)
+
+
+def test_bfloat16_compute_is_inside_a_bound_that_8_bits_are_not(tiny):
+    """The served configuration computes in bfloat16 (8 bits of mantissa).
+    Its logits lie within 0.05 of the float32 reference's here; the same
+    model with its weights rounded to an 8-bit float (e4m3, 4 bits) is
+    0.15 and more away, so the bound tells the two apart."""
+    _, variables, flat = tiny
+    ids = prompt_of(48)
+    want = np.asarray(reference.logits(flat, ids, 2, 1e-5, **MODEL))
+    bf16 = OlmoeLM(olmoe_tiny(max_seq=MAX_SEQ, dtype=jnp.bfloat16))
+    got = np.asarray(bf16.apply(variables, jnp.asarray([ids])))[0]
+    eight = jax.tree.map(
+        lambda x: x.astype(jnp.float8_e4m3fn).astype(jnp.float32), variables)
+    module = OlmoeLM(olmoe_tiny(max_seq=MAX_SEQ))
+    coarse = np.asarray(module.apply(eight, jnp.asarray([ids])))[0]
+    bound = 0.05
+    assert np.abs(got - want).max() < bound < np.abs(coarse - want).max()
+
+
+def test_the_two_copies_of_the_reference_agree(tiny):
+    from chipbench.references import olmoe as benchmarks_copy
+
+    _, _, flat = tiny
+    ids = prompt_of(40)
+    ours = np.asarray(reference.logits(flat, ids, 2, 1e-5, **MODEL))
+    theirs = np.asarray(benchmarks_copy.logits(flat, ids, 2, 1e-5, **MODEL))
+    np.testing.assert_array_equal(ours, theirs)
+    # experts per token and the rotary base come from its own file
+    assert benchmarks_copy.settings() == {"experts_per_token": 8,
+                                          "rope_theta": 10000.0}
+
+
+# -- through the engine ------------------------------------------------------
+async def test_prefill_then_decode_through_the_paged_pool(tiny):
+    _, _, flat = tiny
+    prompt = prompt_of(37)
+    engine = engine_of(tiny, steps_per_call=4)
+    try:
+        tokens, chosen, top = await served(engine, prompt, 24)
+        stats = engine.stats()
+    finally:
+        await engine.close()
+    assert_matches_reference(flat, prompt, tokens, chosen, top)
+    # 6 calls of 4 steps over 2 layers; every row of the 4 slots routes
+    assert stats["moe_experts_touched_mean"] > 1
+    assert stats["moe_load_max_over_mean"] >= 1
+    assert stats["active_params"] == engine.module.config.param_counts()[
+        "active"]
+
+
+async def test_chunked_prefill(tiny):
+    _, _, flat = tiny
+    prompt = prompt_of(75)  # chunks of 32: two whole, one partial
+    engine = engine_of(tiny, prefill_chunk_tokens=32)
+    try:
+        tokens, chosen, top = await served(engine, prompt, 10)
+        assert engine.stats()["chunked_prefill"]["chunks_dispatched"] >= 3
+    finally:
+        await engine.close()
+    assert_matches_reference(flat, prompt, tokens, chosen, top)
+
+
+async def test_a_preempted_request_resumes_on_the_references_logits(tiny):
+    _, _, flat = tiny
+    prompts = [prompt_of(42, stride) for stride in (3, 5, 11)]
+    engine = engine_of(tiny, cache_blocks=10)  # 3 x (42 + 20) needs 12
+    try:
+        results = await asyncio.wait_for(asyncio.gather(*[
+            served(engine, p, 20) for p in prompts]), timeout=300)
+        assert engine.stats()["paged"]["preemptions"] >= 1
+    finally:
+        await engine.close()
+    for prompt, (tokens, chosen, top) in zip(prompts, results):
+        assert_matches_reference(flat, prompt, tokens, chosen, top)
+
+
+async def test_a_speculative_verify_step(tiny):
+    _, _, flat = tiny
+    prompt = [5, 9, 2, 7] * 6  # a suffix the n-gram proposer can replay
+    engine = engine_of(tiny, speculative={"tokens": 3})
+    try:
+        tokens, chosen, top = await served(engine, prompt, 16)
+        assert engine.stats()["speculative"]["waves"] >= 1
+    finally:
+        await engine.close()
+    assert_matches_reference(flat, prompt, tokens, chosen, top)
+
+
+# -- the engine's FLOP and byte model ----------------------------------------
+def test_active_parameters_of_the_benchmarked_shapes():
+    """8 layers at the published widths: 8 x 67.2 M (attention 16.8 M, 8
+    experts of 6.29 M, the router) + the 103 M head = 0.64 B multiplied by
+    a token, of 3.56 B held."""
+    from kfserving_tpu.models.olmoe import OlmoeConfig
+
+    counts = OlmoeConfig(num_layers=8).param_counts()
+    assert counts["total"] == 3_562_604_544
+    assert counts["active"] == 641_009_664
+    assert counts["per_expert"] == 3 * 2048 * 1024
+    assert counts["always_read"] == counts["active"] - 8 * 8 * 3 * 2048 * 1024
+
+
+def test_the_engines_model_counts_active_parameters(tiny):
+    from kfserving_tpu.models.decoder import DecoderLM, decoder_tiny
+
+    module, variables, _ = tiny
+    engine = engine_of(tiny)
+    counts = module.config.param_counts()
+    assert engine._n_params == counts["total"]
+    assert engine._flops_matmul_per_token == 2.0 * counts["active"]
+    assert engine._param_read_bytes == 4 * counts["always_read"]
+    assert engine._expert_read_bytes == 4 * counts["per_expert"]
+    engine.shutdown_nowait()
+    # the dense decoder's figures are what they were: every parameter
+    dense = DecoderLM(decoder_tiny(num_layers=2, max_seq=MAX_SEQ))
+    dense_vars = dense.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    engine = GenerationEngine(dense, dense_vars, max_slots=2,
+                              max_seq=MAX_SEQ, block_size=BS)
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(dense_vars))
+    assert engine._moe is None
+    assert engine._flops_matmul_per_token == 2.0 * n
+    assert engine._param_read_bytes == engine.param_bytes() == 4 * n
+    assert engine.stats()["active_params"] == n
+    engine.shutdown_nowait()
+
+
+# -- sharding ----------------------------------------------------------------
+def test_tp2_gives_the_logits_of_tp1_and_replicates_no_expert(tiny):
+    """QK-norm runs over all heads, which tp splits: the partitioner has to
+    sum the squares across the mesh, so GPT-2's test does not imply this."""
+    from jax.sharding import Mesh
+
+    from kfserving_tpu.parallel import shard_params
+    from kfserving_tpu.parallel.sharding import describe
+
+    module, variables, _ = tiny
+    ids = jnp.asarray([prompt_of(48), prompt_of(48, 5)])
+    want = np.asarray(module.apply(variables, ids))
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 1, 2),
+                ("dp", "sp", "tp"))
+    sharded = {"params": shard_params(variables["params"], mesh)}
+    with jax.set_mesh(mesh):
+        got = np.asarray(jax.jit(module.apply)(sharded, ids))
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+    specs = describe(variables["params"])
+    kernels = {k: v for k, v in specs.items()
+               if k.endswith(("experts/gate", "experts/up", "experts/down"))}
+    assert len(kernels) == 6
+    assert all("tp" in spec for spec in kernels.values()), kernels
+    down = sharded["params"]["layer_0"]["experts"]["down"]
+    assert down.sharding.shard_shape(down.shape) == (8, 32, 128)
