@@ -489,8 +489,13 @@ def phase_generate(config: dict, platform: str, env: dict = None) -> dict:
 
     # Which attention ran is read from the dispatchers' trace-time lines.
     decode = {path for path, _, _ in paths if path.endswith("_paged")}
+    # The kernel reads whole lane tiles: a block, and one heads shard's
+    # row of the pool (H*D over tp), are multiples of 128.
+    shard_width = (config["arch_kwargs"]["hidden_size"]
+                   // config.get("mesh", {}).get("tp", 1))
     want = "pallas_paged" if (platform == "tpu"
-                              and config["block_size"] % 128 == 0) \
+                              and config["block_size"] % 128 == 0
+                              and shard_width % 128 == 0) \
         else "xla_paged"
     check(decode == {want}, f"decode attention {decode}, expected {want}")
     prefill = [(path, shapes) for path, q, shapes in paths
@@ -513,7 +518,8 @@ import numpy as np
 import jax, jax.numpy as jnp
 from kfserving_tpu.ops.attention import _xla_attention
 from kfserving_tpu.ops.paged_attention import (
-    paged_attention_tpu, paged_attention_xla)
+    paged_attention_tpu, paged_attention_xla, paged_write,
+    paged_write_sharded, pool_shape)
 from kfserving_tpu.ops.pallas_attention import flash_attention
 
 shapes, platform = json.loads(sys.argv[1]), sys.argv[2]
@@ -534,7 +540,8 @@ def err(a, b):
 out = {}
 b, h, d = shapes["slots"], shapes["heads"], shapes["head_dim"]
 nb, bs, mb = shapes["blocks"], shapes["block_size"], shapes["blocks_per_slot"]
-q, pk, pv = normal(b, 1, h, d), normal(nb, bs, h, d), normal(nb, bs, h, d)
+pool = pool_shape(nb, bs, h, d)  # one minor dimension of all heads
+q, pk, pv = normal(b, 1, h, d), normal(*pool), normal(*pool)
 lengths = rng.integers(1, mb * bs + 1, size=b).astype(np.int32)
 lengths[0], lengths[-1] = 1, mb * bs
 table = np.full((b, mb), -1, np.int32)
@@ -545,6 +552,15 @@ table, lengths = jnp.asarray(table), jnp.asarray(lengths)
 out["paged"] = err(
     paged_attention_tpu(q, pk, pv, table, lengths, interpret=interpret),
     paged_attention_xla(q, pk, pv, table, lengths))
+# The decode step's write: each row at its length, the kernel against the
+# scatter (exact: both only move values).
+k_step, v_step = normal(b, h, d), normal(b, h, d)
+at = lengths - 1
+want = paged_write(pk, pv, k_step, v_step, table, at)
+got = paged_write_sharded(pk, pv, k_step, v_step,
+                          table[jnp.arange(b), at // bs], at % bs,
+                          interpret=interpret)
+out["paged_write"] = max(err(got[0], want[0]), err(got[1], want[1]))
 L = shapes["prefill"]
 q, k, v = normal(1, L, h, d), normal(1, L, h, d), normal(1, L, h, d)
 causal = jnp.tril(jnp.ones((L, L), jnp.bool_))[None, None]

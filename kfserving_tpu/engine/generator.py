@@ -16,7 +16,7 @@ is the TPU-first design for that:
   change a shape, so XLA never recompiles (the continuous-batching
   analogue of the engine's batch buckets).
 - **paged mode** (`block_size`): the dense pool becomes a shared block
-  pool [NB, BS, H, D] + per-slot block tables — HBM scales with
+  pool [NB, BS, H*D] + per-slot block tables — HBM scales with
   resident tokens (size it with `cache_blocks`), identical prompt
   prefixes share blocks via a chain-hash index, pool pressure queues
   admissions, and block release is deferred past in-flight waves (the
@@ -306,11 +306,12 @@ class GenerationEngine:
         # -- paged vs dense cache layout -------------------------------
         # Dense (block_size=None): per-slot [S, max_seq, H, D] — every
         # slot burns max_seq HBM whatever it holds.  Paged: a shared
-        # block pool [NB, BS, H, D] + per-slot block tables — HBM
-        # scales with resident tokens and identical prompt prefixes
-        # share blocks (VERDICT r4 weak #5; the vLLM PagedAttention
-        # idea, TPU-shaped: static pool/table shapes, OOB-sentinel
-        # scatters, XLA gather attention with a Pallas path to come).
+        # block pool [NB, BS, H*D] (ops/paged_attention.py owns the
+        # layout) + per-slot block tables — HBM scales with resident
+        # tokens and identical prompt prefixes share blocks (VERDICT
+        # r4 weak #5; the vLLM PagedAttention idea, TPU-shaped: static
+        # pool/table shapes, OOB-sentinel scatters, a Pallas decode
+        # kernel that walks the table, XLA gather attention elsewhere).
         self.block_size = int(block_size) if block_size else None
         if self.block_size is not None:
             bs = self.block_size
@@ -330,8 +331,10 @@ class GenerationEngine:
             # traffic rarely needs S full-length slots at once.
             self.num_blocks = int(cache_blocks or
                                   self.max_slots * self.blocks_per_slot)
-            pool_shape = (self.num_blocks, bs, cfg.num_heads,
-                          cfg.head_dim)
+            from kfserving_tpu.ops import paged_attention
+
+            pool_shape = paged_attention.pool_shape(
+                self.num_blocks, bs, cfg.num_heads, cfg.head_dim)
             self._cache_shape = pool_shape
             self._caches = [
                 (jnp.zeros(pool_shape, cache_dtype),
@@ -531,11 +534,14 @@ class GenerationEngine:
             # (parallel/sharding.py transformer_rules) — cache writes
             # and decode attention stay device-local per head group;
             # the per-layer psum after the out-projection is the only
-            # collective.
+            # collective.  A paged pool's last axis is H*D: splitting
+            # it over tp gives the same head groups.
             tp = mesh.shape.get("tp", 1)
             heads_axis = "tp" if cfg.num_heads % max(tp, 1) == 0 else None
-            sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, heads_axis, None))
+            spec = ((None, None, heads_axis, None)
+                    if self.block_size is None
+                    else (None, None, heads_axis))
+            sharding = NamedSharding(mesh, PartitionSpec(*spec))
             self._caches = [
                 (jax.device_put(k, sharding), jax.device_put(v, sharding))
                 for k, v in self._caches
@@ -707,6 +713,13 @@ class GenerationEngine:
                                             kv_lengths=lengths,
                                             return_cache=True,
                                             logit_positions=lengths - 1)
+            if paged:
+                # Leave the program as the pool stores them,
+                # [B, L, H*D]: the insert is then a scatter of whole
+                # blocks, where [B, L, H, D] results (L minor-most on
+                # the chip) would be transposed on their way in.
+                caches = [tuple(x.reshape(x.shape[:2] + (-1,))
+                                for x in kv) for kv in caches]
             last = logits[:, 0]
             first_tokens = sample(last, temps, top_ks, top_ps, seeds,
                                   lengths)
@@ -1666,17 +1679,16 @@ class GenerationEngine:
         # chains to the prefix index — from here the blocks are
         # ordinary shareable device-resident prefix state.
         jnp = self._jnp
-        k0 = self._caches[0][0]
-        bs, H, D = (int(x) for x in k0.shape[1:])
-        dtype = np.dtype(k0.dtype)
-        per = bs * H * D * dtype.itemsize
+        _, bs, width = self._cache_shape   # a block is [BS, H*D]
+        dtype = np.dtype(self._cache_dtype)
+        per = bs * width * dtype.itemsize
         for i in range(0, len(primaries), 32):
             grp = primaries[i:i + 32]
             padded = 1
             while padded < len(grp):
                 padded *= 2
-            layers = [(np.zeros((1, padded * bs, H, D), dtype),
-                       np.zeros((1, padded * bs, H, D), dtype))
+            layers = [(np.zeros((1, padded * bs, width), dtype),
+                       np.zeros((1, padded * bs, width), dtype))
                       for _ in self._caches]
             dest = np.full((1, padded), -1, np.int32)
             for j, (ch, blk) in enumerate(grp):
@@ -1685,11 +1697,11 @@ class GenerationEngine:
                 for li, (k_new, v_new) in enumerate(layers):
                     off = li * 2 * per
                     k_new[0, j * bs:(j + 1) * bs] = np.frombuffer(
-                        pay, dtype, count=bs * H * D,
-                        offset=off).reshape(bs, H, D)
+                        pay, dtype, count=bs * width,
+                        offset=off).reshape(bs, width)
                     v_new[0, j * bs:(j + 1) * bs] = np.frombuffer(
-                        pay, dtype, count=bs * H * D,
-                        offset=off + per).reshape(bs, H, D)
+                        pay, dtype, count=bs * width,
+                        offset=off + per).reshape(bs, width)
             self._note_program("kv_faultback", padded)
             self._caches = self._insert(
                 self._caches,
@@ -2403,10 +2415,13 @@ class GenerationEngine:
         write the donated pool), and nothing was donated or ran, so
         the group can simply be taken again in smaller dispatches.
         Seen on the v5e once launches stopped waiting on a parameter
-        transfer: with gpt2-large's parameters and pool resident (6.0
-        GiB) and the 16-step decode program's 9.2 GiB of temporaries
-        reserved, 0.5 of the chip's 15.75 GiB are left, which hold a
-        (4, 512) dispatch's outputs and not an (8, 512) one's."""
+        transfer, while the decode kernel read a padded twin of every
+        layer's pool: with gpt2-large's parameters and pool resident
+        (6.0 GiB) and the 16-step decode program's 9.2 GiB of
+        temporaries reserved, 0.5 of the chip's 15.75 GiB were left,
+        which held a (4, 512) dispatch's outputs and not an (8, 512)
+        one's.  With the pool stored [NB, BS, H*D] that program keeps
+        1.5 GiB and the refusal has not been seen again."""
         if "RESOURCE_EXHAUSTED" not in str(exc) or rows < 2:
             return False
         padded = 1 << (rows - 1).bit_length()  # the launch's row bucket

@@ -76,8 +76,9 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
     new_cache = None
     if cache is not None and len(cache) == 3:
         # Paged cache: cache = (pool_k, pool_v, block_table) —
-        # shared block pools [NB, BS, H, D] plus this batch's
-        # [B, MB] table (engine/generator.py paged mode; the
+        # shared block pools [NB, BS, H*D] (ops/paged_attention.py
+        # owns the layout and reshapes q, k, v at its edge) plus this
+        # batch's [B, MB] table (engine/generator.py paged mode; the
         # static 3-vs-2 tuple arity picks the branch at trace
         # time).  The table flows in per dispatch and is not
         # returned — only the written pools are.  Lq == 1 is the

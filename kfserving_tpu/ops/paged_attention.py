@@ -2,13 +2,21 @@
 
 The dense slot pool ([S, max_seq, H, D] per layer) burns the same HBM
 for a 40-token chat as for a full-context one (VERDICT r4 weak #5).
-Paging replaces it with a shared block pool ([num_blocks, block_size,
-H, D]) plus a per-slot block table — HBM scales with tokens actually
-resident, and identical prompt prefixes can share blocks (prefix
-reuse).  This is the TPU analogue of vLLM's PagedAttention; the
-reference has no serving-cache concept at all (its `Memory` field is
-a k8s resource quantity, reference
+Paging replaces it with a shared block pool plus a per-slot block
+table — HBM scales with tokens actually resident, and identical prompt
+prefixes can share blocks (prefix reuse).  This is the TPU analogue of
+vLLM's PagedAttention; the reference has no serving-cache concept at
+all (its `Memory` field is a k8s resource quantity, reference
 pkg/apis/serving/v1alpha1/trained_model.go:68-69).
+
+The pool's layout is this module's to decide (`pool_shape`): one minor
+dimension of all heads, [num_blocks, block_size, H*D].  A pool stored
+[.., H, D] tiles its two minor dimensions, and 20 heads of 64 fill a
+bfloat16 tile of 16 x 128 to 3.2 times their bytes in the row-major
+layout a Mosaic kernel asks for; H*D pads for no head geometry.
+Row-major, [BS, H, D] and [BS, H*D] are the same bytes, so a block's
+payload outside the device (host tier, hand-off) does not know.
+Activations stay [.., H, D] and are reshaped at this module's edge.
 
 Two implementations with one contract:
 
@@ -21,10 +29,13 @@ Two implementations with one contract:
   holding valid tokens are read, so a short sequence in a long-context
   pool costs its length, not the pool width.  The dispatcher picks it
   from shapes and the backend; under a mesh it runs per heads shard.
+  Where it serves, the decode step's write is a Mosaic call too
+  (paged_write_tpu), so nothing of XLA's own touches a pool inside the
+  decode program.
 
 Contract (per layer):
     q           [B, 1, H, D]   current step's query
-    pool_k/v    [NB, BS, H, D] shared block pools
+    pool_k/v    [NB, BS, H*D]  shared block pools (`pool_shape`)
     block_table [B, MB] int32  block ids per slot, -1 = unallocated
     lengths     [B] int32      valid tokens INCLUDING the current
                                step's write
@@ -32,6 +43,7 @@ Returns [B, 1, H, D].
 """
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -39,69 +51,90 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from kfserving_tpu.ops import attention
+
 _NEG_INF = -1e30
 
 
+def pool_shape(num_blocks: int, block_size: int, heads: int,
+               head_dim: int):
+    """Shape of one layer's K (or V) block pool."""
+    return (num_blocks, block_size, heads * head_dim)
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one tile of `dtype`: 8 of 32 bits, 16 of 16."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scratch, l_scratch, acc_scratch, *,
-                  block_size: int, scale: float, num_heads: int):
+                  q_scratch, m_scratch, l_scratch, acc_scratch, *,
+                  block_size: int, scale: float, head_dim: int):
     """One batch row's online-softmax walk over its block table, all
-    heads per program (head-batched dot_generals keep the block
-    shapes' trailing dims equal to the array dims — Mosaic's tiling
-    requirement).  Grid: (B, MB) with the block axis innermost and
-    sequential; the index maps clamp the pool-block index so programs
-    past a row's valid length re-DMA an already-resident block —
-    invalid blocks cost neither HBM traffic nor FLOPs (the flash
-    kernel's kv_lengths clamp, applied to a block table).  The
-    gathered [B, MB*BS, H, D] view the XLA fallback materializes
-    every step never exists here."""
+    heads per program, on blocks [BS, H*D] as the pool stores them.
+    The per-head reduction is two MXU products and no [.., H, D] view:
+    the query becomes block-diagonal, `q_bd[r, c] = q[c]` where column
+    c is one of head r's, so `q_bd . K^T` is every head's scores
+    [H_pad, BS]; `p . V` is [H_pad, H*D], of which head r's columns of
+    row r are the answer, taken once at the last block.  Grid: (B, MB)
+    with the block axis innermost and sequential; the index maps clamp
+    the pool-block index so programs past a row's valid length re-DMA
+    an already-resident block — invalid blocks cost neither HBM traffic
+    nor FLOPs (the flash kernel's kv_lengths clamp, applied to a block
+    table).  The gathered [B, MB*BS, H, D] view the XLA fallback
+    materializes every step never exists here."""
     b_idx = pl.program_id(0)
     j_idx = pl.program_id(1)
     num_j = pl.num_programs(1)
     row_len = len_ref[b_idx]
+    h_pad, hd = acc_scratch.shape
+
+    def own_columns():
+        # [h_pad, hd] bool: column c belongs to head r.
+        row = jax.lax.broadcasted_iota(jnp.int32, (h_pad, hd), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (h_pad, hd), 1)
+        return (col >= row * head_dim) & (col < (row + 1) * head_dim)
 
     @pl.when(j_idx == 0)
     def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, _NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
-
-    h = num_heads
+        # Select in float32 and cast: Mosaic refuses the relayout of
+        # a 16-bit select here.
+        q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (h_pad, hd))
+        q_scratch[...] = jnp.where(own_columns(), q,
+                                   0.0).astype(q_scratch.dtype)
+        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
+        l_scratch[...] = jnp.zeros_like(l_scratch)
+        acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
     def _run_block():
-        # Decode attention is a per-head matvec — bandwidth-bound, so
-        # everything here is VPU elementwise+reduce (Mosaic's in-kernel
-        # dot does not take batched dimension numbers).  Scores keep
-        # the [bs, h] orientation end-to-end: reductions run over the
-        # major axis and no relayout-heavy transposes are needed.
-        q = q_ref[0, 0].astype(jnp.float32)               # [h, d]
-        k = k_ref[0].astype(jnp.float32)                  # [bs, h, d]
-        s = jnp.sum(k * q[None], axis=-1) * scale         # [bs, h]
+        k = k_ref[0].astype(q_scratch.dtype)              # [bs, hd]
+        s = jax.lax.dot_general(
+            q_scratch[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [h_pad, bs]
         pos = j_idx * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_size, h), 0)
+            jnp.int32, s.shape, 1)
         s = jnp.where(pos < row_len, s, _NEG_INF)
-        m_prev = m_scratch[0:1, 0:h]                      # [1, h]
-        l_prev = l_scratch[0:1, 0:h]
-        m_cur = jnp.max(s, axis=0, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                            # [bs, h]
-        alpha = jnp.exp(m_prev - m_new)                   # [1, h]
-        l_new = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)                  # [bs, h, d]
-        pv = jnp.sum(p[:, :, None] * v, axis=0)           # [h, d]
-        alpha_col = jnp.swapaxes(alpha, 0, 1)             # [h, 1]
-        acc_scratch[0:h] = acc_scratch[0:h] * alpha_col + pv
-        m_scratch[0:1, 0:h] = m_new
-        l_scratch[0:1, 0:h] = l_new
+        m_prev = m_scratch[...]                           # [h_pad, 1]
+        m_new = jnp.maximum(m_prev,
+                            jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                            # [h_pad, bs]
+        alpha = jnp.exp(m_prev - m_new)                   # [h_pad, 1]
+        l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
+            p, axis=1, keepdims=True)
+        v = v_ref[0]                                      # [bs, hd]
+        pv = jnp.dot(p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)  # [h_pad, hd]
+        acc_scratch[...] = acc_scratch[...] * alpha + pv
+        m_scratch[...] = m_new
 
     # Blocks wholly past the row's length never run.
     pl.when(j_idx * block_size < row_len)(_run_block)
 
     @pl.when(j_idx == num_j - 1)
     def _finalize():
-        l_col = jnp.swapaxes(l_scratch[0:1, 0:h], 0, 1)   # [h, 1]
-        o_ref[0, 0] = (acc_scratch[0:h]
-                       / jnp.maximum(l_col, 1e-30)).astype(o_ref.dtype)
+        out = acc_scratch[...] / jnp.maximum(l_scratch[...], 1e-30)
+        o_ref[0] = jnp.sum(jnp.where(own_columns(), out, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -113,14 +146,15 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     sequence in a long-context pool costs its length, not the pool
     width)."""
     b, lq, h, d = q.shape
-    nb, bs, _, _ = pool_k.shape
+    nb, bs, hd = pool_k.shape
+    assert lq == 1 and hd == h * d, (q.shape, pool_k.shape)
     mb = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
     table_flat = jnp.maximum(block_table, 0).reshape(-1)
     lengths = lengths.astype(jnp.int32)
 
     def q_index(bi, ji, table, lens):
-        return (bi, 0, 0, 0)
+        return (bi, 0, 0)
 
     def kv_index(bi, ji, table, lens):
         # Clamp the walk to the row's last VALID table entry: programs
@@ -129,60 +163,160 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
         last = jnp.maximum(
             jax.lax.div(lens[bi] - 1, jnp.int32(bs)), 0)
         jj = jnp.minimum(ji, last)
-        return (table[bi * mb + jj], 0, 0, 0)
+        return (table[bi * mb + jj], 0, 0)
 
-    # Stats scratch is lane-padded to 128 (Mosaic tiling); only
-    # column 0 is used.
-    h_pad = max(8, -(-h // 8) * 8)
+    # The products run in the pool's precision when the query shares
+    # it (bfloat16 x bfloat16 with float32 accumulation is what a
+    # bfloat16 configuration states); rows pad to a whole sublane tile
+    # of that type.
+    compute = jnp.promote_types(q.dtype, pool_k.dtype)
+    h_pad = -(-h // _sublanes(compute)) * _sublanes(compute)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d), q_index),
-            pl.BlockSpec((1, bs, h, d), kv_index),
-            pl.BlockSpec((1, bs, h, d), kv_index),
+            pl.BlockSpec((1, 1, hd), q_index),
+            pl.BlockSpec((1, bs, hd), kv_index),
+            pl.BlockSpec((1, bs, hd), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, h, d), q_index),
+        out_specs=pl.BlockSpec((1, 1, hd), q_index),
         scratch_shapes=[
-            pltpu.VMEM((h_pad, 128), jnp.float32),
-            pltpu.VMEM((h_pad, 128), jnp.float32),
-            pltpu.VMEM((h_pad, d), jnp.float32),
+            pltpu.VMEM((h_pad, hd), compute),
+            pltpu.VMEM((h_pad, 1), jnp.float32),
+            pltpu.VMEM((h_pad, 1), jnp.float32),
+            pltpu.VMEM((h_pad, hd), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, block_size=bs,
-                               scale=scale, num_heads=h)
+                               scale=scale, head_dim=d)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        interpret=interpret,
+    )(table_flat, lengths, q.reshape(b, 1, hd), pool_k, pool_v)
+    return out.reshape(b, 1, h, d)
+
+
+def _write_kernel(blk_ref, off_ref, k_ref, v_ref, pool_k_in, pool_v_in,
+                  pool_k, pool_v, k_tiles, v_tiles, sems, *, rows: int,
+                  sublanes: int):
+    """A decode step's rows into the pools, in place (the outputs alias
+    the inputs, all four in HBM): row r's sublane tile, the `sublanes`
+    positions of its block around its offset, comes into VMEM, takes
+    the row, and goes back.  A 16-bit pool packs two positions into
+    each word, so one position is not something a DMA can address: the
+    tile is.  Rows to drop (block -1) move nothing.  One program, every
+    copy in flight at once."""
+    del pool_k_in, pool_v_in  # the same buffers as pool_k, pool_v
+
+    def copies(r, to_pool: bool):
+        start = pl.multiple_of(
+            (off_ref[r] // sublanes) * sublanes, sublanes)
+        out = []
+        for i, (pool, tiles) in enumerate(((pool_k, k_tiles),
+                                           (pool_v, v_tiles))):
+            hbm = pool.at[blk_ref[r], pl.ds(start, sublanes), :]
+            src, dst = (tiles.at[r], hbm) if to_pool else (hbm,
+                                                           tiles.at[r])
+            out.append(pltpu.make_async_copy(src, dst, sems.at[i, r]))
+        return out
+
+    def each_live_row(body):
+        def row(r, _):
+            pl.when(blk_ref[r] >= 0)(functools.partial(body, r))
+
+        jax.lax.fori_loop(0, rows, row, None)
+
+    def fetch(r):
+        for copy in copies(r, to_pool=False):
+            copy.start()
+
+    def merge(r):
+        for copy in copies(r, to_pool=False):
+            copy.wait()
+        at = jax.lax.broadcasted_iota(jnp.int32, k_tiles.shape[1:], 0) \
+            == off_ref[r] % sublanes
+        for tiles, step in ((k_tiles, k_ref), (v_tiles, v_ref)):
+            # Select in float32 (a 16-bit select asks Mosaic for a
+            # relayout it refuses); the round trip is exact.
+            row = jnp.broadcast_to(step[r].astype(jnp.float32),
+                                   tiles.shape[1:])
+            tiles[r] = jnp.where(at, row, tiles[r].astype(
+                jnp.float32)).astype(tiles.dtype)
+        for copy in copies(r, to_pool=True):
+            copy.start()
+
+    def land(r):
+        for copy in copies(r, to_pool=True):
+            copy.wait()
+
+    each_live_row(fetch)
+    each_live_row(merge)
+    each_live_row(land)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_write_tpu(pool_k, pool_v, k_step, v_step, blocks, offsets,
+                    interpret: bool = False):
+    """Pallas decode-step write: k/v [B, H*D] into the pools at
+    (blocks [B], offsets [B]), block -1 dropping its row.  Same result
+    as `paged_write`'s scatter; it exists because the pools' only users
+    inside the decode program are then Mosaic calls, which read and
+    write them where they are.  Left to XLA, a scatter into a pool that
+    fits VMEM (gpt2-large's 47 MB) has the whole pool prefetched there
+    and copied back every step."""
+    rows, hd = k_step.shape
+    sublanes = _sublanes(pool_k.dtype)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    step = pl.BlockSpec((rows, 1, hd), lambda i, blk, off: (0, 0, 0))
+    tiles = pltpu.VMEM((rows, sublanes, hd), pool_k.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(1,),
+        in_specs=[step, step, hbm, hbm], out_specs=[hbm, hbm],
+        scratch_shapes=[tiles, tiles,
+                        pltpu.SemaphoreType.DMA((2, rows))])
+    kernel = functools.partial(_write_kernel, rows=rows,
+                               sublanes=sublanes)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, lq, h, d), q.dtype),
-        interpret=interpret,
-    )(table_flat, lengths, q, pool_k, pool_v)
+        out_shape=[jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
+                   jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype)],
+        input_output_aliases={4: 0, 5: 1}, interpret=interpret,
+    )(blocks.astype(jnp.int32), offsets.astype(jnp.int32),
+      k_step.astype(pool_k.dtype).reshape(rows, 1, hd),
+      v_step.astype(pool_v.dtype).reshape(rows, 1, hd), pool_k, pool_v)
+
+
+def _kernels_serve(block_size: int, heads: int, head_dim: int) -> bool:
+    """Whether the Pallas kernels take these shapes, asked at trace
+    time: on a TPU, with block_size and the H*D of one heads shard both
+    lane multiples, so that every block is whole tiles (the XLA
+    formulations serve the rest, and the CPU).
+    KFS_DISABLE_PAGED_KERNEL=1 forces the XLA path — the on-chip A/B
+    kill-switch, mirroring the flash kernel's KFS_DISABLE_FLASH.
+    NOTE: read inside the jitted decode function, so once, at the
+    first decode compile (effectively process start); flipping it later
+    has no effect in-process — restart the replica to switch paths
+    (same semantics as KFS_DISABLE_FLASH)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    shards = 1
+    if not mesh.empty and attention.mesh_axis(mesh, "tp", heads):
+        shards = mesh.shape["tp"]
+    return (attention._tpu_backend() and block_size % 128 == 0
+            and (heads // shards * head_dim) % 128 == 0
+            and os.environ.get("KFS_DISABLE_PAGED_KERNEL", "")
+            in ("", "0", "false"))
 
 
 def paged_attention(q, pool_k, pool_v, block_table, lengths):
-    """Dispatcher: the Pallas kernel on TPU when the shapes meet its
-    assumptions (single-token query, block_size a lane multiple,
-    head_dim a 64-multiple like the flash gate, heads within the
-    stats scratch's 128 lanes), XLA gather otherwise (CPU tests, odd
-    shapes).  KFS_DISABLE_PAGED_KERNEL=1 forces the XLA path — the
-    on-chip A/B kill-switch, mirroring the flash kernel's
-    KFS_DISABLE_FLASH.  NOTE: this branch runs at TRACE time inside
-    the jitted decode function, so the env var is read once at the
-    first decode compile (effectively process start); flipping it
-    later has no effect in-process — restart the replica to switch
-    paths (same semantics as KFS_DISABLE_FLASH)."""
-    import os
-
-    from kfserving_tpu.ops.attention import _tpu_backend, log_dispatch
-
-    bs = pool_k.shape[1]
-    d = q.shape[-1]
-    h = q.shape[2]
-    use_kernel = (_tpu_backend() and q.shape[1] == 1 and h <= 128
-                  and bs % 128 == 0 and d % 64 == 0
-                  and os.environ.get("KFS_DISABLE_PAGED_KERNEL", "")
-                  in ("", "0", "false"))
-    log_dispatch("pallas_paged" if use_kernel else "xla_paged",
-                 q=q.shape, pool=pool_k.shape, table=block_table.shape)
+    """Dispatcher: the Pallas kernel where `_kernels_serve` says so and
+    the query is a single token, XLA gather otherwise (CPU tests, odd
+    shapes).  Runs at trace time inside the jitted decode function."""
+    use_kernel = q.shape[1] == 1 and _kernels_serve(pool_k.shape[1],
+                                                    *q.shape[2:])
+    attention.log_dispatch(
+        "pallas_paged" if use_kernel else "xla_paged",
+        q=q.shape, pool=pool_k.shape, table=block_table.shape)
     if use_kernel:
         return paged_attention_sharded(q, pool_k, pool_v, block_table,
                                        lengths)
@@ -194,33 +328,52 @@ def paged_attention_sharded(q, pool_k, pool_v, block_table, lengths,
     """`paged_attention_tpu`, under `shard_map` when the caller runs
     inside a mesh (`jax.set_mesh`): Mosaic kernels cannot be
     partitioned automatically, and per-head attention needs no
-    collective — q and the pools split on heads over ``tp`` exactly as
-    the engine shards the pool; block table and lengths replicate."""
-    from kfserving_tpu.ops.attention import mesh_axis
-
+    collective — q splits on heads over ``tp`` and the pools on H*D,
+    which is the same head groups and how the engine shards the pool;
+    block table and lengths replicate."""
     kernel = functools.partial(paged_attention_tpu, interpret=interpret)
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return kernel(q, pool_k, pool_v, block_table, lengths)
-    spec = P(None, None, mesh_axis(mesh, "tp", q.shape[2]), None)
+    tp = attention.mesh_axis(mesh, "tp", q.shape[2])
+    heads, pool = P(None, None, tp, None), P(None, None, tp)
     return jax.shard_map(
-        kernel, in_specs=(spec, spec, spec, P(), P()), out_specs=spec,
+        kernel, in_specs=(heads, pool, pool, P(), P()), out_specs=heads,
         check_vma=False)(q, pool_k, pool_v, block_table, lengths)
 
 
-def paged_attention_xla(q, pool_k, pool_v, block_table, lengths):
-    b, lq, h, d = q.shape
-    nb, bs, _, _ = pool_k.shape
-    mb = block_table.shape[1]
-    # Clamp -1 (unallocated) to 0: masked out below, and XLA's gather
-    # clamps anyway — explicit is better than relying on OOB behavior.
-    table = jnp.maximum(block_table, 0)
-    # [B, MB, BS, H, D] -> [B, MB*BS, H, D]
-    k = pool_k[table].reshape(b, mb * bs, h, d)
-    v = pool_v[table].reshape(b, mb * bs, h, d)
-    positions = jnp.arange(mb * bs)[None, :]
-    mask = (positions < lengths[:, None])[:, None, None, :]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+def paged_write_sharded(pool_k, pool_v, k_step, v_step, blocks, offsets,
+                        interpret: bool = False):
+    """`paged_write_tpu` for k/v [B, H, D], per heads shard inside a
+    mesh like `paged_attention_sharded`."""
+    rows, h = k_step.shape[:2]
+    kernel = functools.partial(paged_write_tpu, interpret=interpret)
+    k_step, v_step = k_step.reshape(rows, -1), v_step.reshape(rows, -1)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return kernel(pool_k, pool_v, k_step, v_step, blocks, offsets)
+    tp = attention.mesh_axis(mesh, "tp", h)
+    pool, step = P(None, None, tp), P(None, tp)
+    return jax.shard_map(
+        kernel, in_specs=(pool, pool, step, step, P(), P()),
+        out_specs=(pool, pool), check_vma=False)(
+            pool_k, pool_v, k_step, v_step, blocks, offsets)
+
+
+def _gathered(pool, table, heads: int):
+    """A batch's blocks as one contiguous [B, MB*BS, H, D] view.
+    -1 (unallocated) clamps to block 0: the callers mask it out, and
+    XLA's gather clamps anyway — explicit is better than relying on
+    OOB behavior."""
+    b, mb = table.shape
+    bs = pool.shape[1]
+    return pool[jnp.maximum(table, 0)].reshape(b, mb * bs, heads, -1)
+
+
+def _masked_attention(q, k, v, mask):
+    """softmax(q.k / sqrt(D)) . v in float32 over [B, K, H, D] keys,
+    `mask` [B, 1|H, Lq, K] true where a query may look."""
+    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -228,6 +381,15 @@ def paged_attention_xla(q, pool_k, pool_v, block_table, lengths):
     out = jnp.einsum("bhqk,bkhd->bqhd", weights,
                      v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def paged_attention_xla(q, pool_k, pool_v, block_table, lengths):
+    h = q.shape[2]
+    k = _gathered(pool_k, block_table, h)
+    v = _gathered(pool_v, block_table, h)
+    positions = jnp.arange(k.shape[1])[None, :]
+    mask = (positions < lengths[:, None])[:, None, None, :]
+    return _masked_attention(q, k, v, mask)
 
 
 def paged_write(pool_k, pool_v, k_step, v_step, block_table,
@@ -261,14 +423,18 @@ def paged_write(pool_k, pool_v, k_step, v_step, block_table,
     if chunked:
         rows = rows[:, None]
     blocks = block_table[rows, jnp.minimum(block_idx, mb - 1)]
+    dropped = (blocks < 0) | (block_idx >= mb)
+    if not chunked and _kernels_serve(bs, *k_step.shape[1:]):
+        return paged_write_sharded(pool_k, pool_v, k_step, v_step,
+                                   jnp.where(dropped, -1, blocks), offs)
     # -1 (unallocated) or past-the-table positions -> OOB sentinel so
     # mode="drop" discards the write.
-    blocks = jnp.where((blocks < 0) | (block_idx >= mb),
-                       pool_k.shape[0], blocks)
+    blocks = jnp.where(dropped, pool_k.shape[0], blocks)
+    flat = positions.shape + pool_k.shape[2:]   # [B(, L), H*D]
     pool_k = pool_k.at[blocks, offs].set(
-        k_step.astype(pool_k.dtype), mode="drop")
+        k_step.reshape(flat).astype(pool_k.dtype), mode="drop")
     pool_v = pool_v.at[blocks, offs].set(
-        v_step.astype(pool_v.dtype), mode="drop")
+        v_step.reshape(flat).astype(pool_v.dtype), mode="drop")
     return pool_k, pool_v
 
 
@@ -287,22 +453,12 @@ def paged_prefill_attention_xla(q, pool_k, pool_v, block_table,
                                sentinel (their output is discarded,
                                the mask keeps them finite)
     Returns [B, L, H, D]."""
-    b, lq, h, d = q.shape
-    nb, bs, _, _ = pool_k.shape
-    mb = block_table.shape[1]
-    table = jnp.maximum(block_table, 0)
-    k = pool_k[table].reshape(b, mb * bs, h, d)
-    v = pool_v[table].reshape(b, mb * bs, h, d)
-    key_pos = jnp.arange(mb * bs)[None, None, :]          # [1, 1, K]
+    h = q.shape[2]
+    k = _gathered(pool_k, block_table, h)
+    v = _gathered(pool_v, block_table, h)
+    key_pos = jnp.arange(k.shape[1])[None, None, :]       # [1, 1, K]
     mask = (key_pos <= q_positions[:, :, None])[:, None]  # [B,1,L,K]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    weights = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", weights,
-                     v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    return _masked_attention(q, k, v, mask)
 
 
 def paged_insert(pool_k, pool_v, k_new, v_new, dest_blocks, lengths):
@@ -313,16 +469,15 @@ def paged_insert(pool_k, pool_v, k_new, v_new, dest_blocks, lengths):
     prefix-cache hits whose blocks already hold the data).  Positions
     beyond lengths[i] within a written block are harmless garbage —
     reads mask by length."""
-    b, l, h, d = k_new.shape
+    b, l = k_new.shape[:2]
     bs = pool_k.shape[1]
     chunks = l // bs
     assert chunks * bs == l, "prefill bucket must be block-aligned"
     dest = jnp.where(dest_blocks < 0, pool_k.shape[0], dest_blocks)
-    k_c = k_new.reshape(b * chunks, bs, h, d)
-    v_c = v_new.reshape(b * chunks, bs, h, d)
+    blocks = (b * chunks,) + pool_k.shape[1:]             # [.., BS, H*D]
     flat_dest = dest.reshape(b * chunks)
-    pool_k = pool_k.at[flat_dest].set(k_c.astype(pool_k.dtype),
-                                      mode="drop")
-    pool_v = pool_v.at[flat_dest].set(v_c.astype(pool_v.dtype),
-                                      mode="drop")
+    pool_k = pool_k.at[flat_dest].set(
+        k_new.reshape(blocks).astype(pool_k.dtype), mode="drop")
+    pool_v = pool_v.at[flat_dest].set(
+        v_new.reshape(blocks).astype(pool_v.dtype), mode="drop")
     return pool_k, pool_v

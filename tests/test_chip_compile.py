@@ -73,6 +73,48 @@ def _tp4(devices) -> Mesh:
     return Mesh(np.array(devices[:4]).reshape(1, 1, 4), ("dp", "sp", "tp"))
 
 
+def _paged_args(slots, heads, head_dim, blocks, block_size, blocks_per_slot):
+    """[(shape, dtype, spec under a tp mesh), ...] of the paged decode
+    kernel's operands: q on heads, the flat pools on H*D."""
+    from kfserving_tpu.ops.paged_attention import pool_shape
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = (pool_shape(blocks, block_size, heads, head_dim), bf16,
+            P(None, None, "tp"))
+    return [((slots, 1, heads, head_dim), bf16, P(None, None, "tp", None)),
+            pool, pool, ((slots, blocks_per_slot), i32, P()),
+            ((slots,), i32, P())]
+
+
+def _compile(fn, args, v5e, sharded: bool, donate=()):
+    """`fn` compiled for one described chip, or for four under a tp mesh."""
+    from jax.sharding import SingleDeviceSharding
+
+    fn = jax.jit(fn, donate_argnums=donate)
+    if not sharded:
+        one = SingleDeviceSharding(v5e.devices[0])
+        return fn.lower(*[
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+            for shape, dtype, _ in args]).compile()
+    mesh = _tp4(v5e.devices)
+    structs = [jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+               for shape, dtype, spec in args]
+    with jax.set_mesh(mesh):
+        return fn.lower(*structs).compile()
+
+
+def _pool_copies(compiled, pool_dims) -> list:
+    """The copies a compiled program makes of an array of the pool's
+    dimensions: a `copy` into the layout a kernel asked for, or XLA's
+    memory-space assignment moving a whole pool through VMEM and back
+    (`copy-start`) around an operation of its own on it."""
+    dims = ",".join(str(n) for n in pool_dims)
+    return [line.strip()[:160] for line in compiled.as_text().splitlines()
+            if f"bf16[{dims}]" in line
+            and any(op in line for op in (" copy(", " copy-start("))]
+
+
 def _kernel_case(kernel: str):
     """(function, [(shape, dtype, spec under a tp mesh), ...]) of one kernel
     at the shapes the smoke's generate phase serves.  The functions are
@@ -84,13 +126,9 @@ def _kernel_case(kernel: str):
     heads = P(None, None, "tp", None)
     bf16, i32 = jnp.bfloat16, jnp.int32
     if kernel == "paged":
-        pool = ((s["blocks"], s["block_size"], s["heads"], s["head_dim"]),
-                bf16, heads)
-        return paged_attention.paged_attention_sharded, [
-            ((s["slots"], 1, s["heads"], s["head_dim"]), bf16, heads),
-            pool, pool,
-            ((s["slots"], s["blocks_per_slot"]), i32, P()),
-            ((s["slots"],), i32, P())]
+        return paged_attention.paged_attention_sharded, _paged_args(
+            s["slots"], s["heads"], s["head_dim"], s["blocks"],
+            s["block_size"], s["blocks_per_slot"])
     qkv = ((1, s["prefill"], s["heads"], s["head_dim"]), bf16, heads)
     if kernel == "flash_causal":
         return (lambda q, k, v: attention._flash(q, k, v, True, None),
@@ -104,22 +142,59 @@ def _kernel_case(kernel: str):
 @pytest.mark.parametrize("kernel",
                          ["paged", "flash_causal", "flash_kv_lengths"])
 def test_kernel_compiles_for_described_v5e(v5e, kernel, sharded):
-    from jax.sharding import SingleDeviceSharding
-
     fn, args = _kernel_case(kernel)
-    if sharded:
-        mesh = _tp4(v5e.devices)
-        structs = [jax.ShapeDtypeStruct(shape, dtype,
-                                        sharding=NamedSharding(mesh, spec))
-                   for shape, dtype, spec in args]
-        with jax.set_mesh(mesh):
-            compiled = jax.jit(fn).lower(*structs).compile()
-    else:
-        one = SingleDeviceSharding(v5e.devices[0])
-        structs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-                   for shape, dtype, _ in args]
-        compiled = jax.jit(fn).lower(*structs).compile()
+    assert "tpu_custom_call" in _compile(fn, args, v5e, sharded).as_text()
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one-chip", "tp4-mesh"])
+@pytest.mark.parametrize("heads, blocks, blocks_per_slot", [
+    ((20, 64), 144, 8),     # gpt2-large: padded a [.., H, D] tile 3.2 times
+    ((16, 128), 288, 16),   # OLMoE: whole tiles either way
+    ((4, 128), 288, 16),    # GQA-like: a quarter of a [.., H, D] tile
+], ids=["20x64", "16x128", "4x128"])
+def test_paged_kernel_reads_the_flat_pool_in_place(
+        v5e, monkeypatch, heads, blocks, blocks_per_slot, sharded):
+    """Whatever the head geometry, the kernel takes the pool as it is
+    stored: no `copy` of a pool-shaped operand in front of it and no
+    temporaries.  Where one heads shard's H*D is not whole lanes
+    (20 x 64 over four chips: 320) the dispatcher keeps the kernel out
+    and the XLA formulation serves."""
+    from kfserving_tpu.ops import attention, paged_attention
+
+    args = _paged_args(24, *heads, blocks, 128, blocks_per_slot)
+    nb, bs, hd = args[1][0]
+    shard = hd // 4 if sharded else hd
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    compiled = _compile(paged_attention.paged_attention, args, v5e, sharded)
+    if shard % 128:
+        assert "tpu_custom_call" not in compiled.as_text()
+        return
     assert "tpu_custom_call" in compiled.as_text()
+    assert _pool_copies(compiled, (nb, bs, shard)) == []
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one-chip", "tp4-mesh"])
+def test_paged_write_kernel_updates_the_pool_in_place(v5e, sharded):
+    """A decode step's write at OLMoE's 16 x 128 heads (whole lanes on
+    one chip and on four): a Mosaic call whose outputs are its pool
+    operands, no copy of a pool, no temporaries."""
+    from kfserving_tpu.ops import paged_attention
+
+    _, pool, _, _, rows = _paged_args(24, 16, 128, 288, 128, 16)
+    step = ((24, 16, 128), jnp.bfloat16, P(None, "tp", None))
+    compiled = _compile(paged_attention.paged_write_sharded,
+                        [pool, pool, step, step, rows, rows], v5e, sharded,
+                        donate=(0, 1))  # as the engine's programs do
+    assert "tpu_custom_call" in compiled.as_text()
+    nb, bs, hd = pool[0]
+    assert _pool_copies(compiled, (nb, bs, hd // 4 if sharded else hd)) == []
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes >= 2 * nb * bs * hd * 2 // (
+        4 if sharded else 1)
 
 
 # -- OLMoE at the benchmarked configuration's shapes ----------------------------
@@ -181,11 +256,10 @@ def test_touched_experts_kernel_compiles_for_described_v5e(v5e, tokens):
     assert "ragged-dot" not in compiled.as_text()
 
 
-def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
-    """The 16-step decode program of `olmoe-1b-7b-8l` (24 slots, 288
-    blocks of 128, bfloat16 parameters) with the Pallas paged kernel, as
-    the chip's compiler sees it: what it needs beside its arguments, and
-    that arguments, outputs and temporaries fit 15.75 GiB."""
+def _decode_program(v5e, monkeypatch, serving: dict):
+    """(the 16-step decode program of a benchmarked configuration's
+    serving settings with the Pallas paged kernel, as the chip's compiler
+    sees it; the engine's pool shape; its parameter shapes)."""
     from jax.sharding import SingleDeviceSharding
 
     from kfserving_tpu.engine.generator import GenerationEngine
@@ -194,7 +268,6 @@ def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
 
     # The dispatchers ask the attached backend, which is the CPU here.
     monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
-    serving = _olmoe_serving()
     spec = create_model(serving["architecture"], **serving["arch_kwargs"])
     one = SingleDeviceSharding(v5e.devices[0])
 
@@ -204,7 +277,6 @@ def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
 
     shapes = jax.eval_shape(
         lambda: spec.module.init(jax.random.PRNGKey(0), spec.example))
-    assert {x.dtype.name for x in jax.tree.leaves(shapes)} == {"bfloat16"}
     engine = GenerationEngine(
         spec.module, shapes, max_slots=serving["max_slots"],
         max_seq=serving["max_seq"],
@@ -223,15 +295,56 @@ def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
             on_chip(shapes), on_chip(engine._caches),
             arg(i32, s, engine.blocks_per_slot), arg(i32, s), arg(i32, s),
             arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s)).compile()
+        return compiled, engine._cache_shape, shapes
     finally:
         engine.shutdown_nowait()
+
+
+def _program_bytes(memory) -> int:
+    return (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+
+
+def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
+    """The 16-step decode program of `olmoe-1b-7b-8l` (24 slots, 288
+    blocks of 128, bfloat16 parameters) with the Pallas paged kernel, as
+    the chip's compiler sees it: what it needs beside its arguments, and
+    that arguments, outputs and temporaries fit 15.75 GiB."""
+    compiled, _, shapes = _decode_program(v5e, monkeypatch, _olmoe_serving())
+    assert {x.dtype.name for x in jax.tree.leaves(shapes)} == {"bfloat16"}
     assert "tpu_custom_call" in compiled.as_text()  # the paged kernel
     memory = compiled.memory_analysis()
     print(f"olmoe-1b-7b-8l decode program: {memory}")
-    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
-             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
     assert memory.argument_size_in_bytes > 9.4e9  # 7.13 GB + 2.4 GB pool
-    assert total < 15.75 * 2**30, memory
+    assert _program_bytes(memory) < 15.75 * 2**30, memory
+
+
+@pytest.mark.parametrize("cache_blocks", [144, 192])
+def test_gpt2_large_decode_program_fits_the_described_v5e(
+        v5e, monkeypatch, cache_blocks):
+    """The 16-step decode program of `gpt2-large` (24 slots, 20 heads of
+    64) at the benchmarked 144 blocks and at the 192 the chip refused
+    while the kernel read a padded twin of every layer's pool (9.16 GiB
+    of temporaries): no pool is copied, neither into another layout nor
+    through VMEM, and what is left of the temporaries is the bfloat16
+    twin of the float32 parameters, which XLA converts once a call,
+    outside the 16 steps."""
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "gpt2-large.json")) as f:
+        serving = {**json.load(f)["serving"], "cache_blocks": cache_blocks}
+    compiled, pool, shapes = _decode_program(v5e, monkeypatch, serving)
+    assert pool[0] == cache_blocks
+    # A layer's two Mosaic calls: the step's write, then attention.
+    assert compiled.as_text().count("tpu_custom_call") >= 72
+    assert _pool_copies(compiled, pool) == []
+    memory = compiled.memory_analysis()
+    print(f"gpt2-large decode program, {cache_blocks} blocks: {memory}")
+    float32 = sum(x.size for x in jax.tree.leaves(shapes)
+                  if x.dtype == jnp.float32)
+    assert float32 > 7.7e8  # all of them: 3.1 GB as stored
+    assert memory.temp_size_in_bytes - 2 * float32 < 0.5 * 2**30, memory
+    assert memory.temp_size_in_bytes < 2 * 2**30, memory
+    assert _program_bytes(memory) < 15.75 * 2**30, memory
 
 
 def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):
@@ -240,12 +353,8 @@ def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):
     from kfserving_tpu.ops.paged_attention import paged_attention_tpu
 
     _, args = _kernel_case("paged")
-    mesh = _tp4(v5e.devices)
-    structs = [jax.ShapeDtypeStruct(shape, dtype,
-                                    sharding=NamedSharding(mesh, spec))
-               for shape, dtype, spec in args]
     with pytest.raises(NotImplementedError, match="shard_map"):
-        jax.jit(paged_attention_tpu).lower(*structs).compile()
+        _compile(paged_attention_tpu, args, v5e, sharded=True)
 
 
 # -- the wrappers' numerics, on virtual CPU devices ----------------------------
@@ -263,17 +372,18 @@ def test_paged_kernel_under_mesh_matches_xla(cpu_mesh):
     rng = np.random.default_rng(0)
     b, h, d, nb, bs = 3, 8, 64, 10, 128
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    pool_k = jnp.asarray(rng.standard_normal((nb, bs, h, d)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((nb, bs, h, d)), jnp.float32)
+    pool_k = jnp.asarray(rng.standard_normal((nb, bs, h * d)), jnp.float32)
+    pool_v = jnp.asarray(rng.standard_normal((nb, bs, h * d)), jnp.float32)
     table = jnp.asarray([[0, 1], [2, -1], [3, 4]], jnp.int32)
     lengths = jnp.asarray([200, 7, 256], jnp.int32)
     want = paged_attention_xla(q, pool_k, pool_v, table, lengths)
     heads = NamedSharding(cpu_mesh, P(None, None, "tp", None))
+    pool = NamedSharding(cpu_mesh, P(None, None, "tp"))
     with jax.set_mesh(cpu_mesh):
         got = jax.jit(functools.partial(paged_attention_sharded,
                                         interpret=True))(
-            jax.device_put(q, heads), jax.device_put(pool_k, heads),
-            jax.device_put(pool_v, heads), table, lengths)
+            jax.device_put(q, heads), jax.device_put(pool_k, pool),
+            jax.device_put(pool_v, pool), table, lengths)
     assert len(got.sharding.device_set) == 4
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)

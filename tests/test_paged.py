@@ -477,43 +477,220 @@ async def test_prefill_enqueue_failure_releases_planned_blocks(tiny):
 
 # --------------------------------------------- pallas paged kernel
 
+KERNEL_BS, KERNEL_MB, KERNEL_NB = 128, 4, 8
+KERNEL_LENGTHS = {
+    "inside-a-block": [300, 40, 200],
+    "on-a-block-edge": [128, 256, 384],
+    "one": [1, 1, 1],
+    "full-table": [512, 512, 511],
+}
 
-def test_pallas_paged_kernel_matches_xla():
-    """The Pallas paged-decode kernel (interpret mode on CPU) matches
-    the XLA gather reference across partial blocks, shared blocks,
-    and unallocated (-1) table tails."""
+
+def _pools(rng, nb, bs, h, d):
+    """One layer's K and V as [NB, BS, H, D] arrays (the layout the
+    pool had before it went flat, and the reference's), and the flat
+    pools the ops take: the same bytes."""
+    from kfserving_tpu.ops.paged_attention import pool_shape
+
+    k4, v4 = (rng.normal(size=(nb, bs, h, d)).astype(np.float32)
+              for _ in range(2))
+    flat = pool_shape(nb, bs, h, d)
+    assert flat == (nb, bs, h * d)
+    return k4, v4, jnp.asarray(k4.reshape(flat)), \
+        jnp.asarray(v4.reshape(flat))
+
+
+def _attention_over_blocks(q, k4, v4, table, allowed):
+    """Plain numpy attention over a [NB, BS, H, D] pool: q [B, L, H, D],
+    allowed [B, L, MB*BS] true where a query may look."""
+    b, mb = table.shape
+    _, bs, h, d = k4.shape
+    blocks = np.maximum(table, 0)
+    k = k4[blocks].reshape(b, mb * bs, h, d)
+    v = v4[blocks].reshape(b, mb * bs, h, d)
+    logits = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    logits = np.where(allowed[:, None], logits, -np.inf)
+    weights = np.exp(logits - logits.max(axis=-1, keepdims=True))
+    weights /= weights.sum(axis=-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+@pytest.mark.parametrize("lengths", list(KERNEL_LENGTHS.values()),
+                         ids=list(KERNEL_LENGTHS))
+@pytest.mark.parametrize("heads", [(20, 64), (16, 128), (4, 64)],
+                         ids=["20x64", "16x128", "4x64"])
+def test_pallas_paged_kernel_matches_xla(heads, lengths):
+    """The Pallas paged-decode kernel (interpret mode on CPU) on the
+    flat pool matches the XLA gather reference, and both the plain
+    attention over the same bytes as [NB, BS, H, D]: for heads that
+    padded a tile (20 x 64), fill it (16 x 128) and are a fraction of
+    one (4 x 64); lengths inside a block, on its edge, of one token
+    and of the whole table; a shared block and unallocated (-1) table
+    tails."""
     from kfserving_tpu.ops import paged_attention as pa
 
-    rng = np.random.default_rng(0)
-    B, H, D, BSZ, NB, MB = 3, 4, 64, 128, 8, 4
-    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-    pool_k = jnp.asarray(rng.normal(size=(NB, BSZ, H, D)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(NB, BSZ, H, D)), jnp.float32)
-    table = jnp.asarray([[0, 1, 2, -1],
-                         [3, -1, -1, -1],
-                         [0, 4, -1, -1]], jnp.int32)  # row 2 shares 0
-    lengths = jnp.asarray([300, 40, 200], jnp.int32)
-    want = pa.paged_attention_xla(q, pool_k, pool_v, table, lengths)
-    got = pa.paged_attention_tpu.__wrapped__(
-        q, pool_k, pool_v, table, lengths, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    h, d = heads
+    bs, mb, nb = KERNEL_BS, KERNEL_MB, KERNEL_NB
+    rng = np.random.default_rng(h * 1000 + lengths[0])
+    q = rng.normal(size=(len(lengths), 1, h, d)).astype(np.float32)
+    k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
+    table = np.asarray([[0, 1, 2, 7], [3, 5, 6, 1], [0, 4, 2, 3]],
+                       np.int32)  # rows 0 and 2 share block 0
+    for row, n in enumerate(lengths):
+        table[row, -(-n // bs):] = -1
+    lens = jnp.asarray(lengths, jnp.int32)
+    allowed = (np.arange(mb * bs)[None, None, :]
+               < np.asarray(lengths)[:, None, None])
+    want = _attention_over_blocks(q, k4, v4, table, allowed)
+    xla = pa.paged_attention_xla(jnp.asarray(q), pool_k, pool_v,
+                                 jnp.asarray(table), lens)
+    got = pa.paged_attention_tpu(jnp.asarray(q), pool_k, pool_v,
+                                 jnp.asarray(table), lens,
+                                 interpret=True)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(xla), want, rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
+                               atol=2e-5)
 
 
-def test_pallas_paged_kernel_block_boundary_lengths():
+# ------------------------------ the flat pool against [NB, BS, H, D]
+
+
+def _written(pool4, values, table, positions):
+    """`paged_write`'s contract on a [NB, BS, H, D] array, one position
+    at a time: -1 blocks and positions past the table drop."""
+    want = pool4.copy()
+    bs, mb = pool4.shape[1], table.shape[1]
+    for index in np.ndindex(positions.shape):
+        pos = int(positions[index])
+        blk = table[index[0], pos // bs] if pos // bs < mb else -1
+        if blk >= 0:
+            want[blk, pos % bs] = values[index]
+    return want
+
+
+@pytest.mark.parametrize("op", ["write-decode", "write-chunk", "insert",
+                                "prefill-attention"])
+def test_flat_pool_ops_match_the_4d_pool(op):
+    """`paged_write` (both call shapes), `paged_insert` and
+    `paged_prefill_attention_xla` take [.., H, D] activations and the
+    flat pool; reshaped to [NB, BS, H, D] their results are what the
+    same operations give on such an array."""
     from kfserving_tpu.ops import paged_attention as pa
 
-    rng = np.random.default_rng(1)
-    B, H, D, BSZ, NB, MB = 2, 2, 64, 128, 6, 3
-    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-    pool_k = jnp.asarray(rng.normal(size=(NB, BSZ, H, D)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(NB, BSZ, H, D)), jnp.float32)
-    table = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
-    for lens in ([128, 256], [1, 384], [127, 129]):
-        lengths = jnp.asarray(lens, jnp.int32)
-        want = pa.paged_attention_xla(q, pool_k, pool_v, table,
-                                      lengths)
-        got = pa.paged_attention_tpu.__wrapped__(
-            q, pool_k, pool_v, table, lengths, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5, err_msg=str(lens))
+    h, d, bs, nb, mb, b = 5, 8, 4, 7, 3, 3
+    rng = np.random.default_rng(7)
+    k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
+    table = np.asarray([[0, 1, -1], [2, 3, 4], [5, -1, -1]], np.int32)
+
+    def unflat(pool):
+        return np.asarray(pool).reshape(nb, bs, h, d)
+
+    if op in ("write-decode", "write-chunk"):
+        # Row 0 writes into its -1 block, row 2 past the table: drop.
+        positions = np.asarray([9, 6, mb * bs + 2], np.int32)
+        if op == "write-chunk":
+            positions = positions[:, None] + np.arange(3, dtype=np.int32)
+        k_step, v_step = (rng.normal(
+            size=positions.shape + (h, d)).astype(np.float32)
+            for _ in range(2))
+        got_k, got_v = pa.paged_write(
+            pool_k, pool_v, jnp.asarray(k_step), jnp.asarray(v_step),
+            jnp.asarray(table), jnp.asarray(positions))
+        np.testing.assert_array_equal(
+            unflat(got_k), _written(k4, k_step, table, positions))
+        np.testing.assert_array_equal(
+            unflat(got_v), _written(v4, v_step, table, positions))
+        assert not np.array_equal(unflat(got_k), k4)
+    elif op == "insert":
+        k_new, v_new = (rng.normal(
+            size=(b, 2 * bs, h, d)).astype(np.float32) for _ in range(2))
+        dest = np.asarray([[6, -1], [1, 0], [-1, -1]], np.int32)
+        got_k, got_v = pa.paged_insert(
+            pool_k, pool_v, jnp.asarray(k_new), jnp.asarray(v_new),
+            jnp.asarray(dest), None)
+        want_k, want_v = k4.copy(), v4.copy()
+        for row, chunk in np.ndindex(dest.shape):
+            if dest[row, chunk] >= 0:
+                rows = slice(chunk * bs, (chunk + 1) * bs)
+                want_k[dest[row, chunk]] = k_new[row, rows]
+                want_v[dest[row, chunk]] = v_new[row, rows]
+        np.testing.assert_array_equal(unflat(got_k), want_k)
+        np.testing.assert_array_equal(unflat(got_v), want_v)
+    else:
+        q = rng.normal(size=(b, 3, h, d)).astype(np.float32)
+        q_positions = np.asarray([[3, 4, 5], [9, 10, 11], [0, 1, 2]],
+                                 np.int32)
+        allowed = (np.arange(mb * bs)[None, None, :]
+                   <= q_positions[:, :, None])
+        got = pa.paged_prefill_attention_xla(
+            jnp.asarray(q), pool_k, pool_v, jnp.asarray(table),
+            jnp.asarray(q_positions))
+        np.testing.assert_allclose(
+            np.asarray(got),
+            _attention_over_blocks(q, k4, v4, table, allowed),
+            rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_pallas_paged_write_matches_the_scatter(dtype):
+    """The decode step's write kernel (interpret mode on CPU) against
+    `paged_write`'s scatter: rows at a tile's first and last position
+    and at a block's, a row whose block is unallocated and one parked
+    past the table (both move nothing), and every other position of
+    the pools untouched."""
+    from kfserving_tpu.ops import paged_attention as pa
+
+    h, d, bs, nb = 4, 64, 128, 8
+    rng = np.random.default_rng(3)
+    k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
+    pool_k, pool_v = pool_k.astype(dtype), pool_v.astype(dtype)
+    table = jnp.asarray([[0, 1], [2, -1], [3, 4], [5, -1], [6, 7]],
+                        jnp.int32)
+    positions = jnp.asarray([bs + 15, bs + 5, 2 * bs - 1, 1000, 0],
+                            jnp.int32)
+    k_step, v_step = (jnp.asarray(rng.normal(size=(5, h, d)), dtype)
+                      for _ in range(2))
+    want_k, want_v = pa.paged_write(pool_k, pool_v, k_step, v_step,
+                                    table, positions)
+    got_k, got_v = pa.paged_write_sharded(
+        pool_k, pool_v, k_step, v_step,
+        jnp.asarray([1, -1, 4, -1, 6], jnp.int32), positions % bs,
+        interpret=True)
+    assert np.asarray(want_k != pool_k).any(axis=-1).sum() == 3
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+
+
+def test_flat_pool_block_bytes_are_the_4d_blocks_bytes():
+    """What leaves the device a block at a time (host tier, /kv/chains,
+    the hand-off export) is `pool[idx][row].tobytes()`: out of the flat
+    pool those are the bytes a [BS, H, D] block gave, so a payload
+    written before the pool went flat reads back, and one written now
+    lands (`_land_faultbacks`: frombuffer -> [BS, H*D] -> insert)."""
+    from kfserving_tpu.ops import paged_attention as pa
+
+    h, d, bs, nb = 5, 8, 4, 6
+    rng = np.random.default_rng(11)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(2, 2 * bs, h, d)),
+                                jnp.bfloat16) for _ in range(2))
+    zeros = jnp.zeros(pa.pool_shape(nb, bs, h, d), jnp.bfloat16)
+    dest = jnp.asarray([[4, 1], [0, -1]], jnp.int32)
+    pool_k, pool_v = pa.paged_insert(zeros, zeros, k_new, v_new, dest,
+                                     None)
+    gathered = np.asarray(pool_k[jnp.asarray([4, 1, 0])])
+    blocks_4d = [np.asarray(k_new[0, :bs]), np.asarray(k_new[0, bs:]),
+                 np.asarray(k_new[1, :bs])]
+    for row, block in enumerate(blocks_4d):
+        assert block.shape == (bs, h, d)
+        assert gathered[row].tobytes() == block.tobytes()
+    # And back: a payload's bytes, inserted as [1, BS, H*D], are the
+    # block again.
+    payload = blocks_4d[1].tobytes()
+    landed = np.frombuffer(payload, gathered.dtype).reshape(1, bs, h * d)
+    again, _ = pa.paged_insert(zeros, zeros, jnp.asarray(landed),
+                               jnp.asarray(landed),
+                               jnp.asarray([[3]], jnp.int32), None)
+    assert np.asarray(again[3]).tobytes() == payload
