@@ -43,8 +43,7 @@ WORK = os.path.join(ROOT, ".chip_smoke")  # git-ignored: model dirs, logs
 READY_TIMEOUT_S = 600.0
 
 # -- the configurations the command line runs --------------------------------
-# ResNet-50, BASELINE config 2, with the non-smoke settings of
-# benchmarks/configs.py bench_resnet; batch buckets cut to two so cold
+# ResNet-50, BASELINE config 2; batch buckets cut to two so cold
 # compile stays bounded, raw logits out for the check.
 RESNET50 = {
     "architecture": "resnet50",
@@ -52,8 +51,7 @@ RESNET50 = {
     "pipeline_depth": 3, "max_latency_ms": 15.0, "warmup": True,
     "input_dtype": "uint8", "scale": 1.0 / 255.0, "output": "logits",
 }
-# GPT-2-small widths over the paged cache: the non-smoke config of
-# benchmarks/configs.py bench_generate_4k.
+# GPT-2-small widths and 4,096 positions over a pool of 128-token blocks.
 DECODER = {
     "architecture": "decoder",
     "arch_kwargs": {"vocab_size": 32000, "hidden_size": 768,

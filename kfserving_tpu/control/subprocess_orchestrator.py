@@ -387,9 +387,9 @@ class SubprocessOrchestrator:
             # Recycle successors (and standby activations, whose
             # warmup sits inside the exclusive-device swap gap) warm
             # only the largest bucket: the predecessor populated the
-            # persistent compile cache, so the rest load on demand —
-            # the full grid was the dominant term of successor load
-            # time (r5 SOAK successor_phases).
+            # persistent compile cache, so the rest load on demand
+            # instead of the full grid running inside the successor's
+            # load time.
             env["KFS_MINIMAL_WARMUP"] = "1"
         else:
             # A cold first replica (empty persistent cache) must do

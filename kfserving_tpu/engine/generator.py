@@ -9,23 +9,24 @@ one request is hundreds of sequential device steps, and throughput
 comes from batching *steps across requests*, not requests.  This engine
 is the TPU-first design for that:
 
-- **slot caches, static shapes**: the KV cache is a fixed pool of
-  `max_slots` sequence slots, per layer [S, max_seq, H, D].  The decode
-  step is ONE jit-compiled program over all S slots, compiled once and
-  reused for the life of the server — requests joining or leaving never
-  change a shape, so XLA never recompiles (the continuous-batching
-  analogue of the engine's batch buckets).
-- **paged mode** (`block_size`): the dense pool becomes a shared block
-  pool [NB, BS, H*D] + per-slot block tables — HBM scales with
-  resident tokens (size it with `cache_blocks`), identical prompt
-  prefixes share blocks via a chain-hash index, pool pressure queues
-  admissions, and block release is deferred past in-flight waves (the
-  zombie-wave hazard).  Shapes stay static: tables ride each dispatch
-  as a [S, MB] int32 array (ops/paged_attention.py).
+- **a block pool, static shapes**: the KV cache is a shared pool of
+  blocks per layer, [NB, BS, H*D] (ops/paged_attention.py owns the
+  layout), plus a block table per sequence slot.  The decode step is
+  ONE jit-compiled program over all `max_slots` slots, compiled once
+  and reused for the life of the server — requests joining or leaving
+  never change a shape, so XLA never recompiles (the continuous-
+  batching analogue of the engine's batch buckets); tables ride each
+  dispatch as a [S, MB] int32 array.  HBM scales with resident tokens
+  (size it with `cache_blocks`; unset, there is a block for every
+  position of every slot), identical prompt prefixes share blocks via
+  a chain-hash index, pool pressure queues admissions, and block
+  release is deferred past in-flight waves (the zombie-wave hazard).
+  `block_size` unset is derived from the lengths the engine was given
+  (`derive_block_size`).
 - **prefill/decode split**: prompt ingestion runs as a separate
   bucketed forward (suffix-padded, flash-eligible at long L, one
   compile per bucket) that returns the prompt's k/v for every layer;
-  a jitted scatter inserts them into a free slot.  Decode then costs
+  a jitted scatter inserts them into the slot's blocks.  Decode then costs
   O(1) tokens per step.
 - **continuous batching, fully asynchronous**: admission enqueues
   prefill + insert + feed-scatter and installs the slot WITHOUT a
@@ -63,9 +64,11 @@ import concurrent.futures
 import functools
 import itertools
 import logging
+import math
 import os
+import threading
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 
@@ -151,7 +154,7 @@ class _Request:
     prefill_device_ms: float = 0.0
     decode_device_ms: float = 0.0
     tokens_out: int = 0
-    blocks_held: int = 0          # peak slot-table blocks (paged)
+    blocks_held: int = 0          # peak slot-table blocks
     cache_hit_blocks: int = 0     # prompt blocks served by the index
     cache_saved_tokens: int = 0   # hit blocks x block_size
     # Host KV tier (engine/kv_tier.py): prompt blocks faulted back
@@ -183,7 +186,7 @@ class _Active:
     # (seed, absolute position), so the continuation reproduces what
     # an uninterrupted decode would have sampled).
     tokens: List[int] = field(default_factory=list)
-    # -- chunked-prefill state (paged mode, cold prompts) --------------
+    # -- chunked-prefill state (cold prompts) --------------------------
     # prefilling: the slot holds a cold prompt landing in block-aligned
     # chunks between decode waves — it is NOT decodable yet (decode
     # waves park its feed row on an out-of-range sentinel so their
@@ -259,11 +262,10 @@ class GenerationEngine:
         # Adaptive depth: stop enqueuing SPECULATIVE waves when every
         # active stream provably finishes (by token budget) within the
         # waves already in flight — those extra waves could only
-        # decode garbage (the committed r5 A/B measured ~45% wasted
-        # dispatches under uniform traffic at fixed depth 2, and
-        # depth_speedup 0.98: depth-2 losing to depth-1).  Staggered
-        # traffic keeps remaining work past the horizon, so depth-2's
-        # overlap win is untouched there.
+        # decode garbage (uniform traffic at a fixed depth of 2,
+        # where finishes cluster).  Staggered traffic keeps remaining
+        # work past the horizon, so depth-2's overlap win is
+        # untouched there.
         self.adaptive_depth = bool(adaptive_depth)
         cfg = module.config
         if self.max_seq > cfg.max_seq:
@@ -303,137 +305,124 @@ class GenerationEngine:
         n_layers = cfg.num_layers
         cache_dtype = cfg.dtype
         self._cache_dtype = cache_dtype
-        # -- paged vs dense cache layout -------------------------------
-        # Dense (block_size=None): per-slot [S, max_seq, H, D] — every
-        # slot burns max_seq HBM whatever it holds.  Paged: a shared
-        # block pool [NB, BS, H*D] (ops/paged_attention.py owns the
-        # layout) + per-slot block tables — HBM scales with resident
-        # tokens and identical prompt prefixes share blocks (VERDICT
-        # r4 weak #5; the vLLM PagedAttention idea, TPU-shaped: static
-        # pool/table shapes, OOB-sentinel scatters, a Pallas decode
-        # kernel that walks the table, XLA gather attention elsewhere).
-        self.block_size = int(block_size) if block_size else None
-        if self.block_size is not None:
-            bs = self.block_size
-            if self.max_seq % bs != 0:
+        # -- the KV cache: a block pool ---------------------------------
+        # A shared pool [NB, BS, H*D] a layer (ops/paged_attention.py
+        # owns the layout) + per-slot block tables — HBM scales with
+        # resident tokens and identical prompt prefixes share blocks
+        # (VERDICT r4 weak #5; the vLLM PagedAttention idea, TPU-
+        # shaped: static pool/table shapes, OOB-sentinel scatters, a
+        # Pallas decode kernel that walks the table, XLA gather
+        # attention elsewhere).  block_size unset is derived from the
+        # lengths above: 128 wherever the kernels can serve.
+        self.block_size = bs = (
+            int(block_size) if block_size
+            else derive_block_size(self.max_seq, buckets))
+        if self.max_seq % bs != 0:
+            raise InvalidInput(
+                f"max_seq {self.max_seq} must be a multiple of "
+                f"block_size {bs}")
+        for b in buckets:
+            if b % bs != 0:
                 raise InvalidInput(
-                    f"max_seq {self.max_seq} must be a multiple of "
-                    f"block_size {bs}")
-            for b in buckets:
-                if b % bs != 0:
-                    raise InvalidInput(
-                        f"prefill bucket {b} must be a multiple of "
-                        f"block_size {bs} (paged insert writes whole "
-                        f"blocks)")
-            self.blocks_per_slot = self.max_seq // bs
-            # Parity default: same capacity as the dense pool.  A
-            # smaller cache_blocks is the HBM saving — mixed-length
-            # traffic rarely needs S full-length slots at once.
-            self.num_blocks = int(cache_blocks or
-                                  self.max_slots * self.blocks_per_slot)
-            from kfserving_tpu.ops import paged_attention
+                    f"prefill bucket {b} must be a multiple of "
+                    f"block_size {bs} (paged insert writes whole "
+                    f"blocks)")
+        self.blocks_per_slot = self.max_seq // bs
+        # Parity default: a block for every position of every slot.
+        # A smaller cache_blocks is the HBM saving — mixed-length
+        # traffic rarely needs S full-length slots at once.
+        self.num_blocks = int(cache_blocks or
+                              self.max_slots * self.blocks_per_slot)
+        from kfserving_tpu.ops import paged_attention
 
-            pool_shape = paged_attention.pool_shape(
-                self.num_blocks, bs, cfg.num_heads, cfg.head_dim)
-            self._cache_shape = pool_shape
-            self._caches = [
-                (jnp.zeros(pool_shape, cache_dtype),
-                 jnp.zeros(pool_shape, cache_dtype))
-                for _ in range(n_layers)
-            ]
-            # Host-side paging state (guarded by _block_lock: the
-            # enqueue thread allocates while cancel() frees on the
-            # loop thread).
-            import threading
-            from collections import OrderedDict
+        pool_shape = paged_attention.pool_shape(
+            self.num_blocks, bs, cfg.num_heads, cfg.head_dim)
+        self._cache_shape = pool_shape
+        self._caches = [
+            (jnp.zeros(pool_shape, cache_dtype),
+             jnp.zeros(pool_shape, cache_dtype))
+            for _ in range(n_layers)
+        ]
+        # Host-side paging state (guarded by _block_lock: the
+        # enqueue thread allocates while cancel() frees on the
+        # loop thread).
+        self._block_lock = threading.Lock()
+        self._tables = np.full(
+            (self.max_slots, self.blocks_per_slot), -1, np.int32)
+        self._free_blocks: deque = deque(range(self.num_blocks))
+        self._block_ref = np.zeros(self.num_blocks, np.int64)
+        # chain-hash -> block id for FULL prompt blocks (prefix
+        # reuse); zero-ref registered blocks linger in
+        # _reclaimable (LRU) until allocation pressure evicts.
+        self._prefix_index: Dict[bytes, int] = {}
+        self._block_chain: Dict[int, bytes] = {}
+        self._reclaimable: "OrderedDict[int, None]" = OrderedDict()
+        # Hits per LIVE index entry (reuse depth): the /debug/cache
+        # census and the hot-chain top-K read this; entries drop
+        # with their index entry on eviction/invalidation.
+        self._chain_hits: Dict[bytes, int] = {}
+        # Eviction accounting by cause (registry twin:
+        # kfserving_tpu_generator_block_evictions_total).
+        # Capacity evictions split by fate: spilled (the chain
+        # survives in the host KV tier) vs dropped (the drop-on-
+        # evict baseline — no tier, no chain, or a failed spill).
+        self.block_evictions: Dict[str, int] = {
+            "capacity_dropped": 0, "capacity_spilled": 0,
+            "index_invalidation": 0, "zombie_deferral": 0}
+        self.prefill_tokens_saved = 0
+        # (release_at_decode_step, [block ids]) — see
+        # _free_slot_state for why release is deferred.
+        self._deferred_frees: deque = deque()
+        # slot -> provisional prefix registrations of its last
+        # plan; confirmed once the prefill enqueues, deregistered
+        # if the enqueue fails (the blocks were never written).
+        self._plan_regs: Dict[int, List[Tuple[bytes, int]]] = {}
+        self.prefix_hits = 0
+        self.prefix_misses = 0
+        # -- host KV tier (engine/kv_tier.py) ----------------------
+        # Capacity-evicted prefix blocks spill to a host-RAM mmap
+        # tier instead of being dropped; a returning turn's plan
+        # probes device index -> host tier -> re-prefill.  Off by
+        # default (host_tier_blocks=0); KFS_KV_TIER_BLOCKS is the
+        # env twin for server deployments.
+        if host_tier_blocks is None:
+            try:
+                host_tier_blocks = int(os.environ.get(
+                    "KFS_KV_TIER_BLOCKS", "0"))
+            except ValueError:
+                host_tier_blocks = 0
+        self.kv_tier = None
+        if host_tier_blocks and int(host_tier_blocks) > 0:
+            from kfserving_tpu.engine.kv_tier import HostKVTier
 
-            self._block_lock = threading.Lock()
-            self._tables = np.full(
-                (self.max_slots, self.blocks_per_slot), -1, np.int32)
-            self._free_blocks: deque = deque(range(self.num_blocks))
-            self._block_ref = np.zeros(self.num_blocks, np.int64)
-            # chain-hash -> block id for FULL prompt blocks (prefix
-            # reuse); zero-ref registered blocks linger in
-            # _reclaimable (LRU) until allocation pressure evicts.
-            self._prefix_index: Dict[bytes, int] = {}
-            self._block_chain: Dict[int, bytes] = {}
-            self._reclaimable: "OrderedDict[int, None]" = OrderedDict()
-            # Hits per LIVE index entry (reuse depth): the /debug/cache
-            # census and the hot-chain top-K read this; entries drop
-            # with their index entry on eviction/invalidation.
-            self._chain_hits: Dict[bytes, int] = {}
-            # Eviction accounting by cause (registry twin:
-            # kfserving_tpu_generator_block_evictions_total).
-            # Capacity evictions split by fate: spilled (the chain
-            # survives in the host KV tier) vs dropped (the drop-on-
-            # evict baseline — no tier, no chain, or a failed spill).
-            self.block_evictions: Dict[str, int] = {
-                "capacity_dropped": 0, "capacity_spilled": 0,
-                "index_invalidation": 0, "zombie_deferral": 0}
-            self.prefill_tokens_saved = 0
-            # (release_at_decode_step, [block ids]) — see
-            # _free_slot_state for why release is deferred.
-            self._deferred_frees: deque = deque()
-            # slot -> provisional prefix registrations of its last
-            # plan; confirmed once the prefill enqueues, deregistered
-            # if the enqueue fails (the blocks were never written).
-            self._plan_regs: Dict[int, List[Tuple[bytes, int]]] = {}
-            self.prefix_hits = 0
-            self.prefix_misses = 0
-            # -- host KV tier (engine/kv_tier.py) ----------------------
-            # Capacity-evicted prefix blocks spill to a host-RAM mmap
-            # tier instead of being dropped; a returning turn's plan
-            # probes device index -> host tier -> re-prefill.  Off by
-            # default (host_tier_blocks=0); KFS_KV_TIER_BLOCKS is the
-            # env twin for server deployments.
-            if host_tier_blocks is None:
-                try:
-                    host_tier_blocks = int(os.environ.get(
-                        "KFS_KV_TIER_BLOCKS", "0"))
-                except ValueError:
-                    host_tier_blocks = 0
-            self.kv_tier = None
-            if host_tier_blocks and int(host_tier_blocks) > 0:
-                from kfserving_tpu.engine.kv_tier import HostKVTier
-
-                block_payload = (2 * n_layers * bs * cfg.num_heads
-                                 * cfg.head_dim
-                                 * np.dtype(cache_dtype).itemsize)
-                self.kv_tier = HostKVTier(
-                    block_bytes=block_payload,
-                    capacity_blocks=int(host_tier_blocks),
-                    directory=(host_tier_dir
-                               or os.environ.get("KFS_KV_TIER_DIR")),
-                    model=self.name)
-            # Spills awaiting their device gather: (chain, block).
-            # Appended under _block_lock at eviction time; drained on
-            # the enqueue executor BEFORE any dispatch that could
-            # rewrite the evicted block (same-thread FIFO is the
-            # ordering proof — the gather's snapshot always precedes
-            # the overwrite's dispatch).
-            self._spill_pending: List[Tuple[bytes, int]] = []
-            # Host-tier fault-backs awaiting their pool insert:
-            # (chain, block, request, primary).  primary=False rows
-            # are coalesced riders on the same chain's single read.
-            self._faultback_pending: List[Tuple[bytes, int, Any,
-                                                bool]] = []
-            # chain -> destination block of a PENDING (undrained)
-            # fault-back: a second plan in the same admission batch
-            # shares the block instead of reading the tier twice
-            # (single-flight).  Guarded by _block_lock.
-            self._faultback_by_chain: Dict[bytes, int] = {}
-            self.host_tier_tokens_saved = 0
-        else:
-            self.kv_tier = None  # host tier is paged-mode only
-            cache_shape = (self.max_slots, self.max_seq,
-                           cfg.num_heads, cfg.head_dim)
-            self._cache_shape = cache_shape
-            self._caches = [
-                (jnp.zeros(cache_shape, cache_dtype),
-                 jnp.zeros(cache_shape, cache_dtype))
-                for _ in range(n_layers)
-            ]
-        # -- chunked prefill (paged mode only) -------------------------
+            block_payload = (2 * n_layers * bs * cfg.num_heads
+                             * cfg.head_dim
+                             * np.dtype(cache_dtype).itemsize)
+            self.kv_tier = HostKVTier(
+                block_bytes=block_payload,
+                capacity_blocks=int(host_tier_blocks),
+                directory=(host_tier_dir
+                           or os.environ.get("KFS_KV_TIER_DIR")),
+                model=self.name)
+        # Spills awaiting their device gather: (chain, block).
+        # Appended under _block_lock at eviction time; drained on
+        # the enqueue executor BEFORE any dispatch that could
+        # rewrite the evicted block (same-thread FIFO is the
+        # ordering proof — the gather's snapshot always precedes
+        # the overwrite's dispatch).
+        self._spill_pending: List[Tuple[bytes, int]] = []
+        # Host-tier fault-backs awaiting their pool insert:
+        # (chain, block, request, primary).  primary=False rows
+        # are coalesced riders on the same chain's single read.
+        self._faultback_pending: List[Tuple[bytes, int, Any,
+                                            bool]] = []
+        # chain -> destination block of a PENDING (undrained)
+        # fault-back: a second plan in the same admission batch
+        # shares the block instead of reading the tier twice
+        # (single-flight).  Guarded by _block_lock.
+        self._faultback_by_chain: Dict[bytes, int] = {}
+        self.host_tier_tokens_saved = 0
+        # -- chunked prefill -------------------------------------------
         # A cold prompt longer than prefill_chunk_tokens lands in
         # fixed-width chunks that ride the in-flight FIFO between
         # decode waves instead of one monolithic prefill dispatch —
@@ -443,11 +432,6 @@ class GenerationEngine:
         self.prefill_chunk_tokens = (int(prefill_chunk_tokens)
                                      if prefill_chunk_tokens else None)
         if self.prefill_chunk_tokens is not None:
-            if self.block_size is None:
-                raise InvalidInput(
-                    "prefill_chunk_tokens requires the paged cache "
-                    "(set block_size): chunk state is carried in the "
-                    "block table")
             if self.prefill_chunk_tokens % self.block_size != 0:
                 raise InvalidInput(
                     f"prefill_chunk_tokens {self.prefill_chunk_tokens} "
@@ -534,14 +518,12 @@ class GenerationEngine:
             # (parallel/sharding.py transformer_rules) — cache writes
             # and decode attention stay device-local per head group;
             # the per-layer psum after the out-projection is the only
-            # collective.  A paged pool's last axis is H*D: splitting
-            # it over tp gives the same head groups.
+            # collective.  The pool's last axis is H*D: splitting it
+            # over tp gives the same head groups.
             tp = mesh.shape.get("tp", 1)
             heads_axis = "tp" if cfg.num_heads % max(tp, 1) == 0 else None
-            spec = ((None, None, heads_axis, None)
-                    if self.block_size is None
-                    else (None, None, heads_axis))
-            sharding = NamedSharding(mesh, PartitionSpec(*spec))
+            sharding = NamedSharding(
+                mesh, PartitionSpec(None, None, heads_axis))
             self._caches = [
                 (jax.device_put(k, sharding), jax.device_put(v, sharding))
                 for k, v in self._caches
@@ -632,7 +614,6 @@ class GenerationEngine:
             return chosen_lp, top_ids.astype(jnp.int32), top_lps
 
         k_steps = self.steps_per_call
-        paged = self.block_size is not None
 
         def decode_fn(variables, caches, table, tokens, positions,
                       temps, top_ks, top_ps, seeds):
@@ -648,8 +629,7 @@ class GenerationEngine:
             round trip."""
             def step(carry, _):
                 caches, tokens, positions = carry
-                kv = ([(k, v, table) for k, v in caches] if paged
-                      else caches)
+                kv = [(k, v, table) for k, v in caches]
                 (logits, new_caches), pairs = apply(
                     variables, tokens[:, None], positions=positions,
                     kv_cache=kv)
@@ -713,13 +693,12 @@ class GenerationEngine:
                                             kv_lengths=lengths,
                                             return_cache=True,
                                             logit_positions=lengths - 1)
-            if paged:
-                # Leave the program as the pool stores them,
-                # [B, L, H*D]: the insert is then a scatter of whole
-                # blocks, where [B, L, H, D] results (L minor-most on
-                # the chip) would be transposed on their way in.
-                caches = [tuple(x.reshape(x.shape[:2] + (-1,))
-                                for x in kv) for kv in caches]
+            # Leave the program as the pool stores them, [B, L, H*D]:
+            # the insert is then a scatter of whole blocks, where
+            # [B, L, H, D] results (L minor-most on the chip) would be
+            # transposed on their way in.
+            caches = [tuple(x.reshape(x.shape[:2] + (-1,)) for x in kv)
+                      for kv in caches]
             last = logits[:, 0]
             first_tokens = sample(last, temps, top_ks, top_ps, seeds,
                                   lengths)
@@ -731,33 +710,32 @@ class GenerationEngine:
         # One executable per prompt bucket (jit caches by shape).
         self._prefill = jax.jit(prefill_fn)
 
-        if paged:
-            def chunk_prefill_fn(variables, caches, table, ids, qpos,
-                                 last_idx, temps, top_ks, top_ps,
-                                 seeds, noise_pos):
-                """One chunk of a cold prompt: ids [1, C] write their
-                k/v through the slot's block table at absolute
-                positions qpos [1, C] (padding rows of a partial final
-                chunk park on an out-of-range sentinel and drop), and
-                attend per-query-causally over the pool — earlier
-                chunks are already resident, so cross-chunk attention
-                reads them exactly like decode does.  The head runs
-                only at last_idx; the sampled token matters only for
-                the FINAL chunk (it becomes the stream's first token,
-                noise-keyed on the full prompt length for parity with
-                monolithic prefill) — earlier chunks discard it."""
-                kv = [(k, v, table) for k, v in caches]
-                logits, new_caches = module.apply(
-                    variables, ids, positions=qpos, kv_cache=kv,
-                    logit_positions=last_idx)
-                lg = logits[:, 0]
-                first = sample(lg, temps, top_ks, top_ps, seeds,
-                               noise_pos)
-                chosen_lp, top_ids, top_lps = logprob_of(lg, first)
-                return first, new_caches, chosen_lp, top_ids, top_lps
+        def chunk_prefill_fn(variables, caches, table, ids, qpos,
+                             last_idx, temps, top_ks, top_ps,
+                             seeds, noise_pos):
+            """One chunk of a cold prompt: ids [1, C] write their
+            k/v through the slot's block table at absolute
+            positions qpos [1, C] (padding rows of a partial final
+            chunk park on an out-of-range sentinel and drop), and
+            attend per-query-causally over the pool — earlier
+            chunks are already resident, so cross-chunk attention
+            reads them exactly like decode does.  The head runs
+            only at last_idx; the sampled token matters only for
+            the FINAL chunk (it becomes the stream's first token,
+            noise-keyed on the full prompt length for parity with
+            monolithic prefill) — earlier chunks discard it."""
+            kv = [(k, v, table) for k, v in caches]
+            logits, new_caches = module.apply(
+                variables, ids, positions=qpos, kv_cache=kv,
+                logit_positions=last_idx)
+            lg = logits[:, 0]
+            first = sample(lg, temps, top_ks, top_ps, seeds,
+                           noise_pos)
+            chosen_lp, top_ids, top_lps = logprob_of(lg, first)
+            return first, new_caches, chosen_lp, top_ids, top_lps
 
-            self._chunk_prefill = jax.jit(chunk_prefill_fn,
-                                          donate_argnums=(1,))
+        self._chunk_prefill = jax.jit(chunk_prefill_fn,
+                                      donate_argnums=(1,))
 
         self._spec_draft_fn = None
         if self.spec_tokens > 0:
@@ -788,8 +766,7 @@ class GenerationEngine:
                 dispatch, and positions advance monotonically)."""
                 tokens = jnp.concatenate(
                     [last_tokens[:, None], draft_toks], axis=1)
-                kv = ([(k, v, table) for k, v in caches] if paged
-                      else caches)
+                kv = [(k, v, table) for k, v in caches]
                 s_rows = tokens.shape[0]
                 gather = jnp.broadcast_to(
                     jnp.arange(spec_kp1, dtype=jnp.int32)[None, :],
@@ -834,43 +811,21 @@ class GenerationEngine:
                     jax, self._draft_module, self.max_slots,
                     self._draft_window, self.spec_tokens)
 
-        if paged:
-            from kfserving_tpu.ops.paged_attention import paged_insert
-
-            def insert_fn(caches, new_caches, dest_blocks):
-                """Scatter a prefill batch's k/v into pool blocks.
-                dest_blocks [B, chunks] int32; -1 chunks drop (bucket
-                padding rows, and prefix-cache hits whose shared
-                blocks already hold the data)."""
-                out = []
-                for (pk, pv), (k_new, v_new) in zip(caches,
-                                                    new_caches):
-                    pk, pv = paged_insert(pk, pv, k_new, v_new,
-                                          dest_blocks, None)
-                    out.append((pk, pv))
-                return out
-        else:
-            def insert_fn(caches, new_caches, slots):
-                """Scatter a prefill batch's k/v into its slots.
-                slots is [B] int32; padding rows carry the
-                out-of-bounds sentinel max_slots and mode='drop'
-                discards them (a prefill batch is padded to a pow2 B
-                bucket to bound compile count)."""
-                out = []
-                for (k_cache, v_cache), (k_new, v_new) in zip(
-                        caches, new_caches):
-                    lb = k_new.shape[1]
-                    out.append((
-                        k_cache.at[slots, :lb].set(
-                            k_new.astype(k_cache.dtype), mode="drop"),
-                        v_cache.at[slots, :lb].set(
-                            v_new.astype(v_cache.dtype), mode="drop"),
-                    ))
-                return out
+        def insert_fn(caches, new_caches, dest_blocks):
+            """Scatter a prefill batch's k/v into pool blocks.
+            dest_blocks [B, chunks] int32; -1 chunks drop (bucket
+            padding rows, and prefix-cache hits whose shared blocks
+            already hold the data)."""
+            out = []
+            for (pk, pv), (k_new, v_new) in zip(caches, new_caches):
+                pk, pv = paged_attention.paged_insert(
+                    pk, pv, k_new, v_new, dest_blocks, None)
+                out.append((pk, pv))
+            return out
 
         self._insert = jax.jit(insert_fn, donate_argnums=(0,))
 
-        if paged and self.kv_tier is not None:
+        if self.kv_tier is not None:
             def gather_blocks_fn(caches, idx):
                 """Snapshot the k/v of pool blocks `idx` [N] as
                 standalone device arrays (NOT donating the caches):
@@ -919,7 +874,7 @@ class GenerationEngine:
         self.prefills = 0           # prefill dispatches
         self.prefill_requests = 0   # requests admitted through them
         self.requests_finished = 0
-        self.preemptions = 0        # paged: growth-pressure requeues
+        self.preemptions = 0        # growth-pressure requeues
         self.prefill_chunks = 0     # chunked-prefill dispatches
         self.prefill_chunks_skipped = 0  # whole-chunk prefix hits
         self.chunked_admissions = 0
@@ -1106,12 +1061,11 @@ class GenerationEngine:
             raise InvalidInput(
                 f"prompt length {ids.size} exceeds the largest prefill "
                 f"bucket {self.prefill_buckets[-1]}")
-        if self.block_size is not None:
-            need = -(-int(ids.size) // self.block_size)
-            if need > self.num_blocks:
-                raise InvalidInput(
-                    f"prompt needs {need} cache blocks but the pool "
-                    f"holds {self.num_blocks}")
+        need = -(-int(ids.size) // self.block_size)
+        if need > self.num_blocks:
+            raise InvalidInput(
+                f"prompt needs {need} cache blocks but the pool "
+                f"holds {self.num_blocks}")
         if max_new_tokens < 1:
             raise InvalidInput("max_new_tokens must be >= 1")
         if not 0.0 < float(top_p) <= 1.0:
@@ -1257,52 +1211,51 @@ class GenerationEngine:
                 for b, (real, padded)
                 in sorted(self._prefill_bucket_tokens.copy().items())
                 if padded > 0}
-        if self.block_size is not None:
-            with self._block_lock:
-                refd = int(np.sum(self._block_ref > 0))
-                resident = sum(s.length for s in self._slots
-                               if s is not None)
-                # Fragmentation over per-slot TABLE blocks, not refd:
-                # a shared prefix block appears in every sharer's
-                # table AND every sharer's length, so numerator and
-                # denominator count it the same number of times —
-                # against refd (which counts it once) the ratio went
-                # negative exactly in the shared-prompt regime.
-                table_blocks = int(np.sum(self._tables >= 0))
-                frag = (1.0 - resident
-                        / (table_blocks * self.block_size)
-                        if table_blocks else 0.0)
-                out["paged"] = {
-                    "block_size": self.block_size,
-                    "pool_blocks": self.num_blocks,
-                    # Canonical names, matching the timeline pool
-                    # counter samples (_record_pool_sample).  The
-                    # deprecated blocks_free/blocks_reclaimable aliases
-                    # (ISSUE 13's one-release grace) are gone.
-                    "free_blocks": len(self._free_blocks),
-                    "reclaimable_blocks": len(self._reclaimable),
-                    "prefix_hits": self.prefix_hits,
-                    "prefix_misses": self.prefix_misses,
-                    "prefill_tokens_saved": self.prefill_tokens_saved,
-                    "index_entries": len(self._prefix_index),
-                    "pool_occupancy_ratio": round(
-                        min(1.0, refd / max(1, self.num_blocks)), 4),
-                    "fragmentation_ratio": round(
-                        min(1.0, max(0.0, frag)), 4),
-                    "evictions": dict(self.block_evictions),
-                    "preemptions": self.preemptions,
-                }
-            if self.kv_tier is not None:
-                out["paged"]["host_tier_tokens_saved"] = \
-                    self.host_tier_tokens_saved
-                out["host_tier"] = self.kv_tier.debug()
-            if self.prefill_chunk_tokens is not None:
-                out["chunked_prefill"] = {
-                    "chunk_tokens": self.prefill_chunk_tokens,
-                    "admissions": self.chunked_admissions,
-                    "chunks_dispatched": self.prefill_chunks,
-                    "chunks_skipped_shared": self.prefill_chunks_skipped,
-                }
+        with self._block_lock:
+            refd = int(np.sum(self._block_ref > 0))
+            resident = sum(s.length for s in self._slots
+                           if s is not None)
+            # Fragmentation over per-slot TABLE blocks, not refd:
+            # a shared prefix block appears in every sharer's
+            # table AND every sharer's length, so numerator and
+            # denominator count it the same number of times —
+            # against refd (which counts it once) the ratio went
+            # negative exactly in the shared-prompt regime.
+            table_blocks = int(np.sum(self._tables >= 0))
+            frag = (1.0 - resident
+                    / (table_blocks * self.block_size)
+                    if table_blocks else 0.0)
+            out["paged"] = {
+                "block_size": self.block_size,
+                "pool_blocks": self.num_blocks,
+                # Canonical names, matching the timeline pool
+                # counter samples (_record_pool_sample).  The
+                # deprecated blocks_free/blocks_reclaimable aliases
+                # (ISSUE 13's one-release grace) are gone.
+                "free_blocks": len(self._free_blocks),
+                "reclaimable_blocks": len(self._reclaimable),
+                "prefix_hits": self.prefix_hits,
+                "prefix_misses": self.prefix_misses,
+                "prefill_tokens_saved": self.prefill_tokens_saved,
+                "index_entries": len(self._prefix_index),
+                "pool_occupancy_ratio": round(
+                    min(1.0, refd / max(1, self.num_blocks)), 4),
+                "fragmentation_ratio": round(
+                    min(1.0, max(0.0, frag)), 4),
+                "evictions": dict(self.block_evictions),
+                "preemptions": self.preemptions,
+            }
+        if self.kv_tier is not None:
+            out["paged"]["host_tier_tokens_saved"] = \
+                self.host_tier_tokens_saved
+            out["host_tier"] = self.kv_tier.debug()
+        if self.prefill_chunk_tokens is not None:
+            out["chunked_prefill"] = {
+                "chunk_tokens": self.prefill_chunk_tokens,
+                "admissions": self.chunked_admissions,
+                "chunks_dispatched": self.prefill_chunks,
+                "chunks_skipped_shared": self.prefill_chunks_skipped,
+            }
         if self.spec_tokens:
             out["speculative"] = self.spec_debug()
         return out
@@ -1346,11 +1299,6 @@ class GenerationEngine:
         exact feed prefix-affinity routing (ROADMAP item 3) and the
         LRU HBM residency manager (item 4) will read, federated by
         the router under the `replica` label."""
-        if self.block_size is None:
-            out = {"paged": False}
-            if self.spec_tokens:
-                out["speculative"] = self.spec_debug()
-            return out
         with self._block_lock:
             census = {chain: self._chain_hits.get(chain, 0)
                       for chain in self._prefix_index}
@@ -1383,7 +1331,7 @@ class GenerationEngine:
             ret["speculative"] = self.spec_debug()
         return ret
 
-    # -- paged-cache bookkeeping -------------------------------------------
+    # -- block-pool bookkeeping --------------------------------------------
     # All mutation happens under _block_lock: the enqueue thread
     # allocates during prefill planning is NOT true — planning runs on
     # the loop thread, but cancel() (loop) can race wave enqueues
@@ -1454,8 +1402,6 @@ class GenerationEngine:
         """Remove a slot's PROVISIONAL prefix registrations (its
         prefill never enqueued, so the registered blocks hold no
         data).  No-op once the plan was confirmed."""
-        if self.block_size is None:
-            return
         with self._block_lock:
             dropped = 0
             for chain, blk in self._plan_regs.pop(slot, []):
@@ -1483,9 +1429,8 @@ class GenerationEngine:
     def _confirm_plan(self, slot: int) -> None:
         """The slot's prefill is enqueued: its registrations are
         backed by real (dispatched) writes."""
-        if self.block_size is not None:
-            with self._block_lock:
-                self._plan_regs.pop(slot, None)
+        with self._block_lock:
+            self._plan_regs.pop(slot, None)
 
     def _schedule_block_release(self, slot: int) -> None:
         """Queue a slot's blocks for release.  Release is DEFERRED by
@@ -1494,8 +1439,6 @@ class GenerationEngine:
         tail blocks — releasing (and possibly reallocating) those
         blocks inside that window would let a zombie wave corrupt
         another request's cache."""
-        if self.block_size is None:
-            return
         with self._block_lock:
             blocks = [int(b) for b in self._tables[slot] if b >= 0]
             self._tables[slot, :] = -1
@@ -1504,8 +1447,6 @@ class GenerationEngine:
                 (self.decode_steps + self.pipeline_depth + 1, blocks))
 
     def _process_deferred_frees(self, force: bool = False) -> None:
-        if self.block_size is None:
-            return
         released = 0
         while self._deferred_frees and (
                 force or self._deferred_frees[0][0] <= self.decode_steps):
@@ -1757,7 +1698,7 @@ class GenerationEngine:
         stretches the swap window."""
         zeros = {"exported": 0, "skipped": 0, "dropped": 0,
                  "failed": 0}
-        if self.block_size is None or self.kv_tier is None:
+        if self.kv_tier is None:
             return zeros
         deadline = time.monotonic() + max(0.0, float(budget_s))
         try:
@@ -1909,8 +1850,7 @@ class GenerationEngine:
         )
 
         out = {"imported": 0, "skipped": 0, "failed": 0}
-        if self.block_size is None or self.kv_tier is None or \
-                not pairs:
+        if self.kv_tier is None or not pairs:
             return out
         try:
             if faults.configured(fault_sites.ENGINE_KV_IMPORT):
@@ -2215,8 +2155,6 @@ class GenerationEngine:
         pipeline_depth * K decode steps (device positions run ahead
         of the host by up to that).  Returns slots that could not
         grow — the caller fails those requests."""
-        if self.block_size is None:
-            return []
         bs = self.block_size
         horizon = self.steps_per_call * self.pipeline_depth + 1
         if self.spec_tokens:
@@ -2254,16 +2192,12 @@ class GenerationEngine:
         return failed
 
     def _table_device(self):
-        """Device copy of the block tables for a dispatch (dense mode:
-        a dummy — the jitted program ignores it)."""
-        jnp = self._jnp
-        if self.block_size is None:
-            return jnp.zeros((1,), jnp.int32)
+        """Device copy of the block tables for a dispatch."""
         with self._block_lock:
             # Copy under the lock: cancel() clears rows on the loop
             # thread while waves enqueue on the enqueue thread.
             snap = self._tables.copy()
-        return jnp.asarray(snap)
+        return self._jnp.asarray(snap)
 
     def _record_pool_sample(self) -> None:
         """Occupancy counter sample for the event timeline (rendered
@@ -2280,10 +2214,9 @@ class GenerationEngine:
             # describes — untagged samples would blend two engines'
             # pools into one meaningless ratio.
             "engine": self.name,
+            "free_blocks": len(self._free_blocks),
+            "reclaimable_blocks": len(self._reclaimable),
         }
-        if self.block_size is not None:
-            values["free_blocks"] = len(self._free_blocks)
-            values["reclaimable_blocks"] = len(self._reclaimable)
         TIMELINE.counter("pool", values)
 
     # -- scheduler ---------------------------------------------------------
@@ -2360,20 +2293,17 @@ class GenerationEngine:
         bucket, up to the free slot count — they ride ONE prefill
         dispatch (or up to the row count the runtime has shown it can
         hold, see _prefill_refused).  Strict FIFO: a different-bucket
-        request at the front is never jumped.  In paged mode each
-        taken request's prompt
-        blocks are planned (allocated/prefix-shared) HERE on the loop
-        thread; a request the pool cannot hold yet stays pending (it
-        admits when slots release blocks).  Returns
-        (group, slots, bucket, dest_rows) — dest_rows is None for
-        dense mode."""
+        request at the front is never jumped.  Each taken request's
+        prompt blocks are planned (allocated/prefix-shared) HERE on
+        the loop thread; a request the pool cannot hold yet stays
+        pending (it admits when slots release blocks).  Returns
+        (group, slots, bucket, dest_rows)."""
         free = [i for i, s in enumerate(self._slots) if s is None]
         if self._prefill_rows_cap is not None:
             free = free[:self._prefill_rows_cap]
         group: List[_Request] = []
         bucket = 0
-        dest_rows: Optional[List[List[int]]] = (
-            [] if self.block_size is not None else None)
+        dest_rows: List[List[int]] = []
         while self._pending and len(group) < len(free):
             if self._is_cold(self._pending[0]):
                 break  # cold prompts take the chunked path
@@ -2382,13 +2312,12 @@ class GenerationEngine:
                 bucket = b
             elif b != bucket:
                 break
-            if dest_rows is not None:
-                plan = self._plan_prompt_blocks(self._pending[0],
-                                                free[len(group)],
-                                                force_miss=force_miss)
-                if plan is None:
-                    break  # pool pressure: wait for released blocks
-                dest_rows.append(plan)
+            plan = self._plan_prompt_blocks(self._pending[0],
+                                            free[len(group)],
+                                            force_miss=force_miss)
+            if plan is None:
+                break  # pool pressure: wait for released blocks
+            dest_rows.append(plan)
             group.append(self._pending.popleft())
         now = time.perf_counter()
         for req in group:
@@ -2436,8 +2365,7 @@ class GenerationEngine:
         return True
 
     # -- chunked prefill ---------------------------------------------------
-    # A COLD prompt (longer than prefill_chunk_tokens, paged mode)
-    # lands in fixed-width, block-aligned chunks that ride the same
+    # A COLD prompt (longer than prefill_chunk_tokens) lands in fixed-width, block-aligned chunks that ride the same
     # in-flight FIFO as decode waves — the scheduler alternates chunk
     # and wave dispatches, so live streams stall per-chunk instead of
     # per-prompt.  Carried state: the slot's block table holds every
@@ -2784,8 +2712,7 @@ class GenerationEngine:
             admitted = False
             while (not self._growth_starved and self._pending
                    and self._free_slot() is not None):
-                force_miss = (self.block_size is not None
-                              and await self._probe_prefix_fault())
+                force_miss = await self._probe_prefix_fault()
                 if self._is_cold(self._pending[0]):
                     # Cold long prompt: chunked admission — one slot,
                     # block-aligned chunks interleaving with decode
@@ -2801,7 +2728,7 @@ class GenerationEngine:
                     group, slots, bucket, dest_rows = \
                         self._take_prefill_group(force_miss=force_miss)
                 if not group:
-                    break  # paged pool pressure: wait for frees
+                    break  # pool pressure: wait for frees
                 if (self.kv_tier is not None
                         and self._faultback_pending
                         and not await loop.run_in_executor(
@@ -3041,9 +2968,8 @@ class GenerationEngine:
                         # (by token budget) within the waves already
                         # in flight — a speculative wave here could
                         # only decode garbage (the fixed-depth-2
-                        # failure mode: ~45% wasted dispatches when
-                        # finishes cluster, r5 A/B depth_speedup
-                        # 0.98).  Staggered traffic keeps remaining
+                        # failure mode when finishes cluster).
+                        # Staggered traffic keeps remaining
                         # work past the horizon and still gets the
                         # full configured depth.
                         self.suppressed_waves += 1
@@ -3346,8 +3272,7 @@ class GenerationEngine:
     def _enqueue_prefill_group(self, group: List[_Request],
                                slots: List[int],
                                bucket: int,
-                               dest_rows: Optional[List[List[int]]]
-                               = None):
+                               dest_rows: List[List[int]]):
         """Runs on the enqueue executor: dispatch one bucket-padded
         prefill for the WHOLE group (a burst of arrivals rides one
         dispatch), chain the cache insert and the device-feed scatter
@@ -3417,20 +3342,16 @@ class GenerationEngine:
                 self._moe.note("prefill", out[5])
         with TIMELINE.span(LAUNCH, "engine.prep.insert"):
             slot_d = jnp.asarray(slot_arr)
-            if dest_rows is not None:
-                # Paged: per-chunk destination blocks (-1 = shared
-                # prefix hit or padding row — the scatter drops those
-                # chunks).
-                chunks = bucket // self.block_size
-                dest = np.full((b_bucket, chunks), -1, np.int32)
-                for i, row in enumerate(dest_rows):
-                    dest[i, :len(row)] = row
-                insert_arg = jnp.asarray(dest)
-            else:
-                insert_arg = slot_d
+            # Per-chunk destination blocks (-1 = shared prefix hit or
+            # padding row — the scatter drops those chunks).
+            chunks = bucket // self.block_size
+            dest = np.full((b_bucket, chunks), -1, np.int32)
+            for i, row in enumerate(dest_rows):
+                dest[i, :len(row)] = row
+            dest_d = jnp.asarray(dest)
         with TIMELINE.span(LAUNCH, "engine.launch.insert", rows=b):
             self._caches = self._insert(self._caches, new_caches,
-                                        insert_arg)
+                                        dest_d)
         # The admitted slots' first feed token/position land in the
         # device-resident feed arrays; rows of slots NOT in this group
         # keep their device values (the last enqueued wave's outputs,
@@ -3569,10 +3490,9 @@ class GenerationEngine:
                 continue
             self._occupied_slot_steps += k
             s.req.decode_device_ms += share_ms
-            if self.block_size is not None:
-                s.req.blocks_held = max(
-                    s.req.blocks_held,
-                    -(-int(s.length) // self.block_size))
+            s.req.blocks_held = max(
+                s.req.blocks_held,
+                -(-int(s.length) // self.block_size))
             # Roofline accounting over LIVE rows: matmul FLOPs per fed
             # token plus attention over the slot's resident context
             # (length at wave start — within a K-step wave the drift
@@ -3710,9 +3630,8 @@ class GenerationEngine:
         as ONE chained device program pair — the verify consumes the
         draft's output handle, so no host round trip separates them
         and the fetch below joins both.  Rows not in `eligible` park
-        on the max_seq position sentinel: their writes drop (paged OOB
-        sentinel / dense mode='drop') and their samples are
-        discarded."""
+        on the max_seq position sentinel: their writes drop (the
+        out-of-range block index) and their samples are discarded."""
         jnp = self._jnp
         self._drain_spills()
         S = self.max_slots
@@ -3860,10 +3779,9 @@ class GenerationEngine:
             s.req.decode_device_ms += share_ms
             s.req.spec_draft_ms += draft_share
             s.req.spec_verify_ms += verify_share
-            if self.block_size is not None:
-                s.req.blocks_held = max(
-                    s.req.blocks_held,
-                    -(-int(s.length + a) // self.block_size))
+            s.req.blocks_held = max(
+                s.req.blocks_held,
+                -(-int(s.length + a) // self.block_size))
             # Roofline over ACCEPTED tokens only: rejected positions
             # burn device time without useful FLOPs (that waste is the
             # acceptance-rate trade, visible in goodput_ratio).
@@ -3920,6 +3838,15 @@ class GenerationEngine:
         jax = self._jax
         return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
                    for x in jax.tree.leaves(self.draft_variables))
+
+
+def derive_block_size(max_seq: int, prefill_buckets: List[int]) -> int:
+    """The pool's block size where the caller set none: the largest
+    divisor of 128 that divides max_seq and every prefill bucket (a
+    block never straddles a slot's end, and the insert writes whole
+    blocks).  128, which the Pallas kernels need, wherever the lengths
+    are multiples of it; 16 for pow-2 buckets from 16."""
+    return math.gcd(128, int(max_seq), *(int(b) for b in prefill_buckets))
 
 
 def _pow2_buckets(max_seq: int) -> List[int]:

@@ -200,8 +200,8 @@ class JaxEngine:
         self.pipeline_depth = max(1, pipeline_depth)
         # Param provenance ("mmap" | "checkpoint" | "init" | None):
         # lets a scrape tell a mapped-warm successor from a replica
-        # that paid full materialization — the lifecycle SOAK's
-        # per-replica evidence that the mmap cache actually engaged.
+        # that paid full materialization: per-replica evidence that
+        # the mmap cache actually engaged.
         self.param_source = param_source
         # Identity for the KFS_SANITIZE recompile assertion: each
         # engine declares its own warmup, so one engine warming never
@@ -399,9 +399,8 @@ class JaxEngine:
         minimal=True warms only the LARGEST batch bucket per seq
         bucket — the recycle-successor mode: the predecessor populated
         the persistent compile cache, so the remaining programs load
-        on demand in sub-seconds, and the full grid's ~RTT-per-program
-        dispatch tax was the dominant term of successor load time
-        (measured r5 SOAK: warmup was 11 of a warm successor's 21 s)."""
+        on demand, where the full grid pays a dispatch round trip per
+        program inside the successor's load time."""
         start = time.perf_counter()
         batch_buckets = buckets or self.batch_buckets.buckets
         if minimal:

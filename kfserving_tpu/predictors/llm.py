@@ -28,19 +28,25 @@ config.json schema:
       "max_new_tokens": 64,        # default generation budget
       "temperature": 0.0,          # default sampling temperature
       "tokenizer": "byte",         # "byte" | "hf:<name>"
-      "block_size": 128,           # paged KV cache (optional): HBM
-      "cache_blocks": 48,          #   scales with resident tokens,
-                                   #   shared prompt prefixes share
-                                   #   blocks; default pool = dense
-                                   #   parity (max_slots*max_seq).
+      "block_size": 128,           # the KV cache is a pool of
+      "cache_blocks": 48,          #   blocks of block_size tokens:
+                                   #   HBM scales with resident
+                                   #   tokens, shared prompt prefixes
+                                   #   share blocks.  block_size
+                                   #   unset is derived:
+                                   #   gcd(128, max_seq, every
+                                   #   prefill bucket).  cache_blocks
+                                   #   unset holds every position of
+                                   #   every slot (max_slots*max_seq
+                                   #   / block_size).
                                    #   NOTE: the TPU Pallas paged
-                                   #   kernel requires block_size to
+                                   #   kernels require block_size to
                                    #   be a multiple of 128 (lane
-                                   #   width); other sizes serve
-                                   #   correctly but fall back to the
-                                   #   slower XLA gather path (logged
-                                   #   once at load)
-      "prefill_chunk_tokens": 512, # chunked prefill (paged only):
+                                   #   width); other sizes, set or
+                                   #   derived, serve correctly on
+                                   #   the slower XLA gather path
+                                   #   (logged once at load)
+      "prefill_chunk_tokens": 512, # chunked prefill:
                                    #   a COLD prompt longer than this
                                    #   lands in block-aligned chunks
                                    #   interleaved with decode waves,
@@ -51,7 +57,7 @@ config.json schema:
                                    #   decode wave (steps_per_call
                                    #   decode steps).  Must be a
                                    #   multiple of block_size.
-      "host_tier_blocks": 256,     # host KV tier (paged only):
+      "host_tier_blocks": 256,     # host KV tier:
                                    #   capacity-evicted prefix blocks
                                    #   spill to a host-RAM mmap tier
                                    #   of this many blocks and fault
@@ -128,20 +134,26 @@ EOS_ID = 257
 _warned_block_size = False
 
 
-def _warn_paged_kernel_ineligible(block_size: int) -> None:
+def _warn_paged_kernel_ineligible(block_size: int,
+                                  derived: bool = False) -> None:
     """One warning per process: a block_size that isn't a 128-multiple
     silently loses the Pallas paged-kernel speedup on TPU (the XLA
     gather fallback serves correctly) — surface the config smell
-    instead of hiding a perf cliff (ADVICE r5)."""
+    instead of hiding a perf cliff (ADVICE r5).  `derived`: the caller
+    set none and the engine worked it out from max_seq and the
+    prefill buckets."""
     global _warned_block_size
     if _warned_block_size:
         return
     _warned_block_size = True
     logger.warning(
-        "block_size=%d is not a multiple of 128: the TPU Pallas paged-"
-        "attention kernel is ineligible and decode uses the slower XLA "
-        "gather path. Use a 128-multiple block_size to enable it.",
-        block_size)
+        "block_size=%d%s is not a multiple of 128: the TPU Pallas "
+        "paged-attention kernel is ineligible and decode uses the "
+        "slower XLA gather path. Use %s to enable it.", block_size,
+        " (derived: none was set, and max_seq and the prefill buckets "
+        "share no multiple of 128)" if derived else "",
+        "128-multiple lengths" if derived
+        else "a 128-multiple block_size")
 
 
 def _find_stop(text: str, stops: List[str]) -> int:
@@ -412,18 +424,19 @@ class GenerativeConfig:
         # device compute; 1 = strictly blocking, the A/B baseline).
         self.pipeline_depth = int(pipeline_depth)
         self.logprob_topk = int(logprob_topk)
-        # Paged KV cache: block_size enables it (HBM scales with
-        # resident tokens; identical prompt prefixes share blocks);
-        # cache_blocks sizes the pool (default: dense-parity capacity).
+        # The KV cache's block pool (HBM scales with resident tokens;
+        # identical prompt prefixes share blocks).  block_size None =
+        # the engine derives it, gcd(128, max_seq, prefill buckets);
+        # cache_blocks None = a block for every position of every slot.
         self.block_size = int(block_size) if block_size else None
         self.cache_blocks = (int(cache_blocks) if cache_blocks
                              else None)
-        # Chunked prefill (paged only): cold prompts longer than this
+        # Chunked prefill: cold prompts longer than this
         # land chunk-by-chunk between decode waves; adaptive depth
         # stops speculative waves that could only decode garbage.
         self.prefill_chunk_tokens = (int(prefill_chunk_tokens)
                                      if prefill_chunk_tokens else None)
-        # Host KV tier (paged only): capacity-evicted prefix blocks
+        # Host KV tier: capacity-evicted prefix blocks
         # spill to a host-RAM mmap tier of this many blocks instead of
         # dropping; 0/None = off (KFS_KV_TIER_BLOCKS is the env twin).
         self.host_tier_blocks = (int(host_tier_blocks)
@@ -489,8 +502,6 @@ class GenerativeModel(Model):
                 overrides=self.config_overrides)
             self.config = cfg
         self.tokenizer = build_tokenizer(cfg.tokenizer)
-        if cfg.block_size is not None and cfg.block_size % 128 != 0:
-            _warn_paged_kernel_ineligible(cfg.block_size)
 
         spec = create_model(cfg.architecture, **cfg.arch_kwargs)
         # mmap-first materialization (shared with JaxModel): a standby
@@ -563,6 +574,9 @@ class GenerativeModel(Model):
             adaptive_depth=cfg.adaptive_depth,
             speculative=speculative,
             mesh=mesh, name=self.name)
+        if engine.block_size % 128 != 0:
+            _warn_paged_kernel_ineligible(
+                engine.block_size, derived=cfg.block_size is None)
         if self.hbm is not None:
             # Generation residency = params + the slot cache pool,
             # plus the draft model's params when speculation runs one

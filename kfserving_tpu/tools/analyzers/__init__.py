@@ -87,10 +87,10 @@ def default_target() -> str:
 
 def default_targets() -> List[str]:
     """Everything a bare `kfs-lint` (and the fast-tier gate) scans:
-    the package tree plus the `benchmarks/` and `tests/` trees living
-    next to it when present — bench drivers and tests run the same
-    event-loop/device disciplines the package does, and a spin-loop
-    in a test hangs CI exactly like one in the scheduler would."""
+    the package tree plus the `tests/` tree living next to it when
+    present — tests run the same event-loop/device disciplines the
+    package does, and a spin-loop in a test hangs CI exactly like one
+    in the scheduler would."""
     pkg = default_target()
     roots = [pkg]
     repo = os.path.dirname(pkg)
@@ -98,8 +98,7 @@ def default_targets() -> List[str]:
     # in site-packages a sibling `tests/` dir is some OTHER
     # distribution's packaging accident, not ours to lint.
     if os.path.isfile(os.path.join(repo, "pyproject.toml")):
-        for extra in ("benchmarks", "tests"):
-            path = os.path.join(repo, extra)
-            if os.path.isdir(path):
-                roots.append(path)
+        tests = os.path.join(repo, "tests")
+        if os.path.isdir(tests):
+            roots.append(tests)
     return roots

@@ -16,8 +16,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument(
         "paths", nargs="*",
         help="files/directories to analyze (default: the installed "
-             "kfserving_tpu package plus the benchmarks/ and tests/ "
-             "trees next to it)")
+             "kfserving_tpu package plus the tests/ tree next to "
+             "it)")
     parser.add_argument(
         "--baseline", default=None,
         help="baseline JSON path (default: the committed "

@@ -217,7 +217,7 @@ def normalize_path(path: str) -> str:
     """Stable finding/baseline path identity, posix separators.
     Paths inside the checkout normalize relative to the REPO ROOT —
     not the CWD — so the committed baseline (keyed on
-    'benchmarks/...', 'kfserving_tpu/...') matches however and from
+    'tests/...', 'kfserving_tpu/...') matches however and from
     wherever the run was spelled.  Paths outside the checkout fall
     back to CWD-relative."""
     abspath = os.path.abspath(path)

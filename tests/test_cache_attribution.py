@@ -227,15 +227,6 @@ async def test_cache_debug_census_and_ratio_gauges(tiny):
         # Ratio stats stay inside the unit their suffix declares.
         assert 0.0 <= st["pool_occupancy_ratio"] <= 1.0
         assert 0.0 <= st["fragmentation_ratio"] <= 1.0
-        # Dense engines answer paged: false instead of crashing.
-        module, variables, _ = tiny
-        dense = GenerationEngine(module, variables, max_slots=2,
-                                 max_seq=MAX_SEQ,
-                                 prefill_buckets=[16, 32, MAX_SEQ])
-        try:
-            assert dense.cache_debug() == {"paged": False}
-        finally:
-            dense.shutdown_nowait()
     finally:
         await eng.close()
 
@@ -409,6 +400,15 @@ async def test_debug_cache_endpoint_matches_engine(tmp_path):
                         json={"text_input": SHARED_PROMPT + tail,
                               "parameters": {"max_tokens": 4}}) as r:
                     assert r.status == 200, await r.text()
+            # A finished request's blocks are released a few waves
+            # late (the zombie-wave deferral): read both views of an
+            # engine at rest, not of one still releasing.
+            for _ in range(200):
+                st = model.engine.stats()["paged"]
+                if (st["free_blocks"] + st["reclaimable_blocks"]
+                        == st["pool_blocks"]):
+                    break
+                await asyncio.sleep(0.05)
             async with s.get(f"{base}/debug/cache?top_k=3") as r:
                 assert r.status == 200
                 body = await r.json()
@@ -416,10 +416,9 @@ async def test_debug_cache_endpoint_matches_engine(tmp_path):
         st = model.engine.stats()["paged"]
         assert snap["paged"] is True
         assert snap["index_entries"] == st["index_entries"]
-        # Acceptance: the snapshot's pool view matches engine stats
-        # within one block (scrape vs. stats race on a live engine).
+        # Acceptance: the snapshot's pool view is the engine's.
         for key in ("free_blocks", "reclaimable_blocks"):
-            assert abs(snap["pool"][key] - st[key]) <= 1, key
+            assert snap["pool"][key] == st[key], key
         assert snap["pool"]["prefix_hits"] == st["prefix_hits"] >= 2
         assert len(snap["hot_chains"]) <= 3
         assert body["hbm"] is None  # no manager wired in this server

@@ -480,11 +480,16 @@ async def test_adaptive_depth_keeps_pipelining_for_long_streams(tiny):
 
 def test_chunked_validation(tiny):
     module, variables, _ = tiny
-    with pytest.raises(InvalidInput, match="paged"):
-        GenerationEngine(module, variables, max_slots=2,
-                         max_seq=MAX_SEQ,
-                         prefill_buckets=[16, MAX_SEQ],
-                         prefill_chunk_tokens=32)  # no block_size
+    # No block_size: 16 is derived from these buckets, and chunks
+    # are held to it like to an explicit one.
+    derived = GenerationEngine(module, variables, max_slots=2,
+                               max_seq=MAX_SEQ,
+                               prefill_buckets=[16, MAX_SEQ],
+                               prefill_chunk_tokens=32)
+    try:
+        assert derived.block_size == 16
+    finally:
+        derived.shutdown_nowait()
     with pytest.raises(InvalidInput, match="multiple of block_size"):
         make_engine(tiny, chunk=24)  # 24 % 16 != 0
     with pytest.raises(InvalidInput, match="exceeds max_seq"):

@@ -77,8 +77,8 @@ class TestJaxEngine:
 
     def test_warmup_minimal_only_largest_bucket(self):
         """Recycle-successor mode: warm the largest bucket only; the
-        rest load on demand from the persistent cache (r5 SOAK found
-        the full grid was the dominant successor-load term)."""
+        rest load on demand from the persistent cache, instead of
+        the full grid running inside the successor's load time."""
         engine, _ = make_engine()
         engine.warmup(np.zeros((3,), np.float32), minimal=True)
         assert engine.compile_count == 1

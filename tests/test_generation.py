@@ -385,14 +385,16 @@ def test_draft_parameters_are_placed_with_the_target(tiny):
             == eng.draft_param_bytes())
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-async def test_host_and_placed_trees_generate_identically(tiny, paged):
+@pytest.mark.parametrize("explicit", [False, True],
+                         ids=["derived", "explicit"])
+async def test_host_and_placed_trees_generate_identically(tiny,
+                                                          explicit):
     """Placement moves bytes, not mathematics: an engine built from
     the host tree and one built from a pre-placed tree give bit-equal
     greedy tokens and log-probabilities."""
     module, variables, _ = tiny
-    kw = {"block_size": 16} if paged else {}
+    # Unset, buckets [16, 64] derive blocks of 16: two different pools.
+    kw = {"block_size": 8} if explicit else {}
     host = jax.tree.map(np.asarray, variables)
     placed = jax.device_put(host)
     outs = []
@@ -479,10 +481,10 @@ _REFUSAL = ("RESOURCE_EXHAUSTED: Error allocating device buffer: "
             "There are 3.29M free.; (0x0x0_HBM0)")
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
+@pytest.mark.parametrize("explicit", [False, True],
+                         ids=["derived", "explicit"])
 async def test_prefill_refused_for_memory_is_taken_again_smaller(
-        tiny, paged):
+        tiny, explicit):
     """The runtime refuses a prefill launch's output buffers when the
     chip is full (seen on the v5e once launches stopped waiting on a
     parameter transfer).  Nothing ran, so no request fails: the group
@@ -491,7 +493,7 @@ async def test_prefill_refused_for_memory_is_taken_again_smaller(
     module, variables, _ = tiny
     prompts = [[5, 5], [7, 1, 3], [2], [9, 9, 4]]
     wants = [ref_greedy(module, variables, p, 4) for p in prompts]
-    kw = {"block_size": 16} if paged else {}
+    kw = {"block_size": 8} if explicit else {}
     eng = make_engine(tiny, max_slots=4,
                       prefill_buckets=[16, MAX_SEQ], **kw)
     try:

@@ -1,9 +1,8 @@
-"""Paged KV cache: block-pool + block-table serving (VERDICT r4 #4).
+"""The KV cache: block-pool + block-table serving (VERDICT r4 #4).
 
-Parity bar: the paged engine must reproduce the dense engine (and the
-no-cache full recompute) token-for-token — block boundaries, prefix
-sharing, pool pressure, and eviction change WHERE bytes live, never
-results.
+Parity bar: the engine must reproduce the no-cache full recompute
+token-for-token — block boundaries, prefix sharing, pool pressure,
+and eviction change WHERE bytes live, never results.
 """
 
 import asyncio
@@ -182,19 +181,82 @@ async def test_prefix_blocks_linger_and_get_evicted_under_pressure(
 # ------------------------------------------------------ pool sizing
 
 
+@pytest.mark.parametrize("max_seq, buckets, block_size, want", [
+    (32, None, None, 16),        # default pow-2 buckets from 16
+    (1024, [512], None, 128),    # gpt2-large's lengths: the kernels'
+    (2048, [1024], None, 128),   # OLMoE's
+    (48, [24, 48], None, 8),
+    (64, [16, 64], 8, 8),        # explicit: taken as given
+    (64, [16, 64], 24, None),    # ... and still validated
+], ids=["pow2-32", "1024-512", "2048-1024", "24-48", "explicit",
+        "explicit-invalid"])
+def test_block_size_unset_is_derived_from_the_lengths(
+        max_seq, buckets, block_size, want):
+    """block_size=None means gcd(128, max_seq, every prefill bucket);
+    cache_blocks=None then holds every position of every slot."""
+    module = DecoderLM(decoder_tiny(
+        num_layers=1, hidden_size=32, num_heads=2,
+        intermediate_size=64, max_seq=max_seq, vocab_size=96))
+    variables = module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+
+    def build():
+        return GenerationEngine(module, variables, max_slots=2,
+                                max_seq=max_seq, prefill_buckets=buckets,
+                                block_size=block_size)
+
+    if want is None:
+        with pytest.raises(InvalidInput, match="multiple of block_size"):
+            build()
+        return
+    eng = build()
+    try:
+        assert eng.block_size == want
+        assert eng.stats()["paged"]["block_size"] == want
+        assert eng.num_blocks * want == 2 * max_seq
+    finally:
+        eng.shutdown_nowait()
+
+
+async def test_engine_built_with_slots_and_max_seq_alone_shares_prefixes(
+        tiny):
+    """The engine a caller gets who set nothing of the cache: a block
+    pool, reported under stats()["paged"], that shares the blocks of a
+    repeated prompt."""
+    module, variables, _ = tiny
+    prompt = list(range(1, 2 * BS + 4))
+    want = ref_greedy(module, variables, prompt, 4)
+    eng = GenerationEngine(module, variables, max_slots=2,
+                           max_seq=MAX_SEQ)
+    try:
+        pool = eng.stats()["paged"]
+        assert pool["block_size"] == BS
+        assert pool["pool_blocks"] == 2 * MAX_SEQ // BS
+        assert eng.cache_debug()["paged"] is True
+        first, _ = await eng.complete(prompt, max_new_tokens=4)
+        again, _ = await eng.complete(prompt, max_new_tokens=4)
+        pool = eng.stats()["paged"]
+    finally:
+        await eng.close()
+    assert first == again == want
+    assert pool["prefix_hits"] == 2       # both full blocks, shared
+    assert pool["prefill_tokens_saved"] == 2 * BS
+
+
 def test_paged_cache_bytes_scale_with_pool(tiny):
-    module, variables, cfg = tiny
-    dense = GenerationEngine(module, variables, max_slots=4,
-                             max_seq=MAX_SEQ,
-                             prefill_buckets=[16, 32, MAX_SEQ])
+    """cache_blocks unset holds every position of every slot; half the
+    blocks are half the bytes."""
+    _, _, cfg = tiny
+    # layers * k+v * S * max_seq * H * D * itemsize (float32)
+    every_position = (cfg.num_layers * 2 * 4 * MAX_SEQ * cfg.num_heads
+                      * cfg.head_dim * 4)
     parity = make_paged(tiny, max_slots=4)
     half = make_paged(tiny, max_slots=4,
                       cache_blocks=2 * (MAX_SEQ // BS))
     try:
-        assert parity.cache_bytes() == dense.cache_bytes()
-        assert half.cache_bytes() == dense.cache_bytes() // 2
+        assert parity.cache_bytes() == every_position
+        assert half.cache_bytes() == every_position // 2
     finally:
-        dense.shutdown_nowait()
         parity.shutdown_nowait()
         half.shutdown_nowait()
 
@@ -256,9 +318,10 @@ async def test_paged_cancel_releases_blocks(tiny):
 
 
 async def test_paged_model_serves_over_http(tmp_path):
-    """block_size in config.json: the served model runs the paged
-    engine; /metrics exports the prefix-cache stats; results match the
-    dense engine's."""
+    """A served model with no block_size (the engine derives 16 from
+    these buckets, a block for every position of every slot) beside
+    one with an explicit small pool: equal tokens, fewer bytes, and
+    /metrics exports the prefix-cache stats."""
     import json as _json
 
     import aiohttp
@@ -282,30 +345,32 @@ async def test_paged_model_serves_over_http(tmp_path):
         (d / "config.json").write_text(_json.dumps(cfg))
         return str(d)
 
-    dense = GenerativeModel("dense", write_dir("dense", {}))
-    dense.load()
-    paged = GenerativeModel("paged", write_dir(
-        "paged", {"block_size": 16, "cache_blocks": 6}))
-    paged.load()
+    derived = GenerativeModel("derived", write_dir("derived", {}))
+    derived.load()
+    assert derived.engine.block_size == 16
+    small = GenerativeModel("small", write_dir(
+        "small", {"block_size": 16, "cache_blocks": 6}))
+    small.load()
     server = ModelServer(http_port=0)
-    await server.start_async([dense, paged], host="127.0.0.1")
+    await server.start_async([derived, small], host="127.0.0.1")
     base = f"http://127.0.0.1:{server.http_port}"
     try:
         async with aiohttp.ClientSession() as s:
             outs = {}
-            for name in ("dense", "paged"):
+            for name in ("derived", "small"):
                 async with s.post(
                         f"{base}/v2/models/{name}/generate",
                         json={"text_input": "paging!",
                               "parameters": {"max_tokens": 6}}) as r:
                     assert r.status == 200, await r.text()
                     outs[name] = (await r.json())["text_output"]
-            assert outs["dense"] == outs["paged"]
+            assert outs["derived"] == outs["small"]
             async with s.get(f"{base}/metrics") as r:
                 metrics = await r.text()
         assert "kfserving_tpu_engine_paged" in metrics
         assert 'bucket="prefix_hits"' in metrics
-        assert paged.engine.cache_bytes() < dense.engine.cache_bytes()
+        assert (small.engine.cache_bytes()
+                < derived.engine.cache_bytes())
     finally:
         await server.stop_async()
 

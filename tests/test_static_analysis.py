@@ -278,7 +278,7 @@ def test_write_baseline_then_clean_run(tmp_path, capsys):
 def test_finding_paths_cwd_independent_inside_checkout(
         tmp_path, monkeypatch):
     # The committed baseline keys on repo-root-relative paths
-    # ('benchmarks/...'); a bare `kfs-lint` run from ANY cwd must
+    # ('tests/...'); a bare `kfs-lint` run from ANY cwd must
     # produce the same identities or the baseline false-fails.
     target = os.path.abspath(
         os.path.join(FIXTURES, "spin_loop_fire.py"))
@@ -382,7 +382,7 @@ def test_naming_rules_shared_with_check_metrics():
 
 # ------------------------------------------------- the fast-tier gate
 def test_live_tree_is_clean_modulo_baseline():
-    # Full default scope (ISSUE 14): package + benchmarks/ + tests/.
+    # Full default scope: package + tests/; the baseline is empty.
     findings = analyzers.analyze_paths(analyzers.default_targets(),
                                        analyzers.default_rules())
     baseline = analyzers.load_baseline(
@@ -393,10 +393,10 @@ def test_live_tree_is_clean_modulo_baseline():
     assert stale == [], f"stale baseline entries: {stale}"
 
 
-def test_default_targets_cover_benchmarks_and_tests():
+def test_default_targets_cover_package_and_tests():
     targets = analyzers.default_targets()
     names = {os.path.basename(t) for t in targets}
-    assert {"kfserving_tpu", "benchmarks", "tests"} <= names
+    assert {"kfserving_tpu", "tests"} == names
     # The golden fixtures fire by design and must be pruned from the
     # directory walk (their tests analyze them file-by-file).
     from kfserving_tpu.tools.analyzers.core import iter_python_files
