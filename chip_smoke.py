@@ -546,10 +546,19 @@ table = np.full((b, mb), -1, np.int32)
 for i, n in enumerate(lengths):
     used = -(-int(n) // bs)
     table[i, :used] = rng.choice(nb, size=used, replace=False)
+# Beside the live rows, one the engine has freed (its table row -1, its
+# length still counting) and one parked on the position sentinel with its
+# prefilled blocks in place: the kernel walks neither, they are zeros.
+free, parked = 2 % b, 5 % b
+idle_table, idle_lengths = table.copy(), lengths.copy()
+idle_table[free], idle_lengths[free] = -1, 3 * mb * bs
+idle_table[parked, 2:], idle_lengths[parked] = -1, mb * bs + 1
+live = np.setdiff1d(np.arange(b), [free, parked])
+batch = (q, pk, pv, jnp.asarray(idle_table), jnp.asarray(idle_lengths))
+got = paged_attention_tpu(*batch, interpret=interpret)
+out["paged"] = err(got[live], paged_attention_xla(*batch)[live])
+assert not np.asarray(got, np.float32)[[free, parked]].any(), "idle rows"
 table, lengths = jnp.asarray(table), jnp.asarray(lengths)
-out["paged"] = err(
-    paged_attention_tpu(q, pk, pv, table, lengths, interpret=interpret),
-    paged_attention_xla(q, pk, pv, table, lengths))
 # The decode step's write: each row at its length, the kernel against the
 # scatter (exact: both only move values).
 k_step, v_step = normal(b, h, d), normal(b, h, d)
@@ -589,8 +598,10 @@ def kernel_shapes(config: dict) -> dict:
 
 
 def phase_kernels(config: dict, platform: str) -> dict:
-    """Both Pallas kernels against their XLA formulations on seeded bf16
-    inputs at the served shapes, in a child of its own."""
+    """The Pallas kernels against their XLA formulations on seeded bf16
+    inputs at the served shapes, in a child of its own; the paged decode
+    kernel with a free and a parked row in its batch, which must come
+    back as zeros."""
     out = run_child(KERNELS_CHILD, [json.dumps(kernel_shapes(config)),
                                     platform], child_env(), 600.0, "kernels")
     record = json.loads(out.split("KERNELS ", 1)[1])

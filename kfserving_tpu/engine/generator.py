@@ -897,6 +897,10 @@ class GenerationEngine:
         self._spec_verify_s = 0.0
         self._occupied_slot_steps = 0
         self._wasted_token_steps = 0  # garbage steps past a finish
+        # What decode attention had to read, a layer: blocks under the
+        # live rows' contexts, and the tokens in them (_distribute).
+        self._kv_blocks_walked = 0
+        self._kv_context_tokens = 0
         # The row count prefill dispatches are held to once the runtime
         # has refused one for memory (see _prefill_refused).
         self._prefill_rows_cap: Optional[int] = None
@@ -1163,6 +1167,9 @@ class GenerationEngine:
             "depth_effective": self._depth_effective,
             "suppressed_waves": self.suppressed_waves,
             "wasted_token_steps": self._wasted_token_steps,
+            "kv_block_fill": round(
+                self._kv_context_tokens / max(
+                    1, self._kv_blocks_walked * self.block_size), 4),
             "prefill_rows_cap": self._prefill_rows_cap or 0,
             "cache_bytes": self.cache_bytes(),
             "params_resident_bytes": self._params_resident_bytes,
@@ -3471,7 +3478,7 @@ class GenerationEngine:
         K-1 steps of waste."""
         k = tokens.shape[1]
         self._token_steps += k
-        resident_tokens = 0
+        starts = []  # each live row's context when the wave began
         # Even split of the wave's busy interval across the live
         # streams it decoded: the per-request decode cost sums to the
         # engine's device time (additive attribution), and garbage
@@ -3500,7 +3507,7 @@ class GenerationEngine:
             self._decode_flops += k * (self._flops_matmul_per_token
                                        + self._attn_flops_coeff
                                        * s.length)
-            resident_tokens += s.length
+            starts.append(s.length)
             n_lp = s.req.logprobs
             for j in range(k):
                 if self._slots[i] is not s:
@@ -3517,13 +3524,26 @@ class GenerationEngine:
                             zip(lp[1][i, j][:n_lp],
                                 lp[2][i, j][:n_lp])])
                 self._emit(i, int(tokens[i, j]), rec)
-        if resident_tokens:
+        if starts:
+            # Step i of the wave attends over L + i + 1 tokens of a row
+            # that began it with L, in ceil of that over block_size
+            # blocks, whether or not the row finished before step i.
+            context = (np.asarray(starts, np.int64)[:, None]
+                       + np.arange(1, k + 1))
+            tokens_read = int(context.sum())
+            blocks = int((-(-context // self.block_size)).sum())
+            self._kv_context_tokens += tokens_read
+            self._kv_blocks_walked += blocks
+            obs.generator_decode_kv_context_tokens_total().labels(
+                model=self.name).inc(tokens_read)
+            obs.generator_decode_kv_blocks_walked_total().labels(
+                model=self.name).inc(blocks)
             # Decode reads every live slot's resident KV plus the full
             # parameter set once per token step — the bandwidth-bound
             # working set the HBM-utilization gauge divides by peak.
             self._decode_hbm_bytes += k * (
                 self._param_read_bytes
-                + resident_tokens * self._kv_bytes_per_token)
+                + sum(starts) * self._kv_bytes_per_token)
 
     # -- speculative decoding ----------------------------------------------
     async def _spec_or_fallback_wave(self, loop, inflight) -> None:

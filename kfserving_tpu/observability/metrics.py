@@ -363,6 +363,23 @@ def generator_params_resident_bytes():
         "was built, not handed over from the host with every launch")
 
 
+def generator_decode_kv_blocks_walked_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_decode_kv_blocks_walked_total",
+        "KV blocks the decode attention of one layer had to read: "
+        "ceil(context / block_size), summed over the rows that held a "
+        "request when a decode wave was delivered and over the wave's "
+        "steps")
+
+
+def generator_decode_kv_context_tokens_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_decode_kv_context_tokens_total",
+        "Context tokens under those blocks (the step's own included): "
+        "over blocks walked x block_size it is the share of the bytes "
+        "read that a decode step needed")
+
+
 def generator_moe_routed_pairs_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_moe_routed_pairs_total",

@@ -24,14 +24,18 @@ Two implementations with one contract:
   [B, MB*BS, H, D] view and run masked attention.  Compiles anywhere
   (the hermetic CPU tests run it), but materializes the gathered copy
   every step.
-- a Pallas TPU kernel (paged_attention_tpu) that walks the block
-  table with scalar prefetch and never materializes — only blocks
-  holding valid tokens are read, so a short sequence in a long-context
-  pool costs its length, not the pool width.  The dispatcher picks it
-  from shapes and the backend; under a mesh it runs per heads shard.
-  Where it serves, the decode step's write is a Mosaic call too
-  (paged_write_tpu), so nothing of XLA's own touches a pool inside the
-  decode program.
+- a Pallas TPU kernel (paged_attention_tpu) that never materializes:
+  one program walks, in one loop, each row's own blocks and no others
+  (`paged_walk` lists them from the table and the lengths, once a
+  step), bringing K and V blocks from the pools in HBM through VMEM by
+  its own copies, the next pair's in flight while this one is
+  computed.  Its work is the blocks that hold context: a short
+  sequence in a long-context pool costs its length, not the pool
+  width, and a slot nobody decodes in costs a scalar test.  The
+  dispatcher picks it from shapes and the backend; under a mesh it
+  runs per heads shard.  Where it serves, the decode step's write is a
+  Mosaic call too (paged_write_tpu), so nothing of XLA's own touches a
+  pool inside the decode program.
 
 Contract (per layer):
     q           [B, 1, H, D]   current step's query
@@ -39,7 +43,10 @@ Contract (per layer):
     block_table [B, MB] int32  block ids per slot, -1 = unallocated
     lengths     [B] int32      valid tokens INCLUDING the current
                                step's write
-Returns [B, 1, H, D].
+Returns [B, 1, H, D].  A row whose length no table covers (a freed
+slot's goes on counting; a parked one sits on max_seq + 1) holds no
+request: the engine discards it, the gather answers it with garbage
+and the kernel with zeros.
 """
 
 import functools
@@ -67,26 +74,62 @@ def _sublanes(dtype) -> int:
     return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+# K and V blocks in flight or in use at once: the kernel's own
+# pipeline, in place of the one a grid would have given it.
+_BUFFERS = 3
+
+
+def paged_walk(block_table, lengths, block_size: int):
+    """The (row, column) pairs of `block_table` a decode step must
+    read, in the order `paged_attention_tpu` walks them, as flat table
+    indices `row * MB + column` in a [B*MB] int32 list, and how many of
+    its entries count, [1] int32.  Row r walks its first
+    ceil(len[r] / BS) columns, as far as their entries are allocated; a
+    row whose length no table covers (0: never fed; past MB*BS: parked
+    on the position sentinel, or free and still counting) walks none.
+    It depends on the table and the lengths alone, which every layer of
+    a step shares, so XLA computes it once a step."""
+    b, mb = block_table.shape
+    wanted = jnp.where((lengths > 0) & (lengths <= mb * block_size),
+                       -(-lengths // block_size), 0)
+    columns = jnp.arange(mb, dtype=jnp.int32)
+    held = jnp.min(jnp.where(block_table < 0, columns, mb), axis=1)
+    counts = jnp.minimum(wanted, held)                          # [B]
+    ends = jnp.cumsum(counts)
+    at = jnp.arange(b * mb, dtype=jnp.int32)
+    # Entry `at` belongs to the row after those whose walks end at or
+    # before it, and is that row's column `at` less their blocks.
+    before = at[:, None] >= ends[None, :]                   # [B*MB, B]
+    row = jnp.minimum(jnp.sum(before, axis=1), b - 1)
+    column = jnp.clip(
+        at - jnp.sum(jnp.where(before, counts[None, :], 0), axis=1),
+        0, mb - 1)
+    return ((row * mb + column).astype(jnp.int32),
+            ends[-1:].astype(jnp.int32))
+
+
+def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
+                  pool_k, pool_v, o_ref, k_blocks, v_blocks, sems,
                   q_scratch, m_scratch, l_scratch, acc_scratch, *,
-                  block_size: int, scale: float, head_dim: int):
-    """One batch row's online-softmax walk over its block table, all
-    heads per program, on blocks [BS, H*D] as the pool stores them.
+                  block_size: int, table_width: int, scale: float,
+                  head_dim: int):
+    """Every row's online-softmax walk over its own blocks, all heads
+    at once, on blocks [BS, H*D] as the pool stores them: one program,
+    one loop over `paged_walk`'s pairs, so the work is the blocks that
+    hold context and nothing is in proportion to B x MB.  The pools
+    stay in HBM; pair i's K and V blocks come through VMEM by this
+    kernel's own copies, `_BUFFERS` deep, and the copy of a later pair
+    starts before this one is computed whichever row it belongs to, so
+    a row of one block does not wait for its read.
     The per-head reduction is two MXU products and no [.., H, D] view:
     the query becomes block-diagonal, `q_bd[r, c] = q[c]` where column
     c is one of head r's, so `q_bd . K^T` is every head's scores
     [H_pad, BS]; `p . V` is [H_pad, H*D], of which head r's columns of
-    row r are the answer, taken once at the last block.  Grid: (B, MB)
-    with the block axis innermost and sequential; the index maps clamp
-    the pool-block index so programs past a row's valid length re-DMA
-    an already-resident block — invalid blocks cost neither HBM traffic
-    nor FLOPs (the flash kernel's kv_lengths clamp, applied to a block
-    table).  The gathered [B, MB*BS, H, D] view the XLA fallback
-    materializes every step never exists here."""
-    b_idx = pl.program_id(0)
-    j_idx = pl.program_id(1)
-    num_j = pl.num_programs(1)
-    row_len = len_ref[b_idx]
+    row r are the answer, taken once at the row's last block.  A row
+    that walks nothing is never touched: its output stays zeros.  The
+    gathered [B, MB*BS, H, D] view the XLA fallback materializes every
+    step never exists here."""
+    count = count_ref[0]
     h_pad, hd = acc_scratch.shape
 
     def own_columns():
@@ -95,23 +138,46 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (h_pad, hd), 1)
         return (col >= row * head_dim) & (col < (row + 1) * head_dim)
 
-    @pl.when(j_idx == 0)
-    def _init():
-        # Select in float32 and cast: Mosaic refuses the relayout of
-        # a 16-bit select here.
-        q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (h_pad, hd))
-        q_scratch[...] = jnp.where(own_columns(), q,
-                                   0.0).astype(q_scratch.dtype)
-        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
+    def copies(i):
+        block = table_ref[pairs_ref[i]]
+        slot = i % _BUFFERS
+        return [pltpu.make_async_copy(pool.at[block], blocks.at[slot],
+                                      sems.at[j, slot])
+                for j, (pool, blocks) in enumerate(
+                    ((pool_k, k_blocks), (pool_v, v_blocks)))]
 
-    def _run_block():
-        k = k_ref[0].astype(q_scratch.dtype)              # [bs, hd]
+    def fetch(i):
+        @pl.when(i < count)
+        def _start():
+            for copy in copies(i):
+                copy.start()
+
+    def pair(i, _):
+        fetch(i + _BUFFERS - 1)  # into the slot pair i - 1 has left
+        at = pairs_ref[i]
+        row, column = at // table_width, at % table_width
+        row_len = len_ref[row]
+        slot = i % _BUFFERS
+
+        @pl.when(column == 0)
+        def _init():
+            # Select in float32 and cast: Mosaic refuses the relayout of
+            # a 16-bit select here.
+            q = jnp.broadcast_to(q_ref[row].astype(jnp.float32),
+                                 (h_pad, hd))
+            q_scratch[...] = jnp.where(own_columns(), q,
+                                       0.0).astype(q_scratch.dtype)
+            m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
+            l_scratch[...] = jnp.zeros_like(l_scratch)
+            acc_scratch[...] = jnp.zeros_like(acc_scratch)
+
+        for copy in copies(i):
+            copy.wait()
+        k = k_blocks[slot].astype(q_scratch.dtype)            # [bs, hd]
         s = jax.lax.dot_general(
             q_scratch[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [h_pad, bs]
-        pos = j_idx * block_size + jax.lax.broadcasted_iota(
+        pos = column * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(pos < row_len, s, _NEG_INF)
         m_prev = m_scratch[...]                           # [h_pad, 1]
@@ -121,49 +187,45 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)                   # [h_pad, 1]
         l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
             p, axis=1, keepdims=True)
-        v = v_ref[0]                                      # [bs, hd]
+        v = v_blocks[slot]                                # [bs, hd]
         pv = jnp.dot(p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)  # [h_pad, hd]
         acc_scratch[...] = acc_scratch[...] * alpha + pv
         m_scratch[...] = m_new
 
-    # Blocks wholly past the row's length never run.
-    pl.when(j_idx * block_size < row_len)(_run_block)
+        # The row's last pair: the list ends, or the next pair starts
+        # a row (every walk starts at column 0).
+        after = pairs_ref[jnp.minimum(i + 1, pairs_ref.shape[0] - 1)]
 
-    @pl.when(j_idx == num_j - 1)
-    def _finalize():
-        out = acc_scratch[...] / jnp.maximum(l_scratch[...], 1e-30)
-        o_ref[0] = jnp.sum(jnp.where(own_columns(), out, 0.0), axis=0,
-                           keepdims=True).astype(o_ref.dtype)
+        @pl.when((i + 1 == count) | (after % table_width == 0))
+        def _finalize():
+            out = acc_scratch[...] / jnp.maximum(l_scratch[...], 1e-30)
+            o_ref[row] = jnp.sum(jnp.where(own_columns(), out, 0.0),
+                                 axis=0, keepdims=True).astype(o_ref.dtype)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    for i in range(_BUFFERS - 1):
+        fetch(i)
+    jax.lax.fori_loop(0, count, pair, None)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
                         interpret: bool = False):
     """Pallas paged decode attention — same contract as
-    `paged_attention_xla`, without materializing the gathered cache
-    view, and reading only blocks that hold valid tokens (a short
-    sequence in a long-context pool costs its length, not the pool
-    width)."""
+    `paged_attention_xla` on every row that holds context, without
+    materializing the gathered cache view, and reading only blocks that
+    hold valid tokens (a short sequence in a long-context pool costs
+    its length, not the pool width; a slot nobody decodes in costs
+    nothing).  A row that walks no block (`paged_walk`) comes back as
+    zeros."""
     b, lq, h, d = q.shape
     nb, bs, hd = pool_k.shape
     assert lq == 1 and hd == h * d, (q.shape, pool_k.shape)
     mb = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
-    table_flat = jnp.maximum(block_table, 0).reshape(-1)
     lengths = lengths.astype(jnp.int32)
-
-    def q_index(bi, ji, table, lens):
-        return (bi, 0, 0)
-
-    def kv_index(bi, ji, table, lens):
-        # Clamp the walk to the row's last VALID table entry: programs
-        # past the length re-address a resident block (no new DMA, and
-        # pl.when skips their compute).
-        last = jnp.maximum(
-            jax.lax.div(lens[bi] - 1, jnp.int32(bs)), 0)
-        jj = jnp.minimum(ji, last)
-        return (table[bi * mb + jj], 0, 0)
+    pairs, count = paged_walk(block_table, lengths, bs)
 
     # The products run in the pool's precision when the query shares
     # it (bfloat16 x bfloat16 with float32 accumulation is what a
@@ -171,16 +233,16 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     # of that type.
     compute = jnp.promote_types(q.dtype, pool_k.dtype)
     h_pad = -(-h // _sublanes(compute)) * _sublanes(compute)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    rows = pl.BlockSpec((b, 1, hd), lambda i, *_: (0, 0, 0))
+    blocks = pltpu.VMEM((_BUFFERS, bs, hd), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), q_index),
-            pl.BlockSpec((1, bs, hd), kv_index),
-            pl.BlockSpec((1, bs, hd), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd), q_index),
+        num_scalar_prefetch=4,
+        grid=(1,),
+        in_specs=[rows, hbm, hbm],
+        out_specs=rows,
         scratch_shapes=[
+            blocks, blocks, pltpu.SemaphoreType.DMA((2, _BUFFERS)),
             pltpu.VMEM((h_pad, hd), compute),
             pltpu.VMEM((h_pad, 1), jnp.float32),
             pltpu.VMEM((h_pad, 1), jnp.float32),
@@ -188,12 +250,13 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
         ],
     )
     kernel = functools.partial(_paged_kernel, block_size=bs,
-                               scale=scale, head_dim=d)
+                               table_width=mb, scale=scale, head_dim=d)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
         interpret=interpret,
-    )(table_flat, lengths, q.reshape(b, 1, hd), pool_k, pool_v)
+    )(pairs, count, block_table.reshape(-1), lengths,
+      q.reshape(b, 1, hd), pool_k, pool_v)
     return out.reshape(b, 1, h, d)
 
 

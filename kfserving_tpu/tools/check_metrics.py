@@ -190,6 +190,10 @@ async def smoke() -> List[str]:
         model="metrics-probe").set(0.18)
     obs.generator_params_resident_bytes().labels(
         model="metrics-probe").set(3.1e9)
+    obs.generator_decode_kv_blocks_walked_total().labels(
+        model="metrics-probe").inc(640)
+    obs.generator_decode_kv_context_tokens_total().labels(
+        model="metrics-probe").inc(61000)
     for program in ("decode", "prefill"):
         obs.generator_moe_routed_pairs_total().labels(
             model="metrics-probe", program=program).inc(3072)

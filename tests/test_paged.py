@@ -32,12 +32,18 @@ def tiny():
 
 
 def ref_greedy(module, variables, prompt, steps):
+    """Greedy continuation by full recompute, no cache.  Every step
+    runs the one shape [1, MAX_SEQ]: attention is causal, so what pads
+    the row after its last token reaches no logit that is read, and a
+    shape a step would compile the model anew 60 times a test."""
+    apply = jax.jit(module.apply)
     ids = [int(t) for t in prompt]
     out = []
     for _ in range(steps):
-        logits = module.apply(variables,
-                              jnp.asarray([ids], jnp.int32))
-        nxt = int(jnp.argmax(logits[0, -1]))
+        row = np.zeros((1, MAX_SEQ), np.int32)
+        row[0, :len(ids)] = ids
+        logits = apply(variables, jnp.asarray(row))
+        nxt = int(jnp.argmax(logits[0, len(ids) - 1]))
         out.append(nxt)
         ids.append(nxt)
     return out
@@ -549,6 +555,42 @@ KERNEL_LENGTHS = {
     "one": [1, 1, 1],
     "full-table": [512, 512, 511],
 }
+KERNEL_TABLE = [[0, 1, 2, 7], [3, 5, 6, 1], [0, 4, 2, 3]]  # 0 is shared
+
+
+def _chat_mix():
+    """24 rows of an 8-column table as `gpt2-large.chat` fills them:
+    20 rows hold contexts of 50-770 tokens, 4 are free and still
+    counting."""
+    rng = np.random.default_rng(29)
+    lengths = np.exp(rng.uniform(np.log(50), np.log(770), 24)).astype(int)
+    free = [3, 9, 15, 21]
+    lengths[free] = [2900, 1025, 640, 77]
+    table = np.full((24, 8), -1, np.int32)
+    for row in set(range(24)) - set(free):
+        table[row, :-(-lengths[row] // KERNEL_BS)] = rng.integers(
+            0, 64, -(-lengths[row] // KERNEL_BS))
+    return lengths.tolist(), table.tolist(), free
+
+
+# name -> (lengths, table, rows that walk nothing).  A table of None is
+# KERNEL_TABLE with each row cut to its length, as the engine keeps it.
+KERNEL_CASES = {
+    **{name: (lengths, None, []) for name, lengths in
+       KERNEL_LENGTHS.items()},
+    # Freed between two live rows: its table row is all -1 and its
+    # length went on counting past what a table covers.
+    "free-row-stale-length": (
+        [300, 2000, 200], [[0, 1, 2, -1], [-1] * 4, [0, 4, -1, -1]], [1]),
+    # Parked on the position sentinel (max_seq + 1 with the step's own
+    # token), its prefilled chunks still in its table.
+    "parked-row": (
+        [130, 513, 40], [[6, 2, -1, -1], [3, 5, -1, -1], [1, -1, -1, -1]],
+        [1]),
+    "every-row-free": ([700, 513, 90], [[-1] * 4] * 3, [0, 1, 2]),
+    "whole-table-beside-one-token": ([1, 512, 1], None, []),
+    "chat-mix-24x8": _chat_mix(),
+}
 
 
 def _pools(rng, nb, bs, h, d):
@@ -580,43 +622,111 @@ def _attention_over_blocks(q, k4, v4, table, allowed):
     return np.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-@pytest.mark.parametrize("lengths", list(KERNEL_LENGTHS.values()),
-                         ids=list(KERNEL_LENGTHS))
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
 @pytest.mark.parametrize("heads", [(20, 64), (16, 128), (4, 64)],
                          ids=["20x64", "16x128", "4x64"])
-def test_pallas_paged_kernel_matches_xla(heads, lengths):
+def test_pallas_paged_kernel_matches_xla(heads, case):
     """The Pallas paged-decode kernel (interpret mode on CPU) on the
     flat pool matches the XLA gather reference, and both the plain
     attention over the same bytes as [NB, BS, H, D]: for heads that
     padded a tile (20 x 64), fill it (16 x 128) and are a fraction of
     one (4 x 64); lengths inside a block, on its edge, of one token
     and of the whole table; a shared block and unallocated (-1) table
-    tails."""
+    tails.  A row that walks nothing (free, parked) comes back as
+    zeros, whatever is beside it."""
     from kfserving_tpu.ops import paged_attention as pa
 
     h, d = heads
-    bs, mb, nb = KERNEL_BS, KERNEL_MB, KERNEL_NB
+    lengths, table, idle = KERNEL_CASES[case]
+    bs = KERNEL_BS
     rng = np.random.default_rng(h * 1000 + lengths[0])
+    if table is None:
+        table = np.asarray(KERNEL_TABLE, np.int32)
+        for row, n in enumerate(lengths):
+            table[row, -(-n // bs):] = -1
+    table = np.asarray(table, np.int32)
+    mb, nb = table.shape[1], max(KERNEL_NB, int(table.max()) + 1)
     q = rng.normal(size=(len(lengths), 1, h, d)).astype(np.float32)
     k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
-    table = np.asarray([[0, 1, 2, 7], [3, 5, 6, 1], [0, 4, 2, 3]],
-                       np.int32)  # rows 0 and 2 share block 0
-    for row, n in enumerate(lengths):
-        table[row, -(-n // bs):] = -1
     lens = jnp.asarray(lengths, jnp.int32)
+    live = np.setdiff1d(np.arange(len(lengths)), idle)
     allowed = (np.arange(mb * bs)[None, None, :]
                < np.asarray(lengths)[:, None, None])
     want = _attention_over_blocks(q, k4, v4, table, allowed)
     xla = pa.paged_attention_xla(jnp.asarray(q), pool_k, pool_v,
                                  jnp.asarray(table), lens)
-    got = pa.paged_attention_tpu(jnp.asarray(q), pool_k, pool_v,
-                                 jnp.asarray(table), lens,
-                                 interpret=True)
+    got = np.asarray(pa.paged_attention_tpu(
+        jnp.asarray(q), pool_k, pool_v, jnp.asarray(table), lens,
+        interpret=True))
     assert got.shape == q.shape
-    np.testing.assert_allclose(np.asarray(xla), want, rtol=2e-5,
+    np.testing.assert_allclose(np.asarray(xla)[live], want[live],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5,
                                atol=2e-5)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
-                               atol=2e-5)
+    np.testing.assert_array_equal(got[idle], 0.0)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_paged_walk_lists_each_rows_blocks_in_order(seed):
+    """`paged_walk` against a plain loop: row r walks its first
+    ceil(len / BS) columns as far as they are allocated, rows in order;
+    a length of 0 or past the table's coverage walks nothing."""
+    from kfserving_tpu.ops.paged_attention import paged_walk
+
+    rng = np.random.default_rng(seed)
+    b, mb, bs = 9, 6, 16
+    lengths = rng.integers(0, mb * bs + 1, b)
+    lengths[rng.integers(0, b)] = mb * bs + 1      # parked
+    lengths[rng.integers(0, b)] = 3 * mb * bs      # free, still counting
+    table = rng.integers(0, 50, (b, mb)).astype(np.int32)
+    for row in range(b):
+        # mostly what the length needs; sometimes fewer, or none
+        held = -(-int(lengths[row]) // bs) - int(rng.integers(0, 4) == 0)
+        table[row, max(0, held) * int(rng.integers(0, 5) > 0):] = -1
+    want = []
+    for row in range(b):
+        if not 0 < lengths[row] <= mb * bs:
+            continue
+        for column in range(-(-int(lengths[row]) // bs)):
+            if table[row, column] < 0:
+                break
+            want.append(row * mb + column)
+    pairs, count = paged_walk(jnp.asarray(table),
+                              jnp.asarray(lengths, jnp.int32), bs)
+    pairs, count = np.asarray(pairs), np.asarray(count)
+    assert pairs.shape == (b * mb,) and count.shape == (1,)
+    assert pairs.dtype == count.dtype == np.int32
+    assert pairs[:count[0]].tolist() == want
+    # what follows the count is never read, and still inside the table
+    assert ((0 <= pairs) & (pairs < b * mb)).all()
+
+
+async def test_decode_waves_count_the_blocks_and_tokens_they_read(tiny):
+    """`kv_block_fill`: over the rows that hold a request when a wave is
+    delivered and over its steps, context tokens (the step's own
+    included) against the blocks that hold them."""
+    from kfserving_tpu.observability import metrics as obs
+
+    prompt, new, k = [5, 9, 2, 7, 11, 3, 8], 14, 4
+    eng = make_paged(tiny, max_slots=2, steps_per_call=k)
+    eng.name = "kv-counted"
+    try:
+        got, _ = await eng.complete(prompt, max_new_tokens=new)
+        stats = eng.stats()
+    finally:
+        await eng.close()
+    assert len(got) == new
+    # Prefill answers the first token; the other 13 take 4 waves of 4
+    # steps, the last of which decodes 3 steps past the request's end.
+    context = len(prompt) + 1 + np.arange(-(-(new - 1) // k) * k)
+    tokens, blocks = int(context.sum()), int((-(-context // BS)).sum())
+    assert eng._kv_context_tokens == tokens
+    assert eng._kv_blocks_walked == blocks
+    assert stats["kv_block_fill"] == round(tokens / (blocks * BS), 4)
+    assert obs.generator_decode_kv_context_tokens_total().labels(
+        model="kv-counted").value == tokens
+    assert obs.generator_decode_kv_blocks_walked_total().labels(
+        model="kv-counted").value == blocks
 
 
 # ------------------------------ the flat pool against [NB, BS, H, D]
