@@ -235,6 +235,7 @@ class GenerationEngine:
                  speculative: Optional[Dict[str, Any]] = None,
                  rng_seed: int = 0,
                  logprob_topk: int = 5,
+                 prefill_rows: Optional[int] = None,
                  mesh=None,
                  name: str = "decoder"):
         import jax
@@ -302,9 +303,28 @@ class GenerationEngine:
         self.sanitize_source = (
             f"generator:{self.name}:{next(_generator_seq)}")
 
-        n_layers = cfg.num_layers
         cache_dtype = cfg.dtype
         self._cache_dtype = cache_dtype
+        # -- what each layer keeps between steps -------------------------
+        # The model declares it (`config.cache_layers()`, models/
+        # decoder.py): K/V rows in the block pool below, arrays of a
+        # slot's own (a recurrence's state: models/nemotron_h.py), or
+        # nothing.  Every size the engine books or counts comes from
+        # this declaration.
+        from kfserving_tpu.models.decoder import KVCache, StateCache
+
+        self._cache_layers = list(cfg.cache_layers())
+        kv_layers = [c for c in self._cache_layers
+                     if isinstance(c, KVCache)]
+        self._has_state = any(isinstance(c, StateCache)
+                              for c in self._cache_layers)
+        if not kv_layers or len(set(kv_layers)) != 1:
+            raise InvalidInput(
+                "the engine pages K/V: a model needs at least one K/V "
+                f"layer, and all of one geometry; {name!r} declares "
+                f"{sorted(set(kv_layers))}")
+        kv_heads, kv_head_dim = kv_layers[0]
+        n_kv_layers = len(kv_layers)
         # -- the KV cache: a block pool ---------------------------------
         # A shared pool [NB, BS, H*D] a layer (ops/paged_attention.py
         # owns the layout) + per-slot block tables — HBM scales with
@@ -336,13 +356,35 @@ class GenerationEngine:
         from kfserving_tpu.ops import paged_attention
 
         pool_shape = paged_attention.pool_shape(
-            self.num_blocks, bs, cfg.num_heads, cfg.head_dim)
+            self.num_blocks, bs, kv_heads, kv_head_dim)
         self._cache_shape = pool_shape
-        self._caches = [
-            (jnp.zeros(pool_shape, cache_dtype),
-             jnp.zeros(pool_shape, cache_dtype))
-            for _ in range(n_layers)
-        ]
+
+        def layer_cache(kind):
+            """One layer's arrays: its two pools, its state with the
+            slots leading ([max_slots, ...]; a slot's row is written by
+            the insert that admits a request there and stepped by every
+            decode wave, so a reused slot's old state is overwritten
+            before anything reads it), or none."""
+            if isinstance(kind, KVCache):
+                return (jnp.zeros(pool_shape, cache_dtype),
+                        jnp.zeros(pool_shape, cache_dtype))
+            if isinstance(kind, StateCache):
+                return tuple(jnp.zeros((self.max_slots,) + tuple(shape),
+                                       dtype)
+                             for shape, dtype in kind.arrays)
+            return ()
+
+        def nbytes(arrays) -> int:
+            return sum(int(x.size) * x.dtype.itemsize
+                       for x in jax.tree.leaves(arrays))
+
+        self._caches = [layer_cache(kind) for kind in self._cache_layers]
+        self._cache_bytes = nbytes(self._caches)
+        self.recurrent_state_bytes = nbytes([
+            layer for kind, layer in zip(self._cache_layers, self._caches)
+            if isinstance(kind, StateCache)])
+        obs.generator_recurrent_state_bytes().labels(
+            model=name).set(self.recurrent_state_bytes)
         # Host-side paging state (guarded by _block_lock: the
         # enqueue thread allocates while cancel() frees on the
         # loop thread).
@@ -395,8 +437,8 @@ class GenerationEngine:
         if host_tier_blocks and int(host_tier_blocks) > 0:
             from kfserving_tpu.engine.kv_tier import HostKVTier
 
-            block_payload = (2 * n_layers * bs * cfg.num_heads
-                             * cfg.head_dim
+            block_payload = (2 * n_kv_layers * bs * kv_heads
+                             * kv_head_dim
                              * np.dtype(cache_dtype).itemsize)
             self.kv_tier = HostKVTier(
                 block_bytes=block_payload,
@@ -492,6 +534,38 @@ class GenerationEngine:
                     self._draft_window = int(speculative.get(
                         "draft_window", DEFAULT_DRAFT_WINDOW))
 
+        # A recurrence's state is not rows addressed by position: a
+        # shared prefix has no blocks to point at (every plan is a miss,
+        # and is counted), and the features below each rest on
+        # rewriting, re-reading or moving such rows.
+        if self._has_state:
+            for setting, on, why in (
+                    ("speculative", self.spec_tokens > 0,
+                     "a rejected draft token has already moved the "
+                     "state, and nothing overwrites it"),
+                    ("prefill_chunk_tokens",
+                     self.prefill_chunk_tokens is not None,
+                     "a chunk would have to continue from the state the "
+                     "chunk before it left"),
+                    ("host_tier_blocks", self.kv_tier is not None,
+                     "a spilled prefix is K/V blocks, and the state at "
+                     "its end is kept nowhere")):
+                if on:
+                    raise InvalidInput(
+                        f"{setting} is not served for {name!r}, a model "
+                        f"with recurrent state: {why}")
+
+            # The recurrence's prefill once hung a v5e, and a hang takes
+            # the chip: on a TPU only the shapes that have run are served.
+            from kfserving_tpu.ops import ssm
+
+            unproven = ssm.unproven_on_chip(prefill_rows,
+                                            self.prefill_buckets)
+            if unproven and jax.default_backend() == "tpu":
+                raise InvalidInput(
+                    f"{name!r} has recurrent state, whose prefill has run "
+                    f"on the chip at few shapes only: {unproven}")
+
         # Parameters are resident from here on, like the pool: a host
         # leaf handed to a jitted call is transferred again on every
         # launch (3.1 GB a launch for gpt2-large, ROADMAP A9).  Under
@@ -521,12 +595,13 @@ class GenerationEngine:
             # collective.  The pool's last axis is H*D: splitting it
             # over tp gives the same head groups.
             tp = mesh.shape.get("tp", 1)
-            heads_axis = "tp" if cfg.num_heads % max(tp, 1) == 0 else None
+            heads_axis = "tp" if kv_heads % max(tp, 1) == 0 else None
             sharding = NamedSharding(
                 mesh, PartitionSpec(None, None, heads_axis))
             self._caches = [
-                (jax.device_put(k, sharding), jax.device_put(v, sharding))
-                for k, v in self._caches
+                tuple(jax.device_put(x, sharding if isinstance(kind, KVCache)
+                                     else replicated) for x in layer)
+                for kind, layer in zip(self._cache_layers, self._caches)
             ]
 
         base_key = self._rng
@@ -544,15 +619,28 @@ class GenerationEngine:
 
             self._moe = MoeCounters(name, cfg.num_experts)
         routed = self._moe is not None
+        cache_kinds = self._cache_layers
 
         def apply(variables, ids, **kw):
-            """(module.apply's outputs, routed pairs [layers, experts]
-            of an expert model or None)."""
+            """(module.apply's outputs, what an expert model's routers
+            chose or None): `pairs` [expert layers, experts held], and
+            `elsewhere` [expert layers] where the model holds a share of
+            its experts."""
             if not routed:
                 return module.apply(variables, ids, **kw), None
             out, state = module.apply(variables, ids, mutable=["moe"],
                                       **kw)
-            return out, module.routed_pairs(state)
+            chose = {"pairs": module.routed_pairs(state)}
+            if hasattr(module, "routed_elsewhere"):
+                chose["elsewhere"] = module.routed_elsewhere(state)
+            return out, chose
+
+        def with_table(caches, table):
+            """The caches as the model takes them: a K/V layer's pools
+            with this dispatch's block table."""
+            return [layer + (table,) if isinstance(kind, KVCache)
+                    else layer
+                    for kind, layer in zip(cache_kinds, caches)]
 
         def mask_to_support(logits, top_ks, top_ps):
             """Restrict logits to the top-k / nucleus support.  Both
@@ -629,10 +717,9 @@ class GenerationEngine:
             round trip."""
             def step(carry, _):
                 caches, tokens, positions = carry
-                kv = [(k, v, table) for k, v in caches]
                 (logits, new_caches), pairs = apply(
                     variables, tokens[:, None], positions=positions,
-                    kv_cache=kv)
+                    kv_cache=with_table(caches, table))
                 lg = logits[:, 0]
                 # The token being sampled extends a prefix of length
                 # positions+1 — the noise index is that length, so
@@ -697,15 +784,16 @@ class GenerationEngine:
             # the insert is then a scatter of whole blocks, where
             # [B, L, H, D] results (L minor-most on the chip) would be
             # transposed on their way in.
-            caches = [tuple(x.reshape(x.shape[:2] + (-1,)) for x in kv)
-                      for kv in caches]
+            caches = [tuple(x.reshape(x.shape[:2] + (-1,)) for x in layer)
+                      if isinstance(kind, KVCache) else layer
+                      for kind, layer in zip(cache_kinds, caches)]
             last = logits[:, 0]
             first_tokens = sample(last, temps, top_ks, top_ps, seeds,
                                   lengths)
             chosen_lp, top_ids, top_lps = logprob_of(last,
                                                      first_tokens)
             out = (first_tokens, caches, chosen_lp, top_ids, top_lps)
-            return out + ({"pairs": pairs},) if routed else out
+            return out + (pairs,) if routed else out
 
         # One executable per prompt bucket (jit caches by shape).
         self._prefill = jax.jit(prefill_fn)
@@ -724,9 +812,9 @@ class GenerationEngine:
             the FINAL chunk (it becomes the stream's first token,
             noise-keyed on the full prompt length for parity with
             monolithic prefill) — earlier chunks discard it."""
-            kv = [(k, v, table) for k, v in caches]
             logits, new_caches = module.apply(
-                variables, ids, positions=qpos, kv_cache=kv,
+                variables, ids, positions=qpos,
+                kv_cache=with_table(caches, table),
                 logit_positions=last_idx)
             lg = logits[:, 0]
             first = sample(lg, temps, top_ks, top_ps, seeds,
@@ -766,7 +854,7 @@ class GenerationEngine:
                 dispatch, and positions advance monotonically)."""
                 tokens = jnp.concatenate(
                     [last_tokens[:, None], draft_toks], axis=1)
-                kv = [(k, v, table) for k, v in caches]
+                kv = with_table(caches, table)
                 s_rows = tokens.shape[0]
                 gather = jnp.broadcast_to(
                     jnp.arange(spec_kp1, dtype=jnp.int32)[None, :],
@@ -811,16 +899,23 @@ class GenerationEngine:
                     jax, self._draft_module, self.max_slots,
                     self._draft_window, self.spec_tokens)
 
-        def insert_fn(caches, new_caches, dest_blocks):
+        def insert_fn(caches, new_caches, dest_blocks, slots=None):
             """Scatter a prefill batch's k/v into pool blocks.
             dest_blocks [B, chunks] int32; -1 chunks drop (bucket
             padding rows, and prefix-cache hits whose shared blocks
-            already hold the data)."""
+            already hold the data).  A state layer's rows go to their
+            slots whole (`slots` [B] int32, past-the-end for a padding
+            row, which drops)."""
             out = []
-            for (pk, pv), (k_new, v_new) in zip(caches, new_caches):
-                pk, pv = paged_attention.paged_insert(
-                    pk, pv, k_new, v_new, dest_blocks, None)
-                out.append((pk, pv))
+            for kind, layer, new in zip(cache_kinds, caches, new_caches):
+                if isinstance(kind, KVCache):
+                    layer = paged_attention.paged_insert(
+                        *layer, *new, dest_blocks, None)
+                elif isinstance(kind, StateCache):
+                    layer = tuple(
+                        old.at[slots].set(x.astype(old.dtype), mode="drop")
+                        for old, x in zip(layer, new))
+                out.append(layer)
             return out
 
         self._insert = jax.jit(insert_fn, donate_argnums=(0,))
@@ -901,9 +996,17 @@ class GenerationEngine:
         # live rows' contexts, and the tokens in them (_distribute).
         self._kv_blocks_walked = 0
         self._kv_context_tokens = 0
-        # The row count prefill dispatches are held to once the runtime
-        # has refused one for memory (see _prefill_refused).
-        self._prefill_rows_cap: Optional[int] = None
+        # The row count prefill dispatches are held to: configured
+        # (`prefill_rows`: the deployment knows what fits beside its
+        # parameters), or learned once the runtime has refused one for
+        # memory (see _prefill_refused), which also makes every later
+        # dispatch wait for its insert.
+        if prefill_rows is not None and int(prefill_rows) < 1:
+            raise InvalidInput("prefill_rows must be >= 1")
+        self.prefill_rows = int(prefill_rows) if prefill_rows else None
+        self._prefill_rows_cap: Optional[int] = self.prefill_rows
+        self._prefill_refusals = 0
+        self.prefix_reuse_refused = 0
         # Union of enqueue->fetch intervals (overlap-corrected at
         # depth >= 2, so the stat stays <= wall clock).
         self._decode_device_s = 0.0
@@ -936,10 +1039,12 @@ class GenerationEngine:
             self._param_read_bytes = counts["always_read"] * per_param
             self._expert_read_bytes = counts["per_expert"] * per_param
         self._flops_matmul_per_token = 2.0 * self._active_params
-        self._attn_flops_coeff = (4.0 * n_layers * cfg.num_heads
-                                  * cfg.head_dim)
-        self._kv_bytes_per_token = (2 * n_layers * cfg.num_heads
-                                    * cfg.head_dim
+        # Query heads do the arithmetic, KV heads are what is read.
+        self._attn_flops_coeff = (4.0 * n_kv_layers
+                                  * getattr(cfg, "num_heads", kv_heads)
+                                  * kv_head_dim)
+        self._kv_bytes_per_token = (2 * n_kv_layers * kv_heads
+                                    * kv_head_dim
                                     * np.dtype(cache_dtype).itemsize)
         from kfserving_tpu.engine.jax_engine import device_peak_flops
         from kfserving_tpu.observability.profiling.roofline import (
@@ -960,9 +1065,8 @@ class GenerationEngine:
 
     # -- public API --------------------------------------------------------
     def cache_bytes(self) -> int:
-        per_buf = int(np.prod(self._cache_shape)) * \
-            np.dtype(self._cache_dtype).itemsize
-        return per_buf * 2 * len(self._caches)
+        """Every layer's pools and per-slot state."""
+        return self._cache_bytes
 
     def param_bytes(self) -> int:
         jax = self._jax
@@ -1171,7 +1275,9 @@ class GenerationEngine:
                 self._kv_context_tokens / max(
                     1, self._kv_blocks_walked * self.block_size), 4),
             "prefill_rows_cap": self._prefill_rows_cap or 0,
+            "prefill_rows": self.prefill_rows or 0,
             "cache_bytes": self.cache_bytes(),
+            "recurrent_state_bytes": self.recurrent_state_bytes,
             "params_resident_bytes": self._params_resident_bytes,
             "active_params": self._active_params,
             "decode_device_s": round(self._decode_device_s, 4),
@@ -1911,6 +2017,14 @@ class GenerationEngine:
         dispatch enqueues."""
         import hashlib
 
+        if self._has_state:
+            # No block stands for a prefix of a recurrence: the plan is
+            # a miss whatever the index holds, registers nothing, and
+            # says so.
+            force_miss = True
+            self.prefix_reuse_refused += 1
+            obs.generator_prefix_reuse_refused_total().labels(
+                model=self.name).inc()
         bs = self.block_size
         n = int(req.prompt_ids.size)
         full = n // bs
@@ -2096,7 +2210,7 @@ class GenerationEngine:
                         # duplicate block for no gain.
                         if self._prefix_index.get(chain) is None:
                             chunk_regs[c] = (chain, blk)
-                    else:
+                    elif not self._has_state:
                         self._prefix_index[chain] = blk
                         self._block_chain[blk] = chain
                         fresh_regs.append((chain, blk))
@@ -2362,6 +2476,7 @@ class GenerationEngine:
             return False
         padded = 1 << (rows - 1).bit_length()  # the launch's row bucket
         self._prefill_rows_cap = padded // 2
+        self._prefill_refusals += 1
         from kfserving_tpu.engine.hbm import device_hbm_stat
 
         logger.warning(
@@ -3239,7 +3354,8 @@ class GenerationEngine:
                 if self._moe is not None:
                     self._moe.note(
                         "decode", out[7], layer_steps=(
-                            self.steps_per_call * len(self._caches)))
+                            self.steps_per_call
+                            * out[7]["pairs"].shape[0]))
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         self.decode_steps += 1
         # Snapshot records mid-chunked-prefill slots as None: this
@@ -3358,7 +3474,7 @@ class GenerationEngine:
             dest_d = jnp.asarray(dest)
         with TIMELINE.span(LAUNCH, "engine.launch.insert", rows=b):
             self._caches = self._insert(self._caches, new_caches,
-                                        dest_d)
+                                        dest_d, slot_d)
         # The admitted slots' first feed token/position land in the
         # device-resident feed arrays; rows of slots NOT in this group
         # keep their device values (the last enqueued wave's outputs,
@@ -3370,13 +3486,13 @@ class GenerationEngine:
                 self._feed_update(
                     self._feed_tokens, self._feed_positions,
                     slot_d, firsts, lengths_d)
-        if self._prefill_rows_cap is not None:
+        if self._prefill_refusals:
             # Memory is that tight (see _prefill_refused): nothing else
             # is launched until the insert has consumed this dispatch's
             # k/v, so that two dispatches' outputs are never held at
             # once.
             with TIMELINE.span(LAUNCH, "engine.wait.insert"):
-                self._jax.block_until_ready(self._caches[0])
+                self._jax.block_until_ready(self._caches)
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         return firsts, lp_h
 

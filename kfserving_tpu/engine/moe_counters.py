@@ -6,7 +6,10 @@ expert) pairs summed over the call's steps, `touched` (distinct experts
 read, summed over the call's layer-steps) and `load_max` (the busiest
 expert's pairs, summed likewise); per prefill dispatch `pairs` alone.  The
 counts are over the rows a program computed: a parked decode row's garbage
-step reads its experts too.
+step reads its experts too.  A model that holds a share of its experts
+(models/nemotron_h.py) counts all of these among the experts held, over its
+expert layers, and reports beside them `elsewhere`, the pairs its routers
+gave to experts that live on other chips.
 
 The launching thread `note`s the handles; a fetch worker `drain`s those
 that are ready after its own wave's fetch, so no launch and no fetch waits
@@ -22,11 +25,16 @@ import numpy as np
 from kfserving_tpu.observability import metrics as obs
 
 
-def decode_call_stats(pairs) -> Dict[str, Any]:
-    """Device side: `pairs` [steps, layers, experts] of one decode call."""
-    return {"pairs": pairs.sum(axis=0),
-            "touched": (pairs > 0).sum(),
-            "load_max": pairs.max(axis=-1).sum()}
+def decode_call_stats(chose: Dict[str, Any]) -> Dict[str, Any]:
+    """Device side: what the routers chose over one decode call,
+    `pairs` [steps, layers, experts] and, under a share, `elsewhere`
+    [steps, layers]."""
+    pairs = chose["pairs"]
+    stats = {"pairs": pairs.sum(axis=0), "touched": (pairs > 0).sum(),
+             "load_max": pairs.max(axis=-1).sum()}
+    if "elsewhere" in chose:
+        stats["elsewhere"] = chose["elsewhere"].sum()
+    return stats
 
 
 class MoeCounters:
@@ -34,6 +42,7 @@ class MoeCounters:
         self.model = model
         self.experts = experts
         self.pairs = {"decode": 0, "prefill": 0}  # routed, by program
+        self.elsewhere = 0     # routed to experts not held here
         self.touched = 0       # distinct experts read, over layer-steps
         self.layer_steps = 0   # decode layer-steps counted
         self.load_max = 0      # busiest expert's pairs, over layer-steps
@@ -64,6 +73,11 @@ class MoeCounters:
                 self.pairs[program] += pairs
                 obs.generator_moe_routed_pairs_total().labels(
                     model=self.model, program=program).inc(pairs)
+                if "elsewhere" in host:
+                    elsewhere = int(host["elsewhere"].sum())
+                    self.elsewhere += elsewhere
+                    obs.generator_moe_routed_pairs_elsewhere_total().labels(
+                        model=self.model).inc(elsewhere)
                 if layer_steps:
                     self.touched += int(host["touched"])
                     self.load_max += int(host["load_max"])
@@ -81,7 +95,10 @@ class MoeCounters:
                 return {}
             mean_load = (self.pairs["decode"] / self.experts
                          / self.layer_steps)
+            held = sum(self.pairs.values())
             return {
+                "moe_pairs_held_share": round(
+                    held / max(1, held + self.elsewhere), 4),
                 "moe_experts_touched_mean": round(
                     self.touched / self.layer_steps, 4),
                 "moe_load_max_over_mean": round(

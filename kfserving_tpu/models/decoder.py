@@ -35,12 +35,26 @@ TPU notes:
   kernels when eligible, XLA gathers elsewhere).
 """
 
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax.numpy as jnp
 
 from kfserving_tpu.ops import dot_product_attention
+
+
+# What a layer keeps between steps, as a model's config declares it
+# (`config.cache_layers()`, one entry a layer) and the engine builds it:
+# K/V rows in the block pool, arrays of a slot's own (a recurrence's
+# state), or None.
+class KVCache(NamedTuple):
+    heads: int       # KV heads: fewer than the query's under GQA
+    head_dim: int
+
+
+class StateCache(NamedTuple):
+    # ((shape without the slot axis, dtype), ...), one per array
+    arrays: Tuple[Tuple[Tuple[int, ...], Any], ...]
 
 
 class DecoderConfig:
@@ -66,13 +80,19 @@ class DecoderConfig:
     def head_dim(self):
         return self.hidden_size // self.num_heads
 
+    def cache_layers(self):
+        return [KVCache(self.num_heads, self.head_dim)] * self.num_layers
+
 
 def cached_attention(q, k, v, *, cache=None, positions=None,
                      kv_lengths=None, attn_fn=None):
     """Attention of one block, shared by every decoder block of the zoo
-    (GPT-2's here, OLMoE's in models/olmoe.py): q, k, v are
-    [B, L, H, D] as projected (and, for rotary models, rotated — the
-    pool stores what attention reads).  `cache` is None (full forward
+    (GPT-2's here, OLMoE's in models/olmoe.py, Nemotron-H's in
+    models/nemotron_h.py): q, k, v are [B, L, H, D] as projected (and,
+    for rotary models, rotated — the pool stores what attention reads);
+    k and v may have fewer heads than q (grouped-query attention: query
+    head j reads KV head j // (Hq / Hkv)), and the pool holds theirs.
+    `cache` is None (full forward
     and prefill: causal attention over q, k, v themselves) or
     (pool_k, pool_v, block_table): the engine's block pools
     [NB, BS, H*D] (ops/paged_attention.py owns the layout and reshapes
@@ -84,6 +104,11 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
     — cross-chunk attention comes from the pool, exactly like decode).
     Returns (out [B, L, H, D], new_cache)."""
     lq = q.shape[1]
+    group = q.shape[2] // k.shape[2]
+    new_cache = (k, v)
+    if cache is None and group > 1:
+        # Full forward and prefill: every query head its own copy.
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     if cache is not None:
         from kfserving_tpu.ops.paged_attention import (
             paged_attention,
@@ -117,11 +142,9 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
         # this a prefill with return_cache=True under a pluggable
         # attn_fn returned caches=[None, ...] and crashed deep in
         # the engine's insert scatter instead of working.
-        new_cache = (k, v)
     else:
         out = dot_product_attention(q, k, v, causal=True,
                                     kv_lengths=kv_lengths)
-        new_cache = (k, v)
     return out, new_cache
 
 

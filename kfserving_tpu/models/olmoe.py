@@ -31,7 +31,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from kfserving_tpu.models.decoder import cached_attention
+from kfserving_tpu.models.decoder import KVCache, cached_attention
 from kfserving_tpu.ops import moe
 
 
@@ -60,6 +60,9 @@ class OlmoeConfig:
     @property
     def head_dim(self):
         return self.hidden_size // self.num_heads
+
+    def cache_layers(self):
+        return [KVCache(self.num_heads, self.head_dim)] * self.num_layers
 
     def param_counts(self):
         """Parameters by how a served token meets them: `per_expert`

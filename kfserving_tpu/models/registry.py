@@ -34,6 +34,9 @@ _LAZY_BUILTINS: Dict[str, Tuple[str, str]] = {
                      "_create_decoder_tiny"),
     "olmoe": ("kfserving_tpu.models.olmoe", "_create_olmoe"),
     "olmoe_tiny": ("kfserving_tpu.models.olmoe", "_create_olmoe_tiny"),
+    "nemotron_h": ("kfserving_tpu.models.nemotron_h", "_create_nemotron_h"),
+    "nemotron_h_tiny": ("kfserving_tpu.models.nemotron_h",
+                        "_create_nemotron_h_tiny"),
 }
 
 

@@ -413,6 +413,31 @@ def generator_moe_expert_load_max_total():
         "uneven the routing is")
 
 
+def generator_moe_routed_pairs_elsewhere_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_routed_pairs_elsewhere_total",
+        "(token, expert) pairs the routers gave to experts this replica "
+        "does not hold (a model served as one chip's share of its "
+        "experts): with generator_moe_routed_pairs_total, the share of "
+        "the routed work that is done here")
+
+
+def generator_recurrent_state_bytes():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_recurrent_state_bytes",
+        "Bytes of per-slot recurrent state (a state-space layer's, "
+        "beside the paged K/V pool) resident for the model; 0 for a "
+        "model whose every layer caches K/V")
+
+
+def generator_prefix_reuse_refused_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_prefix_reuse_refused_total",
+        "Prompt plans made without probing the prefix index because the "
+        "model keeps recurrent state: no block stands for a prefix of a "
+        "recurrence, so every such plan is a miss")
+
+
 # -- HBM residency (engine/hbm.py accountant) ---------------------------
 def hbm_resident_bytes():
     return REGISTRY.gauge(

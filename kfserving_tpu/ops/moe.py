@@ -1,11 +1,20 @@
-"""Routed expert layer: softmax top-k routing and three ways through the
-experts, chosen from shapes (and where the program runs) at trace time.
+"""Routed expert layer: top-k routing (two routers) and three ways through
+the experts, chosen from shapes (and where the program runs) at trace time.
 
-A mixture-of-experts MLP (OLMoE, models/olmoe.py) holds E gated MLPs
-`down_e(silu(x·gate_e) ⊙ x·up_e)` and sends every token through the k of
-them its router scores highest, weighted by the router's probabilities.
-No token is dropped and there is no capacity limit: every path computes
-the k·T (token, expert) pairs the router chose.
+A mixture-of-experts MLP holds E experts and sends every token through the
+k of them its router scores highest, weighted by the router's weights.  No
+token is dropped and there is no capacity limit: every path computes the
+(token, expert) pairs the router chose.  Two things vary by model and are
+arguments of every path, not copies of it:
+
+- the expert's form: gated, `down_e(silu(x·gate_e) ⊙ x·up_e)` (OLMoE,
+  models/olmoe.py: three matrices), or plain, `down_e(relu(x·up_e)²)`
+  (Nemotron-H, models/nemotron_h.py: two; `gate=None`);
+- the experts held: all E (`first=None`), or the `up.shape[0]` of them that
+  start at expert `first` (one chip's share under expert parallelism).  The
+  router's ids run over all E; a pair whose expert is not held is computed
+  by no path here (it is another chip's), like a pair of a token that is
+  not `valid`.
 
 - `experts_grouped`: routed work only.  The pairs are sorted by expert,
   the tokens gathered in that order, and three `jax.lax.ragged_dot`s
@@ -76,50 +85,88 @@ def route(logits: jax.Array, k: int):
     return top, experts.astype(jnp.int32)
 
 
+def route_sigmoid(logits: jax.Array, bias: jax.Array, k: int,
+                  scale: float):
+    """The DeepSeek-V3 router as Nemotron-H uses it (one group, so no
+    group limit).  Scores s = sigmoid(logits) in float32 over all E; the k
+    largest of s + bias are chosen (the bias steers the choice alone);
+    their weights are scale · s_i / (Σ_chosen s + 1e-20).  Returns
+    (weights [T, k] float32, experts [T, k] int32)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = scale * chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    return weights, experts.astype(jnp.int32)
+
+
+def _held(experts: jax.Array, count: int, first: Optional[int],
+          valid: Optional[jax.Array]) -> jax.Array:
+    """The router's ids as indices into the `count` experts held, and
+    `count` (one past them) for a pair no path computes: its token is not
+    valid, or its expert is not among those that start at `first`."""
+    if first is None and valid is None:
+        return experts
+    keep = None if valid is None else valid[:, None]
+    if first is not None:
+        experts = experts - first
+        mine = (experts >= 0) & (experts < count)
+        keep = mine if keep is None else keep & mine
+    return jnp.where(keep, experts, count)
+
+
 def routed_pairs(experts: jax.Array, num_experts: int,
-                 valid: Optional[jax.Array] = None) -> jax.Array:
-    """[E] int32: how many (token, expert) pairs each expert was given."""
-    if valid is not None:
-        experts = jnp.where(valid[:, None], experts, num_experts)
+                 valid: Optional[jax.Array] = None,
+                 first: Optional[int] = None) -> jax.Array:
+    """[num_experts] int32: how many (token, expert) pairs each expert
+    held was given."""
+    experts = _held(experts, num_experts, first, valid)
     return jnp.zeros(num_experts, jnp.int32).at[experts.reshape(-1)].add(
         1, mode="drop")
 
 
-def _gated(g: jax.Array, u: jax.Array) -> jax.Array:
-    return jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+def _activation(g: Optional[jax.Array], u: jax.Array) -> jax.Array:
+    """float32: silu(g) ⊙ u of a gated expert, relu(u)² of a plain one."""
+    u = u.astype(jnp.float32)
+    if g is None:
+        return jnp.square(jax.nn.relu(u))
+    return jax.nn.silu(g.astype(jnp.float32)) * u
 
 
-def experts_streamed(x, gate, up, down, probs, experts):
-    """x [T, H]; gate, up [E, H, F]; down [E, F, H]; probs, experts
-    [T, k].  Every expert on every token, weighted by the router's
-    probability where the expert was chosen and by zero elsewhere."""
-    t, e = x.shape[0], gate.shape[0]
+def experts_streamed(x, gate, up, down, probs, experts, valid=None,
+                     first=None):
+    """x [T, H]; gate (or None), up [E, H, F]; down [E, F, H]; probs,
+    experts [T, k].  Every held expert on every token, weighted by the
+    router's weight where the expert was chosen and by zero elsewhere."""
+    t, e = x.shape[0], up.shape[0]
     with jax.named_scope("moe.dispatch"):
         weights = jnp.zeros((t, e), jnp.float32).at[
-            jnp.arange(t)[:, None], experts].add(probs)
+            jnp.arange(t)[:, None], _held(experts, e, first, valid)].add(
+                probs, mode="drop")
     with jax.named_scope("moe.experts"):
-        g = jnp.einsum("th,ehf->etf", x, gate)
+        g = None if gate is None else jnp.einsum("th,ehf->etf", x, gate)
         u = jnp.einsum("th,ehf->etf", x, up)
-        act = (_gated(g, u) * weights.T[:, :, None]).astype(x.dtype)
+        act = (_activation(g, u) * weights.T[:, :, None]).astype(x.dtype)
         return jnp.einsum("etf,efh->th", act, down)
 
 
-def experts_grouped(x, gate, up, down, probs, experts, valid=None):
+def experts_grouped(x, gate, up, down, probs, experts, valid=None,
+                    first=None):
     """The same sum over routed pairs only: rows sorted by expert, one
     grouped matmul per matrix.  valid: optional [T] bool; a token that
-    is not valid is routed to no expert and gets a zero row."""
+    is not valid is routed to no expert and gets a zero row.  Pairs of
+    experts not held sort past the last group beside theirs."""
     t, k = experts.shape
-    e = gate.shape[0]
+    e = up.shape[0]
     with jax.named_scope("moe.dispatch"):
-        sizes = routed_pairs(experts, e, valid)
-        if valid is not None:
-            experts = jnp.where(valid[:, None], experts, e)
+        experts = _held(experts, e, first, valid)
+        sizes = routed_pairs(experts, e)
         order = jnp.argsort(experts.reshape(-1), stable=True)
         rows = x[order // k]
     with jax.named_scope("moe.experts"):
-        g = jax.lax.ragged_dot(rows, gate, sizes)
+        g = None if gate is None else jax.lax.ragged_dot(rows, gate, sizes)
         u = jax.lax.ragged_dot(rows, up, sizes)
-        out = jax.lax.ragged_dot(_gated(g, u).astype(x.dtype), down, sizes)
+        out = jax.lax.ragged_dot(_activation(g, u).astype(x.dtype), down,
+                                 sizes)
     with jax.named_scope("moe.combine"):
         # Rows past the last group belong to no expert; whatever the
         # kernel left there is replaced, not scaled.
@@ -133,12 +180,14 @@ def experts_grouped(x, gate, up, down, probs, experts, valid=None):
             axis=1, dtype=jnp.float32).astype(x.dtype)
 
 
-def _touched_kernel(ids_ref, count_ref, x_ref, w_ref, gate_ref, up_ref,
-                    down_ref, o_ref, acc_ref):
-    """One grid step an entry of the touched list: this expert's three
-    matrices are in VMEM (the next entry's are on their way), every
-    token goes through it, and the router's weight (zero for a token
-    that did not choose it) scales what it adds."""
+def _touched_kernel(ids_ref, count_ref, x_ref, w_ref, *refs, gated: bool):
+    """One grid step an entry of the touched list: this expert's
+    matrices (gate, up, down; or up, down of a plain expert) are in VMEM
+    (the next entry's are on their way), every token goes through it,
+    and the router's weight (zero for a token that did not choose it)
+    scales what it adds."""
+    gate_ref = refs[0] if gated else None
+    up_ref, down_ref, o_ref, acc_ref = refs[-4:]
     j = pl.program_id(0)
 
     @pl.when(j == 0)
@@ -148,9 +197,10 @@ def _touched_kernel(ids_ref, count_ref, x_ref, w_ref, gate_ref, up_ref,
     @pl.when(j < count_ref[0])
     def _expert():
         x = x_ref[...]
-        g = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
+        g = None if gate_ref is None else jnp.dot(
+            x, gate_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
-        act = (jax.nn.silu(g) * u * w_ref[0]).astype(x.dtype)
+        act = (_activation(g, u) * w_ref[0]).astype(x.dtype)
         acc_ref[...] += jnp.dot(act, down_ref[0],
                                 preferred_element_type=jnp.float32)
 
@@ -161,9 +211,9 @@ def _touched_kernel(ids_ref, count_ref, x_ref, w_ref, gate_ref, up_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def experts_touched(x, gate, up, down, probs, experts, valid=None,
-                    interpret: bool = False):
+                    first=None, interpret: bool = False):
     """The same sum for few tokens, as a Pallas TPU kernel that reads
-    each *touched* expert's three matrices once and no other's: the
+    each *touched* held expert's matrices once and no other's: the
     grid walks the list of experts some token chose (scalar prefetch:
     the list picks the blocks), whole matrices are double-buffered
     through VMEM while the MXU multiplies all T tokens by the resident
@@ -172,10 +222,10 @@ def experts_touched(x, gate, up, down, probs, experts, valid=None,
     alone, whatever the routing; T x touched experts of arithmetic hides
     under the stream as `experts_streamed`'s does."""
     t, k = experts.shape
-    e, h, f = gate.shape
+    e, h, f = up.shape
+    matrices = [up, down] if gate is None else [gate, up, down]
     with jax.named_scope("moe.dispatch"):
-        if valid is not None:
-            experts = jnp.where(valid[:, None], experts, e)
+        experts = _held(experts, e, first, valid)
         weights = jnp.zeros((t, e), jnp.float32).at[
             jnp.arange(t)[:, None], experts].add(probs, mode="drop")
         touched = jnp.zeros((e,), jnp.bool_).at[experts.reshape(-1)].set(
@@ -201,57 +251,63 @@ def experts_touched(x, gate, up, down, probs, experts, valid=None,
         in_specs=[
             pl.BlockSpec((rows, h), whole),
             pl.BlockSpec((1, rows, 1), expert_block),
-            pl.BlockSpec((1, h, f), expert_block),
-            pl.BlockSpec((1, h, f), expert_block),
-            pl.BlockSpec((1, f, h), expert_block),
-        ],
+        ] + [pl.BlockSpec((1,) + m.shape[1:], expert_block)
+             for m in matrices],
         out_specs=pl.BlockSpec((rows, h), whole),
         scratch_shapes=[pltpu.VMEM((rows, h), jnp.float32)],
     )
     with jax.named_scope("moe.experts"):
         out = pl.pallas_call(
-            _touched_kernel, grid_spec=grid_spec,
+            functools.partial(_touched_kernel, gated=gate is not None),
+            grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
-                vmem_limit_bytes=_touched_vmem_bytes(rows, h, f,
-                                                     gate.dtype)),
+                vmem_limit_bytes=_touched_vmem_bytes(
+                    rows, h, f, up.dtype, len(matrices))),
             name="moe_experts_touched", interpret=interpret,
-        )(ids, count[None], x_rows, w_rows, gate, up, down)
+        )(ids, count[None], x_rows, w_rows, *matrices)
     return out[:t]
 
 
-def _touched_vmem_bytes(rows: int, h: int, f: int, dtype) -> int:
-    """Three matrices an expert, two buffers each, the tokens in and
-    out, the float32 sum and the gated activations, and room to spare."""
+def _touched_vmem_bytes(rows: int, h: int, f: int, dtype,
+                        matrices: int = 3) -> int:
+    """An expert's matrices, two buffers each, the tokens in and out,
+    the float32 sum and the activations, and room to spare."""
     item = jnp.dtype(dtype).itemsize
-    return (6 * h * f * item + 4 * rows * h * item + 4 * rows * h
-            + 16 * rows * f + (8 << 20))
+    return (2 * matrices * h * f * item + 4 * rows * h * item
+            + 4 * rows * h + 16 * rows * f + (8 << 20))
 
 
-def _touched_kernel_serves(x, gate) -> bool:
+def _touched_kernel_serves(x, up, matrices: int = 3) -> bool:
     """The Pallas kernel's gate, read at trace time: a TPU, no ambient
     mesh (a Mosaic kernel is not partitioned automatically; under `tp`
-    the XLA paths split the expert width), lane-aligned widths, and an
-    expert's matrices twice over within the chip's VMEM."""
+    the XLA paths split the expert width), lane-aligned widths (a model
+    whose expert width is not one stores its matrices padded with zeros:
+    models/nemotron_h.py, 1856 as 1920), and an expert's matrices twice
+    over within the chip's VMEM."""
     from kfserving_tpu.ops.attention import _tpu_backend
 
-    _, h, f = gate.shape
+    _, h, f = up.shape
     return (_tpu_backend() and jax.sharding.get_abstract_mesh().empty
             and h % 128 == 0 and f % 128 == 0
-            and _touched_vmem_bytes(x.shape[0] + 16, h, f, gate.dtype)
-            <= TOUCHED_MAX_VMEM_BYTES)
+            and _touched_vmem_bytes(x.shape[0] + 16, h, f, up.dtype,
+                                    matrices) <= TOUCHED_MAX_VMEM_BYTES)
 
 
-def routed_experts(x, gate, up, down, probs, experts, valid=None):
-    """Σ_k p_k · down_k(silu(x·gate_k) ⊙ x·up_k) for x [T, H], by the
+def routed_experts(x, gate, up, down, probs, experts, valid=None,
+                   first=None):
+    """Σ_k p_k · expert_k(x) over the held experts for x [T, H], by the
     path that fits T (a static shape) and where it runs."""
     pairs = x.shape[0] * experts.shape[1]
     if x.shape[0] <= STREAMED_MAX_TOKENS:
-        if _touched_kernel_serves(x, gate):
-            return experts_touched(x, gate, up, down, probs, experts, valid)
-        if pairs > GROUPED_MAX_PAIRS_PER_EXPERT * gate.shape[0]:
-            if valid is not None:
-                probs = jnp.where(valid[:, None], probs, 0.0)
-            return experts_streamed(x, gate, up, down, probs, experts)
-    return experts_grouped(x, gate, up, down, probs, experts, valid)
+        if _touched_kernel_serves(x, up, 2 if gate is None else 3):
+            return experts_touched(x, gate, up, down, probs, experts, valid,
+                                   first)
+        # Under a share the pairs that land here are not known at trace
+        # time; what is, is that the held experts are all read anyway.
+        if first is not None \
+                or pairs > GROUPED_MAX_PAIRS_PER_EXPERT * up.shape[0]:
+            return experts_streamed(x, gate, up, down, probs, experts,
+                                    valid, first)
+    return experts_grouped(x, gate, up, down, probs, experts, valid, first)

@@ -37,8 +37,14 @@ Two implementations with one contract:
   Mosaic call too (paged_write_tpu), so nothing of XLA's own touches a
   pool inside the decode program.
 
+Grouped-query attention: the pools hold the KV heads, `H` below, and the
+query may bring `G` heads for each (query head j reads KV head j // G;
+`G = q heads * D / pool width`, 1 for the models whose heads all have
+their own K/V).  The kernel multiplies a KV head's G query rows by its
+block in the same product as everything else.
+
 Contract (per layer):
-    q           [B, 1, H, D]   current step's query
+    q           [B, 1, G*H, D] current step's query
     pool_k/v    [NB, BS, H*D]  shared block pools (`pool_shape`)
     block_table [B, MB] int32  block ids per slot, -1 = unallocated
     lengths     [B] int32      valid tokens INCLUDING the current
@@ -65,7 +71,7 @@ _NEG_INF = -1e30
 
 def pool_shape(num_blocks: int, block_size: int, heads: int,
                head_dim: int):
-    """Shape of one layer's K (or V) block pool."""
+    """Shape of one layer's K (or V) block pool; `heads` are KV heads."""
     return (num_blocks, block_size, heads * head_dim)
 
 
@@ -112,7 +118,7 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
                   pool_k, pool_v, o_ref, k_blocks, v_blocks, sems,
                   q_scratch, m_scratch, l_scratch, acc_scratch, *,
                   block_size: int, table_width: int, scale: float,
-                  head_dim: int):
+                  head_dim: int, group: int):
     """Every row's online-softmax walk over its own blocks, all heads
     at once, on blocks [BS, H*D] as the pool stores them: one program,
     one loop over `paged_walk`'s pairs, so the work is the blocks that
@@ -125,7 +131,10 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
     the query becomes block-diagonal, `q_bd[r, c] = q[c]` where column
     c is one of head r's, so `q_bd . K^T` is every head's scores
     [H_pad, BS]; `p . V` is [H_pad, H*D], of which head r's columns of
-    row r are the answer, taken once at the row's last block.  A row
+    row r are the answer, taken once at the row's last block.  With
+    `group` query heads a KV head, row r is query head r, its columns
+    are KV head r // group's, and the query and the answer are [G*H, D]
+    a row (not flat): the same two products.  A row
     that walks nothing is never touched: its output stays zeros.  The
     gathered [B, MB*BS, H, D] view the XLA fallback materializes every
     step never exists here."""
@@ -133,8 +142,10 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
     h_pad, hd = acc_scratch.shape
 
     def own_columns():
-        # [h_pad, hd] bool: column c belongs to head r.
+        # [h_pad, hd] bool: column c belongs to (the KV head of) head r.
         row = jax.lax.broadcasted_iota(jnp.int32, (h_pad, hd), 0)
+        if group > 1:
+            row = row // group
         col = jax.lax.broadcasted_iota(jnp.int32, (h_pad, hd), 1)
         return (col >= row * head_dim) & (col < (row + 1) * head_dim)
 
@@ -163,8 +174,11 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
         def _init():
             # Select in float32 and cast: Mosaic refuses the relayout of
             # a 16-bit select here.
-            q = jnp.broadcast_to(q_ref[row].astype(jnp.float32),
-                                 (h_pad, hd))
+            q = q_ref[row].astype(jnp.float32)
+            if group == 1:
+                q = jnp.broadcast_to(q, (h_pad, hd))
+            else:  # [h_pad, D], repeated under every KV head's columns
+                q = jnp.concatenate([q] * (hd // head_dim), axis=1)
             q_scratch[...] = jnp.where(own_columns(), q,
                                        0.0).astype(q_scratch.dtype)
             m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
@@ -200,8 +214,13 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
         @pl.when((i + 1 == count) | (after % table_width == 0))
         def _finalize():
             out = acc_scratch[...] / jnp.maximum(l_scratch[...], 1e-30)
-            o_ref[row] = jnp.sum(jnp.where(own_columns(), out, 0.0),
-                                 axis=0, keepdims=True).astype(o_ref.dtype)
+            out = jnp.where(own_columns(), out, 0.0)
+            if group == 1:
+                out = jnp.sum(out, axis=0, keepdims=True)
+            else:
+                out = sum(out[:, g * head_dim:(g + 1) * head_dim]
+                          for g in range(hd // head_dim))
+            o_ref[row] = out.astype(o_ref.dtype)
 
     o_ref[...] = jnp.zeros_like(o_ref)
     for i in range(_BUFFERS - 1):
@@ -221,7 +240,8 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     zeros."""
     b, lq, h, d = q.shape
     nb, bs, hd = pool_k.shape
-    assert lq == 1 and hd == h * d, (q.shape, pool_k.shape)
+    group = h * d // hd
+    assert lq == 1 and hd * group == h * d, (q.shape, pool_k.shape)
     mb = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
     lengths = lengths.astype(jnp.int32)
@@ -234,7 +254,10 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     compute = jnp.promote_types(q.dtype, pool_k.dtype)
     h_pad = -(-h // _sublanes(compute)) * _sublanes(compute)
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    rows = pl.BlockSpec((b, 1, hd), lambda i, *_: (0, 0, 0))
+    # A row's query and answer: all heads flat, or [heads, D] when a KV
+    # head has several (whole sublane tiles of them: `_kernels_serve`).
+    row_shape = (b, 1, hd) if group == 1 else (b, h, d)
+    rows = pl.BlockSpec(row_shape, lambda i, *_: (0, 0, 0))
     blocks = pltpu.VMEM((_BUFFERS, bs, hd), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -250,13 +273,14 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
         ],
     )
     kernel = functools.partial(_paged_kernel, block_size=bs,
-                               table_width=mb, scale=scale, head_dim=d)
+                               table_width=mb, scale=scale, head_dim=d,
+                               group=group)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(row_shape, q.dtype),
         interpret=interpret,
     )(pairs, count, block_table.reshape(-1), lengths,
-      q.reshape(b, 1, hd), pool_k, pool_v)
+      q.reshape(row_shape), pool_k, pool_v)
     return out.reshape(b, 1, h, d)
 
 
@@ -350,11 +374,14 @@ def paged_write_tpu(pool_k, pool_v, k_step, v_step, blocks, offsets,
       v_step.astype(pool_v.dtype).reshape(rows, 1, hd), pool_k, pool_v)
 
 
-def _kernels_serve(block_size: int, heads: int, head_dim: int) -> bool:
+def _kernels_serve(block_size: int, heads: int, head_dim: int,
+                   group: int = 1) -> bool:
     """Whether the Pallas kernels take these shapes, asked at trace
-    time: on a TPU, with block_size and the H*D of one heads shard both
-    lane multiples, so that every block is whole tiles (the XLA
-    formulations serve the rest, and the CPU).
+    time: on a TPU, with block_size and the H*D of one (KV) heads shard
+    both lane multiples, so that every block is whole tiles (the XLA
+    formulations serve the rest, and the CPU); with `group` > 1 query
+    heads a KV head, also a head size of whole lane tiles, whole sublane
+    tiles of query heads and no mesh.
     KFS_DISABLE_PAGED_KERNEL=1 forces the XLA path — the on-chip A/B
     kill-switch, mirroring the flash kernel's KFS_DISABLE_FLASH.
     NOTE: read inside the jitted decode function, so once, at the
@@ -365,6 +392,9 @@ def _kernels_serve(block_size: int, heads: int, head_dim: int) -> bool:
     shards = 1
     if not mesh.empty and attention.mesh_axis(mesh, "tp", heads):
         shards = mesh.shape["tp"]
+    if group > 1 and not (mesh.empty and head_dim % 128 == 0
+                          and (heads * group) % 16 == 0):
+        return False
     return (attention._tpu_backend() and block_size % 128 == 0
             and (heads // shards * head_dim) % 128 == 0
             and os.environ.get("KFS_DISABLE_PAGED_KERNEL", "")
@@ -375,8 +405,10 @@ def paged_attention(q, pool_k, pool_v, block_table, lengths):
     """Dispatcher: the Pallas kernel where `_kernels_serve` says so and
     the query is a single token, XLA gather otherwise (CPU tests, odd
     shapes).  Runs at trace time inside the jitted decode function."""
-    use_kernel = q.shape[1] == 1 and _kernels_serve(pool_k.shape[1],
-                                                    *q.shape[2:])
+    heads, head_dim = q.shape[2:]
+    kv_heads = pool_k.shape[2] // head_dim
+    use_kernel = q.shape[1] == 1 and _kernels_serve(
+        pool_k.shape[1], kv_heads, head_dim, heads // kv_heads)
     attention.log_dispatch(
         "pallas_paged" if use_kernel else "xla_paged",
         q=q.shape, pool=pool_k.shape, table=block_table.shape)
@@ -423,33 +455,38 @@ def paged_write_sharded(pool_k, pool_v, k_step, v_step, blocks, offsets,
             pool_k, pool_v, k_step, v_step, blocks, offsets)
 
 
-def _gathered(pool, table, heads: int):
+def _gathered(pool, table, head_dim: int):
     """A batch's blocks as one contiguous [B, MB*BS, H, D] view.
     -1 (unallocated) clamps to block 0: the callers mask it out, and
     XLA's gather clamps anyway — explicit is better than relying on
     OOB behavior."""
     b, mb = table.shape
     bs = pool.shape[1]
-    return pool[jnp.maximum(table, 0)].reshape(b, mb * bs, heads, -1)
+    return pool[jnp.maximum(table, 0)].reshape(b, mb * bs, -1, head_dim)
 
 
 def _masked_attention(q, k, v, mask):
     """softmax(q.k / sqrt(D)) . v in float32 over [B, K, H, D] keys,
-    `mask` [B, 1|H, Lq, K] true where a query may look."""
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+    `mask` [B, 1, Lq, K] true where a query may look; q [B, Lq, G*H, D],
+    query head j on KV head j // G."""
+    b, lq, heads, d = q.shape
+    kv_heads = k.shape[2]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    q = q.reshape(b, lq, kv_heads, heads // kv_heads, d)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
-    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    logits = jnp.where(mask[:, :, None], logits,
+                       jnp.finfo(jnp.float32).min)
     weights = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", weights,
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", weights,
                      v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    return out.reshape(b, lq, heads, d).astype(q.dtype)
 
 
 def paged_attention_xla(q, pool_k, pool_v, block_table, lengths):
-    h = q.shape[2]
-    k = _gathered(pool_k, block_table, h)
-    v = _gathered(pool_v, block_table, h)
+    d = q.shape[3]
+    k = _gathered(pool_k, block_table, d)
+    v = _gathered(pool_v, block_table, d)
     positions = jnp.arange(k.shape[1])[None, :]
     mask = (positions < lengths[:, None])[:, None, None, :]
     return _masked_attention(q, k, v, mask)
@@ -516,9 +553,9 @@ def paged_prefill_attention_xla(q, pool_k, pool_v, block_table,
                                sentinel (their output is discarded,
                                the mask keeps them finite)
     Returns [B, L, H, D]."""
-    h = q.shape[2]
-    k = _gathered(pool_k, block_table, h)
-    v = _gathered(pool_v, block_table, h)
+    d = q.shape[3]
+    k = _gathered(pool_k, block_table, d)
+    v = _gathered(pool_v, block_table, d)
     key_pos = jnp.arange(k.shape[1])[None, None, :]       # [1, 1, K]
     mask = (key_pos <= q_positions[:, :, None])[:, None]  # [B,1,L,K]
     return _masked_attention(q, k, v, mask)

@@ -16,11 +16,18 @@ config.json schema:
     {
       "architecture": "decoder" | "decoder_tiny"    # GPT-2 block
                     | "olmoe" | "olmoe_tiny"        # RoPE, RMSNorm,
-                    | <registered>,                 #   QK-norm, SwiGLU,
+                                                    #   QK-norm, SwiGLU,
                                                     #   64 routed experts
-                                                    #   (models/olmoe.py);
-                                                    #   same engine, pool
-                                                    #   and decode kernel
+                                                    #   (models/olmoe.py)
+                    | "nemotron_h" | "nemotron_h_tiny"  # Mamba-2 state
+                                                    #   beside the pool,
+                                                    #   GQA, a share of
+                                                    #   relu² experts
+                                                    #   (models/
+                                                    #   nemotron_h.py)
+                    | <registered>,                 # all: same engine,
+                                                    #   pool and decode
+                                                    #   kernel
       "arch_kwargs": {...},
       "max_slots": 8,              # continuous-batching slot count
       "max_seq": 512,              # KV-cache capacity per slot
@@ -46,6 +53,9 @@ config.json schema:
                                    #   derived, serve correctly on
                                    #   the slower XLA gather path
                                    #   (logged once at load)
+      "prefill_rows": 8,           # the most rows one prefill
+                                   #   dispatch carries (default:
+                                   #   every free slot)
       "prefill_chunk_tokens": 512, # chunked prefill:
                                    #   a COLD prompt longer than this
                                    #   lands in block-aligned chunks
@@ -405,6 +415,7 @@ class GenerativeConfig:
                  host_tier_dir: Optional[str] = None,
                  adaptive_depth: bool = True,
                  speculative: Optional[Dict[str, Any]] = None,
+                 prefill_rows: Optional[int] = None,
                  mesh: Optional[Dict[str, int]] = None,
                  **_ignored):
         self.architecture = architecture
@@ -448,6 +459,10 @@ class GenerativeConfig:
         # None/absent defers to the engine's KFS_SPECDEC_TOKENS env
         # twin (n-gram proposer only); see the module docstring.
         self.speculative = dict(speculative) if speculative else None
+        # The most rows one prefill dispatch may carry (None: every
+        # free slot): what fits beside the parameters is the
+        # deployment's to know, not the runtime's to refuse.
+        self.prefill_rows = int(prefill_rows) if prefill_rows else None
         self.mesh = mesh or {}
 
     @classmethod
@@ -573,6 +588,7 @@ class GenerativeModel(Model):
             host_tier_dir=cfg.host_tier_dir,
             adaptive_depth=cfg.adaptive_depth,
             speculative=speculative,
+            prefill_rows=cfg.prefill_rows,
             mesh=mesh, name=self.name)
         if engine.block_size % 128 != 0:
             _warn_paged_kernel_ineligible(

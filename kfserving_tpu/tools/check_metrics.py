@@ -203,6 +203,12 @@ async def smoke() -> List[str]:
         model="metrics-probe").inc(128)
     obs.generator_moe_expert_load_max_total().labels(
         model="metrics-probe").inc(900)
+    obs.generator_moe_routed_pairs_elsewhere_total().labels(
+        model="metrics-probe").inc(1500)
+    obs.generator_recurrent_state_bytes().labels(
+        model="metrics-probe").set(0.96e9)
+    obs.generator_prefix_reuse_refused_total().labels(
+        model="metrics-probe").inc()
     obs.hbm_resident_bytes().labels(model="metrics-probe").set(2.1e9)
     obs.hbm_budget_bytes().set(12.0 * 1024**3)
     obs.hbm_evictions_total().labels(model="metrics-probe").inc()

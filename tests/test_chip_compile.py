@@ -279,6 +279,54 @@ def test_touched_experts_kernel_compiles_for_described_v5e(v5e, tokens):
     assert "ragged-dot" not in compiled.as_text()
 
 
+def test_held_plain_experts_kernel_compiles_for_described_v5e(v5e):
+    """`nemotron-3-nano-16l-ep2`'s expert layer at a decode wave's 64 rows:
+    two matrices an expert of width 1856 stored as 1920 (15 lane tiles),
+    64 of the router's 128 experts held, through the same Pallas kernel;
+    the matrices are read where they are, in no other layout."""
+    from jax.sharding import SingleDeviceSharding
+
+    from kfserving_tpu.models.nemotron_h import NemotronHConfig
+    from kfserving_tpu.ops import moe
+
+    cfg = NemotronHConfig(**_nemotron_serving()["arch_kwargs"])
+    e, h, f = cfg.num_experts, cfg.hidden_size, cfg.expert_width_stored
+    assert (e, h, f, cfg.intermediate_size) == (64, 2688, 1920, 1856)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(
+        lambda x, up, down, w, chosen: moe.experts_touched(
+            x, None, up, down, w, chosen, None, cfg.experts_first)).lower(
+        arg((64, h), jnp.bfloat16), arg((e, h, f), jnp.bfloat16),
+        arg((e, f, h), jnp.bfloat16), arg((64, 6), jnp.float32),
+        arg((64, 6), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_grouped_query_paged_kernel_compiles_for_described_v5e(v5e):
+    """The paged decode kernel at 2 x 128 K/V lanes with 16 query heads
+    on each KV head, 64 rows over the configuration's pool: one Mosaic
+    call, the pools not copied."""
+    from kfserving_tpu.ops import paged_attention
+
+    serving = _nemotron_serving()
+    kw = serving["arch_kwargs"]
+    args = _paged_args(serving["max_slots"], kw["num_kv_heads"],
+                       kw["head_dim"], serving["cache_blocks"],
+                       serving["block_size"],
+                       serving["max_seq"] // serving["block_size"])
+    args[0] = ((serving["max_slots"], 1, kw["num_heads"], kw["head_dim"]),
+               jnp.bfloat16, P())
+    compiled = _compile(paged_attention.paged_attention_sharded, args, v5e,
+                        sharded=False)
+    assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 1
+    assert _pool_copies(compiled, args[1][0]) == []
+
+
 def _decode_program(v5e, monkeypatch, serving: dict):
     """(the 16-step decode program of a benchmarked configuration's
     serving settings with the Pallas paged kernel, as the chip's compiler
@@ -323,6 +371,42 @@ def _decode_program(v5e, monkeypatch, serving: dict):
         engine.shutdown_nowait()
 
 
+def _prefill_program(v5e, monkeypatch, serving: dict, rows: int):
+    """The (rows, largest bucket) prefill program of a configuration's
+    serving settings, as the chip's compiler sees it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from kfserving_tpu.engine.generator import GenerationEngine
+    from kfserving_tpu.models import create_model
+    from kfserving_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    spec = create_model(serving["architecture"], **serving["arch_kwargs"])
+    one = SingleDeviceSharding(v5e.devices[0])
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(
+            lambda: spec.module.init(jax.random.PRNGKey(0), spec.example)))
+    engine = GenerationEngine(
+        spec.module, shapes, max_slots=serving["max_slots"],
+        max_seq=serving["max_seq"],
+        prefill_buckets=serving["prefill_buckets"],
+        block_size=serving["block_size"],
+        cache_blocks=serving["cache_blocks"],
+        steps_per_call=serving["steps_per_call"])
+    try:
+        def arg(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        i32, f32 = jnp.int32, jnp.float32
+        return engine._prefill.lower(
+            shapes, arg(i32, rows, max(serving["prefill_buckets"])),
+            arg(i32, rows), arg(f32, rows), arg(i32, rows), arg(f32, rows),
+            arg(i32, rows)).compile()
+    finally:
+        engine.shutdown_nowait()
+
+
 def _program_bytes(memory) -> int:
     return (memory.argument_size_in_bytes + memory.output_size_in_bytes
             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
@@ -342,6 +426,55 @@ def test_olmoe_decode_program_fits_the_described_v5e(v5e, monkeypatch):
     assert 9.5e9 < memory.argument_size_in_bytes < 9.6e9  # 7.13 + 2.42
     assert memory.temp_size_in_bytes < 0.03e9, memory
     assert _program_bytes(memory) < 15.75 * 2**30, memory
+
+
+def _nemotron_serving() -> dict:
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "nemotron-3-nano-16l-ep2.json")) as f:
+        return json.load(f)["serving"]
+
+
+def test_nemotron_decode_program_fits_the_described_v5e(v5e, monkeypatch):
+    """The 16-step decode program of `nemotron-3-nano-16l-ep2` (64 slots;
+    7 Mamba, 7 expert and 2 attention layers; experts 0-63 of 128): its
+    arguments are the parameters, the per-slot state and the K/V pool;
+    the two attention layers read the pool through the Pallas kernel
+    (16 query heads on each of 2 KV heads), the seven expert layers read
+    their touched experts through `moe_experts_touched`, and no expert
+    matrix is copied into another layout on the way."""
+    compiled, pool, shapes = _decode_program(v5e, monkeypatch,
+                                             _nemotron_serving())
+    assert pool == (768, 128, 256)
+    assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 2
+    assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 2
+    assert compiled.as_text().count("moe_experts_touched") >= 7
+    assert "ragged-dot" not in compiled.as_text()
+    assert [line for line in compiled.as_text().splitlines()
+            if " copy(" in line and "bf16[64,2688,1920]" in line] == []
+    memory = compiled.memory_analysis()
+    print(f"nemotron-3-nano-16l-ep2 decode program: {memory}")
+    stored = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    assert 11.5e9 < stored < 11.7e9                 # 5.79 B, bfloat16
+    # ... + 0.96 GB of state + 0.20 GB of pool
+    assert 12.7e9 < memory.argument_size_in_bytes < 12.9e9
+    assert memory.temp_size_in_bytes < 0.3e9, memory
+    assert _program_bytes(memory) < 15.75 * 2**30, memory
+
+
+def test_nemotron_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
+    """Its (8, 1024) prefill, the most one dispatch carries
+    (`prefill_rows` 8): parameters, temporaries (the 10304-wide Mamba
+    projection, the chunked scan's float32 blocks, 49152 routed rows) and
+    outputs fit beside the 1.16 GB of state and pool that the program
+    does not see."""
+    serving = _nemotron_serving()
+    compiled = _prefill_program(v5e, monkeypatch, serving,
+                                serving["prefill_rows"])
+    assert compiled.as_text().count("ragged-dot") >= 14  # 7 layers x 2
+    memory = compiled.memory_analysis()
+    print(f"nemotron-3-nano-16l-ep2 (8, 1024) prefill program: {memory}")
+    assert memory.temp_size_in_bytes < 2.6e9, memory
+    assert _program_bytes(memory) + 1.16e9 < 15.75 * 2**30, memory
 
 
 @pytest.mark.parametrize("cache_blocks", [144, 192])

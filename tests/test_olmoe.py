@@ -122,58 +122,159 @@ def test_router_chooses_the_references_experts(tiny):
             == [set(r) for r in want.tolist()]
 
 
+# An expert's form and the experts held are arguments of every path:
+# OLMoE's (gated, all held), Nemotron-H's (plain relu², a share of the
+# router's experts starting at `first`), and the two crossed.
+FORMS = [("gated", None), ("plain", None), ("plain", 2), ("gated", 5)]
+
+
+def layer_of(rng, form, first, e, h, f, k, tokens, router="softmax"):
+    """(x, gate or None, up, down, weights, experts) of one expert layer
+    whose router runs over `e` experts; with a share, the 4 experts from
+    `first` are held."""
+    held = e if first is None else 4
+    x = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
+    gate, up = (jnp.asarray(rng.standard_normal((held, h, f)) / h ** 0.5,
+                            jnp.float32) for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((held, f, h)) / f ** 0.5,
+                       jnp.float32)
+    logits = jnp.asarray(rng.standard_normal((tokens, e)), jnp.float32)
+    if router == "softmax":
+        weights, experts = moe.route(logits, k)
+    else:
+        weights, experts = moe.route_sigmoid(
+            logits, jnp.asarray(rng.standard_normal(e) * 0.5, jnp.float32),
+            k, 2.5)
+    return x, (gate if form == "gated" else None), up, down, weights, experts
+
+
+def plain_sum(x, gate, up, down, weights, experts, valid, first):
+    """The layer as a loop over tokens and their chosen experts."""
+    x, up, down, weights = (np.asarray(t, np.float64)
+                            for t in (x, up, down, weights))
+    out = np.zeros_like(x)
+    for t, e in np.ndindex(experts.shape):
+        at = int(experts[t, e]) - (first or 0)
+        if (valid is not None and not valid[t]) or not 0 <= at < len(up):
+            continue
+        u = x[t] @ up[at]
+        if gate is None:
+            act = np.maximum(u, 0.0) ** 2
+        else:
+            g = x[t] @ np.asarray(gate, np.float64)[at]
+            act = g / (1 + np.exp(-g)) * u
+        out[t] += weights[t, e] * (act @ down[at])
+    return out
+
+
+@pytest.mark.parametrize("form,first", FORMS)
 @pytest.mark.parametrize("tokens", [24, 300])
-def test_grouped_and_streamed_expert_paths_agree(tokens):
+def test_grouped_and_streamed_expert_paths_agree(tokens, form, first):
     rng = np.random.default_rng(tokens)
     e, h, f, k = 8, 32, 48, 3
-    x = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
-    gate, up = (jnp.asarray(rng.standard_normal((e, h, f)) / h ** 0.5,
-                            jnp.float32) for _ in range(2))
-    down = jnp.asarray(rng.standard_normal((e, f, h)) / f ** 0.5,
-                       jnp.float32)
-    probs, experts = moe.route(
-        jnp.asarray(rng.standard_normal((tokens, e)), jnp.float32), k)
+    x, gate, up, down, probs, experts = layer_of(rng, form, first, e, h, f,
+                                                 k, tokens)
     valid = jnp.asarray(rng.random(tokens) < 0.8)
-    streamed = moe.experts_streamed(
-        x, gate, up, down, jnp.where(valid[:, None], probs, 0.0), experts)
-    grouped = moe.experts_grouped(x, gate, up, down, probs, experts, valid)
+    streamed = moe.experts_streamed(x, gate, up, down, probs, experts,
+                                    valid, first)
+    grouped = moe.experts_grouped(x, gate, up, down, probs, experts, valid,
+                                  first)
+    want = plain_sum(x, gate, up, down, probs, np.asarray(experts),
+                     np.asarray(valid), first)
+    np.testing.assert_allclose(np.asarray(streamed), want, atol=1e-5, rtol=0)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(streamed),
                                atol=1e-5, rtol=0)
     assert not np.asarray(grouped)[~np.asarray(valid)].any()
     # the dispatcher takes the path that fits the token count
-    chosen = moe.routed_experts(x, gate, up, down, probs, experts, valid)
+    chosen = moe.routed_experts(x, gate, up, down, probs, experts, valid,
+                                first)
     np.testing.assert_allclose(np.asarray(chosen), np.asarray(streamed),
                                atol=1e-5, rtol=0)
+    pairs = np.asarray(moe.routed_pairs(experts, up.shape[0], valid, first))
+    local = np.asarray(experts)[np.asarray(valid)] - (first or 0)
+    assert pairs.tolist() == [int((local == i).sum())
+                              for i in range(up.shape[0])]
 
 
+@pytest.mark.parametrize("form,first", FORMS)
 @pytest.mark.parametrize("tokens,valid_share", [(24, None), (5, None),
                                                 (40, 0.6)])
 def test_touched_experts_kernel_agrees_with_the_grouped_path(tokens,
-                                                             valid_share):
+                                                             valid_share,
+                                                             form, first):
     """The Pallas kernel that serves a decode wave on the chip, run by the
     interpreter here: lane-aligned widths, a third of the experts chosen
     by no token (their blocks are never addressed), with and without
-    padding tokens.  float32 on both sides, so 1e-5 as above."""
+    padding tokens; three matrices an expert or two, all experts held or
+    four of the twelve.  float32 on both sides, so 1e-5 as above."""
     rng = np.random.default_rng(tokens)
     e, h, f, k = 12, 256, 128, 3
-    x = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
-    gate, up = (jnp.asarray(rng.standard_normal((e, h, f)) / h ** 0.5,
-                            jnp.float32) for _ in range(2))
-    down = jnp.asarray(rng.standard_normal((e, f, h)) / f ** 0.5,
-                       jnp.float32)
+    x, gate, up, down, _, _ = layer_of(rng, form, first, e, h, f, k, tokens)
     logits = rng.standard_normal((tokens, e))
     logits[:, ::3] -= 100.0
     probs, experts = moe.route(jnp.asarray(logits, jnp.float32), k)
-    assert len(set(np.asarray(experts).reshape(-1).tolist())) == 8
+    assert len(set(np.asarray(experts).reshape(-1).tolist())) <= 8
     valid = (None if valid_share is None
              else jnp.asarray(rng.random(tokens) < valid_share))
-    grouped = moe.experts_grouped(x, gate, up, down, probs, experts, valid)
+    grouped = moe.experts_grouped(x, gate, up, down, probs, experts, valid,
+                                  first)
     touched = moe.experts_touched(x, gate, up, down, probs, experts, valid,
-                                  interpret=True)
+                                  first, interpret=True)
     np.testing.assert_allclose(np.asarray(touched), np.asarray(grouped),
                                atol=1e-5, rtol=0)
     if valid is not None:
         assert not np.asarray(touched)[~np.asarray(valid)].any()
+
+
+def test_the_sigmoid_router_chooses_by_biased_score_and_weighs_by_score():
+    """Nemotron-H's router: the k largest of sigmoid(logit) + bias are
+    chosen; the weights are scale x their sigmoids over the sum of the
+    chosen sigmoids, the bias left out."""
+    rng = np.random.default_rng(5)
+    logits = rng.standard_normal((40, 16)).astype(np.float32)
+    bias = (rng.standard_normal(16) * 0.7).astype(np.float32)
+    weights, experts = moe.route_sigmoid(jnp.asarray(logits),
+                                         jnp.asarray(bias), 4, 2.5)
+    scores = 1 / (1 + np.exp(-logits.astype(np.float64)))
+    want = np.argsort(-(scores + bias), axis=-1)[:, :4]
+    np.testing.assert_array_equal(np.sort(np.asarray(experts), -1),
+                                  np.sort(want, -1))
+    picked = np.take_along_axis(scores, np.asarray(experts), axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(weights), 2.5 * picked / picked.sum(-1, keepdims=True),
+        atol=1e-6, rtol=0)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 2.5, atol=1e-5)
+    # the bias moved choices, and no weight holds it
+    unbiased = np.argsort(-scores, axis=-1)[:, :4]
+    assert (np.sort(unbiased, -1) != np.sort(want, -1)).any()
+    assert np.asarray(experts).dtype == np.int32
+
+
+@pytest.mark.parametrize("path", ["streamed", "grouped", "touched"])
+def test_the_shares_of_a_layer_add_up_to_the_layer(path):
+    """Three chips that hold experts 0-3, 4-7 and 8-11 of a 12-expert
+    layer compute parts that sum to what one holder of all 12 computes,
+    by every path (plain experts, the sigmoid router)."""
+    rng = np.random.default_rng(11)
+    e, h, f, k, tokens = 12, 256, 128, 3, 20
+    x, _, up, down, weights, experts = layer_of(
+        rng, "plain", None, e, h, f, k, tokens, router="sigmoid")
+    valid = jnp.asarray(rng.random(tokens) < 0.8)
+
+    def run(up, down, first):
+        if path == "touched":
+            return moe.experts_touched(x, None, up, down, weights, experts,
+                                       valid, first, interpret=True)
+        return getattr(moe, "experts_" + path)(x, None, up, down, weights,
+                                               experts, valid, first)
+
+    whole = np.asarray(run(up, down, None))
+    parts = sum(np.asarray(run(up[i:i + 4], down[i:i + 4], i))
+                for i in (0, 4, 8))
+    np.testing.assert_allclose(parts, whole, atol=1e-5, rtol=0)
+    np.testing.assert_allclose(
+        whole, plain_sum(x, None, up, down, weights, np.asarray(experts),
+                         np.asarray(valid), None), atol=1e-4, rtol=0)
 
 
 def test_the_kernel_serves_few_tokens_on_a_tpu_outside_a_mesh(monkeypatch):

@@ -623,20 +623,24 @@ def _attention_over_blocks(q, k4, v4, table, allowed):
 
 
 @pytest.mark.parametrize("case", list(KERNEL_CASES))
-@pytest.mark.parametrize("heads", [(20, 64), (16, 128), (4, 64)],
-                         ids=["20x64", "16x128", "4x64"])
+@pytest.mark.parametrize("heads", [(20, 64, 1), (16, 128, 1), (4, 64, 1),
+                                   (2, 128, 4), (2, 128, 16)],
+                         ids=["20x64", "16x128", "4x64", "2x128-4q",
+                              "2x128-16q"])
 def test_pallas_paged_kernel_matches_xla(heads, case):
     """The Pallas paged-decode kernel (interpret mode on CPU) on the
     flat pool matches the XLA gather reference, and both the plain
     attention over the same bytes as [NB, BS, H, D]: for heads that
     padded a tile (20 x 64), fill it (16 x 128) and are a fraction of
-    one (4 x 64); lengths inside a block, on its edge, of one token
+    one (4 x 64), and for 2 KV heads of 128 that 4 or 16 query heads
+    each read (grouped-query attention: query head j on KV head
+    j // group); lengths inside a block, on its edge, of one token
     and of the whole table; a shared block and unallocated (-1) table
     tails.  A row that walks nothing (free, parked) comes back as
     zeros, whatever is beside it."""
     from kfserving_tpu.ops import paged_attention as pa
 
-    h, d = heads
+    h, d, group = heads
     lengths, table, idle = KERNEL_CASES[case]
     bs = KERNEL_BS
     rng = np.random.default_rng(h * 1000 + lengths[0])
@@ -646,13 +650,15 @@ def test_pallas_paged_kernel_matches_xla(heads, case):
             table[row, -(-n // bs):] = -1
     table = np.asarray(table, np.int32)
     mb, nb = table.shape[1], max(KERNEL_NB, int(table.max()) + 1)
-    q = rng.normal(size=(len(lengths), 1, h, d)).astype(np.float32)
+    q = rng.normal(size=(len(lengths), 1, h * group, d)).astype(np.float32)
     k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
     lens = jnp.asarray(lengths, jnp.int32)
     live = np.setdiff1d(np.arange(len(lengths)), idle)
     allowed = (np.arange(mb * bs)[None, None, :]
                < np.asarray(lengths)[:, None, None])
-    want = _attention_over_blocks(q, k4, v4, table, allowed)
+    want = _attention_over_blocks(q, np.repeat(k4, group, axis=2),
+                                  np.repeat(v4, group, axis=2), table,
+                                  allowed)
     xla = pa.paged_attention_xla(jnp.asarray(q), pool_k, pool_v,
                                  jnp.asarray(table), lens)
     got = np.asarray(pa.paged_attention_tpu(
@@ -745,16 +751,19 @@ def _written(pool4, values, table, positions):
     return want
 
 
+@pytest.mark.parametrize("group", [1, 4], ids=["1q", "4q"])
 @pytest.mark.parametrize("op", ["write-decode", "write-chunk", "insert",
                                 "prefill-attention"])
-def test_flat_pool_ops_match_the_4d_pool(op):
+def test_flat_pool_ops_match_the_4d_pool(op, group):
     """`paged_write` (both call shapes), `paged_insert` and
     `paged_prefill_attention_xla` take [.., H, D] activations and the
     flat pool; reshaped to [NB, BS, H, D] their results are what the
-    same operations give on such an array."""
+    same operations give on such an array.  The pool holds the KV
+    heads (2 with `group` 4: what is written is theirs), and the query
+    brings `group` heads for each."""
     from kfserving_tpu.ops import paged_attention as pa
 
-    h, d, bs, nb, mb, b = 5, 8, 4, 7, 3, 3
+    h, d, bs, nb, mb, b = (5 if group == 1 else 2), 8, 4, 7, 3, 3
     rng = np.random.default_rng(7)
     k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
     table = np.asarray([[0, 1, -1], [2, 3, 4], [5, -1, -1]], np.int32)
@@ -794,7 +803,7 @@ def test_flat_pool_ops_match_the_4d_pool(op):
         np.testing.assert_array_equal(unflat(got_k), want_k)
         np.testing.assert_array_equal(unflat(got_v), want_v)
     else:
-        q = rng.normal(size=(b, 3, h, d)).astype(np.float32)
+        q = rng.normal(size=(b, 3, h * group, d)).astype(np.float32)
         q_positions = np.asarray([[3, 4, 5], [9, 10, 11], [0, 1, 2]],
                                  np.int32)
         allowed = (np.arange(mb * bs)[None, None, :]
@@ -804,7 +813,9 @@ def test_flat_pool_ops_match_the_4d_pool(op):
             jnp.asarray(q_positions))
         np.testing.assert_allclose(
             np.asarray(got),
-            _attention_over_blocks(q, k4, v4, table, allowed),
+            _attention_over_blocks(q, np.repeat(k4, group, axis=2),
+                                   np.repeat(v4, group, axis=2), table,
+                                   allowed),
             rtol=2e-5, atol=2e-5)
 
 
