@@ -617,7 +617,8 @@ class GenerationEngine:
                 decode_call_stats,
             )
 
-            self._moe = MoeCounters(name, cfg.num_experts)
+            self._moe = MoeCounters(name, cfg.num_experts,
+                                    cfg.experts_per_token)
         routed = self._moe is not None
         cache_kinds = self._cache_layers
 
@@ -3462,7 +3463,8 @@ class GenerationEngine:
                                 *sampling)
             firsts, new_caches, chosen_lp, top_ids, top_lps = out[:5]
             if self._moe is not None:
-                self._moe.note("prefill", out[5])
+                self._moe.note("prefill", out[5],
+                               tokens=b_bucket * bucket)
         with TIMELINE.span(LAUNCH, "engine.prep.insert"):
             slot_d = jnp.asarray(slot_arr)
             # Per-chunk destination blocks (-1 = shared prefix hit or

@@ -422,6 +422,24 @@ def generator_moe_routed_pairs_elsewhere_total():
         "the routed work that is done here")
 
 
+def generator_moe_grouped_pair_rows_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_grouped_pair_rows_total",
+        "(token, expert) rows that prefill dispatches offered the grouped "
+        "expert path: the padded bucket's tokens x choices a token, per "
+        "expert layer, real pairs or not")
+
+
+def generator_moe_grouped_pair_rows_computed_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_moe_grouped_pair_rows_computed_total",
+        "Of those rows, the real ones in whole tiles: each expert layer's "
+        "real pairs (a valid token's, of an expert held here) rounded up "
+        "to the grouped kernel's row tile.  The least the path's matmuls "
+        "can visit, counted on the host whichever way a dispatch went: "
+        "what the traffic offered, not what a kernel did")
+
+
 def generator_recurrent_state_bytes():
     return REGISTRY.gauge(
         "kfserving_tpu_generator_recurrent_state_bytes",

@@ -16,15 +16,25 @@ arguments of every path, not copies of it:
   by no path here (it is another chip's), like a pair of a token that is
   not `valid`.
 
-- `experts_grouped`: routed work only.  The pairs are sorted by expert,
-  the tokens gathered in that order, and three `jax.lax.ragged_dot`s
-  multiply each group of rows by its own expert's matrix.  XLA lowers
-  `ragged_dot` on TPU to its own grouped-matmul kernel (a
-  `tpu_custom_call` over row tiles and the groups in them) and on the
-  CPU to a masked dense form, which the tests use at toy size.  An
-  expert no row chose is not read; tokens marked not `valid` (bucket
-  padding) are given to no expert: they sort past the last group and
-  their rows come back zero.
+- `experts_grouped`: routed work only, and of the routed pairs only the
+  real ones: those of a `valid` token and a held expert.  The pairs are
+  sorted by expert, the rest past the last group, the tokens gathered in
+  that order, and a Pallas TPU kernel walks the (row tile, expert) pairs
+  in which a tile of 256 sorted rows holds a row of the expert: the
+  expert's matrices whole in VMEM and the next one's on their way (the
+  walk of `experts_touched`, over rows), the tile through them, and the
+  walk ends at the last real row, so padding and other chips' pairs cost
+  a sort and a gathered row and no arithmetic.  Each token then sums its
+  own k rows, times the router's weights, in float32.  Where the kernel
+  does not serve (off the TPU, under a mesh, and at any shape it has no
+  record at on the chip: `_grouped_kernel_serves`), two or three
+  `jax.lax.ragged_dot`s take its place (on a v5e XLA's kernel takes 0.2
+  ms for every group that has a row, however few: PERF.md, PR 32; on the
+  CPU a masked dense form, which the tests use at toy size).  An expert
+  no row chose is not read.  That list of shapes is a workaround for a
+  hang of the chip that is not understood (PERF.md §7 N6), not a design:
+  it goes, and the kernel serves every dispatch on a TPU, when the hang
+  is.
 - `experts_touched`: a Pallas TPU kernel for few tokens (a decode wave,
   a speculative verify).  It walks the list of experts some token chose
   and streams each one's three matrices through VMEM once, whole, the
@@ -69,9 +79,28 @@ STREAMED_MAX_TOKENS = 256
 # untouched (3 a piece: 5% of them if it is even), and the grouped kernel
 # reads no more than the streamed path does.
 GROUPED_MAX_PAIRS_PER_EXPERT = 3
-# What `experts_touched` may ask of VMEM (a v5e core has 128 MiB): OLMoE's
+# The grouped path's kernel multiplies tiles of this many sorted rows by
+# the one expert they belong to: 256 rows of arithmetic last as long as
+# the stream of the next expert's matrices (2 x 10.3 MB and 27 us for
+# Nemotron's, 3 x 4.2 MB and 16 us for OLMoE's, on a v5e).
+GROUPED_TILE_ROWS = 256
+# What a kernel here may ask of a core's 128 MiB of VMEM (v5e): OLMoE's
 # 3 x 4 MiB an expert, twice over, need 33 MiB.
-TOUCHED_MAX_VMEM_BYTES = 96 << 20
+KERNEL_MAX_VMEM_BYTES = 96 << 20
+# The shapes at which the grouped kernel serves, in bfloat16: (sorted rows
+# T x k, H, F, matrices an expert).  With the kernel at every row count
+# the Nemotron-H prefill stopped the chip (4 of 20 benchmark runs, 1 in 27
+# and 1 in 49 ramps of the server from idle; the oldest unfinished program
+# a (2, 1024) prefill both times it was looked at, where XLA keeps the
+# kernel's sorted rows in VMEM above the kernel's own 66 MB).  The cause
+# is not known (PERF.md, PR 32, N6), and a hang takes the chip and raises
+# nothing, so a shape is listed once it has run 100 such ramps on a v5e
+# without a stall, and the kernel serves no other.  OLMoE's 4 rows of
+# 1024, (4 * 1024 * 8, 2048, 1024, 3), has run 49 and waits for the rest.
+GROUPED_KERNEL_PROVEN = frozenset({
+    (4 * 1024 * 6, 2688, 1920, 2),  # Nemotron-H, 4 and 8 rows of 1024
+    (8 * 1024 * 6, 2688, 1920, 2),
+})
 
 
 def route(logits: jax.Array, k: int):
@@ -149,35 +178,176 @@ def experts_streamed(x, gate, up, down, probs, experts, valid=None,
         return jnp.einsum("etf,efh->th", act, down)
 
 
+def grouped_rows_offered(tokens: int, per_token: int) -> int:
+    """Host side, for the counters: the (token, expert) rows a dispatch of
+    `tokens` tokens (padding and all) offers the grouped path in every
+    expert layer; 0 for so few tokens that `routed_experts` may take
+    another path."""
+    return tokens * per_token if tokens > STREAMED_MAX_TOKENS else 0
+
+
+def grouped_rows_real(real):
+    """Host side: `real` pairs (an int, or an array of them) in whole
+    tiles of the kernel's: the sorted rows up to the last real one, which
+    is the least a grouped matmul can visit and where either way here
+    stops (XLA's grouped matmul ends at the last group too: PERF.md, PR
+    32).  A count of what the traffic offered, not of what a kernel did:
+    the kernel's walk visits a tile once for every expert with a row in
+    it, up to tiles + experts - 1 visits."""
+    return -(-real // GROUPED_TILE_ROWS) * GROUPED_TILE_ROWS
+
+
 def experts_grouped(x, gate, up, down, probs, experts, valid=None,
-                    first=None):
-    """The same sum over routed pairs only: rows sorted by expert, one
-    grouped matmul per matrix.  valid: optional [T] bool; a token that
-    is not valid is routed to no expert and gets a zero row.  Pairs of
-    experts not held sort past the last group beside theirs."""
+                    first=None, interpret: bool = False):
+    """The same sum over the real routed pairs only, n of the T x k: those
+    of a valid token (valid: optional [T] bool) and a held expert.  The
+    pairs are sorted by expert, the rest past the last group, and the
+    experts' matmuls stop at row n, a value on the device
+    (`_grouped_tiles`, the kernel, where `_grouped_kernel_serves` or
+    `interpret` asks for its interpreter; else `_grouped_ragged`).  Each
+    token sums its k rows, times the router's weights, in float32,
+    rounded once; a token with no real pair gets a zero row.  With no
+    `valid` and every expert held n = T x k."""
     t, k = experts.shape
     e = up.shape[0]
+    matrices = [up, down] if gate is None else [gate, up, down]
+    tiles = interpret or _grouped_kernel_serves(x, k, up, len(matrices))
     with jax.named_scope("moe.dispatch"):
         experts = _held(experts, e, first, valid)
         sizes = routed_pairs(experts, e)
         order = jnp.argsort(experts.reshape(-1), stable=True)
-        rows = x[order // k]
+        # Whole tiles for the kernel; the rows added are past the last group.
+        pad = -(t * k) % GROUPED_TILE_ROWS if tiles else 0
+        rows = x[jnp.pad(order // k, (0, pad))]
     with jax.named_scope("moe.experts"):
-        g = None if gate is None else jax.lax.ragged_dot(rows, gate, sizes)
-        u = jax.lax.ragged_dot(rows, up, sizes)
-        out = jax.lax.ragged_dot(_activation(g, u).astype(x.dtype), down,
-                                 sizes)
+        if tiles:
+            out = _grouped_tiles(rows, matrices, sizes, interpret)
+        else:
+            out = _grouped_ragged(rows, matrices, sizes)
     with jax.named_scope("moe.combine"):
-        # Rows past the last group belong to no expert; whatever the
-        # kernel left there is replaced, not scaled.
-        routed = jnp.arange(t * k) < jnp.sum(sizes)
-        out = jnp.where(routed[:, None],
-                        out.astype(jnp.float32)
-                        * probs.reshape(-1)[order][:, None],
-                        0.0).astype(x.dtype)
-        back = jnp.argsort(order)
-        return out[back].reshape(t, k, -1).sum(
-            axis=1, dtype=jnp.float32).astype(x.dtype)
+        # Where each token's k pairs lie in the sorted order; choice by
+        # choice, a gather of [T, H] added to the sum.  A pair that is
+        # not real lies past the last group; whatever a kernel left there
+        # is replaced, not scaled.
+        at = jnp.argsort(order).reshape(t, k)
+        weights = jnp.where(experts < e, probs, 0.0)
+        total = jnp.zeros(x.shape, jnp.float32)
+        for j in range(k):
+            w = weights[:, j, None]
+            total += jnp.where(w != 0.0,
+                               out[at[:, j]].astype(jnp.float32) * w, 0.0)
+        return total.astype(x.dtype)
+
+
+def _grouped_ragged(rows, matrices, sizes):
+    """[R, H]: rows [R, H] sorted by expert, the first `sizes[0]` of them
+    expert 0's and so on, each through its own expert, by XLA's grouped
+    matmul.  Rows past the last group come back as its kernel leaves
+    them."""
+    gate, up, down = ([None] + matrices)[-3:]
+    g = None if gate is None else jax.lax.ragged_dot(rows, gate, sizes)
+    u = jax.lax.ragged_dot(rows, up, sizes)
+    return jax.lax.ragged_dot(_activation(g, u).astype(rows.dtype), down,
+                              sizes)
+
+
+def _grouped_kernel(tile_ref, group_ref, lo_ref, hi_ref, count_ref, rows_ref,
+                    *refs, gated: bool):
+    """One grid step a (row tile, expert) pair of the walk: the expert's
+    matrices are in VMEM (the next pair's on their way, unless it is the
+    same expert), the tile's rows go through it, and those that are not
+    this expert's add nothing to the tile's float32 sum, which is
+    rounded and written when the walk leaves the tile."""
+    gate_ref = refs[0] if gated else None
+    up_ref, down_ref, o_ref, acc_ref = refs[-4:]
+    i = pl.program_id(0)
+    rows_per_tile = rows_ref.shape[0]
+
+    @pl.when(i < count_ref[0])
+    def _pair():
+        tile, group = tile_ref[i], group_ref[i]
+
+        @pl.when((i == 0) | (tile != tile_ref[jnp.maximum(i - 1, 0)]))
+        def _enter():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = rows_ref[...]
+        g = None if gate_ref is None else jnp.dot(
+            x, gate_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+        row = tile * rows_per_tile + jax.lax.broadcasted_iota(
+            jnp.int32, (rows_per_tile, 1), 0)
+        mine = (row >= lo_ref[group]) & (row < hi_ref[group])
+        act = jnp.where(mine, _activation(g, u), 0.0).astype(x.dtype)
+        acc_ref[...] += jnp.dot(act, down_ref[0],
+                                preferred_element_type=jnp.float32)
+
+        # Entries past the walk's end repeat its last pair.
+        @pl.when((i == count_ref[0] - 1) | (tile != tile_ref[i + 1]))
+        def _leave():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    @pl.when((i == 0) & (count_ref[0] == 0))
+    def _no_pair_at_all():  # the first tile is written back all the same
+        o_ref[...] = rows_ref[...]
+
+
+def _grouped_tiles(rows, matrices, sizes, interpret: bool = False):
+    """[R, H]: rows [R, H] sorted by expert (R whole tiles), the first
+    `sizes[0]` of them expert 0's and so on, each through its own
+    expert, as a Pallas TPU kernel.  Its grid walks the (row tile,
+    expert) pairs in which a tile holds a row of the expert, by expert
+    and so by tile (scalar prefetch: the walk picks the blocks): a tile
+    that holds three experts' rows is visited three times, an expert
+    whose rows lie in two tiles twice with its matrices read once.
+    Entries past the walk's end re-address its last pair (no copy) and
+    skip the arithmetic; the result takes the rows' place in memory, and
+    a tile past the last group's last row is not visited: its rows come
+    back as they went in."""
+    r, h = rows.shape
+    e, _, f = matrices[-2].shape
+    rows_per_tile = GROUPED_TILE_ROWS
+    tiles = r // rows_per_tile
+    # From one pair to the next the walk moves a tile on, or an expert on.
+    steps = tiles + e - 1
+    hi = jnp.cumsum(sizes.astype(jnp.int32))
+    lo = hi - sizes
+    first_tile = lo // rows_per_tile
+    visits = jnp.where(hi > lo, (hi - 1) // rows_per_tile - first_tile + 1, 0)
+    stops = jnp.cumsum(visits)
+    count = stops[-1]
+    i = jnp.minimum(jnp.arange(steps + 1), jnp.maximum(count - 1, 0))
+    group = jnp.minimum(jnp.sum(stops[None, :] <= i[:, None], axis=1),
+                        e - 1).astype(jnp.int32)
+    tile = jnp.clip(first_tile[group] + i - (stops[group] - visits[group]),
+                    0, tiles - 1).astype(jnp.int32)
+
+    def tile_block(i, tile, group, lo, hi, count):
+        return (tile[i], 0)
+
+    def expert_block(i, tile, group, lo, hi, count):
+        return (group[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((rows_per_tile, h), tile_block)]
+        + [pl.BlockSpec((1,) + m.shape[1:], expert_block) for m in matrices],
+        out_specs=pl.BlockSpec((rows_per_tile, h), tile_block),
+        scratch_shapes=[pltpu.VMEM((rows_per_tile, h), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, gated=len(matrices) == 3),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, h), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_kernel_vmem_bytes(
+                rows_per_tile, h, f, rows.dtype, len(matrices))),
+        # A tile's rows are in VMEM before its result is written.
+        input_output_aliases={5: 0},
+        name="moe_experts_grouped", interpret=interpret,
+    )(tile, group, lo, hi, count[None], rows, *matrices)
 
 
 def _touched_kernel(ids_ref, count_ref, x_ref, w_ref, *refs, gated: bool):
@@ -263,15 +433,15 @@ def experts_touched(x, gate, up, down, probs, experts, valid=None,
             out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
-                vmem_limit_bytes=_touched_vmem_bytes(
+                vmem_limit_bytes=_kernel_vmem_bytes(
                     rows, h, f, up.dtype, len(matrices))),
             name="moe_experts_touched", interpret=interpret,
         )(ids, count[None], x_rows, w_rows, *matrices)
     return out[:t]
 
 
-def _touched_vmem_bytes(rows: int, h: int, f: int, dtype,
-                        matrices: int = 3) -> int:
+def _kernel_vmem_bytes(rows: int, h: int, f: int, dtype,
+                       matrices: int = 3) -> int:
     """An expert's matrices, two buffers each, the tokens in and out,
     the float32 sum and the activations, and room to spare."""
     item = jnp.dtype(dtype).itemsize
@@ -279,20 +449,31 @@ def _touched_vmem_bytes(rows: int, h: int, f: int, dtype,
             + 4 * rows * h + 16 * rows * f + (8 << 20))
 
 
-def _touched_kernel_serves(x, up, matrices: int = 3) -> bool:
-    """The Pallas kernel's gate, read at trace time: a TPU, no ambient
-    mesh (a Mosaic kernel is not partitioned automatically; under `tp`
-    the XLA paths split the expert width), lane-aligned widths (a model
-    whose expert width is not one stores its matrices padded with zeros:
-    models/nemotron_h.py, 1856 as 1920), and an expert's matrices twice
-    over within the chip's VMEM."""
+def _kernel_serves(rows: int, up, matrices: int = 3) -> bool:
+    """The gate of both Pallas kernels, read at trace time: a TPU, no
+    ambient mesh (a Mosaic kernel is not partitioned automatically; under
+    `tp` the XLA paths split the expert width), lane-aligned widths (a
+    model whose expert width is not one stores its matrices padded with
+    zeros: models/nemotron_h.py, 1856 as 1920), and an expert's matrices
+    twice over beside `rows` rows within the chip's VMEM."""
     from kfserving_tpu.ops.attention import _tpu_backend
 
     _, h, f = up.shape
     return (_tpu_backend() and jax.sharding.get_abstract_mesh().empty
             and h % 128 == 0 and f % 128 == 0
-            and _touched_vmem_bytes(x.shape[0] + 16, h, f, up.dtype,
-                                    matrices) <= TOUCHED_MAX_VMEM_BYTES)
+            and _kernel_vmem_bytes(rows, h, f, up.dtype, matrices)
+            <= KERNEL_MAX_VMEM_BYTES)
+
+
+def _grouped_kernel_serves(x, k: int, up, matrices: int) -> bool:
+    """The grouped kernel's gate: `_kernel_serves`, and a shape that has
+    run on the chip under traffic (`GROUPED_KERNEL_PROVEN`: a workaround
+    for a hang that is not understood, not a design).  Any other
+    dispatch goes by `_grouped_ragged`, which has no stall on record."""
+    _, h, f = up.shape
+    return (_kernel_serves(GROUPED_TILE_ROWS, up, matrices)
+            and x.dtype == jnp.bfloat16
+            and (x.shape[0] * k, h, f, matrices) in GROUPED_KERNEL_PROVEN)
 
 
 def routed_experts(x, gate, up, down, probs, experts, valid=None,
@@ -301,7 +482,7 @@ def routed_experts(x, gate, up, down, probs, experts, valid=None,
     path that fits T (a static shape) and where it runs."""
     pairs = x.shape[0] * experts.shape[1]
     if x.shape[0] <= STREAMED_MAX_TOKENS:
-        if _touched_kernel_serves(x, up, 2 if gate is None else 3):
+        if _kernel_serves(x.shape[0] + 16, up, 2 if gate is None else 3):
             return experts_touched(x, gate, up, down, probs, experts, valid,
                                    first)
         # Under a share the pairs that land here are not known at trace

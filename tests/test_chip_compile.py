@@ -13,6 +13,7 @@
 import functools
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -227,14 +228,57 @@ def _olmoe_serving() -> dict:
         return json.load(f)["serving"]
 
 
-def test_grouped_expert_matmuls_compile_for_described_v5e(v5e):
-    """The prefill path of ops/moe.py at 4 rows x 1024 tokens: XLA's own
-    grouped-matmul kernel (`lax.ragged_dot`), three a layer, routed rows
-    only (a dense evaluation would be 8x the operations)."""
+def _wide_operations(compiled, scope: str, rows: int):
+    """The program's operations traced under `scope` whose result is a
+    matrix of `rows` rows (a vector of as many indices is not one)."""
+    wide = re.compile(r"= \(?\w+\[%d,\d" % rows)
+    return [line.strip() for line in compiled.as_text().splitlines()
+            if f"/{scope}/" in line and wide.search(line)]
+
+
+def _assert_grouped_work_is_the_kernels(compiled, layers: int, pairs: int):
+    """Every expert layer of a prefill program goes through the grouped
+    kernel, whose walk ends at the last real row; nothing else under
+    `moe.experts` or `moe.combine` runs over the `pairs` offered rows (no
+    grouped matmul of XLA's, no activation, no un-sort), and no loop is
+    left to XLA (PERF.md, PR 32: a `while` in this program keeps every
+    Mamba layer's projection alive to the end, 0.6 GB)."""
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert " while(" not in text
+    kernels = _wide_operations(compiled, "moe.experts", pairs)
+    assert len(kernels) == layers
+    assert all("moe_experts_grouped" in line and "tpu_custom_call" in line
+               for line in kernels)
+    # the sorted rows, which the result overwrites, are in HBM (`S(1)` on
+    # a shape is VMEM), as they were when this shape earned its place in
+    # `moe.GROUPED_KERNEL_PROVEN`
+    assert not any("S(1)}" in line.split(" custom-call(")[0]
+                   for line in kernels)
+    assert _wide_operations(compiled, "moe.combine", pairs) == []
+
+
+OLMOE_4_ROWS = (4 * 1024 * 8, 2048, 1024, 3)
+
+
+@pytest.mark.parametrize("way", ["served", "kernel"])
+def test_grouped_expert_layer_compiles_for_described_v5e(v5e, monkeypatch,
+                                                         way):
+    """The prefill path of ops/moe.py at 4 rows x 1024 tokens of OLMoE's
+    widths.  As served, XLA's own grouped matmuls, three a layer, routed
+    rows only (the shape waits for its record on the chip:
+    `moe.GROUPED_KERNEL_PROVEN`), and the sum back with no operation over
+    the T x k rows.  With the shape listed, what it would run then: one
+    Mosaic call over the sorted rows, three matrices an expert whole in
+    VMEM, and XLA left with no arithmetic to speak of."""
     from jax.sharding import SingleDeviceSharding
 
-    from kfserving_tpu.ops import moe
+    from kfserving_tpu.ops import attention, moe
 
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    if way == "kernel":
+        monkeypatch.setattr(moe, "GROUPED_KERNEL_PROVEN",
+                            moe.GROUPED_KERNEL_PROVEN | {OLMOE_4_ROWS})
     kw = _olmoe_serving()["arch_kwargs"]
     e, h, f = kw["num_experts"], kw["hidden_size"], kw["intermediate_size"]
     k, tokens = kw["experts_per_token"], 4 * 1024
@@ -243,14 +287,26 @@ def test_grouped_expert_matmuls_compile_for_described_v5e(v5e):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    compiled = jax.jit(moe.routed_experts).lower(
+    # a function of its own: the gate is read when a trace is made
+    compiled = jax.jit(lambda *args: moe.routed_experts(*args)).lower(
         arg((tokens, h), jnp.bfloat16), arg((e, h, f), jnp.bfloat16),
         arg((e, h, f), jnp.bfloat16), arg((e, f, h), jnp.bfloat16),
         arg((tokens, k), jnp.float32), arg((tokens, k), jnp.int32),
         arg((tokens,), jnp.bool_)).compile()
-    assert compiled.as_text().count("ragged-dot") >= 3
     routed = 2 * 3 * tokens * k * h * f
-    assert routed <= compiled.cost_analysis()["flops"] < 1.5 * routed
+    flops = compiled.cost_analysis()["flops"]
+    assert _wide_operations(compiled, "moe.combine", tokens * k) == []
+    if way == "served":
+        assert compiled.as_text().count("ragged-dot") >= 3
+        assert not _mosaic_calls(compiled, "moe_experts_grouped")
+        assert routed <= flops < 1.5 * routed
+        return
+    assert len(_mosaic_calls(compiled, "moe_experts_grouped")) == 1
+    assert "ragged-dot" not in compiled.as_text()
+    assert flops < 0.01 * routed
+    # the sorted rows (the result takes their place) and k slabs of [T, H]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * (
+        tokens * k * h * 2)
 
 
 @pytest.mark.parametrize("tokens", [24, 256])
@@ -464,17 +520,69 @@ def test_nemotron_decode_program_fits_the_described_v5e(v5e, monkeypatch):
 def test_nemotron_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
     """Its (8, 1024) prefill, the most one dispatch carries
     (`prefill_rows` 8): parameters, temporaries (the 10304-wide Mamba
-    projection, the chunked scan's float32 blocks, 49152 routed rows) and
+    projection, the chunked scan's float32 blocks, 49152 sorted rows) and
     outputs fit beside the 1.16 GB of state and pool that the program
-    does not see."""
+    does not see; the seven expert layers go through the grouped
+    kernel."""
     serving = _nemotron_serving()
     compiled = _prefill_program(v5e, monkeypatch, serving,
                                 serving["prefill_rows"])
-    assert compiled.as_text().count("ragged-dot") >= 14  # 7 layers x 2
+    _assert_grouped_work_is_the_kernels(compiled, layers=7, pairs=8192 * 6)
     memory = compiled.memory_analysis()
     print(f"nemotron-3-nano-16l-ep2 (8, 1024) prefill program: {memory}")
-    assert memory.temp_size_in_bytes < 2.6e9, memory
+    # 1.88 GB at PR 31 and now: the attention layers' scores
+    assert memory.temp_size_in_bytes < 1.9e9, memory
     assert _program_bytes(memory) + 1.16e9 < 15.75 * 2**30, memory
+
+
+def test_a_dispatch_with_no_record_on_the_chip_takes_no_kernel(v5e,
+                                                               monkeypatch):
+    """The (2, 1024) Nemotron prefill is not in
+    `moe.GROUPED_KERNEL_PROVEN`: with the kernel in it (the chip's
+    compiler keeps its 66 MB of sorted rows in VMEM above the kernel's
+    own 66 MB) it stopped the chip once in some hundreds of dispatches
+    (PERF.md, PR 32).  It goes by XLA's grouped matmuls, two a layer; the
+    sum back is the k gathers all the same, and no loop."""
+    compiled = _prefill_program(v5e, monkeypatch, _nemotron_serving(), 2)
+    text = compiled.as_text()
+    assert "moe_experts_grouped" not in text
+    assert text.count("ragged-dot") >= 14
+    assert " while(" not in text
+    assert _wide_operations(compiled, "moe.combine", 2048 * 6) == []
+
+
+@pytest.mark.parametrize("way", ["served", "kernel"])
+def test_olmoe_prefill_program_fits_the_described_v5e(v5e, monkeypatch,
+                                                      way):
+    """`olmoe-1b-7b-8l`'s (4, 1024) prefill, the most rows its one bucket
+    was warmed for that the experts' temporaries decide.  As served (the
+    shape is not in `moe.GROUPED_KERNEL_PROVEN` yet) eight expert layers
+    of XLA's grouped matmuls with the sum back that has no operation
+    over the 32768 rows; with the shape listed, eight grouped kernels and
+    nothing else over them, and less beside the parameters than the
+    0.373 GB of PR 31."""
+    from kfserving_tpu.ops import moe
+
+    if way == "kernel":
+        monkeypatch.setattr(moe, "GROUPED_KERNEL_PROVEN",
+                            moe.GROUPED_KERNEL_PROVEN | {OLMOE_4_ROWS})
+    compiled = _prefill_program(v5e, monkeypatch, _olmoe_serving(), 4)
+    if way == "kernel":
+        _assert_grouped_work_is_the_kernels(compiled, layers=8,
+                                            pairs=4096 * 8)
+    else:
+        text = compiled.as_text()
+        assert "moe_experts_grouped" not in text
+        assert text.count("ragged-dot") >= 24
+        assert " while(" not in text
+        assert _wide_operations(compiled, "moe.combine", 4096 * 8) == []
+    memory = compiled.memory_analysis()
+    print(f"olmoe-1b-7b-8l (4, 1024) prefill program, {way}: {memory}")
+    # PR 31: 0.373 GB.  The sum back's k gathers of [T, H] are alive
+    # together and XLA's grouped matmuls keep their float32 activations:
+    # 0.442; the kernel keeps neither
+    assert memory.temp_size_in_bytes < (
+        0.373e9 if way == "kernel" else 0.45e9), memory
 
 
 @pytest.mark.parametrize("cache_blocks", [144, 192])

@@ -226,6 +226,121 @@ def test_touched_experts_kernel_agrees_with_the_grouped_path(tokens,
         assert not np.asarray(touched)[~np.asarray(valid)].any()
 
 
+# -- the grouped path: the real pairs' work and no more ------------------------
+TILE = 8  # rows a tile of the grouped kernel holds in these tests
+
+
+def valid_with(per_token, target: int):
+    """[T] bool: tokens, taken in order, whose real pairs add up to
+    `target` exactly."""
+    valid, total = np.zeros(len(per_token), bool), 0
+    for i, c in enumerate(per_token):
+        if c and total + c <= target:
+            valid[i], total = True, total + c
+    assert total == target, (total, target)
+    return valid
+
+
+def grouped_case(real, form, first, dtype=jnp.float32):
+    """One expert layer of 40 tokens x 3 choices and the `valid` that
+    leaves it the real pairs asked for: every pair (no mask), a third
+    of the tokens, all of them (a mask that masks nothing), none, or
+    tokens whose real pairs end on the edge of the third tile of 8 rows,
+    or as little past it as can be (a token of a layer that holds every
+    expert brings its three pairs or none)."""
+    rng = np.random.default_rng(7)
+    e, h, f, k, tokens = 8, 32, 48, 3, 40
+    x, gate, up, down, probs, experts = layer_of(rng, form, first, e, h, f,
+                                                 k, tokens)
+    x, up, down = (a.astype(dtype) for a in (x, up, down))
+    gate = None if gate is None else gate.astype(dtype)
+    per_token = np.asarray(
+        (moe._held(experts, up.shape[0], first, None) < up.shape[0]).sum(-1))
+    valid = {"every": None, "third": rng.random(tokens) < 1 / 3,
+             "all": np.ones(tokens, bool),
+             "none": np.zeros(tokens, bool)}.get(real)
+    if real in ("edge", "past"):
+        valid = valid_with(per_token, 3 * TILE + (real == "past") * (
+            k if first is None else 1))
+    n = int(per_token.sum() if valid is None else per_token[valid].sum())
+    valid = None if valid is None else jnp.asarray(valid)
+    return (x, gate, up, down, probs, experts, valid, first), n
+
+
+@pytest.mark.parametrize("form,first", FORMS)
+@pytest.mark.parametrize("real", ["every", "third", "all", "none", "edge",
+                                  "past"])
+@pytest.mark.parametrize("way", ["ragged", "kernel"])
+def test_the_grouped_path_computes_the_real_pairs(monkeypatch, way, real,
+                                                  form, first):
+    """Gated and plain experts, all held and a share, by XLA's grouped
+    matmul and by the kernel (its interpreter, tiles of 8 rows): every
+    real pair and nothing else, whether the real pairs are all of the
+    T x k, a third, none at all, or end on a tile's edge or one past
+    it.  float32 on both sides."""
+    monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", TILE)
+    args, n = grouped_case(real, form, first)
+    assert real not in ("none", "edge") or n == {"none": 0,
+                                                  "edge": 3 * TILE}[real]
+    assert real != "past" or 0 < n % TILE <= 3
+    want = moe.experts_streamed(*args)
+    got = moe.experts_grouped(*args, interpret=way == "kernel")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
+                               rtol=0)
+    if args[6] is not None:
+        assert not np.asarray(got)[~np.asarray(args[6])].any()
+
+
+@pytest.mark.parametrize("form,first", FORMS)
+@pytest.mark.parametrize("way", ["ragged", "kernel"])
+def test_the_grouped_path_in_bfloat16(monkeypatch, way, form, first):
+    """The served precision: bfloat16 operands, float32 sums, an
+    expert's output rounded once and the k weighted rows summed in
+    float32.  Within 0.03 of the float64 loop on outputs of size 1,
+    where a float8 expert output would be 0.1 away."""
+    monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", 16)
+    args, _ = grouped_case("third", form, first, jnp.bfloat16)
+    got = moe.experts_grouped(*args, interpret=way == "kernel")
+    assert got.dtype == jnp.bfloat16
+    x, gate, up, down, probs, experts, valid, _ = args
+    want = plain_sum(*(None if a is None else np.asarray(a, np.float32)
+                       for a in (x, gate, up, down)), probs,
+                     np.asarray(experts), np.asarray(valid), first)
+    assert np.abs(want).max() > 0.5
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=0.03,
+                               rtol=0)
+
+
+@pytest.mark.parametrize("real", ["none", "edge", "past", "third", "every"])
+def test_the_grouped_kernel_visits_the_real_rows_tiles_and_no_other(
+        monkeypatch, real):
+    """The work is bounded by the real pairs, not by T x k: the kernel's
+    walk ends at the tile that holds the last real row.  Its result
+    takes the rows' place in memory, so a tile it did not visit comes
+    back as it went in; in the tiles it visited, rows past the last
+    group are zero.  The counters report the extent of those tiles
+    (`grouped_rows_real`), not how often the walk visited them."""
+    monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", TILE)
+    (x, gate, up, down, _, experts, valid, first), n = grouped_case(
+        real, "plain", 2)
+    e, (t, k) = up.shape[0], experts.shape
+    held = moe._held(experts, e, first, valid)
+    order = jnp.argsort(held.reshape(-1), stable=True)
+    rows = x[order // k]
+    out = np.asarray(moe._grouped_tiles(
+        rows, [up, down], moe.routed_pairs(held, e), interpret=True))
+    visited = moe.grouped_rows_real(n)
+    assert visited == -(-n // TILE) * TILE <= t * k
+    assert visited - n < TILE
+    assert not out[n:visited].any()
+    np.testing.assert_array_equal(out[visited:], np.asarray(rows)[visited:])
+    if n:
+        assert np.abs(out[:n]).max() > 0
+    # the counters' arithmetic, on a layer's pairs and on several layers'
+    assert moe.grouped_rows_real(np.array([0, 1, 256, 257])).tolist() == [
+        0, TILE, 256, 256 + TILE]
+
+
 def test_the_sigmoid_router_chooses_by_biased_score_and_weighs_by_score():
     """Nemotron-H's router: the k largest of sigmoid(logit) + bias are
     chosen; the weights are scale x their sigmoids over the sum of the
@@ -278,22 +393,32 @@ def test_the_shares_of_a_layer_add_up_to_the_layer(path):
 
 
 def test_the_kernel_serves_few_tokens_on_a_tpu_outside_a_mesh(monkeypatch):
-    """What `routed_experts` asks at trace time, and nothing else: the
-    backend, the ambient mesh and the shapes."""
+    """What `routed_experts` and the grouped path ask at trace time, and
+    nothing else: the backend, the ambient mesh and the shapes."""
     from kfserving_tpu.ops import attention
 
-    x = jnp.zeros((24, 2048), jnp.bfloat16)
     gate = jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16)
-    assert not moe._touched_kernel_serves(x, gate)  # the CPU
+    assert not moe._kernel_serves(24, gate)  # the CPU
     monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
-    assert moe._touched_kernel_serves(x, gate)
-    assert not moe._touched_kernel_serves(
-        x, jax.ShapeDtypeStruct((8, 128, 64), jnp.float32))  # olmoe_tiny
-    assert not moe._touched_kernel_serves(
-        x, jax.ShapeDtypeStruct((8, 8192, 4096), jnp.bfloat16))  # VMEM
+    assert moe._kernel_serves(24, gate)
+    assert moe._kernel_serves(moe.GROUPED_TILE_ROWS, gate)
+    # the grouped kernel besides: only the shapes with a record on the
+    # chip (Nemotron-H's 4 and 8 rows of 1024), in bfloat16
+    rows = lambda n, h: jax.ShapeDtypeStruct((n * 1024, h), jnp.bfloat16)
+    up = jax.ShapeDtypeStruct((64, 2688, 1920), jnp.bfloat16)
+    assert [n for n in (1, 2, 3, 4, 8, 16)
+            if moe._grouped_kernel_serves(rows(n, 2688), 6, up, 2)] == [4, 8]
+    assert not moe._grouped_kernel_serves(
+        jax.ShapeDtypeStruct((4096, 2688), jnp.float32), 6, up, 2)
+    assert not any(moe._grouped_kernel_serves(rows(n, 2048), 8, gate, 3)
+                   for n in (1, 2, 4, 8, 16))  # OLMoE: 49 ramps of 100
+    assert not moe._kernel_serves(
+        24, jax.ShapeDtypeStruct((8, 128, 64), jnp.float32))  # olmoe_tiny
+    assert not moe._kernel_serves(
+        24, jax.ShapeDtypeStruct((8, 8192, 4096), jnp.bfloat16))  # VMEM
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("tp",))
     with jax.set_mesh(mesh):
-        assert not moe._touched_kernel_serves(x, gate)
+        assert not moe._kernel_serves(24, gate)
 
 
 def test_bfloat16_compute_is_inside_a_bound_that_8_bits_are_not(tiny):
@@ -343,6 +468,72 @@ async def test_prefill_then_decode_through_the_paged_pool(tiny):
     assert stats["moe_load_max_over_mean"] >= 1
     assert stats["active_params"] == engine.module.config.param_counts()[
         "active"]
+
+
+def counter_value(name: str, model: str) -> float:
+    from kfserving_tpu.observability import metrics as obs
+
+    for line in obs.REGISTRY.render().splitlines():
+        if line.startswith(name + "{") and f'model="{model}"' in line:
+            return float(line.rsplit(" ", 1)[1])
+    return 0.0
+
+
+def test_the_counters_of_the_grouped_path_are_host_arithmetic():
+    """What the grouped path was offered and what its matmuls visited,
+    from the `pairs` [expert layers, experts] a prefill dispatch returns
+    anyway and the dispatch's shape: tokens x choices a layer, and each
+    layer's real pairs rounded up to the kernel's tile."""
+    from kfserving_tpu.engine.moe_counters import MoeCounters
+
+    model = "grouped-counters-test"
+    counters = MoeCounters(model, experts=4, per_token=2)
+    assert counters.stats() == {}
+    # (2, 512): 2048 rows a layer offered; 300 and 513 real pairs
+    counters.note("prefill", {"pairs": jnp.asarray(
+        [[100, 0, 200, 0], [256, 256, 1, 0]])}, tokens=1024)
+    # 64 tokens: `routed_experts` may take another path, nothing is counted
+    counters.note("prefill", {"pairs": jnp.asarray(
+        [[9, 9, 9, 9], [9, 9, 9, 9]])}, tokens=64)
+    counters.drain()
+    tile = moe.GROUPED_TILE_ROWS
+    assert tile == 256
+    assert counters.grouped_rows == 2 * 2048
+    assert counters.grouped_rows_computed == 2 * tile + 3 * tile
+    assert counters.pairs["prefill"] == 300 + 513 + 72
+    assert counters.stats() == {
+        "moe_grouped_rows_computed_share": round(5 * tile / 4096, 4)}
+    prefix = "kfserving_tpu_generator_moe_grouped_pair_rows"
+    assert counter_value(prefix + "_total", model) == 4096
+    assert counter_value(prefix + "_computed_total", model) == 5 * tile
+    # a dispatch with no real pair at all visits nothing
+    counters.note("prefill", {"pairs": jnp.zeros((2, 4), jnp.int32)},
+                  tokens=1024)
+    counters.drain()
+    assert (counters.grouped_rows, counters.grouped_rows_computed) == (
+        4 * 2048, 5 * tile)
+
+
+async def test_prefill_dispatches_count_what_the_grouped_path_visited(tiny):
+    """Three prompts admitted together pad to (4, 256): 1024 tokens, over
+    the 256 that `routed_experts` may give another path, so the dispatch
+    is counted: 2 choices x 1024 tokens x 2 layers offered, the real
+    pairs of 3 prompts visited."""
+    engine = engine_of(tiny, prefill_buckets=[MAX_SEQ])
+    prompts = [prompt_of(100), prompt_of(60, 5), prompt_of(31, 7)]
+    try:
+        await asyncio.wait_for(asyncio.gather(*[
+            served(engine, p, 4) for p in prompts]), timeout=600)
+        stats, counters = engine.stats(), engine._moe
+    finally:
+        await engine.close()
+    assert counters.grouped_rows % (2 * MAX_SEQ * 2) == 0
+    assert counters.grouped_rows >= 2 * 2 * MAX_SEQ * 2
+    real = 2 * sum(map(len, prompts))  # a layer's, were all admitted at once
+    assert 0 < counters.grouped_rows_computed <= 2 * 3 * (
+        moe.grouped_rows_real(real))
+    assert stats["moe_grouped_rows_computed_share"] == round(
+        counters.grouped_rows_computed / counters.grouped_rows, 4) < 1
 
 
 async def test_chunked_prefill(tiny):
@@ -416,6 +607,13 @@ def test_the_engines_model_counts_active_parameters(tiny):
                               max_seq=MAX_SEQ, block_size=BS)
     n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(dense_vars))
     assert engine._moe is None
+    # ... and its programs, fetches and stats: five outputs a prefill, no
+    # routing beside them, no counter of the expert paths
+    ids = jax.ShapeDtypeStruct((1, MAX_SEQ), jnp.int32)
+    row = [jax.ShapeDtypeStruct((1,), t) for t in (
+        jnp.int32, jnp.float32, jnp.int32, jnp.float32, jnp.int32)]
+    assert len(jax.eval_shape(engine._prefill, dense_vars, ids, *row)) == 5
+    assert not [k for k in engine.stats() if k.startswith("moe_")]
     assert engine._flops_matmul_per_token == 2.0 * n
     assert engine._param_read_bytes == engine.param_bytes() == 4 * n
     assert engine.stats()["active_params"] == n
