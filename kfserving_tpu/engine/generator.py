@@ -75,14 +75,11 @@ from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 import numpy as np
 
 from kfserving_tpu.engine import compile_cache
+from kfserving_tpu.engine import inflight as inflight_table
 from kfserving_tpu.observability import attribution
 from kfserving_tpu.observability import metrics as obs
 from kfserving_tpu.observability.profiling import TIMELINE
-from kfserving_tpu.observability.profiling.timeline import (
-    FETCH,
-    HOST,
-    LAUNCH,
-)
+from kfserving_tpu.observability.profiling.timeline import HOST, LAUNCH
 from kfserving_tpu.parallel.mesh import mesh_scope
 from kfserving_tpu.protocol.errors import InferenceError, InvalidInput
 from kfserving_tpu.reliability import sanitizer
@@ -951,6 +948,11 @@ class GenerationEngine:
         self._enqueue_executor = concurrent.futures.ThreadPoolExecutor(
             max_workers=1,
             thread_name_prefix=f"generator-enq-{name}")
+        # Every launch's row until its fetch returns (engine/inflight.py),
+        # and the loop's once-a-second look at it while a fetch is out.
+        self._inflight = inflight_table.InflightTable(
+            name, (f"generator-enq-{name}_", f"generator-{name}_"))
+        self._stall_timer: Optional[asyncio.TimerHandle] = None
         self._slots: List[Optional[_Active]] = [None] * self.max_slots
         self._pending: deque = deque()
         # Growth starvation: a decodable slot's table cannot cover the
@@ -1217,6 +1219,9 @@ class GenerationEngine:
 
     async def close(self):
         self._closed = True
+        if self._stall_timer is not None:
+            self._stall_timer.cancel()
+            self._stall_timer = None
         if self._loop_task is not None:
             if self._wakeup is not None:
                 self._wakeup.set()
@@ -1285,6 +1290,7 @@ class GenerationEngine:
             "decode_wait_s": round(self._decode_wait_s, 4),
             "prefill_wait_s": round(self._prefill_wait_s, 4),
             "prefill_device_s": round(self._prefill_device_s, 4),
+            "inflight": self._inflight.rows(),
         }
         # -- roofline block (promoted to registry gauges by
         # observability/profiling/roofline.py; keys must stay in sync
@@ -2568,7 +2574,7 @@ class GenerationEngine:
         final = idx >= act.chunk_total - 1
         act.chunk_next = idx + 1
         try:
-            firsts_h, lp_h = await loop.run_in_executor(
+            firsts_h, lp_h, seq = await loop.run_in_executor(
                 self._enqueue_executor, self._enqueue_chunk,
                 slot, act, idx, final)
         except Exception as e:
@@ -2596,10 +2602,11 @@ class GenerationEngine:
         obs.generator_prefill_chunks_total().labels(
             outcome="dispatched").inc()
         act.chunks_inflight += 1
-        fut = loop.run_in_executor(self._executor, self._fetch_wave,
-                                   firsts_h, lp_h)
+        fut = loop.run_in_executor(
+            self._executor, self._fetch_joined, seq, "chunk",
+            self._fetch_wave, firsts_h, lp_h)
         inflight.append(("chunk", fut, (slot, act, idx, final),
-                         time.perf_counter()))
+                         time.perf_counter(), seq))
 
     def _register_chunk_blocks(self, act: _Active, idx: int) -> None:
         if not act.chunk_regs:
@@ -2667,7 +2674,7 @@ class GenerationEngine:
             slot_d = jnp.asarray(np.asarray([slot], np.int32))
             park = (jnp.zeros((1,), jnp.int32),
                     jnp.full((1,), self.max_seq, jnp.int32))
-        with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=1):
+        with self._inflight.launch("feed", rows=1):
             self._feed_tokens, self._feed_positions = \
                 self._feed_update(
                     self._feed_tokens, self._feed_positions,
@@ -2701,21 +2708,21 @@ class GenerationEngine:
                                                np.float32)),
                         jnp.asarray(np.asarray([req.seed], np.int32)),
                         n_d]
-            with TIMELINE.span(LAUNCH, "engine.launch.chunk",
-                               trace_id=req.trace_id, slot=slot,
-                               rows=1, bucket=nb * self.block_size):
+            with self._inflight.launch(
+                    "chunk", trace_id=req.trace_id, slot=slot, rows=1,
+                    bucket=nb * self.block_size) as launched:
                 (first, self._caches, chosen_lp, top_ids, top_lps) = \
                     self._chunk_prefill(self.variables, self._caches,
                                         *args)
         if final:
-            with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=1):
+            with self._inflight.launch("feed", rows=1):
                 self._feed_tokens, self._feed_positions = \
                     self._feed_update(
                         self._feed_tokens, self._feed_positions,
                         slot_d, first, n_d)
         lp_h = ((chosen_lp, top_ids, top_lps)
                 if req.logprobs > 0 else None)
-        return first, lp_h
+        return first, lp_h, launched.seq
 
     async def _run_inner(self):
         loop = asyncio.get_event_loop()
@@ -2725,9 +2732,10 @@ class GenerationEngine:
         # forward + cache insert + feed scatter and returns WITHOUT a
         # host sync (the old blocking admission added a full
         # prefill-dispatch of inter-token stall to every live stream).
-        # Items: ("decode", fetch_future, snapshot, t0) or
-        # ("prefill", fetch_future, entries, t0) where entries is
-        # [(slot, _Active|None)] in batch order.  Fetch futures are
+        # Items: ("decode", fetch_future, snapshot, t0, seq) or
+        # ("prefill", fetch_future, entries, t0, seq) where entries is
+        # [(slot, _Active|None)] in batch order and seq the launch's
+        # number in the in-flight table.  Fetch futures are
         # submitted EAGERLY at enqueue (round trips overlap on the
         # 2-worker fetch executor); awaiting in FIFO order preserves
         # delivery order.
@@ -2867,7 +2875,7 @@ class GenerationEngine:
                     self._requeue_group(group, slots)
                     continue
                 try:
-                    firsts_h, lp_h = await loop.run_in_executor(
+                    firsts_h, lp_h, seq = await loop.run_in_executor(
                         self._enqueue_executor,
                         self._enqueue_prefill_group,
                         group, slots, bucket, dest_rows)
@@ -2923,9 +2931,10 @@ class GenerationEngine:
                 # overlaps other fetches; the FIFO await below keeps
                 # delivery order.
                 fut = loop.run_in_executor(
-                    self._executor, self._fetch_wave, firsts_h, lp_h)
+                    self._executor, self._fetch_joined, seq, "prefill",
+                    self._fetch_wave, firsts_h, lp_h)
                 inflight.append(("prefill", fut, entries,
-                                 time.perf_counter()))
+                                 time.perf_counter(), seq))
                 admitted = True
             active = any(s is not None for s in self._slots)
             if not active and not inflight:
@@ -2934,6 +2943,7 @@ class GenerationEngine:
                 # a fully-idle engine would strand blocks until the
                 # next wave advanced the counter).
                 self._process_deferred_frees(force=True)
+                self._inflight.settle()
                 # The HOLD's reason is gone with the pipeline empty
                 # and the deferred frees landed; left set, it would
                 # gate admissions while this branch `continue`s above
@@ -3099,12 +3109,13 @@ class GenerationEngine:
                         obs.generator_suppressed_waves_total().inc()
                         TIMELINE.record("host", "wave.suppressed")
                         break
-                    kind_, toks_h, lp_h, snap, t0_ = \
+                    kind_, toks_h, lp_h, snap, t0_, seq = \
                         await loop.run_in_executor(
                             self._enqueue_executor, self._enqueue_wave)
                     fut = loop.run_in_executor(
-                        self._executor, self._fetch_wave, toks_h, lp_h)
-                    inflight.append((kind_, fut, snap, t0_))
+                        self._executor, self._fetch_joined, seq, kind_,
+                        self._fetch_wave, toks_h, lp_h)
+                    inflight.append((kind_, fut, snap, t0_, seq))
                     waves += 1
             if decodable and waves != self._depth_effective:
                 self._depth_effective = waves
@@ -3116,17 +3127,23 @@ class GenerationEngine:
                 # succeeds next iteration.
                 self._process_deferred_frees(force=True)
                 continue
-            kind, fut, meta, t0 = inflight.popleft()
+            kind, fut, meta, t0, seq = inflight.popleft()
+            self._watch_inflight(loop)
             t_await = time.perf_counter()
             try:
                 # Held across the await, like engine.wait.request.
-                with TIMELINE.span(HOST, "engine.wait.fetch"):
-                    fetched, lp, _worker_span = await fut
+                with TIMELINE.span(HOST, "engine.wait.fetch", seq=seq):
+                    fetched, lp, fetched_t = await fut
                 # Host-blocked time is the LOOP-side await, not the
                 # worker's span: eager fetches overlap on the worker
                 # pool and their spans cover whole-wave latency — the
                 # sum would exceed wall clock and lie in A/Bs.
-                wait_s = time.perf_counter() - t_await
+                taken_up = time.perf_counter()
+                wait_s = taken_up - t_await
+                # From the fetch's return on its worker to here: what
+                # the loop, not the device, added to the round trip.
+                obs.generator_deliver_lag_ms().observe(
+                    (taken_up - fetched_t) * 1000.0)
             except Exception as e:
                 if kind == "prefill":
                     # Fail THAT group; in-flight slots keep decoding.
@@ -3343,9 +3360,9 @@ class GenerationEngine:
                 table = self._table_device()
                 sampling = [jnp.asarray(a)
                             for a in (temps, top_ks, top_ps, seeds)]
-            with TIMELINE.span(LAUNCH, "engine.launch.decode",
-                               rows=self.max_slots,
-                               steps=self.steps_per_call):
+            with self._inflight.launch(
+                    "decode", rows=self.max_slots,
+                    steps=self.steps_per_call) as launched:
                 out = self._decode(
                     self.variables, self._caches, table,
                     self._feed_tokens, self._feed_positions, *sampling)
@@ -3367,19 +3384,44 @@ class GenerationEngine:
         snapshot = [None if (s is not None and s.prefilling) else s
                     for s in self._slots]
         return ("decode", toks, lp_h, snapshot,
-                time.perf_counter())
+                time.perf_counter(), launched.seq)
+
+    def _fetch_joined(self, seq: int, program: str, fetch, *handles):
+        """Runs on a fetch worker: `fetch` (`_fetch_wave` or
+        `_fetch_spec`) under the in-flight table's `engine.fetch` span,
+        which carries the launch's `seq`; the row retires when the
+        fetch returns, however it returns.  Gives (fetched, lp, the
+        fetch's return on this worker's clock): deliver lag runs from
+        there."""
+        with self._inflight.fetch(seq, program) as joined:
+            fetched, lp = fetch(*handles)
+        return fetched, lp, joined.done_t
+
+    def _watch_inflight(self, loop) -> None:
+        """Before the loop awaits a fetch: have the in-flight table
+        looked at once a second until no fetch is outstanding
+        (`InflightTable.check`: the oldest-age gauge, and a stall
+        counted and reported once).  A timer on the loop, so it runs
+        whichever await the loop is parked at, and cancels nothing."""
+        if self._stall_timer is None and not self._closed:
+            self._stall_timer = loop.call_later(
+                inflight_table.STALL_CHECK_S, self._look_at_inflight,
+                loop)
+
+    def _look_at_inflight(self, loop) -> None:
+        self._stall_timer = None
+        if self._inflight.check():
+            self._watch_inflight(loop)
 
     def _fetch_wave(self, toks_h, lp_h):
         """Runs on the executor thread: the D2H fetch that joins the
         device timeline (block_until_ready on this transport acks the
         dispatch without joining — only the fetch truly waits).
-        Returns (tokens, lp, wait_s); the caller attributes the wait
-        to decode or prefill (this path serves both kinds)."""
-        t0 = time.perf_counter()
+        Returns (tokens, lp); the caller attributes the wait to decode
+        or prefill (this path serves both kinds)."""
         # THE sanctioned generation fetch: the one place device
         # handles become host arrays, on the fetch executor.
-        with TIMELINE.span(FETCH, "engine.fetch"), \
-                sanitizer.sanctioned_fetch():
+        with sanitizer.sanctioned_fetch():
             # kfslint: disable=host-sync — sanctioned fetch site: the
             # wave's D2H join, off-loop on the fetch executor.
             tokens = np.asarray(toks_h)
@@ -3390,7 +3432,7 @@ class GenerationEngine:
                 lp = tuple(np.asarray(h) for h in lp_h)
             if self._moe is not None:
                 self._moe.drain()
-        return tokens, lp, time.perf_counter() - t0
+        return tokens, lp
 
     @_dispatch_timed("prefill")
     def _enqueue_prefill_group(self, group: List[_Request],
@@ -3456,9 +3498,9 @@ class GenerationEngine:
         # so a request's spans share its identifier (the profiler's
         # annotation takes the scalars alone).
         with mesh_scope(self.mesh), \
-                TIMELINE.span(LAUNCH, "engine.launch.prefill", rows=b,
-                              bucket=bucket,
-                              trace_ids=[r.trace_id for r in group]):
+                self._inflight.launch(
+                    "prefill", rows=b, bucket=bucket,
+                    trace_ids=[r.trace_id for r in group]) as launched:
             out = self._prefill(self.variables, ids_d, lengths_d,
                                 *sampling)
             firsts, new_caches, chosen_lp, top_ids, top_lps = out[:5]
@@ -3474,7 +3516,7 @@ class GenerationEngine:
             for i, row in enumerate(dest_rows):
                 dest[i, :len(row)] = row
             dest_d = jnp.asarray(dest)
-        with TIMELINE.span(LAUNCH, "engine.launch.insert", rows=b):
+        with self._inflight.launch("insert", rows=b):
             self._caches = self._insert(self._caches, new_caches,
                                         dest_d, slot_d)
         # The admitted slots' first feed token/position land in the
@@ -3483,7 +3525,7 @@ class GenerationEngine:
         # which the host may not have seen yet).  The next decode wave
         # therefore includes these slots before the host ever sees
         # their first token.
-        with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=b):
+        with self._inflight.launch("feed", rows=b):
             self._feed_tokens, self._feed_positions = \
                 self._feed_update(
                     self._feed_tokens, self._feed_positions,
@@ -3496,7 +3538,7 @@ class GenerationEngine:
             with TIMELINE.span(LAUNCH, "engine.wait.insert"):
                 self._jax.block_until_ready(self._caches)
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
-        return firsts, lp_h
+        return firsts, lp_h, launched.seq
 
     def _sampling_arrays(self):
         """Per-slot sampling parameter arrays for a decode dispatch.
@@ -3686,21 +3728,23 @@ class GenerationEngine:
                 windows = self._build_draft_windows(eligible)
             else:
                 ngram, host_ms = self._propose_ngram(eligible)
-            kind_, handles, lp_h, meta_, t0_ = \
+            kind_, handles, lp_h, meta_, t0_, seq = \
                 await loop.run_in_executor(
                     self._enqueue_executor, self._enqueue_spec_wave,
                     eligible, ngram, windows, host_ms)
             fut = loop.run_in_executor(
-                self._executor, self._fetch_spec, handles, lp_h)
-            inflight.append((kind_, fut, meta_, t0_))
+                self._executor, self._fetch_joined, seq, kind_,
+                self._fetch_spec, handles, lp_h)
+            inflight.append((kind_, fut, meta_, t0_, seq))
             return
         if fall_site is not None:
             self._count_spec_fallback(fall_site)
-        kind_, toks_h, lp_h, snap, t0_ = await loop.run_in_executor(
+        kind_, toks_h, lp_h, snap, t0_, seq = await loop.run_in_executor(
             self._enqueue_executor, self._enqueue_resynced_wave)
         fut = loop.run_in_executor(
-            self._executor, self._fetch_wave, toks_h, lp_h)
-        inflight.append((kind_, fut, snap, t0_))
+            self._executor, self._fetch_joined, seq, kind_,
+            self._fetch_wave, toks_h, lp_h)
+        inflight.append((kind_, fut, snap, t0_, seq))
 
     async def _probe_spec_fault(self) -> Optional[str]:
         """Chaos seams of the speculative path, probed ON the loop
@@ -3788,8 +3832,8 @@ class GenerationEngine:
             else:
                 draft_dev = jnp.asarray(ngram)
         if windows is not None:
-            with TIMELINE.span(LAUNCH, "engine.launch.spec_draft",
-                               rows=len(eligible)):
+            with self._inflight.launch("spec_draft",
+                                       rows=len(eligible)):
                 draft_dev = self._spec_draft_fn(self.draft_variables,
                                                 windows_d)
         with mesh_scope(self.mesh):
@@ -3799,8 +3843,8 @@ class GenerationEngine:
                 last_d, qpos_d = jnp.asarray(last), jnp.asarray(qpos)
                 sampling = [jnp.asarray(a)
                             for a in (temps, top_ks, top_ps, seeds)]
-            with TIMELINE.span(LAUNCH, "engine.launch.spec",
-                               rows=len(eligible), steps=K + 1):
+            with self._inflight.launch(
+                    "spec", rows=len(eligible), steps=K + 1) as launched:
                 (samples, draft_echo, self._caches, chosen_lp, top_ids,
                  top_lps) = self._spec_verify(
                     self.variables, self._caches, table, last_d,
@@ -3810,7 +3854,7 @@ class GenerationEngine:
         lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         return ("spec", (samples, draft_echo, windows is not None),
                 lp_h, (list(eligible), host_draft_ms),
-                time.perf_counter())
+                time.perf_counter(), launched.seq)
 
     def _fetch_spec(self, handles, lp_h):
         """Runs on the fetch executor: join the spec wave's device
@@ -3820,8 +3864,7 @@ class GenerationEngine:
         extra transfers: block_until_ready moves no data)."""
         samples_h, draft_h, timed_draft = handles
         t0 = time.perf_counter()
-        with TIMELINE.span(FETCH, "engine.fetch"), \
-                sanitizer.sanctioned_fetch():
+        with sanitizer.sanctioned_fetch():
             draft_ready_s = 0.0
             if timed_draft:
                 # kfslint: disable=host-sync — sanctioned fetch site:
@@ -3839,8 +3882,7 @@ class GenerationEngine:
                 # kfslint: disable=host-sync — sanctioned fetch site:
                 # logprob handles fetched beside their wave's tokens.
                 lp = tuple(np.asarray(h) for h in lp_h)
-        return ((samples, draft, draft_ready_s), lp,
-                time.perf_counter() - t0)
+        return (samples, draft, draft_ready_s), lp
 
     def _enqueue_resynced_wave(self):
         """Runs on the enqueue executor: re-sync the device feed
@@ -3863,7 +3905,7 @@ class GenerationEngine:
                 toks[i] = s.last_token
                 pos[i] = s.length
         self._note_program("feed_resync", S)
-        with TIMELINE.span(LAUNCH, "engine.launch.feed", rows=S):
+        with self._inflight.launch("feed", rows=S):
             self._feed_tokens, self._feed_positions = \
                 self._feed_update(
                     self._feed_tokens, self._feed_positions,

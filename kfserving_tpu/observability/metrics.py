@@ -109,6 +109,53 @@ def generator_dispatch_host_ms():
         "(program=decode|prefill|chunk|spec)")
 
 
+# In-flight time and deliver lag must hold a stall (a minute and more)
+# and be fine where a healthy engine reads (under a second).
+INFLIGHT_BUCKETS_MS = [1, 2.5, 5, 10, 25, 50, 75, 100, 150, 200, 300, 400,
+                       500, 750, 1000, 2500, 5000, 10000, 30000, 60000,
+                       120000]
+DELIVER_LAG_BUCKETS_MS = [0.1, 0.25, 0.5, 1, 2.5, 5, 10, 25, 50, 100, 250,
+                          500, 1000, 2500, 5000, 10000, 30000, 60000]
+
+
+def generator_program_inflight_ms():
+    return REGISTRY.histogram(
+        "kfserving_tpu_generator_program_inflight_ms",
+        "One launched program from its launch call's return to its "
+        "fetch's return, observed on the fetch worker when its row "
+        "leaves the in-flight table (program=decode|prefill|chunk|"
+        "spec).  Under a pipeline it holds the wait behind earlier "
+        "programs: the round trip a token rides, not the device's time",
+        buckets=INFLIGHT_BUCKETS_MS)
+
+
+def generator_deliver_lag_ms():
+    return REGISTRY.histogram(
+        "kfserving_tpu_generator_deliver_lag_ms",
+        "From a fetch's return on its worker to the scheduler loop "
+        "taking that result up: a loop that was held (garbage "
+        "collection, a profiler starting, another handler) shows here "
+        "and not in generator_program_inflight_ms",
+        buckets=DELIVER_LAG_BUCKETS_MS)
+
+
+def generator_program_stalls_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_program_stalls_total",
+        "Launched programs whose age in flight passed max(5 s, 20 x "
+        "the running mean of their program) before their fetch "
+        "returned; each is counted once and reported once in the log "
+        "(`engine stalled:`) with every row in flight")
+
+
+def generator_inflight_oldest_age_s():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_inflight_oldest_age_s",
+        "Seconds since the launch of the oldest program whose fetch "
+        "has not returned, as of the scheduler loop's last look (once "
+        "a second while a fetch is outstanding); 0 with none in flight")
+
+
 def llm_inter_token_ms():
     return REGISTRY.histogram(
         "kfserving_tpu_llm_inter_token_ms",

@@ -346,6 +346,17 @@ async def smoke() -> List[str]:
     for reason in ("error", "dropped", "spool"):
         obs.incident_failures_total().labels(reason=reason).inc()
     obs.incident_duration_ms().observe(42_000.0)
+    # In-flight table families (ISSUE 39): a launched program's round
+    # trip by program, the loop's deliver lag, the stall counter and
+    # the oldest-age gauge, touched so names and suffixes always lint.
+    for program in ("decode", "prefill", "chunk", "spec"):
+        obs.generator_program_inflight_ms().labels(
+            program=program).observe(150.0)
+        obs.generator_program_stalls_total().labels(
+            model="metrics-probe", program=program).inc()
+    obs.generator_deliver_lag_ms().observe(0.4)
+    obs.generator_inflight_oldest_age_s().labels(
+        model="metrics-probe").set(0.2)
     problems: List[str] = []
     if resp.status != 200:
         problems.append(
