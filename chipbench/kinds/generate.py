@@ -16,6 +16,7 @@ import os
 import random
 import re
 import shutil
+import statistics
 import subprocess
 import sys
 import threading
@@ -25,6 +26,7 @@ from concurrent.futures import ThreadPoolExecutor
 from chipbench import prom, schedule, servers, stats
 from chipbench.servers import BenchFailure, log
 
+HERE = os.path.dirname(os.path.abspath(__file__))
 BOS_ID = 256  # the served byte tokenizer: BOS, then one id per UTF-8 byte
 CHECK_STEPS = 8  # decode steps compared per check prompt
 CHECK_TOP = 5
@@ -102,15 +104,28 @@ def reference_answers(config: dict, cases: list) -> tuple:
         return json.load(f)["cases"], time.monotonic() - t0
 
 
-def reference_gap(cases: list, answers: list) -> float:
-    """Largest |served - reference| log-probability over the chosen token of
-    every compared step and the first step's top tokens."""
-    gap = 0.0
-    for case, answer in zip(cases, answers):
-        for served, ref in zip(case["chosen"] + case["top"],
-                               answer["chosen"] + answer["top"]):
-            gap = max(gap, abs(served - ref))
-    return gap
+def reference_gaps(cases: list, answers: list) -> list:
+    """|served - reference| log-probability of the chosen token of every
+    compared step and of the first step's top tokens: every one of them."""
+    return [abs(served - ref)
+            for case, answer in zip(cases, answers)
+            for served, ref in zip(case["chosen"] + case["top"],
+                                   answer["chosen"] + answer["top"])]
+
+
+def reference_limits(config: dict) -> dict:
+    """{number compared with the reference: its limit}.  A configuration's
+    own `reference.tolerance` bounds the widest gap.  Where the widest gap
+    cannot tell a lower precision from the stated one (a router whose flipped
+    near-tie moves one token by more than rounding moves them all),
+    chipbench/limits/<configuration>.json names the numbers that can and
+    their limits, this one's anew among them; PERF.md gives the readings."""
+    path = os.path.join(os.path.dirname(HERE), "limits",
+                        config["name"] + ".json")
+    if not os.path.exists(path):
+        return {"reference_gap": config["reference"]["tolerance"]}
+    with open(path) as f:
+        return {name: entry["limit"] for name, entry in json.load(f).items()}
 
 
 # -- programs: every prefill shape the window can use ------------------------
@@ -183,8 +198,9 @@ def plan_for(run: dict, server) -> dict:
         plan.update(clients=traffic["clients"],
                     stagger_s=traffic["stagger_s"],
                     warm_rounds=traffic["warm_rounds"],
-                    requests=schedule.closed_requests(
-                        traffic, run["seed"], traffic["requests"]))
+                    # the generator draws `schedule.closed_stream` itself:
+                    # the list has no end, so it cannot be written here
+                    traffic=traffic, seed=run["seed"])
     else:
         plan.update(tail_s=traffic["tail_s"],
                     requests=schedule.open_requests(
@@ -230,7 +246,7 @@ def measure(run: dict) -> dict:
             warm_programs(server, config, run["seed"])
             phase("programs_s")
             answers, ref_s = reference.result()
-        gap = reference_gap(cases, answers)
+        gaps = reference_gaps(cases, answers)
         phase("reference_wait_s")
 
         plan = plan_for(run, server)
@@ -260,7 +276,9 @@ def measure(run: dict) -> dict:
     out.update(
         device=device, device_after=device_after, startup=startup,
         setup_phases=phases, setup_s=out["window"][0] - run["t_start"],
-        reference={"gap": gap, "tolerance": config["reference"]["tolerance"],
+        reference={"gap": max(gaps), "gap_median": statistics.median(gaps),
+                   "limits": reference_limits(config),
+                   "tolerance": config["reference"]["tolerance"],
                    "seconds": ref_s, "prompts": len(cases)},
         compiles_in_window=servers.compile_lines(window_log),
         trace_dir=plan["trace"]["log_dir"] if plan["trace"] else None)

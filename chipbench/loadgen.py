@@ -2,18 +2,21 @@
 
     python -m chipbench.loadgen <plan.json>
 
-It reads a plan (the requests `schedule` built, the loop kind, the window's
-length), drives a generate server over HTTP, and writes one JSON file: a
-record per request with the arrival time of every streamed token, the
-window it measured, and what it read from the server at the window's edges
-(/metrics, the device record, the size of the server's log).  It takes no
+It reads a plan (the loop kind, the window's length; an open loop's requests
+as `schedule` built them; a closed loop's traffic and seed, from which it
+draws `schedule.closed_stream` itself, because that list has no end), drives
+a generate server over HTTP, and writes one JSON file: a record per request
+with the arrival time of every streamed token, the window it measured, and
+what it read from the server at the window's edges (/metrics, the device
+record, the size of the server's log).  It takes no
 measurement of its own beyond timestamps; `stats` and the per-layer readers
 reduce them.  All times are this process's CLOCK_MONOTONIC, which the parent
 shares.
 
-closed loop: clients start staggered and pull requests from one list; the
-    window opens when every client has finished `warm_rounds` requests, and
-    closes `seconds` later while traffic still flows.
+closed loop: clients start staggered and pull requests from one list that
+    never runs out; the window opens when every client has finished
+    `warm_rounds` requests, and closes `seconds` later while traffic still
+    flows.
 open loop: request i is sent at its due time whatever is outstanding; the
     window opens after the lead-in and closes `seconds` later; the schedule
     keeps flowing through the tail, until every request due in the window
@@ -27,6 +30,8 @@ import sys
 import time
 
 import aiohttp
+
+from chipbench import schedule
 
 now = time.monotonic
 
@@ -166,7 +171,7 @@ class Generator:
     # -- the two loops ---------------------------------------------------------
     async def closed_loop(self) -> None:
         plan = self.plan
-        requests = iter(plan["requests"])
+        requests = schedule.closed_stream(plan["traffic"], plan["seed"])
         clients = int(plan["clients"])
         done = [0] * clients
         warm = asyncio.Event()
@@ -175,12 +180,8 @@ class Generator:
         async def client(k: int):
             await asyncio.sleep(k * float(plan["stagger_s"]) / clients)
             while True:
-                request = next(requests, None)
-                if request is None:
-                    self.notes.append("closed loop ran out of requests")
-                    return
                 phase = "window" if self.window else "warm"
-                await self.generate(request, now(), phase)
+                await self.generate(next(requests), now(), phase)
                 done[k] += 1
                 if min(done) >= rounds:
                     warm.set()

@@ -9,6 +9,8 @@ The harness is driven by data and finds everything by name:
     a traffic mix    chipbench/traffic/<traffic>.json
     how it is served chipbench/kinds/<config's "kind">.py, `measure(run)`
     its reference    chipbench/references/<config's reference module>.py
+    its limits       the configuration's `reference.tolerance`, or
+                     chipbench/limits/<config>.json where there is one
     a metric         chipbench/end_to_end/<name>.py or
                      chipbench/layer_metrics/<name>.py, `read(run)`; a reader
                      that finds nothing to read returns None and the metric
@@ -22,7 +24,10 @@ phase, throughput by slice, how late the generator ran).  The last line is
 the result.  A run that cannot give one (no TPU, fewer chips than the cell
 asks for, a child that dies, a directory that is not a checkout) prints none
 and exits non-zero; a run whose answers are off the reference, or that
-compiled inside its window, prints `"correct": false` and exits non-zero.
+compiled inside its window, prints `"correct": false` and exits 0, as every
+run that has a result does: what reads the line is told by the line.
+Every result carries the numbers that decided `correct`, each beside its
+limit: under `compared`, the line's last key, and as stderr's last lines.
 """
 
 import argparse
@@ -106,9 +111,10 @@ def observations(run: dict) -> None:
     log("setup by phase: " + json.dumps(
         {k: round(v, 2) for k, v in run["setup_phases"].items()})
         + f"; server's own marks {json.dumps(run['startup'])}")
-    log(f"reference: gap {run['reference']['gap']:.5f} (tolerance "
-        f"{run['reference']['tolerance']}) over "
-        f"{run['reference']['prompts']} prompts, "
+    log("reference: " + ", ".join(
+        f"{name} {c['value']:.5f} (limit {c['limit']})"
+        for name, c in compared(run).items() if name.startswith("reference"))
+        + f" over {run['reference']['prompts']} prompts, "
         f"{run['reference']['seconds']:.1f}s")
     kind = load_by_path("kinds", run["config"]["kind"])
     if hasattr(kind, "observe"):
@@ -125,6 +131,21 @@ def observations(run: dict) -> None:
             + "; ".join(c[:120] for c in run["compiles_in_window"]))
 
 
+def compared(run: dict) -> dict:
+    """Each number that decides `correct`, beside its limit.  Which numbers
+    are compared with the reference, and their limits, the kind has read for
+    the configuration (`kinds/generate.reference_limits`)."""
+    reference = run["reference"]
+    limits = reference.get("limits") or {
+        "reference_gap": reference["tolerance"]}
+    read = {"reference_gap": "gap", "reference_gap_median": "gap_median"}
+    out = {name: {"value": reference[read[name]], "limit": limit}
+           for name, limit in limits.items()}
+    out["compiles_in_window"] = {"value": len(run["compiles_in_window"]),
+                                 "limit": 0}
+    return out
+
+
 def outcome(run: dict) -> dict:
     """attempted / failed over requests due in the window whose outcome is
     known (those still running when the run ended are neither), and whether
@@ -135,8 +156,7 @@ def outcome(run: dict) -> dict:
     failed = [r for r in known if not r["ok"]]
     for r in failed[:5]:
         log(f"failed request {r['i']}: {r['error']}")
-    correct = (run["reference"]["gap"] <= run["reference"]["tolerance"]
-               and not run["compiles_in_window"]
+    correct = (all(c["value"] <= c["limit"] for c in compared(run).values())
                and run["device"]["platform"] == run["platform"])
     return {"correct": bool(correct), "attempted": len(known),
             "failed": len(failed)}
@@ -191,6 +211,7 @@ def result_of(manifest: dict, run: dict) -> dict:
         result["device"]["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"][:10],
                                "idle_gaps": reduced["idle_gaps"][:10]}
+    result["compared"] = compared(run)  # last in the line
     return result
 
 
@@ -210,8 +231,11 @@ def main(argv=None) -> int:
     except BenchFailure as e:
         print(f"[chipbench] no result: {e}", file=sys.stderr, flush=True)
         return 1
+    for name, c in result["compared"].items():  # stderr's last lines
+        print(f"[chipbench] compared {name}: {c['value']:.6g} "
+              f"(limit {c['limit']:.6g})", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
-    return 0 if result["correct"] else 1
+    return 0
 
 
 if __name__ == "__main__":

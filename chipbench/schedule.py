@@ -1,13 +1,20 @@
 """Traffic file + seed + seconds -> the requests a run offers.
 
-Rule: every seed offers the same work.  A traffic file fixes how many
-requests there are and the multiset of their lengths (the quantiles
-(i + 0.5) / n of its two distributions).
+Rule: every seed offers the same work.  A traffic file fixes the multiset of
+the requests' lengths (the quantiles (i + 0.5) / n of its two distributions)
+and, for an open loop, how many there are.
 
 closed loop: the request list is a sequence of blocks of `block` requests;
     every block holds the same multiset, freshly permuted by the seed.
     Clients pull from the front, so however far a run gets, it has offered
-    whole blocks of the same work plus part of one.
+    whole blocks of the same work plus part of one.  The list has no end
+    (`closed_stream`): `requests` in the traffic file is the first batch,
+    `closed_requests(traffic, seed, requests)`, drawn in one piece as it
+    always was, and past it further blocks are drawn one by one as clients
+    pull, each from a stream of its own (`closed:<seed>:<block index>`).  A
+    count, however large, is a ceiling that a faster server reaches: then
+    tokens per second cannot rise and the traced tail of the window holds
+    an idle chip (PERF.md, PR 41).
 open loop: exactly round(rate * seconds) arrivals fall in the window, uniform
     draws (a Poisson process conditioned on its count), with their own
     quantile multiset; the lead-in before and the tail after the window are
@@ -24,6 +31,7 @@ open loop: exactly round(rate * seconds) arrivals fall in the window, uniform
     median of time to first answer strays by 4-8% either way: PERF.md.)
 """
 
+import itertools
 import json
 import random
 
@@ -79,6 +87,24 @@ def closed_requests(traffic: dict, seed: int, count: int) -> list:
     while len(pairs) < count:
         pairs.extend(_permuted_pairs(traffic, block, rng))
     return [_request(i, pair, rng) for i, pair in enumerate(pairs)]
+
+
+def closed_stream(traffic: dict, seed: int):
+    """The closed loop's requests, without end: the first batch, built here
+    and now, then whole blocks for as long as a client asks.  A later
+    block's text is drawn as each request is pulled, so the load generator
+    never stops for a whole block."""
+    first = closed_requests(traffic, seed, int(traffic["requests"]))
+    block = int(traffic["block"])
+    whole = len(first) // block  # `closed_requests` gives whole blocks
+
+    def later_blocks():
+        for index in itertools.count(whole):
+            rng = random.Random(f"closed:{seed}:{index}")
+            for j, pair in enumerate(_permuted_pairs(traffic, block, rng)):
+                yield _request(index * block + j, pair, rng)
+
+    return itertools.chain(first, later_blocks())
 
 
 def open_requests(traffic: dict, seed: int, seconds: float) -> list:

@@ -48,6 +48,7 @@ def test_nothing_to_read_is_nothing_reported():
 def test_the_manifest_lists_it_for_the_closed_loop_cells():
     entry = [m for m in MANIFEST["per_layer"]
              if m["name"] == "paged_block_fill"]
-    assert len(entry) == 1 and MANIFEST["per_layer"][-1] is entry[0]
-    assert entry[0]["workloads"] == ["gpt2-large.chat",
-                                     "olmoe-1b-7b-8l.chat-long"]
+    # later PRs append their metrics after it and their cells to its list
+    assert len(entry) == 1 and entry[0] in MANIFEST["per_layer"]
+    assert {"gpt2-large.chat", "olmoe-1b-7b-8l.chat-long"} \
+        <= set(entry[0]["workloads"])

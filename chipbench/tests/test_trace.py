@@ -81,6 +81,21 @@ def test_a_trace_without_a_device_plane_is_refused():
         trace.reduce(only_host)
 
 
+def test_an_idle_capture_says_that_the_device_ran_nothing():
+    """A capture of a chip with no work holds no device plane (a server
+    whose load generator had run out of requests, until PR 41): the
+    reduction still fails, and says what that means and what was there."""
+    only_host = {"planes": [p for p in handmade()["planes"]
+                            if p["name"].startswith("/host")]}
+    with pytest.raises(ValueError) as refused:
+        trace.reduce(only_host)
+    message = str(refused.value)
+    assert message.startswith("the device ran nothing during the capture")
+    assert "/host:CPU" in message
+    with pytest.raises(ValueError, match="the device ran nothing"):
+        trace.reduce({"planes": []})
+
+
 @pytest.mark.skipif(not os.path.exists(RECORDED),
                     reason="no recorded trace in this checkout")
 def test_reduction_of_the_recorded_trace():

@@ -123,8 +123,12 @@ def reduce(trace: dict) -> dict:
     planes = trace["planes"]
     device_planes = [p for p in planes if DEVICE_PLANE.match(p["name"])]
     if not device_planes:
-        raise ValueError("the trace has no /device:TPU:<n> plane: "
-                         + ", ".join(p["name"] for p in planes))
+        raise ValueError(
+            "the device ran nothing during the capture: the trace has no "
+            "/device:TPU:<n> plane, only "
+            + (", ".join(p["name"] for p in planes) or "no plane at all")
+            + ".  A server that had no request left to answer in the "
+            "window's last seconds gives such a trace")
     every = [(s, s + d) for p in planes for line in p["lines"]
              for _, s, d in line["events"]]
     t0, t1 = min(s for s, _ in every), max(e for _, e in every)
