@@ -568,6 +568,10 @@ class GenerationEngine:
         # launch (3.1 GB a launch for gpt2-large, ROADMAP A9).  Under
         # a mesh, leaves that arrive sharded (shard_params) keep their
         # shardings and whatever is still on the host is replicated.
+        # A leaf rests in the dtype its model reads it in, where the
+        # model says which that is (`config.resident_dtypes`): a program
+        # handed float32 leaves that it multiplies in bfloat16 rebuilds
+        # their bfloat16 twin on every call (ROADMAP A8).
         from kfserving_tpu import startup
         from kfserving_tpu.engine import param_cache
 
@@ -576,12 +580,23 @@ class GenerationEngine:
             from jax.sharding import NamedSharding, PartitionSpec
 
             replicated = NamedSharding(mesh, PartitionSpec())
+        stored = (self.variables, self.draft_variables)
         self.variables, self.draft_variables = \
-            param_cache.place_on_device(
-                (self.variables, self.draft_variables), replicated)
+            param_cache.place_on_device(stored, replicated, tuple(
+                _read_dtypes(model, tree) for model, tree in
+                zip((module, self._draft_module), stored)))
         self._params_resident_bytes = param_cache.device_resident_bytes(
             (self.variables, self.draft_variables))
+        narrowed_leaves, self._params_narrowed_bytes = \
+            param_cache.narrowed(
+                stored, (self.variables, self.draft_variables))
         startup.mark("params_device")
+        logger.info(
+            "%s: parameters resident, %d bytes; %d leaves narrowed to the "
+            "dtype they are read in, %d bytes saved", name,
+            self._params_resident_bytes, narrowed_leaves,
+            self._params_narrowed_bytes)
+        del stored
 
         if mesh is not None:
             # Tensor parallelism: the cache shards on the heads axis,
@@ -1285,6 +1300,7 @@ class GenerationEngine:
             "cache_bytes": self.cache_bytes(),
             "recurrent_state_bytes": self.recurrent_state_bytes,
             "params_resident_bytes": self._params_resident_bytes,
+            "params_narrowed_bytes": self._params_narrowed_bytes,
             "active_params": self._active_params,
             "decode_device_s": round(self._decode_device_s, 4),
             "decode_wait_s": round(self._decode_wait_s, 4),
@@ -4036,3 +4052,16 @@ def _pow2_buckets(max_seq: int) -> List[int]:
         b *= 2
     out.append(max_seq)
     return out
+
+
+def _read_dtypes(module, variables):
+    """The dtype `module`'s programs read each leaf of `variables` in,
+    as its config declares it (`resident_dtypes`, models/decoder.py);
+    the stored dtypes for a model that declares nothing."""
+    import jax
+
+    declare = getattr(getattr(module, "config", None),
+                      "resident_dtypes", None)
+    if declare is None:
+        return jax.tree.map(lambda leaf: leaf.dtype, variables)
+    return declare(variables)

@@ -301,15 +301,35 @@ def load_or_materialize(architecture: str, arch_kwargs: Optional[Dict],
     return variables, source
 
 
-def place_on_device(tree: Any, sharding=None) -> Any:
+def _narrows(stored, read) -> bool:
+    """A float leaf stored wider than it is read."""
+    import jax.numpy as jnp
+
+    return (jnp.issubdtype(stored, jnp.floating)
+            and jnp.issubdtype(read, jnp.floating)
+            and np.dtype(read).itemsize < np.dtype(stored).itemsize)
+
+
+def place_on_device(tree: Any, sharding=None, dtypes: Any = None) -> Any:
     """`tree` with every host leaf (`np.ndarray`, so the memmap views
     above too) put on the device in ONE `jax.device_put`, waited for
     so the caller's time-to-ready includes the transfer.  Leaves that
-    are already `jax.Array`s are returned as they are, shardings
-    included (a `shard_params` tree passes through untouched).
+    are already `jax.Array`s stay where they are, shardings included
+    (a `shard_params` tree passes through).
 
     sharding: where host leaves go; None is the default device,
         uncommitted, like a `jnp.zeros` allocation.
+    dtypes: a tree like `tree` of the dtype the model's programs read
+        each leaf in (a config's `resident_dtypes`), or None.  A float
+        leaf stored wider than it is read rests in the read dtype: the
+        programs would convert it on every call otherwise, and nothing
+        else reads it.  It is narrowed on its devices, by the `convert`
+        the programs would have run (round to nearest even), and the
+        wide copy this call put there is freed at once; a
+        `ShapeDtypeStruct` narrows as a shape.  (Narrowing the mapped
+        pages on the host first would halve the transfer and was no
+        faster on the chip's machines: PERF.md, PR 42.)  The stored
+        bytes (the cache entry, the caller's tree) are not touched.
     """
     import jax
 
@@ -317,11 +337,35 @@ def place_on_device(tree: Any, sharding=None) -> Any:
     host = [i for i, leaf in enumerate(leaves)
             if isinstance(leaf, np.ndarray)]
     if host:
-        placed = jax.block_until_ready(
-            jax.device_put([leaves[i] for i in host], sharding))
+        placed = jax.device_put([leaves[i] for i in host], sharding)
         for i, leaf in zip(host, placed):
             leaves[i] = leaf
-    return jax.tree.unflatten(treedef, leaves)
+    if dtypes is not None:
+        for i, read in enumerate(treedef.flatten_up_to(dtypes)):
+            leaf = leaves[i]
+            if not _narrows(leaf.dtype, read):
+                continue
+            if isinstance(leaf, jax.ShapeDtypeStruct):
+                leaves[i] = jax.ShapeDtypeStruct(
+                    leaf.shape, read, sharding=leaf.sharding)
+            else:
+                leaves[i] = leaf.astype(read)
+                if i in host:
+                    leaf.delete()
+    return jax.block_until_ready(jax.tree.unflatten(treedef, leaves))
+
+
+def narrowed(stored: Any, placed: Any) -> Tuple[int, int]:
+    """(leaves, bytes) that `place_on_device` narrowed: the leaves of
+    `placed` whose dtype is not their `stored` twin's, and the bytes
+    that saved."""
+    import jax
+
+    saved = [a.size * (a.dtype.itemsize - b.dtype.itemsize)
+             for a, b in zip(jax.tree.leaves(stored),
+                             jax.tree.leaves(placed))
+             if a.dtype != b.dtype]
+    return len(saved), sum(saved)
 
 
 def device_resident_bytes(tree: Any) -> int:

@@ -57,6 +57,11 @@ class StateCache(NamedTuple):
     arrays: Tuple[Tuple[Tuple[int, ...], Any], ...]
 
 
+# The modules of DecoderLM that read their parameters in `config.dtype`.
+_READ_IN_DTYPE = frozenset({"wte", "wpe", "query", "key", "value", "out",
+                            "mlp_in", "mlp_out"})
+
+
 class DecoderConfig:
     def __init__(self, vocab_size=32000, hidden_size=768, num_layers=12,
                  num_heads=12, intermediate_size=3072, max_seq=1024,
@@ -82,6 +87,25 @@ class DecoderConfig:
 
     def cache_layers(self):
         return [KVCache(self.num_heads, self.head_dim)] * self.num_layers
+
+    def resident_dtypes(self, variables):
+        """A tree like `variables` whose leaves are the dtype the
+        programs read that leaf in: what the engine may keep resident
+        in its place (engine/param_cache.place_on_device).  Every Dense
+        and Embed below casts its kernel, bias or table to `dtype`
+        before it multiplies, adds or gathers (Flax's `promote_dtype`),
+        so those rest in `dtype`; LayerNorm multiplies by `scale` and
+        adds `bias` in float32 whatever `dtype` is, so they, and any
+        leaf this class does not know, rest as stored."""
+        import jax
+
+        read = jnp.dtype(self.dtype)
+
+        def of(path, leaf):
+            names = {getattr(key, "key", None) for key in path}
+            return read if names & _READ_IN_DTYPE else jnp.dtype(leaf.dtype)
+
+        return jax.tree_util.tree_map_with_path(of, variables)
 
 
 def cached_attention(q, k, v, *, cache=None, positions=None,

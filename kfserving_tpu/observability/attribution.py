@@ -185,17 +185,21 @@ def publish_cache_gauges(model: str, stats: Dict[str, Any]) -> Set[str]:
     """Promote an engine stats dict's paged-pool ratios and resident
     parameter bytes into registry gauges at /metrics scrape time (the
     roofline.publish_gauges shape).  Returns the consumed TOP-LEVEL
-    stat keys — `params_resident_bytes` alone: the `paged` dict keeps
+    stat keys — the two `params_*_bytes`: the `paged` dict keeps
     its legacy per-key export (tests and dashboards read
     `kfserving_tpu_engine_paged{bucket=...}`), the ratio gauges are
     published IN ADDITION so the `_ratio` unit contract holds."""
     consumed: Set[str] = set()
     try:
-        resident = stats.get("params_resident_bytes")
-        if isinstance(resident, (int, float)):
-            obs.generator_params_resident_bytes().labels(
-                model=model).set(float(resident))
-            consumed.add("params_resident_bytes")
+        for key, gauge in (
+                ("params_resident_bytes",
+                 obs.generator_params_resident_bytes),
+                ("params_narrowed_bytes",
+                 obs.generator_params_narrowed_bytes)):
+            value = stats.get(key)
+            if isinstance(value, (int, float)):
+                gauge().labels(model=model).set(float(value))
+                consumed.add(key)
         paged = stats.get("paged")
         if isinstance(paged, dict):
             occ = paged.get("pool_occupancy_ratio")

@@ -410,6 +410,15 @@ def generator_params_resident_bytes():
         "was built, not handed over from the host with every launch")
 
 
+def generator_params_narrowed_bytes():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_params_narrowed_bytes",
+        "Bytes the placement saved by keeping parameter leaves (target "
+        "and draft model) in the dtype their programs read them in "
+        "rather than the wider one they are stored in; 0 for a model "
+        "that stores what it reads")
+
+
 def generator_decode_kv_blocks_walked_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_decode_kv_blocks_walked_total",

@@ -189,7 +189,9 @@ async def smoke() -> List[str]:
     obs.generator_pool_fragmentation_ratio().labels(
         model="metrics-probe").set(0.18)
     obs.generator_params_resident_bytes().labels(
-        model="metrics-probe").set(3.1e9)
+        model="metrics-probe").set(1.55e9)
+    obs.generator_params_narrowed_bytes().labels(
+        model="metrics-probe").set(1.55e9)
     obs.generator_decode_kv_blocks_walked_total().labels(
         model="metrics-probe").inc(640)
     obs.generator_decode_kv_context_tokens_total().labels(

@@ -386,7 +386,8 @@ def test_grouped_query_paged_kernel_compiles_for_described_v5e(v5e):
 def _decode_program(v5e, monkeypatch, serving: dict):
     """(the 16-step decode program of a benchmarked configuration's
     serving settings with the Pallas paged kernel, as the chip's compiler
-    sees it; the engine's pool shape; its parameter shapes)."""
+    sees it, compiled from the parameters as the engine keeps them
+    resident; the engine's pool shape; the parameter shapes as stored)."""
     from jax.sharding import SingleDeviceSharding
 
     from kfserving_tpu.engine.generator import GenerationEngine
@@ -419,7 +420,7 @@ def _decode_program(v5e, monkeypatch, serving: dict):
 
         i32, f32 = jnp.int32, jnp.float32
         compiled = engine._decode.lower(
-            on_chip(shapes), on_chip(engine._caches),
+            on_chip(engine.variables), on_chip(engine._caches),
             arg(i32, s, engine.blocks_per_slot), arg(i32, s), arg(i32, s),
             arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s)).compile()
         return compiled, engine._cache_shape, shapes
@@ -456,7 +457,8 @@ def _prefill_program(v5e, monkeypatch, serving: dict, rows: int):
 
         i32, f32 = jnp.int32, jnp.float32
         return engine._prefill.lower(
-            shapes, arg(i32, rows, max(serving["prefill_buckets"])),
+            engine.variables,
+            arg(i32, rows, max(serving["prefill_buckets"])),
             arg(i32, rows), arg(f32, rows), arg(i32, rows), arg(f32, rows),
             arg(i32, rows)).compile()
     finally:
@@ -585,6 +587,23 @@ def test_olmoe_prefill_program_fits_the_described_v5e(v5e, monkeypatch,
         0.373e9 if way == "kernel" else 0.45e9), memory
 
 
+def _gpt2_large_serving() -> dict:
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "gpt2-large.json")) as f:
+        return json.load(f)["serving"]
+
+
+def _parameter_converts(compiled) -> list:
+    """The `convert`s of a compiled `gpt2-large` program whose result has
+    the shape of a parameter matrix or table: the per-call bfloat16 twin
+    of float32 parameters, where a program is handed those."""
+    matrix = re.compile(
+        r"bf16\[(1280,20,64|20,64,1280|1280,5120|5120,1280|50257,1280"
+        r"|1024,1280)\]")
+    return [line.strip()[:160] for line in compiled.as_text().splitlines()
+            if " convert(" in line and matrix.search(line.split(" convert(")[0])]
+
+
 @pytest.mark.parametrize("cache_blocks", [144, 192])
 def test_gpt2_large_decode_program_fits_the_described_v5e(
         v5e, monkeypatch, cache_blocks):
@@ -592,12 +611,10 @@ def test_gpt2_large_decode_program_fits_the_described_v5e(
     64) at the benchmarked 144 blocks and at the 192 the chip refused
     while the kernel read a padded twin of every layer's pool (9.16 GiB
     of temporaries): no pool is copied, neither into another layout nor
-    through VMEM, and what is left of the temporaries is the bfloat16
-    twin of the float32 parameters, which XLA converts once a call,
-    outside the 16 steps."""
-    with open(os.path.join(REPO, "chipbench", "configs",
-                           "gpt2-large.json")) as f:
-        serving = {**json.load(f)["serving"], "cache_blocks": cache_blocks}
+    through VMEM, and no parameter is converted: the float32 matrices
+    as stored rest in bfloat16 (1.44 GiB fewer arguments), so the twin
+    that was 1.44 of the program's 1.55 GiB of temporaries is gone."""
+    serving = {**_gpt2_large_serving(), "cache_blocks": cache_blocks}
     compiled, pool, shapes = _decode_program(v5e, monkeypatch, serving)
     assert pool[0] == cache_blocks
     # A layer's two Mosaic calls: the step's write, then attention.
@@ -609,14 +626,27 @@ def test_gpt2_large_decode_program_fits_the_described_v5e(
     assert _pool_copies(compiled, pool) == []
     memory = compiled.memory_analysis()
     print(f"gpt2-large decode program, {cache_blocks} blocks: {memory}")
-    float32 = sum(x.size for x in jax.tree.leaves(shapes)
-                  if x.dtype == jnp.float32)
-    assert float32 > 7.7e8  # all of them: 3.1 GB as stored
-    assert memory.temp_size_in_bytes - 2 * float32 < 0.5 * 2**30, memory
-    assert memory.temp_size_in_bytes < 1.6 * 2**30, memory  # 1.55 at PR 27
+    assert {x.dtype.name for x in jax.tree.leaves(shapes)} == {"float32"}
+    assert sum(x.size for x in jax.tree.leaves(shapes)) > 7.7e8  # 3.1 GB
+    assert _parameter_converts(compiled) == []
+    assert memory.temp_size_in_bytes < 0.2 * 2**30, memory  # 1.55 at PR 27
     if cache_blocks == 144:
-        assert abs(memory.argument_size_in_bytes / 2**30 - 6.05) < 0.01
+        # 1.44 GiB of parameters + 3.16 of pool; 6.05 with float32
+        assert abs(memory.argument_size_in_bytes / 2**30 - 4.61) < 0.02
     assert _program_bytes(memory) < 15.75 * 2**30, memory
+
+
+def test_gpt2_large_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
+    """Its (16, 512) prefill, the widest `warm_rows` brings in: a
+    dispatch converts no parameter either, so its temporaries are the
+    activations' alone."""
+    compiled = _prefill_program(v5e, monkeypatch, _gpt2_large_serving(), 16)
+    assert _parameter_converts(compiled) == []
+    memory = compiled.memory_analysis()
+    print(f"gpt2-large (16, 512) prefill program: {memory}")
+    assert 1.4 < memory.argument_size_in_bytes / 2**30 < 1.5, memory
+    assert memory.temp_size_in_bytes < 0.3 * 2**30, memory  # 0.27
+    assert _program_bytes(memory) + 3.4e9 < 15.75 * 2**30, memory
 
 
 def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):
