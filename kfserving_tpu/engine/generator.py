@@ -225,6 +225,7 @@ class GenerationEngine:
                  pipeline_depth: int = 2,
                  block_size: Optional[int] = None,
                  cache_blocks: Optional[int] = None,
+                 window_cache_blocks: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  host_tier_blocks: Optional[int] = None,
                  host_tier_dir: Optional[str] = None,
@@ -315,13 +316,21 @@ class GenerationEngine:
                      if isinstance(c, KVCache)]
         self._has_state = any(isinstance(c, StateCache)
                               for c in self._cache_layers)
-        if not kv_layers or len(set(kv_layers)) != 1:
+        geometries = {(c.heads, c.head_dim) for c in kv_layers}
+        windows = {c.window for c in kv_layers}
+        if len(geometries) != 1 or None not in windows or len(windows) > 2:
             raise InvalidInput(
                 "the engine pages K/V: a model needs at least one K/V "
-                f"layer, and all of one geometry; {name!r} declares "
-                f"{sorted(set(kv_layers))}")
-        kv_heads, kv_head_dim = kv_layers[0]
+                "layer that keeps its whole context, all K/V layers of "
+                "one geometry, and its sliding-window layers of one "
+                f"window; {name!r} declares {sorted(set(kv_layers), key=str)}")
+        (kv_heads, kv_head_dim), = geometries
         n_kv_layers = len(kv_layers)
+        # Sliding-window layers keep a ring of blocks a sequence in a
+        # pool of their own kind (ops/paged_attention.py); None for a
+        # model without any.
+        self._window = max(windows - {None}, default=None)
+        n_window_layers = sum(c.window is not None for c in kv_layers)
         # -- the KV cache: a block pool ---------------------------------
         # A shared pool [NB, BS, H*D] a layer (ops/paged_attention.py
         # owns the layout) + per-slot block tables — HBM scales with
@@ -355,6 +364,27 @@ class GenerationEngine:
         pool_shape = paged_attention.pool_shape(
             self.num_blocks, bs, kv_heads, kv_head_dim)
         self._cache_shape = pool_shape
+        # The window pool: every window layer's K and V are
+        # [num_window_blocks, BS, H*D], one table [slots, ring] for them
+        # all.  A sequence never holds more than its ring, whatever its
+        # length, so a ring for every slot can never run out; more than
+        # that (`window_cache_blocks`) is room for the blocks of finished
+        # requests that wait out the zombie-wave deferral.
+        self.window_blocks_per_slot = self.num_window_blocks = 0
+        if self._window is not None:
+            self.window_blocks_per_slot = paged_attention.ring_blocks(
+                self._window, bs)
+            self.num_window_blocks = int(
+                window_cache_blocks
+                or self.max_slots * self.window_blocks_per_slot)
+            if self.num_window_blocks < self.window_blocks_per_slot:
+                raise InvalidInput(
+                    f"window_cache_blocks {self.num_window_blocks} is "
+                    f"less than one sequence's ring of "
+                    f"{self.window_blocks_per_slot} blocks (window "
+                    f"{self._window}, block_size {bs})")
+        window_pool_shape = paged_attention.pool_shape(
+            self.num_window_blocks, bs, kv_heads, kv_head_dim)
 
         def layer_cache(kind):
             """One layer's arrays: its two pools, its state with the
@@ -363,8 +393,10 @@ class GenerationEngine:
             decode wave, so a reused slot's old state is overwritten
             before anything reads it), or none."""
             if isinstance(kind, KVCache):
-                return (jnp.zeros(pool_shape, cache_dtype),
-                        jnp.zeros(pool_shape, cache_dtype))
+                shape = (pool_shape if kind.window is None
+                         else window_pool_shape)
+                return (jnp.zeros(shape, cache_dtype),
+                        jnp.zeros(shape, cache_dtype))
             if isinstance(kind, StateCache):
                 return tuple(jnp.zeros((self.max_slots,) + tuple(shape),
                                        dtype)
@@ -390,6 +422,20 @@ class GenerationEngine:
             (self.max_slots, self.blocks_per_slot), -1, np.int32)
         self._free_blocks: deque = deque(range(self.num_blocks))
         self._block_ref = np.zeros(self.num_blocks, np.int64)
+        # The window pool's allocator: a slot's ring table (block j of
+        # the sequence in column j % ring), the free list, and how many
+        # of its sequence's blocks each slot's ring has taken in so far.
+        # A window block is never shared, so it needs no reference count.
+        self._win_tables = np.full(
+            (self.max_slots, self.window_blocks_per_slot), -1, np.int32)
+        self._win_free: deque = deque(range(self.num_window_blocks))
+        self._win_covered = np.zeros(self.max_slots, np.int64)
+        self.window_blocks_recycled = 0
+        if self._window is not None:
+            for pool, blocks in (("global", self.num_blocks),
+                                 ("window", self.num_window_blocks)):
+                obs.generator_kv_pool_blocks().labels(
+                    model=name, pool=pool).set(blocks)
         # chain-hash -> block id for FULL prompt blocks (prefix
         # reuse); zero-ref registered blocks linger in
         # _reclaimable (LRU) until allocation pressure evicts.
@@ -563,6 +609,32 @@ class GenerationEngine:
                     f"{name!r} has recurrent state, whose prefill has run "
                     f"on the chip at few shapes only: {unproven}")
 
+        # A ring holds a position wherever it falls, so the position
+        # sentinel that parks a row (a chunk's padding, a verify wave's
+        # rows that take no draft) has a place in a live ring and would
+        # overwrite it; and a spilled prefix is the whole-context
+        # layers' blocks alone.  A shared prefix likewise stands for no
+        # ring: every plan of a window model is a miss, and is counted.
+        if self._window is not None:
+            for setting, on, why in (
+                    ("speculative", self.spec_tokens > 0,
+                     "a verify wave parks the rows it does not draft for "
+                     "on a position sentinel, which a ring gives a place "
+                     "and lets overwrite live keys"),
+                    ("prefill_chunk_tokens",
+                     self.prefill_chunk_tokens is not None,
+                     "a chunk's padding parks on a position sentinel, "
+                     "which a ring gives a place, and a chunk of more "
+                     "than a block overwrites keys its first queries "
+                     "still see"),
+                    ("host_tier_blocks", self.kv_tier is not None,
+                     "a spilled prefix is the whole-context layers' "
+                     "blocks, and the rings at its end are kept nowhere")):
+                if on:
+                    raise InvalidInput(
+                        f"{setting} is not served for {name!r}, a model "
+                        f"with sliding-window layers: {why}")
+
         # Parameters are resident from here on, like the pool: a host
         # leaf handed to a jitted call is transferred again on every
         # launch (3.1 GB a launch for gpt2-large, ROADMAP A9).  Under
@@ -648,11 +720,22 @@ class GenerationEngine:
                 chose["elsewhere"] = module.routed_elsewhere(state)
             return out, chose
 
+        has_window = self._window is not None
+
+        def by_pool(per_pool, kind):
+            """A K/V layer's own of a dispatch's tables (or insert
+            destinations): one array for a model whose layers all keep
+            their whole context, (whole-context, ring) for one with
+            sliding-window layers."""
+            if not has_window:
+                return per_pool
+            return per_pool[0 if kind.window is None else 1]
+
         def with_table(caches, table):
             """The caches as the model takes them: a K/V layer's pools
-            with this dispatch's block table."""
-            return [layer + (table,) if isinstance(kind, KVCache)
-                    else layer
+            with this dispatch's block table for its pool."""
+            return [layer + (by_pool(table, kind),)
+                    if isinstance(kind, KVCache) else layer
                     for kind, layer in zip(cache_kinds, caches)]
 
         def mask_to_support(logits, top_ks, top_ps):
@@ -916,14 +999,16 @@ class GenerationEngine:
             """Scatter a prefill batch's k/v into pool blocks.
             dest_blocks [B, chunks] int32; -1 chunks drop (bucket
             padding rows, and prefix-cache hits whose shared blocks
-            already hold the data).  A state layer's rows go to their
+            already hold the data); for a model with sliding-window
+            layers a pair of them, the second the rings', which take
+            a prompt's last blocks alone.  A state layer's rows go to their
             slots whole (`slots` [B] int32, past-the-end for a padding
             row, which drops)."""
             out = []
             for kind, layer, new in zip(cache_kinds, caches, new_caches):
                 if isinstance(kind, KVCache):
                     layer = paged_attention.paged_insert(
-                        *layer, *new, dest_blocks, None)
+                        *layer, *new, by_pool(dest_blocks, kind), None)
                 elif isinstance(kind, StateCache):
                     layer = tuple(
                         old.at[slots].set(x.astype(old.dtype), mode="drop")
@@ -1014,6 +1099,10 @@ class GenerationEngine:
         # live rows' contexts, and the tokens in them (_distribute).
         self._kv_blocks_walked = 0
         self._kv_context_tokens = 0
+        # The same of a sliding-window layer: min(context, window) rows,
+        # in the ring's columns the walk reads.
+        self._win_blocks_walked = 0
+        self._win_context_tokens = 0
         # The row count prefill dispatches are held to: configured
         # (`prefill_rows`: the deployment knows what fits beside its
         # parameters), or learned once the runtime has refused one for
@@ -1064,6 +1153,9 @@ class GenerationEngine:
         self._kv_bytes_per_token = (2 * n_kv_layers * kv_heads
                                     * kv_head_dim
                                     * np.dtype(cache_dtype).itemsize)
+        # Of the K/V layers, the share that reads min(context, window)
+        # rows and not the context (`_attended`).
+        self._window_layer_share = n_window_layers / n_kv_layers
         from kfserving_tpu.engine.jax_engine import device_peak_flops
         from kfserving_tpu.observability.profiling.roofline import (
             device_peak_hbm_bw,
@@ -1381,6 +1473,27 @@ class GenerationEngine:
                 "evictions": dict(self.block_evictions),
                 "preemptions": self.preemptions,
             }
+            if self._window is not None:
+                # Each pool's own books: capacity, the share of it that
+                # slots' tables hold, and of the rows its decode walk
+                # read the share that held context.
+                out["paged"]["pools"] = {
+                    "global": {
+                        "blocks": self.num_blocks,
+                        "fill": out["paged"]["pool_occupancy_ratio"],
+                        "block_fill": out["kv_block_fill"]},
+                    "window": {
+                        "blocks": self.num_window_blocks,
+                        "fill": round(
+                            int(np.sum(self._win_tables >= 0))
+                            / self.num_window_blocks, 4),
+                        "block_fill": round(
+                            self._win_context_tokens / max(
+                                1, self._win_blocks_walked
+                                * self.block_size), 4),
+                        "window": self._window,
+                        "blocks_per_slot": self.window_blocks_per_slot,
+                        "recycled": self.window_blocks_recycled}}
         if self.kv_tier is not None:
             out["paged"]["host_tier_tokens_saved"] = \
                 self.host_tier_tokens_saved
@@ -1578,19 +1691,24 @@ class GenerationEngine:
         with self._block_lock:
             blocks = [int(b) for b in self._tables[slot] if b >= 0]
             self._tables[slot, :] = -1
-        if blocks:
+            ring = [int(b) for b in self._win_tables[slot] if b >= 0]
+            self._win_tables[slot, :] = -1
+            self._win_covered[slot] = 0
+        if blocks or ring:
             self._deferred_frees.append(
-                (self.decode_steps + self.pipeline_depth + 1, blocks))
+                (self.decode_steps + self.pipeline_depth + 1, blocks,
+                 ring))
 
     def _process_deferred_frees(self, force: bool = False) -> None:
         released = 0
         while self._deferred_frees and (
                 force or self._deferred_frees[0][0] <= self.decode_steps):
-            _, blocks = self._deferred_frees.popleft()
+            _, blocks, ring = self._deferred_frees.popleft()
             released += len(blocks)
             with self._block_lock:
                 for blk in blocks:
                     self._unref_block_locked(blk)
+                self._win_free.extend(ring)
         if released:
             # The normal release path: every slot block matures through
             # the zombie-wave deferral window exactly once.
@@ -2040,18 +2158,23 @@ class GenerationEngine:
         dispatch enqueues."""
         import hashlib
 
-        if self._has_state:
-            # No block stands for a prefix of a recurrence: the plan is
-            # a miss whatever the index holds, registers nothing, and
-            # says so.
-            force_miss = True
-            self.prefix_reuse_refused += 1
-            obs.generator_prefix_reuse_refused_total().labels(
-                model=self.name).inc()
         bs = self.block_size
         n = int(req.prompt_ids.size)
         full = n // bs
         total = (n + bs - 1) // bs
+        ring = min(total, self.window_blocks_per_slot)
+        if len(self._win_free) < ring:
+            # The rings' pool first, before anything is taken: only this
+            # thread allocates, so what is free now is free below.
+            return None
+        if self._has_state or self._window is not None:
+            # No block stands for a prefix of a recurrence, nor for the
+            # rings at a prefix's end: the plan is a miss whatever the
+            # index holds, registers nothing, and says so.
+            force_miss = True
+            self.prefix_reuse_refused += 1
+            obs.generator_prefix_reuse_refused_total().labels(
+                model=self.name).inc()
         dest: List[int] = []
         taken: List[int] = []
         fresh_regs: List[Tuple[bytes, int]] = []
@@ -2233,12 +2356,19 @@ class GenerationEngine:
                         # duplicate block for no gain.
                         if self._prefix_index.get(chain) is None:
                             chunk_regs[c] = (chain, blk)
-                    elif not self._has_state:
+                    elif not (self._has_state
+                              or self._window is not None):
                         self._prefix_index[chain] = blk
                         self._block_chain[blk] = chain
                         fresh_regs.append((chain, blk))
             if chunk_regs is None:
                 self._plan_regs[slot] = fresh_regs
+            # The rings take the prompt's last blocks, block j in column
+            # j % ring: the insert writes those and drops the rest.
+            for j in range(total - ring, total):
+                self._win_tables[slot, j % self.window_blocks_per_slot] = \
+                    self._win_free.popleft()
+            self._win_covered[slot] = total
             if host_faults:
                 # Claimed under the lock; the caller MUST drain these
                 # (one tier read + one pool insert dispatch on the
@@ -2331,9 +2461,34 @@ class GenerationEngine:
                 # cur and only increases, so it IS the table's block
                 # count for this stream now.
                 s.req.blocks_held = max(s.req.blocks_held, grown)
+                if ok and self._window is not None:
+                    ok = self._grow_ring_locked(i, need)
                 if not ok:
                     failed.append(i)
         return failed
+
+    def _grow_ring_locked(self, slot: int, need: int) -> bool:
+        """Take the sequence's blocks up to `need` into the slot's ring:
+        a column not held yet gets a block of the window pool; one that
+        is held is recycled, its block's oldest rows overwritten by the
+        positions to come, with nothing to tell the device (the table
+        does not change).  False where the pool has no block (its freed
+        ones wait out the deferral: the caller holds)."""
+        ring = self.window_blocks_per_slot
+        recycled = 0
+        for j in range(int(self._win_covered[slot]), need):
+            if self._win_tables[slot, j % ring] >= 0:
+                recycled += 1
+            elif self._win_free:
+                self._win_tables[slot, j % ring] = self._win_free.popleft()
+            else:
+                break
+            self._win_covered[slot] = j + 1
+        if recycled:
+            self.window_blocks_recycled += recycled
+            obs.generator_window_blocks_recycled_total().labels(
+                model=self.name).inc(recycled)
+        return self._win_covered[slot] >= need
 
     def _table_device(self):
         """Device copy of the block tables for a dispatch."""
@@ -2341,7 +2496,11 @@ class GenerationEngine:
             # Copy under the lock: cancel() clears rows on the loop
             # thread while waves enqueue on the enqueue thread.
             snap = self._tables.copy()
-        return self._jnp.asarray(snap)
+            ring = (None if self._window is None
+                    else self._win_tables.copy())
+        if ring is None:
+            return self._jnp.asarray(snap)
+        return self._jnp.asarray(snap), self._jnp.asarray(ring)
 
     def _record_pool_sample(self) -> None:
         """Occupancy counter sample for the event timeline (rendered
@@ -3501,7 +3660,7 @@ class GenerationEngine:
                 n = int(req.prompt_ids.size)
                 self._prefill_flops += (
                     self._flops_matmul_per_token * n
-                    + self._attn_flops_coeff * n * (n + 1) / 2.0)
+                    + self._attn_flops_coeff * self._attended(n, True))
             rec = self._prefill_bucket_tokens.setdefault(bucket,
                                                          [0.0, 0.0])
             rec[0] += sum(int(r.prompt_ids.size) for r in group)
@@ -3532,6 +3691,19 @@ class GenerationEngine:
             for i, row in enumerate(dest_rows):
                 dest[i, :len(row)] = row
             dest_d = jnp.asarray(dest)
+            if self._window is not None:
+                # The rings take each prompt's last blocks alone, block
+                # j into the column the plan gave it.
+                ring = self.window_blocks_per_slot
+                ring_dest = np.full((b_bucket, chunks), -1, np.int32)
+                with self._block_lock:
+                    for i, (req, slot) in enumerate(zip(group, slots)):
+                        total = -(-int(req.prompt_ids.size)
+                                  // self.block_size)
+                        for j in range(max(0, total - ring), total):
+                            ring_dest[i, j] = \
+                                self._win_tables[slot, j % ring]
+                dest_d = (dest_d, jnp.asarray(ring_dest))
         with self._inflight.launch("insert", rows=b):
             self._caches = self._insert(self._caches, new_caches,
                                         dest_d, slot_d)
@@ -3642,6 +3814,20 @@ class GenerationEngine:
         else:
             s.last_token = token
 
+    def _attended(self, n: int, prefill: bool = False):
+        """Key rows a K/V layer reads for a query at context `n`, on
+        average over the model's K/V layers (a sliding-window layer
+        reads min(n, window)); with `prefill`, summed over a prompt's
+        queries 1..n.  `n` itself, or the triangle, for a model without
+        window layers."""
+        whole = n * (n + 1) / 2.0 if prefill else n
+        if self._window is None:
+            return whole
+        w = min(n, self._window)
+        seen = w * (w + 1) / 2.0 + (n - w) * w if prefill else w
+        share = self._window_layer_share
+        return (1.0 - share) * whole + share * seen
+
     def _distribute(self, tokens: np.ndarray, lp, snapshot,
                     device_ms: float = 0.0):
         """tokens [S, K]: deliver each slot's chunk in order.  A slot
@@ -3682,7 +3868,7 @@ class GenerationEngine:
             # is < K positions, noise against the ±10% stats bar).
             self._decode_flops += k * (self._flops_matmul_per_token
                                        + self._attn_flops_coeff
-                                       * s.length)
+                                       * self._attended(s.length))
             starts.append(s.length)
             n_lp = s.req.logprobs
             for j in range(k):
@@ -3719,7 +3905,24 @@ class GenerationEngine:
             # working set the HBM-utilization gauge divides by peak.
             self._decode_hbm_bytes += k * (
                 self._param_read_bytes
-                + sum(starts) * self._kv_bytes_per_token)
+                + sum(self._attended(n) for n in starts)
+                * self._kv_bytes_per_token)
+            if self._window is not None:
+                # A window layer reads min(context, window) rows, in the
+                # columns of its ring that the sequence has reached.
+                ring = self.window_blocks_per_slot
+                win_tokens = int(np.minimum(context, self._window).sum())
+                win_blocks = int(np.minimum(
+                    -(-context // self.block_size), ring).sum())
+                self._win_context_tokens += win_tokens
+                self._win_blocks_walked += win_blocks
+                for pool, read, walked in (
+                        ("global", tokens_read, blocks),
+                        ("window", win_tokens, win_blocks)):
+                    obs.generator_decode_kv_pool_context_tokens_total(
+                        ).labels(model=self.name, pool=pool).inc(read)
+                    obs.generator_decode_kv_pool_blocks_walked_total(
+                        ).labels(model=self.name, pool=pool).inc(walked)
 
     # -- speculative decoding ----------------------------------------------
     async def _spec_or_fallback_wave(self, loop, inflight) -> None:

@@ -50,6 +50,11 @@ from kfserving_tpu.ops import dot_product_attention
 class KVCache(NamedTuple):
     heads: int       # KV heads: fewer than the query's under GQA
     head_dim: int
+    # Sliding-window attention: a query sees this many latest keys, its
+    # own among them, and the layer keeps a ring of blocks that long a
+    # sequence (ops/paged_attention.py) in a pool of its own kind.
+    # None: the whole context.
+    window: Optional[int] = None
 
 
 class StateCache(NamedTuple):
@@ -109,7 +114,7 @@ class DecoderConfig:
 
 
 def cached_attention(q, k, v, *, cache=None, positions=None,
-                     kv_lengths=None, attn_fn=None):
+                     kv_lengths=None, attn_fn=None, window=None):
     """Attention of one block, shared by every decoder block of the zoo
     (GPT-2's here, OLMoE's in models/olmoe.py, Nemotron-H's in
     models/nemotron_h.py): q, k, v are [B, L, H, D] as projected (and,
@@ -126,6 +131,10 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
     chunk's tokens write through the table, then attend over the pool
     with per-query causal masking (earlier chunks are already resident
     — cross-chunk attention comes from the pool, exactly like decode).
+    `window` (static; None for the whole context) makes the layer a
+    sliding-window one on every branch: a query at t sees keys s with
+    t - window < s <= t, and `cache` is then the layer's ring pools and
+    ring table (ops/paged_attention.py).
     Returns (out [B, L, H, D], new_cache)."""
     lq = q.shape[1]
     group = q.shape[2] // k.shape[2]
@@ -144,17 +153,20 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
         if lq == 1:
             pool_k, pool_v = paged_write(pool_k, pool_v, k[:, 0],
                                          v[:, 0], table,
-                                         positions[:, 0])
+                                         positions[:, 0], window)
             out = paged_attention(q, pool_k, pool_v, table,
-                                  positions[:, 0] + 1)
+                                  positions[:, 0] + 1, window)
         else:
             pool_k, pool_v = paged_write(pool_k, pool_v, k, v,
-                                         table, positions)
+                                         table, positions, window)
             out = paged_prefill_attention_xla(q, pool_k, pool_v,
-                                              table, positions)
+                                              table, positions, window)
         new_cache = (pool_k, pool_v)
     elif attn_fn is not None:
         causal = jnp.tril(jnp.ones((lq, lq), jnp.bool_))[None, None]
+        if window is not None:
+            causal &= jnp.triu(jnp.ones((lq, lq), jnp.bool_),
+                               k=1 - window)[None, None]
         if kv_lengths is not None:
             pad = (jnp.arange(lq)[None, :]
                    < kv_lengths[:, None])[:, None, None, :]
@@ -168,7 +180,7 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
         # the engine's insert scatter instead of working.
     else:
         out = dot_product_attention(q, k, v, causal=True,
-                                    kv_lengths=kv_lengths)
+                                    kv_lengths=kv_lengths, window=window)
     return out, new_cache
 
 

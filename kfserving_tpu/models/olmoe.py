@@ -141,7 +141,11 @@ class ExpertLayer(nn.Module):
             logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                               param_dtype=cfg.param_dtype,
                               name="router")(x.astype(jnp.float32))
-            probs, experts = moe.route(logits, cfg.experts_per_token)
+            # Mellum's config (models/mellum.py) renormalises the kept
+            # probabilities; OLMoE's has no such key and does not.
+            probs, experts = moe.route(
+                logits, cfg.experts_per_token,
+                getattr(cfg, "norm_topk_prob", False))
             if not self.is_initializing():
                 self.sow("moe", "pairs",
                          moe.routed_pairs(experts, e, valid),

@@ -37,6 +37,8 @@ _LAZY_BUILTINS: Dict[str, Tuple[str, str]] = {
     "nemotron_h": ("kfserving_tpu.models.nemotron_h", "_create_nemotron_h"),
     "nemotron_h_tiny": ("kfserving_tpu.models.nemotron_h",
                         "_create_nemotron_h_tiny"),
+    "mellum": ("kfserving_tpu.models.mellum", "_create_mellum"),
+    "mellum_tiny": ("kfserving_tpu.models.mellum", "_create_mellum_tiny"),
 }
 
 

@@ -436,6 +436,45 @@ def generator_decode_kv_context_tokens_total():
         "read that a decode step needed")
 
 
+def generator_decode_kv_pool_blocks_walked_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_decode_kv_pool_blocks_walked_total",
+        "The same by pool, for a model with sliding-window layers "
+        "(pool=global: one whole-context layer, as the unlabelled "
+        "series; pool=window: one window layer, the columns of its "
+        "ring the walk reads, min(ceil(context / block_size), ring))")
+
+
+def generator_decode_kv_pool_context_tokens_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_decode_kv_pool_context_tokens_total",
+        "Context tokens under those blocks, by pool: the whole context "
+        "for pool=global, min(context, window) for pool=window")
+
+
+def generator_window_blocks_recycled_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_window_blocks_recycled_total",
+        "Times a sequence's growth re-used a block its ring already "
+        "held (the block that left the window) instead of taking one "
+        "from the window pool")
+
+
+def generator_kv_pool_blocks():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_kv_pool_blocks",
+        "Capacity of each KV pool of a model with sliding-window "
+        "layers, in blocks a layer (pool=global|window)")
+
+
+def generator_kv_pool_fill_ratio():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_kv_pool_fill_ratio",
+        "Blocks of each KV pool that a slot's table holds, over the "
+        "pool's capacity (pool=global|window; the global pool's is "
+        "pool_occupancy_ratio again)")
+
+
 def generator_moe_routed_pairs_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_moe_routed_pairs_total",

@@ -40,6 +40,14 @@ _FLASH_MIN_SEQ_HALF_LANE = 1024  # D % 128 != 0 pads the lane width
 # 128-lane width but measured 34 TF/s on v5e; smaller head dims waste
 # more than half the array and fall back to XLA.
 _FLASH_HEAD_DIM_MULTIPLE = 64
+# A causal prefill over a padded bucket (kv_lengths) or with a sliding
+# window goes to XLA below this length, as it always has, and to the
+# kernel from it on: the [B, H, L, L] float32 scores XLA materializes
+# are 0.5 GB a row of 32 heads at 2048 and 8.6 GB at 8192, which no
+# chip holds.  Under a causal mask a real query never sees the padding
+# behind it, so the kernel's causal and length masks together say what
+# the derived mask says on every row that is read.
+_FLASH_CAUSAL_MASKED_MIN_SEQ = 2048
 
 
 def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -80,7 +88,8 @@ def mesh_axis(mesh, name: str, dim: int) -> Optional[str]:
 
 
 def _flash(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
-           lengths: Optional[jax.Array]) -> jax.Array:
+           lengths: Optional[jax.Array],
+           window: Optional[int] = None) -> jax.Array:
     """The Pallas flash kernel, under `shard_map` when the caller runs
     inside a mesh (`jax.set_mesh`): Mosaic kernels cannot be
     partitioned automatically, and per-(batch, head) attention needs
@@ -90,7 +99,8 @@ def _flash(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 
     def kernel(q, k, v, *lens):
         return flash_attention(q, k, v, causal=causal,
-                               kv_lengths=lens[0] if lens else None)
+                               kv_lengths=lens[0] if lens else None,
+                               window=window)
 
     args = (q, k, v) if lengths is None else (q, k, v, lengths)
     mesh = jax.sharding.get_abstract_mesh()
@@ -125,7 +135,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           mask: Optional[jax.Array] = None,
                           causal: bool = False,
                           kv_lengths: Optional[jax.Array] = None,
-                          prefix_padding: bool = False
+                          prefix_padding: bool = False,
+                          window: Optional[int] = None
                           ) -> jax.Array:
     """Attention over [batch, len, heads, head_dim] tensors.
 
@@ -149,7 +160,14 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         correct on XLA and is wrong only where the declaration was
         load-bearing (the kernel), unlike kv_lengths which bakes the
         suffix form into both paths.
+    window: with `causal`, a sliding window: the query at position t
+        sees keys s with t - window < s <= t (`window` keys, its own
+        among them).  A window no shorter than the sequence is plain
+        causal attention.
     """
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal band: pass "
+                         "causal=True")
     if kv_lengths is not None and mask is not None:
         raise ValueError(
             "mask and kv_lengths are mutually exclusive: kv_lengths "
@@ -171,14 +189,20 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # (Lk - Lq + i), so the allowed region is a shifted triangle.
         causal_mask = jnp.tril(
             jnp.ones((Lq, Lk), jnp.bool_), k=Lk - Lq)[None, None, :, :]
+        plain = mask is None or kv_lengths is not None
         mask = causal_mask if mask is None else (mask & causal_mask)
+        if window is not None:
+            mask = mask & jnp.triu(jnp.ones((Lq, Lk), jnp.bool_),
+                                   k=Lk - Lq - window + 1)[None, None]
         # The Pallas kernel's causal mask assumes query i sits at absolute
         # position i, which only holds when Lq == Lk; KV-cache decode
         # (Lq < Lk, shifted triangle) must take the XLA path.  Causal +
-        # key-padding composition stays on XLA too.
-        flash_ok = (mask is causal_mask and Lq == Lk
-                    and kv_lengths is None)
-        lengths = None
+        # key-padding composition and a window stay on XLA too while
+        # XLA can hold their scores.
+        flash_ok = plain and Lq == Lk and (
+            (kv_lengths is None and window is None)
+            or Lq >= _FLASH_CAUSAL_MASKED_MIN_SEQ)
+        lengths = kv_lengths
     else:
         # Non-causal flash handles rectangular (Lq != Lk) grids and
         # key-padding lengths natively.
@@ -186,9 +210,11 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     or derived_lengths is not None)
         lengths = kv_lengths if kv_lengths is not None else derived_lengths
     use_flash = flash_ok and _flash_eligible(q)
-    log_dispatch("pallas_flash" if use_flash else "xla",
-                 q=q.shape, k=k.shape, causal=causal,
-                 kv_lengths=kv_lengths is not None)
+    shapes = dict(q=q.shape, k=k.shape, causal=causal,
+                  kv_lengths=kv_lengths is not None)
+    if window is not None:
+        shapes["window"] = window
+    log_dispatch("pallas_flash" if use_flash else "xla", **shapes)
     if use_flash:
-        return _flash(q, k, v, causal, lengths)
+        return _flash(q, k, v, causal, lengths, window)
     return _xla_attention(q, k, v, mask)

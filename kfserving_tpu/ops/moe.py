@@ -1,4 +1,5 @@
-"""Routed expert layer: top-k routing (two routers) and three ways through
+"""Routed expert layer: top-k routing (two routers, one of them with or
+without renormalised weights) and three ways through
 the experts, chosen from shapes (and where the program runs) at trace time.
 
 A mixture-of-experts MLP holds E experts and sends every token through the
@@ -103,14 +104,17 @@ GROUPED_KERNEL_PROVEN = frozenset({
 })
 
 
-def route(logits: jax.Array, k: int):
+def route(logits: jax.Array, k: int, renormalise: bool = False):
     """Router probabilities.  logits [T, E] in any dtype; the softmax runs
     over all E in float32 and the k largest are kept with their
-    probabilities as they are (not renormalised; ties go to the lowest
-    index, as `lax.top_k`).  Returns (probs [T, k] float32, experts
-    [T, k] int32)."""
+    probabilities as they are (OLMoE), or, with `renormalise`, divided by
+    their sum so that a token's weights add up to 1 (`norm_topk_prob`:
+    Mellum, the Qwen3-MoE family); ties go to the lowest index, as
+    `lax.top_k`.  Returns (probs [T, k] float32, experts [T, k] int32)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top, experts = jax.lax.top_k(probs, k)
+    if renormalise:
+        top = top / top.sum(axis=-1, keepdims=True)
     return top, experts.astype(jnp.int32)
 
 

@@ -43,6 +43,15 @@ query may bring `G` heads for each (query head j reads KV head j // G;
 their own K/V).  The kernel multiplies a KV head's G query rows by its
 block in the same product as everything else.
 
+A sliding-window layer (`window` = W, a static int; None for a layer
+that sees its whole context) keeps a RING of MB = ceil(W / BS) + 1 blocks a
+sequence and no more, whatever its length: absolute block j lives in
+column j % MB of the layer's own [B, MB] table (`ring_blocks`), a position
+past the ring's end overwrites the block that left the window, and the
+table stops changing once its columns are held.  A query at t sees key s
+iff t - W < s <= t, and those keys always lie within the ring's MB
+blocks.  Every function below takes `window`; with None it is what it was.
+
 Contract (per layer):
     q           [B, 1, G*H, D] current step's query
     pool_k/v    [NB, BS, H*D]  shared block pools (`pool_shape`)
@@ -85,7 +94,22 @@ def _sublanes(dtype) -> int:
 _BUFFERS = 3
 
 
-def paged_walk(block_table, lengths, block_size: int):
+def ring_blocks(window: int, block_size: int) -> int:
+    """Columns of a sliding-window layer's table: the blocks a window of
+    `window` keys can touch when it starts anywhere in a block."""
+    return -(-window // block_size) + 1
+
+
+def _ring_positions(lengths, columns, table_width: int, block_size: int):
+    """Absolute position of the first row of ring column `columns` for a
+    sequence of `lengths` tokens (the step's own included): the latest
+    block congruent to the column that the sequence has reached.
+    Negative for a column the sequence has not reached."""
+    last = (lengths - 1) // block_size
+    return (last - (last - columns) % table_width) * block_size
+
+
+def paged_walk(block_table, lengths, block_size: int, window=None):
     """The (row, column) pairs of `block_table` a decode step must
     read, in the order `paged_attention_tpu` walks them, as flat table
     indices `row * MB + column` in a [B*MB] int32 list, and how many of
@@ -94,10 +118,18 @@ def paged_walk(block_table, lengths, block_size: int):
     row whose length no table covers (0: never fed; past MB*BS: parked
     on the position sentinel, or free and still counting) walks none.
     It depends on the table and the lengths alone, which every layer of
-    a step shares, so XLA computes it once a step."""
+    a step shares, so XLA computes it once a step.  With a `window` the
+    table is a ring (`ring_blocks`): a row walks the columns it has
+    reached, all MB of them once it is a window long, and it is the
+    table alone (all -1 for a free or a parked row) that says a row
+    holds no request."""
     b, mb = block_table.shape
-    wanted = jnp.where((lengths > 0) & (lengths <= mb * block_size),
-                       -(-lengths // block_size), 0)
+    if window is None:
+        wanted = jnp.where((lengths > 0) & (lengths <= mb * block_size),
+                           -(-lengths // block_size), 0)
+    else:
+        wanted = jnp.where(lengths > 0,
+                           jnp.minimum(-(-lengths // block_size), mb), 0)
     columns = jnp.arange(mb, dtype=jnp.int32)
     held = jnp.min(jnp.where(block_table < 0, columns, mb), axis=1)
     counts = jnp.minimum(wanted, held)                          # [B]
@@ -118,7 +150,7 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
                   pool_k, pool_v, o_ref, k_blocks, v_blocks, sems,
                   q_scratch, m_scratch, l_scratch, acc_scratch, *,
                   block_size: int, table_width: int, scale: float,
-                  head_dim: int, group: int):
+                  head_dim: int, group: int, window=None):
     """Every row's online-softmax walk over its own blocks, all heads
     at once, on blocks [BS, H*D] as the pool stores them: one program,
     one loop over `paged_walk`'s pairs, so the work is the blocks that
@@ -191,9 +223,18 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
         s = jax.lax.dot_general(
             q_scratch[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [h_pad, bs]
-        pos = column * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < row_len, s, _NEG_INF)
+        if window is None:
+            first = column * block_size
+        else:  # the ring column's place in the sequence
+            first = _ring_positions(row_len, column, table_width,
+                                    block_size)
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = pos < row_len
+        if window is not None:
+            # A column wholly before the window adds exp(-1e30 - m) = 0
+            # (or is wiped by the first real maximum's alpha = 0).
+            seen &= pos >= row_len - window
+        s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_scratch[...]                           # [h_pad, 1]
         m_new = jnp.maximum(m_prev,
                             jnp.max(s, axis=1, keepdims=True))
@@ -228,9 +269,9 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
     jax.lax.fori_loop(0, count, pair, None)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
-                        interpret: bool = False):
+                        interpret: bool = False, window=None):
     """Pallas paged decode attention — same contract as
     `paged_attention_xla` on every row that holds context, without
     materializing the gathered cache view, and reading only blocks that
@@ -245,7 +286,7 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     mb = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
     lengths = lengths.astype(jnp.int32)
-    pairs, count = paged_walk(block_table, lengths, bs)
+    pairs, count = paged_walk(block_table, lengths, bs, window)
 
     # The products run in the pool's precision when the query shares
     # it (bfloat16 x bfloat16 with float32 accumulation is what a
@@ -274,7 +315,7 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     )
     kernel = functools.partial(_paged_kernel, block_size=bs,
                                table_width=mb, scale=scale, head_dim=d,
-                               group=group)
+                               group=group, window=window)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(row_shape, q.dtype),
@@ -401,7 +442,8 @@ def _kernels_serve(block_size: int, heads: int, head_dim: int,
             in ("", "0", "false"))
 
 
-def paged_attention(q, pool_k, pool_v, block_table, lengths):
+def paged_attention(q, pool_k, pool_v, block_table, lengths,
+                    window=None):
     """Dispatcher: the Pallas kernel where `_kernels_serve` says so and
     the query is a single token, XLA gather otherwise (CPU tests, odd
     shapes).  Runs at trace time inside the jitted decode function."""
@@ -409,24 +451,28 @@ def paged_attention(q, pool_k, pool_v, block_table, lengths):
     kv_heads = pool_k.shape[2] // head_dim
     use_kernel = q.shape[1] == 1 and _kernels_serve(
         pool_k.shape[1], kv_heads, head_dim, heads // kv_heads)
+    shapes = dict(q=q.shape, pool=pool_k.shape, table=block_table.shape)
+    if window is not None:
+        shapes["window"] = window
     attention.log_dispatch(
-        "pallas_paged" if use_kernel else "xla_paged",
-        q=q.shape, pool=pool_k.shape, table=block_table.shape)
+        "pallas_paged" if use_kernel else "xla_paged", **shapes)
     if use_kernel:
         return paged_attention_sharded(q, pool_k, pool_v, block_table,
-                                       lengths)
-    return paged_attention_xla(q, pool_k, pool_v, block_table, lengths)
+                                       lengths, window=window)
+    return paged_attention_xla(q, pool_k, pool_v, block_table, lengths,
+                               window)
 
 
 def paged_attention_sharded(q, pool_k, pool_v, block_table, lengths,
-                            interpret: bool = False):
+                            interpret: bool = False, window=None):
     """`paged_attention_tpu`, under `shard_map` when the caller runs
     inside a mesh (`jax.set_mesh`): Mosaic kernels cannot be
     partitioned automatically, and per-head attention needs no
     collective — q splits on heads over ``tp`` and the pools on H*D,
     which is the same head groups and how the engine shards the pool;
     block table and lengths replicate."""
-    kernel = functools.partial(paged_attention_tpu, interpret=interpret)
+    kernel = functools.partial(paged_attention_tpu, interpret=interpret,
+                               window=window)
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return kernel(q, pool_k, pool_v, block_table, lengths)
@@ -483,17 +529,35 @@ def _masked_attention(q, k, v, mask):
     return out.reshape(b, lq, heads, d).astype(q.dtype)
 
 
-def paged_attention_xla(q, pool_k, pool_v, block_table, lengths):
+def _ring_key_positions(table_width: int, block_size: int, lengths):
+    """[B, MB*BS] position in its sequence of every row of a window
+    layer's gathered ring, for sequences of `lengths` tokens: the latest
+    position written to the row (row r of the ring holds the positions
+    congruent to r modulo the ring's MB*BS rows; negative where the
+    sequence has not reached the row)."""
+    rows = table_width * block_size
+    last = lengths[:, None] - 1
+    return last - (last - jnp.arange(rows)[None, :]) % rows
+
+
+def paged_attention_xla(q, pool_k, pool_v, block_table, lengths,
+                        window=None):
     d = q.shape[3]
     k = _gathered(pool_k, block_table, d)
     v = _gathered(pool_v, block_table, d)
-    positions = jnp.arange(k.shape[1])[None, :]
-    mask = (positions < lengths[:, None])[:, None, None, :]
-    return _masked_attention(q, k, v, mask)
+    if window is None:
+        positions = jnp.arange(k.shape[1])[None, :]
+        mask = positions < lengths[:, None]
+    else:
+        positions = _ring_key_positions(block_table.shape[1],
+                                        pool_k.shape[1], lengths)
+        mask = ((positions < lengths[:, None]) & (positions >= 0)
+                & (positions >= lengths[:, None] - window))
+    return _masked_attention(q, k, v, mask[:, None, None, :])
 
 
 def paged_write(pool_k, pool_v, k_step, v_step, block_table,
-                positions):
+                positions, window=None):
     """Scatter a step's k/v into the pools at each slot's positions.
 
     Two call shapes, distinguished at trace time:
@@ -513,7 +577,11 @@ def paged_write(pool_k, pool_v, k_step, v_step, block_table,
     sits past the first rejection), and the next wave over the slot
     re-writes those very positions before its own attention reads
     them.  Only the drop-never-clamp rule above makes the parked-slot
-    and near-max_seq overrun cases of that scheme safe."""
+    and near-max_seq overrun cases of that scheme safe.
+
+    With a `window` the table is the layer's ring: position p goes to
+    column (p // BS) % MB, no position is past the table, and a row
+    that holds no request is dropped by its table's -1s alone."""
     bs = pool_k.shape[1]
     mb = block_table.shape[1]
     chunked = positions.ndim == 2
@@ -522,8 +590,12 @@ def paged_write(pool_k, pool_v, k_step, v_step, block_table,
     rows = jnp.arange(block_table.shape[0])
     if chunked:
         rows = rows[:, None]
-    blocks = block_table[rows, jnp.minimum(block_idx, mb - 1)]
-    dropped = (blocks < 0) | (block_idx >= mb)
+    if window is None:
+        blocks = block_table[rows, jnp.minimum(block_idx, mb - 1)]
+        dropped = (blocks < 0) | (block_idx >= mb)
+    else:
+        blocks = block_table[rows, block_idx % mb]
+        dropped = blocks < 0
     if not chunked and _kernels_serve(bs, *k_step.shape[1:]):
         return paged_write_sharded(pool_k, pool_v, k_step, v_step,
                                    jnp.where(dropped, -1, blocks), offs)
@@ -539,7 +611,7 @@ def paged_write(pool_k, pool_v, k_step, v_step, block_table,
 
 
 def paged_prefill_attention_xla(q, pool_k, pool_v, block_table,
-                                q_positions):
+                                q_positions, window=None):
     """Chunk-prefill attention: multi-token queries over the paged
     pool with PER-QUERY causal masking (query at absolute position p
     attends keys at positions <= p).  The single-length mask of
@@ -552,13 +624,24 @@ def paged_prefill_attention_xla(q, pool_k, pool_v, block_table,
                                partial final chunk on an out-of-range
                                sentinel (their output is discarded,
                                the mask keeps them finite)
+    With a `window` the table is the layer's ring as the chunk's own
+    writes left it (every query position real, the row's last the
+    latest): a chunk of at most MB*BS - W + 1 tokens (BS + 1 or more)
+    has overwritten no key that its first query still sees.
     Returns [B, L, H, D]."""
     d = q.shape[3]
     k = _gathered(pool_k, block_table, d)
     v = _gathered(pool_v, block_table, d)
-    key_pos = jnp.arange(k.shape[1])[None, None, :]       # [1, 1, K]
-    mask = (key_pos <= q_positions[:, :, None])[:, None]  # [B,1,L,K]
-    return _masked_attention(q, k, v, mask)
+    if window is None:
+        key_pos = jnp.arange(k.shape[1])[None, None, :]   # [1, 1, K]
+        mask = key_pos <= q_positions[:, :, None]         # [B, L, K]
+    else:
+        key_pos = _ring_key_positions(
+            block_table.shape[1], pool_k.shape[1],
+            jnp.max(q_positions, axis=1) + 1)[:, None, :]
+        mask = ((key_pos <= q_positions[:, :, None]) & (key_pos >= 0)
+                & (key_pos > q_positions[:, :, None] - window))
+    return _masked_attention(q, k, v, mask[:, None])
 
 
 def paged_insert(pool_k, pool_v, k_new, v_new, dest_blocks, lengths):

@@ -41,9 +41,17 @@ def _fit_block(block: int, length: int) -> Optional[int]:
     return None
 
 
+def _first_block(q_idx, block_q: int, block_k: int, window: Optional[int]):
+    """The first key block that holds a key the query block's first query
+    sees: 0 without a window."""
+    if window is None:
+        return 0
+    return jnp.maximum(q_idx * block_q - window + 1, 0) // block_k
+
+
 def _flash_kernel(*refs,
                   causal: bool, scale: float, block_q: int, block_k: int,
-                  has_lengths: bool):
+                  has_lengths: bool, window: Optional[int] = None):
     if has_lengths:
         # Scalar-prefetch layout: the lengths vector precedes the
         # tensor refs (PrefetchScalarGridSpec).
@@ -55,11 +63,14 @@ def _flash_kernel(*refs,
             m_scratch, l_scratch, acc_scratch = refs
     bh_idx = pl.program_id(0)
     q_idx = pl.program_id(1)
-    k_idx = pl.program_id(2)
+    k_step = pl.program_id(2)
     num_k = pl.num_programs(2)
+    # With a window the k axis walks the band of key blocks this query
+    # block can see, from `_first_block` on; without one it walks them all.
+    k_idx = k_step + _first_block(q_idx, block_q, block_k, window)
     row_len = len_ref[bh_idx] if has_lengths else None
 
-    @pl.when(k_idx == 0)
+    @pl.when(k_step == 0)
     def _init():
         m_scratch[:] = jnp.full_like(m_scratch, _NEG_INF)
         l_scratch[:] = jnp.zeros_like(l_scratch)
@@ -81,6 +92,10 @@ def _flash_kernel(*refs,
             q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            if window is not None:
+                # Sliding window: a query sees the `window` latest keys,
+                # its own among them.
+                s = jnp.where(k_pos > q_pos - window, s, _NEG_INF)
         if has_lengths:
             # Key-padding: keys at positions >= this batch row's real
             # length never contribute (suffix padding from the serving
@@ -107,6 +122,9 @@ def _flash_kernel(*refs,
     if causal:
         # Skip fully-masked k blocks above the diagonal.
         pred = k_idx * block_k <= q_idx * block_q + (block_q - 1)
+        if window is not None:
+            # ... and those wholly before the first query's window.
+            pred &= (k_idx + 1) * block_k - 1 > q_idx * block_q - window
     if has_lengths:
         # Skip k blocks entirely beyond this row's length (dynamic
         # predicate — pl.when accepts traced conditions).
@@ -117,7 +135,7 @@ def _flash_kernel(*refs,
     else:
         pl.when(pred)(_run_block)
 
-    @pl.when(k_idx == num_k - 1)
+    @pl.when(k_step == num_k - 1)
     def _finalize():
         # max() guards rows with length 0 (batch-dim padding): 0/eps
         # instead of 0/0 NaN; those rows are sliced away by the caller.
@@ -125,12 +143,14 @@ def _flash_kernel(*refs,
                     / jnp.maximum(l_scratch[:], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "window"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    kv_lengths: "jax.Array | None" = None) -> jax.Array:
+                    kv_lengths: "jax.Array | None" = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Fused attention over [B, L, H, D]; returns [B, L, H, D].
 
     kv_lengths: optional int32 [B] — per-row count of real keys (suffix
@@ -138,7 +158,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     blocks are skipped).  This is what lets the serving path's
     seq-bucket padding ride the flash kernel instead of falling back
     to XLA with a materialized mask.
+
+    window: with `causal`, a query at t sees keys s with t - window < s
+    <= t; the grid's k axis is then the band of key blocks a query block
+    can see (block_q + window - 1 keys), not the sequence's.
     """
+    assert window is None or causal, "a window is a causal band"
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     # Blocks shrink to the largest power-of-two divisor <= the requested
@@ -157,11 +182,27 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
     vt = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
 
-    grid = (B * H, Lq // block_q, Lk // block_k)
+    num_k = Lk // block_k
+    if window is not None:
+        # A query block sees block_q + window - 1 keys: that many blocks
+        # at most, however long the sequence.
+        num_k = min(num_k, (block_q + window - 2) // block_k + 2)
+    grid = (B * H, Lq // block_q, num_k)
     has_lengths = kv_lengths is not None
+
+    def seen(i, j, last):
+        """The key block of grid step (i, j), held inside what query block
+        i sees: a step outside it asks for a block it already has, which
+        the pipeline does not copy again (the kernel skips its work)."""
+        if causal:
+            last = jnp.minimum(last, (i * block_q + block_q - 1) // block_k)
+        return jnp.clip(j + _first_block(i, block_q, block_k, window),
+                        0, last)
+
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, has_lengths=has_lengths)
+        block_q=block_q, block_k=block_k, has_lengths=has_lengths,
+        window=window)
     scratch_shapes = [
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
@@ -184,7 +225,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             # index_map signature: (*grid_indices, *scalar_refs)
             last = jnp.maximum(
                 (lens[bh] + block_k - 1) // block_k - 1, 0)
-            return (bh, jnp.minimum(j, last), 0)
+            return (bh, seen(i, j, last), 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -204,13 +245,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             compiler_params=params,
         )(lengths_bh, qt, kt, vt)
     else:
+        def kv_block(bh, i, j):
+            return (bh, seen(i, j, Lk // block_k - 1) if causal else j, 0)
+
         out = pl.pallas_call(
             kernel,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
+                pl.BlockSpec((1, block_k, D), kv_block),
+                pl.BlockSpec((1, block_k, D), kv_block),
             ],
             out_specs=pl.BlockSpec((1, block_q, D),
                                    lambda bh, i, j: (bh, i, 0)),

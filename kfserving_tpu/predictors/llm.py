@@ -25,6 +25,14 @@ config.json schema:
                                                     #   relu² experts
                                                     #   (models/
                                                     #   nemotron_h.py)
+                    | "mellum" | "mellum_tiny"      # sliding-window
+                                                    #   layers beside
+                                                    #   whole-context
+                                                    #   ones, two rotary
+                                                    #   tables, GQA,
+                                                    #   renormalised
+                                                    #   experts (models/
+                                                    #   mellum.py)
                     | <registered>,                 # all: same engine,
                                                     #   pool and decode
                                                     #   kernel
@@ -53,6 +61,20 @@ config.json schema:
                                    #   derived, serve correctly on
                                    #   the slower XLA gather path
                                    #   (logged once at load)
+      "window_cache_blocks": 648,  # a model with sliding-window
+                                   #   layers ("mellum") keeps their
+                                   #   K/V in a second pool, a ring of
+                                   #   ceil(window/block_size)+1
+                                   #   blocks a sequence whatever its
+                                   #   length; unset holds a ring for
+                                   #   every slot, more leaves room
+                                   #   for finished requests' blocks
+                                   #   while they wait to be freed.
+                                   #   Such a model refuses
+                                   #   speculative,
+                                   #   prefill_chunk_tokens and
+                                   #   host_tier_blocks at load, and
+                                   #   shares no prompt prefixes
       "prefill_rows": 8,           # the most rows one prefill
                                    #   dispatch carries (default:
                                    #   every free slot)
@@ -410,6 +432,7 @@ class GenerativeConfig:
                  logprob_topk: int = 5,
                  block_size: Optional[int] = None,
                  cache_blocks: Optional[int] = None,
+                 window_cache_blocks: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  host_tier_blocks: Optional[int] = None,
                  host_tier_dir: Optional[str] = None,
@@ -442,6 +465,11 @@ class GenerativeConfig:
         self.block_size = int(block_size) if block_size else None
         self.cache_blocks = (int(cache_blocks) if cache_blocks
                              else None)
+        # A model with sliding-window layers keeps those layers' K/V in
+        # a pool of their own, a ring of ceil(window / block_size) + 1
+        # blocks a sequence; None = a ring for every slot.
+        self.window_cache_blocks = (int(window_cache_blocks)
+                                    if window_cache_blocks else None)
         # Chunked prefill: cold prompts longer than this
         # land chunk-by-chunk between decode waves; adaptive depth
         # stops speculative waves that could only decode garbage.
@@ -583,6 +611,7 @@ class GenerativeModel(Model):
             logprob_topk=cfg.logprob_topk,
             block_size=cfg.block_size,
             cache_blocks=cfg.cache_blocks,
+            window_cache_blocks=cfg.window_cache_blocks,
             prefill_chunk_tokens=cfg.prefill_chunk_tokens,
             host_tier_blocks=cfg.host_tier_blocks,
             host_tier_dir=cfg.host_tier_dir,

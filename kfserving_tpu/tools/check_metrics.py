@@ -196,6 +196,17 @@ async def smoke() -> List[str]:
         model="metrics-probe").inc(640)
     obs.generator_decode_kv_context_tokens_total().labels(
         model="metrics-probe").inc(61000)
+    for pool in ("global", "window"):
+        obs.generator_decode_kv_pool_blocks_walked_total().labels(
+            model="metrics-probe", pool=pool).inc(576)
+        obs.generator_decode_kv_pool_context_tokens_total().labels(
+            model="metrics-probe", pool=pool).inc(61000)
+        obs.generator_kv_pool_blocks().labels(
+            model="metrics-probe", pool=pool).set(576)
+        obs.generator_kv_pool_fill_ratio().labels(
+            model="metrics-probe", pool=pool).set(0.8)
+    obs.generator_window_blocks_recycled_total().labels(
+        model="metrics-probe").inc(12)
     for program in ("decode", "prefill"):
         obs.generator_moe_routed_pairs_total().labels(
             model="metrics-probe", program=program).inc(3072)

@@ -411,6 +411,7 @@ def _decode_program(v5e, monkeypatch, serving: dict):
         prefill_buckets=serving["prefill_buckets"],
         block_size=serving["block_size"],
         cache_blocks=serving["cache_blocks"],
+        window_cache_blocks=serving.get("window_cache_blocks"),
         steps_per_call=serving["steps_per_call"])
     try:
         s = serving["max_slots"]
@@ -419,9 +420,12 @@ def _decode_program(v5e, monkeypatch, serving: dict):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
         i32, f32 = jnp.int32, jnp.float32
+        table = arg(i32, s, engine.blocks_per_slot)
+        if engine.window_blocks_per_slot:  # and the rings' beside it
+            table = (table, arg(i32, s, engine.window_blocks_per_slot))
         compiled = engine._decode.lower(
             on_chip(engine.variables), on_chip(engine._caches),
-            arg(i32, s, engine.blocks_per_slot), arg(i32, s), arg(i32, s),
+            table, arg(i32, s), arg(i32, s),
             arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s)).compile()
         return compiled, engine._cache_shape, shapes
     finally:
@@ -450,6 +454,7 @@ def _prefill_program(v5e, monkeypatch, serving: dict, rows: int):
         prefill_buckets=serving["prefill_buckets"],
         block_size=serving["block_size"],
         cache_blocks=serving["cache_blocks"],
+        window_cache_blocks=serving.get("window_cache_blocks"),
         steps_per_call=serving["steps_per_call"])
     try:
         def arg(dtype, *shape):
@@ -585,6 +590,89 @@ def test_olmoe_prefill_program_fits_the_described_v5e(v5e, monkeypatch,
     # 0.442; the kernel keeps neither
     assert memory.temp_size_in_bytes < (
         0.373e9 if way == "kernel" else 0.45e9), memory
+
+
+def _mellum_serving() -> dict:
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "mellum2-12b-a2.5b-8l.json")) as f:
+        return json.load(f)["serving"]
+
+
+def test_mellum_decode_program_fits_the_described_v5e(v5e, monkeypatch):
+    """The 16-step decode program of `mellum2-12b-a2.5b-8l` (64 slots; 6
+    sliding-window and 2 whole-context layers, 32 query heads on 4 KV
+    heads of 128; 64 experts of 896): its arguments are the parameters
+    and both pools, 3,584 blocks of whole contexts and 648 ring blocks;
+    all eight layers read and write their pool through the Pallas
+    kernels, six of them over rings of 9 columns, with one walk a pool
+    and not one a layer; the experts go through `moe_experts_touched`."""
+    serving = _mellum_serving()
+    compiled, pool, shapes = _decode_program(v5e, monkeypatch, serving)
+    assert pool == (3584, 128, 512)
+    assert {x.dtype.name for x in jax.tree.leaves(shapes)} == {"bfloat16"}
+    assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 8
+    assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 8
+    assert _pool_copies(compiled, pool) == []
+    assert _pool_copies(compiled, (648, 128, 512)) == []
+    # a walk a pool (over 64 x 81 and 64 x 9 table entries), not a layer
+    walks = [line for line in _walk_operations(compiled) if "s32[" in line]
+    assert len(walks) < 16
+    assert sum("s32[576]" in line for line in walks) \
+        == sum("s32[5184]" in line for line in walks) < 8
+    assert compiled.as_text().count("moe_experts_touched") >= 8
+    assert "ragged-dot" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    print(f"mellum2-12b-a2.5b-8l decode program: {memory}")
+    stored = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    assert 7.58e9 < stored < 7.60e9                  # 3.795 B, bfloat16
+    # ... + 1.88 GB of whole contexts (2 layers) + 1.02 GB of rings (6)
+    assert 10.4e9 < memory.argument_size_in_bytes < 10.6e9
+    assert memory.temp_size_in_bytes < 0.3e9, memory
+    assert _program_bytes(memory) < 15.75e9, memory
+
+
+def test_mellum_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
+    """Its (1, 8192) prefill, the largest (`prefill_rows` 1): all eight
+    layers' attention is the flash kernel, causal and padded, six of them
+    with the window (XLA's scores, 32 x 8192 x 8192 float32 = 8.6 GB,
+    would not fit); the experts go by XLA's grouped matmuls, since the
+    grouped kernel serves no shape without a record on the chip; and
+    parameters, temporaries and outputs fit beside the 2.90 GB of pools
+    that the program does not see."""
+    serving = _mellum_serving()
+    assert serving["prefill_rows"] == 1
+    compiled = _prefill_program(v5e, monkeypatch, serving, 1)
+    text = compiled.as_text()
+    assert len(_mosaic_calls(compiled, "flash_attention")) == 8
+    assert "f32[1,32,8192,8192]" not in text
+    assert "moe_experts_grouped" not in text
+    assert text.count("ragged-dot") >= 24
+    memory = compiled.memory_analysis()
+    print(f"mellum2-12b-a2.5b-8l (1, 8192) prefill program: {memory}")
+    assert memory.temp_size_in_bytes < 2.5e9, memory
+    assert _program_bytes(memory) + 2.90e9 < 15.75e9, memory
+
+
+@pytest.mark.parametrize("bucket", [2560, 3584, 7168])
+def test_mellum_prefill_buckets_between_the_powers_of_two_compile(
+        v5e, monkeypatch, bucket):
+    """The configuration's buckets lie every 512 tokens from 2048 to 4096
+    and every 1024 to 8192, so that a prompt pays for 1.15 times its
+    length and not 1.44: one that is no power of two keeps eight flash
+    kernels (blocks of 256 queries and 512 keys divide it), whose k axis
+    is the band a window layer's query block sees, 4 key blocks, and
+    every key block of a whole-context layer.  (1536 is not among them:
+    the chip's compiler refuses that program, out of scoped VMEM in the
+    experts' gather over 12,288 rows.)"""
+    serving = _mellum_serving()
+    assert serving["prefill_buckets"] == [1024, 2048, 2560, 3072, 3584, 4096,
+                                          5120, 6144, 7168, 8192]
+    serving["prefill_buckets"] = [b for b in serving["prefill_buckets"]
+                                  if b <= bucket]
+    compiled = _prefill_program(v5e, monkeypatch, serving, 1)
+    assert len(_mosaic_calls(compiled, "flash_attention")) == 8
+    assert f"f32[1,32,{bucket},{bucket}]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 def _gpt2_large_serving() -> dict:
