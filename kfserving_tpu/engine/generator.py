@@ -183,6 +183,9 @@ class _Active:
     # (seed, absolute position), so the continuation reproduces what
     # an uninterrupted decode would have sampled).
     tokens: List[int] = field(default_factory=list)
+    # Why `_emit` ended the request, once it has: "length" is the end
+    # the decode program was told of (`_stop_positions`).
+    finished: Optional[str] = None
     # -- chunked-prefill state (cold prompts) --------------------------
     # prefilling: the slot holds a cold prompt landing in block-aligned
     # chunks between decode waves — it is NOT decodable yet (decode
@@ -800,7 +803,7 @@ class GenerationEngine:
         k_steps = self.steps_per_call
 
         def decode_fn(variables, caches, table, tokens, positions,
-                      temps, top_ks, top_ps, seeds):
+                      stops, temps, top_ks, top_ps, seeds):
             """K decode steps in ONE device dispatch (lax.scan): on a
             high-RTT link each host round trip costs ~an RTT, so
             single-token stepping caps tokens/s at 1/RTT per wave;
@@ -810,12 +813,25 @@ class GenerationEngine:
             after an EOS/budget stop).  Also returns the final carry's
             feed tokens/positions as device arrays: the pipelined
             scheduler chains dispatch N+1 off them without a host
-            round trip."""
+            round trip.
+
+            `stops` [S] (`_stop_positions`) is where each row's token
+            budget ends.  A row whose feed position has reached it
+            owes no token, and the step parks it: its table row is all
+            -1 in every pool, so `paged_walk` lists none of its blocks
+            and `paged_write` drops its row, and an expert model
+            routes it to no expert.  Its tokens and positions go on in
+            the carry as a freed slot's always have; the head and the
+            sampler stay dense over the slots."""
             def step(carry, _):
                 caches, tokens, positions = carry
+                live = positions < stops
+                parked = jax.tree.map(
+                    lambda t: jnp.where(live[:, None], t, -1), table)
+                kw = {"valid": live[:, None]} if routed else {}
                 (logits, new_caches), pairs = apply(
                     variables, tokens[:, None], positions=positions,
-                    kv_cache=with_table(caches, table))
+                    kv_cache=with_table(caches, parked), **kw)
                 lg = logits[:, 0]
                 # The token being sampled extends a prefix of length
                 # positions+1 — the noise index is that length, so
@@ -1095,6 +1111,9 @@ class GenerationEngine:
         self._spec_verify_s = 0.0
         self._occupied_slot_steps = 0
         self._wasted_token_steps = 0  # garbage steps past a finish
+        # Of them, those past a token budget's end: the decode program
+        # had the row parked (no block walked or written, no expert).
+        self._parked_token_steps = 0
         # What decode attention had to read, a layer: blocks under the
         # live rows' contexts, and the tokens in them (_distribute).
         self._kv_blocks_walked = 0
@@ -1384,6 +1403,7 @@ class GenerationEngine:
             "depth_effective": self._depth_effective,
             "suppressed_waves": self.suppressed_waves,
             "wasted_token_steps": self._wasted_token_steps,
+            "parked_token_steps": self._parked_token_steps,
             "kv_block_fill": round(
                 self._kv_context_tokens / max(
                     1, self._kv_blocks_walked * self.block_size), 4),
@@ -3533,14 +3553,15 @@ class GenerationEngine:
                 temps, top_ks, top_ps, seeds, want_lp = \
                     self._sampling_arrays()
                 table = self._table_device()
-                sampling = [jnp.asarray(a)
-                            for a in (temps, top_ks, top_ps, seeds)]
+                per_slot = [jnp.asarray(a)
+                            for a in (self._stop_positions(), temps,
+                                      top_ks, top_ps, seeds)]
             with self._inflight.launch(
                     "decode", rows=self.max_slots,
                     steps=self.steps_per_call) as launched:
                 out = self._decode(
                     self.variables, self._caches, table,
-                    self._feed_tokens, self._feed_positions, *sampling)
+                    self._feed_tokens, self._feed_positions, *per_slot)
                 (toks, self._caches, self._feed_tokens,
                  self._feed_positions, chosen_lp, top_ids,
                  top_lps) = out[:7]
@@ -3748,6 +3769,25 @@ class GenerationEngine:
             want_lp = want_lp or s.req.logprobs > 0
         return temps, top_ks, top_ps, seeds, want_lp
 
+    def _stop_positions(self) -> np.ndarray:
+        """[max_slots] int32: the feed position at which a decodable
+        slot's request owes no further token (`decode_fn` parks the row
+        from there).  The prefill gave the first of the budget's tokens
+        and left the feed at the prompt's length, and every decode step
+        gives one more, so the step at prompt + budget - 2 gives the
+        last.  From the request's constants alone, never from
+        `generated`: the host lags the device by up to pipeline_depth
+        waves.  A preempted request resumes with its tokens merged into
+        its prompt and taken off its budget, so its stop is what it
+        was.  0, which no position is below, for a free slot and for
+        one mid-chunked-prefill."""
+        stops = np.zeros(self.max_slots, np.int32)
+        for i, s in enumerate(self._slots):
+            if s is not None and not s.prefilling:
+                stops[i] = (s.req.prompt_ids.size
+                            + s.req.max_new_tokens - 1)
+        return stops
+
     def _emit(self, slot: int, token: int, lp_rec=None):
         """Account a newly produced token for `slot` and deliver it (or
         the finish marker) to the request's stream.
@@ -3802,6 +3842,7 @@ class GenerationEngine:
             s.tokens.append(token)
             s.req.out.put_nowait((token, finished))
         if finished is not None:
+            s.finished = finished
             duration_s = now - s.req.submit_t
             if duration_s > 0:
                 obs.llm_tokens_per_second().observe(
@@ -3837,10 +3878,13 @@ class GenerationEngine:
         garbage for this wave, and its row is discarded (that waste is
         the pipelining trade; counted in wasted_token_steps).  A slot
         finishing mid-chunk discards its remaining positions — at most
-        K-1 steps of waste."""
+        K-1 steps of waste.  Where the request ended by its token
+        budget, the device had those steps' row parked
+        (`_stop_positions`): parked_token_steps counts them too."""
         k = tokens.shape[1]
         self._token_steps += k
         starts = []  # each live row's context when the wave began
+        ran = []     # and how many of the wave's steps its budget had left
         # Even split of the wave's busy interval across the live
         # streams it decoded: the per-request decode cost sums to the
         # engine's device time (additive attribution), and garbage
@@ -3856,6 +3900,8 @@ class GenerationEngine:
                 # Freed (EOS/budget/cancel) after this wave was
                 # enqueued: the device decoded K garbage steps for it.
                 self._wasted_token_steps += k
+                if s.finished == "length":
+                    self._parked_token_steps += k
                 continue
             self._occupied_slot_steps += k
             s.req.decode_device_ms += share_ms
@@ -3870,11 +3916,14 @@ class GenerationEngine:
                                        + self._attn_flops_coeff
                                        * self._attended(s.length))
             starts.append(s.length)
+            ran.append(min(k, s.req.max_new_tokens - s.generated))
             n_lp = s.req.logprobs
             for j in range(k):
                 if self._slots[i] is not s:
                     # Finished mid-chunk: remaining positions wasted.
                     self._wasted_token_steps += k - j
+                    if s.finished == "length":
+                        self._parked_token_steps += k - j
                     break
                 # Each scanned step wrote the fed token's k/v at the
                 # slot's position: the cache grew by one per step.
@@ -3889,9 +3938,12 @@ class GenerationEngine:
         if starts:
             # Step i of the wave attends over L + i + 1 tokens of a row
             # that began it with L, in ceil of that over block_size
-            # blocks, whether or not the row finished before step i.
+            # blocks, as far as the row's budget ran: past it the row
+            # is parked and walks nothing, and short of it the device
+            # walks on whether or not an EOS ended the row before.
             context = (np.asarray(starts, np.int64)[:, None]
                        + np.arange(1, k + 1))
+            context = context[np.arange(k) < np.asarray(ran)[:, None]]
             tokens_read = int(context.sum())
             blocks = int((-(-context // self.block_size)).sum())
             self._kv_context_tokens += tokens_read

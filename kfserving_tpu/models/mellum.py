@@ -261,7 +261,8 @@ class MellumLM(nn.Module):
                  kv_cache: Optional[Any] = None,
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
-                 logit_positions: Optional[Any] = None):
+                 logit_positions: Optional[Any] = None,
+                 valid: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         if positions is None:
@@ -270,8 +271,8 @@ class MellumLM(nn.Module):
             pos = positions.reshape(b, -1)
         # Padding is given to no expert, as in `OlmoeLM`: past kv_lengths
         # in a prefill bucket, past what a full layer's table can hold in
-        # a chunk (a ring's table holds every position).
-        valid = None
+        # a chunk (a ring's table holds every position), and a decode
+        # step's rows that the engine says are not `valid`.
         if kv_lengths is not None:
             valid = jnp.arange(l)[None, :] < kv_lengths[:, None]
         elif kv_cache is not None and l > 1:

@@ -409,7 +409,8 @@ class NemotronHLM(nn.Module):
                  kv_cache: Optional[Any] = None,
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
-                 logit_positions: Optional[Any] = None):
+                 logit_positions: Optional[Any] = None,
+                 valid: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         if positions is None:
@@ -417,9 +418,11 @@ class NemotronHLM(nn.Module):
         else:
             pos = positions.reshape(b, -1)
         # A prefill bucket's padding is given to no expert (and, by
-        # `kv_lengths`, leaves no mark on a state); a decode step
-        # computes every row it was given, parked ones too.
-        valid = None
+        # `kv_lengths`, leaves no mark on a state), nor are the rows of
+        # a decode step that the engine says are not `valid` ([B, 1]
+        # bool: past their token budget; their state goes on stepping,
+        # and the insert that admits the slot's next request overwrites
+        # it).
         if kv_lengths is not None:
             valid = jnp.arange(l)[None, :] < kv_lengths[:, None]
         hidden = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,

@@ -233,7 +233,8 @@ class OlmoeLM(nn.Module):
                  kv_cache: Optional[Any] = None,
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
-                 logit_positions: Optional[Any] = None):
+                 logit_positions: Optional[Any] = None,
+                 valid: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         if positions is None:
@@ -241,10 +242,10 @@ class OlmoeLM(nn.Module):
         else:
             pos = positions.reshape(b, -1)
         # Tokens no request owns are given to no expert: a prefill
-        # bucket's padding (past kv_lengths) and a chunk's padding
-        # (parked on the out-of-range sentinel).  A decode step computes
-        # every row it was given, parked ones too.
-        valid = None
+        # bucket's padding (past kv_lengths), a chunk's padding (parked
+        # on the out-of-range sentinel), and the rows of a decode step
+        # that the engine says are not `valid` ([B, 1] bool: past their
+        # token budget).
         if kv_lengths is not None:
             valid = jnp.arange(l)[None, :] < kv_lengths[:, None]
         elif kv_cache is not None and l > 1:

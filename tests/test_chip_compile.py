@@ -425,7 +425,7 @@ def _decode_program(v5e, monkeypatch, serving: dict):
             table = (table, arg(i32, s, engine.window_blocks_per_slot))
         compiled = engine._decode.lower(
             on_chip(engine.variables), on_chip(engine._caches),
-            table, arg(i32, s), arg(i32, s),
+            table, arg(i32, s), arg(i32, s), arg(i32, s),
             arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s)).compile()
         return compiled, engine._cache_shape, shapes
     finally:
@@ -692,9 +692,52 @@ def _parameter_converts(compiled) -> list:
             if " convert(" in line and matrix.search(line.split(" convert(")[0])]
 
 
+@pytest.fixture(scope="module")
+def gpt2_large_decode(v5e):
+    """`_decode_program` of `gpt2-large` as benchmarked, 144 blocks,
+    compiled once for the tests that read it."""
+    from kfserving_tpu.observability import REGISTRY
+
+    with pytest.MonkeyPatch.context() as patch:
+        program = _decode_program(v5e, patch, _gpt2_large_serving())
+    REGISTRY.reset()  # the engine's gauges: no test may start with any
+    return program
+
+
+def test_gpt2_large_decode_program_takes_each_rows_stop(gpt2_large_decode):
+    """The decode program is told where each row's token budget ends by
+    one more `s32[24]` beside the feed and the sampling arrays, and parks
+    a row through the table its kernels already take: the same 72 Mosaic
+    calls, no pool copied, the list of blocks to walk still made once a
+    step, and 96 bytes of arguments more."""
+    compiled, pool, _ = gpt2_large_decode
+    entry = compiled.as_text().split("\nENTRY ")[1].split("\n}")[0]
+    per_slot = {
+        name: shape for name, shape in re.findall(
+            r"%(\w+?)\.\d+ = (\w+\[[\d,]*\])\S* parameter\(", entry)
+        if not name.startswith(("variables__", "caches_"))}
+    assert per_slot == {
+        "table": "s32[24,8]", "tokens": "s32[24]", "positions": "s32[24]",
+        "stops": "s32[24]", "temps": "f32[24]", "top_ks": "s32[24]",
+        "top_ps": "f32[24]", "seeds": "s32[24]"}
+    assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 36
+    assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 36
+    assert 0 < len(_walk_operations(compiled)) < 12
+    assert _pool_copies(compiled, pool) == []
+    stored = sum(
+        int(np.prod([int(n) for n in dims.split(",") if n]))
+        * {"bf16": 2, "f32": 4, "s32": 4}[dtype]
+        for dtype, dims in re.findall(
+            r" = (\w+)\[([\d,]*)\]\S* parameter\(", entry))
+    memory = compiled.memory_analysis()
+    # What the chip keeps for its arguments is the arrays' own bytes and
+    # tiling's rounding of the small ones.
+    assert 0 <= memory.argument_size_in_bytes - stored < 1 << 20, memory
+
+
 @pytest.mark.parametrize("cache_blocks", [144, 192])
 def test_gpt2_large_decode_program_fits_the_described_v5e(
-        v5e, monkeypatch, cache_blocks):
+        v5e, monkeypatch, cache_blocks, request):
     """The 16-step decode program of `gpt2-large` (24 slots, 20 heads of
     64) at the benchmarked 144 blocks and at the 192 the chip refused
     while the kernel read a padded twin of every layer's pool (9.16 GiB
@@ -703,7 +746,10 @@ def test_gpt2_large_decode_program_fits_the_described_v5e(
     as stored rest in bfloat16 (1.44 GiB fewer arguments), so the twin
     that was 1.44 of the program's 1.55 GiB of temporaries is gone."""
     serving = {**_gpt2_large_serving(), "cache_blocks": cache_blocks}
-    compiled, pool, shapes = _decode_program(v5e, monkeypatch, serving)
+    if serving == _gpt2_large_serving():
+        compiled, pool, shapes = request.getfixturevalue("gpt2_large_decode")
+    else:
+        compiled, pool, shapes = _decode_program(v5e, monkeypatch, serving)
     assert pool[0] == cache_blocks
     # A layer's two Mosaic calls: the step's write, then attention.
     assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 36

@@ -967,3 +967,211 @@ async def test_pipeline_decode_wait_tracked(tiny):
         assert stats["suppressed_waves"] >= 1
     finally:
         await eng.close()
+
+
+# ----------------------------------- rows parked at their token budget
+
+
+PARKED_SEQ, PARKED_BS = 128, 16
+FAMILIES = {
+    "decoder": ("decoder_tiny", dict(num_layers=2, hidden_size=64,
+                                     num_heads=2, intermediate_size=128,
+                                     vocab_size=96)),
+    "olmoe": ("olmoe_tiny", {}),
+    "nemotron_h": ("nemotron_h_tiny", {}),
+    "mellum": ("mellum_tiny", {}),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def family(request):
+    from kfserving_tpu.models import create_model, init_params
+
+    name, sizes = FAMILIES[request.param]
+    spec = create_model(name, max_seq=PARKED_SEQ, **sizes)
+    return spec.module, init_params(spec, seed=3)
+
+
+def family_engine(family, **kw):
+    module, variables = family
+    kw.setdefault("max_slots", 4)
+    return GenerationEngine(module, variables, max_seq=PARKED_SEQ,
+                            block_size=PARKED_BS,
+                            prefill_buckets=[16, 32, 64], **kw)
+
+
+def _prompt(length: int, stride: int):
+    return [(j * stride) % 90 + 1 for j in range(length)]
+
+
+async def _served(eng, requests):
+    """Every request's (tokens, log-probabilities), all submitted at
+    once: (prompt, budget, sampling keywords) each."""
+    async def one(prompt, budget, sampling):
+        req = eng.submit(prompt, max_new_tokens=budget, logprobs=1,
+                         **sampling)
+        tokens = [t async for t, _ in eng.stream(req) if t is not None]
+        return tokens, list(req.lp_chosen)
+
+    return await asyncio.wait_for(asyncio.gather(*[
+        one(*r) for r in requests]), timeout=600)
+
+
+async def _settled(eng):
+    """Until every wave the engine launched has been fetched and
+    accounted: its loop leaves once nothing is active or in flight."""
+    await asyncio.wait_for(eng._loop_task, timeout=60)
+
+
+async def test_a_row_parked_at_its_budget_changes_no_stream(family):
+    """Four steps a call and two waves in flight, so that the device
+    runs up to eight steps past what the host has seen and parks each
+    row at its budget's end by itself: budgets that end at every step
+    of a wave (and at the prefill), prompts that end on a block's
+    boundary and off it, more requests than slots, some sampling; then
+    three long streams in a pool too small for them, so that one is
+    preempted and resumed.  Every stream is what an engine that steps
+    once a call, one wave at a time, over an ample pool gives it."""
+    def batch(*rows):
+        return [(_prompt(length, stride), budget, sampling)
+                for length, stride, budget, sampling in rows]
+
+    short = batch((16, 3, 1, {}), (13, 5, 2, {}), (32, 7, 3, {}),
+                  (21, 11, 4, {"temperature": 0.9, "seed": 5}),
+                  (16, 13, 5, {}), (29, 17, 6, {}),
+                  (32, 19, 7, {"temperature": 1.1, "seed": 11}),
+                  (5, 23, 8, {}), (16, 29, 9, {}))
+    long = batch((42, 3, 20, {}), (42, 5, 19, {}),
+                 (42, 11, 18, {"temperature": 0.8, "seed": 2}))
+    results = {}
+    for label, kw in (
+            ("stepwise", dict(steps_per_call=1, pipeline_depth=1)),
+            ("parked", dict(steps_per_call=4, pipeline_depth=2,
+                            cache_blocks=10))):
+        eng = family_engine(family, **kw)
+        try:
+            results[label] = (await _served(eng, short)
+                              + await _served(eng, long))
+            stats = eng.stats()
+        finally:
+            await eng.close()
+    assert stats["paged"]["preemptions"] >= 1
+    assert stats["parked_token_steps"] > 0
+    for (_, budget, _), want, got in zip(short + long, results["stepwise"],
+                                         results["parked"]):
+        assert len(got[0]) == budget
+        assert got[0] == want[0]
+        # float32's last digits: the two engines group their prefills
+        # differently, and a resumed stream's next token is a prefill's.
+        np.testing.assert_allclose(got[1], want[1], rtol=0, atol=1e-5)
+
+
+async def test_no_step_past_a_budget_writes_or_routes(family):
+    """What the device did, read off the device: a request of 16 prompt
+    tokens (one whole block) and a budget of 6 owes five decode steps,
+    which write rows 0-4 of its second block; of the other 11 steps of
+    its four waves (fixed depth: none is suppressed) none writes a row,
+    and none is given an expert."""
+    from kfserving_tpu.models.decoder import KVCache
+
+    eng = family_engine(family, max_slots=2, steps_per_call=4,
+                        pipeline_depth=2, adaptive_depth=False)
+    try:
+        tokens, reason = await eng.complete(_prompt(16, 7),
+                                            max_new_tokens=6)
+        await _settled(eng)
+        stats = eng.stats()
+        whole = next(i for i, kind in enumerate(eng._cache_layers)
+                     if isinstance(kind, KVCache) and kind.window is None)
+        pool_k = np.asarray(eng._caches[whole][0], np.float32)
+        if eng._moe is not None:
+            eng._moe.drain()
+            routed = sum(eng._moe.pairs.values()) + eng._moe.elsewhere
+            expert_layers = eng._moe.layer_steps // stats["token_steps"]
+    finally:
+        await eng.close()
+    assert (len(tokens), reason) == (6, "length")
+    assert stats["token_steps"] >= 8
+    assert stats["wasted_token_steps"] == stats["token_steps"] - 5
+    assert stats["parked_token_steps"] == stats["wasted_token_steps"]
+    written = np.flatnonzero(np.abs(pool_k).sum(axis=(1, 2)))
+    assert len(written) == 2
+    rows = np.abs(pool_k[written[1]]).sum(axis=1) > 0
+    assert rows.tolist() == [True] * 5 + [False] * (PARKED_BS - 5)
+    if eng._moe is not None:
+        assert expert_layers >= 2
+        assert routed == (16 + 5) * eng._moe.per_token * expert_layers
+
+
+@pytest.mark.parametrize("eos", [False, True], ids=["budget", "eos"])
+async def test_parked_steps_are_the_wasted_steps_of_a_budgets_end(tiny,
+                                                                  eos):
+    """Every dead step past a budget's end is one the device was told
+    of; one past an EOS is not."""
+    module, variables, _ = tiny
+    prompt = [5, 9, 2, 7, 11]
+    ref = ref_greedy(module, variables, prompt, 12)
+    eng = make_engine(tiny, max_slots=2, steps_per_call=4,
+                      pipeline_depth=2, adaptive_depth=False,
+                      eos_id=ref[5] if eos else None)
+    try:
+        got = await asyncio.gather(
+            eng.complete(prompt, max_new_tokens=12),
+            eng.complete([7, 1, 4], max_new_tokens=7))
+        await _settled(eng)
+        stats = eng.stats()
+    finally:
+        await eng.close()
+    assert got[0][1] == ("eos" if eos else "length")
+    assert stats["wasted_token_steps"] > 0
+    if eos:
+        assert (0 < stats["parked_token_steps"]
+                < stats["wasted_token_steps"])
+    else:
+        assert stats["parked_token_steps"] == stats["wasted_token_steps"]
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["whole", "ring"])
+def test_a_masked_table_walks_the_live_rows_alone(window):
+    """What `decode_fn` does to a step's table, against `paged_walk`: with
+    the rows past their stop masked to -1, the walk lists exactly the
+    blocks that the live rows' own tables list, in a whole-context table
+    and in a ring, and a decode write through the masked table leaves a
+    parked row's blocks as they were."""
+    from kfserving_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(4)
+    b, bs = 8, 16
+    mb = 6 if window is None else pa.ring_blocks(window, bs)
+    positions = rng.integers(1, (6 if window is None else 12) * bs - 1, b)
+    stops = positions + rng.integers(-3, 4, b)
+    stops[0], stops[1] = 0, positions[1]       # free; at its stop
+    live = positions < stops
+    assert live.any() and not live.all()
+    table = rng.permutation(b * mb).astype(np.int32).reshape(b, mb)
+    masked = jnp.where(jnp.asarray(live)[:, None], table, -1)
+    lengths = jnp.asarray(positions + 1, jnp.int32)
+
+    def walked(tbl, rows):
+        pairs, count = pa.paged_walk(jnp.asarray(tbl), lengths, bs, window)
+        pairs = np.asarray(pairs)[:int(count[0])]
+        assert set(pairs // mb) <= set(rows)
+        return [int(np.asarray(tbl).reshape(-1)[p]) for p in pairs]
+
+    everyone = np.arange(b)
+    want = [blk for row in everyone[live]
+            for blk in walked(np.where((everyone == row)[:, None],
+                                       table, -1), [row])]
+    assert walked(masked, everyone[live]) == want
+    assert len(want) > 0
+
+    pool = jnp.zeros(pa.pool_shape(b * mb, bs, 2, 8), jnp.float32)
+    step = jnp.ones((b, 2, 8), jnp.float32)
+    pool_k, pool_v = pa.paged_write(pool, pool, step, step, masked,
+                                    jnp.asarray(positions, jnp.int32),
+                                    window)
+    written = np.flatnonzero(np.asarray(pool_k).sum(axis=(1, 2)))
+    column = positions // bs % mb if window else positions // bs
+    assert sorted(written) == sorted(
+        table[row, column[row]] for row in everyone[live])
+    np.testing.assert_array_equal(pool_k, pool_v)

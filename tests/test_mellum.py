@@ -494,10 +494,13 @@ async def test_a_window_layer_holds_its_ring_and_a_global_layer_its_context(
     assert held[-1][2] >= 10 * WINDOW // BS - 1
     window, whole = stats["paged"]["pools"]["window"], \
         stats["paged"]["pools"]["global"]
-    # 60 steps from context 100: min(len, 16) rows in min(blocks, 3) columns
-    assert engine._win_context_tokens == 60 * WINDOW
-    assert engine._win_blocks_walked == 60 * RING
-    assert engine._kv_context_tokens == sum(range(101, 161))
+    # The prefill answers the first token and 59 steps from context 100
+    # the rest: min(len, 16) rows in min(blocks, 3) columns each.  The
+    # fifteenth wave's fourth step is past the budget: parked, it walks
+    # nothing.
+    assert engine._win_context_tokens == 59 * WINDOW
+    assert engine._win_blocks_walked == 59 * RING
+    assert engine._kv_context_tokens == sum(range(101, 160))
     assert window["block_fill"] == pytest.approx(WINDOW / (RING * BS), abs=1e-3)
     assert whole["block_fill"] > 0.9
     assert window["recycled"] >= (160 - 100) // BS

@@ -709,8 +709,9 @@ def test_paged_walk_lists_each_rows_blocks_in_order(seed):
 
 async def test_decode_waves_count_the_blocks_and_tokens_they_read(tiny):
     """`kv_block_fill`: over the rows that hold a request when a wave is
-    delivered and over its steps, context tokens (the step's own
-    included) against the blocks that hold them."""
+    delivered and over its steps as far as the request's budget runs,
+    context tokens (the step's own included) against the blocks that
+    hold them."""
     from kfserving_tpu.observability import metrics as obs
 
     prompt, new, k = [5, 9, 2, 7, 11, 3, 8], 14, 4
@@ -723,8 +724,9 @@ async def test_decode_waves_count_the_blocks_and_tokens_they_read(tiny):
         await eng.close()
     assert len(got) == new
     # Prefill answers the first token; the other 13 take 4 waves of 4
-    # steps, the last of which decodes 3 steps past the request's end.
-    context = len(prompt) + 1 + np.arange(-(-(new - 1) // k) * k)
+    # steps, the last of which has 3 steps past the request's end: the
+    # row is parked for them and walks nothing.
+    context = len(prompt) + 1 + np.arange(new - 1)
     tokens, blocks = int(context.sum()), int((-(-context // BS)).sum())
     assert eng._kv_context_tokens == tokens
     assert eng._kv_blocks_walked == blocks

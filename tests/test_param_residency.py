@@ -223,6 +223,7 @@ def _decode_call(eng):
         eng.variables, eng._caches, jnp.asarray(table),
         jnp.asarray([3, 17, 42, 5], jnp.int32),
         jnp.asarray([0, 9, 30, 1], jnp.int32),
+        jnp.full(SLOTS, eng.max_seq, jnp.int32),  # no budget ends here
         jnp.asarray([0.0, 0.0, 0.8, 0.0], jnp.float32),
         jnp.zeros(SLOTS, jnp.int32), jnp.ones(SLOTS, jnp.float32),
         jnp.arange(SLOTS, dtype=jnp.int32))
