@@ -558,6 +558,26 @@ batch = (q, pk, pv, jnp.asarray(idle_table), jnp.asarray(idle_lengths))
 got = paged_attention_tpu(*batch, interpret=interpret)
 out["paged"] = err(got[live], paged_attention_xla(*batch)[live])
 assert not np.asarray(got, np.float32)[[free, parked]].any(), "idle rows"
+# A narrow pool, 8 query heads on each of 4 KV heads of 128, where one loop
+# iteration of the kernel takes 4 blocks of a row: rows whose last chunk is
+# full (4, 8 blocks), one block over (5, 9) and ragged (1, 2, 13), a free
+# and a parked row between them.
+walked = [5, 1, 0, 9, 4, 0, 13, 2, 8]
+gmb, gnb = 13, 64
+gpool = pool_shape(gnb, bs, 4, 128)
+gq, gk, gv = normal(len(walked), 1, 32, 128), normal(*gpool), normal(*gpool)
+gtable = np.full((len(walked), gmb), -1, np.int32)
+glengths = np.zeros(len(walked), np.int32)
+for i, c in enumerate(walked):
+    gtable[i, :c] = rng.choice(gnb, size=c, replace=False)
+    glengths[i] = (c - 1) * bs + rng.integers(1, bs + 1)
+glengths[2], glengths[5] = 3 * gmb * bs, gmb * bs + 1      # free; parked,
+gtable[5, :2] = [7, 11]                             # its chunks in place
+batch = (gq, gk, gv, jnp.asarray(gtable), jnp.asarray(glengths))
+got = paged_attention_tpu(*batch, interpret=interpret)
+glive = np.flatnonzero(walked)
+out["paged_grouped"] = err(got[glive], paged_attention_xla(*batch)[glive])
+assert not np.asarray(got, np.float32)[[2, 5]].any(), "idle rows"
 table, lengths = jnp.asarray(table), jnp.asarray(lengths)
 # The decode step's write: each row at its length, the kernel against the
 # scatter (exact: both only move values).
@@ -601,7 +621,8 @@ def phase_kernels(config: dict, platform: str) -> dict:
     """The Pallas kernels against their XLA formulations on seeded bf16
     inputs at the served shapes, in a child of its own; the paged decode
     kernel with a free and a parked row in its batch, which must come
-    back as zeros."""
+    back as zeros, and once more on a narrow grouped-query pool, where
+    it takes several blocks of a row in one loop iteration."""
     out = run_child(KERNELS_CHILD, [json.dumps(kernel_shapes(config)),
                                     platform], child_env(), 600.0, "kernels")
     record = json.loads(out.split("KERNELS ", 1)[1])

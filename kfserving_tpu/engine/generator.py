@@ -388,6 +388,16 @@ class GenerationEngine:
                     f"{self._window}, block_size {bs})")
         window_pool_shape = paged_attention.pool_shape(
             self.num_window_blocks, bs, kv_heads, kv_head_dim)
+        # Blocks of a row that one loop iteration of the paged decode
+        # kernel takes, by pool (global, window): the kernel's own rule
+        # on the pool one device holds.
+        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+        shards = tp if kv_heads % tp == 0 else 1
+        self._walk_chunks = tuple(
+            paged_attention.blocks_per_iteration(
+                bs, kv_heads // shards * kv_head_dim, cache_dtype, columns)
+            for columns in (self.blocks_per_slot,
+                            self.window_blocks_per_slot or 1))
 
         def layer_cache(kind):
             """One layer's arrays: its two pools, its state with the
@@ -1118,10 +1128,14 @@ class GenerationEngine:
         # live rows' contexts, and the tokens in them (_distribute).
         self._kv_blocks_walked = 0
         self._kv_context_tokens = 0
+        # The paged kernel's loop iterations over those blocks: a row's
+        # ceil(blocks / blocks_per_iteration) a step.
+        self._kv_walk_iterations = 0
         # The same of a sliding-window layer: min(context, window) rows,
         # in the ring's columns the walk reads.
         self._win_blocks_walked = 0
         self._win_context_tokens = 0
+        self._win_walk_iterations = 0
         # The row count prefill dispatches are held to: configured
         # (`prefill_rows`: the deployment knows what fits beside its
         # parameters), or learned once the runtime has refused one for
@@ -1407,6 +1421,10 @@ class GenerationEngine:
             "kv_block_fill": round(
                 self._kv_context_tokens / max(
                     1, self._kv_blocks_walked * self.block_size), 4),
+            "kv_blocks_per_iteration": round(
+                (self._kv_blocks_walked + self._win_blocks_walked) / max(
+                    1, self._kv_walk_iterations
+                    + self._win_walk_iterations), 4),
             "prefill_rows_cap": self._prefill_rows_cap or 0,
             "prefill_rows": self.prefill_rows or 0,
             "cache_bytes": self.cache_bytes(),
@@ -3945,13 +3963,19 @@ class GenerationEngine:
                        + np.arange(1, k + 1))
             context = context[np.arange(k) < np.asarray(ran)[:, None]]
             tokens_read = int(context.sum())
-            blocks = int((-(-context // self.block_size)).sum())
+            row_blocks = -(-context // self.block_size)
+            blocks = int(row_blocks.sum())
+            chunk, win_chunk = self._walk_chunks
+            iterations = int((-(-row_blocks // chunk)).sum())
             self._kv_context_tokens += tokens_read
             self._kv_blocks_walked += blocks
+            self._kv_walk_iterations += iterations
             obs.generator_decode_kv_context_tokens_total().labels(
                 model=self.name).inc(tokens_read)
             obs.generator_decode_kv_blocks_walked_total().labels(
                 model=self.name).inc(blocks)
+            obs.generator_decode_kv_walk_iterations_total().labels(
+                model=self.name).inc(iterations)
             # Decode reads every live slot's resident KV plus the full
             # parameter set once per token step — the bandwidth-bound
             # working set the HBM-utilization gauge divides by peak.
@@ -3964,17 +3988,23 @@ class GenerationEngine:
                 # columns of its ring that the sequence has reached.
                 ring = self.window_blocks_per_slot
                 win_tokens = int(np.minimum(context, self._window).sum())
-                win_blocks = int(np.minimum(
-                    -(-context // self.block_size), ring).sum())
+                win_row_blocks = np.minimum(row_blocks, ring)
+                win_blocks = int(win_row_blocks.sum())
+                win_iterations = int(
+                    (-(-win_row_blocks // win_chunk)).sum())
                 self._win_context_tokens += win_tokens
                 self._win_blocks_walked += win_blocks
-                for pool, read, walked in (
-                        ("global", tokens_read, blocks),
-                        ("window", win_tokens, win_blocks)):
+                self._win_walk_iterations += win_iterations
+                for pool, read, walked, looped in (
+                        ("global", tokens_read, blocks, iterations),
+                        ("window", win_tokens, win_blocks,
+                         win_iterations)):
                     obs.generator_decode_kv_pool_context_tokens_total(
                         ).labels(model=self.name, pool=pool).inc(read)
                     obs.generator_decode_kv_pool_blocks_walked_total(
                         ).labels(model=self.name, pool=pool).inc(walked)
+                    obs.generator_decode_kv_pool_walk_iterations_total(
+                        ).labels(model=self.name, pool=pool).inc(looped)
 
     # -- speculative decoding ----------------------------------------------
     async def _spec_or_fallback_wave(self, loop, inflight) -> None:

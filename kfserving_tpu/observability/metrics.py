@@ -452,6 +452,23 @@ def generator_decode_kv_pool_context_tokens_total():
         "for pool=global, min(context, window) for pool=window")
 
 
+def generator_decode_kv_walk_iterations_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_decode_kv_walk_iterations_total",
+        "Loop iterations the paged decode kernel of one layer needed "
+        "for those blocks: ceil(blocks / n) a row and step, n the "
+        "consecutive blocks of a row one iteration takes "
+        "(ops/paged_attention.blocks_per_iteration: 1 where the pool "
+        "is wide, so that this equals the blocks walked)")
+
+
+def generator_decode_kv_pool_walk_iterations_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_decode_kv_pool_walk_iterations_total",
+        "The same by pool, for a model with sliding-window layers: "
+        "each pool's blocks walked in its own table's iterations")
+
+
 def generator_window_blocks_recycled_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_window_blocks_recycled_total",

@@ -29,7 +29,10 @@ Two implementations with one contract:
   (`paged_walk` lists them from the table and the lengths, once a
   step), bringing K and V blocks from the pools in HBM through VMEM by
   its own copies, the next pair's in flight while this one is
-  computed.  Its work is the blocks that hold context: a short
+  computed; where the pool is narrow a loop iteration takes several
+  consecutive blocks of a row at once (`blocks_per_iteration`), so
+  that its copies, and not the iteration itself, are what it costs.
+  Its work is the blocks that hold context: a short
   sequence in a long-context pool costs its length, not the pool
   width, and a slot nobody decodes in costs a scalar test.  The
   dispatcher picks it from shapes and the backend; under a mesh it
@@ -93,6 +96,32 @@ def _sublanes(dtype) -> int:
 # pipeline, in place of the one a grid would have given it.
 _BUFFERS = 3
 
+# K + V bytes one loop iteration of the paged kernel should bring in,
+# and the most blocks it takes for them.  An iteration costs about half
+# a microsecond whatever it copies (two semaphore waits, the scalar
+# reads, one max / exp / sum, the accumulator's rescale, in a chain the
+# next iteration waits for); a MiB is what a pool 2048 wide copies in
+# one block pair, where the kernel reads 91% of the bandwidth.  Past 4
+# blocks nothing is gained on the chip (PERF.md, PR 47): by then the
+# copies are what an iteration costs, and a short row's last iteration
+# still multiplies by its whole slot, so 8 cost a pool 256 wide a
+# quarter more a block than 4 on rows of 4 blocks.
+_ITERATION_BYTES = 1 << 20
+_ITERATION_BLOCKS = 4
+
+
+def blocks_per_iteration(block_size: int, pool_width: int, dtype,
+                         table_width: int) -> int:
+    """Consecutive blocks of a row that one loop iteration of
+    `paged_attention_tpu` takes: as many as bring its K and V copies to
+    `_ITERATION_BYTES`, at least 1 and at most `_ITERATION_BLOCKS` or a
+    table's columns.  `pool_width` is the H*D the kernel sees (one
+    shard's under a mesh).  In bfloat16 with blocks of 128: 1 for pools
+    1280 and 2048 wide, 4 for 512 and 256."""
+    pair = 2 * block_size * pool_width * jnp.dtype(dtype).itemsize
+    return max(1, min(_ITERATION_BYTES // pair, _ITERATION_BLOCKS,
+                      table_width))
+
 
 def ring_blocks(window: int, block_size: int) -> int:
     """Columns of a sliding-window layer's table: the blocks a window of
@@ -109,7 +138,23 @@ def _ring_positions(lengths, columns, table_width: int, block_size: int):
     return (last - (last - columns) % table_width) * block_size
 
 
-def paged_walk(block_table, lengths, block_size: int, window=None):
+def _blocks_walked(block_table, lengths, block_size: int, window=None):
+    """[B] int32: the leading columns of its table row that each row's
+    decode step reads (`paged_walk`)."""
+    mb = block_table.shape[1]
+    if window is None:
+        wanted = jnp.where((lengths > 0) & (lengths <= mb * block_size),
+                           -(-lengths // block_size), 0)
+    else:
+        wanted = jnp.where(lengths > 0,
+                           jnp.minimum(-(-lengths // block_size), mb), 0)
+    columns = jnp.arange(mb, dtype=jnp.int32)
+    held = jnp.min(jnp.where(block_table < 0, columns, mb), axis=1)
+    return jnp.minimum(wanted, held)
+
+
+def paged_walk(block_table, lengths, block_size: int, window=None,
+               chunk: int = 1):
     """The (row, column) pairs of `block_table` a decode step must
     read, in the order `paged_attention_tpu` walks them, as flat table
     indices `row * MB + column` in a [B*MB] int32 list, and how many of
@@ -122,35 +167,34 @@ def paged_walk(block_table, lengths, block_size: int, window=None):
     table is a ring (`ring_blocks`): a row walks the columns it has
     reached, all MB of them once it is a window long, and it is the
     table alone (all -1 for a free or a parked row) that says a row
-    holds no request."""
+    holds no request.
+    With `chunk` = n > 1 (`blocks_per_iteration`) an entry stands for
+    up to n consecutive columns of its row and names the first: a row
+    that walks c columns is listed ceil(c / n) times, at columns 0, n,
+    2n, ..., and its last entry stands for the c - n * (ceil(c / n) - 1)
+    columns that are left."""
     b, mb = block_table.shape
-    if window is None:
-        wanted = jnp.where((lengths > 0) & (lengths <= mb * block_size),
-                           -(-lengths // block_size), 0)
-    else:
-        wanted = jnp.where(lengths > 0,
-                           jnp.minimum(-(-lengths // block_size), mb), 0)
-    columns = jnp.arange(mb, dtype=jnp.int32)
-    held = jnp.min(jnp.where(block_table < 0, columns, mb), axis=1)
-    counts = jnp.minimum(wanted, held)                          # [B]
+    counts = _blocks_walked(block_table, lengths, block_size, window)
+    if chunk > 1:
+        counts = -(-counts // chunk)                # entries a row
     ends = jnp.cumsum(counts)
     at = jnp.arange(b * mb, dtype=jnp.int32)
     # Entry `at` belongs to the row after those whose walks end at or
-    # before it, and is that row's column `at` less their blocks.
+    # before it, and is that row's entry `at` less theirs.
     before = at[:, None] >= ends[None, :]                   # [B*MB, B]
     row = jnp.minimum(jnp.sum(before, axis=1), b - 1)
     column = jnp.clip(
-        at - jnp.sum(jnp.where(before, counts[None, :], 0), axis=1),
-        0, mb - 1)
+        (at - jnp.sum(jnp.where(before, counts[None, :], 0), axis=1))
+        * chunk, 0, mb - 1)
     return ((row * mb + column).astype(jnp.int32),
             ends[-1:].astype(jnp.int32))
 
 
-def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
-                  pool_k, pool_v, o_ref, k_blocks, v_blocks, sems,
+def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
+                  q_ref, pool_k, pool_v, o_ref, k_blocks, v_blocks, sems,
                   q_scratch, m_scratch, l_scratch, acc_scratch, *,
                   block_size: int, table_width: int, scale: float,
-                  head_dim: int, group: int, window=None):
+                  head_dim: int, group: int, chunk: int, window=None):
     """Every row's online-softmax walk over its own blocks, all heads
     at once, on blocks [BS, H*D] as the pool stores them: one program,
     one loop over `paged_walk`'s pairs, so the work is the blocks that
@@ -169,7 +213,15 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
     a row (not flat): the same two products.  A row
     that walks nothing is never touched: its output stays zeros.  The
     gathered [B, MB*BS, H, D] view the XLA fallback materializes every
-    step never exists here."""
+    step never exists here.
+    With `chunk` = n > 1 a pair is a row's next n columns or what is
+    left of them (`walked_ref`: the columns each row walks; None when
+    n is 1): a VMEM slot is n blocks long, each block the chunk holds
+    is a copy of its own into its BS rows of the slot, the scores are
+    [H_pad, n * BS] from one product against the whole slot, and one
+    max / exp / sum and one rescale of the accumulator serve them all.
+    Rows of a slot that no copy of this chunk wrote lie past every
+    position the mask lets through."""
     count = count_ref[0]
     h_pad, hd = acc_scratch.shape
 
@@ -181,19 +233,37 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (h_pad, hd), 1)
         return (col >= row * head_dim) & (col < (row + 1) * head_dim)
 
-    def copies(i):
-        block = table_ref[pairs_ref[i]]
+    def held(at):
+        """Blocks of the pair at flat table index `at`."""
+        if chunk == 1:
+            return 1
+        return jnp.minimum(
+            walked_ref[at // table_width] - at % table_width, chunk)
+
+    def each_copy(i, at, blocks, do):
+        """`do` on the copy of each of the `blocks` that pair i, at
+        flat table index `at`, holds, into its place in the pair's
+        slot."""
         slot = i % _BUFFERS
-        return [pltpu.make_async_copy(pool.at[block], blocks.at[slot],
-                                      sems.at[j, slot])
-                for j, (pool, blocks) in enumerate(
-                    ((pool_k, k_blocks), (pool_v, v_blocks)))]
+        for j in range(chunk):
+            def _copies(j=j):
+                block = table_ref[at + j]
+                rows = pl.ds(j * block_size, block_size)
+                for s, (pool, slots) in enumerate(
+                        ((pool_k, k_blocks), (pool_v, v_blocks))):
+                    do(pltpu.make_async_copy(
+                        pool.at[block], slots.at[slot, rows],
+                        sems.at[s, slot]))
+            if j == 0:  # every pair holds a block
+                _copies()
+            else:
+                pl.when(j < blocks)(_copies)
 
     def fetch(i):
         @pl.when(i < count)
         def _start():
-            for copy in copies(i):
-                copy.start()
+            at = pairs_ref[i]
+            each_copy(i, at, held(at), lambda copy: copy.start())
 
     def pair(i, _):
         fetch(i + _BUFFERS - 1)  # into the slot pair i - 1 has left
@@ -217,18 +287,25 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
             l_scratch[...] = jnp.zeros_like(l_scratch)
             acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
-        for copy in copies(i):
-            copy.wait()
-        k = k_blocks[slot].astype(q_scratch.dtype)            # [bs, hd]
+        blocks = held(at)
+        each_copy(i, at, blocks, lambda copy: copy.wait())
+        k = k_blocks[slot].astype(q_scratch.dtype)        # [n * bs, hd]
         s = jax.lax.dot_general(
             q_scratch[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [h_pad, bs]
-        if window is None:
-            first = column * block_size
-        else:  # the ring column's place in the sequence
-            first = _ring_positions(row_len, column, table_width,
-                                    block_size)
-        pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            preferred_element_type=jnp.float32) * scale  # [h_pad, n * bs]
+        within = jax.lax.broadcasted_iota(jnp.int32,
+                                          (h_pad, block_size), 1)
+        pos = []
+        for j in range(chunk):
+            if window is None:
+                first = (column + j) * block_size
+            else:  # the ring column's place in the sequence
+                first = _ring_positions(row_len, column + j, table_width,
+                                        block_size)
+            if j:  # a block the pair does not hold: past the sequence
+                first = jnp.where(j < blocks, first, row_len)
+            pos.append(first + within)
+        pos = pos[0] if chunk == 1 else jnp.concatenate(pos, axis=1)
         seen = pos < row_len
         if window is not None:
             # A column wholly before the window adds exp(-1e30 - m) = 0
@@ -238,11 +315,11 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
         m_prev = m_scratch[...]                           # [h_pad, 1]
         m_new = jnp.maximum(m_prev,
                             jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                            # [h_pad, bs]
+        p = jnp.exp(s - m_new)                        # [h_pad, n * bs]
         alpha = jnp.exp(m_prev - m_new)                   # [h_pad, 1]
         l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
             p, axis=1, keepdims=True)
-        v = v_blocks[slot]                                # [bs, hd]
+        v = v_blocks[slot]                                # [n * bs, hd]
         pv = jnp.dot(p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)  # [h_pad, hd]
         acc_scratch[...] = acc_scratch[...] * alpha + pv
@@ -264,6 +341,11 @@ def _paged_kernel(pairs_ref, count_ref, table_ref, len_ref, q_ref,
             o_ref[row] = out.astype(o_ref.dtype)
 
     o_ref[...] = jnp.zeros_like(o_ref)
+    if chunk > 1:
+        # p is 0 on the rows of a slot that no copy of the pair wrote,
+        # and 0 x NaN is NaN in p . V: what lies there must be finite,
+        # and what VMEM holds before its first write need not be.
+        v_blocks[...] = jnp.zeros_like(v_blocks)
     for i in range(_BUFFERS - 1):
         fetch(i)
     jax.lax.fori_loop(0, count, pair, None)
@@ -286,7 +368,12 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     mb = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
     lengths = lengths.astype(jnp.int32)
-    pairs, count = paged_walk(block_table, lengths, bs, window)
+    chunk = blocks_per_iteration(bs, hd, pool_k.dtype, mb)
+    pairs, count = paged_walk(block_table, lengths, bs, window, chunk)
+    scalars = (pairs, count, block_table.reshape(-1), lengths)
+    if chunk > 1:
+        scalars = (_blocks_walked(block_table, lengths, bs, window)
+                   .astype(jnp.int32),) + scalars
 
     # The products run in the pool's precision when the query shares
     # it (bfloat16 x bfloat16 with float32 accumulation is what a
@@ -299,9 +386,9 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     # head has several (whole sublane tiles of them: `_kernels_serve`).
     row_shape = (b, 1, hd) if group == 1 else (b, h, d)
     rows = pl.BlockSpec(row_shape, lambda i, *_: (0, 0, 0))
-    blocks = pltpu.VMEM((_BUFFERS, bs, hd), pool_k.dtype)
+    blocks = pltpu.VMEM((_BUFFERS, chunk * bs, hd), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(scalars),
         grid=(1,),
         in_specs=[rows, hbm, hbm],
         out_specs=rows,
@@ -315,13 +402,14 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     )
     kernel = functools.partial(_paged_kernel, block_size=bs,
                                table_width=mb, scale=scale, head_dim=d,
-                               group=group, window=window)
+                               group=group, chunk=chunk, window=window)
+    if chunk == 1:  # every pair is one block: no `walked_ref`
+        kernel = functools.partial(kernel, None)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(row_shape, q.dtype),
         interpret=interpret,
-    )(pairs, count, block_table.reshape(-1), lengths,
-      q.reshape(row_shape), pool_k, pool_v)
+    )(*scalars, q.reshape(row_shape), pool_k, pool_v)
     return out.reshape(b, 1, h, d)
 
 

@@ -196,7 +196,11 @@ async def smoke() -> List[str]:
         model="metrics-probe").inc(640)
     obs.generator_decode_kv_context_tokens_total().labels(
         model="metrics-probe").inc(61000)
+    obs.generator_decode_kv_walk_iterations_total().labels(
+        model="metrics-probe").inc(200)
     for pool in ("global", "window"):
+        obs.generator_decode_kv_pool_walk_iterations_total().labels(
+            model="metrics-probe", pool=pool).inc(180)
         obs.generator_decode_kv_pool_blocks_walked_total().labels(
             model="metrics-probe", pool=pool).inc(576)
         obs.generator_decode_kv_pool_context_tokens_total().labels(

@@ -363,24 +363,43 @@ def test_held_plain_experts_kernel_compiles_for_described_v5e(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def test_grouped_query_paged_kernel_compiles_for_described_v5e(v5e):
-    """The paged decode kernel at 2 x 128 K/V lanes with 16 query heads
-    on each KV head, 64 rows over the configuration's pool: one Mosaic
-    call, the pools not copied."""
+@pytest.mark.parametrize("cell, window", [
+    ("nemotron", None), ("mellum", None), ("mellum", 1024)],
+    ids=["2x128-16q", "4x128-8q", "4x128-8q-ring"])
+def test_grouped_query_paged_kernel_compiles_for_described_v5e(v5e, cell,
+                                                               window):
+    """The paged decode kernel on the two narrow pools, 2 x 128 K/V
+    lanes with 16 query heads on each KV head and 4 x 128 with 8, 64
+    rows over the configuration's pool and table (a ring of 9 columns
+    for a window layer): one Mosaic call, the pools not copied.  A loop
+    iteration takes 4 blocks of a row here, and the slots that hold them
+    (3 x 4 blocks of K and of V, 1.5 and 3 MiB) are the kernel's VMEM:
+    the program's temporaries in HBM are the walk's lists and no more."""
     from kfserving_tpu.ops import paged_attention
 
-    serving = _nemotron_serving()
+    serving = _nemotron_serving() if cell == "nemotron" else \
+        _mellum_serving()
     kw = serving["arch_kwargs"]
+    columns = serving["max_seq"] // serving["block_size"]
+    blocks = serving["cache_blocks"]
+    if window is not None:
+        columns = paged_attention.ring_blocks(window, serving["block_size"])
+        blocks = serving["window_cache_blocks"]
     args = _paged_args(serving["max_slots"], kw["num_kv_heads"],
-                       kw["head_dim"], serving["cache_blocks"],
-                       serving["block_size"],
-                       serving["max_seq"] // serving["block_size"])
+                       kw["head_dim"], blocks, serving["block_size"],
+                       columns)
     args[0] = ((serving["max_slots"], 1, kw["num_heads"], kw["head_dim"]),
                jnp.bfloat16, P())
-    compiled = _compile(paged_attention.paged_attention_sharded, args, v5e,
-                        sharded=False)
+    assert paged_attention.blocks_per_iteration(
+        serving["block_size"], args[1][0][2], jnp.bfloat16, columns) == 4
+    compiled = _compile(
+        functools.partial(paged_attention.paged_attention_sharded,
+                          window=window), args, v5e, sharded=False)
     assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 1
     assert _pool_copies(compiled, args[1][0]) == []
+    memory = compiled.memory_analysis()
+    print(f"{cell} paged kernel, window {window}: {memory}")
+    assert memory.temp_size_in_bytes < 2**18, memory
 
 
 def _decode_program(v5e, monkeypatch, serving: dict):
@@ -510,6 +529,9 @@ def test_nemotron_decode_program_fits_the_described_v5e(v5e, monkeypatch):
     assert pool == (768, 128, 256)
     assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 2
     assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 2
+    # one walk a step for both attention layers (7 operations; 14 if
+    # each layer listed its own chunks)
+    assert len(_walk_operations(compiled)) < 10
     assert compiled.as_text().count("moe_experts_touched") >= 7
     assert "ragged-dot" not in compiled.as_text()
     assert [line for line in compiled.as_text().splitlines()

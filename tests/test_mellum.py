@@ -33,6 +33,7 @@ import mellum_reference as reference  # noqa: E402
 from kfserving_tpu.engine.generator import GenerationEngine  # noqa: E402
 from kfserving_tpu.models import create_model, init_params, mellum  # noqa: E402
 from kfserving_tpu.models.decoder import KVCache, cached_attention  # noqa: E402
+from kfserving_tpu.observability import metrics as obs  # noqa: E402
 from kfserving_tpu.ops import dot_product_attention, moe  # noqa: E402
 from kfserving_tpu.ops import paged_attention as pa  # noqa: E402
 from kfserving_tpu.protocol.errors import InvalidInput  # noqa: E402
@@ -378,31 +379,48 @@ def _ring_case(rng, lengths, heads=2, d=32, group=4, ring=RING, bs=BS,
     return q, pool_k, pool_v, table, want
 
 
-@pytest.mark.parametrize("lengths", [
-    (5, 16, 17, 24),        # under the window, at it, past it, at a ring
-    (25, 33, 160, 7),       # recycled once, twice, ten windows long
-    (48, 0, 41, 8),         # a row never fed walks nothing
+@pytest.mark.parametrize("window, lengths", [
+    (WINDOW, (5, 16, 17, 24)),   # under the window, at it, past it, at a ring
+    (WINDOW, (25, 33, 160, 7)),  # recycled once, twice, ten windows long
+    (WINDOW, (48, 0, 41, 8)),    # a row never fed walks nothing
+    # A ring of 9, wider than the 4 blocks a loop iteration takes and no
+    # multiple of them (4 + 4 + 1, as the 1024-token window's in blocks
+    # of 128): under the window, at it, a block past it, ten windows long.
+    (64, (5, 30, 63, 64)),
+    (64, (65, 72, 640, 0)),
+    (64, (100, 33, 577, 8)),
 ])
-def test_paged_kernel_with_a_window_in_interpret_mode(lengths):
+def test_paged_kernel_with_a_window_in_interpret_mode(window, lengths):
     """The Pallas decode kernel over rings (interpret mode) against the
     XLA path and the definition: 8 query heads on 2 KV heads."""
+    ring = pa.ring_blocks(window, BS)
+    chunk = pa.blocks_per_iteration(BS, 2 * 32, jnp.float32, ring)
+    assert (ring, chunk) in ((RING, RING), (9, 4))
     rng = np.random.default_rng(sum(lengths))
-    q, pool_k, pool_v, table, want = _ring_case(rng, lengths)
+    q, pool_k, pool_v, table, want = _ring_case(rng, lengths, ring=ring,
+                                                window=window)
     lens = jnp.asarray(lengths, jnp.int32)
     args = [jnp.asarray(x) for x in (q, pool_k, pool_v, table)]
-    xla = np.asarray(pa.paged_attention_xla(*args, lens, WINDOW))
+    xla = np.asarray(pa.paged_attention_xla(*args, lens, window))
     got = np.asarray(pa.paged_attention_tpu(*args, lens, interpret=True,
-                                            window=WINDOW))
+                                            window=window))
     live = [r for r, n in enumerate(lengths) if n > 0]
     np.testing.assert_allclose(xla[live], want[live], atol=2e-5, rtol=0)
     np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=0)
     for r, n in enumerate(lengths):
         if n == 0:
             np.testing.assert_array_equal(got[r], 0.0)
-    # the walk reads the ring's columns the sequence has reached, and no more
-    pairs, count = pa.paged_walk(jnp.asarray(table), lens, BS, WINDOW)
-    assert int(count[0]) == sum(min(-(-n // BS), RING) for n in lengths)
-    assert int(count[0]) <= RING * len(lengths)
+    # the walk reads the ring's columns the sequence has reached, and no
+    # more; the kernel's own list has an entry for every `chunk` of them
+    reached = [min(-(-n // BS), ring) for n in lengths]
+    pairs, count = pa.paged_walk(jnp.asarray(table), lens, BS, window)
+    assert int(count[0]) == sum(reached) <= ring * len(lengths)
+    pairs, count = pa.paged_walk(jnp.asarray(table), lens, BS, window,
+                                 chunk)
+    assert int(count[0]) == sum(-(-c // chunk) for c in reached)
+    assert np.asarray(pairs)[:int(count[0])].tolist() == [
+        r * ring + column for r, c in enumerate(reached)
+        for column in range(0, c, chunk)]
 
 
 def test_ring_write_and_chunk_attention_follow_the_ring():
@@ -501,6 +519,20 @@ async def test_a_window_layer_holds_its_ring_and_a_global_layer_its_context(
     assert engine._win_context_tokens == 59 * WINDOW
     assert engine._win_blocks_walked == 59 * RING
     assert engine._kv_context_tokens == sum(range(101, 160))
+    # The kernel's loop iterations, by each pool's own table: the whole
+    # ring of 3 at once, the global layer's columns 4 at a time.
+    assert engine._walk_chunks == (4, RING)
+    assert engine._win_walk_iterations == 59
+    columns = [-(-n // BS) for n in range(101, 160)]
+    assert engine._kv_blocks_walked == sum(columns)
+    assert engine._kv_walk_iterations == sum(-(-c // 4) for c in columns)
+    assert stats["kv_blocks_per_iteration"] == round(
+        (sum(columns) + 59 * RING)
+        / (engine._kv_walk_iterations + 59), 4)
+    for pool, iterations in (("global", engine._kv_walk_iterations),
+                             ("window", 59)):
+        assert obs.generator_decode_kv_pool_walk_iterations_total(
+            ).labels(model="mellum-test", pool=pool).value >= iterations
     assert window["block_fill"] == pytest.approx(WINDOW / (RING * BS), abs=1e-3)
     assert whole["block_fill"] > 0.9
     assert window["recycled"] >= (160 - 100) // BS

@@ -573,6 +573,25 @@ def _chat_mix():
     return lengths.tolist(), table.tolist(), free
 
 
+def _wider_than_a_chunk():
+    """8 rows of a 13-column table, wider than the blocks one loop
+    iteration of the kernel takes (`blocks_per_iteration`: 2 or 4 of
+    the narrow pools' here): rows of 1, 4, 5, 8, 9 and 13 blocks, so a
+    row's last chunk is exactly full, one block over and ragged for
+    either; a free row and a parked one between them; block 0 shared."""
+    mb = 13
+    blocks = [1, 4, 5, None, 8, 9, None, 13]
+    lengths = [77, 4 * KERNEL_BS, 4 * KERNEL_BS + 1, 5000,
+               8 * KERNEL_BS - 3, 8 * KERNEL_BS + 100,
+               mb * KERNEL_BS + 1, mb * KERNEL_BS - 50]
+    table, fresh = np.full((len(blocks), mb), -1, np.int32), iter(range(1, 99))
+    for row, held in enumerate(blocks):
+        if held is not None:
+            table[row, :held] = [0] + [next(fresh) for _ in range(held - 1)]
+    table[6, :3] = [next(fresh) for _ in range(3)]  # parked mid-prefill
+    return lengths, table.tolist(), [3, 6]
+
+
 # name -> (lengths, table, rows that walk nothing).  A table of None is
 # KERNEL_TABLE with each row cut to its length, as the engine keeps it.
 KERNEL_CASES = {
@@ -590,21 +609,24 @@ KERNEL_CASES = {
     "every-row-free": ([700, 513, 90], [[-1] * 4] * 3, [0, 1, 2]),
     "whole-table-beside-one-token": ([1, 512, 1], None, []),
     "chat-mix-24x8": _chat_mix(),
+    "wider-than-a-chunk-8x13": _wider_than_a_chunk(),
 }
 
 
-def _pools(rng, nb, bs, h, d):
+def _pools(rng, nb, bs, h, d, dtype="float32"):
     """One layer's K and V as [NB, BS, H, D] arrays (the layout the
-    pool had before it went flat, and the reference's), and the flat
-    pools the ops take: the same bytes."""
+    pool had before it went flat, and the reference's: float32 arrays
+    of the values `dtype` holds), and the flat pools the ops take: the
+    same bytes."""
     from kfserving_tpu.ops.paged_attention import pool_shape
 
-    k4, v4 = (rng.normal(size=(nb, bs, h, d)).astype(np.float32)
+    k4, v4 = (np.asarray(jnp.asarray(rng.normal(size=(nb, bs, h, d)),
+                                     dtype), np.float32)
               for _ in range(2))
     flat = pool_shape(nb, bs, h, d)
     assert flat == (nb, bs, h * d)
-    return k4, v4, jnp.asarray(k4.reshape(flat)), \
-        jnp.asarray(v4.reshape(flat))
+    return k4, v4, jnp.asarray(k4.reshape(flat), dtype), \
+        jnp.asarray(v4.reshape(flat), dtype)
 
 
 def _attention_over_blocks(q, k4, v4, table, allowed):
@@ -623,26 +645,32 @@ def _attention_over_blocks(q, k4, v4, table, allowed):
 
 
 @pytest.mark.parametrize("case", list(KERNEL_CASES))
-@pytest.mark.parametrize("heads", [(20, 64, 1), (16, 128, 1), (4, 64, 1),
-                                   (2, 128, 4), (2, 128, 16)],
-                         ids=["20x64", "16x128", "4x64", "2x128-4q",
-                              "2x128-16q"])
+@pytest.mark.parametrize("heads", [
+    (20, 64, 1, "float32", 1), (16, 128, 1, "float32", 1),
+    (4, 64, 1, "float32", 4), (2, 128, 4, "float32", 4),
+    (2, 128, 16, "float32", 4), (4, 128, 8, "float32", 2),
+    (4, 128, 8, "bfloat16", 4), (2, 128, 16, "bfloat16", 4)],
+    ids=["20x64", "16x128", "4x64", "2x128-4q", "2x128-16q", "4x128-8q",
+         "4x128-8q-bf16", "2x128-16q-bf16"])
 def test_pallas_paged_kernel_matches_xla(heads, case):
     """The Pallas paged-decode kernel (interpret mode on CPU) on the
     flat pool matches the XLA gather reference, and both the plain
     attention over the same bytes as [NB, BS, H, D]: for heads that
     padded a tile (20 x 64), fill it (16 x 128) and are a fraction of
-    one (4 x 64), and for 2 KV heads of 128 that 4 or 16 query heads
-    each read (grouped-query attention: query head j on KV head
+    one (4 x 64), and for 2 or 4 KV heads of 128 that 4, 8 or 16 query
+    heads each read (grouped-query attention: query head j on KV head
     j // group); lengths inside a block, on its edge, of one token
     and of the whole table; a shared block and unallocated (-1) table
-    tails.  A row that walks nothing (free, parked) comes back as
-    zeros, whatever is beside it."""
+    tails.  The narrow pools take 2 or 4 blocks of a row in one loop
+    iteration (a wide one takes 1), as many as the configurations with
+    bfloat16 pools of these widths do.  A row that walks nothing (free,
+    parked) comes back as zeros, whatever is beside it."""
     from kfserving_tpu.ops import paged_attention as pa
 
-    h, d, group = heads
+    h, d, group, dtype, chunk = heads
     lengths, table, idle = KERNEL_CASES[case]
     bs = KERNEL_BS
+    tol = 2e-5 if dtype == "float32" else 2e-2
     rng = np.random.default_rng(h * 1000 + lengths[0])
     if table is None:
         table = np.asarray(KERNEL_TABLE, np.int32)
@@ -650,8 +678,10 @@ def test_pallas_paged_kernel_matches_xla(heads, case):
             table[row, -(-n // bs):] = -1
     table = np.asarray(table, np.int32)
     mb, nb = table.shape[1], max(KERNEL_NB, int(table.max()) + 1)
+    assert pa.blocks_per_iteration(bs, h * d, dtype, mb) == min(chunk, mb)
     q = rng.normal(size=(len(lengths), 1, h * group, d)).astype(np.float32)
-    k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d)
+    k4, v4, pool_k, pool_v = _pools(rng, nb, bs, h, d, dtype)
+    q = np.asarray(jnp.asarray(q, dtype), np.float32)  # as the kernel has it
     lens = jnp.asarray(lengths, jnp.int32)
     live = np.setdiff1d(np.arange(len(lengths)), idle)
     allowed = (np.arange(mb * bs)[None, None, :]
@@ -659,28 +689,73 @@ def test_pallas_paged_kernel_matches_xla(heads, case):
     want = _attention_over_blocks(q, np.repeat(k4, group, axis=2),
                                   np.repeat(v4, group, axis=2), table,
                                   allowed)
-    xla = pa.paged_attention_xla(jnp.asarray(q), pool_k, pool_v,
+    xla = pa.paged_attention_xla(jnp.asarray(q, dtype), pool_k, pool_v,
                                  jnp.asarray(table), lens)
     got = np.asarray(pa.paged_attention_tpu(
-        jnp.asarray(q), pool_k, pool_v, jnp.asarray(table), lens,
-        interpret=True))
+        jnp.asarray(q, dtype), pool_k, pool_v, jnp.asarray(table), lens,
+        interpret=True), np.float32)
     assert got.shape == q.shape
-    np.testing.assert_allclose(np.asarray(xla)[live], want[live],
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(got[live], want[live], rtol=2e-5,
-                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(xla, np.float32)[live],
+                               want[live], rtol=tol, atol=tol)
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
     np.testing.assert_array_equal(got[idle], 0.0)
 
 
+def test_a_slots_stale_rows_reach_no_answer():
+    """A loop iteration that holds fewer blocks than its VMEM slot has
+    room for (a row's ragged last chunk) multiplies by the whole slot:
+    what its copies did not write, memory never written (NaN in this
+    interpreter) or another row's blocks, is masked out of the weights
+    and must not reach the answer through 0 x NaN either.  Blocks that
+    no row owns hold infinities and are never read."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from kfserving_tpu.ops import paged_attention as pa
+
+    h, d, group, bs, mb, nb = 4, 128, 8, KERNEL_BS, 13, 24
+    assert pa.blocks_per_iteration(bs, h * d, jnp.bfloat16, mb) == 4
+    rng = np.random.default_rng(47)
+    k4, v4, _, _ = _pools(rng, nb, bs, h, d, "bfloat16")
+    table = np.full((4, mb), -1, np.int32)
+    fresh = iter(range(1, nb))
+    for row, held in enumerate([5, 1, 9, 2]):   # 4 + 1, 1, 4 + 4 + 1, 2
+        table[row, :held] = [next(fresh) for _ in range(held)]
+    lengths = [4 * bs + 17, 1, 8 * bs + 1, 2 * bs]
+    unowned = np.setdiff1d(np.arange(1, nb), table)
+    k4[unowned], v4[unowned] = np.inf, -np.inf
+    q = np.asarray(jnp.asarray(rng.normal(size=(4, 1, h * group, d)),
+                               jnp.bfloat16), np.float32)
+    allowed = (np.arange(mb * bs)[None, None, :]
+               < np.asarray(lengths)[:, None, None])
+    want = _attention_over_blocks(q, np.repeat(k4, group, axis=2),
+                                  np.repeat(v4, group, axis=2), table,
+                                  allowed)
+    flat = pa.pool_shape(nb, bs, h, d)
+    got = np.asarray(pa.paged_attention_tpu(
+        jnp.asarray(q, jnp.bfloat16),
+        jnp.asarray(k4.reshape(flat), jnp.bfloat16),
+        jnp.asarray(v4.reshape(flat), jnp.bfloat16), jnp.asarray(table),
+        jnp.asarray(lengths, jnp.int32),
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan")),
+        np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 8])
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_paged_walk_lists_each_rows_blocks_in_order(seed):
+def test_paged_walk_lists_each_rows_blocks_in_order(seed, chunk):
     """`paged_walk` against a plain loop: row r walks its first
     ceil(len / BS) columns as far as they are allocated, rows in order;
-    a length of 0 or past the table's coverage walks nothing."""
+    a length of 0 or past the table's coverage walks nothing.  Listed
+    for a kernel that takes `chunk` columns a loop iteration, a row
+    appears once for every `chunk` of its columns, at the first of
+    them; a chunk of 1 lists every column, and is what the argument's
+    default lists."""
     from kfserving_tpu.ops.paged_attention import paged_walk
 
     rng = np.random.default_rng(seed)
-    b, mb, bs = 9, 6, 16
+    b, mb, bs = 9, 11, 16
     lengths = rng.integers(0, mb * bs + 1, b)
     lengths[rng.integers(0, b)] = mb * bs + 1      # parked
     lengths[rng.integers(0, b)] = 3 * mb * bs      # free, still counting
@@ -697,8 +772,13 @@ def test_paged_walk_lists_each_rows_blocks_in_order(seed):
             if table[row, column] < 0:
                 break
             want.append(row * mb + column)
+    each, total = paged_walk(jnp.asarray(table),
+                             jnp.asarray(lengths, jnp.int32), bs)
+    assert np.asarray(each)[:int(total[0])].tolist() == want
+    want = [at for at in want if at % mb % chunk == 0]
     pairs, count = paged_walk(jnp.asarray(table),
-                              jnp.asarray(lengths, jnp.int32), bs)
+                              jnp.asarray(lengths, jnp.int32), bs,
+                              chunk=chunk)
     pairs, count = np.asarray(pairs), np.asarray(count)
     assert pairs.shape == (b * mb,) and count.shape == (1,)
     assert pairs.dtype == count.dtype == np.int32
@@ -707,34 +787,50 @@ def test_paged_walk_lists_each_rows_blocks_in_order(seed):
     assert ((0 <= pairs) & (pairs < b * mb)).all()
 
 
-async def test_decode_waves_count_the_blocks_and_tokens_they_read(tiny):
+@pytest.mark.parametrize("chunk", [None, 1, 2], ids=["rule", "1", "2"])
+async def test_decode_waves_count_the_blocks_and_tokens_they_read(
+        tiny, monkeypatch, chunk):
     """`kv_block_fill`: over the rows that hold a request when a wave is
     delivered and over its steps as far as the request's budget runs,
     context tokens (the step's own included) against the blocks that
-    hold them."""
+    hold them.  `kv_blocks_per_iteration`: those blocks against the
+    paged kernel's loop iterations, ceil(blocks / n) a row and step for
+    the n blocks an iteration takes (the kernel's rule on this pool, or
+    here 1, where they are the blocks, and 2)."""
     from kfserving_tpu.observability import metrics as obs
+    from kfserving_tpu.ops import paged_attention as pa
 
-    prompt, new, k = [5, 9, 2, 7, 11, 3, 8], 14, 4
+    prompt, new, k = [5, 9, 2, 7, 11, 3, 8], 46, 4
+    rule = pa.blocks_per_iteration(BS, 64, tiny[2].dtype, MAX_SEQ // BS)
+    assert rule == 4    # the tiny pool is narrow: 2 heads of 32
+    if chunk is not None:
+        monkeypatch.setattr(pa, "blocks_per_iteration", lambda *_: chunk)
+    n = chunk or rule
     eng = make_paged(tiny, max_slots=2, steps_per_call=k)
-    eng.name = "kv-counted"
+    eng.name = f"kv-counted-{n}"
     try:
         got, _ = await eng.complete(prompt, max_new_tokens=new)
         stats = eng.stats()
     finally:
         await eng.close()
     assert len(got) == new
-    # Prefill answers the first token; the other 13 take 4 waves of 4
+    # Prefill answers the first token; the other 45 take 12 waves of 4
     # steps, the last of which has 3 steps past the request's end: the
     # row is parked for them and walks nothing.
     context = len(prompt) + 1 + np.arange(new - 1)
     tokens, blocks = int(context.sum()), int((-(-context // BS)).sum())
+    iterations = int((-(-(-(-context // BS)) // n)).sum())
     assert eng._kv_context_tokens == tokens
     assert eng._kv_blocks_walked == blocks
+    assert eng._kv_walk_iterations == iterations
+    assert (iterations == blocks) == (n == 1)
     assert stats["kv_block_fill"] == round(tokens / (blocks * BS), 4)
-    assert obs.generator_decode_kv_context_tokens_total().labels(
-        model="kv-counted").value == tokens
-    assert obs.generator_decode_kv_blocks_walked_total().labels(
-        model="kv-counted").value == blocks
+    assert stats["kv_blocks_per_iteration"] == round(blocks / iterations, 4)
+    for counter, value in (
+            (obs.generator_decode_kv_context_tokens_total, tokens),
+            (obs.generator_decode_kv_blocks_walked_total, blocks),
+            (obs.generator_decode_kv_walk_iterations_total, iterations)):
+        assert counter().labels(model=eng.name).value == value
 
 
 # ------------------------------ the flat pool against [NB, BS, H, D]
