@@ -66,6 +66,7 @@ import itertools
 import logging
 import math
 import os
+import statistics
 import threading
 import time
 from collections import OrderedDict, deque
@@ -109,7 +110,10 @@ def _dispatch_timed(program: str):
     return wrap
 
 
-@dataclass
+# eq=False: a request is itself.  Compared by its fields, cancel()'s
+# `_pending.remove` would weigh prompt arrays against each other and
+# miss a request queued behind another.
+@dataclass(eq=False)
 class _Request:
     prompt_ids: np.ndarray
     max_new_tokens: int
@@ -1097,6 +1101,12 @@ class GenerationEngine:
         self._token_steps = 0       # dispatches x steps_per_call
         self.prefills = 0           # prefill dispatches
         self.prefill_requests = 0   # requests admitted through them
+        self.prefill_rows_dispatched = 0  # their programs' rows
+        self.prefill_rows_padded = 0  # the dummy rows among them
+        # Both series read 0 from the start: a scrape that lacks them
+        # is a server without the counters, not one without a prefill.
+        obs.engine_prefill_rows_total().labels(model=self.name)
+        obs.engine_prefill_rows_padded_total().labels(model=self.name)
         self.requests_finished = 0
         self.preemptions = 0        # growth-pressure requeues
         self.prefill_chunks = 0     # chunked-prefill dispatches
@@ -1151,6 +1161,14 @@ class GenerationEngine:
         # depth >= 2, so the stat stays <= wall clock).
         self._decode_device_s = 0.0
         self._last_fetch_done = 0.0
+        # (rows, bucket) -> what that prefill program's last dispatches
+        # took on the device, by the fetch workers' clock: a fetch's
+        # return less the one before it (or less its own launch, where
+        # the device was idle).  A program's first dispatch compiles and
+        # leaves an empty record.  `_prefill_rows_to_take` weighs a
+        # group's pieces against its padded program by these.
+        self._prefill_took_s: Dict[Tuple[int, int], deque] = {}
+        self._last_fetched_t = 0.0
         self._decode_wait_s = 0.0     # host blocked in decode fetches
         self._prefill_wait_s = 0.0    # host blocked in prefill fetches
         self._prefill_device_s = 0.0
@@ -1407,6 +1425,15 @@ class GenerationEngine:
             "steps_per_call": self.steps_per_call,
             "prefills": self.prefills,
             "prefill_requests": self.prefill_requests,
+            "prefill_rows_dispatched": self.prefill_rows_dispatched,
+            "prefill_rows_padded": self.prefill_rows_padded,
+            # What each prefill program's last dispatches took (median),
+            # which is what decides whether a group splits.
+            "prefill_program_ms": {
+                f"{rows}x{bucket}": round(
+                    1e3 * statistics.median(list(took)), 3)
+                for (rows, bucket), took
+                in list(self._prefill_took_s.items()) if took},
             "requests_finished": self.requests_finished,
             "slot_occupancy": round(
                 self._occupied_slot_steps / (steps * self.max_slots), 4),
@@ -2631,39 +2658,76 @@ class GenerationEngine:
 
     def _take_prefill_group(self, force_miss: bool = False):
         """Pop the front run of pending requests that share a prefill
-        bucket, up to the free slot count — they ride ONE prefill
-        dispatch (or up to the row count the runtime has shown it can
-        hold, see _prefill_refused).  Strict FIFO: a different-bucket
-        request at the front is never jumped.  Each taken request's
-        prompt blocks are planned (allocated/prefix-shared) HERE on
-        the loop thread; a request the pool cannot hold yet stays
-        pending (it admits when slots release blocks).  Returns
-        (group, slots, bucket, dest_rows)."""
+        bucket, up to the free slot count (or up to the row count the
+        deployment set or the runtime has shown it can hold, see
+        _prefill_refused) — they ride ONE prefill dispatch.  Where the
+        run is no power of two and its pieces' programs have taken less
+        than the padded one (`_prefill_rows_to_take`), the take stops
+        at the largest power of two in it: the loop's next take has
+        the rest, so 7 go as 4 + 2 + 1 and no program carries a dummy
+        row.  Strict FIFO: a different-bucket request at the front is
+        never jumped.  Each taken request's prompt blocks are planned
+        (allocated/prefix-shared) HERE on the loop thread; a request
+        the pool cannot hold yet stays pending (it admits when slots
+        release blocks).  Returns (group, slots, bucket, dest_rows)."""
         free = [i for i, s in enumerate(self._slots) if s is None]
         if self._prefill_rows_cap is not None:
             free = free[:self._prefill_rows_cap]
-        group: List[_Request] = []
-        bucket = 0
-        dest_rows: List[List[int]] = []
-        while self._pending and len(group) < len(free):
-            if self._is_cold(self._pending[0]):
+        run = bucket = 0
+        for req in itertools.islice(self._pending, len(free)):
+            if self._is_cold(req):
                 break  # cold prompts take the chunked path
-            b = self._bucket_for(self._pending[0].prompt_ids.size)
-            if not group:
-                bucket = b
-            elif b != bucket:
+            b = self._bucket_for(req.prompt_ids.size)
+            if run and b != bucket:
                 break
-            plan = self._plan_prompt_blocks(self._pending[0],
-                                            free[len(group)],
+            bucket = b
+            run += 1
+        group: List[_Request] = []
+        dest_rows: List[List[int]] = []
+        for slot in free[:self._prefill_rows_to_take(run, bucket)]:
+            plan = self._plan_prompt_blocks(self._pending[0], slot,
                                             force_miss=force_miss)
             if plan is None:
                 break  # pool pressure: wait for released blocks
             dest_rows.append(plan)
             group.append(self._pending.popleft())
+        keep = self._prefill_rows_to_take(len(group), bucket)
+        if keep < len(group):
+            # The pool stopped the take short of a power of two: the
+            # rows past the largest one in it go back, plans undone.
+            self._requeue_group(group[keep:], free[keep:len(group)])
+            del group[keep:], dest_rows[keep:]
         now = time.perf_counter()
         for req in group:
             req.taken_t = now
         return group, free[:len(group)], bucket, dest_rows
+
+    def _prefill_rows_to_take(self, rows: int, bucket: int) -> int:
+        """How many of `rows` same-bucket requests the next prefill
+        dispatch carries: all of them, padded to the next power of two
+        with dummy rows, or the largest power of two in `rows`, through
+        the program of exactly that many (the next takes have the
+        rest).  The cut is made where this engine has timed the padded
+        program and the program of every power of two in `rows` (7:
+        those of 8, 4, 2 and 1 rows), and the pieces together took less
+        than the padded one.  Both hold or fail by what the model's
+        programs cost on this device: a dense model's grow with their
+        rows, so 5 as 4 + 1 is five eighths of a program of 8; an
+        expert model's small ones may stream as many experts as its
+        large ones and cost as much.  A program is timed from its
+        second dispatch on, so a split compiles nothing: the programs a
+        process compiles, and when, are those of an engine that always
+        pads."""
+        top = 1 << rows.bit_length() >> 1
+        if top == rows:
+            return rows
+        pieces = [1 << i for i in range(rows.bit_length()) if rows >> i & 1]
+        took = [self._prefill_took_s.get((r, bucket))
+                for r in [2 * top] + pieces]
+        if not all(took):
+            return rows
+        padded_s, *pieces_s = map(statistics.median, took)
+        return top if sum(pieces_s) < padded_s else rows
 
     def _requeue_group(self, group: List[_Request],
                        slots: List[int]) -> None:
@@ -2946,9 +3010,9 @@ class GenerationEngine:
         # host sync (the old blocking admission added a full
         # prefill-dispatch of inter-token stall to every live stream).
         # Items: ("decode", fetch_future, snapshot, t0, seq) or
-        # ("prefill", fetch_future, entries, t0, seq) where entries is
-        # [(slot, _Active|None)] in batch order and seq the launch's
-        # number in the in-flight table.  Fetch futures are
+        # ("prefill", fetch_future, (entries, bucket), t0, seq) where
+        # entries is [(slot, _Active|None)] in batch order and seq the
+        # launch's number in the in-flight table.  Fetch futures are
         # submitted EAGERLY at enqueue (round trips overlap on the
         # 2-worker fetch executor); awaiting in FIFO order preserves
         # delivery order.
@@ -3146,7 +3210,7 @@ class GenerationEngine:
                 fut = loop.run_in_executor(
                     self._executor, self._fetch_joined, seq, "prefill",
                     self._fetch_wave, firsts_h, lp_h)
-                inflight.append(("prefill", fut, entries,
+                inflight.append(("prefill", fut, (entries, bucket),
                                  time.perf_counter(), seq))
                 admitted = True
             active = any(s is not None for s in self._slots)
@@ -3363,7 +3427,7 @@ class GenerationEngine:
                     # (If the poisoned cache chain breaks later waves,
                     # their fetch error still fails everything.)
                     logger.exception("prefill failed")
-                    for slot, act in meta:
+                    for slot, act in meta[0]:
                         if act is not None and \
                                 self._slots[slot] is act:
                             self._free_slot_state(slot)
@@ -3386,6 +3450,10 @@ class GenerationEngine:
             now = time.perf_counter()
             busy = now - max(t0, self._last_fetch_done)
             self._last_fetch_done = now
+            # The device's time for this program: fetches return in the
+            # device's order, each when its program has run.
+            took_s = max(0.0, fetched_t - max(t0, self._last_fetched_t))
+            self._last_fetched_t = max(fetched_t, self._last_fetched_t)
             # Device-path timeline: one device-track slice per fetched
             # dispatch (the dispatch->fetch busy interval — the same
             # overlap-corrected span the device_s stats accumulate, so
@@ -3501,6 +3569,8 @@ class GenerationEngine:
             else:
                 self._prefill_device_s += busy
                 self._prefill_wait_s += wait_s
+                meta, bucket = meta
+                self._note_prefill_took(len(fetched), bucket, took_s)
                 TIMELINE.record(
                     "device", "prefill.bucket", dur_s=dev_dur,
                     t_end=wall, attrs={"batch": len(meta)})
@@ -3516,12 +3586,31 @@ class GenerationEngine:
                                          device_ms=dev_dur * 1000.0)
             self._process_deferred_frees()
 
+    def _note_prefill_took(self, rows: int, bucket: int,
+                           seconds: float) -> None:
+        """One fetched dispatch of the (rows, bucket) prefill program
+        took `seconds` on the device.  The first of a program compiled
+        inside its launch: it opens the record and is not kept.  Eight
+        are: enough for a median that one late fetch does not move."""
+        took = self._prefill_took_s.get((rows, bucket))
+        if took is None:
+            self._prefill_took_s[rows, bucket] = deque(maxlen=8)
+        else:
+            took.append(seconds)
+
     def _finish_prefill(self, firsts: np.ndarray, lp, entries,
                         device_ms: float = 0.0):
         """Deliver a fetched prefill batch's first tokens.  A slot
         whose _Active was replaced since enqueue (cancel) discards its
         row, exactly like _distribute."""
         self.prefills += 1
+        padded = len(firsts) - len(entries)
+        self.prefill_rows_dispatched += len(firsts)
+        self.prefill_rows_padded += padded
+        obs.engine_prefill_rows_total().labels(
+            model=self.name).inc(len(firsts))
+        obs.engine_prefill_rows_padded_total().labels(
+            model=self.name).inc(padded)
         # Even split of the bucket dispatch across the rows whose cost
         # records are still OPEN (slot unchanged since enqueue).  A
         # cancelled row's record was finalized at cancel time —
@@ -3660,7 +3749,8 @@ class GenerationEngine:
         sync — prompt ingestion rides the same in-flight pipeline as
         decode waves, so admissions no longer stall live streams by a
         full prefill dispatch.  The batch pads to a pow2 row bucket so
-        compile count stays bounded; padding rows carry an
+        compile count stays bounded (where `_take_prefill_group` cuts
+        a run at a power of two nothing pads); padding rows carry an
         out-of-bounds slot sentinel the scatters drop."""
         # This group's plans may have evicted spill-pending blocks the
         # insert below will rewrite: gather first.

@@ -419,6 +419,22 @@ def generator_params_narrowed_bytes():
         "that stores what it reads")
 
 
+def engine_prefill_rows_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_engine_prefill_rows_total",
+        "Rows of the prefill programs whose results were fetched, dummy "
+        "rows included: a group of same-bucket arrivals rides a program "
+        "of power-of-two rows")
+
+
+def engine_prefill_rows_padded_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_engine_prefill_rows_padded_total",
+        "Those of the prefill programs' rows that no request filled "
+        "(length 1, scattered nowhere): the whole program runs over them "
+        "for nobody")
+
+
 def generator_decode_kv_blocks_walked_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_decode_kv_blocks_walked_total",

@@ -192,6 +192,10 @@ async def smoke() -> List[str]:
         model="metrics-probe").set(1.55e9)
     obs.generator_params_narrowed_bytes().labels(
         model="metrics-probe").set(1.55e9)
+    obs.engine_prefill_rows_total().labels(
+        model="metrics-probe").inc(24)
+    obs.engine_prefill_rows_padded_total().labels(
+        model="metrics-probe").inc(3)
     obs.generator_decode_kv_blocks_walked_total().labels(
         model="metrics-probe").inc(640)
     obs.generator_decode_kv_context_tokens_total().labels(

@@ -1175,3 +1175,439 @@ def test_a_masked_table_walks_the_live_rows_alone(window):
     assert sorted(written) == sorted(
         table[row, column[row]] for row in everyone[live])
     np.testing.assert_array_equal(pool_k, pool_v)
+
+
+# ------------------------------- a group goes as the row counts it fills
+# A group of b same-bucket requests pads to the next power of two with
+# dummy rows, or is taken as the binary pieces of b, one prefill program
+# of exactly its rows a piece: where the engine has timed the padded
+# program and every piece's, and the pieces took less.
+
+SPLIT_BS = 8
+_ROWS_COUNTED = ("prefills", "prefill_requests", "prefill_rows_dispatched",
+                 "prefill_rows_padded")
+
+
+def _pieces(b: int):
+    """The powers of two in b, largest first: 13 is 8 + 4 + 1."""
+    return [1 << i for i in reversed(range(b.bit_length())) if b >> i & 1]
+
+
+def _split_prompt(i: int):
+    """Request i of a burst: every third one opens with one whole block
+    that the others of its kind share (a later piece's prefix hit on an
+    earlier piece's block), all within the 16-token bucket."""
+    if i % 3 == 0:
+        return list(range(1, SPLIT_BS + 1)) + [20 + i, 50 + i][:1 + i % 2]
+    return [(7 * i + j) % 90 + 1 for j in range(1 + i % 13)]
+
+
+def split_engine(tiny, **kw):
+    kw.setdefault("max_slots", 8)
+    return make_engine(tiny, prefill_buckets=[16, MAX_SEQ],
+                       block_size=SPLIT_BS, **kw)
+
+
+def _price(eng, cost=float):
+    """Every prefill dispatch from now on is timed at cost(rows)
+    seconds, a program's first too: what the rule decides is then the
+    test's to say, not this CPU's clock."""
+    def note(rows, bucket, seconds):
+        eng._prefill_took_s[rows, bucket] = [cost(rows)]
+
+    eng._note_prefill_took = note
+
+
+def _watch(eng, monkeypatch=None):
+    """Record the rows of every prefill program launched, the requests
+    in the order of their first token, and every program shape that is
+    noted for the first time (a compile, on a chip)."""
+    from kfserving_tpu.engine import compile_cache
+
+    seen = {"rows": [], "first": [], "noted": []}
+    prefill, emit = eng._prefill, eng._emit
+    note = compile_cache.note_compilation
+
+    def watched_prefill(variables, ids, *rest):
+        seen["rows"].append(ids.shape[0])
+        return prefill(variables, ids, *rest)
+
+    def watched_emit(slot, token, lp_rec=None):
+        req = eng._slots[slot].req
+        if req.last_emit_t is None:
+            seen["first"].append(req)
+        return emit(slot, token, lp_rec)
+
+    def watched_note(source, key):
+        seen["noted"].append(key)
+        return note(source, key)
+
+    eng._prefill, eng._emit = watched_prefill, watched_emit
+    if monkeypatch is not None:
+        monkeypatch.setattr(compile_cache, "note_compilation", watched_note)
+    return seen
+
+
+def _order(reqs, firsts):
+    """The burst's indices of the requests in `firsts`."""
+    return [reqs.index(r) for r in firsts]
+
+
+async def _burst(eng, seen, n, prompt=_split_prompt):
+    """n requests submitted before the scheduler wakes: one run at the
+    front of the queue."""
+    before = eng.stats()
+    for record in seen.values():
+        del record[:]
+    reqs = [eng.submit(prompt(i), max_new_tokens=3) for i in range(n)]
+    tokens = await asyncio.wait_for(
+        asyncio.gather(*(_drain(eng, r) for r in reqs)), timeout=120)
+    after = eng.stats()
+    return {"tokens": tokens, "rows": list(seen["rows"]),
+            "noted": list(seen["noted"]),
+            "first_in_order": _order(reqs, seen["first"]),
+            "counted": {k: after[k] - before[k] for k in _ROWS_COUNTED}}
+
+
+def _prefill_programs(eng):
+    return {rows for kind, rows, *_ in eng._dispatched_programs
+            if kind == "prefill"}
+
+
+@pytest.fixture(scope="module")
+def warm_bursts(tiny):
+    """(each request's tokens sent alone, {b: what a burst of b gave})
+    on one engine whose every power-of-two prefill program has been
+    dispatched, and timed at its rows, before the bursts."""
+    from kfserving_tpu.observability import REGISTRY
+
+    async def collect():
+        eng = split_engine(tiny, max_slots=16)
+        _price(eng)  # a program costs its rows: every split pays
+        patch = pytest.MonkeyPatch()
+        seen = _watch(eng, patch)
+        try:
+            alone = [(await eng.complete(_split_prompt(i),
+                                         max_new_tokens=3))[0]
+                     for i in range(16)]
+            for rows in (2, 4, 8, 16):
+                await _burst(eng, seen, rows)
+            assert _prefill_programs(eng) == {1, 2, 4, 8, 16}
+            return alone, {b: await _burst(eng, seen, b)
+                           for b in range(1, 17)}
+        finally:
+            patch.undo()
+            await eng.close()
+
+    try:
+        return asyncio.run(collect())
+    finally:
+        # This engine ran outside any test: its series are not the next
+        # test's.
+        REGISTRY.reset()
+
+
+@pytest.mark.parametrize("b", range(1, 17))
+def test_warm_group_goes_as_its_binary_pieces(warm_bursts, b):
+    alone, bursts = warm_bursts
+    assert bursts[b]["rows"] == _pieces(b)  # largest first, no dummy row
+    assert bursts[b]["tokens"] == alone[:b]
+    assert all(len(t) == 3 for t in bursts[b]["tokens"])
+
+
+@pytest.mark.parametrize("b", range(1, 17))
+def test_split_keeps_arrival_order_and_notes_no_program(warm_bursts, b):
+    """Every piece rides a program dispatched before: nothing reaches
+    compile_cache.note_compilation (KFS_SANITIZE's recompile check)."""
+    _, bursts = warm_bursts
+    assert bursts[b]["first_in_order"] == list(range(b))
+    assert bursts[b]["noted"] == []
+
+
+@pytest.mark.parametrize("b", range(1, 17))
+def test_prefill_row_counters_count_what_was_dispatched(warm_bursts, b):
+    _, bursts = warm_bursts
+    assert bursts[b]["counted"] == {
+        "prefills": len(_pieces(b)), "prefill_requests": b,
+        "prefill_rows_dispatched": b, "prefill_rows_padded": 0}
+
+
+@pytest.mark.parametrize("warm, b, then", [
+    ([2, 1], 3, [2, 1]),
+    ([4, 1], 5, [4, 1]),
+    ([4, 2], 6, [4, 2]),
+    ([4, 2, 1], 7, [4, 2, 1]),
+    ([8, 4], 5, [8]),     # the 1-row program is still cold: pads again
+], ids=["3", "5", "6", "7", "5-piece-cold"])
+async def test_group_pads_until_its_padded_program_is_timed(
+        tiny, monkeypatch, warm, b, then):
+    """The pieces' programs alone do not split a group: it pads as it
+    always did until the padded program has been dispatched, and so
+    timed, too (a harness that warms the 8-row program with a burst
+    admitted as 5 must still get it), and the next group of that size
+    splits."""
+    eng = split_engine(tiny)
+    _price(eng)
+    seen = _watch(eng, monkeypatch)
+    try:
+        for rows in warm:
+            await _burst(eng, seen, rows)
+        padded = 1 << (b - 1).bit_length()
+        first = await _burst(eng, seen, b)
+        assert first["rows"] == [padded]
+        assert first["noted"] == ([] if padded in warm
+                                  else [("prefill", padded, 16)])
+        assert first["counted"]["prefill_rows_padded"] == padded - b
+        assert _prefill_programs(eng) == set(warm) | {padded}
+        second = await _burst(eng, seen, b)
+        assert second["rows"] == then and second["noted"] == []
+        assert second["tokens"] == first["tokens"]
+        assert _prefill_programs(eng) == set(warm) | {padded}
+    finally:
+        await eng.close()
+
+
+# What a program of r rows took, by name: a dense model's (its rows and
+# little else), one whose every dispatch streams gigabytes whatever its
+# rows, and Nemotron-H's at 1024 tokens as timed on the v5e (PERF.md,
+# PR 49: the 1- and 2-row programs leave the grouped kernel).
+_COSTS = {"by-row": lambda r: 0.25 + r,
+          "by-dispatch": lambda r: 100.0 + r,
+          "small-ones-dear": {1: 60.0, 2: 80.0, 4: 72.0, 8: 147.0}.get}
+_WENT = {"by-row": {3: [2, 1], 5: [4, 1], 6: [4, 2], 7: [4, 2, 1]},
+         "by-dispatch": {3: [4], 5: [8], 6: [8], 7: [8]},
+         "small-ones-dear": {3: [4], 5: [4, 1], 6: [8], 7: [8]}}
+
+
+@pytest.fixture(scope="module")
+def priced_bursts(tiny):
+    """{cost: {b: the rows a burst of b went as}} on one engine with its
+    1-, 2-, 4- and 8-row programs dispatched, under each table of what
+    they took."""
+    from kfserving_tpu.observability import REGISTRY
+
+    async def collect():
+        eng = split_engine(tiny)
+        seen = _watch(eng)
+        try:
+            for rows in (1, 2, 4, 8):
+                await _burst(eng, seen, rows)
+            eng._note_prefill_took = lambda rows, bucket, seconds: None
+            went = {}
+            for name, cost in _COSTS.items():
+                eng._prefill_took_s = {(r, 16): [cost(r)]
+                                       for r in (1, 2, 4, 8)}
+                went[name] = {b: (await _burst(eng, seen, b))["rows"]
+                              for b in (3, 5, 6, 7)}
+            return went
+        finally:
+            await eng.close()
+
+    try:
+        return asyncio.run(collect())
+    finally:
+        REGISTRY.reset()
+
+
+@pytest.mark.parametrize("b", [3, 5, 6, 7])
+@pytest.mark.parametrize("cost", sorted(_COSTS))
+def test_group_splits_only_where_its_pieces_took_less(priced_bursts,
+                                                      cost, b):
+    assert priced_bursts[cost][b] == _WENT[cost][b]
+
+
+async def test_a_programs_first_dispatch_is_not_a_timing(tiny):
+    """The first dispatch of a program compiles inside its launch: it
+    opens the program's record and leaves it empty, so nothing is
+    decided on it.  From the second on a dispatch is timed by the fetch
+    workers' clock, and the last eight are kept."""
+    eng = split_engine(tiny)
+    try:
+        assert eng._prefill_rows_to_take(3, 16) == 3
+        for n in range(11):
+            await eng.complete([1 + n, 2, 3], max_new_tokens=2)
+            took = eng._prefill_took_s[1, 16]
+            assert len(took) == min(n, 8)
+        assert all(0.0 < s < 60.0 for s in took)
+        assert set(eng._prefill_took_s) == {(1, 16)}
+        assert eng._prefill_rows_to_take(3, 16) == 3  # 2 and 4 untimed
+        assert eng.stats()["prefill_program_ms"] == {
+            "1x16": round(1e3 * float(np.median(took)), 3)}
+    finally:
+        await eng.close()
+
+
+async def test_prefill_rows_bounds_a_group_before_it_is_cut(tiny):
+    """`prefill_rows` 3: no take sees more than 3 requests, and a take
+    of 3 goes as 2 (then what is left is taken under the same bound)."""
+    eng = split_engine(tiny, prefill_rows=3)
+    _price(eng)
+    seen = _watch(eng)
+    try:
+        for rows in (1, 2, 3):
+            await _burst(eng, seen, rows)
+        assert _prefill_programs(eng) == {1, 2, 4}
+        alone = [(await eng.complete(_split_prompt(i),
+                                     max_new_tokens=3))[0]
+                 for i in range(7)]
+        got = await _burst(eng, seen, 7)
+        assert got["rows"] == [2, 2, 2, 1]
+        assert got["tokens"] == alone
+        assert got["first_in_order"] == list(range(7))
+    finally:
+        await eng.close()
+
+
+@pytest.mark.parametrize("n, refused_rows, rows_after", [
+    (6, 2, [4, 2, 1, 1]),           # 4 + 2: the 2 goes back, cap 1
+    (6, 4, [4, 2, 2, 2]),           # the 4 goes back, cap 2
+    (7, 2, [4, 2, 1, 1, 1]),        # 4 + 2 + 1: the 2 goes back, cap 1
+])
+async def test_refusal_of_a_piece_halves_from_that_piece(
+        tiny, n, refused_rows, rows_after):
+    """The runtime refuses one piece's launch for memory: the pieces
+    before it are in flight and stay so, the refused one goes back to
+    the front of the queue, and dispatches are held to half ITS rows
+    from then on (not half the whole run's).  No request fails."""
+    eng = split_engine(tiny)
+    _price(eng)
+    seen = _watch(eng)
+    try:
+        for rows in (2, 4, 8):
+            await _burst(eng, seen, rows)
+        alone = [(await eng.complete(_split_prompt(i),
+                                     max_new_tokens=3))[0]
+                 for i in range(n)]
+        watched = eng._prefill
+        left = {"n": 1}
+
+        def refusing(variables, ids, *rest):
+            if ids.shape[0] == refused_rows and left["n"]:
+                left["n"] -= 1
+                seen["rows"].append(ids.shape[0])
+                raise ValueError(_REFUSAL)
+            return watched(variables, ids, *rest)
+
+        eng._prefill = refusing
+        got = await _burst(eng, seen, n)
+        assert got["tokens"] == alone
+        assert got["rows"] == rows_after
+        assert got["first_in_order"] == list(range(n))
+        assert eng.stats()["prefill_rows_cap"] == refused_rows // 2
+        # The refused launch ran nothing and is not counted.
+        assert got["counted"]["prefill_rows_dispatched"] == n
+        assert got["counted"]["prefill_rows_padded"] == 0
+    finally:
+        await eng.close()
+
+
+async def test_cancels_inside_a_split_run_leave_its_order(tiny):
+    """Seven arrivals, taken 4 first.  While that piece is on the
+    enqueue executor one of its requests is cancelled (it never holds a
+    slot) and one still queued behind it is (it leaves the queue): the
+    next take has what is left, in arrival order."""
+    eng = split_engine(tiny)
+    _price(eng)
+    seen = _watch(eng)
+    try:
+        for rows in (2, 4, 8):
+            await _burst(eng, seen, rows)
+        alone = [(await eng.complete(_split_prompt(i),
+                                     max_new_tokens=3))[0]
+                 for i in range(7)]
+        orig = eng._enqueue_prefill_group
+        reqs = []
+
+        def cancel_two(group, slots, bucket, dest_rows=None):
+            for r in (reqs[2], reqs[5]):
+                eng.cancel(r)
+            return orig(group, slots, bucket, dest_rows)
+
+        eng._enqueue_prefill_group = cancel_two
+        del seen["rows"][:], seen["first"][:]
+        reqs.extend(eng.submit(_split_prompt(i), max_new_tokens=3)
+                    for i in range(7))
+        outs = await asyncio.wait_for(
+            asyncio.gather(*(_drain(eng, r) for r in reqs)), timeout=60)
+        assert seen["rows"] == [4, 2]
+        assert outs == [[] if i in (2, 5) else alone[i] for i in range(7)]
+        assert _order(reqs, seen["first"]) == [0, 1, 3, 4, 6]
+        assert all(s is None for s in eng._slots)
+    finally:
+        await eng.close()
+
+
+async def test_pool_starved_take_rolls_back_to_a_power_of_two(tiny):
+    """Four arrivals of two blocks each and a pool that holds three of
+    them: the take plans three, keeps the two a warm program carries,
+    and undoes the third's plan (its provisional prefix registration
+    with it).  Everyone is answered in arrival order as alone, and the
+    pool ends as it began."""
+    def two_blocks(i):
+        return [(11 * i + j) % 90 + 1 for j in range(9)]
+
+    roomy = split_engine(tiny)
+    try:
+        alone = [(await roomy.complete(two_blocks(i), max_new_tokens=3))[0]
+                 for i in range(4)]
+    finally:
+        await roomy.close()
+    eng = split_engine(tiny, cache_blocks=7)
+    _price(eng)
+    seen = _watch(eng)
+    try:
+        for rows in (1, 2, 4):  # one block a request: all four fit
+            await _burst(eng, seen, rows, prompt=lambda i: [i + 1, 2])
+        invalidated = eng.block_evictions["index_invalidation"]
+        requeue, undone = eng._requeue_group, []
+
+        def watched_requeue(group, slots):
+            undone.append([r.prompt_ids.tolist() for r in group])
+            return requeue(group, slots)
+
+        eng._requeue_group = watched_requeue
+        got = await _burst(eng, seen, 4, prompt=two_blocks)
+        assert got["rows"][0] == 2 and sum(got["rows"]) == 4
+        assert got["counted"]["prefill_rows_padded"] == 0
+        assert got["tokens"] == alone
+        assert got["first_in_order"] == [0, 1, 2, 3]
+        # The third request's plan was undone once, by the take's cut
+        # (a plan the pool cannot finish undoes itself and counts too).
+        assert undone == [[two_blocks(2)]]
+        assert eng.block_evictions["index_invalidation"] > invalidated
+        assert not eng._plan_regs
+        await _settled(eng)
+        assert eng.stats()["paged"]["free_blocks"] \
+            + eng.stats()["paged"]["reclaimable_blocks"] == 7
+    finally:
+        await eng.close()
+
+
+async def test_padded_rows_share_is_read_from_the_scrapes(tiny):
+    """The two counters are on /metrics from the engine's start, and the
+    benchmark's reader makes the share of them: a cold group of 3 pads
+    to 4, one row in four a dummy."""
+    from chipbench import run as bench
+    from kfserving_tpu.server.metrics import Metrics
+    from kfserving_tpu.tools.check_metrics import lint_exposition
+
+    eng = split_engine(tiny, name="m")
+    try:
+        scrapes = {"open": {"metrics": Metrics().render()}}
+        for series in ("rows_total", "rows_padded_total"):
+            assert (f'kfserving_tpu_engine_prefill_{series}{{model="m"}} 0\n'
+                    in scrapes["open"]["metrics"])
+        await _burst(eng, _watch(eng), 3)
+        scrapes["close"] = {"metrics": Metrics().render()}
+        stats = eng.stats()
+        assert (stats["prefill_rows_dispatched"],
+                stats["prefill_rows_padded"]) == (4, 1)
+        assert ('kfserving_tpu_engine_prefill_rows_total{model="m"} 4\n'
+                in scrapes["close"]["metrics"])
+        assert lint_exposition(scrapes["close"]["metrics"]) == []
+        reader = bench.load_by_path("layer_metrics",
+                                    "prefill_padded_rows_share")
+        assert reader.read({"config": {"name": "m"},
+                            "scrapes": scrapes}) == 25.0
+    finally:
+        await eng.close()

@@ -573,6 +573,39 @@ async def test_streams_side_by_side_keep_their_own_rings(tiny):
     assert engine.prefix_reuse_refused == 5
 
 
+async def test_a_split_group_fills_every_rows_ring(tiny):
+    """Three arrivals once the 1-, 2- and 4-row programs are warm go as
+    2 + 1, each piece an insert of its own: every row's last blocks land
+    in its own slot's ring (prompts of two to four windows), so twenty
+    served tokens each match the reference."""
+    prompts = [prompt_of(41, 5), prompt_of(64, 13), prompt_of(33, 11)]
+    engine = engine_of(tiny, prefill_buckets=[64, 128])
+    rows, prefill = [], engine._prefill
+
+    def watched(variables, ids, *rest):
+        rows.append(ids.shape[0])
+        return prefill(variables, ids, *rest)
+
+    engine._prefill = watched
+    # every dispatched program timed at its rows: the pieces take less
+    engine._note_prefill_took = lambda rows, bucket, seconds: \
+        engine._prefill_took_s.__setitem__((rows, bucket), [float(rows)])
+    try:
+        for n in (1, 2, 4):
+            await asyncio.gather(*(served(engine, prompt_of(40 + i), 2)
+                                   for i in range(n)))
+        assert rows == [1, 2, 4]
+        results = await asyncio.gather(*(served(engine, p, 20)
+                                         for p in prompts))
+        stats = engine.stats()
+    finally:
+        await engine.close()
+    assert rows[3:] == [2, 1]
+    assert stats["prefill_rows_padded"] == 0
+    for prompt, result in zip(prompts, results):
+        assert_matches_reference(tiny, prompt, *result)
+
+
 async def test_a_repeated_prompt_shares_no_blocks_and_is_counted(tiny):
     engine = engine_of(tiny)
     prompt = prompt_of(40)

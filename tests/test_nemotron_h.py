@@ -446,6 +446,41 @@ async def test_prefill_rows_bounds_a_dispatch_without_a_sync(tiny):
         assert_matches_reference(tiny, prompt, tokens, chosen, top)
 
 
+async def test_a_split_group_inserts_every_rows_state(tiny):
+    """Seven arrivals once the 1-, 2-, 4- and 8-row programs are warm go
+    as 4 + 2 + 1, each piece an insert of its own into its rows' slots:
+    every request's conv and scan state (and K/V) is its own, so eight
+    served tokens each match the reference."""
+    prompts = [prompt_of(18 + i, 3 + i) for i in range(7)]
+    engine = engine_of(tiny, max_slots=8, prefill_buckets=[32, MAX_SEQ])
+    rows, prefill = [], engine._prefill
+
+    def watched(variables, ids, *rest):
+        rows.append(ids.shape[0])
+        return prefill(variables, ids, *rest)
+
+    engine._prefill = watched
+    # every dispatched program timed at its rows: the pieces take less
+    engine._note_prefill_took = lambda rows, bucket, seconds: \
+        engine._prefill_took_s.__setitem__((rows, bucket), [float(rows)])
+    try:
+        for n in (1, 2, 4, 8):
+            await asyncio.wait_for(asyncio.gather(*[
+                served(engine, prompt_of(5 + i), 2) for i in range(n)]),
+                timeout=300)
+        assert rows == [1, 2, 4, 8]
+        results = await asyncio.wait_for(asyncio.gather(*[
+            served(engine, p, 8) for p in prompts]), timeout=300)
+        stats = engine.stats()
+    finally:
+        await engine.close()
+    assert rows[4:] == [4, 2, 1]
+    assert stats["prefill_rows_dispatched"] == 15 + 7
+    assert stats["prefill_rows_padded"] == 0
+    for prompt, (tokens, chosen, top) in zip(prompts, results):
+        assert_matches_reference(tiny, prompt, tokens, chosen, top)
+
+
 def test_the_older_models_declare_the_cache_the_engine_built_before():
     from kfserving_tpu.models.decoder import decoder_tiny
     from kfserving_tpu.models.olmoe import olmoe_tiny
