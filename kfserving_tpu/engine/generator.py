@@ -22,7 +22,7 @@ is the TPU-first design for that:
   a chain-hash index, pool pressure queues admissions, and block
   release is deferred past in-flight waves (the zombie-wave hazard).
   `block_size` unset is derived from the lengths the engine was given
-  (`derive_block_size`).
+  (`programs.derive_block_size`).
 - **prefill/decode split**: prompt ingestion runs as a separate
   bucketed forward (suffix-padded, flash-eligible at long L, one
   compile per bucket) that returns the prompt's k/v for every layer;
@@ -64,7 +64,6 @@ import concurrent.futures
 import functools
 import itertools
 import logging
-import math
 import os
 import statistics
 import threading
@@ -308,127 +307,39 @@ class GenerationEngine:
         self.sanitize_source = (
             f"generator:{self.name}:{next(_generator_seq)}")
 
-        cache_dtype = cfg.dtype
-        self._cache_dtype = cache_dtype
-        # -- what each layer keeps between steps -------------------------
-        # The model declares it (`config.cache_layers()`, models/
-        # decoder.py): K/V rows in the block pool below, arrays of a
-        # slot's own (a recurrence's state: models/nemotron_h.py), or
-        # nothing.  Every size the engine books or counts comes from
-        # this declaration.
-        from kfserving_tpu.models.decoder import KVCache, StateCache
+        # -- the device side (engine/programs.py) ------------------------
+        # The cache's arrays and the facts the host books by, from the
+        # model's declaration (`config.cache_layers()`) and the sizes
+        # above: K/V block pools [NB, BS, H*D] a layer with per-slot
+        # block tables, a recurrence's state by slot.
+        from kfserving_tpu.engine import programs
 
-        self._cache_layers = list(cfg.cache_layers())
-        kv_layers = [c for c in self._cache_layers
-                     if isinstance(c, KVCache)]
-        self._has_state = any(isinstance(c, StateCache)
-                              for c in self._cache_layers)
-        geometries = {(c.heads, c.head_dim) for c in kv_layers}
-        windows = {c.window for c in kv_layers}
-        if len(geometries) != 1 or None not in windows or len(windows) > 2:
-            raise InvalidInput(
-                "the engine pages K/V: a model needs at least one K/V "
-                "layer that keeps its whole context, all K/V layers of "
-                "one geometry, and its sliding-window layers of one "
-                f"window; {name!r} declares {sorted(set(kv_layers), key=str)}")
-        (kv_heads, kv_head_dim), = geometries
-        n_kv_layers = len(kv_layers)
+        layout = programs.lay_out(
+            cfg, name, max_slots=self.max_slots, max_seq=self.max_seq,
+            prefill_buckets=buckets, block_size=block_size,
+            cache_blocks=cache_blocks,
+            window_cache_blocks=window_cache_blocks, mesh=mesh)
+        self._cache_layers = layout.kinds
+        self._caches = layout.caches
+        self._cache_dtype = cache_dtype = layout.dtype
+        self._cache_shape = layout.pool_shape
+        self._cache_bytes = layout.cache_bytes
+        self.recurrent_state_bytes = layout.state_bytes
+        self.block_size = bs = layout.block_size
+        self.blocks_per_slot = layout.blocks_per_slot
+        self.num_blocks = layout.num_blocks
         # Sliding-window layers keep a ring of blocks a sequence in a
-        # pool of their own kind (ops/paged_attention.py); None for a
-        # model without any.
-        self._window = max(windows - {None}, default=None)
-        n_window_layers = sum(c.window is not None for c in kv_layers)
-        # -- the KV cache: a block pool ---------------------------------
-        # A shared pool [NB, BS, H*D] a layer (ops/paged_attention.py
-        # owns the layout) + per-slot block tables — HBM scales with
-        # resident tokens and identical prompt prefixes share blocks
-        # (VERDICT r4 weak #5; the vLLM PagedAttention idea, TPU-
-        # shaped: static pool/table shapes, OOB-sentinel scatters, a
-        # Pallas decode kernel that walks the table, XLA gather
-        # attention elsewhere).  block_size unset is derived from the
-        # lengths above: 128 wherever the kernels can serve.
-        self.block_size = bs = (
-            int(block_size) if block_size
-            else derive_block_size(self.max_seq, buckets))
-        if self.max_seq % bs != 0:
-            raise InvalidInput(
-                f"max_seq {self.max_seq} must be a multiple of "
-                f"block_size {bs}")
-        for b in buckets:
-            if b % bs != 0:
-                raise InvalidInput(
-                    f"prefill bucket {b} must be a multiple of "
-                    f"block_size {bs} (paged insert writes whole "
-                    f"blocks)")
-        self.blocks_per_slot = self.max_seq // bs
-        # Parity default: a block for every position of every slot.
-        # A smaller cache_blocks is the HBM saving — mixed-length
-        # traffic rarely needs S full-length slots at once.
-        self.num_blocks = int(cache_blocks or
-                              self.max_slots * self.blocks_per_slot)
-        from kfserving_tpu.ops import paged_attention
-
-        pool_shape = paged_attention.pool_shape(
-            self.num_blocks, bs, kv_heads, kv_head_dim)
-        self._cache_shape = pool_shape
-        # The window pool: every window layer's K and V are
-        # [num_window_blocks, BS, H*D], one table [slots, ring] for them
-        # all.  A sequence never holds more than its ring, whatever its
-        # length, so a ring for every slot can never run out; more than
-        # that (`window_cache_blocks`) is room for the blocks of finished
-        # requests that wait out the zombie-wave deferral.
-        self.window_blocks_per_slot = self.num_window_blocks = 0
-        if self._window is not None:
-            self.window_blocks_per_slot = paged_attention.ring_blocks(
-                self._window, bs)
-            self.num_window_blocks = int(
-                window_cache_blocks
-                or self.max_slots * self.window_blocks_per_slot)
-            if self.num_window_blocks < self.window_blocks_per_slot:
-                raise InvalidInput(
-                    f"window_cache_blocks {self.num_window_blocks} is "
-                    f"less than one sequence's ring of "
-                    f"{self.window_blocks_per_slot} blocks (window "
-                    f"{self._window}, block_size {bs})")
-        window_pool_shape = paged_attention.pool_shape(
-            self.num_window_blocks, bs, kv_heads, kv_head_dim)
-        # Blocks of a row that one loop iteration of the paged decode
-        # kernel takes, by pool (global, window): the kernel's own rule
-        # on the pool one device holds.
-        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-        shards = tp if kv_heads % tp == 0 else 1
-        self._walk_chunks = tuple(
-            paged_attention.blocks_per_iteration(
-                bs, kv_heads // shards * kv_head_dim, cache_dtype, columns)
-            for columns in (self.blocks_per_slot,
-                            self.window_blocks_per_slot or 1))
-
-        def layer_cache(kind):
-            """One layer's arrays: its two pools, its state with the
-            slots leading ([max_slots, ...]; a slot's row is written by
-            the insert that admits a request there and stepped by every
-            decode wave, so a reused slot's old state is overwritten
-            before anything reads it), or none."""
-            if isinstance(kind, KVCache):
-                shape = (pool_shape if kind.window is None
-                         else window_pool_shape)
-                return (jnp.zeros(shape, cache_dtype),
-                        jnp.zeros(shape, cache_dtype))
-            if isinstance(kind, StateCache):
-                return tuple(jnp.zeros((self.max_slots,) + tuple(shape),
-                                       dtype)
-                             for shape, dtype in kind.arrays)
-            return ()
-
-        def nbytes(arrays) -> int:
-            return sum(int(x.size) * x.dtype.itemsize
-                       for x in jax.tree.leaves(arrays))
-
-        self._caches = [layer_cache(kind) for kind in self._cache_layers]
-        self._cache_bytes = nbytes(self._caches)
-        self.recurrent_state_bytes = nbytes([
-            layer for kind, layer in zip(self._cache_layers, self._caches)
-            if isinstance(kind, StateCache)])
+        # pool of their own kind; None and 0 for a model without any.
+        self._window = layout.window
+        self.window_blocks_per_slot = layout.ring_columns
+        self.num_window_blocks = layout.num_window_blocks
+        self._walk_chunks = layout.walk_chunks
+        self._has_state = "recurrent state" in layout.limits
+        # Whether a block can stand for a prompt's prefix: where not,
+        # every plan is a miss, registers nothing, and is counted.
+        self._shares_prefixes = layout.shares_prefixes
+        kv_heads, kv_head_dim = layout.kv_heads, layout.kv_head_dim
+        n_kv_layers = layout.kv_layers
         obs.generator_recurrent_state_bytes().labels(
             model=name).set(self.recurrent_state_bytes)
         # Host-side paging state (guarded by _block_lock: the
@@ -594,27 +505,15 @@ class GenerationEngine:
                     self._draft_window = int(speculative.get(
                         "draft_window", DEFAULT_DRAFT_WINDOW))
 
-        # A recurrence's state is not rows addressed by position: a
-        # shared prefix has no blocks to point at (every plan is a miss,
-        # and is counted), and the features below each rest on
-        # rewriting, re-reading or moving such rows.
+        # What a kind of layer this model has cannot serve
+        # (`programs.UNSERVED`).
+        refused = programs.refusal(layout, name, {
+            "speculative": self.spec_tokens > 0,
+            "prefill_chunk_tokens": self.prefill_chunk_tokens is not None,
+            "host_tier_blocks": self.kv_tier is not None})
+        if refused:
+            raise InvalidInput(refused)
         if self._has_state:
-            for setting, on, why in (
-                    ("speculative", self.spec_tokens > 0,
-                     "a rejected draft token has already moved the "
-                     "state, and nothing overwrites it"),
-                    ("prefill_chunk_tokens",
-                     self.prefill_chunk_tokens is not None,
-                     "a chunk would have to continue from the state the "
-                     "chunk before it left"),
-                    ("host_tier_blocks", self.kv_tier is not None,
-                     "a spilled prefix is K/V blocks, and the state at "
-                     "its end is kept nowhere")):
-                if on:
-                    raise InvalidInput(
-                        f"{setting} is not served for {name!r}, a model "
-                        f"with recurrent state: {why}")
-
             # The recurrence's prefill once hung a v5e, and a hang takes
             # the chip: on a TPU only the shapes that have run are served.
             from kfserving_tpu.ops import ssm
@@ -625,32 +524,6 @@ class GenerationEngine:
                 raise InvalidInput(
                     f"{name!r} has recurrent state, whose prefill has run "
                     f"on the chip at few shapes only: {unproven}")
-
-        # A ring holds a position wherever it falls, so the position
-        # sentinel that parks a row (a chunk's padding, a verify wave's
-        # rows that take no draft) has a place in a live ring and would
-        # overwrite it; and a spilled prefix is the whole-context
-        # layers' blocks alone.  A shared prefix likewise stands for no
-        # ring: every plan of a window model is a miss, and is counted.
-        if self._window is not None:
-            for setting, on, why in (
-                    ("speculative", self.spec_tokens > 0,
-                     "a verify wave parks the rows it does not draft for "
-                     "on a position sentinel, which a ring gives a place "
-                     "and lets overwrite live keys"),
-                    ("prefill_chunk_tokens",
-                     self.prefill_chunk_tokens is not None,
-                     "a chunk's padding parks on a position sentinel, "
-                     "which a ring gives a place, and a chunk of more "
-                     "than a block overwrites keys its first queries "
-                     "still see"),
-                    ("host_tier_blocks", self.kv_tier is not None,
-                     "a spilled prefix is the whole-context layers' "
-                     "blocks, and the rings at its end are kept nowhere")):
-                if on:
-                    raise InvalidInput(
-                        f"{setting} is not served for {name!r}, a model "
-                        f"with sliding-window layers: {why}")
 
         # Parameters are resident from here on, like the pool: a host
         # leaf handed to a jitted call is transferred again on every
@@ -687,204 +560,31 @@ class GenerationEngine:
             self._params_narrowed_bytes)
         del stored
 
-        if mesh is not None:
-            # Tensor parallelism: the cache shards on the heads axis,
-            # exactly like the q/k/v projections that fill it
-            # (parallel/sharding.py transformer_rules) — cache writes
-            # and decode attention stay device-local per head group;
-            # the per-layer psum after the out-projection is the only
-            # collective.  The pool's last axis is H*D: splitting it
-            # over tp gives the same head groups.
-            tp = mesh.shape.get("tp", 1)
-            heads_axis = "tp" if kv_heads % max(tp, 1) == 0 else None
-            sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, heads_axis))
-            self._caches = [
-                tuple(jax.device_put(x, sharding if isinstance(kind, KVCache)
-                                     else replicated) for x in layer)
-                for kind, layer in zip(self._cache_layers, self._caches)
-            ]
-
-        base_key = self._rng
-        lp_n = self.logprob_topk
-
         # A model with routed experts (models/olmoe.py) also reports
         # what its routers chose; a dense decoder's programs and
         # fetches are what they were.
         self._moe = None
         if getattr(cfg, "num_experts", 0):
-            from kfserving_tpu.engine.moe_counters import (
-                MoeCounters,
-                decode_call_stats,
-            )
+            from kfserving_tpu.engine.moe_counters import MoeCounters
 
             self._moe = MoeCounters(name, cfg.num_experts,
                                     cfg.experts_per_token)
         routed = self._moe is not None
-        cache_kinds = self._cache_layers
-
-        def apply(variables, ids, **kw):
-            """(module.apply's outputs, what an expert model's routers
-            chose or None): `pairs` [expert layers, experts held], and
-            `elsewhere` [expert layers] where the model holds a share of
-            its experts."""
-            if not routed:
-                return module.apply(variables, ids, **kw), None
-            out, state = module.apply(variables, ids, mutable=["moe"],
-                                      **kw)
-            chose = {"pairs": module.routed_pairs(state)}
-            if hasattr(module, "routed_elsewhere"):
-                chose["elsewhere"] = module.routed_elsewhere(state)
-            return out, chose
-
-        has_window = self._window is not None
-
-        def by_pool(per_pool, kind):
-            """A K/V layer's own of a dispatch's tables (or insert
-            destinations): one array for a model whose layers all keep
-            their whole context, (whole-context, ring) for one with
-            sliding-window layers."""
-            if not has_window:
-                return per_pool
-            return per_pool[0 if kind.window is None else 1]
-
-        def with_table(caches, table):
-            """The caches as the model takes them: a K/V layer's pools
-            with this dispatch's block table for its pool."""
-            return [layer + (by_pool(table, kind),)
-                    if isinstance(kind, KVCache) else layer
-                    for kind, layer in zip(cache_kinds, caches)]
-
-        def mask_to_support(logits, top_ks, top_ps):
-            """Restrict logits to the top-k / nucleus support.  Both
-            knobs are per-row; 0 / 1.0 disable them.  One sort serves
-            both masks."""
-            v = logits.shape[-1]
-            sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-            k_eff = jnp.where((top_ks <= 0) | (top_ks >= v), v,
-                              top_ks)
-            kth = jnp.take_along_axis(sorted_desc,
-                                      (k_eff - 1)[:, None], axis=-1)
-            keep = logits >= kth
-            # Nucleus: keep the smallest prefix of the sorted
-            # distribution whose mass reaches top_p (the first token
-            # is always kept — cumsum-before-it is 0).
-            probs = jax.nn.softmax(sorted_desc, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            keep_sorted = (cum - probs) < top_ps[:, None]
-            n_keep = jnp.maximum(jnp.sum(keep_sorted, axis=-1), 1)
-            p_thresh = jnp.take_along_axis(
-                sorted_desc, (n_keep - 1)[:, None], axis=-1)
-            keep &= logits >= p_thresh
-            return jnp.where(keep, logits,
-                             jnp.finfo(logits.dtype).min)
-
-        def sample(logits, temps, top_ks, top_ps, seeds, noise_pos):
-            """logits [B, V] float32.  Noise is keyed per ROW from
-            (request seed, absolute position), never from wave or slot
-            identity — a request's sampled tokens reproduce exactly
-            for a given seed no matter how it was scheduled."""
-            greedy = jnp.argmax(logits, axis=-1)
-            need_mask = jnp.any((top_ks > 0) | (top_ps < 1.0))
-            masked = jax.lax.cond(
-                need_mask,
-                lambda l: mask_to_support(l, top_ks, top_ps),
-                lambda l: l, logits)
-
-            def row_key(seed, pos):
-                return jax.random.fold_in(
-                    jax.random.fold_in(base_key, seed), pos)
-
-            keys = jax.vmap(row_key)(seeds, noise_pos)
-            gumbel = jax.vmap(
-                lambda k: jax.random.gumbel(k, (logits.shape[-1],))
-            )(keys)
-            scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
-            sampled = jnp.argmax(scaled + gumbel, axis=-1)
-            return jnp.where(temps <= 0.0, greedy,
-                             sampled).astype(jnp.int32)
-
-        def logprob_of(logits, chosen):
-            """Chosen-token logprob + top-N (ids, logprobs) over the
-            UNMASKED distribution — diagnostics follow the model, not
-            the sampler's support restriction."""
-            lps = jax.nn.log_softmax(logits, axis=-1)
-            chosen_lp = jnp.take_along_axis(
-                lps, chosen[:, None].astype(jnp.int32), axis=-1)[:, 0]
-            top_lps, top_ids = jax.lax.top_k(lps, lp_n)
-            return chosen_lp, top_ids.astype(jnp.int32), top_lps
-
-        k_steps = self.steps_per_call
-
-        def decode_fn(variables, caches, table, tokens, positions,
-                      stops, temps, top_ks, top_ps, seeds):
-            """K decode steps in ONE device dispatch (lax.scan): on a
-            high-RTT link each host round trip costs ~an RTT, so
-            single-token stepping caps tokens/s at 1/RTT per wave;
-            scanning K steps on device multiplies that by K.  Tokens
-            feed forward on device; the host sees [S, K] at once (stop
-            conditions checked per chunk — at most K-1 wasted steps
-            after an EOS/budget stop).  Also returns the final carry's
-            feed tokens/positions as device arrays: the pipelined
-            scheduler chains dispatch N+1 off them without a host
-            round trip.
-
-            `stops` [S] (`_stop_positions`) is where each row's token
-            budget ends.  A row whose feed position has reached it
-            owes no token, and the step parks it: its table row is all
-            -1 in every pool, so `paged_walk` lists none of its blocks
-            and `paged_write` drops its row, and an expert model
-            routes it to no expert.  Its tokens and positions go on in
-            the carry as a freed slot's always have; the head and the
-            sampler stay dense over the slots."""
-            def step(carry, _):
-                caches, tokens, positions = carry
-                live = positions < stops
-                parked = jax.tree.map(
-                    lambda t: jnp.where(live[:, None], t, -1), table)
-                kw = {"valid": live[:, None]} if routed else {}
-                (logits, new_caches), pairs = apply(
-                    variables, tokens[:, None], positions=positions,
-                    kv_cache=with_table(caches, parked), **kw)
-                lg = logits[:, 0]
-                # The token being sampled extends a prefix of length
-                # positions+1 — the noise index is that length, so
-                # prefill (length L) and decode agree on the sequence
-                # L, L+1, ... per request.
-                nxt = sample(lg, temps, top_ks, top_ps, seeds,
-                             positions + 1)
-                lp = logprob_of(lg, nxt)
-                return (new_caches, nxt, positions + 1), (nxt, lp, pairs)
-
-            (caches, next_tokens, next_positions), (toks, lps, pairs) = \
-                jax.lax.scan(step, (caches, tokens, positions),
-                             None, length=k_steps)
-            chosen_lp, top_ids, top_lps = lps
-            # scan stacks on axis 0: [K, S, ...] -> [S, K, ...]
-            out = (toks.T, caches, next_tokens, next_positions,
-                   chosen_lp.T, jnp.swapaxes(top_ids, 0, 1),
-                   jnp.swapaxes(top_lps, 0, 1))
-            # Expert models: the call's routing, reduced on the device.
-            return out + (decode_call_stats(pairs),) if routed else out
-
-        # Donate caches AND the feed arrays: in-place HBM update, one
-        # resident pool; the feed tokens/positions chain wave-to-wave
-        # entirely on device.  The block table (arg 2) is NOT donated:
-        # the host re-sends it per wave (2 KB; it changes at
-        # allocation time).
-        self._decode = jax.jit(decode_fn, donate_argnums=(1, 3, 4))
-
-        def feed_update_fn(tokens, positions, slot_arr, new_tokens,
-                           new_positions):
-            """Scatter newly admitted requests' first feed token and
-            position into the device-resident feed arrays (OOB
-            sentinel rows drop, like the cache insert)."""
-            return (tokens.at[slot_arr].set(new_tokens, mode="drop"),
-                    positions.at[slot_arr].set(new_positions,
-                                               mode="drop"))
-
-        self._feed_update = jax.jit(feed_update_fn,
-                                    donate_argnums=(0, 1))
+        # The jitted programs, under the names the scheduler (and the
+        # tests that replace or lower them) use.
+        built = programs.build(
+            module, self._cache_layers, self.steps_per_call,
+            self.logprob_topk, self._rng, self.spec_tokens,
+            self.kv_tier is not None)
+        self._decode = built.decode
+        self._feed_update = built.feed_update
+        self._prefill = built.prefill
+        self._chunk_prefill = built.chunk_prefill
+        self._insert = built.insert
+        if built.spec_verify is not None:
+            self._spec_verify = built.spec_verify
+        if built.gather_blocks is not None:
+            self._gather_blocks = built.gather_blocks
         # Device-resident feed state: the token each slot feeds next
         # and its position.  Rows of freed slots go stale — that is
         # deliberate; a garbage decode on a free slot is harmless
@@ -893,126 +593,8 @@ class GenerationEngine:
         self._feed_tokens = jnp.zeros(self.max_slots, jnp.int32)
         self._feed_positions = jnp.zeros(self.max_slots, jnp.int32)
 
-        def prefill_fn(variables, ids, lengths, temps, top_ks, top_ps,
-                       seeds):
-            # logit_positions: the LM head runs only on each row's
-            # last real token — sampling never needs the [B, L, V]
-            # logits cube, and at a 4096 bucket the full-cube head
-            # matmul dominated prefill FLOPs.  Numerically identical
-            # per row to slicing the full cube (norm + head are
-            # per-position), so the chunked path (which uses the same
-            # sliced head) samples the same first token.
-            (logits, caches), pairs = apply(variables, ids,
-                                            kv_lengths=lengths,
-                                            return_cache=True,
-                                            logit_positions=lengths - 1)
-            # Leave the program as the pool stores them, [B, L, H*D]:
-            # the insert is then a scatter of whole blocks, where
-            # [B, L, H, D] results (L minor-most on the chip) would be
-            # transposed on their way in.
-            caches = [tuple(x.reshape(x.shape[:2] + (-1,)) for x in layer)
-                      if isinstance(kind, KVCache) else layer
-                      for kind, layer in zip(cache_kinds, caches)]
-            last = logits[:, 0]
-            first_tokens = sample(last, temps, top_ks, top_ps, seeds,
-                                  lengths)
-            chosen_lp, top_ids, top_lps = logprob_of(last,
-                                                     first_tokens)
-            out = (first_tokens, caches, chosen_lp, top_ids, top_lps)
-            return out + (pairs,) if routed else out
-
-        # One executable per prompt bucket (jit caches by shape).
-        self._prefill = jax.jit(prefill_fn)
-
-        def chunk_prefill_fn(variables, caches, table, ids, qpos,
-                             last_idx, temps, top_ks, top_ps,
-                             seeds, noise_pos):
-            """One chunk of a cold prompt: ids [1, C] write their
-            k/v through the slot's block table at absolute
-            positions qpos [1, C] (padding rows of a partial final
-            chunk park on an out-of-range sentinel and drop), and
-            attend per-query-causally over the pool — earlier
-            chunks are already resident, so cross-chunk attention
-            reads them exactly like decode does.  The head runs
-            only at last_idx; the sampled token matters only for
-            the FINAL chunk (it becomes the stream's first token,
-            noise-keyed on the full prompt length for parity with
-            monolithic prefill) — earlier chunks discard it."""
-            logits, new_caches = module.apply(
-                variables, ids, positions=qpos,
-                kv_cache=with_table(caches, table),
-                logit_positions=last_idx)
-            lg = logits[:, 0]
-            first = sample(lg, temps, top_ks, top_ps, seeds,
-                           noise_pos)
-            chosen_lp, top_ids, top_lps = logprob_of(lg, first)
-            return first, new_caches, chosen_lp, top_ids, top_lps
-
-        self._chunk_prefill = jax.jit(chunk_prefill_fn,
-                                      donate_argnums=(1,))
-
         self._spec_draft_fn = None
         if self.spec_tokens > 0:
-            spec_kp1 = self.spec_tokens + 1
-
-            def spec_verify_fn(variables, caches, table, last_tokens,
-                               draft_toks, positions, temps, top_ks,
-                               top_ps, seeds):
-                """Verify K draft tokens per slot in ONE Lq=K+1
-                dispatch.  Row i feeds [last_token, draft_0..K-1] at
-                absolute positions [L, L+K] (parked rows ride the
-                max_seq sentinel: their writes drop / clamp and their
-                samples are discarded).  logit_positions asks the LM
-                head for ALL K+1 positions — position j's logits see
-                exactly the prefix a sequential decode would have at
-                step j, so sampling them with the SAME per-row
-                (seed, position) noise keys reproduces sequential
-                decode's draws bit-exactly.  Exact-match acceptance of
-                the longest agreeing prefix is then rejection sampling
-                under the slot's deterministic noise key: the target's
-                draw at a position is a point, and accept-iff-equal is
-                the degenerate (and parity-exact) rejection rule.
-                Rollback past the first rejection needs NO cache
-                surgery — the host length pointer simply does not
-                advance over rejected positions, and the garbage k/v
-                written there is overwritten by later waves before any
-                query can attend it (writes precede attention in every
-                dispatch, and positions advance monotonically)."""
-                tokens = jnp.concatenate(
-                    [last_tokens[:, None], draft_toks], axis=1)
-                kv = with_table(caches, table)
-                s_rows = tokens.shape[0]
-                gather = jnp.broadcast_to(
-                    jnp.arange(spec_kp1, dtype=jnp.int32)[None, :],
-                    (s_rows, spec_kp1))
-                logits, new_caches = module.apply(
-                    variables, tokens, positions=positions,
-                    kv_cache=kv, logit_positions=gather)
-                flat = logits.reshape(s_rows * spec_kp1, -1)
-
-                def rep(a):
-                    return jnp.repeat(a, spec_kp1)
-
-                # noise index = length of the prefix each draw
-                # extends: position p's sample starts a prefix of
-                # p + 1 tokens — identical keying to decode_fn.
-                samples = sample(flat, rep(temps), rep(top_ks),
-                                 rep(top_ps), rep(seeds),
-                                 (positions + 1).reshape(-1))
-                chosen_lp, top_ids, top_lps = logprob_of(flat,
-                                                         samples)
-                # draft_toks are echoed through so the host reads
-                # proposals + verdicts in the same fetch: the draft
-                # arm costs ONE host round trip per spec wave, same
-                # as a plain decode wave.
-                return (samples.reshape(s_rows, spec_kp1), draft_toks,
-                        new_caches,
-                        chosen_lp.reshape(s_rows, spec_kp1),
-                        top_ids.reshape(s_rows, spec_kp1, lp_n),
-                        top_lps.reshape(s_rows, spec_kp1, lp_n))
-
-            self._spec_verify = jax.jit(spec_verify_fn,
-                                        donate_argnums=(1,))
             from kfserving_tpu.engine.speculative import NGramProposer
 
             self._ngram = NGramProposer(self.spec_tokens)
@@ -1024,41 +606,6 @@ class GenerationEngine:
                 self._spec_draft_fn = make_draft_proposer(
                     jax, self._draft_module, self.max_slots,
                     self._draft_window, self.spec_tokens)
-
-        def insert_fn(caches, new_caches, dest_blocks, slots=None):
-            """Scatter a prefill batch's k/v into pool blocks.
-            dest_blocks [B, chunks] int32; -1 chunks drop (bucket
-            padding rows, and prefix-cache hits whose shared blocks
-            already hold the data); for a model with sliding-window
-            layers a pair of them, the second the rings', which take
-            a prompt's last blocks alone.  A state layer's rows go to their
-            slots whole (`slots` [B] int32, past-the-end for a padding
-            row, which drops)."""
-            out = []
-            for kind, layer, new in zip(cache_kinds, caches, new_caches):
-                if isinstance(kind, KVCache):
-                    layer = paged_attention.paged_insert(
-                        *layer, *new, by_pool(dest_blocks, kind), None)
-                elif isinstance(kind, StateCache):
-                    layer = tuple(
-                        old.at[slots].set(x.astype(old.dtype), mode="drop")
-                        for old, x in zip(layer, new))
-                out.append(layer)
-            return out
-
-        self._insert = jax.jit(insert_fn, donate_argnums=(0,))
-
-        if self.kv_tier is not None:
-            def gather_blocks_fn(caches, idx):
-                """Snapshot the k/v of pool blocks `idx` [N] as
-                standalone device arrays (NOT donating the caches):
-                the spill path fetches the snapshot on the fetch
-                executor while later dispatches keep mutating the
-                pool — the data dependency pins the pre-overwrite
-                contents."""
-                return [(k[idx], v[idx]) for k, v in caches]
-
-            self._gather_blocks = jax.jit(gather_blocks_fn)
 
         # Two executors with distinct roles: `_executor` owns blocking
         # D2H fetches (each ~an RTT) — TWO workers, because fetches
@@ -1206,7 +753,7 @@ class GenerationEngine:
                                     * np.dtype(cache_dtype).itemsize)
         # Of the K/V layers, the share that reads min(context, window)
         # rows and not the context (`_attended`).
-        self._window_layer_share = n_window_layers / n_kv_layers
+        self._window_layer_share = layout.window_layers / n_kv_layers
         from kfserving_tpu.engine.jax_engine import device_peak_flops
         from kfserving_tpu.observability.profiling.roofline import (
             device_peak_hbm_bw,
@@ -4409,15 +3956,6 @@ class GenerationEngine:
         jax = self._jax
         return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
                    for x in jax.tree.leaves(self.draft_variables))
-
-
-def derive_block_size(max_seq: int, prefill_buckets: List[int]) -> int:
-    """The pool's block size where the caller set none: the largest
-    divisor of 128 that divides max_seq and every prefill bucket (a
-    block never straddles a slot's end, and the insert writes whole
-    blocks).  128, which the Pallas kernels need, wherever the lengths
-    are multiples of it; 16 for pow-2 buckets from 16."""
-    return math.gcd(128, int(max_seq), *(int(b) for b in prefill_buckets))
 
 
 def _pow2_buckets(max_seq: int) -> List[int]:

@@ -1,7 +1,8 @@
 """Routing counters of an expert model, from the device to /metrics.
 
 The programs of a model with routed experts return, beside their tokens,
-what the routers chose: per decode call `pairs` [layers, experts] (token,
+what the routers chose: per decode call (`programs.decode_call_stats`)
+`pairs` [layers, experts] (token,
 expert) pairs summed over the call's steps, `touched` (distinct experts
 read, summed over the call's layer-steps) and `load_max` (the busiest
 expert's pairs, summed likewise); per prefill dispatch `pairs` alone.  The
@@ -29,18 +30,6 @@ import numpy as np
 
 from kfserving_tpu.observability import metrics as obs
 from kfserving_tpu.ops import moe
-
-
-def decode_call_stats(chose: Dict[str, Any]) -> Dict[str, Any]:
-    """Device side: what the routers chose over one decode call,
-    `pairs` [steps, layers, experts] and, under a share, `elsewhere`
-    [steps, layers]."""
-    pairs = chose["pairs"]
-    stats = {"pairs": pairs.sum(axis=0), "touched": (pairs > 0).sum(),
-             "load_max": pairs.max(axis=-1).sum()}
-    if "elsewhere" in chose:
-        stats["elsewhere"] = chose["elsewhere"].sum()
-    return stats
 
 
 class MoeCounters:
