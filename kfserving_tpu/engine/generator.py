@@ -68,7 +68,7 @@ import os
 import statistics
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 
@@ -82,7 +82,8 @@ from kfserving_tpu.observability.profiling import TIMELINE
 from kfserving_tpu.observability.profiling.timeline import HOST, LAUNCH
 from kfserving_tpu.parallel.mesh import mesh_scope
 from kfserving_tpu.protocol.errors import InferenceError, InvalidInput
-from kfserving_tpu.reliability import sanitizer
+from kfserving_tpu.reliability import fault_sites, sanitizer
+from kfserving_tpu.reliability.faults import FaultInjected, faults
 
 logger = logging.getLogger("kfserving_tpu.engine.generator")
 
@@ -346,30 +347,29 @@ class GenerationEngine:
         # enqueue thread allocates while cancel() frees on the
         # loop thread).
         self._block_lock = threading.Lock()
-        self._tables = np.full(
-            (self.max_slots, self.blocks_per_slot), -1, np.int32)
-        self._free_blocks: deque = deque(range(self.num_blocks))
-        self._block_ref = np.zeros(self.num_blocks, np.int64)
-        # The window pool's allocator: a slot's ring table (block j of
-        # the sequence in column j % ring), the free list, and how many
-        # of its sequence's blocks each slot's ring has taken in so far.
-        # A window block is never shared, so it needs no reference count.
-        self._win_tables = np.full(
-            (self.max_slots, self.window_blocks_per_slot), -1, np.int32)
-        self._win_free: deque = deque(range(self.num_window_blocks))
-        self._win_covered = np.zeros(self.max_slots, np.int64)
-        self.window_blocks_recycled = 0
+        # Each pool's blocks, tabled by slot (engine/block_pool.py): the
+        # whole-context pool, whose blocks prompts share, and the rings'
+        # (None for a model without window layers), whose blocks nobody
+        # shares.
+        from kfserving_tpu.engine.block_pool import BlockPool
+
+        self._pool = BlockPool("global", self.num_blocks, self.max_slots,
+                               self.blocks_per_slot,
+                               evicted=self._block_evicted_locked)
+        self._ring = None
+        self._pools = [self._pool]
         if self._window is not None:
-            for pool, blocks in (("global", self.num_blocks),
-                                 ("window", self.num_window_blocks)):
+            self._ring = BlockPool("window", self.num_window_blocks,
+                                   self.max_slots,
+                                   self.window_blocks_per_slot)
+            self._pools.append(self._ring)
+            for pool in self._pools:
                 obs.generator_kv_pool_blocks().labels(
-                    model=name, pool=pool).set(blocks)
+                    model=name, pool=pool.name).set(pool.blocks)
         # chain-hash -> block id for FULL prompt blocks (prefix
-        # reuse); zero-ref registered blocks linger in
-        # _reclaimable (LRU) until allocation pressure evicts.
+        # reuse); registered blocks that nobody holds linger in the
+        # pool (LRU) until allocation pressure evicts.
         self._prefix_index: Dict[bytes, int] = {}
-        self._block_chain: Dict[int, bytes] = {}
-        self._reclaimable: "OrderedDict[int, None]" = OrderedDict()
         # Hits per LIVE index entry (reuse depth): the /debug/cache
         # census and the hot-chain top-K read this; entries drop
         # with their index entry on eviction/invalidation.
@@ -690,9 +690,9 @@ class GenerationEngine:
         self._kv_walk_iterations = 0
         # The same of a sliding-window layer: min(context, window) rows,
         # in the ring's columns the walk reads.
-        self._win_blocks_walked = 0
-        self._win_context_tokens = 0
-        self._win_walk_iterations = 0
+        self._ring_blocks_walked = 0
+        self._ring_context_tokens = 0
+        self._ring_walk_iterations = 0
         # The row count prefill dispatches are held to: configured
         # (`prefill_rows`: the deployment knows what fits beside its
         # parameters), or learned once the runtime has refused one for
@@ -996,9 +996,9 @@ class GenerationEngine:
                 self._kv_context_tokens / max(
                     1, self._kv_blocks_walked * self.block_size), 4),
             "kv_blocks_per_iteration": round(
-                (self._kv_blocks_walked + self._win_blocks_walked) / max(
+                (self._kv_blocks_walked + self._ring_blocks_walked) / max(
                     1, self._kv_walk_iterations
-                    + self._win_walk_iterations), 4),
+                    + self._ring_walk_iterations), 4),
             "prefill_rows_cap": self._prefill_rows_cap or 0,
             "prefill_rows": self.prefill_rows or 0,
             "cache_bytes": self.cache_bytes(),
@@ -1052,7 +1052,7 @@ class GenerationEngine:
                 in sorted(self._prefill_bucket_tokens.copy().items())
                 if padded > 0}
         with self._block_lock:
-            refd = int(np.sum(self._block_ref > 0))
+            refd = int(np.sum(self._pool.ref > 0))
             resident = sum(s.length for s in self._slots
                            if s is not None)
             # Fragmentation over per-slot TABLE blocks, not refd:
@@ -1061,7 +1061,7 @@ class GenerationEngine:
             # denominator count it the same number of times —
             # against refd (which counts it once) the ratio went
             # negative exactly in the shared-prompt regime.
-            table_blocks = int(np.sum(self._tables >= 0))
+            table_blocks = self._pool.tabled()
             frag = (1.0 - resident
                     / (table_blocks * self.block_size)
                     if table_blocks else 0.0)
@@ -1072,8 +1072,8 @@ class GenerationEngine:
                 # counter samples (_record_pool_sample).  The
                 # deprecated blocks_free/blocks_reclaimable aliases
                 # (ISSUE 13's one-release grace) are gone.
-                "free_blocks": len(self._free_blocks),
-                "reclaimable_blocks": len(self._reclaimable),
+                "free_blocks": len(self._pool.free),
+                "reclaimable_blocks": len(self._pool.lingering),
                 "prefix_hits": self.prefix_hits,
                 "prefix_misses": self.prefix_misses,
                 "prefill_tokens_saved": self.prefill_tokens_saved,
@@ -1085,7 +1085,7 @@ class GenerationEngine:
                 "evictions": dict(self.block_evictions),
                 "preemptions": self.preemptions,
             }
-            if self._window is not None:
+            if self._ring is not None:
                 # Each pool's own books: capacity, the share of it that
                 # slots' tables hold, and of the rows its decode walk
                 # read the share that held context.
@@ -1095,17 +1095,16 @@ class GenerationEngine:
                         "fill": out["paged"]["pool_occupancy_ratio"],
                         "block_fill": out["kv_block_fill"]},
                     "window": {
-                        "blocks": self.num_window_blocks,
+                        "blocks": self._ring.blocks,
                         "fill": round(
-                            int(np.sum(self._win_tables >= 0))
-                            / self.num_window_blocks, 4),
+                            self._ring.tabled() / self._ring.blocks, 4),
                         "block_fill": round(
-                            self._win_context_tokens / max(
-                                1, self._win_blocks_walked
+                            self._ring_context_tokens / max(
+                                1, self._ring_blocks_walked
                                 * self.block_size), 4),
                         "window": self._window,
-                        "blocks_per_slot": self.window_blocks_per_slot,
-                        "recycled": self.window_blocks_recycled}}
+                        "blocks_per_slot": self._ring.columns,
+                        "recycled": self._ring.recycled}}
         if self.kv_tier is not None:
             out["paged"]["host_tier_tokens_saved"] = \
                 self.host_tier_tokens_saved
@@ -1199,40 +1198,34 @@ class GenerationEngine:
     # (enqueue thread) that read tables, and deferred frees run on the
     # loop thread; the lock keeps the free-list/refcount state sane.
 
-    def _alloc_block_locked(self) -> Optional[int]:
-        if self._free_blocks:
-            return self._free_blocks.popleft()
-        if self._reclaimable:
-            # Evict the LRU zero-ref registered block: prefix entries
-            # linger for reuse only until allocation pressure.
-            blk, _ = self._reclaimable.popitem(last=False)
-            chain = self._block_chain.pop(blk, None)
-            if chain is not None and self._prefix_index.get(chain) == blk:
-                # Only drop the index entry this block actually backs —
-                # a concurrent duplicate admission may have re-pointed
-                # the chain at a different (still-resident) block.
-                self._prefix_index.pop(chain, None)
-                self._chain_hits.pop(chain, None)
-            # Fate of the evicted state: spill to the host tier when
-            # one is wired (the chain digest is the key; the device
-            # gather rides the enqueue executor BEFORE any dispatch
-            # can rewrite blk), otherwise — or for an unregistered
-            # block — it drops, the baseline.  Spill outcomes resolve
-            # asynchronously: the cause counter lands when the tier
-            # write commits (capacity_spilled) or fails
-            # (capacity_dropped), keeping the split honest under
-            # chaos injection.
-            if self.kv_tier is None or chain is None:
-                self._count_capacity_locked("capacity_dropped", blk)
-            elif self.kv_tier.contains(chain):
-                # Already host-resident (spilled on a previous
-                # eviction and faulted back since): the state is
-                # safe, no second copy needed.
-                self._count_capacity_locked("capacity_spilled", blk)
-            else:
-                self._spill_pending.append((chain, blk))
-            return blk
-        return None
+    def _block_evicted_locked(self, blk: int,
+                              chain: Optional[bytes]) -> None:
+        """The whole-context pool took the lingering block `blk`, which
+        held `chain`, for an allocation: the chain's fate."""
+        if chain is not None and self._prefix_index.get(chain) == blk:
+            # Only drop the index entry this block actually backs —
+            # a concurrent duplicate admission may have re-pointed
+            # the chain at a different (still-resident) block.
+            self._prefix_index.pop(chain, None)
+            self._chain_hits.pop(chain, None)
+        # Fate of the evicted state: spill to the host tier when
+        # one is wired (the chain digest is the key; the device
+        # gather rides the enqueue executor BEFORE any dispatch
+        # can rewrite blk), otherwise — or for an unregistered
+        # block — it drops, the baseline.  Spill outcomes resolve
+        # asynchronously: the cause counter lands when the tier
+        # write commits (capacity_spilled) or fails
+        # (capacity_dropped), keeping the split honest under
+        # chaos injection.
+        if self.kv_tier is None or chain is None:
+            self._count_capacity_locked("capacity_dropped", blk)
+        elif self.kv_tier.contains(chain):
+            # Already host-resident (spilled on a previous
+            # eviction and faulted back since): the state is
+            # safe, no second copy needed.
+            self._count_capacity_locked("capacity_spilled", blk)
+        else:
+            self._spill_pending.append((chain, blk))
 
     def _count_capacity_locked(self, cause: str, blk: int) -> None:
         self.block_evictions[cause] += 1
@@ -1240,19 +1233,6 @@ class GenerationEngine:
             model=self.name, cause=cause).inc()
         TIMELINE.record("host", "cache.evict",
                         attrs={"cause": cause, "block": blk})
-
-    def _ref_block_locked(self, blk: int) -> None:
-        self._block_ref[blk] += 1
-        self._reclaimable.pop(blk, None)
-
-    def _unref_block_locked(self, blk: int) -> None:
-        self._block_ref[blk] -= 1
-        if self._block_ref[blk] <= 0:
-            self._block_ref[blk] = 0
-            if blk in self._block_chain:
-                self._reclaimable[blk] = None  # linger for reuse
-            else:
-                self._free_blocks.append(blk)
 
     def _free_slot_state(self, i: int) -> None:
         """Free slot i AND schedule its blocks' release."""
@@ -1269,7 +1249,7 @@ class GenerationEngine:
                 if self._prefix_index.pop(chain, None) is not None:
                     dropped += 1
                     self._chain_hits.pop(chain, None)
-                self._block_chain.pop(blk, None)
+                self._pool.chain.pop(blk, None)
             self._count_invalidations_locked(dropped)
 
     def _count_invalidations_locked(self, dropped: int) -> None:
@@ -1301,26 +1281,20 @@ class GenerationEngine:
         blocks inside that window would let a zombie wave corrupt
         another request's cache."""
         with self._block_lock:
-            blocks = [int(b) for b in self._tables[slot] if b >= 0]
-            self._tables[slot, :] = -1
-            ring = [int(b) for b in self._win_tables[slot] if b >= 0]
-            self._win_tables[slot, :] = -1
-            self._win_covered[slot] = 0
-        if blocks or ring:
+            released = [pool.release(slot) for pool in self._pools]
+        if any(released):
             self._deferred_frees.append(
-                (self.decode_steps + self.pipeline_depth + 1, blocks,
-                 ring))
+                (self.decode_steps + self.pipeline_depth + 1, released))
 
     def _process_deferred_frees(self, force: bool = False) -> None:
         released = 0
         while self._deferred_frees and (
                 force or self._deferred_frees[0][0] <= self.decode_steps):
-            _, blocks, ring = self._deferred_frees.popleft()
-            released += len(blocks)
+            _, matured = self._deferred_frees.popleft()
+            released += len(matured[0])
             with self._block_lock:
-                for blk in blocks:
-                    self._unref_block_locked(blk)
-                self._win_free.extend(ring)
+                for pool, blocks in zip(self._pools, matured):
+                    pool.give_back(blocks)
         if released:
             # The normal release path: every slot block matures through
             # the zombie-wave deferral window exactly once.
@@ -1378,15 +1352,9 @@ class GenerationEngine:
         drop-on-evict baseline, and the tier index only publishes
         after the full payload landed, so a half-spilled chain is
         never readable.  The eviction-cause accounting deferred at
-        `_alloc_block_locked` lands here: capacity_spilled when the
+        `_block_evicted_locked` lands here: capacity_spilled when the
         tier committed, capacity_dropped otherwise — the split stays
         honest under chaos."""
-        from kfserving_tpu.reliability import fault_sites
-        from kfserving_tpu.reliability.faults import (
-            FaultInjected,
-            faults,
-        )
-
         outcomes: List[Tuple[int, str]] = []
         try:
             if faults.configured(fault_sites.ENGINE_KV_SPILL):
@@ -1449,12 +1417,6 @@ class GenerationEngine:
     def _land_faultbacks(self, pending) -> bool:
         """The body of `_drain_faultbacks` once there is something to
         land: read, insert, publish; False without a dispatch."""
-        from kfserving_tpu.reliability import fault_sites
-        from kfserving_tpu.reliability.faults import (
-            FaultInjected,
-            faults,
-        )
-
         primaries = [(ch, blk) for ch, blk, _r, prim in pending
                      if prim]
         riders = len(pending) - len(primaries)
@@ -1525,7 +1487,7 @@ class GenerationEngine:
                 # _register_chunk_blocks); our block stays private.
                 if self._prefix_index.get(ch) is None:
                     self._prefix_index[ch] = blk
-                    self._block_chain[blk] = ch
+                    self._pool.chain[blk] = ch
                 self._faultback_by_chain.pop(ch, None)
             for _ch, _blk, req, _prim in pending:
                 req.host_tier_hit_blocks += 1
@@ -1586,12 +1548,6 @@ class GenerationEngine:
         drain degrades to the no-handoff baseline)."""
         import hashlib
 
-        from kfserving_tpu.reliability import fault_sites
-        from kfserving_tpu.reliability.faults import (
-            FaultInjected,
-            faults,
-        )
-
         out = {"exported": 0, "skipped": 0, "dropped": 0, "failed": 0}
         t0 = time.perf_counter()
         bs = self.block_size
@@ -1620,7 +1576,7 @@ class GenerationEngine:
                         chain
                         + allids[c * bs:(c + 1) * bs].tobytes(),
                         digest_size=16).digest()
-                    blk = int(self._tables[si, c])
+                    blk = int(self._pool.table[si, c])
                     if blk < 0 or chain in seen:
                         continue
                     seen.add(chain)
@@ -1709,12 +1665,6 @@ class GenerationEngine:
         (every pair counted outcome=failed), so a failed import
         leaves the tier untouched and the returning turn degrades to
         a clean re-prefill."""
-        from kfserving_tpu.reliability import fault_sites
-        from kfserving_tpu.reliability.faults import (
-            FaultInjected,
-            faults,
-        )
-
         out = {"imported": 0, "skipped": 0, "failed": 0}
         if self.kv_tier is None or not pairs:
             return out
@@ -1774,21 +1724,21 @@ class GenerationEngine:
         n = int(req.prompt_ids.size)
         full = n // bs
         total = (n + bs - 1) // bs
-        ring = min(total, self.window_blocks_per_slot)
-        if len(self._win_free) < ring:
+        pool, ring = self._pool, self._ring
+        in_ring = 0 if ring is None else min(total, ring.columns)
+        if in_ring and len(ring.free) < in_ring:
             # The rings' pool first, before anything is taken: only this
             # thread allocates, so what is free now is free below.
             return None
-        if self._has_state or self._window is not None:
-            # No block stands for a prefix of a recurrence, nor for the
-            # rings at a prefix's end: the plan is a miss whatever the
-            # index holds, registers nothing, and says so.
+        if not self._shares_prefixes:
+            # No block stands for a prefix (`programs.UNSERVED`): the
+            # plan is a miss whatever the index holds, registers
+            # nothing, and says so.
             force_miss = True
             self.prefix_reuse_refused += 1
             obs.generator_prefix_reuse_refused_total().labels(
                 model=self.name).inc()
         dest: List[int] = []
-        taken: List[int] = []
         fresh_regs: List[Tuple[bytes, int]] = []
         # Plan-local lookup accounting, flushed to the registry twins
         # outside the block lock (one .labels() resolve per plan, not
@@ -1859,9 +1809,7 @@ class GenerationEngine:
                            else self._prefix_index.get(chain))
                     if hit is not None and (max_hit_blocks is None
                                             or c < max_hit_blocks):
-                        self._ref_block_locked(hit)
-                        self._tables[slot, c] = hit
-                        taken.append(hit)
+                        pool.place(slot, c, hit)
                         dest.append(-1)
                         self.prefix_hits += 1
                         plan_hits += 1
@@ -1886,16 +1834,14 @@ class GenerationEngine:
                             # fault-back already targets this chain —
                             # ride its block instead of reading the
                             # tier twice.
-                            self._ref_block_locked(shared)
-                            self._tables[slot, c] = shared
-                            taken.append(shared)
+                            pool.place(slot, c, shared)
                             dest.append(-1)
                             plan_host_hits += 1
                             host_faults.append((chain, shared, False))
                             continue
                         if self.kv_tier.begin_fault(chain):
                             host_chain = chain
-                blk = self._alloc_block_locked()
+                blk = pool.alloc()
                 if blk is None and host_chain is not None:
                     self.kv_tier.end_fault(host_chain)
                 if blk is None:
@@ -1909,10 +1855,9 @@ class GenerationEngine:
                         if self._prefix_index.pop(ch, None) is not None:
                             dropped += 1
                             self._chain_hits.pop(ch, None)
-                        self._block_chain.pop(b, None)
+                        pool.chain.pop(b, None)
                     self._count_invalidations_locked(dropped)
-                    for b in taken:
-                        self._unref_block_locked(b)
+                    pool.give_back(pool.release(slot))
                     # Release this plan's host-tier claims: primaries
                     # unpin their tier entries (eviction may take them
                     # again) and unpublish the coalescing point; the
@@ -1930,14 +1875,11 @@ class GenerationEngine:
                                 self._chain_hits.pop(ch, None)
                             else:
                                 self._chain_hits[ch] = d - 1
-                    self._tables[slot, :] = -1
                     self._flush_lookup_counters(
                         req, None, plan_hits, plan_misses, depth_obs,
                         plan_host_hits=plan_host_hits)
                     return None
-                self._ref_block_locked(blk)
-                self._tables[slot, c] = blk
-                taken.append(blk)
+                pool.place(slot, c, blk)
                 if host_chain is not None:
                     # Fault-back: the host tier holds this chain's
                     # k/v.  The drain (enqueue executor, FIFO-before
@@ -1968,19 +1910,16 @@ class GenerationEngine:
                         # duplicate block for no gain.
                         if self._prefix_index.get(chain) is None:
                             chunk_regs[c] = (chain, blk)
-                    elif not (self._has_state
-                              or self._window is not None):
+                    elif self._shares_prefixes:
                         self._prefix_index[chain] = blk
-                        self._block_chain[blk] = chain
+                        pool.chain[blk] = chain
                         fresh_regs.append((chain, blk))
             if chunk_regs is None:
                 self._plan_regs[slot] = fresh_regs
-            # The rings take the prompt's last blocks, block j in column
-            # j % ring: the insert writes those and drops the rest.
-            for j in range(total - ring, total):
-                self._win_tables[slot, j % self.window_blocks_per_slot] = \
-                    self._win_free.popleft()
-            self._win_covered[slot] = total
+            # The rings take the prompt's last blocks alone: the insert
+            # writes those and drops the rest.
+            for j in range(total - in_ring, total):
+                ring.place(slot, j, ring.alloc())
             if host_faults:
                 # Claimed under the lock; the caller MUST drain these
                 # (one tier read + one pool insert dispatch on the
@@ -2050,6 +1989,7 @@ class GenerationEngine:
             horizon = max(horizon, self.spec_tokens + 2)
         failed: List[int] = []
         with self._block_lock:
+            recycled = -sum(pool.recycled for pool in self._pools)
             for i, s in enumerate(self._slots):
                 if s is None or s.prefilling:
                     # Mid-chunked-prefill slots hold their whole
@@ -2058,61 +1998,28 @@ class GenerationEngine:
                     continue
                 need = min((s.length + horizon + bs - 1) // bs,
                            self.blocks_per_slot)
-                cur = int(np.sum(self._tables[i] >= 0))
-                ok = True
-                grown = cur
-                for c in range(cur, need):
-                    blk = self._alloc_block_locked()
-                    if blk is None:
-                        ok = False
-                        break
-                    self._ref_block_locked(blk)
-                    self._tables[i, c] = blk
-                    grown = c + 1
-                # Peak residency for the cost record: grown starts at
-                # cur and only increases, so it IS the table's block
-                # count for this stream now.
-                s.req.blocks_held = max(s.req.blocks_held, grown)
-                if ok and self._window is not None:
-                    ok = self._grow_ring_locked(i, need)
-                if not ok:
+                # A pool that runs out keeps what it took, and the rings
+                # are not asked once the whole-context pool has.
+                if not all(pool.take(i, need) for pool in self._pools):
                     failed.append(i)
-        return failed
-
-    def _grow_ring_locked(self, slot: int, need: int) -> bool:
-        """Take the sequence's blocks up to `need` into the slot's ring:
-        a column not held yet gets a block of the window pool; one that
-        is held is recycled, its block's oldest rows overwritten by the
-        positions to come, with nothing to tell the device (the table
-        does not change).  False where the pool has no block (its freed
-        ones wait out the deferral: the caller holds)."""
-        ring = self.window_blocks_per_slot
-        recycled = 0
-        for j in range(int(self._win_covered[slot]), need):
-            if self._win_tables[slot, j % ring] >= 0:
-                recycled += 1
-            elif self._win_free:
-                self._win_tables[slot, j % ring] = self._win_free.popleft()
-            else:
-                break
-            self._win_covered[slot] = j + 1
+                # Peak residency for the cost record.
+                s.req.blocks_held = max(s.req.blocks_held,
+                                        int(self._pool.covered[i]))
+            recycled += sum(pool.recycled for pool in self._pools)
         if recycled:
-            self.window_blocks_recycled += recycled
             obs.generator_window_blocks_recycled_total().labels(
                 model=self.name).inc(recycled)
-        return self._win_covered[slot] >= need
+        return failed
 
     def _table_device(self):
         """Device copy of the block tables for a dispatch."""
         with self._block_lock:
             # Copy under the lock: cancel() clears rows on the loop
             # thread while waves enqueue on the enqueue thread.
-            snap = self._tables.copy()
-            ring = (None if self._window is None
-                    else self._win_tables.copy())
-        if ring is None:
-            return self._jnp.asarray(snap)
-        return self._jnp.asarray(snap), self._jnp.asarray(ring)
+            tables = [pool.snapshot() for pool in self._pools]
+        if len(tables) == 1:
+            return self._jnp.asarray(tables[0])
+        return tuple(self._jnp.asarray(t) for t in tables)
 
     def _record_pool_sample(self) -> None:
         """Occupancy counter sample for the event timeline (rendered
@@ -2129,8 +2036,8 @@ class GenerationEngine:
             # describes — untagged samples would blend two engines'
             # pools into one meaningless ratio.
             "engine": self.name,
-            "free_blocks": len(self._free_blocks),
-            "reclaimable_blocks": len(self._reclaimable),
+            "free_blocks": len(self._pool.free),
+            "reclaimable_blocks": len(self._pool.lingering),
         }
         TIMELINE.counter("pool", values)
 
@@ -2188,12 +2095,6 @@ class GenerationEngine:
         storm on demand whose misses the lookup telemetry must count.
         configured() keeps the no-faults hot path at one dict
         lookup."""
-        from kfserving_tpu.reliability import fault_sites
-        from kfserving_tpu.reliability.faults import (
-            FaultInjected,
-            faults,
-        )
-
         if not faults.configured(fault_sites.GENERATOR_PREFIX_LOOKUP):
             return False
         try:
@@ -2449,13 +2350,13 @@ class GenerationEngine:
                     # chain first (both planned before either's chunk
                     # dispatched, so both allocated fresh blocks).
                     # Keep the canonical entry: overwriting would leave
-                    # the first block's _block_chain mapping orphaned,
+                    # the first block's chain mapping orphaned,
                     # and its eventual eviction used to delete the
                     # survivor's index entry.  Our block stays private
                     # and frees normally.
                     continue
                 self._prefix_index[chain] = blk
-                self._block_chain[blk] = chain
+                self._pool.chain[blk] = chain
 
     @_dispatch_timed("chunk")
     def _enqueue_chunk(self, slot: int, act: _Active, idx: int,
@@ -2513,13 +2414,13 @@ class GenerationEngine:
         # the first full-length cold prefill; padding queries still
         # drop via the block_idx >= mb guard in paged_write.
         bpc = C // self.block_size
-        nb = min((idx + 1) * bpc, self._tables.shape[1])
+        nb = min((idx + 1) * bpc, self.blocks_per_slot)
         with mesh_scope(self.mesh):
             with TIMELINE.span(LAUNCH, "engine.prep.chunk",
                                trace_id=req.trace_id, slot=slot):
                 self._note_program("chunk", nb)
                 with self._block_lock:
-                    row = self._tables[slot:slot + 1, :nb].copy()
+                    row = self._pool.table[slot:slot + 1, :nb].copy()
                 n_d = jnp.asarray(np.asarray([n], np.int32))
                 args = [jnp.asarray(row), jnp.asarray(ids),
                         jnp.asarray(qpos),
@@ -3367,18 +3268,18 @@ class GenerationEngine:
             for i, row in enumerate(dest_rows):
                 dest[i, :len(row)] = row
             dest_d = jnp.asarray(dest)
-            if self._window is not None:
+            if self._ring is not None:
                 # The rings take each prompt's last blocks alone, block
-                # j into the column the plan gave it.
-                ring = self.window_blocks_per_slot
+                # j into the one the plan tabled for it.
+                ring = self._ring
                 ring_dest = np.full((b_bucket, chunks), -1, np.int32)
                 with self._block_lock:
                     for i, (req, slot) in enumerate(zip(group, slots)):
                         total = -(-int(req.prompt_ids.size)
                                   // self.block_size)
-                        for j in range(max(0, total - ring), total):
-                            ring_dest[i, j] = \
-                                self._win_tables[slot, j % ring]
+                        for j in range(max(0, total - ring.columns),
+                                       total):
+                            ring_dest[i, j] = ring.at(slot, j)
                 dest_d = (dest_d, jnp.asarray(ring_dest))
         with self._inflight.launch("insert", rows=b):
             self._caches = self._insert(self._caches, new_caches,
@@ -3623,15 +3524,15 @@ class GenerationEngine:
             if self._window is not None:
                 # A window layer reads min(context, window) rows, in the
                 # columns of its ring that the sequence has reached.
-                ring = self.window_blocks_per_slot
                 win_tokens = int(np.minimum(context, self._window).sum())
-                win_row_blocks = np.minimum(row_blocks, ring)
+                win_row_blocks = np.minimum(row_blocks,
+                                            self._ring.columns)
                 win_blocks = int(win_row_blocks.sum())
                 win_iterations = int(
                     (-(-win_row_blocks // win_chunk)).sum())
-                self._win_context_tokens += win_tokens
-                self._win_blocks_walked += win_blocks
-                self._win_walk_iterations += win_iterations
+                self._ring_context_tokens += win_tokens
+                self._ring_blocks_walked += win_blocks
+                self._ring_walk_iterations += win_iterations
                 for pool, read, walked, looped in (
                         ("global", tokens_read, blocks, iterations),
                         ("window", win_tokens, win_blocks,
@@ -3691,12 +3592,6 @@ class GenerationEngine:
         non-speculative decode — same tokens, fewer per dispatch.
         configured() keeps the no-faults hot path at two dict
         lookups."""
-        from kfserving_tpu.reliability import fault_sites
-        from kfserving_tpu.reliability.faults import (
-            FaultInjected,
-            faults,
-        )
-
         if faults.configured(fault_sites.ENGINE_SPEC_DRAFT):
             try:
                 await faults.inject(fault_sites.ENGINE_SPEC_DRAFT,

@@ -168,12 +168,12 @@ async def test_eviction_causes_discriminating_sequence(tiny):
             held = []
             # kfslint: disable=spin-loop — bounded drain of the
             # free-block deque under the lock; nothing refills it.
-            while eng._free_blocks:
-                held.append(eng._free_blocks.popleft())
-            victim = eng._alloc_block_locked()
+            while eng._pool.free:
+                held.append(eng._pool.free.popleft())
+            victim = eng._pool.alloc()
             assert victim is not None
             assert eng._prefix_index == {}  # entry evicted with it
-            eng._free_blocks.extend(held + [victim])
+            eng._pool.free.extend(held + [victim])
         ev = eng.stats()["paged"]["evictions"]
         # No host tier wired: a capacity eviction IS a drop (the
         # baseline the ISSUE 16 split makes explicit).
@@ -185,16 +185,16 @@ async def test_eviction_causes_discriminating_sequence(tiny):
         # chunk 0 then fails allocation on chunk 1 rolls back and
         # deregisters exactly one provisional chain.
         with eng._block_lock:
-            held = [eng._alloc_block_locked()
+            held = [eng._pool.alloc()
                     for _ in range(2)]
             for b in held:
-                eng._ref_block_locked(b)
+                eng._pool.hold(b)
         req = _Request(np.asarray(list(range(1, 2 * BS + 1)),
                                   np.int32), 4, 0.0)
         assert eng._plan_prompt_blocks(req, 0) is None
         with eng._block_lock:
             for b in held:
-                eng._unref_block_locked(b)
+                eng._pool.drop(b)
         ev = eng.stats()["paged"]["evictions"]
         assert ev == {"capacity_dropped": 1, "capacity_spilled": 0,
                       "index_invalidation": 1, "zombie_deferral": 2}

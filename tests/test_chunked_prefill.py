@@ -315,11 +315,11 @@ async def test_duplicate_deferred_registration_survives_eviction(tiny):
         for slot in (0, 1):
             with eng._block_lock:
                 for c in range(prompt.size // BS):
-                    eng._unref_block_locked(int(eng._tables[slot, c]))
-                eng._tables[slot, :] = -1
+                    eng._pool.drop(int(eng._pool.table[slot, c]))
+                eng._pool.table[slot, :] = -1
         n_blocks = prompt.size // BS
         with eng._block_lock:
-            taken = [eng._alloc_block_locked() for _ in range(n_blocks)]
+            taken = [eng._pool.alloc() for _ in range(n_blocks)]
             assert all(b is not None for b in taken)
             # One full set of canonical entries survives, each backed
             # by a block that still maps its chain.  (Pre-fix: the
@@ -329,7 +329,7 @@ async def test_duplicate_deferred_registration_survives_eviction(tiny):
             # with the duplicate blocks still resident.)
             assert len(eng._prefix_index) == n_blocks
             for chain, blk in eng._prefix_index.items():
-                assert eng._block_chain.get(blk) == chain
+                assert eng._pool.chain.get(blk) == chain
     finally:
         await eng.close()
 

@@ -402,12 +402,12 @@ async def test_coalesced_riders_share_one_faultback(tiny):
             held = []
             # kfslint: disable=spin-loop — bounded drain of the
             # free-block deque under the lock; nothing refills it.
-            while eng._free_blocks:
-                held.append(eng._free_blocks.popleft())
-            victims = [eng._alloc_block_locked() for _ in range(2)]
+            while eng._pool.free:
+                held.append(eng._pool.free.popleft())
+            victims = [eng._pool.alloc() for _ in range(2)]
             assert all(v is not None for v in victims)
             assert eng._prefix_index == {}
-            eng._free_blocks.extend(held + victims)
+            eng._pool.free.extend(held + victims)
         # Any enqueue drains the spill queue (gather-before-overwrite
         # discipline); wait for both commits.
         await eng.complete([90, 91, 92], max_new_tokens=1)

@@ -495,8 +495,8 @@ async def test_a_window_layer_holds_its_ring_and_a_global_layer_its_context(
         if slot is None:  # the last token: the slot is released already
             return
         held.append((engine._slots[slot].length,
-                     int((engine._win_tables[slot] >= 0).sum()),
-                     int((engine._tables[slot] >= 0).sum())))
+                     int((engine._ring.table[slot] >= 0).sum()),
+                     int((engine._pool.table[slot] >= 0).sum())))
 
     engine = engine_of(tiny)
     try:
@@ -516,13 +516,13 @@ async def test_a_window_layer_holds_its_ring_and_a_global_layer_its_context(
     # the rest: min(len, 16) rows in min(blocks, 3) columns each.  The
     # fifteenth wave's fourth step is past the budget: parked, it walks
     # nothing.
-    assert engine._win_context_tokens == 59 * WINDOW
-    assert engine._win_blocks_walked == 59 * RING
+    assert engine._ring_context_tokens == 59 * WINDOW
+    assert engine._ring_blocks_walked == 59 * RING
     assert engine._kv_context_tokens == sum(range(101, 160))
     # The kernel's loop iterations, by each pool's own table: the whole
     # ring of 3 at once, the global layer's columns 4 at a time.
     assert engine._walk_chunks == (4, RING)
-    assert engine._win_walk_iterations == 59
+    assert engine._ring_walk_iterations == 59
     columns = [-(-n // BS) for n in range(101, 160)]
     assert engine._kv_blocks_walked == sum(columns)
     assert engine._kv_walk_iterations == sum(-(-c // 4) for c in columns)
@@ -546,7 +546,7 @@ async def test_released_window_blocks_are_reused_and_leak_nothing(tiny):
     engine = engine_of(tiny, max_slots=1, window_cache_blocks=RING)
     try:
         first = await served(engine, prompt_of(41), 20)
-        assert len(engine._win_free) == RING  # released
+        assert len(engine._ring.free) == RING  # released
         second = await served(engine, prompt_of(50, 11), 20)
         again = await served(engine, prompt_of(12, 3), 8)
     finally:

@@ -486,16 +486,16 @@ async def test_plan_rollback_deregisters_provisional_chains(tiny):
         held = []
         with eng._block_lock:
             for _ in range(2):
-                b = eng._alloc_block_locked()
-                eng._ref_block_locked(b)
+                b = eng._pool.alloc()
+                eng._pool.hold(b)
                 held.append(b)
         req = _Request(_np.asarray(prompt, _np.int32), 4, 0.0)
         assert eng._plan_prompt_blocks(req, 0) is None
         assert eng._prefix_index == {}  # no stale registration
-        assert eng._block_chain == {}
+        assert eng._pool.chain == {}
         with eng._block_lock:
             for b in held:
-                eng._unref_block_locked(b)
+                eng._pool.drop(b)
         # And the request now completes CORRECTLY end-to-end.
         want = ref_greedy(module, variables, prompt, 4)
         got, _ = await eng.complete(prompt, max_new_tokens=4)
