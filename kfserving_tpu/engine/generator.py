@@ -10,19 +10,16 @@ comes from batching *steps across requests*, not requests.  This engine
 is the TPU-first design for that:
 
 - **a block pool, static shapes**: the KV cache is a shared pool of
-  blocks per layer, [NB, BS, H*D] (ops/paged_attention.py owns the
-  layout), plus a block table per sequence slot.  The decode step is
-  ONE jit-compiled program over all `max_slots` slots, compiled once
+  blocks per layer plus a block table per sequence slot
+  (engine/programs.py lays it out and builds the programs,
+  engine/block_pool.py keeps who holds which block).  The decode step
+  is ONE jit-compiled program over all `max_slots` slots, compiled once
   and reused for the life of the server — requests joining or leaving
-  never change a shape, so XLA never recompiles (the continuous-
-  batching analogue of the engine's batch buckets); tables ride each
-  dispatch as a [S, MB] int32 array.  HBM scales with resident tokens
-  (size it with `cache_blocks`; unset, there is a block for every
-  position of every slot), identical prompt prefixes share blocks via
-  a chain-hash index, pool pressure queues admissions, and block
-  release is deferred past in-flight waves (the zombie-wave hazard).
-  `block_size` unset is derived from the lengths the engine was given
-  (`programs.derive_block_size`).
+  never change a shape, so XLA never recompiles; tables ride each
+  dispatch as a [S, MB] int32 array.  Identical prompt prefixes share
+  blocks via a chain-hash index, pool pressure queues admissions, and
+  block release is deferred past in-flight waves (the zombie-wave
+  hazard).
 - **prefill/decode split**: prompt ingestion runs as a separate
   bucketed forward (suffix-padded, flash-eligible at long L, one
   compile per bucket) that returns the prompt's k/v for every layer;
@@ -43,17 +40,9 @@ is the TPU-first design for that:
   transport the wave period drops from RTT + K steps toward
   max(RTT, K steps).  Stop decisions lag the device by at most
   depth-1 waves (bounded garbage steps, counted in stats).
-- **on-device sampling**: greedy, temperature (Gumbel trick), top-k
-  and top-p (nucleus) per slot — the mask-then-sample runs on device,
-  so only the [S] int32 token vector crosses the host boundary per
-  step — never the [S, V] logits (1.6 MB/step for a GPT-2 vocab).
-  Noise is keyed
-  per request from (seed, absolute position): a seeded request
-  reproduces exactly no matter how it was scheduled.  Top-N logprobs
-  are computed every step and fetched only when a request asks.
-- **donated caches**: the decode step donates the cache buffers, so
-  XLA updates them in place — HBM holds ONE cache pool, not
-  step-transient copies.
+- **on-device sampling, donated caches** (engine/programs.py): only
+  the [S] int32 token vector crosses the host boundary per step, and
+  the decode step updates ONE cache pool in place.
 
 Cache HBM is accounted via `cache_bytes()` so the predictor can admit
 params + cache against engine/hbm.py's budget.
@@ -76,6 +65,7 @@ import numpy as np
 
 from kfserving_tpu.engine import compile_cache
 from kfserving_tpu.engine import inflight as inflight_table
+from kfserving_tpu.engine.buckets import pow2_buckets
 from kfserving_tpu.observability import attribution
 from kfserving_tpu.observability import metrics as obs
 from kfserving_tpu.observability.profiling import TIMELINE
@@ -108,6 +98,17 @@ def _dispatch_timed(program: str):
             return out
         return timed
     return wrap
+
+
+# What `_distribute` counts of decode attention's reads, as (tokens,
+# blocks, loop iterations): the whole-context pool's families, and those
+# that say which pool of a model with two.
+_WALKED = (obs.generator_decode_kv_context_tokens_total,
+           obs.generator_decode_kv_blocks_walked_total,
+           obs.generator_decode_kv_walk_iterations_total)
+_WALKED_BY_POOL = (obs.generator_decode_kv_pool_context_tokens_total,
+                   obs.generator_decode_kv_pool_blocks_walked_total,
+                   obs.generator_decode_kv_pool_walk_iterations_total)
 
 
 # eq=False: a request is itself.  Compared by its fields, cancel()'s
@@ -282,7 +283,7 @@ class GenerationEngine:
         self.name = name
         self.mesh = mesh
         buckets = sorted(set(prefill_buckets or
-                             _pow2_buckets(self.max_seq)))
+                             pow2_buckets(self.max_seq, 16)))
         if buckets[-1] > self.max_seq:
             raise InvalidInput(
                 f"prefill bucket {buckets[-1]} exceeds max_seq "
@@ -315,18 +316,18 @@ class GenerationEngine:
         # block tables, a recurrence's state by slot.
         from kfserving_tpu.engine import programs
 
-        layout = programs.lay_out(
+        layout = programs.CacheLayout(
             cfg, name, max_slots=self.max_slots, max_seq=self.max_seq,
             prefill_buckets=buckets, block_size=block_size,
             cache_blocks=cache_blocks,
             window_cache_blocks=window_cache_blocks, mesh=mesh)
         self._cache_layers = layout.kinds
         self._caches = layout.caches
-        self._cache_dtype = cache_dtype = layout.dtype
+        self._cache_dtype = layout.dtype
         self._cache_shape = layout.pool_shape
         self._cache_bytes = layout.cache_bytes
         self.recurrent_state_bytes = layout.state_bytes
-        self.block_size = bs = layout.block_size
+        self.block_size = layout.block_size
         self.blocks_per_slot = layout.blocks_per_slot
         self.num_blocks = layout.num_blocks
         # Sliding-window layers keep a ring of blocks a sequence in a
@@ -335,12 +336,9 @@ class GenerationEngine:
         self.window_blocks_per_slot = layout.ring_columns
         self.num_window_blocks = layout.num_window_blocks
         self._walk_chunks = layout.walk_chunks
-        self._has_state = "recurrent state" in layout.limits
         # Whether a block can stand for a prompt's prefix: where not,
         # every plan is a miss, registers nothing, and is counted.
-        self._shares_prefixes = layout.shares_prefixes
-        kv_heads, kv_head_dim = layout.kv_heads, layout.kv_head_dim
-        n_kv_layers = layout.kv_layers
+        self._shares_prefixes = not layout.limits
         obs.generator_recurrent_state_bytes().labels(
             model=name).set(self.recurrent_state_bytes)
         # Host-side paging state (guarded by _block_lock: the
@@ -356,13 +354,12 @@ class GenerationEngine:
         self._pool = BlockPool("global", self.num_blocks, self.max_slots,
                                self.blocks_per_slot,
                                evicted=self._block_evicted_locked)
-        self._ring = None
-        self._pools = [self._pool]
-        if self._window is not None:
-            self._ring = BlockPool("window", self.num_window_blocks,
-                                   self.max_slots,
-                                   self.window_blocks_per_slot)
-            self._pools.append(self._ring)
+        self._ring = None if self._window is None else BlockPool(
+            "window", self.num_window_blocks, self.max_slots,
+            self.window_blocks_per_slot)
+        self._pools = [pool for pool in (self._pool, self._ring)
+                       if pool is not None]
+        if self._ring is not None:
             for pool in self._pools:
                 obs.generator_kv_pool_blocks().labels(
                     model=name, pool=pool.name).set(pool.blocks)
@@ -408,11 +405,8 @@ class GenerationEngine:
         if host_tier_blocks and int(host_tier_blocks) > 0:
             from kfserving_tpu.engine.kv_tier import HostKVTier
 
-            block_payload = (2 * n_kv_layers * bs * kv_heads
-                             * kv_head_dim
-                             * np.dtype(cache_dtype).itemsize)
             self.kv_tier = HostKVTier(
-                block_bytes=block_payload,
+                block_bytes=layout.kv_bytes_per_token * self.block_size,
                 capacity_blocks=int(host_tier_blocks),
                 directory=(host_tier_dir
                            or os.environ.get("KFS_KV_TIER_DIR")),
@@ -488,32 +482,34 @@ class GenerationEngine:
         self._draft_module = None
         self.draft_variables = None
         self._draft_window = 0
+        self._spec_draft_fn = None
         if speculative:
             self.spec_tokens = int(speculative.get("tokens", 0))
             if self.spec_tokens < 0:
                 raise InvalidInput(
                     "speculative tokens must be >= 0")
-            if self.spec_tokens > 0:
-                self._draft_module = speculative.get("draft_module")
-                self.draft_variables = speculative.get(
-                    "draft_variables")
-                if self._draft_module is not None:
-                    from kfserving_tpu.engine.speculative import (
-                        DEFAULT_DRAFT_WINDOW,
-                    )
+        if self.spec_tokens > 0:
+            from kfserving_tpu.engine import speculative as spec
 
-                    self._draft_window = int(speculative.get(
-                        "draft_window", DEFAULT_DRAFT_WINDOW))
+            self._ngram = spec.NGramProposer(self.spec_tokens)
+            self._draft_module = speculative.get("draft_module")
+            self.draft_variables = speculative.get("draft_variables")
+            if self._draft_module is not None:
+                self._draft_window = int(speculative.get(
+                    "draft_window", spec.DEFAULT_DRAFT_WINDOW))
+                self._spec_draft_fn = spec.make_draft_proposer(
+                    jax, self._draft_module, self.max_slots,
+                    self._draft_window, self.spec_tokens)
 
         # What a kind of layer this model has cannot serve
         # (`programs.UNSERVED`).
-        refused = programs.refusal(layout, name, {
+        refused = layout.refusal(name, {
             "speculative": self.spec_tokens > 0,
             "prefill_chunk_tokens": self.prefill_chunk_tokens is not None,
             "host_tier_blocks": self.kv_tier is not None})
         if refused:
             raise InvalidInput(refused)
-        if self._has_state:
+        if "recurrent state" in layout.limits:
             # The recurrence's prefill once hung a v5e, and a hang takes
             # the chip: on a TPU only the shapes that have run are served.
             from kfserving_tpu.ops import ssm
@@ -525,40 +521,23 @@ class GenerationEngine:
                     f"{name!r} has recurrent state, whose prefill has run "
                     f"on the chip at few shapes only: {unproven}")
 
-        # Parameters are resident from here on, like the pool: a host
-        # leaf handed to a jitted call is transferred again on every
-        # launch (3.1 GB a launch for gpt2-large, ROADMAP A9).  Under
-        # a mesh, leaves that arrive sharded (shard_params) keep their
-        # shardings and whatever is still on the host is replicated.
-        # A leaf rests in the dtype its model reads it in, where the
-        # model says which that is (`config.resident_dtypes`): a program
-        # handed float32 leaves that it multiplies in bfloat16 rebuilds
-        # their bfloat16 twin on every call (ROADMAP A8).
-        from kfserving_tpu import startup
+        # Parameters are resident from here on, like the pool.
+        stored = (self.variables, self.draft_variables)
+        self.variables, self.draft_variables = placed = \
+            programs.place_params((module, self._draft_module), stored,
+                                  mesh)
         from kfserving_tpu.engine import param_cache
 
-        replicated = None
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            replicated = NamedSharding(mesh, PartitionSpec())
-        stored = (self.variables, self.draft_variables)
-        self.variables, self.draft_variables = \
-            param_cache.place_on_device(stored, replicated, tuple(
-                _read_dtypes(model, tree) for model, tree in
-                zip((module, self._draft_module), stored)))
         self._params_resident_bytes = param_cache.device_resident_bytes(
-            (self.variables, self.draft_variables))
+            placed)
         narrowed_leaves, self._params_narrowed_bytes = \
-            param_cache.narrowed(
-                stored, (self.variables, self.draft_variables))
-        startup.mark("params_device")
+            param_cache.narrowed(stored, placed)
         logger.info(
             "%s: parameters resident, %d bytes; %d leaves narrowed to the "
             "dtype they are read in, %d bytes saved", name,
             self._params_resident_bytes, narrowed_leaves,
             self._params_narrowed_bytes)
-        del stored
+        del stored, placed
 
         # A model with routed experts (models/olmoe.py) also reports
         # what its routers chose; a dense decoder's programs and
@@ -569,22 +548,14 @@ class GenerationEngine:
 
             self._moe = MoeCounters(name, cfg.num_experts,
                                     cfg.experts_per_token)
-        routed = self._moe is not None
         # The jitted programs, under the names the scheduler (and the
         # tests that replace or lower them) use.
-        built = programs.build(
+        (self._decode, self._feed_update, self._prefill,
+         self._chunk_prefill, self._insert, self._spec_verify,
+         self._gather_blocks) = programs.build(
             module, self._cache_layers, self.steps_per_call,
             self.logprob_topk, self._rng, self.spec_tokens,
             self.kv_tier is not None)
-        self._decode = built.decode
-        self._feed_update = built.feed_update
-        self._prefill = built.prefill
-        self._chunk_prefill = built.chunk_prefill
-        self._insert = built.insert
-        if built.spec_verify is not None:
-            self._spec_verify = built.spec_verify
-        if built.gather_blocks is not None:
-            self._gather_blocks = built.gather_blocks
         # Device-resident feed state: the token each slot feeds next
         # and its position.  Rows of freed slots go stale — that is
         # deliberate; a garbage decode on a free slot is harmless
@@ -592,20 +563,6 @@ class GenerationEngine:
         # drop, gathers clamp) and admission overwrites the row.
         self._feed_tokens = jnp.zeros(self.max_slots, jnp.int32)
         self._feed_positions = jnp.zeros(self.max_slots, jnp.int32)
-
-        self._spec_draft_fn = None
-        if self.spec_tokens > 0:
-            from kfserving_tpu.engine.speculative import NGramProposer
-
-            self._ngram = NGramProposer(self.spec_tokens)
-            if self._draft_module is not None:
-                from kfserving_tpu.engine.speculative import (
-                    make_draft_proposer,
-                )
-
-                self._spec_draft_fn = make_draft_proposer(
-                    jax, self._draft_module, self.max_slots,
-                    self._draft_window, self.spec_tokens)
 
         # Two executors with distinct roles: `_executor` owns blocking
         # D2H fetches (each ~an RTT) — TWO workers, because fetches
@@ -681,18 +638,13 @@ class GenerationEngine:
         # Of them, those past a token budget's end: the decode program
         # had the row parked (no block walked or written, no expert).
         self._parked_token_steps = 0
-        # What decode attention had to read, a layer: blocks under the
-        # live rows' contexts, and the tokens in them (_distribute).
-        self._kv_blocks_walked = 0
-        self._kv_context_tokens = 0
-        # The paged kernel's loop iterations over those blocks: a row's
+        # What decode attention had to read, a layer of each pool
+        # (_distribute): the tokens of the live rows' contexts (of a
+        # window layer min(context, window)), the blocks they lie in,
+        # and the paged kernel's loop iterations over those, a row's
         # ceil(blocks / blocks_per_iteration) a step.
-        self._kv_walk_iterations = 0
-        # The same of a sliding-window layer: min(context, window) rows,
-        # in the ring's columns the walk reads.
-        self._ring_blocks_walked = 0
-        self._ring_context_tokens = 0
-        self._ring_walk_iterations = 0
+        self._walked = {pool.name: np.zeros(3, np.int64)
+                        for pool in self._pools}
         # The row count prefill dispatches are held to: configured
         # (`prefill_rows`: the deployment knows what fits beside its
         # parameters), or learned once the runtime has refused one for
@@ -733,7 +685,7 @@ class GenerationEngine:
         self._param_read_bytes = self.param_bytes()
         self._active_params = self._n_params
         self._expert_read_bytes = 0.0
-        if routed:
+        if self._moe is not None:
             # A token multiplies by its own experts alone, and a step
             # reads the experts its rows touched (counted on the
             # device, added when the counters arrive) beside what
@@ -745,15 +697,13 @@ class GenerationEngine:
             self._expert_read_bytes = counts["per_expert"] * per_param
         self._flops_matmul_per_token = 2.0 * self._active_params
         # Query heads do the arithmetic, KV heads are what is read.
-        self._attn_flops_coeff = (4.0 * n_kv_layers
-                                  * getattr(cfg, "num_heads", kv_heads)
-                                  * kv_head_dim)
-        self._kv_bytes_per_token = (2 * n_kv_layers * kv_heads
-                                    * kv_head_dim
-                                    * np.dtype(cache_dtype).itemsize)
+        self._attn_flops_coeff = (
+            4.0 * layout.kv_layers * layout.kv_head_dim
+            * getattr(cfg, "num_heads", layout.kv_heads))
+        self._kv_bytes_per_token = layout.kv_bytes_per_token
         # Of the K/V layers, the share that reads min(context, window)
         # rows and not the context (`_attended`).
-        self._window_layer_share = layout.window_layers / n_kv_layers
+        self._window_layer_share = layout.window_layers / layout.kv_layers
         from kfserving_tpu.engine.jax_engine import device_peak_flops
         from kfserving_tpu.observability.profiling.roofline import (
             device_peak_hbm_bw,
@@ -992,13 +942,10 @@ class GenerationEngine:
             "suppressed_waves": self.suppressed_waves,
             "wasted_token_steps": self._wasted_token_steps,
             "parked_token_steps": self._parked_token_steps,
-            "kv_block_fill": round(
-                self._kv_context_tokens / max(
-                    1, self._kv_blocks_walked * self.block_size), 4),
+            "kv_block_fill": self._block_fill(self._pool),
             "kv_blocks_per_iteration": round(
-                (self._kv_blocks_walked + self._ring_blocks_walked) / max(
-                    1, self._kv_walk_iterations
-                    + self._ring_walk_iterations), 4),
+                sum(w[1] for w in self._walked.values()) / max(
+                    1, sum(w[2] for w in self._walked.values())), 4),
             "prefill_rows_cap": self._prefill_rows_cap or 0,
             "prefill_rows": self.prefill_rows or 0,
             "cache_bytes": self.cache_bytes(),
@@ -1098,10 +1045,7 @@ class GenerationEngine:
                         "blocks": self._ring.blocks,
                         "fill": round(
                             self._ring.tabled() / self._ring.blocks, 4),
-                        "block_fill": round(
-                            self._ring_context_tokens / max(
-                                1, self._ring_blocks_walked
-                                * self.block_size), 4),
+                        "block_fill": self._block_fill(self._ring),
                         "window": self._window,
                         "blocks_per_slot": self._ring.columns,
                         "recycled": self._ring.recycled}}
@@ -1120,18 +1064,17 @@ class GenerationEngine:
             out["speculative"] = self.spec_debug()
         return out
 
+    def _block_fill(self, pool) -> float:
+        """Of the rows in the blocks a pool's decode walk read, the
+        share that held context."""
+        tokens, blocks, _ = self._walked[pool.name]
+        return round(tokens / max(1, blocks * self.block_size), 4)
+
     def spec_debug(self) -> Dict[str, Any]:
         """Speculative-decoding snapshot for stats() and the
         /debug/cache body (the router federates per-replica acceptance
         rates from here, like the prefix census)."""
         lengths = sorted(self._spec_lengths)
-
-        def lpct(q: float) -> int:
-            if not lengths:
-                return 0
-            return lengths[min(len(lengths) - 1,
-                               int(len(lengths) * q))]
-
         proposed = self.spec_proposed_tokens
         return {
             "tokens": self.spec_tokens,
@@ -1144,8 +1087,8 @@ class GenerationEngine:
             "acceptance_rate": (round(
                 self.spec_accepted_tokens / proposed, 4)
                 if proposed else 0.0),
-            "accepted_length_p50": lpct(0.50),
-            "accepted_length_p99": lpct(0.99),
+            "accepted_length_p50": _percentile(lengths, 0.50),
+            "accepted_length_p99": _percentile(lengths, 0.99),
             "draft_device_s": round(self._spec_draft_s, 4),
             "verify_device_s": round(self._spec_verify_s, 4),
             "draft_param_bytes": self.draft_param_bytes(),
@@ -1163,20 +1106,14 @@ class GenerationEngine:
             census = {chain: self._chain_hits.get(chain, 0)
                       for chain in self._prefix_index}
         depths = sorted(census.values())
-
-        def pct(q: float) -> int:
-            if not depths:
-                return 0
-            return depths[min(len(depths) - 1, int(len(depths) * q))]
-
         hot = sorted(census.items(), key=lambda kv: (-kv[1], kv[0]))
         hot = hot[:max(0, int(top_k))]
         ret = {
             "paged": True,
             "index_entries": len(census),
             "reuse_depth": {
-                "p50": pct(0.50),
-                "p99": pct(0.99),
+                "p50": _percentile(depths, 0.50),
+                "p99": _percentile(depths, 0.99),
                 "max": depths[-1] if depths else 0,
                 "mean": (round(sum(depths) / len(depths), 3)
                          if depths else 0.0),
@@ -1192,11 +1129,9 @@ class GenerationEngine:
         return ret
 
     # -- block-pool bookkeeping --------------------------------------------
-    # All mutation happens under _block_lock: the enqueue thread
-    # allocates during prefill planning is NOT true — planning runs on
-    # the loop thread, but cancel() (loop) can race wave enqueues
-    # (enqueue thread) that read tables, and deferred frees run on the
-    # loop thread; the lock keeps the free-list/refcount state sane.
+    # The pools (engine/block_pool.py) change under _block_lock alone:
+    # planning, cancel() and deferred frees run on the loop thread, and
+    # wave enqueues (enqueue thread) read the tables meanwhile.
 
     def _block_evicted_locked(self, blk: int,
                               chain: Optional[bytes]) -> None:
@@ -1328,9 +1263,7 @@ class GenerationEngine:
         with TIMELINE.span(LAUNCH, "engine.spill", blocks=len(pending)):
             for i in range(0, len(pending), 32):
                 grp = pending[i:i + 32]
-                padded = 1
-                while padded < len(grp):
-                    padded *= 2
+                padded = 1 << (len(grp) - 1).bit_length()
                 # Pad to a pow2 gather width (bounded compile count,
                 # same discipline as prefill row buckets); pad rows
                 # duplicate block 0 and are simply not written to the
@@ -1453,9 +1386,7 @@ class GenerationEngine:
         per = bs * width * dtype.itemsize
         for i in range(0, len(primaries), 32):
             grp = primaries[i:i + 32]
-            padded = 1
-            while padded < len(grp):
-                padded *= 2
+            padded = 1 << (len(grp) - 1).bit_length()
             layers = [(np.zeros((1, padded * bs, width), dtype),
                        np.zeros((1, padded * bs, width), dtype))
                       for _ in self._caches]
@@ -1611,9 +1542,7 @@ class GenerationEngine:
                 out["dropped"] += len(cand) - i
                 break
             grp = cand[i:i + 32]
-            padded = 1
-            while padded < len(grp):
-                padded *= 2
+            padded = 1 << (len(grp) - 1).bit_length()
             idx = np.asarray(
                 [b for _, b in grp]
                 + [grp[0][1]] * (padded - len(grp)), np.int32)
@@ -3004,12 +2933,7 @@ class GenerationEngine:
                     # — intervening waves already decoded this slot;
                     # FIFO order delivers this token before theirs).
                     self.prefill_requests += 1
-                    rec = None
-                    n_lp = act.req.logprobs
-                    if lp is not None and n_lp > 0:
-                        rec = (float(lp[0][0]),
-                               [(int(t), float(p)) for t, p in
-                                zip(lp[1][0][:n_lp], lp[2][0][:n_lp])])
+                    rec = _logprob_record(lp, act.req.logprobs, 0)
                     with TIMELINE.span(HOST, "engine.deliver",
                                        trace_id=act.req.trace_id,
                                        slot=slot, rows=1):
@@ -3074,13 +2998,8 @@ class GenerationEngine:
             if act is None or self._slots[slot] is not act:
                 continue
             self.prefill_requests += 1
-            rec = None
-            n_lp = act.req.logprobs
-            if lp is not None and n_lp > 0:
-                rec = (float(lp[0][i]),
-                       [(int(t), float(p)) for t, p in
-                        zip(lp[1][i][:n_lp], lp[2][i][:n_lp])])
-            self._emit(slot, int(firsts[i]), rec)
+            self._emit(slot, int(firsts[i]),
+                       _logprob_record(lp, act.req.logprobs, i))
 
     def _note_program(self, kind: str, *signature) -> None:
         """Record one dispatched program shape (enqueue-executor
@@ -3205,9 +3124,7 @@ class GenerationEngine:
         self._drain_spills()
         jnp = self._jnp
         b = len(group)
-        b_bucket = 1
-        while b_bucket < b:
-            b_bucket *= 2
+        b_bucket = 1 << (b - 1).bit_length()
         with mesh_scope(self.mesh), \
                 TIMELINE.span(LAUNCH, "engine.prep.prefill"):
             ids = np.zeros((b_bucket, bucket), np.int32)
@@ -3484,13 +3401,8 @@ class GenerationEngine:
                 # Each scanned step wrote the fed token's k/v at the
                 # slot's position: the cache grew by one per step.
                 s.length += 1
-                rec = None
-                if lp is not None and n_lp > 0:
-                    rec = (float(lp[0][i, j]),
-                           [(int(t), float(p)) for t, p in
-                            zip(lp[1][i, j][:n_lp],
-                                lp[2][i, j][:n_lp])])
-                self._emit(i, int(tokens[i, j]), rec)
+                self._emit(i, int(tokens[i, j]),
+                           _logprob_record(lp, n_lp, (i, j)))
         if starts:
             # Step i of the wave attends over L + i + 1 tokens of a row
             # that began it with L, in ceil of that over block_size
@@ -3500,20 +3412,22 @@ class GenerationEngine:
             context = (np.asarray(starts, np.int64)[:, None]
                        + np.arange(1, k + 1))
             context = context[np.arange(k) < np.asarray(ran)[:, None]]
-            tokens_read = int(context.sum())
             row_blocks = -(-context // self.block_size)
-            blocks = int(row_blocks.sum())
-            chunk, win_chunk = self._walk_chunks
-            iterations = int((-(-row_blocks // chunk)).sum())
-            self._kv_context_tokens += tokens_read
-            self._kv_blocks_walked += blocks
-            self._kv_walk_iterations += iterations
-            obs.generator_decode_kv_context_tokens_total().labels(
-                model=self.name).inc(tokens_read)
-            obs.generator_decode_kv_blocks_walked_total().labels(
-                model=self.name).inc(blocks)
-            obs.generator_decode_kv_walk_iterations_total().labels(
-                model=self.name).inc(iterations)
+            for pool, chunk in zip(self._pools, self._walk_chunks):
+                # A window layer reads min(context, window) rows, in the
+                # columns of its ring that the sequence has reached.
+                read = (context if pool is self._pool
+                        else np.minimum(context, self._window))
+                columns = np.minimum(row_blocks, pool.columns)
+                walked = (int(read.sum()), int(columns.sum()),
+                          int((-(-columns // chunk)).sum()))
+                self._walked[pool.name] += walked
+                counted = [(_WALKED, {})] if pool is self._pool else []
+                if self._ring is not None:
+                    counted.append((_WALKED_BY_POOL, {"pool": pool.name}))
+                for families, labels in counted:
+                    for family, n in zip(families, walked):
+                        family().labels(model=self.name, **labels).inc(n)
             # Decode reads every live slot's resident KV plus the full
             # parameter set once per token step — the bandwidth-bound
             # working set the HBM-utilization gauge divides by peak.
@@ -3521,28 +3435,6 @@ class GenerationEngine:
                 self._param_read_bytes
                 + sum(self._attended(n) for n in starts)
                 * self._kv_bytes_per_token)
-            if self._window is not None:
-                # A window layer reads min(context, window) rows, in the
-                # columns of its ring that the sequence has reached.
-                win_tokens = int(np.minimum(context, self._window).sum())
-                win_row_blocks = np.minimum(row_blocks,
-                                            self._ring.columns)
-                win_blocks = int(win_row_blocks.sum())
-                win_iterations = int(
-                    (-(-win_row_blocks // win_chunk)).sum())
-                self._ring_context_tokens += win_tokens
-                self._ring_blocks_walked += win_blocks
-                self._ring_walk_iterations += win_iterations
-                for pool, read, walked, looped in (
-                        ("global", tokens_read, blocks, iterations),
-                        ("window", win_tokens, win_blocks,
-                         win_iterations)):
-                    obs.generator_decode_kv_pool_context_tokens_total(
-                        ).labels(model=self.name, pool=pool).inc(read)
-                    obs.generator_decode_kv_pool_blocks_walked_total(
-                        ).labels(model=self.name, pool=pool).inc(walked)
-                    obs.generator_decode_kv_pool_walk_iterations_total(
-                        ).labels(model=self.name, pool=pool).inc(looped)
 
     # -- speculative decoding ----------------------------------------------
     async def _spec_or_fallback_wave(self, loop, inflight) -> None:
@@ -3810,13 +3702,8 @@ class GenerationEngine:
                     # agreeing prefix is past the stream's end.
                     break
                 s.length += 1
-                rec = None
-                if lp is not None and n_lp > 0:
-                    rec = (float(lp[0][i, j]),
-                           [(int(t), float(p)) for t, p in
-                            zip(lp[1][i, j][:n_lp],
-                                lp[2][i, j][:n_lp])])
-                self._emit(i, int(samples[i, j]), rec)
+                self._emit(i, int(samples[i, j]),
+                           _logprob_record(lp, n_lp, (i, j)))
                 emitted += 1
             self.spec_emitted_tokens += emitted
             self._occupied_slot_steps += emitted
@@ -3853,23 +3740,18 @@ class GenerationEngine:
                    for x in jax.tree.leaves(self.draft_variables))
 
 
-def _pow2_buckets(max_seq: int) -> List[int]:
-    out, b = [], 16
-    while b < max_seq:
-        out.append(b)
-        b *= 2
-    out.append(max_seq)
-    return out
+def _percentile(ordered: List[int], q: float) -> int:
+    """The `q` quantile of `ordered` (sorted), 0 of none."""
+    if not ordered:
+        return 0
+    return ordered[min(len(ordered) - 1, int(len(ordered) * q))]
 
 
-def _read_dtypes(module, variables):
-    """The dtype `module`'s programs read each leaf of `variables` in,
-    as its config declares it (`resident_dtypes`, models/decoder.py);
-    the stored dtypes for a model that declares nothing."""
-    import jax
-
-    declare = getattr(getattr(module, "config", None),
-                      "resident_dtypes", None)
-    if declare is None:
-        return jax.tree.map(lambda leaf: leaf.dtype, variables)
-    return declare(variables)
+def _logprob_record(lp, n: int, at):
+    """`_emit`'s record of the fetched logprob arrays' entry `at` (a
+    row, or (row, step)): (the chosen token's logprob, the top `n`
+    (token, logprob)); None where the request asked for none."""
+    if lp is None or n <= 0:
+        return None
+    return (float(lp[0][at]), [(int(t), float(p)) for t, p in
+                               zip(lp[1][at][:n], lp[2][at][:n])])

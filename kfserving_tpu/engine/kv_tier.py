@@ -443,7 +443,6 @@ class HostKVTier:
                 self.spills += 1
             obs.generator_kv_tier_spills_total().labels(
                 model=self.model, outcome="spilled").inc()
-            self._publish_occupancy()
             return True
         except Exception:
             logger.exception("kv tier spill failed (%s)", self.model)
@@ -539,7 +538,6 @@ class HostKVTier:
             self.dropped += 1
         obs.generator_kv_tier_evictions_total().labels(
             model=self.model, reason="faultback_failed").inc()
-        self._publish_occupancy()
 
     # -- durable handoff: adopting predecessor generations -----------------
     def reattach(self) -> Dict[str, int]:
@@ -786,7 +784,6 @@ class HostKVTier:
                 out["adopted"] += 1
         finally:
             bf.close()
-        self._publish_occupancy()
 
     def _discard_generation(self, mpath: str) -> None:
         """Delete one foreign generation's files (containment-checked:
@@ -828,15 +825,6 @@ class HostKVTier:
             recent, self.storm_window_s)
 
     # -- introspection -----------------------------------------------------
-    def _publish_occupancy(self) -> None:
-        with self._lock:
-            used = len(self._index)
-        obs.generator_kv_tier_blocks().labels(
-            model=self.model).set(float(used))
-        obs.generator_kv_tier_occupancy_ratio().labels(
-            model=self.model).set(
-                min(1.0, used / max(1, self.capacity_blocks)))
-
     def debug(self) -> Dict[str, Any]:
         """The `host_tier` block of `/debug/cache`, federated by the
         router under the `replica` label."""

@@ -1,26 +1,29 @@
-"""The device side of the generation engine: the cache's layout, what a
-kind of cache supports, and the jitted programs.
+"""The device side of the generation engine, built once from the model's
+declaration and the engine's sizes; `GenerationEngine`
+(engine/generator.py) schedules what is built here.
 
-`GenerationEngine` (engine/generator.py) schedules; what it schedules is
-built here, once, from the model's declaration and the engine's sizes:
+- `CacheLayout`: the arrays every layer keeps between steps, and the
+  facts the host side books by; `place_params`: the parameters beside them.
+- `UNSERVED` / `CacheLayout.refusal`: what a kind of layer cannot serve,
+  said once.
+- `build`: the jitted programs, with their donations.  The benchmark's
+  trace reduction and compile-log reader find `decode_fn`, `prefill_fn`
+  and `insert_fn` by name (chipbench/trace.py,
+  chipbench/kinds/generate.py): the inner functions keep their names.
+- `sample`, `mask_to_support`, `logprob_of`: greedy, temperature
+  (Gumbel trick), top-k and top-p (nucleus) per slot, on the device, so
+  only the [S] int32 token vector crosses the host boundary per step —
+  never the [S, V] logits (1.6 MB/step for a GPT-2 vocab).  Noise is
+  keyed per request from (seed, absolute position): a seeded request
+  reproduces exactly no matter how it was scheduled.  Top-N logprobs are
+  computed every step and fetched only when a request asks.
 
-- `lay_out`: the arrays every layer keeps between steps (K/V block
-  pools, a recurrence's state) and the facts the host side books by.
-- `UNSERVED` / `refusal`: what a kind of layer cannot serve, said once.
-- `build`: `decode_fn`, `prefill_fn`, `chunk_prefill_fn`,
-  `spec_verify_fn`, `insert_fn`, `feed_update_fn`, `gather_blocks_fn`,
-  jitted, with their donations.  The benchmark's trace reduction and its
-  compile-log reader find the programs by these names
-  (chipbench/trace.py, chipbench/kinds/generate.py): they stay.
-- `sample`, `mask_to_support`, `logprob_of`: on-device sampling, keyed a
-  row from (seed, absolute position) alone.
-
-Nothing here knows of slots' requests, the block tables' host side
+Nothing here knows of requests, the tables' host side
 (engine/block_pool.py), the metrics registry or the timeline.
 """
 
 import math
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,10 +37,10 @@ from kfserving_tpu.protocol.errors import InvalidInput
 # recurrence's state is not rows addressed by position, and a shared
 # prefix stands for no ring at its end): every plan of such a model is a
 # miss whatever the index holds, registers nothing, and is counted
-# (`CacheLayout.shares_prefixes`).  The settings each rest on rewriting,
-# re-reading or moving rows by position; a ring holds a position wherever
-# it falls, so the position sentinel that parks a row has a place in a
-# live ring and would overwrite it.
+# (`GenerationEngine._shares_prefixes`).  The settings each rest on
+# rewriting, re-reading or moving rows by position; a ring holds a
+# position wherever it falls, so the position sentinel that parks a row
+# has a place in a live ring and would overwrite it.
 UNSERVED: Dict[str, Dict[str, str]] = {
     "recurrent state": {
         "speculative":
@@ -65,58 +68,7 @@ UNSERVED: Dict[str, Dict[str, str]] = {
 }
 
 
-class CacheLayout(NamedTuple):
-    """What `lay_out` gives: the arrays, and the facts the host books by."""
-    kinds: List[Any]            # the model's `cache_layers()`
-    caches: List[Tuple]         # a layer's arrays: pools, state or none
-    dtype: Any
-    pool_shape: Tuple[int, int, int]   # a whole-context pool
-    cache_bytes: int
-    state_bytes: int            # of them a recurrence's state
-    block_size: int
-    blocks_per_slot: int
-    num_blocks: int
-    window: Optional[int]       # None for a model without window layers
-    ring_columns: int           # a slot's ring; 0 without window layers
-    num_window_blocks: int
-    walk_chunks: Tuple[int, int]  # blocks_per_iteration (global, window)
-    kv_heads: int
-    kv_head_dim: int
-    kv_layers: int
-    window_layers: int
-    limits: Tuple[str, ...]     # the kinds of `UNSERVED` the model has
-
-    @property
-    def shares_prefixes(self) -> bool:
-        """Whether a block can stand for a prompt's prefix."""
-        return not self.limits
-
-
-def refusal(layout: CacheLayout, name: str,
-            settings: Dict[str, bool]) -> Optional[str]:
-    """Of `settings` (setting -> whether it is on) the first that a kind
-    of layer this model has cannot serve, as the `InvalidInput` text."""
-    for kind in layout.limits:
-        for setting, why in UNSERVED[kind].items():
-            if settings[setting]:
-                return (f"{setting} is not served for {name!r}, a model "
-                        f"with {kind}: {why}")
-    return None
-
-
-def derive_block_size(max_seq: int, prefill_buckets: List[int]) -> int:
-    """The pool's block size where the caller set none: the largest
-    divisor of 128 that divides max_seq and every prefill bucket (a
-    block never straddles a slot's end, and the insert writes whole
-    blocks).  128, which the Pallas kernels need, wherever the lengths
-    are multiples of it; 16 for pow-2 buckets from 16."""
-    return math.gcd(128, int(max_seq), *(int(b) for b in prefill_buckets))
-
-
-def lay_out(config, name: str, *, max_slots: int, max_seq: int,
-            prefill_buckets: List[int], block_size: Optional[int],
-            cache_blocks: Optional[int],
-            window_cache_blocks: Optional[int], mesh) -> CacheLayout:
+class CacheLayout:
     """The KV cache, a block pool: a shared pool [NB, BS, H*D] a layer
     (ops/paged_attention.py owns the layout) + per-slot block tables, so
     HBM scales with resident tokens and identical prompt prefixes share
@@ -128,126 +80,189 @@ def lay_out(config, name: str, *, max_slots: int, max_seq: int,
     (`config.cache_layers()`, models/decoder.py): K/V rows in the block
     pool, arrays of a slot's own (a recurrence's state:
     models/nemotron_h.py), or nothing.  Every size the engine books or
-    counts comes from this declaration."""
-    kinds = list(config.cache_layers())
-    kv_layers = [c for c in kinds if isinstance(c, KVCache)]
-    has_state = any(isinstance(c, StateCache) for c in kinds)
-    geometries = {(c.heads, c.head_dim) for c in kv_layers}
-    windows = {c.window for c in kv_layers}
-    if len(geometries) != 1 or None not in windows or len(windows) > 2:
-        raise InvalidInput(
-            "the engine pages K/V: a model needs at least one K/V "
-            "layer that keeps its whole context, all K/V layers of "
-            "one geometry, and its sliding-window layers of one "
-            f"window; {name!r} declares {sorted(set(kv_layers), key=str)}")
-    (kv_heads, kv_head_dim), = geometries
-    # Sliding-window layers keep a ring of blocks a sequence in a pool
-    # of their own kind (ops/paged_attention.py).
-    window = max(windows - {None}, default=None)
-    # block_size unset is derived from the lengths: 128 wherever the
-    # kernels can serve.
-    bs = (int(block_size) if block_size
-          else derive_block_size(max_seq, prefill_buckets))
-    if max_seq % bs != 0:
-        raise InvalidInput(
-            f"max_seq {max_seq} must be a multiple of "
-            f"block_size {bs}")
-    for b in prefill_buckets:
-        if b % bs != 0:
+    counts comes from this declaration: the arrays (`caches`, a layer's
+    pools, state or none) and the facts the host side books by are this
+    object's attributes."""
+
+    def __init__(self, config, name: str, *, max_slots: int, max_seq: int,
+                 prefill_buckets: List[int], block_size: Optional[int],
+                 cache_blocks: Optional[int],
+                 window_cache_blocks: Optional[int], mesh):
+        self.kinds = kinds = list(config.cache_layers())
+        kv_layers = [c for c in kinds if isinstance(c, KVCache)]
+        geometries = {(c.heads, c.head_dim) for c in kv_layers}
+        windows = {c.window for c in kv_layers}
+        if len(geometries) != 1 or None not in windows or len(windows) > 2:
             raise InvalidInput(
-                f"prefill bucket {b} must be a multiple of "
-                f"block_size {bs} (paged insert writes whole "
-                f"blocks)")
-    blocks_per_slot = max_seq // bs
-    # Parity default: a block for every position of every slot.  A
-    # smaller cache_blocks is the HBM saving — mixed-length traffic
-    # rarely needs S full-length slots at once.
-    num_blocks = int(cache_blocks or max_slots * blocks_per_slot)
-    pool_shape = paged_attention.pool_shape(
-        num_blocks, bs, kv_heads, kv_head_dim)
-    # The window pool: every window layer's K and V are
-    # [num_window_blocks, BS, H*D], one table [slots, ring] for them
-    # all.  A sequence never holds more than its ring, whatever its
-    # length, so a ring for every slot can never run out; more than
-    # that (`window_cache_blocks`) is room for the blocks of finished
-    # requests that wait out the zombie-wave deferral.
-    ring_columns = num_window_blocks = 0
-    if window is not None:
-        ring_columns = paged_attention.ring_blocks(window, bs)
-        num_window_blocks = int(
-            window_cache_blocks or max_slots * ring_columns)
-        if num_window_blocks < ring_columns:
+                "the engine pages K/V: a model needs at least one K/V "
+                "layer that keeps its whole context, all K/V layers of "
+                "one geometry, and its sliding-window layers of one "
+                f"window; {name!r} declares "
+                f"{sorted(set(kv_layers), key=str)}")
+        (heads, head_dim), = geometries
+        self.kv_heads, self.kv_head_dim = heads, head_dim
+        self.kv_layers = len(kv_layers)
+        self.window_layers = sum(c.window is not None for c in kv_layers)
+        # Sliding-window layers keep a ring of blocks a sequence in a
+        # pool of their own kind (ops/paged_attention.py); None for a
+        # model without any.
+        self.window = window = max(windows - {None}, default=None)
+        # The kinds of `UNSERVED` the model has.
+        self.limits = tuple(kind for kind, has in (
+            ("recurrent state",
+             any(isinstance(c, StateCache) for c in kinds)),
+            ("sliding-window layers", window is not None)) if has)
+        # block_size unset is derived from the lengths: 128 wherever the
+        # kernels can serve.
+        self.block_size = bs = (
+            int(block_size) if block_size
+            else derive_block_size(max_seq, prefill_buckets))
+        if max_seq % bs != 0:
             raise InvalidInput(
-                f"window_cache_blocks {num_window_blocks} is "
-                f"less than one sequence's ring of "
-                f"{ring_columns} blocks (window "
-                f"{window}, block_size {bs})")
-    window_pool_shape = paged_attention.pool_shape(
-        num_window_blocks, bs, kv_heads, kv_head_dim)
-    dtype = config.dtype
-    # Blocks of a row that one loop iteration of the paged decode
-    # kernel takes, by pool (global, window): the kernel's own rule
-    # on the pool one device holds.
-    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    shards = tp if kv_heads % tp == 0 else 1
-    walk_chunks = tuple(
-        paged_attention.blocks_per_iteration(
-            bs, kv_heads // shards * kv_head_dim, dtype, columns)
-        for columns in (blocks_per_slot, ring_columns or 1))
+                f"max_seq {max_seq} must be a multiple of "
+                f"block_size {bs}")
+        for b in prefill_buckets:
+            if b % bs != 0:
+                raise InvalidInput(
+                    f"prefill bucket {b} must be a multiple of "
+                    f"block_size {bs} (paged insert writes whole "
+                    f"blocks)")
+        self.blocks_per_slot = max_seq // bs
+        # Parity default: a block for every position of every slot.  A
+        # smaller cache_blocks is the HBM saving — mixed-length traffic
+        # rarely needs S full-length slots at once.
+        self.num_blocks = int(cache_blocks
+                              or max_slots * self.blocks_per_slot)
+        self.pool_shape = paged_attention.pool_shape(
+            self.num_blocks, bs, heads, head_dim)
+        # The window pool: every window layer's K and V are
+        # [num_window_blocks, BS, H*D], one table [slots, ring] for them
+        # all.  A sequence never holds more than its ring, whatever its
+        # length, so a ring for every slot can never run out; more than
+        # that (`window_cache_blocks`) is room for the blocks of finished
+        # requests that wait out the zombie-wave deferral.
+        self.ring_columns = self.num_window_blocks = 0
+        if window is not None:
+            self.ring_columns = paged_attention.ring_blocks(window, bs)
+            self.num_window_blocks = int(
+                window_cache_blocks or max_slots * self.ring_columns)
+            if self.num_window_blocks < self.ring_columns:
+                raise InvalidInput(
+                    f"window_cache_blocks {self.num_window_blocks} is "
+                    f"less than one sequence's ring of "
+                    f"{self.ring_columns} blocks (window "
+                    f"{window}, block_size {bs})")
+        window_pool_shape = paged_attention.pool_shape(
+            self.num_window_blocks, bs, heads, head_dim)
+        self.dtype = dtype = config.dtype
+        # K and V of one position, over the K/V layers.
+        self.kv_bytes_per_token = (2 * len(kv_layers) * heads * head_dim
+                                   * jnp.dtype(dtype).itemsize)
+        # Blocks of a row that one loop iteration of the paged decode
+        # kernel takes, by pool (global, window): the kernel's own rule
+        # on the pool one device holds.
+        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+        shards = tp if heads % tp == 0 else 1
+        self.walk_chunks = tuple(
+            paged_attention.blocks_per_iteration(
+                bs, heads // shards * head_dim, dtype, columns)
+            for columns in (self.blocks_per_slot, self.ring_columns or 1))
 
-    def layer_cache(kind):
-        """One layer's arrays: its two pools, its state with the
-        slots leading ([max_slots, ...]; a slot's row is written by
-        the insert that admits a request there and stepped by every
-        decode wave, so a reused slot's old state is overwritten
-        before anything reads it), or none."""
-        if isinstance(kind, KVCache):
-            shape = (pool_shape if kind.window is None
-                     else window_pool_shape)
-            return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-        if isinstance(kind, StateCache):
-            return tuple(jnp.zeros((max_slots,) + tuple(shape), dt)
-                         for shape, dt in kind.arrays)
-        return ()
+        def layer_cache(kind):
+            """One layer's arrays: its two pools, its state with the
+            slots leading ([max_slots, ...]; a slot's row is written by
+            the insert that admits a request there and stepped by every
+            decode wave, so a reused slot's old state is overwritten
+            before anything reads it), or none."""
+            if isinstance(kind, KVCache):
+                shape = (self.pool_shape if kind.window is None
+                         else window_pool_shape)
+                return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            if isinstance(kind, StateCache):
+                return tuple(jnp.zeros((max_slots,) + tuple(shape), dt)
+                             for shape, dt in kind.arrays)
+            return ()
 
-    def nbytes(arrays) -> int:
-        return sum(int(x.size) * x.dtype.itemsize
-                   for x in jax.tree.leaves(arrays))
+        def nbytes(arrays) -> int:
+            return sum(int(x.size) * x.dtype.itemsize
+                       for x in jax.tree.leaves(arrays))
 
-    caches = [layer_cache(kind) for kind in kinds]
-    cache_bytes = nbytes(caches)
-    state_bytes = nbytes([layer for kind, layer in zip(kinds, caches)
-                          if isinstance(kind, StateCache)])
+        self.caches = [layer_cache(kind) for kind in kinds]
+        self.cache_bytes = nbytes(self.caches)
+        # Of them a recurrence's state.
+        self.state_bytes = nbytes([
+            layer for kind, layer in zip(kinds, self.caches)
+            if isinstance(kind, StateCache)])
+        if mesh is not None:
+            # Tensor parallelism: the cache shards on the heads axis,
+            # exactly like the q/k/v projections that fill it
+            # (parallel/sharding.py transformer_rules) — cache writes
+            # and decode attention stay device-local per head group;
+            # the per-layer psum after the out-projection is the only
+            # collective.  The pool's last axis is H*D: splitting it
+            # over tp gives the same head groups.
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            heads_axis = "tp" if heads % max(tp, 1) == 0 else None
+            sharding = NamedSharding(
+                mesh, PartitionSpec(None, None, heads_axis))
+            replicated = NamedSharding(mesh, PartitionSpec())
+            self.caches = [
+                tuple(jax.device_put(
+                    x, sharding if isinstance(kind, KVCache)
+                    else replicated) for x in layer)
+                for kind, layer in zip(kinds, self.caches)]
+
+    def refusal(self, name: str, settings: Dict[str, bool]) -> Optional[str]:
+        """Of `settings` (setting -> whether it is on) the first that a
+        kind of layer this model has cannot serve, as the `InvalidInput`
+        text."""
+        for kind in self.limits:
+            for setting, why in UNSERVED[kind].items():
+                if settings[setting]:
+                    return (f"{setting} is not served for {name!r}, a "
+                            f"model with {kind}: {why}")
+        return None
+
+
+def derive_block_size(max_seq: int, prefill_buckets: List[int]) -> int:
+    """The pool's block size where the caller set none: the largest
+    divisor of 128 that divides max_seq and every prefill bucket (a
+    block never straddles a slot's end, and the insert writes whole
+    blocks).  128, which the Pallas kernels need, wherever the lengths
+    are multiples of it; 16 for pow-2 buckets from 16."""
+    return math.gcd(128, int(max_seq), *(int(b) for b in prefill_buckets))
+
+
+def place_params(models, stored, mesh):
+    """`stored` (a tree a model of `models`; None for no model) on the
+    device: a host leaf handed to a jitted call is transferred again on
+    every launch (3.1 GB a launch for gpt2-large, ROADMAP A9).  Under a
+    mesh, leaves that arrive sharded (shard_params) keep their shardings
+    and whatever is still on the host is replicated.  A leaf rests in
+    the dtype its model reads it in, where the model says which that is
+    (`config.resident_dtypes`, models/decoder.py): a program handed
+    float32 leaves that it multiplies in bfloat16 rebuilds their
+    bfloat16 twin on every call (ROADMAP A8)."""
+    from kfserving_tpu import startup
+    from kfserving_tpu.engine import param_cache
+
+    def read_dtypes(module, variables):
+        declare = getattr(getattr(module, "config", None),
+                          "resident_dtypes", None)
+        if declare is None:
+            return jax.tree.map(lambda leaf: leaf.dtype, variables)
+        return declare(variables)
+
+    replicated = None
     if mesh is not None:
-        # Tensor parallelism: the cache shards on the heads axis,
-        # exactly like the q/k/v projections that fill it
-        # (parallel/sharding.py transformer_rules) — cache writes
-        # and decode attention stay device-local per head group;
-        # the per-layer psum after the out-projection is the only
-        # collective.  The pool's last axis is H*D: splitting it
-        # over tp gives the same head groups.
         from jax.sharding import NamedSharding, PartitionSpec
 
-        heads_axis = "tp" if kv_heads % max(tp, 1) == 0 else None
-        sharding = NamedSharding(
-            mesh, PartitionSpec(None, None, heads_axis))
         replicated = NamedSharding(mesh, PartitionSpec())
-        caches = [
-            tuple(jax.device_put(x, sharding if isinstance(kind, KVCache)
-                                 else replicated) for x in layer)
-            for kind, layer in zip(kinds, caches)]
-    return CacheLayout(
-        kinds=kinds, caches=caches, dtype=dtype, pool_shape=pool_shape,
-        cache_bytes=cache_bytes, state_bytes=state_bytes, block_size=bs,
-        blocks_per_slot=blocks_per_slot, num_blocks=num_blocks,
-        window=window, ring_columns=ring_columns,
-        num_window_blocks=num_window_blocks, walk_chunks=walk_chunks,
-        kv_heads=kv_heads, kv_head_dim=kv_head_dim,
-        kv_layers=len(kv_layers),
-        window_layers=sum(c.window is not None for c in kv_layers),
-        limits=tuple(kind for kind, has in (
-            ("recurrent state", has_state),
-            ("sliding-window layers", window is not None)) if has))
+    placed = param_cache.place_on_device(stored, replicated, tuple(
+        read_dtypes(model, tree) for model, tree in zip(models, stored)))
+    startup.mark("params_device")
+    return placed
 
 
 def mask_to_support(logits, top_ks, top_ps):

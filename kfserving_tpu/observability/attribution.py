@@ -209,10 +209,6 @@ def publish_cache_gauges(model: str, stats: Dict[str, Any]) -> Set[str]:
             for pool, rec in (paged.get("pools") or {}).items():
                 obs.generator_kv_pool_fill_ratio().labels(
                     model=model, pool=pool).set(_clamp01(rec["fill"]))
-            frag = paged.get("fragmentation_ratio")
-            if isinstance(frag, (int, float)):
-                obs.generator_pool_fragmentation_ratio().labels(
-                    model=model).set(_clamp01(frag))
     except Exception:
         import logging
 

@@ -256,23 +256,8 @@ def generator_block_evictions_total():
 
 
 # -- host KV tier (engine/kv_tier.py): spilled-conversation residency
-# one level under the device pool — occupancy, spill/fault outcomes,
+# one level under the device pool — spill/fault outcomes,
 # and the latency of faulting a returning turn's blocks back ----------
-def generator_kv_tier_blocks():
-    return REGISTRY.gauge(
-        "kfserving_tpu_generator_kv_tier_blocks",
-        "Blocks currently held by the host KV tier (spilled "
-        "conversation prefixes a returning turn can fault back "
-        "instead of re-prefilling)")
-
-
-def generator_kv_tier_occupancy_ratio():
-    return REGISTRY.gauge(
-        "kfserving_tpu_generator_kv_tier_occupancy_ratio",
-        "Host KV tier occupancy over its capacity (1.0 = the tier's "
-        "own LRU ledger is evicting on every admission)")
-
-
 def generator_kv_tier_spills_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_kv_tier_spills_total",
@@ -391,15 +376,6 @@ def generator_pool_occupancy_ratio():
         "Referenced (ref > 0) blocks over the whole pool at the last "
         "scrape — 1.0 means every block is held by a live slot or "
         "shared prefix; reclaimable cached blocks do not count")
-
-
-def generator_pool_fragmentation_ratio():
-    return REGISTRY.gauge(
-        "kfserving_tpu_generator_pool_fragmentation_ratio",
-        "Internal fragmentation of slot tables: 1 - resident tokens "
-        "/ (table blocks x block_size), with shared prefix blocks "
-        "counted per sharer on both sides — the tail positions "
-        "allocated for growth but not yet holding k/v")
 
 
 def generator_params_resident_bytes():

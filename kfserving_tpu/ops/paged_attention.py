@@ -68,7 +68,6 @@ and the kernel with zeros.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -510,13 +509,7 @@ def _kernels_serve(block_size: int, heads: int, head_dim: int,
     both lane multiples, so that every block is whole tiles (the XLA
     formulations serve the rest, and the CPU); with `group` > 1 query
     heads a KV head, also a head size of whole lane tiles, whole sublane
-    tiles of query heads and no mesh.
-    KFS_DISABLE_PAGED_KERNEL=1 forces the XLA path — the on-chip A/B
-    kill-switch, mirroring the flash kernel's KFS_DISABLE_FLASH.
-    NOTE: read inside the jitted decode function, so once, at the
-    first decode compile (effectively process start); flipping it later
-    has no effect in-process — restart the replica to switch paths
-    (same semantics as KFS_DISABLE_FLASH)."""
+    tiles of query heads and no mesh."""
     mesh = jax.sharding.get_abstract_mesh()
     shards = 1
     if not mesh.empty and attention.mesh_axis(mesh, "tp", heads):
@@ -525,9 +518,7 @@ def _kernels_serve(block_size: int, heads: int, head_dim: int,
                           and (heads * group) % 16 == 0):
         return False
     return (attention._tpu_backend() and block_size % 128 == 0
-            and (heads // shards * head_dim) % 128 == 0
-            and os.environ.get("KFS_DISABLE_PAGED_KERNEL", "")
-            in ("", "0", "false"))
+            and (heads // shards * head_dim) % 128 == 0)
 
 
 def paged_attention(q, pool_k, pool_v, block_table, lengths,

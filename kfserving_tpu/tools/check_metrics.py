@@ -186,8 +186,6 @@ async def smoke() -> List[str]:
         model="metrics-probe").observe(3)
     obs.generator_pool_occupancy_ratio().labels(
         model="metrics-probe").set(0.62)
-    obs.generator_pool_fragmentation_ratio().labels(
-        model="metrics-probe").set(0.18)
     obs.generator_params_resident_bytes().labels(
         model="metrics-probe").set(1.55e9)
     obs.generator_params_narrowed_bytes().labels(
@@ -244,15 +242,11 @@ async def smoke() -> List[str]:
         model="metrics-probe").observe(5)
     obs.request_cache_saved_tokens().labels(
         model="metrics-probe").observe(256)
-    # Tiered KV residency families (ISSUE 16): host-tier occupancy,
-    # spill/fault-back outcomes, tier evictions, fault-back latency,
+    # Tiered KV residency families (ISSUE 16): spill/fault-back
+    # outcomes, tier evictions, fault-back latency,
     # and the per-request host-tier savings histogram (distinct from
     # the device-cache one just above) — representative samples so
     # names, label shapes, and unit suffixes always lint.
-    obs.generator_kv_tier_blocks().labels(
-        model="metrics-probe").set(48.0)
-    obs.generator_kv_tier_occupancy_ratio().labels(
-        model="metrics-probe").set(0.75)
     for outcome in ("spilled", "failed", "duplicate"):
         obs.generator_kv_tier_spills_total().labels(
             model="metrics-probe", outcome=outcome).inc()

@@ -516,20 +516,20 @@ async def test_a_window_layer_holds_its_ring_and_a_global_layer_its_context(
     # the rest: min(len, 16) rows in min(blocks, 3) columns each.  The
     # fifteenth wave's fourth step is past the budget: parked, it walks
     # nothing.
-    assert engine._ring_context_tokens == 59 * WINDOW
-    assert engine._ring_blocks_walked == 59 * RING
-    assert engine._kv_context_tokens == sum(range(101, 160))
+    assert engine._walked["window"][0] == 59 * WINDOW
+    assert engine._walked["window"][1] == 59 * RING
+    assert engine._walked["global"][0] == sum(range(101, 160))
     # The kernel's loop iterations, by each pool's own table: the whole
     # ring of 3 at once, the global layer's columns 4 at a time.
     assert engine._walk_chunks == (4, RING)
-    assert engine._ring_walk_iterations == 59
+    assert engine._walked["window"][2] == 59
     columns = [-(-n // BS) for n in range(101, 160)]
-    assert engine._kv_blocks_walked == sum(columns)
-    assert engine._kv_walk_iterations == sum(-(-c // 4) for c in columns)
+    assert engine._walked["global"][1] == sum(columns)
+    assert engine._walked["global"][2] == sum(-(-c // 4) for c in columns)
     assert stats["kv_blocks_per_iteration"] == round(
         (sum(columns) + 59 * RING)
-        / (engine._kv_walk_iterations + 59), 4)
-    for pool, iterations in (("global", engine._kv_walk_iterations),
+        / (engine._walked["global"][2] + 59), 4)
+    for pool, iterations in (("global", engine._walked["global"][2]),
                              ("window", 59)):
         assert obs.generator_decode_kv_pool_walk_iterations_total(
             ).labels(model="mellum-test", pool=pool).value >= iterations

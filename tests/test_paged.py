@@ -820,9 +820,7 @@ async def test_decode_waves_count_the_blocks_and_tokens_they_read(
     context = len(prompt) + 1 + np.arange(new - 1)
     tokens, blocks = int(context.sum()), int((-(-context // BS)).sum())
     iterations = int((-(-(-(-context // BS)) // n)).sum())
-    assert eng._kv_context_tokens == tokens
-    assert eng._kv_blocks_walked == blocks
-    assert eng._kv_walk_iterations == iterations
+    assert eng._walked["global"].tolist() == [tokens, blocks, iterations]
     assert (iterations == blocks) == (n == 1)
     assert stats["kv_block_fill"] == round(tokens / (blocks * BS), 4)
     assert stats["kv_blocks_per_iteration"] == round(blocks / iterations, 4)

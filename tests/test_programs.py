@@ -33,9 +33,8 @@ NAMES = {"decode": "decode_fn", "prefill": "prefill_fn",
 def test_build_gives_the_programs_under_the_names_the_benchmark_reads(
         architecture, limits):
     module = create_model(architecture).module
-    layout = programs.lay_out(module.config, architecture, **SIZES)
+    layout = programs.CacheLayout(module.config, architecture, **SIZES)
     assert layout.limits == limits
-    assert layout.shares_prefixes == (not limits)
     built = programs.build(module, layout.kinds, 4, 5,
                            jax.random.PRNGKey(0))
     for field, name in NAMES.items():
@@ -52,7 +51,7 @@ def test_build_gives_the_programs_under_the_names_the_benchmark_reads(
 
 def test_decode_fn_is_named_in_its_lowered_program_and_scans_its_steps():
     module = create_model("decoder_tiny").module
-    layout = programs.lay_out(module.config, "m", **SIZES)
+    layout = programs.CacheLayout(module.config, "m", **SIZES)
     variables = jax.eval_shape(
         lambda: module.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 16), jnp.int32)))
@@ -112,9 +111,9 @@ def test_mask_to_support_keeps_top_k_and_the_nucleus():
                              [True, True, True, True]]
 
 
-def test_lay_out_books_both_pools_of_a_window_model():
+def test_the_layout_books_both_pools_of_a_window_model():
     module = create_model("mellum_tiny").module
-    layout = programs.lay_out(module.config, "m", **SIZES)
+    layout = programs.CacheLayout(module.config, "m", **SIZES)
     assert layout.window == 16 and layout.ring_columns == 2
     assert layout.num_window_blocks == 4 * 2
     assert layout.blocks_per_slot == 4 and layout.num_blocks == 16
@@ -134,23 +133,23 @@ def test_refusal_names_the_setting_the_model_and_the_kind(kind, setting):
     architecture = {"recurrent state": "nemotron_h_tiny",
                     "sliding-window layers": "mellum_tiny"}[kind]
     module = create_model(architecture).module
-    layout = programs.lay_out(module.config, "m", **SIZES)
+    layout = programs.CacheLayout(module.config, "m", **SIZES)
     off = dict.fromkeys(programs.UNSERVED[kind], False)
-    assert programs.refusal(layout, "m", off) is None
-    text = programs.refusal(layout, "m", {**off, setting: True})
+    assert layout.refusal("m", off) is None
+    text = layout.refusal("m", {**off, setting: True})
     assert text.startswith(
         f"{setting} is not served for 'm', a model with {kind}: ")
-    dense = programs.lay_out(create_model("decoder_tiny").module.config,
-                             "d", **SIZES)
-    assert programs.refusal(dense, "d", dict.fromkeys(off, True)) is None
+    dense = programs.CacheLayout(
+        create_model("decoder_tiny").module.config, "d", **SIZES)
+    assert dense.refusal("d", dict.fromkeys(off, True)) is None
 
 
-def test_lay_out_refuses_lengths_that_are_not_whole_blocks():
+def test_the_layout_refuses_lengths_that_are_not_whole_blocks():
     config = create_model("decoder_tiny").module.config
     with pytest.raises(InvalidInput, match="multiple of block_size 16"):
-        programs.lay_out(config, "m", **{**SIZES, "max_seq": 72})
+        programs.CacheLayout(config, "m", **{**SIZES, "max_seq": 72})
     with pytest.raises(InvalidInput, match="prefill bucket 24"):
-        programs.lay_out(config, "m",
-                         **{**SIZES, "prefill_buckets": [24, 32]})
+        programs.CacheLayout(
+            config, "m", **{**SIZES, "prefill_buckets": [24, 32]})
     assert programs.derive_block_size(64, [16, 32]) == 16
     assert programs.derive_block_size(2048, [128, 1024]) == 128
