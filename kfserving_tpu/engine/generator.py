@@ -327,6 +327,7 @@ class GenerationEngine:
         self._cache_shape = layout.pool_shape
         self._cache_bytes = layout.cache_bytes
         self.recurrent_state_bytes = layout.state_bytes
+        self._state_bytes_per_slot = layout.state_bytes_per_slot
         self.block_size = layout.block_size
         self.blocks_per_slot = layout.blocks_per_slot
         self.num_blocks = layout.num_blocks
@@ -950,6 +951,11 @@ class GenerationEngine:
             "prefill_rows": self.prefill_rows or 0,
             "cache_bytes": self.cache_bytes(),
             "recurrent_state_bytes": self.recurrent_state_bytes,
+            # What a request costs the cache: a state a slot and K/V rows
+            # a position, each over the layers that keep one (a layer may
+            # keep both).
+            "state_bytes_per_slot": self._state_bytes_per_slot,
+            "kv_bytes_per_token": self._kv_bytes_per_token,
             "params_resident_bytes": self._params_resident_bytes,
             "params_narrowed_bytes": self._params_narrowed_bytes,
             "active_params": self._active_params,

@@ -28,7 +28,7 @@ from typing import Any, Dict, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from kfserving_tpu.models.decoder import KVCache, StateCache
+from kfserving_tpu.models.decoder import BothCaches, KVCache, StateCache
 from kfserving_tpu.ops import paged_attention
 from kfserving_tpu.protocol.errors import InvalidInput
 
@@ -68,6 +68,36 @@ UNSERVED: Dict[str, Dict[str, str]] = {
 }
 
 
+def parts(kind):
+    """(the `KVCache`, the `StateCache`) of one layer's declaration, None
+    for what the layer does not keep."""
+    if isinstance(kind, BothCaches):
+        return kind.kv, kind.state
+    return (kind if isinstance(kind, KVCache) else None,
+            kind if isinstance(kind, StateCache) else None)
+
+
+def by_part(kind, on_kv, on_state, *layers):
+    """One layer's cache with `on_kv(its KVCache, pools...)` in place of its
+    K/V part and `on_state(its StateCache, arrays...)` in place of its
+    state; `layers` are caches of that layer of one structure (the engine's
+    and a prefill's, say; none where the parts are being made), handed
+    over part by part.  A layer that keeps nothing stays the first of
+    them."""
+    if isinstance(kind, BothCaches):
+        return (on_kv(kind.kv, *(layer[0] for layer in layers)),
+                on_state(kind.state, *(layer[1] for layer in layers)))
+    if isinstance(kind, KVCache):
+        return on_kv(kind, *layers)
+    if isinstance(kind, StateCache):
+        return on_state(kind, *layers)
+    return layers[0] if layers else ()
+
+
+def _kept(kind, arrays):
+    return arrays
+
+
 class CacheLayout:
     """The KV cache, a block pool: a shared pool [NB, BS, H*D] a layer
     (ops/paged_attention.py owns the layout) + per-slot block tables, so
@@ -79,7 +109,8 @@ class CacheLayout:
     The model declares what each layer keeps between steps
     (`config.cache_layers()`, models/decoder.py): K/V rows in the block
     pool, arrays of a slot's own (a recurrence's state:
-    models/nemotron_h.py), or nothing.  Every size the engine books or
+    models/nemotron_h.py), both for the one layer (models/falcon_h1.py:
+    the layer's pair of them), or nothing.  Every size the engine books or
     counts comes from this declaration: the arrays (`caches`, a layer's
     pools, state or none) and the facts the host side books by are this
     object's attributes."""
@@ -89,7 +120,8 @@ class CacheLayout:
                  cache_blocks: Optional[int],
                  window_cache_blocks: Optional[int], mesh):
         self.kinds = kinds = list(config.cache_layers())
-        kv_layers = [c for c in kinds if isinstance(c, KVCache)]
+        kv_layers = [kv for kv, _ in map(parts, kinds) if kv is not None]
+        state_layers = [st for _, st in map(parts, kinds) if st is not None]
         geometries = {(c.heads, c.head_dim) for c in kv_layers}
         windows = {c.window for c in kv_layers}
         if len(geometries) != 1 or None not in windows or len(windows) > 2:
@@ -109,8 +141,7 @@ class CacheLayout:
         self.window = window = max(windows - {None}, default=None)
         # The kinds of `UNSERVED` the model has.
         self.limits = tuple(kind for kind, has in (
-            ("recurrent state",
-             any(isinstance(c, StateCache) for c in kinds)),
+            ("recurrent state", bool(state_layers)),
             ("sliding-window layers", window is not None)) if has)
         # block_size unset is derived from the lengths: 128 wherever the
         # kernels can serve.
@@ -168,31 +199,33 @@ class CacheLayout:
                 bs, heads // shards * head_dim, dtype, columns)
             for columns in (self.blocks_per_slot, self.ring_columns or 1))
 
-        def layer_cache(kind):
-            """One layer's arrays: its two pools, its state with the
-            slots leading ([max_slots, ...]; a slot's row is written by
-            the insert that admits a request there and stepped by every
-            decode wave, so a reused slot's old state is overwritten
-            before anything reads it), or none."""
-            if isinstance(kind, KVCache):
-                shape = (self.pool_shape if kind.window is None
-                         else window_pool_shape)
-                return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            if isinstance(kind, StateCache):
-                return tuple(jnp.zeros((max_slots,) + tuple(shape), dt)
-                             for shape, dt in kind.arrays)
-            return ()
+        def pools(kind):
+            shape = (self.pool_shape if kind.window is None
+                     else window_pool_shape)
+            return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+        def state(kind):
+            """The slots leading ([max_slots, ...]): a slot's row is
+            written by the insert that admits a request there and stepped
+            by every decode wave, so a reused slot's old state is
+            overwritten before anything reads it."""
+            return tuple(jnp.zeros((max_slots,) + tuple(shape), dt)
+                         for shape, dt in kind.arrays)
 
         def nbytes(arrays) -> int:
             return sum(int(x.size) * x.dtype.itemsize
                        for x in jax.tree.leaves(arrays))
 
-        self.caches = [layer_cache(kind) for kind in kinds]
+        # One layer's arrays: its two pools, its state, the pair of them,
+        # or none.
+        self.caches = [by_part(kind, pools, state) for kind in kinds]
         self.cache_bytes = nbytes(self.caches)
-        # Of them a recurrence's state.
-        self.state_bytes = nbytes([
-            layer for kind, layer in zip(kinds, self.caches)
-            if isinstance(kind, StateCache)])
+        # Of them a recurrence's state, in all and a slot: what admitting
+        # a request costs beside `kv_bytes_per_token` a position.
+        self.state_bytes_per_slot = sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for kind in state_layers for shape, dt in kind.arrays)
+        self.state_bytes = max_slots * self.state_bytes_per_slot
         if mesh is not None:
             # Tensor parallelism: the cache shards on the heads axis,
             # exactly like the q/k/v projections that fill it
@@ -207,11 +240,13 @@ class CacheLayout:
             sharding = NamedSharding(
                 mesh, PartitionSpec(None, None, heads_axis))
             replicated = NamedSharding(mesh, PartitionSpec())
-            self.caches = [
-                tuple(jax.device_put(
-                    x, sharding if isinstance(kind, KVCache)
-                    else replicated) for x in layer)
-                for kind, layer in zip(kinds, self.caches)]
+            def put(where):
+                return lambda _, arrays: tuple(
+                    jax.device_put(x, where) for x in arrays)
+
+            self.caches = [by_part(kind, put(sharding), put(replicated),
+                                   layer)
+                           for kind, layer in zip(kinds, self.caches)]
 
     def refusal(self, name: str, settings: Dict[str, bool]) -> Optional[str]:
         """Of `settings` (setting -> whether it is on) the first that a
@@ -361,8 +396,8 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
     # what its routers chose; a dense decoder's programs and
     # fetches are what they were.
     routed = bool(getattr(module.config, "num_experts", 0))
-    has_window = any(isinstance(kind, KVCache) and kind.window is not None
-                     for kind in cache_kinds)
+    has_window = any(kv is not None and kv.window is not None
+                     for kv, _ in map(parts, cache_kinds))
 
     def apply(variables, ids, **kw):
         """(module.apply's outputs, what an expert model's routers
@@ -390,8 +425,8 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
     def with_table(caches, table):
         """The caches as the model takes them: a K/V layer's pools
         with this dispatch's block table for its pool."""
-        return [layer + (by_pool(table, kind),)
-                if isinstance(kind, KVCache) else layer
+        return [by_part(kind, lambda kv, pools: pools + (
+                            by_pool(table, kv),), _kept, layer)
                 for kind, layer in zip(cache_kinds, caches)]
 
     k_steps = steps_per_call
@@ -473,8 +508,9 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         # the insert is then a scatter of whole blocks, where
         # [B, L, H, D] results (L minor-most on the chip) would be
         # transposed on their way in.
-        caches = [tuple(x.reshape(x.shape[:2] + (-1,)) for x in layer)
-                  if isinstance(kind, KVCache) else layer
+        caches = [by_part(kind, lambda _, rows: tuple(
+                              x.reshape(x.shape[:2] + (-1,)) for x in rows),
+                          _kept, layer)
                   for kind, layer in zip(cache_kinds, caches)]
         last = logits[:, 0]
         first_tokens = sample(base_key, last, temps, top_ks, top_ps,
@@ -571,20 +607,19 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         padding rows, and prefix-cache hits whose shared blocks
         already hold the data); for a model with sliding-window
         layers a pair of them, the second the rings', which take
-        a prompt's last blocks alone.  A state layer's rows go to their
+        a prompt's last blocks alone.  A state's rows go to their
         slots whole (`slots` [B] int32, past-the-end for a padding
-        row, which drops)."""
-        out = []
-        for kind, layer, new in zip(cache_kinds, caches, new_caches):
-            if isinstance(kind, KVCache):
-                layer = paged_attention.paged_insert(
-                    *layer, *new, by_pool(dest_blocks, kind), None)
-            elif isinstance(kind, StateCache):
-                layer = tuple(
-                    old.at[slots].set(x.astype(old.dtype), mode="drop")
-                    for old, x in zip(layer, new))
-            out.append(layer)
-        return out
+        row, which drops); a layer that keeps both takes both."""
+        def blocks(kv, pools, new):
+            return paged_attention.paged_insert(
+                *pools, *new, by_pool(dest_blocks, kv), None)
+
+        def rows(_, state, new):
+            return tuple(old.at[slots].set(x.astype(old.dtype), mode="drop")
+                         for old, x in zip(state, new))
+
+        return [by_part(kind, blocks, rows, layer, new)
+                for kind, layer, new in zip(cache_kinds, caches, new_caches)]
 
     def gather_blocks_fn(caches, idx):
         """Snapshot the k/v of pool blocks `idx` [N] as

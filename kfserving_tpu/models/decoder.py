@@ -46,7 +46,8 @@ from kfserving_tpu.ops import dot_product_attention
 # What a layer keeps between steps, as a model's config declares it
 # (`config.cache_layers()`, one entry a layer) and the engine builds it:
 # K/V rows in the block pool, arrays of a slot's own (a recurrence's
-# state), or None.
+# state), both (a layer whose attention and recurrence run side by side),
+# or None.
 class KVCache(NamedTuple):
     heads: int       # KV heads: fewer than the query's under GQA
     head_dim: int
@@ -60,6 +61,16 @@ class KVCache(NamedTuple):
 class StateCache(NamedTuple):
     # ((shape without the slot axis, dtype), ...), one per array
     arrays: Tuple[Tuple[Tuple[int, ...], Any], ...]
+
+
+class BothCaches(NamedTuple):
+    """One layer's K/V rows and its state (models/falcon_h1.py).  Wherever
+    a layer's cache is handed over (the engine's arrays, what prefill
+    returns, what decode takes and returns) such a layer's is the pair
+    (what a `KVCache` layer's would be, what a `StateCache` layer's would
+    be)."""
+    kv: KVCache
+    state: StateCache
 
 
 # The modules of DecoderLM that read their parameters in `config.dtype`.

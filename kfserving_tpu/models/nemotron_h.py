@@ -69,7 +69,7 @@ engine refuses the settings that would ask for one.
 """
 
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -214,8 +214,25 @@ def _padded(init, axis: int, width: int):
     return padded
 
 
+def scaled(x, multiplier):
+    """x · multiplier, the product made in float32 and rounded to x's
+    dtype once (a Python scalar times a bfloat16 array would round the
+    multiplier itself to 8 bits first).  `multiplier` is a scalar or an
+    array over x's last axis."""
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
+
+
 class MambaMixer(nn.Module):
-    config: NemotronHConfig
+    """Mamba-2 between a block's norm and its residual.  `config` gives the
+    sizes (any config with this one's Mamba attributes: models/falcon_h1.py
+    brings its own).  The three scales are scalar multipliers that some
+    models put around the projections, None (this model) for none: on the
+    input, on the in-projection's segments (z, x, B, C, dt), on the
+    output."""
+    config: Any
+    in_scale: Optional[float] = None
+    segment_scales: Optional[Tuple[float, ...]] = None
+    out_scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, hidden, *, kv_lengths=None, cache=None):
@@ -228,9 +245,16 @@ class MambaMixer(nn.Module):
         g, n = cfg.ssm_groups, cfg.ssm_state
         inner, conv = cfg.mamba_inner, cfg.conv_width
         with jax.named_scope("ssm.in_proj"):
+            if self.in_scale is not None:
+                hidden = scaled(hidden, self.in_scale)
             zxbcdt = nn.Dense(inner + conv + heads, use_bias=False,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               name="in_proj")(hidden)
+            if self.segment_scales is not None:
+                zxbcdt = scaled(zxbcdt, jnp.concatenate([
+                    jnp.full((width,), m, jnp.float32)
+                    for width, m in zip((inner, inner, g * n, g * n, heads),
+                                        self.segment_scales)]))
             z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv], axis=-1)
             dt_bias = self.param("dt_bias", _dt_bias_init, (heads,),
                                  jnp.float32)
@@ -278,6 +302,8 @@ class MambaMixer(nn.Module):
                  * scale.astype(jnp.float32)).astype(cfg.dtype)
             out = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                            param_dtype=cfg.param_dtype, name="out_proj")(y)
+            if self.out_scale is not None:
+                out = scaled(out, self.out_scale)
         return out, (state, conv_state)
 
 

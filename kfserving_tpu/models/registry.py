@@ -39,6 +39,9 @@ _LAZY_BUILTINS: Dict[str, Tuple[str, str]] = {
                         "_create_nemotron_h_tiny"),
     "mellum": ("kfserving_tpu.models.mellum", "_create_mellum"),
     "mellum_tiny": ("kfserving_tpu.models.mellum", "_create_mellum_tiny"),
+    "falcon_h1": ("kfserving_tpu.models.falcon_h1", "_create_falcon_h1"),
+    "falcon_h1_tiny": ("kfserving_tpu.models.falcon_h1",
+                       "_create_falcon_h1_tiny"),
 }
 
 

@@ -382,8 +382,13 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     h_pad = -(-h // _sublanes(compute)) * _sublanes(compute)
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     # A row's query and answer: all heads flat, or [heads, D] when a KV
-    # head has several (whole sublane tiles of them: `_kernels_serve`).
-    row_shape = (b, 1, hd) if group == 1 else (b, h, d)
+    # head has several, in whole sublane tiles: 20 query heads come as 32
+    # in bfloat16, the rows past them zeros that belong to no KV head
+    # (`own_columns`), whose answers are cut off below.
+    row_shape = (b, 1, hd) if group == 1 else (b, h_pad, d)
+    q = q.reshape(b, 1, hd) if group == 1 else q.reshape(b, h, d)
+    if group > 1 and h_pad > h:
+        q = jnp.pad(q, ((0, 0), (0, h_pad - h), (0, 0)))
     rows = pl.BlockSpec(row_shape, lambda i, *_: (0, 0, 0))
     blocks = pltpu.VMEM((_BUFFERS, chunk * bs, hd), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -408,7 +413,9 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(row_shape, q.dtype),
         interpret=interpret,
-    )(*scalars, q.reshape(row_shape), pool_k, pool_v)
+    )(*scalars, q, pool_k, pool_v)
+    if group > 1:
+        out = out[:, :h]
     return out.reshape(b, 1, h, d)
 
 
@@ -508,14 +515,14 @@ def _kernels_serve(block_size: int, heads: int, head_dim: int,
     time: on a TPU, with block_size and the H*D of one (KV) heads shard
     both lane multiples, so that every block is whole tiles (the XLA
     formulations serve the rest, and the CPU); with `group` > 1 query
-    heads a KV head, also a head size of whole lane tiles, whole sublane
-    tiles of query heads and no mesh."""
+    heads a KV head, also a head size of whole lane tiles and no mesh
+    (query heads that are not whole sublane tiles are padded to them:
+    `paged_attention_tpu`)."""
     mesh = jax.sharding.get_abstract_mesh()
     shards = 1
     if not mesh.empty and attention.mesh_axis(mesh, "tp", heads):
         shards = mesh.shape["tp"]
-    if group > 1 and not (mesh.empty and head_dim % 128 == 0
-                          and (heads * group) % 16 == 0):
+    if group > 1 and not (mesh.empty and head_dim % 128 == 0):
         return False
     return (attention._tpu_backend() and block_size % 128 == 0
             and (heads // shards * head_dim) % 128 == 0)

@@ -33,24 +33,33 @@ import jax.numpy as jnp
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
-# What `ssd_prefill` has run at on a v5e: 1, 2, 3, 4 and 8 rows of a 1024
-# bucket.  The chip hung at (4, 1024) while the chunk recurrence below was a
-# `lax.scan`, and why is not known (PERF.md, PR 31, N6): a hang takes the chip
-# and raises nothing, so on a TPU the engine refuses at load whatever was not
-# run (`unproven_on_chip`), until the cause is found.
-CHIP_PROVEN_ROWS = 8
-CHIP_PROVEN_BUCKET = 1024
+# (rows, bucket) of every prefill that `ssd_prefill` has run in on a v5e,
+# each beside the recurrence it ran (heads x head size x state, groups).
+# The chip hung at (4, 1024) while the chunk recurrence below was a
+# `lax.scan`, and why is not known (PERF.md, PR 31, N6): a hang takes the
+# chip and raises nothing, so on a TPU the engine refuses at load whatever
+# was not run (`unproven_on_chip`), until the cause is found.  A shape is
+# added here by whoever has run it there, alone, with a timeout.
+CHIP_PROVEN = frozenset(
+    # 64 x 64 x 128, 8 groups (PR 31; 3 rows of it too, which no dispatch
+    # has: the engine pads a group's rows to a power of two)
+    [(rows, 1024) for rows in (1, 2, 4, 8)]
+    # 32 x 128 x 256, 2 groups (PR 51)
+    + [(rows, 512) for rows in (1, 2, 4, 8)])
 
 
 def unproven_on_chip(prefill_rows: Optional[int], buckets) -> Optional[str]:
     """Why a prefill of up to `prefill_rows` rows over `buckets` may not be
-    dispatched to a TPU, or None where every shape of it has been run."""
-    if not prefill_rows or prefill_rows > CHIP_PROVEN_ROWS:
-        return (f"prefill_rows must be set, and at most {CHIP_PROVEN_ROWS} "
-                f"(it is {prefill_rows})")
-    if any(int(b) != CHIP_PROVEN_BUCKET for b in buckets):
-        return (f"every prefill bucket must be {CHIP_PROVEN_BUCKET} "
-                f"(they are {list(buckets)})")
+    dispatched to a TPU, or None where every shape of it (the powers of two
+    that hold up to `prefill_rows` rows, at every bucket) has been run."""
+    if not prefill_rows:
+        return "prefill_rows must be set"
+    rows = [1 << i for i in range((int(prefill_rows) - 1).bit_length() + 1)]
+    missing = sorted({(r, int(b)) for r in rows for b in buckets}
+                     - CHIP_PROVEN)
+    if missing:
+        return (f"(rows, bucket) {missing} have not; those that have: "
+                f"{sorted(CHIP_PROVEN)}")
     return None
 
 

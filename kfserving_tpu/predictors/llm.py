@@ -439,6 +439,7 @@ class GenerativeConfig:
                  adaptive_depth: bool = True,
                  speculative: Optional[Dict[str, Any]] = None,
                  prefill_rows: Optional[int] = None,
+                 ignore_eos: bool = False,
                  mesh: Optional[Dict[str, int]] = None,
                  **_ignored):
         self.architecture = architecture
@@ -491,6 +492,11 @@ class GenerativeConfig:
         # free slot): what fits beside the parameters is the
         # deployment's to know, not the runtime's to refuse.
         self.prefill_rows = int(prefill_rows) if prefill_rows else None
+        # An answer ends at its token budget or a stop sequence alone:
+        # for a replica whose weights are seeded and not trained, where
+        # the tokenizer's EOS id is a row of the vocabulary like any
+        # other and a load test asks for answers of given lengths.
+        self.ignore_eos = bool(ignore_eos)
         self.mesh = mesh or {}
 
     @classmethod
@@ -605,7 +611,8 @@ class GenerativeModel(Model):
             spec.module, variables,
             max_slots=cfg.max_slots, max_seq=cfg.max_seq,
             prefill_buckets=cfg.prefill_buckets,
-            eos_id=getattr(self.tokenizer, "eos_id", None),
+            eos_id=(None if cfg.ignore_eos
+                    else getattr(self.tokenizer, "eos_id", None)),
             steps_per_call=cfg.steps_per_call,
             pipeline_depth=cfg.pipeline_depth,
             logprob_topk=cfg.logprob_topk,

@@ -805,6 +805,83 @@ def test_gpt2_large_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
     assert _program_bytes(memory) + 3.4e9 < 15.75 * 2**30, memory
 
 
+def _falcon_serving() -> dict:
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "falcon-h1-34b-6l.json")) as f:
+        return json.load(f)["serving"]
+
+
+def test_five_query_heads_a_kv_head_compile_for_described_v5e(v5e):
+    """The paged decode kernel at `falcon-h1-34b-6l`'s shapes: 20 query
+    heads on 4 KV heads of 128, which are no whole sublane tiles (16 rows
+    of bfloat16): the rows come padded to 32, and the kernel is the one
+    Mosaic call it was at 32 query heads, the pools not copied, 4 blocks an
+    iteration."""
+    from kfserving_tpu.ops import paged_attention
+
+    serving = _falcon_serving()
+    kw = serving["arch_kwargs"]
+    columns = serving["max_seq"] // serving["block_size"]
+    args = _paged_args(serving["max_slots"], kw["num_kv_heads"],
+                       kw["head_dim"], serving["cache_blocks"],
+                       serving["block_size"], columns)
+    args[0] = ((serving["max_slots"], 1, kw["num_heads"], kw["head_dim"]),
+               jnp.bfloat16, P())
+    assert (kw["num_heads"], kw["num_kv_heads"]) == (20, 4)
+    assert paged_attention.blocks_per_iteration(
+        serving["block_size"], args[1][0][2], jnp.bfloat16, columns) == 4
+    compiled = _compile(paged_attention.paged_attention_sharded, args, v5e,
+                        sharded=False)
+    assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 1
+    assert _pool_copies(compiled, args[1][0]) == []
+    text = compiled.as_text()
+    assert "bf16[64,32,128]" in text  # the padded rows, in and out
+    memory = compiled.memory_analysis()
+    print(f"falcon paged kernel: {memory}")
+    assert memory.temp_size_in_bytes < 2**20, memory
+
+
+def test_falcon_decode_program_fits_the_described_v5e(v5e, monkeypatch):
+    """The 16-step decode program of `falcon-h1-34b-6l` (64 slots, six
+    layers that each keep K/V rows AND a state): its arguments are the
+    parameters (10.51 GB), every layer's state (1.62 GB) and pools
+    (1.21 GB); all six layers read the pool through the Pallas kernel and
+    write it through the other, and no pool is copied."""
+    compiled, pool, shapes = _decode_program(v5e, monkeypatch,
+                                             _falcon_serving())
+    assert pool == (768, 128, 512)
+    assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 6
+    assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 6
+    assert _pool_copies(compiled, pool) == []
+    # the walk's int32 lists are a step's, not a layer's; a layer's own
+    # are the pad of its 20 query rows and the cut of its answer
+    walk = _walk_operations(compiled)
+    assert len([op for op in walk if "= s32[" in op]) < 10, walk
+    assert len(walk) < 10 + 2 * 6, walk
+    stored = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    assert 10.50e9 < stored < 10.52e9               # 5.255 B, bfloat16
+    memory = compiled.memory_analysis()
+    print(f"falcon-h1-34b-6l decode program: {memory}")
+    assert 13.33e9 < memory.argument_size_in_bytes < 13.36e9
+    assert memory.temp_size_in_bytes < 1.0e9, memory
+    assert _program_bytes(memory) < 15.75 * 2**30, memory
+
+
+def test_falcon_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
+    """Its (8, 512) prefill, the most one dispatch carries (`prefill_rows`
+    8): parameters, temporaries (the 9248-wide in-projection, the chunked
+    scan's float32 blocks at a state of 128 x 256 a head, the 21504-wide
+    MLP) and outputs (six layers' K/V rows and states) fit beside the
+    2.83 GB of state and pool that the program does not see."""
+    serving = _falcon_serving()
+    assert serving["prefill_rows"] == 8
+    compiled = _prefill_program(v5e, monkeypatch, serving, 8)
+    memory = compiled.memory_analysis()
+    print(f"falcon-h1-34b-6l (8, 512) prefill program: {memory}")
+    assert 10.50e9 < memory.argument_size_in_bytes < 10.52e9
+    assert _program_bytes(memory) + 2.83e9 < 15.75 * 2**30, memory
+
+
 def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):
     """Why the wrappers exist: the pool sharded on heads, as the engine
     shards it under tp, and the kernel called bare."""

@@ -78,6 +78,39 @@ async def test_generative_model_v1_predict(tmp_path):
         await model.close()
 
 
+async def test_ignore_eos_ends_an_answer_at_its_budget_alone(tmp_path):
+    """The same model and prompt, its first greedy token made the EOS id:
+    the answer is empty and ends `eos`; with `ignore_eos` in the model's
+    config the token is content like any other and the budget ends it."""
+    from kfserving_tpu.predictors import llm
+
+    model = GenerativeModel("gen", _write_model_dir(tmp_path))
+    model.load()
+    try:
+        first = (await model.predict({"instances": [
+            {"prompt": "hello", "max_tokens": 6, "logprobs": 1}]})
+            )["predictions"][0]["logprobs"][0]["id"]
+    finally:
+        await model.close()
+    finishes = {}
+    for ignore in (False, True):
+        model = GenerativeModel("gen", _write_model_dir(
+            tmp_path, ignore_eos=ignore))
+        model.load()
+        assert model.config.ignore_eos is ignore
+        assert model.engine.eos_id == (None if ignore else llm.EOS_ID)
+        if not ignore:
+            model.engine.eos_id = first
+        try:
+            out = (await model.predict({"instances": [
+                {"prompt": "hello", "max_tokens": 6}]}))["predictions"][0]
+        finally:
+            await model.close()
+        finishes[ignore] = (out["finish_reason"], out["token_count"])
+    assert finishes == {False: ("eos", 0), True: ("length", 6)}
+    assert llm.GenerativeConfig("decoder_tiny").ignore_eos is False
+
+
 async def test_generative_model_validation(tmp_path):
     from kfserving_tpu.protocol.errors import InvalidInput
 

@@ -129,7 +129,9 @@ def test_conv_steps_continue_from_each_rows_own_length(lengths):
 @pytest.mark.parametrize("rows, buckets, served", [
     (8, [1024], True), (1, [1024], True), (4, (1024,), True),
     (None, [1024], False), (0, [1024], False), (9, [1024], False),
-    (8, [512], False), (8, [1024, 2048], False),
+    (8, [512], True), (8, [1024, 2048], False),
+    (3, [512], True), (8, [512, 1024], True), (16, [512], False),
+    (8, [256], False),
 ])
 def test_the_prefill_shapes_that_have_run_on_the_chip(rows, buckets, served):
     reason = ssm.unproven_on_chip(rows, buckets)
