@@ -612,6 +612,8 @@ class GenerationEngine:
         # is a server without the counters, not one without a prefill.
         obs.engine_prefill_rows_total().labels(model=self.name)
         obs.engine_prefill_rows_padded_total().labels(model=self.name)
+        obs.engine_sampler_tail_calls_total().labels(
+            model=self.name, program="decode", noise="0", logprobs="0")
         self.requests_finished = 0
         self.preemptions = 0        # growth-pressure requeues
         self.prefill_chunks = 0     # chunked-prefill dispatches
@@ -2357,17 +2359,20 @@ class GenerationEngine:
                 with self._block_lock:
                     row = self._pool.table[slot:slot + 1, :nb].copy()
                 n_d = jnp.asarray(np.asarray([n], np.int32))
+                temps = np.asarray([req.temperature], np.float32)
+                want_lp = req.logprobs > 0
                 args = [jnp.asarray(row), jnp.asarray(ids),
                         jnp.asarray(qpos),
                         jnp.asarray(np.asarray([max(width - 1, 0)],
                                                np.int32)),
-                        jnp.asarray(np.asarray([req.temperature],
-                                               np.float32)),
+                        jnp.asarray(temps),
                         jnp.asarray(np.asarray([req.top_k], np.int32)),
                         jnp.asarray(np.asarray([req.top_p],
                                                np.float32)),
                         jnp.asarray(np.asarray([req.seed], np.int32)),
-                        n_d]
+                        n_d,
+                        jnp.asarray(self._tail_asked("chunk", temps,
+                                                     want_lp))]
             with self._inflight.launch(
                     "chunk", trace_id=req.trace_id, slot=slot, rows=1,
                     bucket=nb * self.block_size) as launched:
@@ -2380,8 +2385,7 @@ class GenerationEngine:
                     self._feed_update(
                         self._feed_tokens, self._feed_positions,
                         slot_d, first, n_d)
-        lp_h = ((chosen_lp, top_ids, top_lps)
-                if req.logprobs > 0 else None)
+        lp_h = (chosen_lp, top_ids, top_lps) if want_lp else None
         return first, lp_h, launched.seq
 
     async def _run_inner(self):
@@ -3035,7 +3039,9 @@ class GenerationEngine:
                 table = self._table_device()
                 per_slot = [jnp.asarray(a)
                             for a in (self._stop_positions(), temps,
-                                      top_ks, top_ps, seeds)]
+                                      top_ks, top_ps, seeds,
+                                      self._tail_asked("decode", temps,
+                                                       want_lp))]
             with self._inflight.launch(
                     "decode", rows=self.max_slots,
                     steps=self.steps_per_call) as launched:
@@ -3168,7 +3174,9 @@ class GenerationEngine:
             self._note_program("prefill", b_bucket, bucket)
             ids_d, lengths_d = jnp.asarray(ids), jnp.asarray(lengths)
             sampling = [jnp.asarray(a)
-                        for a in (temps, top_ks, top_ps, seeds)]
+                        for a in (temps, top_ks, top_ps, seeds,
+                                  self._tail_asked("prefill", temps,
+                                                   want_lp))]
         # The launch's ring event carries the trace ids of its rows,
         # so a request's spans share its identifier (the profiler's
         # annotation takes the scalars alone).
@@ -3247,6 +3255,20 @@ class GenerationEngine:
             seeds[i] = s.req.seed
             want_lp = want_lp or s.req.logprobs > 0
         return temps, top_ks, top_ps, seeds, want_lp
+
+    def _tail_asked(self, program: str, temps: np.ndarray,
+                    want_lp: bool) -> np.bool_:
+        """`want_lp` as a dispatch's program takes it, the dispatch
+        counted by what its rows ask of the sampler's tail
+        (engine/programs.py `sample`, `logprob_of`): noise where a row
+        has a temperature, which the program reads off `temps` itself,
+        and log-probabilities where a row asked, which it is told here
+        by the predicate its caller hands `lp_h` on by."""
+        obs.engine_sampler_tail_calls_total().labels(
+            model=self.name, program=program,
+            noise=str(int(bool((temps > 0.0).any()))),
+            logprobs=str(int(want_lp))).inc()
+        return np.bool_(want_lp)
 
     def _stop_positions(self) -> np.ndarray:
         """[max_slots] int32: the feed position at which a decodable
@@ -3573,7 +3595,9 @@ class GenerationEngine:
                 table = self._table_device()
                 last_d, qpos_d = jnp.asarray(last), jnp.asarray(qpos)
                 sampling = [jnp.asarray(a)
-                            for a in (temps, top_ks, top_ps, seeds)]
+                            for a in (temps, top_ks, top_ps, seeds,
+                                      self._tail_asked("spec", temps,
+                                                       want_lp))]
             with self._inflight.launch(
                     "spec", rows=len(eligible), steps=K + 1) as launched:
                 (samples, draft_echo, self._caches, chosen_lp, top_ids,

@@ -10,13 +10,16 @@ declaration and the engine's sizes; `GenerationEngine`
   trace reduction and compile-log reader find `decode_fn`, `prefill_fn`
   and `insert_fn` by name (chipbench/trace.py,
   chipbench/kinds/generate.py): the inner functions keep their names.
-- `sample`, `mask_to_support`, `logprob_of`: greedy, temperature
-  (Gumbel trick), top-k and top-p (nucleus) per slot, on the device, so
-  only the [S] int32 token vector crosses the host boundary per step —
-  never the [S, V] logits (1.6 MB/step for a GPT-2 vocab).  Noise is
-  keyed per request from (seed, absolute position): a seeded request
-  reproduces exactly no matter how it was scheduled.  Top-N logprobs are
-  computed every step and fetched only when a request asks.
+- `sample`, `mask_to_support`, `logprob_of`, `top_n`: greedy,
+  temperature (Gumbel trick), top-k and top-p (nucleus) per slot, on the
+  device, so only the [S] int32 token vector crosses the host boundary
+  per step — never the [S, V] logits (1.6 MB/step for a GPT-2 vocab).
+  Noise is keyed per request from (seed, absolute position): a seeded
+  request reproduces exactly no matter how it was scheduled.  The tail
+  reads the logits as often as the dispatch's rows asked for: one argmax
+  for rows that are all greedy, the noise only where a row samples, top-N
+  logprobs only where a request asked (`want_lp`, the predicate by which
+  the host fetches them).
 
 Nothing here knows of requests, the tables' host side
 (engine/block_pool.py), the metrics registry or the timeline.
@@ -329,37 +332,87 @@ def sample(base_key, logits, temps, top_ks, top_ps, seeds, noise_pos):
     """logits [B, V] float32.  Noise is keyed per ROW from
     (request seed, absolute position), never from wave or slot
     identity — a request's sampled tokens reproduce exactly
-    for a given seed no matter how it was scheduled."""
-    greedy = jnp.argmax(logits, axis=-1)
-    need_mask = jnp.any((top_ks > 0) | (top_ps < 1.0))
-    masked = jax.lax.cond(
-        need_mask,
-        lambda l: mask_to_support(l, top_ks, top_ps),
-        lambda l: l, logits)
+    for a given seed no matter how it was scheduled.
 
-    def row_key(seed, pos):
-        return jax.random.fold_in(
-            jax.random.fold_in(base_key, seed), pos)
+    Rows that are all greedy (`temps` 0) cost one argmax: the keys, the
+    support mask, the [B, V] Gumbel draw and the second argmax run under
+    one `cond`, taken whole where a row samples (and then every row's
+    token is what it has always been)."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    keys = jax.vmap(row_key)(seeds, noise_pos)
-    gumbel = jax.vmap(
-        lambda k: jax.random.gumbel(k, (logits.shape[-1],))
-    )(keys)
-    scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jnp.argmax(scaled + gumbel, axis=-1)
-    return jnp.where(temps <= 0.0, greedy,
-                     sampled).astype(jnp.int32)
+    def drawn():
+        def row_key(seed, pos):
+            return jax.random.fold_in(
+                jax.random.fold_in(base_key, seed), pos)
+
+        keys = jax.vmap(row_key)(seeds, noise_pos)
+
+        def draw(support):
+            gumbel = jax.vmap(
+                lambda k: jax.random.gumbel(k, (logits.shape[-1],))
+            )(keys)
+            scaled = support / jnp.maximum(temps, 1e-6)[:, None]
+            return jnp.argmax(scaled + gumbel, axis=-1).astype(jnp.int32)
+
+        # Either way the branch gives [B] tokens: a `cond` that handed
+        # back the logits, masked or not, would write all [B, V] of them.
+        sampled = jax.lax.cond(
+            jnp.any((top_ks > 0) | (top_ps < 1.0)),
+            lambda: draw(mask_to_support(logits, top_ks, top_ps)),
+            lambda: draw(logits))
+        return jnp.where(temps <= 0.0, greedy, sampled)
+
+    return jax.lax.cond(jnp.any(temps > 0.0), drawn, lambda: greedy)
 
 
-def logprob_of(logits, chosen, top_n: int):
+def top_n(logits, n: int):
+    """The n largest of each row of logits [B, V], largest first, and
+    their columns; of equal values the lower column first, which is
+    `lax.top_k`'s order and `argmax`'s.  n passes of max and argmax over
+    the row less the columns already taken, each one reduction that reads
+    the logits where they are.  `lax.top_k` itself on an operand that
+    another reduction reads too compiles, for the v5e, to a sort of the
+    whole [B, V] array: 21.6 ms at [64, 261120] (PERF.md §6, PR 52)."""
+    columns = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    taken = jnp.zeros(logits.shape, bool)
+    values, ids = [], []
+    for _ in range(n):
+        rest = jnp.where(taken, -jnp.inf, logits)
+        at = jnp.argmax(rest, axis=-1).astype(jnp.int32)
+        values.append(jnp.max(rest, axis=-1))
+        ids.append(at)
+        taken |= columns == at[:, None]
+    return jnp.stack(values, axis=1), jnp.stack(ids, axis=1)
+
+
+def logprob_of(logits, chosen, n: int, want):
     """Chosen-token logprob + top-N (ids, logprobs) over the
     UNMASKED distribution — diagnostics follow the model, not
-    the sampler's support restriction."""
-    lps = jax.nn.log_softmax(logits, axis=-1)
-    chosen_lp = jnp.take_along_axis(
-        lps, chosen[:, None].astype(jnp.int32), axis=-1)[:, 0]
-    top_lps, top_ids = jax.lax.top_k(lps, top_n)
-    return chosen_lp, top_ids.astype(jnp.int32), top_lps
+    the sampler's support restriction.  Zeros unless `want` (a
+    scalar: some row of the dispatch asked for them; the host fetches
+    by the same predicate).
+
+    `jax.nn.log_softmax`'s arithmetic, (x - max) - log(sum(exp(x -
+    max))), on the top-N logits and the chosen one alone: the [B, V]
+    array of log-probabilities is never formed."""
+    rows = logits.shape[0]
+
+    def asked():
+        top_vals, top_ids = top_n(logits, n)
+        peak = top_vals[:, :1]
+        log_total = jnp.log(jnp.sum(jnp.exp(logits - peak), axis=-1,
+                                    keepdims=True))
+        picked = jnp.take_along_axis(
+            logits, chosen[:, None].astype(jnp.int32), axis=-1)
+        return (((picked - peak) - log_total)[:, 0], top_ids,
+                (top_vals - peak) - log_total)
+
+    def unasked():
+        return (jnp.zeros((rows,), logits.dtype),
+                jnp.zeros((rows, n), jnp.int32),
+                jnp.zeros((rows, n), logits.dtype))
+
+    return jax.lax.cond(want, asked, unasked)
 
 
 def decode_call_stats(chose: Dict[str, Any]) -> Dict[str, Any]:
@@ -432,7 +485,7 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
     k_steps = steps_per_call
 
     def decode_fn(variables, caches, table, tokens, positions,
-                  stops, temps, top_ks, top_ps, seeds):
+                  stops, temps, top_ks, top_ps, seeds, want_lp):
         """K decode steps in ONE device dispatch (lax.scan): on a
         high-RTT link each host round trip costs ~an RTT, so
         single-token stepping caps tokens/s at 1/RTT per wave;
@@ -451,7 +504,11 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         blocks and `paged_write` drops its row, and an expert model
         routes it to no expert.  Its tokens and positions go on in
         the carry as a freed slot's always have; the head and the
-        sampler stay dense over the slots."""
+        sampler's argmax stay dense over the slots.  What else the
+        sampler does depends on the wave: `sample` draws noise where a
+        row has a temperature, and `logprob_of` works where `want_lp`
+        (a scalar: a row of the wave asked for log-probabilities, which
+        is when the host fetches them) and gives zeros otherwise."""
         def step(carry, _):
             caches, tokens, positions = carry
             live = positions < stops
@@ -468,7 +525,7 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
             # L, L+1, ... per request.
             nxt = sample(base_key, lg, temps, top_ks, top_ps, seeds,
                          positions + 1)
-            lp = logprob_of(lg, nxt, lp_n)
+            lp = logprob_of(lg, nxt, lp_n, want_lp)
             return (new_caches, nxt, positions + 1), (nxt, lp, pairs)
 
         (caches, next_tokens, next_positions), (toks, lps, pairs) = \
@@ -492,7 +549,7 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
                                            mode="drop"))
 
     def prefill_fn(variables, ids, lengths, temps, top_ks, top_ps,
-                   seeds):
+                   seeds, want_lp):
         # logit_positions: the LM head runs only on each row's
         # last real token — sampling never needs the [B, L, V]
         # logits cube, and at a 4096 bucket the full-cube head
@@ -516,13 +573,13 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         first_tokens = sample(base_key, last, temps, top_ks, top_ps,
                               seeds, lengths)
         chosen_lp, top_ids, top_lps = logprob_of(last, first_tokens,
-                                                 lp_n)
+                                                 lp_n, want_lp)
         out = (first_tokens, caches, chosen_lp, top_ids, top_lps)
         return out + (pairs,) if routed else out
 
     def chunk_prefill_fn(variables, caches, table, ids, qpos,
                          last_idx, temps, top_ks, top_ps,
-                         seeds, noise_pos):
+                         seeds, noise_pos, want_lp):
         """One chunk of a cold prompt: ids [1, C] write their
         k/v through the slot's block table at absolute
         positions qpos [1, C] (padding rows of a partial final
@@ -541,14 +598,15 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         lg = logits[:, 0]
         first = sample(base_key, lg, temps, top_ks, top_ps, seeds,
                        noise_pos)
-        chosen_lp, top_ids, top_lps = logprob_of(lg, first, lp_n)
+        chosen_lp, top_ids, top_lps = logprob_of(lg, first, lp_n,
+                                                 want_lp)
         return first, new_caches, chosen_lp, top_ids, top_lps
 
     spec_kp1 = spec_tokens + 1
 
     def spec_verify_fn(variables, caches, table, last_tokens,
                        draft_toks, positions, temps, top_ks,
-                       top_ps, seeds):
+                       top_ps, seeds, want_lp):
         """Verify K draft tokens per slot in ONE Lq=K+1
         dispatch.  Row i feeds [last_token, draft_0..K-1] at
         absolute positions [L, L+K] (parked rows ride the
@@ -590,7 +648,8 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         samples = sample(base_key, flat, rep(temps), rep(top_ks),
                          rep(top_ps), rep(seeds),
                          (positions + 1).reshape(-1))
-        chosen_lp, top_ids, top_lps = logprob_of(flat, samples, lp_n)
+        chosen_lp, top_ids, top_lps = logprob_of(flat, samples, lp_n,
+                                                 want_lp)
         # draft_toks are echoed through so the host reads
         # proposals + verdicts in the same fetch: the draft
         # arm costs ONE host round trip per spec wave, same

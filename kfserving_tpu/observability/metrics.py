@@ -411,6 +411,18 @@ def engine_prefill_rows_padded_total():
         "for nobody")
 
 
+def engine_sampler_tail_calls_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_engine_sampler_tail_calls_total",
+        "Dispatches of the programs that end in the sampler "
+        "(program=decode|prefill|chunk|spec), by what their rows asked "
+        "of its tail over the [rows, vocabulary] logits: noise=1 where "
+        "a row had a temperature (the support mask, the Gumbel draw and "
+        "a second argmax beside the greedy one), logprobs=1 where a row "
+        "asked for log-probabilities (a top-N and two reductions); "
+        "0 and 0 is one argmax")
+
+
 def generator_decode_kv_blocks_walked_total():
     return REGISTRY.counter(
         "kfserving_tpu_generator_decode_kv_blocks_walked_total",

@@ -131,6 +131,10 @@ Response:
     {"predictions": [{"text": ..., "token_count": n,
                       "finish_reason": "eos"|"length"|"stop",
                       "logprobs": [...]}]}       # logprobs on request
+    "logprobs": N asks for each token's log-probability and the N likeliest
+    beside it (N <= logprob_topk); while such a request holds a slot, every
+    decode wave it rides makes them for all its rows (logprob_topk + 2 more
+    passes over the [slots, vocabulary] logits), a wave with no such row none.
 
 Sampling runs on device (top-k/top-p mask-then-sample; seeded noise
 keyed on (seed, position) so runs reproduce); stop sequences match

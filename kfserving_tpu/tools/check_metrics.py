@@ -194,6 +194,10 @@ async def smoke() -> List[str]:
         model="metrics-probe").inc(24)
     obs.engine_prefill_rows_padded_total().labels(
         model="metrics-probe").inc(3)
+    for noise, logprobs in (("0", "0"), ("1", "0"), ("0", "1")):
+        obs.engine_sampler_tail_calls_total().labels(
+            model="metrics-probe", program="decode", noise=noise,
+            logprobs=logprobs).inc()
     obs.generator_decode_kv_blocks_walked_total().labels(
         model="metrics-probe").inc(640)
     obs.generator_decode_kv_context_tokens_total().labels(

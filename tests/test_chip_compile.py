@@ -445,7 +445,8 @@ def _decode_program(v5e, monkeypatch, serving: dict):
         compiled = engine._decode.lower(
             on_chip(engine.variables), on_chip(engine._caches),
             table, arg(i32, s), arg(i32, s), arg(i32, s),
-            arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s)).compile()
+            arg(f32, s), arg(i32, s), arg(f32, s), arg(i32, s),
+            arg(jnp.bool_)).compile()
         return compiled, engine._cache_shape, shapes
     finally:
         engine.shutdown_nowait()
@@ -484,7 +485,7 @@ def _prefill_program(v5e, monkeypatch, serving: dict, rows: int):
             engine.variables,
             arg(i32, rows, max(serving["prefill_buckets"])),
             arg(i32, rows), arg(f32, rows), arg(i32, rows), arg(f32, rows),
-            arg(i32, rows)).compile()
+            arg(i32, rows), arg(jnp.bool_)).compile()
     finally:
         engine.shutdown_nowait()
 
@@ -728,7 +729,8 @@ def gpt2_large_decode(v5e):
 
 def test_gpt2_large_decode_program_takes_each_rows_stop(gpt2_large_decode):
     """The decode program is told where each row's token budget ends by
-    one more `s32[24]` beside the feed and the sampling arrays, and parks
+    one more `s32[24]` beside the feed and the sampling arrays (and
+    whether a row asked for log-probabilities by one `pred[]`), and parks
     a row through the table its kernels already take: the same 72 Mosaic
     calls, no pool copied, the list of blocks to walk still made once a
     step, and 96 bytes of arguments more."""
@@ -741,14 +743,14 @@ def test_gpt2_large_decode_program_takes_each_rows_stop(gpt2_large_decode):
     assert per_slot == {
         "table": "s32[24,8]", "tokens": "s32[24]", "positions": "s32[24]",
         "stops": "s32[24]", "temps": "f32[24]", "top_ks": "s32[24]",
-        "top_ps": "f32[24]", "seeds": "s32[24]"}
+        "top_ps": "f32[24]", "seeds": "s32[24]", "want_lp": "pred[]"}
     assert len(_mosaic_calls(compiled, "paged_write_tpu")) == 36
     assert len(_mosaic_calls(compiled, "paged_attention_tpu")) == 36
     assert 0 < len(_walk_operations(compiled)) < 12
     assert _pool_copies(compiled, pool) == []
     stored = sum(
         int(np.prod([int(n) for n in dims.split(",") if n]))
-        * {"bf16": 2, "f32": 4, "s32": 4}[dtype]
+        * {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}[dtype]
         for dtype, dims in re.findall(
             r" = (\w+)\[([\d,]*)\]\S* parameter\(", entry))
     memory = compiled.memory_analysis()
@@ -865,6 +867,7 @@ def test_falcon_decode_program_fits_the_described_v5e(v5e, monkeypatch):
     assert 13.33e9 < memory.argument_size_in_bytes < 13.36e9
     assert memory.temp_size_in_bytes < 1.0e9, memory
     assert _program_bytes(memory) < 15.75 * 2**30, memory
+    _assert_the_tail_is_lean(compiled, 64, 261120, "lm_head/dot_general")
 
 
 def test_falcon_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
@@ -880,6 +883,116 @@ def test_falcon_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
     print(f"falcon-h1-34b-6l (8, 512) prefill program: {memory}")
     assert 10.50e9 < memory.argument_size_in_bytes < 10.52e9
     assert _program_bytes(memory) + 2.83e9 < 15.75 * 2**30, memory
+
+
+# -- the sampler's tail, as the chip's compiler leaves it -----------------------
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)"
+    r"|(?:branch|called)_computations=\{([^}]*)\}")
+
+
+def _computations(compiled) -> dict:
+    """{computation: its instruction lines} of a compiled program."""
+    computations, name = {}, None
+    for line in compiled.as_text().splitlines():
+        opened = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$", line)
+        if opened:
+            name = opened.group(1)
+            computations[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            computations[name].append(line.strip())
+    return computations
+
+
+def _callees(line: str) -> list:
+    return [name.strip().lstrip("%")
+            for one, many in _CALLED.findall(line)
+            for name in (one + "," + many).split(",") if name.strip()]
+
+
+def _under_branches(computations: dict) -> set:
+    """The computations that run only where a `conditional` takes the
+    branch that calls them."""
+    todo = [name for lines in computations.values() for line in lines
+            if " conditional(" in line for name in _callees(line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen and name in computations:
+            seen.add(name)
+            todo += [c for line in computations[name]
+                     for c in _callees(line)]
+    return seen
+
+
+def _made(computations: dict, names, shape: str) -> list:
+    """The instructions of `names`, fusions' bodies left out, that compute
+    or copy a result of `shape` in any layout (an element of a tuple
+    counts): what exists in memory at that size.  A `tuple`, a
+    `get-tuple-element`, a `parameter` or a `bitcast` makes nothing."""
+    bodies = {c for lines in computations.values() for line in lines
+              if " fusion(" in line for c in _callees(line)}
+    made = []
+    for name in names:
+        for line in () if name in bodies else computations[name]:
+            result = re.match(r"(\(.*?\)|\S+) ([\w\-]+)\(",
+                              line.split(" = ", 1)[1])
+            if result and shape in result.group(1) and result.group(2) not in (
+                    "tuple", "get-tuple-element", "parameter", "bitcast"):
+                made.append(line)
+    return made
+
+
+def _assert_the_tail_is_lean(compiled, rows: int, vocab: int, head: str):
+    """Outside the branches of a `conditional` the [rows, vocab] float32
+    logits exist once, as the head's result (and as the compiler's own
+    move of it to where the branches read it), and nothing there draws
+    noise; the branch that answers a request for log-probabilities forms
+    no second array of that size: no log-softmax written out, no
+    transposed twin, no sort."""
+    logits = f"f32[{rows},{vocab}]"
+    computations = _computations(compiled)
+    inside = _under_branches(computations)
+    outside = [name for name in computations if name not in inside]
+    made = _made(computations, outside, logits)
+    heads = [line for line in made if head in line]
+    moves = [line for line in made
+             if re.search(r"\) copy-start\(|\} copy-done\(", line)]
+    assert len(heads) == 1, made
+    assert sorted(made) == sorted(heads + moves), made
+    assert not [line for name in outside for line in computations[name]
+                if "threefry" in line or "gumbel" in line]
+    assert [line for name in inside for line in computations[name]
+            if "threefry" in line]               # where a row samples
+    scored = [name for name in inside
+              if any(f"s32[1,{rows},5]" in line and line.startswith("ROOT")
+                     for line in computations[name])
+              and any(logits in line for line in computations[name])]
+    assert len(scored) == 1, scored             # `logprob_of`'s `asked`
+    reach = [scored[0]] + [c for line in computations[scored[0]]
+                           for c in _callees(line)]
+    made = _made(computations, reach, logits)
+    assert all(re.search(r"\) copy-start\(|\} copy-done\(", line)
+               for line in made), made
+    assert not [line for name in reach for line in computations[name]
+                if f"[{rows},{vocab}]{{0,1" in line or " sort(" in line]
+
+
+def test_toy_decoder_with_a_real_vocabulary_keeps_its_tail_lean(v5e,
+                                                                monkeypatch):
+    """The decode program of a one-layer toy decoder with
+    `falcon-h1-34b-6l`'s 64 slots and 261120 columns, compiled for the
+    described v5e."""
+    rows, vocab = 64, 261120
+    compiled, _, _ = _decode_program(v5e, monkeypatch, {
+        "architecture": "decoder_tiny",
+        "arch_kwargs": {"vocab_size": vocab, "num_layers": 1,
+                        "max_seq": 256},
+        "max_slots": rows, "max_seq": 256, "prefill_buckets": [128],
+        "block_size": 128, "cache_blocks": None, "steps_per_call": 4})
+    _assert_the_tail_is_lean(compiled, rows, vocab, "wte.attend/dot_general")
 
 
 def test_bare_mosaic_kernel_is_refused_under_a_mesh(v5e):

@@ -612,7 +612,9 @@ def test_the_engines_model_counts_active_parameters(tiny):
     ids = jax.ShapeDtypeStruct((1, MAX_SEQ), jnp.int32)
     row = [jax.ShapeDtypeStruct((1,), t) for t in (
         jnp.int32, jnp.float32, jnp.int32, jnp.float32, jnp.int32)]
-    assert len(jax.eval_shape(engine._prefill, dense_vars, ids, *row)) == 5
+    asked = jax.ShapeDtypeStruct((), jnp.bool_)   # log-probabilities or not
+    assert len(jax.eval_shape(engine._prefill, dense_vars, ids, *row,
+                              asked)) == 5
     assert not [k for k in engine.stats() if k.startswith("moe_")]
     assert engine._flops_matmul_per_token == 2.0 * n
     assert engine._param_read_bytes == engine.param_bytes() == 4 * n

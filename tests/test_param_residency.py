@@ -210,7 +210,8 @@ def _prefill_args(rows):
     lengths = np.array([BS, 5, 11, 1][:rows], np.int32)
     return (jnp.asarray(ids), jnp.asarray(lengths),
             jnp.zeros(rows, jnp.float32), jnp.zeros(rows, jnp.int32),
-            jnp.ones(rows, jnp.float32), jnp.arange(rows, dtype=jnp.int32))
+            jnp.ones(rows, jnp.float32), jnp.arange(rows, dtype=jnp.int32),
+            jnp.asarray(True))  # log-probabilities asked for
 
 
 def _decode_call(eng):
@@ -226,7 +227,7 @@ def _decode_call(eng):
         jnp.full(SLOTS, eng.max_seq, jnp.int32),  # no budget ends here
         jnp.asarray([0.0, 0.0, 0.8, 0.0], jnp.float32),
         jnp.zeros(SLOTS, jnp.int32), jnp.ones(SLOTS, jnp.float32),
-        jnp.arange(SLOTS, dtype=jnp.int32))
+        jnp.arange(SLOTS, dtype=jnp.int32), jnp.asarray(True))
     toks, caches, _, _, chosen_lp, top_ids, top_lps = out
     assert toks.shape == (SLOTS, STEPS)
     return toks, chosen_lp, top_ids, top_lps, caches

@@ -65,7 +65,7 @@ def test_decode_fn_is_named_in_its_lowered_program_and_scans_its_steps():
     lowered = built.decode.lower(
         variables, layout.caches, arg(i32, 4, layout.blocks_per_slot),
         arg(i32, 4), arg(i32, 4), arg(i32, 4), arg(f32, 4), arg(i32, 4),
-        arg(f32, 4), arg(i32, 4))
+        arg(f32, 4), arg(i32, 4), arg(jnp.bool_))
     text = lowered.as_text()
     assert "jit_decode_fn" in text
     assert "stablehlo.while" in text  # the scan of steps_per_call steps
@@ -153,3 +153,274 @@ def test_the_layout_refuses_lengths_that_are_not_whole_blocks():
             config, "m", **{**SIZES, "prefill_buckets": [24, 32]})
     assert programs.derive_block_size(64, [16, 32]) == 16
     assert programs.derive_block_size(2048, [128, 1024]) == 128
+
+
+# -- the sampler's tail ----------------------------------------------------------
+# `sample` and `logprob_of` as they stood before the tail was gated on
+# what a dispatch's rows ask for: the oracle the gated pair is held to.
+def sample_ungated(base_key, logits, temps, top_ks, top_ps, seeds,
+                   noise_pos):
+    greedy = jnp.argmax(logits, axis=-1)
+    need_mask = jnp.any((top_ks > 0) | (top_ps < 1.0))
+    masked = jax.lax.cond(
+        need_mask,
+        lambda l: programs.mask_to_support(l, top_ks, top_ps),
+        lambda l: l, logits)
+
+    def row_key(seed, pos):
+        return jax.random.fold_in(
+            jax.random.fold_in(base_key, seed), pos)
+
+    keys = jax.vmap(row_key)(seeds, noise_pos)
+    gumbel = jax.vmap(
+        lambda k: jax.random.gumbel(k, (logits.shape[-1],))
+    )(keys)
+    scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
+    sampled = jnp.argmax(scaled + gumbel, axis=-1)
+    return jnp.where(temps <= 0.0, greedy,
+                     sampled).astype(jnp.int32)
+
+
+def logprob_of_ungated(logits, chosen, top_n: int, want=None):
+    lps = jax.nn.log_softmax(logits, axis=-1)
+    chosen_lp = jnp.take_along_axis(
+        lps, chosen[:, None].astype(jnp.int32), axis=-1)[:, 0]
+    top_lps, top_ids = jax.lax.top_k(lps, top_n)
+    return chosen_lp, top_ids.astype(jnp.int32), top_lps
+
+
+WAVES = {"greedy": [0.0] * 6,
+         "sampled": [0.5, 0.8, 1.0, 1.3, 0.8, 0.7],
+         "mixed": [0.0, 0.8, 0.0, 1.3, 0.7, 0.0]}
+SUPPORTS = {"whole": ([0] * 6, [1.0] * 6),
+            "top_k": ([0, 5, 40, 1, 0, 3], [1.0] * 6),
+            "top_p": ([0] * 6, [1.0, 0.9, 0.5, 0.95, 0.3, 1.0]),
+            "both": ([0, 5, 40, 0, 7, 3], [0.9, 1.0, 0.5, 0.95, 1.0, 0.8])}
+
+
+def _wave(wave: str, support: str = "whole", vocab: int = 384):
+    """(logits [6, vocab] with columns 5 and 7 of row 0 tied at the top,
+    temps, top_ks, top_ps, seeds, noise positions)."""
+    rng = np.random.default_rng(3)
+    logits = (3.0 * rng.normal(size=(6, vocab))).astype(np.float32)
+    logits[0, 7] = logits[0, 5] = 20.0
+    top_ks, top_ps = SUPPORTS[support]
+    return (jnp.asarray(logits), jnp.asarray(WAVES[wave], jnp.float32),
+            jnp.asarray(top_ks, jnp.int32), jnp.asarray(top_ps, jnp.float32),
+            jnp.arange(40, 46, dtype=jnp.int32),
+            jnp.arange(17, 23, dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("support", sorted(SUPPORTS))
+@pytest.mark.parametrize("wave", sorted(WAVES))
+def test_a_wave_draws_the_tokens_the_ungated_sampler_drew(wave, support):
+    """Greedy rows take the argmax and sampled rows the token of their
+    (seed, position), bit for bit, whether the wave around them is all
+    greedy (no noise is drawn), all sampled or mixed, and whatever
+    support its rows restrict themselves to."""
+    key = jax.random.PRNGKey(7)
+    args = _wave(wave, support)
+    got = jax.jit(programs.sample)(key, *args)
+    want = jax.jit(sample_ungated)(key, *args)
+    assert got.dtype == jnp.int32 and got.shape == (6,)
+    assert got.tolist() == want.tolist()
+    greedy = [i for i, t in enumerate(WAVES[wave]) if t == 0.0]
+    assert got[jnp.asarray(greedy, jnp.int32)].tolist() == \
+        jnp.argmax(args[0], axis=-1)[jnp.asarray(greedy, jnp.int32)].tolist()
+    if wave != "greedy" and support == "whole":
+        assert got.tolist() != jnp.argmax(args[0], axis=-1).tolist()
+
+
+def test_a_greedy_wave_draws_no_noise():
+    """In the lowered sampler everything that exists for a sampled row
+    (the keys' threefry rounds, the Gumbel draw, the mask's sort) sits
+    inside one branch, and the branch gives [rows] tokens, never
+    [rows, vocab] logits: outside it there is the greedy argmax."""
+    text = jax.jit(programs.sample).lower(
+        jax.random.PRNGKey(7), *_wave("greedy")).as_text()
+    main = text.split("func.func public @main")[1].split(
+        "func.func")[0].splitlines()
+    opens = [n for n, line in enumerate(main) if "stablehlo.case" in line]
+    closes = [n for n, line in enumerate(main)
+              if line.startswith("    }) : (tensor<i32>) -> ")]
+    assert len(closes) == 1 and opens[0] < closes[0]
+    assert main[closes[0]].endswith("-> tensor<6xi32>")
+    outside = "\n".join(main[:opens[0]] + main[closes[0] + 1:])
+    inside = "\n".join(main[opens[0]:closes[0]])
+    for call in ("@_gumbel", "@_threefry_fold_in", "@sort"):
+        assert call in inside and call not in outside, call
+    assert "call @argmax" in outside
+
+
+@pytest.mark.parametrize("wave", sorted(WAVES))
+def test_logprobs_are_the_ungated_ones_where_a_row_asked(wave):
+    """`want` set: the chosen token's and the top 5's log-probabilities to
+    float32 rounding and the top 5's ids exactly, tied maxima the lower
+    column first, though no [rows, vocab] array of log-probabilities is
+    formed."""
+    args = _wave(wave)
+    logits = args[0]
+    chosen = sample_ungated(jax.random.PRNGKey(7), *args)
+    got = jax.jit(programs.logprob_of, static_argnums=2)(
+        logits, chosen, 5, jnp.asarray(True))
+    want = jax.jit(logprob_of_ungated, static_argnums=2)(logits, chosen, 5)
+    assert [x.dtype for x in got] == [x.dtype for x in want]
+    assert got[1].tolist() == want[1].tolist()
+    assert got[1][0, :2].tolist() == [5, 7]     # the tie, in argmax's order
+    assert float(got[2][0, 0]) == float(got[2][0, 1])
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(got[2], want[2], rtol=2e-6, atol=2e-6)
+    assert int(chosen[0]) == 5 or WAVES[wave][0] > 0.0
+    # a chosen token among the top 5 reads the same number both ways
+    at = jnp.argmax(got[1] == chosen[:, None], axis=-1)
+    held = jnp.any(got[1] == chosen[:, None], axis=-1)
+    np.testing.assert_array_equal(
+        np.asarray(got[0])[np.asarray(held)],
+        np.asarray(jnp.take_along_axis(got[2], at[:, None], 1)[:, 0])[
+            np.asarray(held)])
+
+
+def test_logprobs_nobody_asked_for_are_zeros_of_the_same_shapes():
+    args = _wave("mixed")
+    chosen = sample_ungated(jax.random.PRNGKey(7), *args)
+    asked, unasked = (jax.jit(programs.logprob_of, static_argnums=2)(
+        args[0], chosen, 5, jnp.asarray(want)) for want in (True, False))
+    assert [(x.shape, x.dtype) for x in asked] == \
+        [(x.shape, x.dtype) for x in unasked]
+    assert all(not np.asarray(x).any() for x in unasked)
+    assert all(np.asarray(x).any() for x in asked)
+
+
+def _tail_inputs(program: str, layout, temps):
+    """The arguments after (variables[, caches]) of one of `build`'s four
+    programs that end in the sampler, over `SIZES`' four slots, rows 1 and
+    3 sampling where `temps` says so."""
+    rows = 1 if program == "chunk_prefill" else 4
+    i32, f32 = jnp.int32, jnp.float32
+    sampling = (jnp.asarray(temps[:rows], f32),
+                jnp.asarray([0, 5, 0, 0][:rows], i32),
+                jnp.asarray([1.0, 1.0, 1.0, 0.9][:rows], f32),
+                jnp.arange(70, 70 + rows, dtype=i32))
+    table = jnp.arange(4 * layout.blocks_per_slot, dtype=i32).reshape(
+        4, layout.blocks_per_slot)
+    ids = jnp.asarray(np.random.default_rng(5).integers(
+        1, 384, (rows, 16)), i32)
+    if program == "decode":
+        return (table, jnp.asarray([3, 17, 42, 5], i32),
+                jnp.asarray([0, 9, 30, 1], i32), jnp.full((4,), 64, i32),
+                *sampling)
+    if program == "prefill":
+        return (ids, jnp.asarray([16, 5, 11, 1], i32), *sampling)
+    if program == "chunk_prefill":
+        return (table[:1, :1], ids, jnp.arange(16, dtype=i32)[None],
+                jnp.asarray([15], i32), *sampling, jnp.asarray([16], i32))
+    positions = jnp.asarray([4, 9, 30, 1], i32)[:, None] + jnp.arange(3)
+    return (table, jnp.asarray([3, 17, 42, 5], i32), ids[:, :2], positions,
+            *sampling)
+
+
+@pytest.mark.parametrize("temps", [[0.0] * 4, [0.0, 0.8, 0.0, 1.2]],
+                         ids=["greedy", "mixed"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk_prefill",
+                                     "spec_verify"])
+def test_every_program_samples_and_scores_as_with_the_ungated_tail(
+        monkeypatch, program, temps):
+    """Decode, prefill, chunked prefill and speculative verify, built
+    over the gated tail and over the ungated one: the same tokens bit
+    for bit, the same log-probabilities where the dispatch asked, zeros
+    where it did not."""
+    module = create_model("decoder_tiny").module
+    variables = module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+
+    def run(want):
+        layout = programs.CacheLayout(module.config, "m", **SIZES)
+        built = programs.build(module, layout.kinds, 3, 5,
+                               jax.random.PRNGKey(9), spec_tokens=2)
+        caches = () if program == "prefill" else (layout.caches,)
+        out = getattr(built, program)(
+            variables, *caches, *_tail_inputs(program, layout, temps),
+            jnp.asarray(want))
+        tokens = out[0]
+        lps = out[4:7] if program == "decode" else (
+            out[3:6] if program == "spec_verify" else out[2:5])
+        return np.asarray(tokens), [np.asarray(x) for x in lps]
+
+    tokens, lps = run(True)
+    lean_tokens, zeros = run(False)
+    monkeypatch.setattr(programs, "sample", sample_ungated)
+    monkeypatch.setattr(programs, "logprob_of", logprob_of_ungated)
+    old_tokens, old_lps = run(True)
+    np.testing.assert_array_equal(tokens, old_tokens)
+    np.testing.assert_array_equal(lean_tokens, old_tokens)
+    np.testing.assert_array_equal(lps[1], old_lps[1])
+    for new, old in zip(lps[::2], old_lps[::2]):
+        np.testing.assert_allclose(new, old, rtol=2e-6, atol=2e-6)
+    assert all(not x.any() for x in zeros)
+    assert [x.shape for x in zeros] == [x.shape for x in old_lps]
+
+
+async def test_a_request_that_asks_logprobs_between_greedy_waves_gets_them():
+    """Two greedy requests decode, pipelined two calls deep; a third that
+    asks for its top 3 is admitted between their waves.  Until then no
+    wave's log-probabilities are fetched (`lp_h` None, and the program
+    made none); from its first token on the third gets what the ungated
+    tail gives over a full forward pass, and the dispatches are counted
+    by what they asked."""
+    from kfserving_tpu.engine.generator import GenerationEngine
+    from kfserving_tpu.observability import metrics as obs
+
+    module = create_model("decoder_tiny").module
+    variables = module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+    eng = GenerationEngine(module, variables, max_slots=4, max_seq=64,
+                           prefill_buckets=[16, 32], steps_per_call=2,
+                           pipeline_depth=2, name="tail")
+    fetched = []                 # (program's tokens shape, lp fetched?)
+    fetch_wave = eng._fetch_wave
+
+    def spy(toks_h, lp_h):
+        fetched.append((tuple(toks_h.shape), lp_h is not None))
+        return fetch_wave(toks_h, lp_h)
+
+    eng._fetch_wave = spy
+    calls = obs.engine_sampler_tail_calls_total()
+
+    def counted(program, logprobs):
+        return calls.labels(model="tail", program=program, noise="0",
+                            logprobs=logprobs).value
+
+    try:
+        neighbours = [eng.submit(p, max_new_tokens=40)
+                      for p in ([7, 7, 3], [2, 8, 11, 4])]
+        streams = [eng.stream(r) for r in neighbours]
+        for _ in range(6):       # both are decoding, waves in flight
+            for s in streams:
+                await anext(s)
+        assert fetched and not any(lp for _, lp in fetched)
+        assert counted("decode", "1") == counted("prefill", "1") == 0
+        lean_before = counted("decode", "0")
+        assert lean_before > 0
+        prompt = [5, 9, 2, 7, 11]
+        asking = eng.submit(prompt, max_new_tokens=6, logprobs=3)
+        tokens = [t async for t, _ in eng.stream(asking) if t is not None]
+        for r in neighbours:
+            eng.cancel(r)
+    finally:
+        await eng.close()
+    assert len(tokens) == len(asking.lp_chosen) == len(asking.lp_top) == 6
+    assert counted("prefill", "1") == 1 and counted("decode", "1") >= 3
+    assert any(lp for shape, lp in fetched if shape == (4, 2))
+    ids = list(prompt)
+    for step, tok in enumerate(tokens):
+        logits = module.apply(variables, jnp.asarray([ids], jnp.int32))
+        chosen_lp, top_ids, top_lps = logprob_of_ungated(
+            logits[:, -1], jnp.asarray([tok], jnp.int32), 3)
+        assert tok == int(jnp.argmax(logits[0, -1]))
+        np.testing.assert_allclose(asking.lp_chosen[step],
+                                   float(chosen_lp[0]), rtol=2e-3, atol=2e-3)
+        assert [t for t, _ in asking.lp_top[step]] == top_ids[0].tolist()
+        np.testing.assert_allclose([v for _, v in asking.lp_top[step]],
+                                   np.asarray(top_lps[0]),
+                                   rtol=2e-3, atol=2e-3)
+        ids.append(tok)
