@@ -24,7 +24,11 @@ is the TPU-first design for that:
   bucketed forward (suffix-padded, flash-eligible at long L, one
   compile per bucket) that returns the prompt's k/v for every layer;
   a jitted scatter inserts them into the slot's blocks.  Decode then costs
-  O(1) tokens per step.
+  O(1) tokens per step.  The arrivals that wait at a bucket ride one
+  program of power-of-two rows; where every cached layer is whole-
+  context K/V a row carries as many prompts as its blocks hold, each
+  from a block boundary and masked to itself (`lay_rows`,
+  `programs.packs_prompts`).
 - **continuous batching, fully asynchronous**: admission enqueues
   prefill + insert + feed-scatter and installs the slot WITHOUT a
   host sync — prompt ingestion rides the same in-flight pipeline as
@@ -59,7 +63,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
+from typing import Any, AsyncIterator, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -147,6 +151,9 @@ class _Request:
     # again; read once, at the first emission (ttft_stage_ms).
     taken_t: float = 0.0
     enqueued_t: float = 0.0
+    # Where the prompt lies in the prefill program it was taken for
+    # (`lay_rows`): its entry of the program's per-prompt arrays.
+    prefill_entry: int = 0
     # -- cost attribution (observability/attribution.py): accumulated
     # by the scheduler across the request's whole life (preemptions
     # included), finalized into ONE record at the terminal event.
@@ -211,6 +218,27 @@ class _Active:
     # not been enqueued yet.
     chunk_regs: Dict[int, Tuple[bytes, int]] = field(
         default_factory=dict)
+
+
+def lay_rows(sizes: Sequence[int], per_row: int) -> List[int]:
+    """Prompts that take `sizes` consecutive entries each, laid into rows
+    of `per_row` entries: each one's first entry, counted over the rows
+    (row * per_row + its first entry in the row).  First fit, the largest
+    first (of equal ones the earlier): a prompt goes behind what its row
+    already holds, so no entry is owned twice and none is left between
+    two prompts of a row.  Where a row's entries are its blocks, a prompt
+    starts at a block boundary; `per_row` 1 is one prompt a row, in
+    order."""
+    filled: List[int] = []  # a row's entries taken, from its first
+    at = [0] * len(sizes)
+    for i in sorted(range(len(sizes)), key=lambda i: -sizes[i]):
+        row = next((r for r, n in enumerate(filled)
+                    if n + sizes[i] <= per_row), len(filled))
+        if row == len(filled):
+            filled.append(0)
+        at[i] = row * per_row + filled[row]
+        filled[row] += sizes[i]
+    return at
 
 
 class GenerationEngine:
@@ -331,6 +359,13 @@ class GenerationEngine:
         self.block_size = layout.block_size
         self.blocks_per_slot = layout.blocks_per_slot
         self.num_blocks = layout.num_blocks
+        # bucket -> the prompts' entries a row of its prefill program
+        # has: its blocks where a row carries as many prompts as they
+        # hold (`programs.packs_prompts`), one where it carries one.
+        self._row_entries = {
+            b: (b // self.block_size
+                if programs.packs_prompts(layout.kinds, b) else 1)
+            for b in buckets}
         # Sliding-window layers keep a ring of blocks a sequence in a
         # pool of their own kind; None and 0 for a model without any.
         self._window = layout.window
@@ -927,6 +962,12 @@ class GenerationEngine:
             "prefill_requests": self.prefill_requests,
             "prefill_rows_dispatched": self.prefill_rows_dispatched,
             "prefill_rows_padded": self.prefill_rows_padded,
+            # Prompts a row that any prompt lay in: above 1 where the
+            # programs pack (`programs.packs_prompts`).
+            "prefill_prompts_per_row": round(
+                self.prefill_requests
+                / max(1, self.prefill_rows_dispatched
+                      - self.prefill_rows_padded), 4),
             # What each prefill program's last dispatches took (median),
             # which is what decides whether a group splits.
             "prefill_program_ms": {
@@ -2043,56 +2084,93 @@ class GenerationEngine:
 
     def _take_prefill_group(self, force_miss: bool = False):
         """Pop the front run of pending requests that share a prefill
-        bucket, up to the free slot count (or up to the row count the
-        deployment set or the runtime has shown it can hold, see
-        _prefill_refused) — they ride ONE prefill dispatch.  Where the
-        run is no power of two and its pieces' programs have taken less
-        than the padded one (`_prefill_rows_to_take`), the take stops
-        at the largest power of two in it: the loop's next take has
-        the rest, so 7 go as 4 + 2 + 1 and no program carries a dummy
-        row.  Strict FIFO: a different-bucket request at the front is
-        never jumped.  Each taken request's prompt blocks are planned
-        (allocated/prefix-shared) HERE on the loop thread; a request
-        the pool cannot hold yet stays pending (it admits when slots
-        release blocks).  Returns (group, slots, bucket, dest_rows)."""
+        bucket, up to the free slot count — they ride ONE prefill
+        dispatch, each with a slot and a block plan of its own.  The
+        run is laid into the program's rows (`lay_rows`): a prompt
+        takes a row, or, where the program packs
+        (`programs.packs_prompts`), the blocks it fills, the next
+        prompt starting at the row's next block, so a row carries as
+        many prompts as its blocks hold.  How many rows go is asked of
+        the rows (`_prompts_to_take`: the deployment's or the runtime's
+        cap, see _prefill_refused, and the programs' own timings), and
+        the take has the longest front of the run that lies in them:
+        the loop's next take has the rest, whole prompts all.  Strict
+        FIFO between takes: a different-bucket request at the front is
+        never jumped; inside a take the order is the layout's, since
+        it is one dispatch either way.  Each taken request's prompt
+        blocks are planned (allocated/prefix-shared) HERE on the loop
+        thread; a request the pool cannot hold yet stays pending (it
+        admits when slots release blocks).  Returns (group, slots,
+        bucket, dest_rows); a request's place is its `prefill_entry`."""
         free = [i for i, s in enumerate(self._slots) if s is None]
-        if self._prefill_rows_cap is not None:
-            free = free[:self._prefill_rows_cap]
-        run = bucket = 0
+        sizes: List[int] = []  # the entries of a row each prompt takes
+        bucket = 0
         for req in itertools.islice(self._pending, len(free)):
             if self._is_cold(req):
                 break  # cold prompts take the chunked path
             b = self._bucket_for(req.prompt_ids.size)
-            if run and b != bucket:
+            if sizes and b != bucket:
                 break
             bucket = b
-            run += 1
+            sizes.append(-(-int(req.prompt_ids.size) // self.block_size)
+                         if self._row_entries[b] > 1 else 1)
         group: List[_Request] = []
         dest_rows: List[List[int]] = []
-        for slot in free[:self._prefill_rows_to_take(run, bucket)]:
+        for slot in free[:self._prompts_to_take(sizes, bucket)]:
             plan = self._plan_prompt_blocks(self._pending[0], slot,
                                             force_miss=force_miss)
             if plan is None:
                 break  # pool pressure: wait for released blocks
             dest_rows.append(plan)
             group.append(self._pending.popleft())
-        keep = self._prefill_rows_to_take(len(group), bucket)
+        del sizes[len(group):]
+        keep = self._prompts_to_take(sizes, bucket)
         if keep < len(group):
-            # The pool stopped the take short of a power of two: the
-            # rows past the largest one in it go back, plans undone.
+            # The pool stopped the take short of a power of two of
+            # rows: the prompts past the largest one in it go back,
+            # plans undone.
             self._requeue_group(group[keep:], free[keep:len(group)])
-            del group[keep:], dest_rows[keep:]
+            del group[keep:], dest_rows[keep:], sizes[keep:]
         now = time.perf_counter()
-        for req in group:
+        for req, at in zip(group, lay_rows(
+                sizes, self._row_entries.get(bucket, 1))):
             req.taken_t = now
+            req.prefill_entry = at
         return group, free[:len(group)], bucket, dest_rows
 
+    def _prompts_to_take(self, sizes: List[int], bucket: int) -> int:
+        """How many of the front run's prompts (`sizes`: the entries of
+        a row each takes) the next prefill dispatch carries: the
+        longest front that lies in the rows `_prefill_rows_to_take`
+        gives the rows they all lie in, held to the row cap."""
+        if not sizes:
+            return 0
+        per_row = self._row_entries[bucket]
+
+        def rows_of(prompts: int) -> int:
+            return 1 + max(lay_rows(sizes[:prompts], per_row)) // per_row
+
+        rows = rows_of(len(sizes))
+        if self._prefill_rows_cap is not None:
+            rows = min(rows, self._prefill_rows_cap)
+        keep = self._prefill_rows_to_take(rows, bucket)
+        prompts = len(sizes)
+        while rows_of(prompts) > keep:
+            prompts -= 1
+        return prompts
+
+    def _program_rows(self, group: List[_Request], bucket: int) -> int:
+        """The rows of the prefill program that the taken `group` lies
+        in, before they pad to a power of two."""
+        return 1 + (max(req.prefill_entry for req in group)
+                    // self._row_entries[bucket])
+
     def _prefill_rows_to_take(self, rows: int, bucket: int) -> int:
-        """How many of `rows` same-bucket requests the next prefill
-        dispatch carries: all of them, padded to the next power of two
-        with dummy rows, or the largest power of two in `rows`, through
-        the program of exactly that many (the next takes have the
-        rest).  The cut is made where this engine has timed the padded
+        """How many of the `rows` that a run of same-bucket requests
+        lies in the next prefill dispatch carries: all of them, padded
+        to the next power of two with dummy rows, or the largest power
+        of two in `rows`, through the program of exactly that many (the
+        next takes have the rest).  The cut is made where this engine has timed the padded
         program and the program of every power of two in `rows` (7:
         those of 8, 4, 2 and 1 rows), and the pieces together took less
         than the padded one.  Both hold or fail by what the model's
@@ -2544,7 +2622,8 @@ class GenerationEngine:
                         self._enqueue_prefill_group,
                         group, slots, bucket, dest_rows)
                 except Exception as e:
-                    if self._prefill_refused(e, len(group)):
+                    if self._prefill_refused(
+                            e, self._program_rows(group, bucket)):
                         self._requeue_group(group, slots)
                         continue
                     # An enqueue-time failure (e.g. OOM compiling a
@@ -2584,13 +2663,13 @@ class GenerationEngine:
                         self._finalize_cost(req, "cancelled")
                         self.requests_finished += 1
                         self._schedule_block_release(slot)
-                        entries.append((slot, None))
+                        entries.append((slot, None, req.prefill_entry))
                         continue
                     act = _Active(req=req,
                                   length=req.prompt_ids.size,
                                   last_token=-1, generated=0)
                     self._slots[slot] = act
-                    entries.append((slot, act))
+                    entries.append((slot, act, req.prefill_entry))
                 # Eager fetch: the D2H round trip starts NOW and
                 # overlaps other fetches; the FIFO await below keeps
                 # delivery order.
@@ -2814,7 +2893,7 @@ class GenerationEngine:
                     # (If the poisoned cache chain breaks later waves,
                     # their fetch error still fails everything.)
                     logger.exception("prefill failed")
-                    for slot, act in meta[0]:
+                    for slot, act, _ in meta[0]:
                         if act is not None and \
                                 self._slots[slot] is act:
                             self._free_slot_state(slot)
@@ -2952,11 +3031,13 @@ class GenerationEngine:
                 self._prefill_device_s += busy
                 self._prefill_wait_s += wait_s
                 meta, bucket = meta
-                self._note_prefill_took(len(fetched), bucket, took_s)
+                per_row = self._row_entries[bucket]
+                self._note_prefill_took(len(fetched) // per_row, bucket,
+                                        took_s)
                 TIMELINE.record(
                     "device", "prefill.bucket", dur_s=dev_dur,
                     t_end=wall, attrs={"batch": len(meta)})
-                for slot_i, act in meta:
+                for slot_i, act, _ in meta:
                     if act is not None and self._slots[slot_i] is act:
                         TIMELINE.record("slot", "prefill",
                                         dur_s=dev_dur, t_end=wall,
@@ -2964,7 +3045,7 @@ class GenerationEngine:
                                         slot=slot_i)
                 with TIMELINE.span(HOST, "engine.deliver",
                                    rows=len(meta)):
-                    self._finish_prefill(fetched, lp, meta,
+                    self._finish_prefill(fetched, lp, meta, per_row,
                                          device_ms=dev_dur * 1000.0)
             self._process_deferred_frees()
 
@@ -2981,35 +3062,36 @@ class GenerationEngine:
             took.append(seconds)
 
     def _finish_prefill(self, firsts: np.ndarray, lp, entries,
-                        device_ms: float = 0.0):
-        """Deliver a fetched prefill batch's first tokens.  A slot
+                        per_row: int, device_ms: float = 0.0):
+        """Deliver a fetched prefill batch's first tokens: `firsts` has
+        `per_row` entries a row of the program, and `entries` names the
+        one (slot, request, entry) each prompt was read at.  A slot
         whose _Active was replaced since enqueue (cancel) discards its
-        row, exactly like _distribute."""
+        entry, exactly like _distribute."""
         self.prefills += 1
-        padded = len(firsts) - len(entries)
-        self.prefill_rows_dispatched += len(firsts)
+        rows = len(firsts) // per_row
+        # The rows that no prompt lies in.
+        padded = rows - len({at // per_row for _, _, at in entries})
+        self.prefill_rows_dispatched += rows
         self.prefill_rows_padded += padded
         obs.engine_prefill_rows_total().labels(
-            model=self.name).inc(len(firsts))
+            model=self.name).inc(rows)
         obs.engine_prefill_rows_padded_total().labels(
             model=self.name).inc(padded)
-        # Even split of the bucket dispatch across the rows whose cost
-        # records are still OPEN (slot unchanged since enqueue).  A
-        # cancelled row's record was finalized at cancel time —
+        # Even split of the bucket dispatch across the prompts whose
+        # cost records are still OPEN (slot unchanged since enqueue).  A
+        # cancelled one's record was finalized at cancel time —
         # mutating it would be lost work — so its computed prompt's
         # share redistributes onto the survivors of the same dispatch:
         # device time stays conserved across stored records.
-        live = [act for slot, act in entries
+        live = [(slot, act, at) for slot, act, at in entries
                 if act is not None and self._slots[slot] is act]
         share_ms = device_ms / len(live) if live else 0.0
-        for act in live:
+        for slot, act, at in live:
             act.req.prefill_device_ms += share_ms
-        for i, (slot, act) in enumerate(entries):
-            if act is None or self._slots[slot] is not act:
-                continue
             self.prefill_requests += 1
-            self._emit(slot, int(firsts[i]),
-                       _logprob_record(lp, act.req.logprobs, i))
+            self._emit(slot, int(firsts[at]),
+                       _logprob_record(lp, act.req.logprobs, at))
 
     def _note_program(self, kind: str, *signature) -> None:
         """Record one dispatched program shape (enqueue-executor
@@ -3127,36 +3209,64 @@ class GenerationEngine:
         off it, and return the first-token handles WITHOUT any host
         sync — prompt ingestion rides the same in-flight pipeline as
         decode waves, so admissions no longer stall live streams by a
-        full prefill dispatch.  The batch pads to a pow2 row bucket so
-        compile count stays bounded (where `_take_prefill_group` cuts
-        a run at a power of two nothing pads); padding rows carry an
-        out-of-bounds slot sentinel the scatters drop."""
+        full prefill dispatch.  Each prompt lies where
+        `_take_prefill_group` laid it (`prefill_entry`): in a row of
+        its own from the row's first column, or, where the program
+        packs, from one of the row's block boundaries, behind the
+        prompts that share the row (`programs.build`'s `prefill_fn`
+        says what the program is then told).  The rows pad to a pow2
+        row bucket so compile count stays bounded (where
+        `_take_prefill_group` cuts a run at a power of two of rows
+        nothing pads); the per-prompt arrays have an entry for every
+        place a prompt could start, and those where none does, a
+        padding row's among them, carry an out-of-bounds slot sentinel
+        the scatters drop."""
         # This group's plans may have evicted spill-pending blocks the
         # insert below will rewrite: gather first.
         self._drain_spills()
         jnp = self._jnp
         b = len(group)
-        b_bucket = 1 << (b - 1).bit_length()
+        bs = self.block_size
+        per_row = self._row_entries[bucket]
+        b_bucket = 1 << (self._program_rows(group, bucket) - 1).bit_length()
+        entries = b_bucket * per_row
+        # A prompt's row, and the column it starts at.
+        places = [(req.prefill_entry // per_row,
+                   req.prefill_entry % per_row * bs) for req in group]
         with mesh_scope(self.mesh), \
                 TIMELINE.span(LAUNCH, "engine.prep.prefill"):
             ids = np.zeros((b_bucket, bucket), np.int32)
-            lengths = np.ones(b_bucket, np.int32)  # dummy rows: length 1
-            temps = np.zeros(b_bucket, np.float32)
-            top_ks = np.zeros(b_bucket, np.int32)
-            top_ps = np.ones(b_bucket, np.float32)
-            seeds = np.zeros(b_bucket, np.int32)
-            slot_arr = np.full(b_bucket, self.max_slots, np.int32)  # OOB
+            lengths = np.ones(entries, np.int32)  # no prompt: length 1
+            temps = np.zeros(entries, np.float32)
+            top_ks = np.zeros(entries, np.int32)
+            top_ps = np.ones(entries, np.float32)
+            seeds = np.zeros(entries, np.int32)
+            slot_arr = np.full(entries, self.max_slots, np.int32)  # OOB
             want_lp = False
-            for i, (req, slot) in enumerate(zip(group, slots)):
+            packed = ()
+            if per_row > 1:
+                # What says where the prompts lie: a position's prompt
+                # (numbered by the block it starts at; -1 padding), its
+                # position in it, and each prompt's last column.
+                segments = np.full((b_bucket, bucket), -1, np.int32)
+                positions = np.zeros((b_bucket, bucket), np.int32)
+                last = np.zeros((b_bucket, per_row), np.int32)
+                packed = ((segments, positions, last),)
+            for req, slot, (row, start) in zip(group, slots, places):
                 n = req.prompt_ids.size
-                ids[i, :n] = req.prompt_ids
-                lengths[i] = n
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-                top_ps[i] = req.top_p
-                seeds[i] = req.seed
-                slot_arr[i] = slot
+                ids[row, start:start + n] = req.prompt_ids
+                at = req.prefill_entry
+                lengths[at] = n
+                temps[at] = req.temperature
+                top_ks[at] = req.top_k
+                top_ps[at] = req.top_p
+                seeds[at] = req.seed
+                slot_arr[at] = slot
                 want_lp = want_lp or req.logprobs > 0
+                if packed:
+                    segments[row, start:start + n] = start // bs
+                    positions[row, start:start + n] = np.arange(n)
+                    last[row, start // bs] = start + n - 1
             # Roofline accounting: real-token FLOPs (2P matmul + causal
             # attention's triangular sum) and the bucket's token
             # padding — padded rows/positions burn device time without
@@ -3173,6 +3283,7 @@ class GenerationEngine:
             rec[1] += b_bucket * bucket
             self._note_program("prefill", b_bucket, bucket)
             ids_d, lengths_d = jnp.asarray(ids), jnp.asarray(lengths)
+            packed_d = self._jax.tree.map(jnp.asarray, packed)
             sampling = [jnp.asarray(a)
                         for a in (temps, top_ks, top_ps, seeds,
                                   self._tail_asked("prefill", temps,
@@ -3185,19 +3296,21 @@ class GenerationEngine:
                     "prefill", rows=b, bucket=bucket,
                     trace_ids=[r.trace_id for r in group]) as launched:
             out = self._prefill(self.variables, ids_d, lengths_d,
-                                *sampling)
+                                *sampling, *packed_d)
             firsts, new_caches, chosen_lp, top_ids, top_lps = out[:5]
             if self._moe is not None:
                 self._moe.note("prefill", out[5],
                                tokens=b_bucket * bucket)
         with TIMELINE.span(LAUNCH, "engine.prep.insert"):
             slot_d = jnp.asarray(slot_arr)
-            # Per-chunk destination blocks (-1 = shared prefix hit or
-            # padding row — the scatter drops those chunks).
-            chunks = bucket // self.block_size
+            # Per-chunk destination blocks (-1 = shared prefix hit, or
+            # a chunk no prompt lies in — the scatter drops those): a
+            # row's chunks name, one by one, a block of whichever
+            # sequence lies there.
+            chunks = bucket // bs
             dest = np.full((b_bucket, chunks), -1, np.int32)
-            for i, row in enumerate(dest_rows):
-                dest[i, :len(row)] = row
+            for (row, start), plan in zip(places, dest_rows):
+                dest[row, start // bs:start // bs + len(plan)] = plan
             dest_d = jnp.asarray(dest)
             if self._ring is not None:
                 # The rings take each prompt's last blocks alone, block
@@ -3205,12 +3318,11 @@ class GenerationEngine:
                 ring = self._ring
                 ring_dest = np.full((b_bucket, chunks), -1, np.int32)
                 with self._block_lock:
-                    for i, (req, slot) in enumerate(zip(group, slots)):
-                        total = -(-int(req.prompt_ids.size)
-                                  // self.block_size)
+                    for req, slot, (row, _) in zip(group, slots, places):
+                        total = -(-int(req.prompt_ids.size) // bs)
                         for j in range(max(0, total - ring.columns),
                                        total):
-                            ring_dest[i, j] = ring.at(slot, j)
+                            ring_dest[row, j] = ring.at(slot, j)
                 dest_d = (dest_d, jnp.asarray(ring_dest))
         with self._inflight.launch("insert", rows=b):
             self._caches = self._insert(self._caches, new_caches,

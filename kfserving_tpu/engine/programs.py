@@ -6,6 +6,8 @@ declaration and the engine's sizes; `GenerationEngine`
   facts the host side books by; `place_params`: the parameters beside them.
 - `UNSERVED` / `CacheLayout.refusal`: what a kind of layer cannot serve,
   said once.
+- `packs_prompts`: which (rows, bucket) prefill programs carry several
+  prompts a row.
 - `build`: the jitted programs, with their donations.  The benchmark's
   trace reduction and compile-log reader find `decode_fn`, `prefill_fn`
   and `insert_fn` by name (chipbench/trace.py,
@@ -261,6 +263,22 @@ class CacheLayout:
                     return (f"{setting} is not served for {name!r}, a "
                             f"model with {kind}: {why}")
         return None
+
+
+def packs_prompts(cache_kinds, bucket: int) -> bool:
+    """Whether a row of the (rows, `bucket`) prefill program carries as
+    many prompts as its blocks hold (`build`'s `prefill_fn`), by what the
+    model declares and the bucket is: every cached layer keeps whole-
+    context K/V and nothing else, and the bucket's attention is XLA's,
+    which takes any mask.  A recurrence's state and its convolution
+    would have to start again at each prompt, a ring be inserted a
+    prompt at a time, and the flash kernel know of segments: those
+    models and buckets keep one prompt a row."""
+    from kfserving_tpu.ops.attention import masked_prefill_takes_xla
+
+    return masked_prefill_takes_xla(bucket) and all(
+        isinstance(kind, KVCache) and kind.window is None
+        for kind in cache_kinds if kind is not None)
 
 
 def derive_block_size(max_seq: int, prefill_buckets: List[int]) -> int:
@@ -549,18 +567,44 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
                                            mode="drop"))
 
     def prefill_fn(variables, ids, lengths, temps, top_ks, top_ps,
-                   seeds, want_lp):
-        # logit_positions: the LM head runs only on each row's
+                   seeds, want_lp, packed=None):
+        """One prefill dispatch: `ids` [B, L], a row the bucket long.
+
+        A row holds one prompt, from its first column (`packed` None):
+        `lengths` and the sampling arrays are [B], a dummy row's length
+        is 1.  Or, where `packs_prompts` says so, as many prompts as its
+        blocks hold, each from a block boundary: `packed` is (`segments`
+        [B, L]: which prompt of its row a position belongs to, -1 for
+        padding; `positions` [B, L], from 0 again in each; `last`
+        [B, P], P the blocks of a row: the column of the last token of
+        the prompt that starts at block p, any column where none
+        does), and `lengths` and the sampling arrays are [B * P], an
+        entry a block, read where a prompt starts.  The model masks a
+        prompt's queries to its own keys (`decoder.cached_attention`),
+        the head and the sampler run over all B * P entries (the head
+        is a weight stream: its rows cost nothing that can be read), and
+        what comes back for an entry no prompt starts at is thrown
+        away.  A lone prompt is a row with one segment: the packed
+        program is the (rows, bucket) program, not one beside it.
+
+        Either way the caches leave as [B, L, H*D], a row's blocks in
+        the row's order, so `insert_fn` takes them block by block with
+        no notion of whose block is whose."""
+        # logit_positions: the LM head runs only on each prompt's
         # last real token — sampling never needs the [B, L, V]
         # logits cube, and at a 4096 bucket the full-cube head
         # matmul dominated prefill FLOPs.  Numerically identical
         # per row to slicing the full cube (norm + head are
         # per-position), so the chunked path (which uses the same
         # sliced head) samples the same first token.
-        (logits, caches), pairs = apply(variables, ids,
-                                        kv_lengths=lengths,
-                                        return_cache=True,
-                                        logit_positions=lengths - 1)
+        if packed is None:
+            where = {"kv_lengths": lengths, "logit_positions": lengths - 1}
+        else:
+            segments, positions, last = packed
+            where = {"segments": segments, "positions": positions,
+                     "logit_positions": last}
+        (logits, caches), pairs = apply(variables, ids, return_cache=True,
+                                        **where)
         # Leave the program as the pool stores them, [B, L, H*D]:
         # the insert is then a scatter of whole blocks, where
         # [B, L, H, D] results (L minor-most on the chip) would be
@@ -569,10 +613,11 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
                               x.reshape(x.shape[:2] + (-1,)) for x in rows),
                           _kept, layer)
                   for kind, layer in zip(cache_kinds, caches)]
-        last = logits[:, 0]
-        first_tokens = sample(base_key, last, temps, top_ks, top_ps,
+        # [B, 1, V], or [B, P, V] packed: an entry a row of the tail.
+        last_logits = logits.reshape(-1, logits.shape[-1])
+        first_tokens = sample(base_key, last_logits, temps, top_ks, top_ps,
                               seeds, lengths)
-        chosen_lp, top_ids, top_lps = logprob_of(last, first_tokens,
+        chosen_lp, top_ids, top_lps = logprob_of(last_logits, first_tokens,
                                                  lp_n, want_lp)
         out = (first_tokens, caches, chosen_lp, top_ids, top_lps)
         return out + (pairs,) if routed else out

@@ -125,7 +125,8 @@ class DecoderConfig:
 
 
 def cached_attention(q, k, v, *, cache=None, positions=None,
-                     kv_lengths=None, attn_fn=None, window=None):
+                     kv_lengths=None, attn_fn=None, window=None,
+                     segments=None):
     """Attention of one block, shared by every decoder block of the zoo
     (GPT-2's here, OLMoE's in models/olmoe.py, Nemotron-H's in
     models/nemotron_h.py): q, k, v are [B, L, H, D] as projected (and,
@@ -146,6 +147,11 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
     sliding-window one on every branch: a query at t sees keys s with
     t - window < s <= t, and `cache` is then the layer's ring pools and
     ring table (ops/paged_attention.py).
+    `segments` [B, L] int32 (a prefill whose rows each carry several
+    prompts, engine/programs.py `prefill_fn`; in place of `kv_lengths`)
+    numbers the prompt a position belongs to, -1 for padding: a query
+    sees the keys of its own prompt that are not behind it, so a key of
+    another prompt has weight exactly 0, as a padded one has.
     Returns (out [B, L, H, D], new_cache)."""
     lq = q.shape[1]
     group = q.shape[2] // k.shape[2]
@@ -182,6 +188,8 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
             pad = (jnp.arange(lq)[None, :]
                    < kv_lengths[:, None])[:, None, None, :]
             attn_mask = causal & pad
+        elif segments is not None:
+            attn_mask = causal & same_segment(segments)
         else:
             attn_mask = causal
         out = attn_fn(q, k, v, attn_mask)
@@ -189,10 +197,23 @@ def cached_attention(q, k, v, *, cache=None, positions=None,
         # this a prefill with return_cache=True under a pluggable
         # attn_fn returned caches=[None, ...] and crashed deep in
         # the engine's insert scatter instead of working.
+    elif segments is not None:
+        # An explicit mask takes XLA's attention whatever the length:
+        # the caller gives segments only to the lengths whose padded
+        # prefill takes it too (`attention.masked_prefill_takes_xla`).
+        out = dot_product_attention(q, k, v, mask=same_segment(segments),
+                                    causal=True, window=window)
     else:
         out = dot_product_attention(q, k, v, causal=True,
                                     kv_lengths=kv_lengths, window=window)
     return out, new_cache
+
+
+def same_segment(segments):
+    """[B, 1, Lq, Lk] bool of `segments` [B, L]: the key is of the
+    query's prompt, and of a prompt at all."""
+    keys = segments[:, None, None, :]
+    return (segments[:, None, :, None] == keys) & (keys >= 0)
 
 
 class DecoderBlock(nn.Module):
@@ -200,10 +221,11 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, *, mask=None, kv_lengths=None,
-                 cache=None, positions=None):
+                 cache=None, positions=None, segments=None):
         """cache: optional (pool_k, pool_v, block_table) — decode and
         chunk prefill.  positions: [B, L] absolute positions of the
-        fed tokens — where the cache write lands."""
+        fed tokens — where the cache write lands.  segments: [B, L],
+        the prompt each position of a packed prefill belongs to."""
         cfg = self.config
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="attn_norm")(hidden)
@@ -217,7 +239,7 @@ class DecoderBlock(nn.Module):
         v = proj("value")(x)
         out, new_cache = cached_attention(
             q, k, v, cache=cache, positions=positions,
-            kv_lengths=kv_lengths, attn_fn=cfg.attn_fn)
+            kv_lengths=kv_lengths, attn_fn=cfg.attn_fn, segments=segments)
         out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1),
                               dtype=cfg.dtype, name="out")(out)
         hidden = hidden + out
@@ -255,6 +277,11 @@ class DecoderLM(nn.Module):
         (chunked prefill's last-token slice); [B, P] returns
         [B, P, V] — speculative decoding's verify dispatch reads all
         K+1 positions of a draft run from the one Lq>1 forward.
+    packed prefill: `segments` [B, L] int32 in place of `kv_lengths`,
+        with `positions` [B, L] — a row carries several prompts, each
+        numbered in `segments` (-1: padding) and counted from 0 again
+        in `positions`; each attends to itself alone, and
+        `logit_positions` [B, P] names each one's last token.
     """
 
     config: DecoderConfig
@@ -264,7 +291,8 @@ class DecoderLM(nn.Module):
                  kv_cache: Optional[Any] = None,
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
-                 logit_positions: Optional[Any] = None):
+                 logit_positions: Optional[Any] = None,
+                 segments: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
@@ -287,7 +315,7 @@ class DecoderLM(nn.Module):
                          else pos.reshape(b, -1))
             hidden, new_cache = DecoderBlock(cfg, name=f"layer_{i}")(
                 hidden, kv_lengths=kv_lengths, cache=layer_cache,
-                positions=layer_pos)
+                positions=layer_pos, segments=segments)
             caches.append(new_cache)
         if logit_positions is not None:
             # Per-row gather BEFORE the norm + LM head: LayerNorm and

@@ -162,7 +162,7 @@ class OlmoeBlock(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, positions, rotary, *, kv_lengths=None,
-                 cache=None, valid=None):
+                 cache=None, valid=None, segments=None):
         cfg = self.config
 
         def norm(name):
@@ -189,7 +189,8 @@ class OlmoeBlock(nn.Module):
             out, new_cache = cached_attention(
                 q, k, v, cache=cache,
                 positions=None if cache is None else positions,
-                kv_lengths=kv_lengths, attn_fn=cfg.attn_fn)
+                kv_lengths=kv_lengths, attn_fn=cfg.attn_fn,
+                segments=segments)
             hidden = hidden + nn.DenseGeneral(
                 cfg.hidden_size, axis=(-2, -1), use_bias=False,
                 dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -234,7 +235,8 @@ class OlmoeLM(nn.Module):
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
                  logit_positions: Optional[Any] = None,
-                 valid: Optional[Any] = None):
+                 valid: Optional[Any] = None,
+                 segments: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         if positions is None:
@@ -242,12 +244,14 @@ class OlmoeLM(nn.Module):
         else:
             pos = positions.reshape(b, -1)
         # Tokens no request owns are given to no expert: a prefill
-        # bucket's padding (past kv_lengths), a chunk's padding (parked
-        # on the out-of-range sentinel), and the rows of a decode step
-        # that the engine says are not `valid` ([B, 1] bool: past their
-        # token budget).
+        # bucket's padding (past kv_lengths, or in no segment of a
+        # packed prefill), a chunk's padding (parked on the out-of-range
+        # sentinel), and the rows of a decode step that the engine says
+        # are not `valid` ([B, 1] bool: past their token budget).
         if kv_lengths is not None:
             valid = jnp.arange(l)[None, :] < kv_lengths[:, None]
+        elif segments is not None:
+            valid = segments >= 0
         elif kv_cache is not None and l > 1:
             # What a row's table can hold: the engine parks padding
             # past it, where cache writes drop.
@@ -262,7 +266,7 @@ class OlmoeLM(nn.Module):
             hidden, new_cache = OlmoeBlock(cfg, name=f"layer_{i}")(
                 hidden, pos, rotary, kv_lengths=kv_lengths,
                 cache=None if kv_cache is None else kv_cache[i],
-                valid=valid)
+                valid=valid, segments=segments)
             caches.append(new_cache)
         if logit_positions is not None:
             hidden = jnp.take_along_axis(

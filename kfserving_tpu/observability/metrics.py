@@ -400,15 +400,16 @@ def engine_prefill_rows_total():
         "kfserving_tpu_engine_prefill_rows_total",
         "Rows of the prefill programs whose results were fetched, dummy "
         "rows included: a group of same-bucket arrivals rides a program "
-        "of power-of-two rows")
+        "of power-of-two rows, a row carrying one prompt or, for a "
+        "whole-context attention model, as many as its blocks hold")
 
 
 def engine_prefill_rows_padded_total():
     return REGISTRY.counter(
         "kfserving_tpu_engine_prefill_rows_padded_total",
-        "Those of the prefill programs' rows that no request filled "
-        "(length 1, scattered nowhere): the whole program runs over them "
-        "for nobody")
+        "Those of the prefill programs' rows that no prompt lay in "
+        "(scattered nowhere): the whole program runs over them for "
+        "nobody")
 
 
 def engine_sampler_tail_calls_total():

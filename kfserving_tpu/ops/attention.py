@@ -50,6 +50,14 @@ _FLASH_HEAD_DIM_MULTIPLE = 64
 _FLASH_CAUSAL_MASKED_MIN_SEQ = 2048
 
 
+def masked_prefill_takes_xla(length: int) -> bool:
+    """A causal prefill of `length` positions under a padding mask is
+    served by XLA's attention on every backend and for every head size:
+    what a prefill that needs another mask than the kernel's two (one
+    row carrying several prompts, engine/programs.py) may count on."""
+    return length < _FLASH_CAUSAL_MASKED_MIN_SEQ
+
+
 def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    mask: Optional[jax.Array]) -> jax.Array:
     """Reference einsum attention in BLHD layout; XLA fuses scale+bias+softmax

@@ -452,15 +452,30 @@ def _decode_program(v5e, monkeypatch, serving: dict):
         engine.shutdown_nowait()
 
 
-def _prefill_program(v5e, monkeypatch, serving: dict, rows: int):
+_PREFILL_PROGRAMS = {}
+
+
+def _prefill_program(v5e, monkeypatch, serving: dict, rows: int,
+                     a_prompt_a_row: bool = False):
     """The (rows, largest bucket) prefill program of a configuration's
-    serving settings, as the chip's compiler sees it."""
+    serving settings, as the chip's compiler sees it: in the form the
+    engine dispatches it (a row carrying as many prompts as its blocks
+    hold where `programs.packs_prompts` says so), or with
+    `a_prompt_a_row` in the form that lays one prompt in a row whatever
+    the model: the program of the engine before rows were packed."""
     from jax.sharding import SingleDeviceSharding
 
     from kfserving_tpu.engine.generator import GenerationEngine
     from kfserving_tpu.models import create_model
-    from kfserving_tpu.ops import attention
+    from kfserving_tpu.ops import attention, moe
 
+    # One compile a program and a run of this file: the packed form is
+    # asked for by the case that sizes it and by the one that holds it
+    # against a prompt a row.
+    key = (json.dumps(serving, sort_keys=True), rows, a_prompt_a_row,
+           moe.GROUPED_KERNEL_PROVEN)
+    if key in _PREFILL_PROGRAMS:
+        return _PREFILL_PROGRAMS[key]
     monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
     spec = create_model(serving["architecture"], **serving["arch_kwargs"])
     one = SingleDeviceSharding(v5e.devices[0])
@@ -481,11 +496,18 @@ def _prefill_program(v5e, monkeypatch, serving: dict, rows: int):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
         i32, f32 = jnp.int32, jnp.float32
-        return engine._prefill.lower(
-            engine.variables,
-            arg(i32, rows, max(serving["prefill_buckets"])),
-            arg(i32, rows), arg(f32, rows), arg(i32, rows), arg(f32, rows),
-            arg(i32, rows), arg(jnp.bool_)).compile()
+        bucket = max(serving["prefill_buckets"])
+        per_row = 1 if a_prompt_a_row else engine._row_entries[bucket]
+        entries = rows * per_row
+        packed = () if per_row == 1 else ((
+            arg(i32, rows, bucket), arg(i32, rows, bucket),
+            arg(i32, rows, per_row)),)
+        compiled = _PREFILL_PROGRAMS[key] = engine._prefill.lower(
+            engine.variables, arg(i32, rows, bucket),
+            arg(i32, entries), arg(f32, entries), arg(i32, entries),
+            arg(f32, entries), arg(i32, entries), arg(jnp.bool_),
+            *packed).compile()
+        return compiled
     finally:
         engine.shutdown_nowait()
 
@@ -805,6 +827,37 @@ def test_gpt2_large_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
     assert 1.4 < memory.argument_size_in_bytes / 2**30 < 1.5, memory
     assert memory.temp_size_in_bytes < 0.3 * 2**30, memory  # 0.27
     assert _program_bytes(memory) + 3.4e9 < 15.75 * 2**30, memory
+
+
+@pytest.mark.parametrize("config, rows", [("olmoe-1b-7b-8l", 4),
+                                          ("gpt2-large", 16)])
+def test_packed_prefill_program_keeps_the_temporaries_of_a_prompt_a_row(
+        v5e, monkeypatch, config, rows):
+    """A whole-context attention model's (rows, bucket) program, with its
+    rows carrying a prompt a block: no larger beside its arguments than
+    the program that lays one prompt in a row by more than the head's
+    and the sampler's [rows * blocks, vocabulary] float32 logits, where
+    there were [rows, vocabulary]; the same K/V out, and no operation
+    over scores that the other does not have."""
+    serving = {"olmoe-1b-7b-8l": _olmoe_serving,
+               "gpt2-large": _gpt2_large_serving}[config]()
+    bucket = max(serving["prefill_buckets"])
+    blocks = bucket // serving["block_size"]
+    packed = _prefill_program(v5e, monkeypatch, serving, rows)
+    alone = _prefill_program(v5e, monkeypatch, serving, rows,
+                             a_prompt_a_row=True)
+    memory, before = packed.memory_analysis(), alone.memory_analysis()
+    print(f"{config} ({rows}, {bucket}) prefill program, packed: {memory}\n"
+          f"a prompt a row: {before}")
+    vocabulary = serving["arch_kwargs"]["vocab_size"]
+    logits = 4 * rows * blocks * vocabulary
+    assert memory.temp_size_in_bytes <= before.temp_size_in_bytes + logits
+    # int32 segments, positions and last columns in; an entry a block out
+    assert 0 < (memory.argument_size_in_bytes
+                - before.argument_size_in_bytes) < 3 * 4 * rows * bucket
+    assert 0 <= (memory.output_size_in_bytes
+                - before.output_size_in_bytes) < 2**14
+    assert _parameter_converts(packed) == []
 
 
 def _falcon_serving() -> dict:

@@ -491,7 +491,8 @@ async def test_prefill_refused_for_memory_is_taken_again_smaller(
     goes back to the queue and rides dispatches of half the refused
     row count from then on."""
     module, variables, _ = tiny
-    prompts = [[5, 5], [7, 1, 3], [2], [9, 9, 4]]
+    # Each fills its row of the 16 bucket whatever the block size.
+    prompts = [[5, 5] * 6, [7, 1, 3] * 4, [2] * 9, [9, 9, 4] * 5]
     wants = [ref_greedy(module, variables, p, 4) for p in prompts]
     kw = {"block_size": 8} if explicit else {}
     eng = make_engine(tiny, max_slots=4,
@@ -1196,10 +1197,12 @@ def _pieces(b: int):
 def _split_prompt(i: int):
     """Request i of a burst: every third one opens with one whole block
     that the others of its kind share (a later piece's prefix hit on an
-    earlier piece's block), all within the 16-token bucket."""
+    earlier piece's block), all within the 16-token bucket and all of
+    more than one of its two blocks, so a prompt fills a row of the
+    program and what is said of rows here is said of requests."""
     if i % 3 == 0:
         return list(range(1, SPLIT_BS + 1)) + [20 + i, 50 + i][:1 + i % 2]
-    return [(7 * i + j) % 90 + 1 for j in range(1 + i % 13)]
+    return [(7 * i + j) % 90 + 1 for j in range(SPLIT_BS + 1 + i % 8)]
 
 
 def split_engine(tiny, **kw):
@@ -1556,8 +1559,9 @@ async def test_pool_starved_take_rolls_back_to_a_power_of_two(tiny):
     _price(eng)
     seen = _watch(eng)
     try:
-        for rows in (1, 2, 4):  # one block a request: all four fit
-            await _burst(eng, seen, rows, prompt=lambda i: [i + 1, 2])
+        # The pool holds no four prompts that fill a row each: the
+        # programs a take of three is weighed by are priced unrun.
+        eng._prefill_took_s.update({(r, 16): [float(r)] for r in (1, 2, 4)})
         invalidated = eng.block_evictions["index_invalidation"]
         requeue, undone = eng._requeue_group, []
 
@@ -1611,3 +1615,246 @@ async def test_padded_rows_share_is_read_from_the_scrapes(tiny):
                             "scrapes": scrapes}) == 25.0
     finally:
         await eng.close()
+
+
+# ------------------------- a prefill row that carries several prompts
+# One bucket of four blocks: a prompt takes the blocks it fills, and the
+# next one of the run starts at the row's next block where the model's
+# programs pack (`programs.packs_prompts`: the dense decoder and OLMoE
+# of `FAMILIES`; Nemotron-H's state and Mellum's rings keep a prompt a
+# row).
+PACK_BUCKET = 64
+PACKS = {"decoder": True, "olmoe": True, "nemotron_h": False,
+         "mellum": False}
+
+
+def packing_engine(family, **kw):
+    module, variables = family
+    kw.setdefault("max_slots", 8)
+    return GenerationEngine(module, variables, max_seq=PARKED_SEQ,
+                            block_size=PARKED_BS,
+                            prefill_buckets=[PACK_BUCKET], **kw)
+
+
+def _packs(eng) -> bool:
+    return eng._row_entries[PACK_BUCKET] > 1
+
+
+def _same_streams(got, want):
+    for (tokens, lps), (alone, alone_lps) in zip(got, want):
+        assert tokens == alone
+        np.testing.assert_allclose(lps, alone_lps, rtol=0, atol=1e-5)
+
+
+# (tokens, stride, budget, sampling): one block, off a boundary, two
+# blocks exactly, the whole bucket, and two that sample.
+MIXED = [(16, 3, 4, {}), (5, 5, 6, {}), (32, 7, 3, {}), (64, 11, 2, {}),
+         (21, 13, 5, {"temperature": 0.9, "seed": 5}), (1, 17, 4, {}),
+         (40, 19, 3, {"temperature": 1.1, "seed": 11}), (17, 23, 5, {})]
+
+
+def _requests(rows):
+    return [(_prompt(length, stride), budget, sampling)
+            for length, stride, budget, sampling in rows]
+
+
+async def _alone(family, requests, **kw):
+    """Every request's stream from an engine that serves it alone."""
+    eng = packing_engine(family, **kw)
+    try:
+        return [(await _served(eng, [r]))[0] for r in requests]
+    finally:
+        await eng.close()
+
+
+@pytest.fixture(scope="module")
+def mixed_alone(family):
+    from kfserving_tpu.observability import REGISTRY
+
+    try:
+        return asyncio.run(_alone(family, _requests(MIXED)))
+    finally:
+        REGISTRY.reset()  # this engine ran outside any test
+
+
+@pytest.fixture
+def packs(request):
+    """Whether the `family` of this test lays several prompts in a row."""
+    return PACKS[request.node.callspec.params["family"]]
+
+
+async def test_a_burst_of_mixed_lengths_is_served_as_each_alone(
+        family, mixed_alone, packs):
+    """Eight arrivals of 1 to 64 tokens before the scheduler wakes: one
+    take, one dispatch.  Where the programs pack they lie in 4 rows (the
+    blocks 4, 3+1, 2+2, 2+1+1) where they filled 8; a state model and a
+    ring model dispatch a prompt a row as before.  Every stream, sampled
+    ones too, is what the request gets served alone."""
+    eng = packing_engine(family)
+    asked = []
+    rule = eng._prefill_rows_to_take
+    eng._prefill_rows_to_take = lambda rows, bucket: (
+        asked.append(rows), rule(rows, bucket))[1]
+    try:
+        assert _packs(eng) is packs
+        got = await _served(eng, _requests(MIXED))
+        stats = eng.stats()
+    finally:
+        await eng.close()
+    _same_streams(got, mixed_alone)
+    rows = 4 if packs else 8
+    assert (stats["prefills"], stats["prefill_requests"]) == (1, 8)
+    assert (stats["prefill_rows_dispatched"],
+            stats["prefill_rows_padded"]) == (rows, 0)
+    assert stats["prefill_prompts_per_row"] == 8 / rows
+    # PR 49's rule is asked about the program's rows, not the prompts.
+    assert set(asked) == {rows}
+    assert {key for key in eng._dispatched_programs
+            if key[0] == "prefill"} == {("prefill", rows, PACK_BUCKET)}
+
+
+@pytest.mark.parametrize("sizes, per_row, rows", [
+    ([1], 4, 1), ([4], 4, 1), ([1, 1, 1, 1], 4, 1), ([1, 1, 1, 1, 1], 4, 2),
+    ([4, 3, 1, 2, 2, 2, 1, 1], 4, 4),      # the burst above
+    ([2, 3, 2, 3], 4, 3),                  # 3 + 2 fits no row, 2 + 2 does
+    ([3, 1, 3, 1, 2], 4, 3), ([1, 4, 1, 4, 1], 4, 3),
+    ([5, 3, 8, 2, 2, 4], 8, 3), ([1, 2, 3, 4, 5, 6, 7, 8], 8, 5),
+    ([1, 1, 1], 1, 3), ([1] * 7, 1, 7),    # one prompt a row, in order
+    ([], 4, 0),
+], ids=str)
+def test_lay_rows_gives_each_prompt_consecutive_entries_of_one_row(
+        sizes, per_row, rows):
+    from kfserving_tpu.engine.generator import lay_rows
+
+    at = lay_rows(sizes, per_row)
+    owned = [e for first, size in zip(at, sizes)
+             for e in range(first, first + size)]
+    assert len(set(owned)) == len(owned) == sum(sizes)  # none owned twice
+    for first, size in zip(at, sizes):
+        assert first % per_row + size <= per_row        # within one row
+    used = {e // per_row for e in owned}
+    assert used == set(range(rows))                     # no row skipped
+    # a row is filled from its first entry, with no hole before its end
+    for row in used:
+        mine = sorted(e % per_row for e in owned if e // per_row == row)
+        assert mine == list(range(len(mine)))
+    if per_row == 1:
+        assert at == list(range(len(sizes)))
+
+
+@pytest.mark.parametrize("member", ["decoder", "olmoe"])
+async def test_a_packed_group_with_a_prefix_hit_a_cancel_and_a_short_pool(
+        member):
+    """Through one engine of a family that packs: (1) a prompt whose first
+    block another request left in the prefix index rides a row with two
+    others, its first chunk a -1 the insert drops; (2) one request of a
+    packed row is cancelled between dispatch and fetch, and its row's
+    others get their streams; (3) the pool stops a take of five short at
+    five prompts of two blocks, which lie in 3 rows: the 4 that fill 2
+    rows go (the programs priced by their rows), the fifth's plan is
+    undone and it waits with the rest.  Every stream is what the request
+    gets alone."""
+    from kfserving_tpu.models import create_model, init_params
+    from kfserving_tpu.observability import REGISTRY
+
+    name, sizes = FAMILIES[member]
+    spec = create_model(name, max_seq=PARKED_SEQ, **sizes)
+    family = spec.module, init_params(spec, seed=3)
+    shared = _prompt(PARKED_BS, 3)
+    hit = [(shared + _prompt(9, 29), 4, {}), (_prompt(12, 7), 4, {}),
+           (_prompt(20, 11), 3, {"temperature": 0.8, "seed": 9})]
+    cancelled = _requests([(18, 5, 4, {}), (14, 13, 5, {}), (7, 17, 3, {})])
+    # 2 blocks each and a pool of 11: the sixth plan finds 1 free.
+    short = _requests([(18 + 2 * i, 3 + 2 * i, 3, {}) for i in range(7)])
+    alone = await _alone(family, hit + cancelled + short)
+    REGISTRY.reset()
+    eng = packing_engine(family, cache_blocks=11)
+    try:
+        # (1)
+        await eng.complete(shared + [1, 2], max_new_tokens=2)
+        before = eng.stats()
+        got = await _served(eng, hit)
+        after = eng.stats()
+        _same_streams(got, alone[:3])
+        assert after["prefill_rows_dispatched"] \
+            - before["prefill_rows_dispatched"] == 2   # blocks 2 + 1, 2
+        assert after["paged"]["prefix_hits"] \
+            - before["paged"]["prefix_hits"] == 1
+        # (2)
+        orig, reqs = eng._enqueue_prefill_group, []
+
+        def cancel_one(group, slots, bucket, dest_rows=None):
+            assert len({r.prefill_entry // 4 for r in group}) == 1
+            eng.cancel(reqs[1])
+            return orig(group, slots, bucket, dest_rows)
+
+        eng._enqueue_prefill_group = cancel_one
+        reqs.extend(eng.submit(p, max_new_tokens=budget, logprobs=1, **kw)
+                    for p, budget, kw in cancelled)
+        outs = await asyncio.wait_for(
+            asyncio.gather(*(_drain(eng, r) for r in reqs)), timeout=120)
+        eng._enqueue_prefill_group = orig
+        assert outs == [alone[3][0], [], alone[5][0]]
+        # (3)
+        await _settled(eng)
+        _price(eng)
+        eng._prefill_took_s.update(
+            {(r, PACK_BUCKET): [float(r)] for r in (1, 2, 4)})
+        seen = _watch(eng)
+        requeue, undone = eng._requeue_group, []
+
+        def watched_requeue(group, slots):
+            undone.append([r.prompt_ids.tolist() for r in group])
+            return requeue(group, slots)
+
+        eng._requeue_group = watched_requeue
+        got = await _served(eng, short)
+        _same_streams(got, alone[6:])
+        assert seen["rows"][0] == 2 and sum(seen["rows"]) == 4
+        assert undone[0] == [short[4][0]]
+        assert all(s is None for s in eng._slots)
+    finally:
+        await eng.close()
+
+
+async def test_a_stream_resumed_after_preemption_rides_a_packed_row(
+        family, packs):
+    """Three long streams in a pool too small for them: some are
+    preempted, and their prompts and tokens are prefilled again.  A
+    short request arrives at the first preemption and waits behind
+    them: where the programs pack it rides a resumed stream's row.
+    Every stream is what the request gets alone over an ample pool."""
+    rows = _requests([(33, 3, 24, {}), (35, 5, 23, {}),
+                      (34, 11, 22, {"temperature": 0.8, "seed": 2}),
+                      (9, 7, 6, {})])
+    want = await _alone(family, rows)
+    eng = packing_engine(family, cache_blocks=10, steps_per_call=4)
+    orig, groups = eng._enqueue_prefill_group, []
+
+    def watched(group, slots, bucket, dest_rows=None):
+        groups.append([(int(r.prompt_ids.size),
+                        r.prefill_entry // eng._row_entries[bucket])
+                       for r in group])
+        return orig(group, slots, bucket, dest_rows)
+
+    eng._enqueue_prefill_group = watched
+
+    async def late():
+        while eng.stats()["paged"]["preemptions"] < 1:
+            await asyncio.sleep(0)
+        return (await _served(eng, rows[3:]))[0]
+
+    try:
+        got = await asyncio.wait_for(asyncio.gather(
+            _served(eng, rows[:3]), late()), timeout=300)
+        stats = eng.stats()
+    finally:
+        await eng.close()
+    assert stats["paged"]["preemptions"] >= 1
+    _same_streams(got[0] + [got[1]], want)
+    # The take the short request rode: with streams that were resumed
+    # (their prompts have grown), one of them in its row where rows pack.
+    ridden, = [group for group in groups if 9 in dict(group)]
+    beside = [size for size, row in ridden
+              if size > 35 and row == dict(ridden)[9]]
+    assert any(size > 35 for size, _ in ridden) and bool(beside) is packs

@@ -515,12 +515,13 @@ def test_the_counters_of_the_grouped_path_are_host_arithmetic():
 
 
 async def test_prefill_dispatches_count_what_the_grouped_path_visited(tiny):
-    """Three prompts admitted together pad to (4, 256): 1024 tokens, over
-    the 256 that `routed_experts` may give another path, so the dispatch
-    is counted: 2 choices x 1024 tokens x 2 layers offered, the real
-    pairs of 3 prompts visited."""
+    """Three prompts admitted together, each of more than half the
+    bucket's blocks so that none shares a row, pad to (4, 128): 512
+    tokens, over the 256 that `routed_experts` may give another path, so
+    the dispatch is counted: 2 choices x 512 tokens x 2 layers offered,
+    the real pairs of 3 prompts visited."""
     engine = engine_of(tiny, prefill_buckets=[MAX_SEQ])
-    prompts = [prompt_of(100), prompt_of(60, 5), prompt_of(31, 7)]
+    prompts = [prompt_of(100), prompt_of(90, 5), prompt_of(70, 7)]
     try:
         await asyncio.wait_for(asyncio.gather(*[
             served(engine, p, 4) for p in prompts]), timeout=600)

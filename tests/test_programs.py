@@ -424,3 +424,150 @@ async def test_a_request_that_asks_logprobs_between_greedy_waves_gets_them():
                                    np.asarray(top_lps[0]),
                                    rtol=2e-3, atol=2e-3)
         ids.append(tok)
+
+
+# -- a prefill row that carries several prompts ----------------------------------
+PACKED = {**SIZES, "prefill_buckets": [32], "block_size": 8}
+BUCKET, BS = 32, 8
+PER_ROW = BUCKET // BS
+
+
+@pytest.mark.parametrize("architecture,bucket,packs", [
+    ("decoder_tiny", 32, True), ("olmoe_tiny", 32, True),
+    ("decoder_tiny", 1024, True),
+    ("decoder_tiny", 2048, False),        # the flash kernel's, on a chip
+    ("nemotron_h_tiny", 32, False),       # a state starts once a row
+    ("mellum_tiny", 32, False),           # a ring is inserted a prompt
+    ("falcon_h1_tiny", 32, False),        # K/V and a state in one layer
+])
+def test_which_programs_pack_follows_from_the_layers_and_the_bucket(
+        architecture, bucket, packs):
+    kinds = create_model(architecture).module.config.cache_layers()
+    assert programs.packs_prompts(kinds, bucket) is packs
+
+
+def _prompt(n: int, salt: int):
+    return [(salt * 31 + 7 * j) % 250 + 1 for j in range(n)]
+
+
+def _packed_args(placed, rows: int):
+    """`prefill_fn`'s arguments after `variables` for prompts `placed`
+    as (ids, row, first block, temperature, seed)."""
+    ids = np.zeros((rows, BUCKET), np.int32)
+    segments = np.full((rows, BUCKET), -1, np.int32)
+    positions = np.zeros((rows, BUCKET), np.int32)
+    last = np.zeros((rows, PER_ROW), np.int32)
+    lengths = np.ones(rows * PER_ROW, np.int32)
+    temps = np.zeros(rows * PER_ROW, np.float32)
+    seeds = np.zeros(rows * PER_ROW, np.int32)
+    for prompt, row, block, temp, seed in placed:
+        n, start, at = len(prompt), block * BS, row * PER_ROW + block
+        assert (segments[row, start:start + n] == -1).all()
+        ids[row, start:start + n] = prompt
+        segments[row, start:start + n] = block
+        positions[row, start:start + n] = np.arange(n)
+        last[row, block] = start + n - 1
+        lengths[at], temps[at], seeds[at] = n, temp, seed
+    i32 = jnp.int32
+    return [jnp.asarray(ids), jnp.asarray(lengths), jnp.asarray(temps),
+            jnp.zeros(rows * PER_ROW, i32),
+            jnp.ones(rows * PER_ROW, jnp.float32), jnp.asarray(seeds),
+            jnp.asarray(True),
+            (jnp.asarray(segments), jnp.asarray(positions),
+             jnp.asarray(last))]
+
+
+@pytest.fixture(scope="module", params=["decoder_tiny", "olmoe_tiny"])
+def packing(request):
+    """(the prefill program of a model that packs, its variables)."""
+    module = create_model(request.param).module
+    assert module.config.dtype == jnp.float32
+    layout = programs.CacheLayout(module.config, "m", **PACKED)
+    assert programs.packs_prompts(layout.kinds, BUCKET)
+    built = programs.build(module, layout.kinds, 4, 5,
+                           jax.random.PRNGKey(9))
+    variables = module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))
+    return built.prefill, variables
+
+
+def _read(out, row: int, block: int, n: int):
+    """What a prompt of n tokens placed at (row, block) got: its first
+    token, its log-probabilities, and its K/V a layer, chunk for chunk
+    (the positions it owns: what lies behind it in its last block is
+    never read)."""
+    at, start = row * PER_ROW + block, block * BS
+    firsts, caches, chosen, top_ids, top_lps = out[:5]
+    kv = [np.asarray(x[row, start:start + n])
+          for layer in caches for x in layer]
+    assert caches[0][0].shape[:2] == (firsts.shape[0] // PER_ROW, BUCKET)
+    return (int(firsts[at]), float(chosen[at]), np.asarray(top_ids[at]),
+            np.asarray(top_lps[at]), kv)
+
+
+def _same(got, want):
+    assert got[0] == want[0]
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[2], want[2])
+    np.testing.assert_allclose(got[3], want[3], rtol=1e-5, atol=1e-5)
+    for ours, theirs in zip(got[4], want[4]):
+        np.testing.assert_allclose(ours, theirs, rtol=1e-5, atol=1e-5)
+
+
+# name -> (the prompt's tokens, its row, its first block, what lies
+# beside it as (tokens, row, first block), the program's rows)
+BESIDE = {
+    "alone": (11, 0, 0, [], 1),
+    "first-in-a-row": (11, 0, 0, [(7, 0, 2), (8, 0, 3)], 1),
+    "last-in-a-row": (11, 0, 2, [(16, 0, 0)], 1),
+    "between-two": (11, 0, 1, [(3, 0, 0), (5, 0, 3)], 1),
+    "one-block": (8, 0, 2, [(16, 0, 0), (8, 0, 3)], 1),
+    "one-block-alone": (8, 0, 0, [], 1),
+    "the-bucket": (32, 1, 0, [(9, 0, 0), (12, 0, 2)], 2),
+    "second-row": (11, 1, 2, [(32, 0, 0), (8, 1, 0), (1, 1, 1)], 2),
+}
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.9], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("case", sorted(BESIDE))
+def test_a_packed_prompt_gets_what_it_gets_alone_in_a_row(packing, case,
+                                                          temp):
+    """Whatever shares its row: the first token (a sampled one drawn by
+    the prompt's own length and seed), the chosen and the top 5
+    log-probabilities, and K/V, against the same prompt through the
+    one-prompt-a-row form of the program (`lengths`, no segments)."""
+    prefill, variables = packing
+    n, row, block, others, rows = BESIDE[case]
+    prompt = _prompt(n, 1)
+    padded = np.zeros((1, BUCKET), np.int32)
+    padded[0, :n] = prompt
+    alone = prefill(variables, jnp.asarray(padded),
+                    jnp.asarray([n], jnp.int32),
+                    jnp.asarray([temp], jnp.float32),
+                    jnp.zeros(1, jnp.int32), jnp.ones(1, jnp.float32),
+                    jnp.asarray([77], jnp.int32), jnp.asarray(True))
+    want = (int(alone[0][0]), float(alone[2][0]), np.asarray(alone[3][0]),
+            np.asarray(alone[4][0]),
+            [np.asarray(x[0, :n]) for layer in alone[1] for x in layer])
+    placed = [(prompt, row, block, temp, 77)] + [
+        (_prompt(m, 2 + i), r, b, 1.3, 5 + i)
+        for i, (m, r, b) in enumerate(others)]
+    out = prefill(variables, *_packed_args(placed, rows))
+    _same(_read(out, row, block, n), want)
+
+
+def test_unused_entries_change_nothing(packing):
+    """An entry no prompt starts at may name any column as its last
+    token: what the prompts of the row get does not move, and neither
+    does it for a padding row beside them."""
+    prefill, variables = packing
+    placed = [(_prompt(11, 1), 0, 1, 0.0, 3), (_prompt(5, 2), 0, 3, 0.7, 4)]
+    args = _packed_args(placed, 2)
+    base = prefill(variables, *args)
+    segments, positions, last = args[-1]
+    moved = last.at[0, 0].set(31).at[0, 2].set(9).at[1].set(
+        jnp.asarray([3, 30, 17, 8], jnp.int32))
+    other = prefill(variables, *args[:-1], (segments, positions, moved))
+    for prompt, row, block, _, _ in placed:
+        _same(_read(other, row, block, len(prompt)),
+              _read(base, row, block, len(prompt)))
