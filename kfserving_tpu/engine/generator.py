@@ -372,9 +372,10 @@ class GenerationEngine:
         self.window_blocks_per_slot = layout.ring_columns
         self.num_window_blocks = layout.num_window_blocks
         self._walk_chunks = layout.walk_chunks
+        self._pool_bytes = layout.pool_bytes
         # Whether a block can stand for a prompt's prefix: where not,
         # every plan is a miss, registers nothing, and is counted.
-        self._shares_prefixes = not layout.limits
+        self._shares_prefixes = layout.shares_prefixes
         obs.generator_recurrent_state_bytes().labels(
             model=name).set(self.recurrent_state_bytes)
         # Host-side paging state (guarded by _block_lock: the
@@ -387,7 +388,8 @@ class GenerationEngine:
         # shares.
         from kfserving_tpu.engine.block_pool import BlockPool
 
-        self._pool = BlockPool("global", self.num_blocks, self.max_slots,
+        self._pool = BlockPool(layout.pool_name, self.num_blocks,
+                               self.max_slots,
                                self.blocks_per_slot,
                                evicted=self._block_evicted_locked)
         self._ring = None if self._window is None else BlockPool(
@@ -395,10 +397,16 @@ class GenerationEngine:
             self.window_blocks_per_slot)
         self._pools = [pool for pool in (self._pool, self._ring)
                        if pool is not None]
-        if self._ring is not None:
+        # A model whose pools are not the one whole-context K/V pool says
+        # which pool a series counts (`pool=`).
+        self._names_pools = self._ring is not None or layout.latent
+        if self._names_pools:
             for pool in self._pools:
                 obs.generator_kv_pool_blocks().labels(
                     model=name, pool=pool.name).set(pool.blocks)
+                obs.generator_kv_pool_bytes().labels(
+                    model=name, pool=pool.name).set(
+                        self._pool_bytes[pool.name])
         # chain-hash -> block id for FULL prompt blocks (prefix
         # reuse); registered blocks that nobody holds linger in the
         # pool (LRU) until allocation pressure evicts.
@@ -1081,23 +1089,26 @@ class GenerationEngine:
                 "evictions": dict(self.block_evictions),
                 "preemptions": self.preemptions,
             }
-            if self._ring is not None:
+            if self._names_pools:
                 # Each pool's own books: capacity, the share of it that
                 # slots' tables hold, and of the rows its decode walk
                 # read the share that held context.
                 out["paged"]["pools"] = {
-                    "global": {
+                    self._pool.name: {
                         "blocks": self.num_blocks,
+                        "bytes": self._pool_bytes[self._pool.name],
                         "fill": out["paged"]["pool_occupancy_ratio"],
-                        "block_fill": out["kv_block_fill"]},
-                    "window": {
-                        "blocks": self._ring.blocks,
-                        "fill": round(
-                            self._ring.tabled() / self._ring.blocks, 4),
-                        "block_fill": self._block_fill(self._ring),
-                        "window": self._window,
-                        "blocks_per_slot": self._ring.columns,
-                        "recycled": self._ring.recycled}}
+                        "block_fill": out["kv_block_fill"]}}
+            if self._ring is not None:
+                out["paged"]["pools"]["window"] = {
+                    "blocks": self._ring.blocks,
+                    "bytes": self._pool_bytes["window"],
+                    "fill": round(
+                        self._ring.tabled() / self._ring.blocks, 4),
+                    "block_fill": self._block_fill(self._ring),
+                    "window": self._window,
+                    "blocks_per_slot": self._ring.columns,
+                    "recycled": self._ring.recycled}
         if self.kv_tier is not None:
             out["paged"]["host_tier_tokens_saved"] = \
                 self.host_tier_tokens_saved
@@ -3563,7 +3574,7 @@ class GenerationEngine:
                           int((-(-columns // chunk)).sum()))
                 self._walked[pool.name] += walked
                 counted = [(_WALKED, {})] if pool is self._pool else []
-                if self._ring is not None:
+                if self._names_pools:
                     counted.append((_WALKED_BY_POOL, {"pool": pool.name}))
                 for families, labels in counted:
                     for family, n in zip(families, walked):

@@ -33,7 +33,12 @@ from typing import Any, Dict, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from kfserving_tpu.models.decoder import BothCaches, KVCache, StateCache
+from kfserving_tpu.models.decoder import (
+    BothCaches,
+    KVCache,
+    LatentCache,
+    StateCache,
+)
 from kfserving_tpu.ops import paged_attention
 from kfserving_tpu.protocol.errors import InvalidInput
 
@@ -45,8 +50,17 @@ from kfserving_tpu.protocol.errors import InvalidInput
 # (`GenerationEngine._shares_prefixes`).  The settings each rest on
 # rewriting, re-reading or moving rows by position; a ring holds a
 # position wherever it falls, so the position sentinel that parks a row
-# has a place in a live ring and would overwrite it.
+# has a place in a live ring and would overwrite it.  A latent row IS
+# addressed by position, in one table with one free list: chunks, a verify
+# and a shared prefix read and write it as they do K/V rows (absorbed,
+# ops/paged_attention.latent_attention); what it lacks is the host tier's
+# payload.
 UNSERVED: Dict[str, Dict[str, str]] = {
+    "latent rows": {
+        "host_tier_blocks":
+            "a spilled block's payload is written and read back as a K "
+            "and a V array a layer (engine/kv_tier.py, the spill and "
+            "fault-back paths), and a latent layer keeps one array"},
     "recurrent state": {
         "speculative":
             "a rejected draft token has already moved the "
@@ -74,17 +88,19 @@ UNSERVED: Dict[str, Dict[str, str]] = {
 
 
 def parts(kind):
-    """(the `KVCache`, the `StateCache`) of one layer's declaration, None
-    for what the layer does not keep."""
+    """(the `KVCache` or `LatentCache`: what lives in a block pool; the
+    `StateCache`) of one layer's declaration, None for what the layer does
+    not keep."""
     if isinstance(kind, BothCaches):
         return kind.kv, kind.state
-    return (kind if isinstance(kind, KVCache) else None,
+    return (kind if isinstance(kind, (KVCache, LatentCache)) else None,
             kind if isinstance(kind, StateCache) else None)
 
 
 def by_part(kind, on_kv, on_state, *layers):
-    """One layer's cache with `on_kv(its KVCache, pools...)` in place of its
-    K/V part and `on_state(its StateCache, arrays...)` in place of its
+    """One layer's cache with `on_kv(its KVCache or LatentCache, pools...)`
+    in place of its K/V part (a latent layer's pools a one-tuple) and
+    `on_state(its StateCache, arrays...)` in place of its
     state; `layers` are caches of that layer of one structure (the engine's
     and a prefill's, say; none where the parts are being made), handed
     over part by part.  A layer that keeps nothing stays the first of
@@ -92,7 +108,7 @@ def by_part(kind, on_kv, on_state, *layers):
     if isinstance(kind, BothCaches):
         return (on_kv(kind.kv, *(layer[0] for layer in layers)),
                 on_state(kind.state, *(layer[1] for layer in layers)))
-    if isinstance(kind, KVCache):
+    if isinstance(kind, (KVCache, LatentCache)):
         return on_kv(kind, *layers)
     if isinstance(kind, StateCache):
         return on_state(kind, *layers)
@@ -115,7 +131,8 @@ class CacheLayout:
     (`config.cache_layers()`, models/decoder.py): K/V rows in the block
     pool, arrays of a slot's own (a recurrence's state:
     models/nemotron_h.py), both for the one layer (models/falcon_h1.py:
-    the layer's pair of them), or nothing.  Every size the engine books or
+    the layer's pair of them), latent rows in a pool of one array a layer
+    (models/deepseek_v3.py), or nothing.  Every size the engine books or
     counts comes from this declaration: the arrays (`caches`, a layer's
     pools, state or none) and the facts the host side books by are this
     object's attributes."""
@@ -127,16 +144,21 @@ class CacheLayout:
         self.kinds = kinds = list(config.cache_layers())
         kv_layers = [kv for kv, _ in map(parts, kinds) if kv is not None]
         state_layers = [st for _, st in map(parts, kinds) if st is not None]
-        geometries = {(c.heads, c.head_dim) for c in kv_layers}
+        geometries = {(type(c), c.heads, c.head_dim) for c in kv_layers}
         windows = {c.window for c in kv_layers}
         if len(geometries) != 1 or None not in windows or len(windows) > 2:
             raise InvalidInput(
                 "the engine pages K/V: a model needs at least one K/V "
                 "layer that keeps its whole context, all K/V layers of "
-                "one geometry, and its sliding-window layers of one "
+                "one geometry (or all of them latent, of one), and its "
+                "sliding-window layers of one "
                 f"window; {name!r} declares "
                 f"{sorted(set(kv_layers), key=str)}")
-        (heads, head_dim), = geometries
+        (kind_of_pool, heads, head_dim), = geometries
+        # A latent pool's block is key and value in one array.
+        self.latent = latent = kind_of_pool is LatentCache
+        arrays = 1 if latent else 2
+        self.pool_name = "latent" if latent else "global"
         self.kv_heads, self.kv_head_dim = heads, head_dim
         self.kv_layers = len(kv_layers)
         self.window_layers = sum(c.window is not None for c in kv_layers)
@@ -147,7 +169,10 @@ class CacheLayout:
         # The kinds of `UNSERVED` the model has.
         self.limits = tuple(kind for kind, has in (
             ("recurrent state", bool(state_layers)),
-            ("sliding-window layers", window is not None)) if has)
+            ("sliding-window layers", window is not None),
+            ("latent rows", latent)) if has)
+        # Whether a block can stand for a prompt's prefix.
+        self.shares_prefixes = not state_layers and window is None
         # block_size unset is derived from the lengths: 128 wherever the
         # kernels can serve.
         self.block_size = bs = (
@@ -169,8 +194,10 @@ class CacheLayout:
         # rarely needs S full-length slots at once.
         self.num_blocks = int(cache_blocks
                               or max_slots * self.blocks_per_slot)
-        self.pool_shape = paged_attention.pool_shape(
-            self.num_blocks, bs, heads, head_dim)
+        self.pool_shape = (
+            paged_attention.latent_pool_shape(self.num_blocks, bs, head_dim)
+            if latent else paged_attention.pool_shape(
+                self.num_blocks, bs, heads, head_dim))
         # The window pool: every window layer's K and V are
         # [num_window_blocks, BS, H*D], one table [slots, ring] for them
         # all.  A sequence never holds more than its ring, whatever its
@@ -191,8 +218,17 @@ class CacheLayout:
         window_pool_shape = paged_attention.pool_shape(
             self.num_window_blocks, bs, heads, head_dim)
         self.dtype = dtype = config.dtype
-        # K and V of one position, over the K/V layers.
-        self.kv_bytes_per_token = (2 * len(kv_layers) * heads * head_dim
+        # HBM of each pool's arrays over its layers, as the device holds
+        # them (a latent row in whole lane tiles), by the pool's name.
+        itemsize = jnp.dtype(dtype).itemsize
+        self.pool_bytes = {
+            self.pool_name: (arrays * (len(kv_layers) - self.window_layers)
+                             * math.prod(self.pool_shape) * itemsize),
+            "window": (arrays * self.window_layers
+                       * math.prod(window_pool_shape) * itemsize)}
+        # K and V of one position (a latent row: the numbers it holds,
+        # whatever the pool pads them to), over the K/V layers.
+        self.kv_bytes_per_token = (arrays * len(kv_layers) * heads * head_dim
                                    * jnp.dtype(dtype).itemsize)
         # Blocks of a row that one loop iteration of the paged decode
         # kernel takes, by pool (global, window): the kernel's own rule
@@ -201,13 +237,13 @@ class CacheLayout:
         shards = tp if heads % tp == 0 else 1
         self.walk_chunks = tuple(
             paged_attention.blocks_per_iteration(
-                bs, heads // shards * head_dim, dtype, columns)
+                bs, self.pool_shape[2] // shards, dtype, columns, arrays)
             for columns in (self.blocks_per_slot, self.ring_columns or 1))
 
         def pools(kind):
             shape = (self.pool_shape if kind.window is None
                      else window_pool_shape)
-            return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            return tuple(jnp.zeros(shape, dtype) for _ in range(arrays))
 
         def state(kind):
             """The slots leading ([max_slots, ...]): a slot's row is
@@ -221,8 +257,8 @@ class CacheLayout:
             return sum(int(x.size) * x.dtype.itemsize
                        for x in jax.tree.leaves(arrays))
 
-        # One layer's arrays: its two pools, its state, the pair of them,
-        # or none.
+        # One layer's arrays: its two pools (a latent layer's one), its
+        # state, the pair of them, or none.
         self.caches = [by_part(kind, pools, state) for kind in kinds]
         self.cache_bytes = nbytes(self.caches)
         # Of them a recurrence's state, in all and a slot: what admitting
@@ -272,8 +308,9 @@ def packs_prompts(cache_kinds, bucket: int) -> bool:
     context K/V and nothing else, and the bucket's attention is XLA's,
     which takes any mask.  A recurrence's state and its convolution
     would have to start again at each prompt, a ring be inserted a
-    prompt at a time, and the flash kernel know of segments: those
-    models and buckets keep one prompt a row."""
+    prompt at a time, the flash kernel know of segments, and a latent
+    layer's expanded prefill take them: those models and buckets keep
+    one prompt a row."""
     from kfserving_tpu.ops.attention import masked_prefill_takes_xla
 
     return masked_prefill_takes_xla(bucket) and all(
@@ -715,6 +752,9 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         slots whole (`slots` [B] int32, past-the-end for a padding
         row, which drops); a layer that keeps both takes both."""
         def blocks(kv, pools, new):
+            if isinstance(kv, LatentCache):
+                return (paged_attention.latent_insert(
+                    *pools, *new, dest_blocks),)
             return paged_attention.paged_insert(
                 *pools, *new, by_pool(dest_blocks, kv), None)
 

@@ -47,7 +47,7 @@ from kfserving_tpu.ops import dot_product_attention
 # (`config.cache_layers()`, one entry a layer) and the engine builds it:
 # K/V rows in the block pool, arrays of a slot's own (a recurrence's
 # state), both (a layer whose attention and recurrence run side by side),
-# or None.
+# latent rows in a block pool of their own kind, or None.
 class KVCache(NamedTuple):
     heads: int       # KV heads: fewer than the query's under GQA
     head_dim: int
@@ -56,6 +56,27 @@ class KVCache(NamedTuple):
     # sequence (ops/paged_attention.py) in a pool of its own kind.
     # None: the whole context.
     window: Optional[int] = None
+
+
+class LatentCache(NamedTuple):
+    """Latent attention (models/deepseek_v3.py): ONE row a token, the
+    normed compression (`rank`) beside the rotated key all heads share
+    (`rope_dim`), which every query head reads as its key and, over its
+    first `rank` columns, as its value.  Addressed by position as K/V rows
+    are: the engine tables, shares and frees its blocks the same way, and
+    wherever a layer's cache is handed over it is the one-tuple of what a
+    `KVCache` layer hands over as a pair.  To the code that asks a K/V
+    layer for its geometry it is one head as wide as the row that keeps
+    its whole context."""
+    rank: int
+    rope_dim: int
+
+    heads = 1
+    window = None
+
+    @property
+    def head_dim(self):
+        return self.rank + self.rope_dim
 
 
 class StateCache(NamedTuple):

@@ -42,6 +42,10 @@ _LAZY_BUILTINS: Dict[str, Tuple[str, str]] = {
     "falcon_h1": ("kfserving_tpu.models.falcon_h1", "_create_falcon_h1"),
     "falcon_h1_tiny": ("kfserving_tpu.models.falcon_h1",
                        "_create_falcon_h1_tiny"),
+    "deepseek_v3": ("kfserving_tpu.models.deepseek_v3",
+                    "_create_deepseek_v3"),
+    "deepseek_v3_tiny": ("kfserving_tpu.models.deepseek_v3",
+                         "_create_deepseek_v3_tiny"),
 }
 
 

@@ -486,15 +486,24 @@ def generator_kv_pool_blocks():
     return REGISTRY.gauge(
         "kfserving_tpu_generator_kv_pool_blocks",
         "Capacity of each KV pool of a model with sliding-window "
-        "layers, in blocks a layer (pool=global|window)")
+        "layers or latent ones, in blocks a layer "
+        "(pool=global|window|latent)")
+
+
+def generator_kv_pool_bytes():
+    return REGISTRY.gauge(
+        "kfserving_tpu_generator_kv_pool_bytes",
+        "HBM of each pool's arrays over its layers, as the device holds "
+        "them (pool=global|window|latent; a latent row in whole lane "
+        "tiles)")
 
 
 def generator_kv_pool_fill_ratio():
     return REGISTRY.gauge(
         "kfserving_tpu_generator_kv_pool_fill_ratio",
         "Blocks of each KV pool that a slot's table holds, over the "
-        "pool's capacity (pool=global|window; the global pool's is "
-        "pool_occupancy_ratio again)")
+        "pool's capacity (pool=global|window|latent; the global and the "
+        "latent pool's is pool_occupancy_ratio again)")
 
 
 def generator_moe_routed_pairs_total():

@@ -110,14 +110,15 @@ _ITERATION_BLOCKS = 4
 
 
 def blocks_per_iteration(block_size: int, pool_width: int, dtype,
-                         table_width: int) -> int:
+                         table_width: int, copies: int = 2) -> int:
     """Consecutive blocks of a row that one loop iteration of
     `paged_attention_tpu` takes: as many as bring its K and V copies to
     `_ITERATION_BYTES`, at least 1 and at most `_ITERATION_BLOCKS` or a
     table's columns.  `pool_width` is the H*D the kernel sees (one
     shard's under a mesh).  In bfloat16 with blocks of 128: 1 for pools
-    1280 and 2048 wide, 4 for 512 and 256."""
-    pair = 2 * block_size * pool_width * jnp.dtype(dtype).itemsize
+    1280 and 2048 wide, 4 for 512 and 256.  A latent pool's block is
+    key and value in one copy (`copies` 1): 4 at 576 wide."""
+    pair = copies * block_size * pool_width * jnp.dtype(dtype).itemsize
     return max(1, min(_ITERATION_BYTES // pair, _ITERATION_BLOCKS,
                       table_width))
 
@@ -220,9 +221,18 @@ def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
     [H_pad, n * BS] from one product against the whole slot, and one
     max / exp / sum and one rescale of the accumulator serve them all.
     Rows of a slot that no copy of this chunk wrote lie past every
-    position the mask lets through."""
+    position the mask lets through.
+    A LATENT pool (`pool_v` and `v_blocks` None: `latent_attention_tpu`)
+    has one row a token that every query head reads whole, as the key
+    over all its columns and as the value over its first
+    `acc_scratch.shape[1]`: a block is copied once and used twice, the
+    query comes [H_pad, width] as it is multiplied (no block diagonal:
+    there is one head of keys), and the answer leaves [H_pad, rank]."""
     count = count_ref[0]
     h_pad, hd = acc_scratch.shape
+    latent = pool_v is None
+    pools = (((pool_k, k_blocks),) if latent
+             else ((pool_k, k_blocks), (pool_v, v_blocks)))
 
     def own_columns():
         # [h_pad, hd] bool: column c belongs to (the KV head of) head r.
@@ -248,8 +258,7 @@ def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
             def _copies(j=j):
                 block = table_ref[at + j]
                 rows = pl.ds(j * block_size, block_size)
-                for s, (pool, slots) in enumerate(
-                        ((pool_k, k_blocks), (pool_v, v_blocks))):
+                for s, (pool, slots) in enumerate(pools):
                     do(pltpu.make_async_copy(
                         pool.at[block], slots.at[slot, rows],
                         sems.at[s, slot]))
@@ -273,15 +282,18 @@ def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
 
         @pl.when(column == 0)
         def _init():
-            # Select in float32 and cast: Mosaic refuses the relayout of
-            # a 16-bit select here.
-            q = q_ref[row].astype(jnp.float32)
-            if group == 1:
-                q = jnp.broadcast_to(q, (h_pad, hd))
-            else:  # [h_pad, D], repeated under every KV head's columns
-                q = jnp.concatenate([q] * (hd // head_dim), axis=1)
-            q_scratch[...] = jnp.where(own_columns(), q,
-                                       0.0).astype(q_scratch.dtype)
+            if latent:
+                q_scratch[...] = q_ref[row].astype(q_scratch.dtype)
+            else:
+                # Select in float32 and cast: Mosaic refuses the relayout
+                # of a 16-bit select here.
+                q = q_ref[row].astype(jnp.float32)
+                if group == 1:
+                    q = jnp.broadcast_to(q, (h_pad, hd))
+                else:  # [h_pad, D], repeated under every KV head's columns
+                    q = jnp.concatenate([q] * (hd // head_dim), axis=1)
+                q_scratch[...] = jnp.where(own_columns(), q,
+                                           0.0).astype(q_scratch.dtype)
             m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
             l_scratch[...] = jnp.zeros_like(l_scratch)
             acc_scratch[...] = jnp.zeros_like(acc_scratch)
@@ -318,7 +330,8 @@ def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
         alpha = jnp.exp(m_prev - m_new)                   # [h_pad, 1]
         l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
             p, axis=1, keepdims=True)
-        v = v_blocks[slot]                                # [n * bs, hd]
+        # [n * bs, hd]; a latent row's first hd columns
+        v = k_blocks[slot][:, :hd] if latent else v_blocks[slot]
         pv = jnp.dot(p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)  # [h_pad, hd]
         acc_scratch[...] = acc_scratch[...] * alpha + pv
@@ -331,12 +344,13 @@ def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
         @pl.when((i + 1 == count) | (after % table_width == 0))
         def _finalize():
             out = acc_scratch[...] / jnp.maximum(l_scratch[...], 1e-30)
-            out = jnp.where(own_columns(), out, 0.0)
-            if group == 1:
-                out = jnp.sum(out, axis=0, keepdims=True)
-            else:
-                out = sum(out[:, g * head_dim:(g + 1) * head_dim]
-                          for g in range(hd // head_dim))
+            if not latent:
+                out = jnp.where(own_columns(), out, 0.0)
+                if group == 1:
+                    out = jnp.sum(out, axis=0, keepdims=True)
+                else:
+                    out = sum(out[:, g * head_dim:(g + 1) * head_dim]
+                              for g in range(hd // head_dim))
             o_ref[row] = out.astype(o_ref.dtype)
 
     o_ref[...] = jnp.zeros_like(o_ref)
@@ -344,7 +358,8 @@ def _paged_kernel(walked_ref, pairs_ref, count_ref, table_ref, len_ref,
         # p is 0 on the rows of a slot that no copy of the pair wrote,
         # and 0 x NaN is NaN in p . V: what lies there must be finite,
         # and what VMEM holds before its first write need not be.
-        v_blocks[...] = jnp.zeros_like(v_blocks)
+        values = k_blocks if latent else v_blocks
+        values[...] = jnp.zeros_like(values)
     for i in range(_BUFFERS - 1):
         fetch(i)
     jax.lax.fori_loop(0, count, pair, None)
@@ -419,24 +434,90 @@ def paged_attention_tpu(q, pool_k, pool_v, block_table, lengths,
     return out.reshape(b, 1, h, d)
 
 
-def _write_kernel(blk_ref, off_ref, k_ref, v_ref, pool_k_in, pool_v_in,
-                  pool_k, pool_v, k_tiles, v_tiles, sems, *, rows: int,
-                  sublanes: int):
+def _latent_kernel(*refs, **static):
+    """`_paged_kernel` on a latent pool: the refs it is handed have no
+    second pool and no second set of blocks."""
+    *before, o_ref, blocks, sems, q_scratch, m, l, acc = refs
+    _paged_kernel(*before, None, o_ref, blocks, None, sems, q_scratch, m,
+                  l, acc, **static)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def latent_attention_tpu(q, pool, block_table, lengths, *, rank: int,
+                         scale: float, interpret: bool = False):
+    """Pallas decode attention over a latent pool [NB, BS, W]
+    (models/deepseek_v3.py): q [B, 1, H, W] is every head's absorbed
+    query, a pool row the key of them all over its W columns and their
+    value over its first `rank`; `scale` multiplies the scores (the
+    published head's 1/sqrt, not W's).  Returns [B, 1, H, rank]; a row
+    that walks no block comes back as zeros.  `paged_attention_tpu`'s
+    walk and loop, each block copied once."""
+    b, lq, h, w = q.shape
+    nb, bs, width = pool.shape
+    assert lq == 1 and w == width and rank <= width, (q.shape, pool.shape)
+    mb = block_table.shape[1]
+    lengths = lengths.astype(jnp.int32)
+    chunk = blocks_per_iteration(bs, width, pool.dtype, mb, copies=1)
+    pairs, count = paged_walk(block_table, lengths, bs, None, chunk)
+    scalars = (pairs, count, block_table.reshape(-1), lengths)
+    if chunk > 1:
+        scalars = (_blocks_walked(block_table, lengths, bs)
+                   .astype(jnp.int32),) + scalars
+    compute = jnp.promote_types(q.dtype, pool.dtype)
+    h_pad = -(-h // _sublanes(compute)) * _sublanes(compute)
+    q = q.reshape(b, h, w)
+    if h_pad > h:
+        q = jnp.pad(q, ((0, 0), (0, h_pad - h), (0, 0)))
+
+    def rows(width):
+        return pl.BlockSpec((b, h_pad, width), lambda i, *_: (0, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(1,),
+        in_specs=[rows(w), pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=rows(rank),
+        scratch_shapes=[
+            pltpu.VMEM((_BUFFERS, chunk * bs, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, _BUFFERS)),
+            pltpu.VMEM((h_pad, w), compute),
+            pltpu.VMEM((h_pad, 1), jnp.float32),
+            pltpu.VMEM((h_pad, 1), jnp.float32),
+            pltpu.VMEM((h_pad, rank), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_latent_kernel, block_size=bs,
+                               table_width=mb, scale=scale, head_dim=w,
+                               group=h, chunk=chunk)
+    if chunk == 1:  # every pair is one block: no `walked_ref`
+        kernel = functools.partial(kernel, None)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h_pad, rank), q.dtype),
+        interpret=interpret,
+    )(*scalars, q, pool)
+    return out[:, :h].reshape(b, 1, h, rank)
+
+
+def _write_kernel(blk_ref, off_ref, *refs, rows: int, sublanes: int):
     """A decode step's rows into the pools, in place (the outputs alias
-    the inputs, all four in HBM): row r's sublane tile, the `sublanes`
+    the inputs, all in HBM): row r's sublane tile, the `sublanes`
     positions of its block around its offset, comes into VMEM, takes
     the row, and goes back.  A 16-bit pool packs two positions into
     each word, so one position is not something a DMA can address: the
     tile is.  Rows to drop (block -1) move nothing.  One program, every
-    copy in flight at once."""
-    del pool_k_in, pool_v_in  # the same buffers as pool_k, pool_v
+    copy in flight at once.  `refs`: the step's rows a pool (K and V, or
+    a latent pool's one), the pools in (the same buffers as) and out, a
+    pool's tiles, the semaphores."""
+    n = len(refs) // 4
+    steps, pools, tiles_of, sems = (refs[:n], refs[2 * n:3 * n],
+                                    refs[3 * n:4 * n], refs[-1])
 
     def copies(r, to_pool: bool):
         start = pl.multiple_of(
             (off_ref[r] // sublanes) * sublanes, sublanes)
         out = []
-        for i, (pool, tiles) in enumerate(((pool_k, k_tiles),
-                                           (pool_v, v_tiles))):
+        for i, (pool, tiles) in enumerate(zip(pools, tiles_of)):
             hbm = pool.at[blk_ref[r], pl.ds(start, sublanes), :]
             src, dst = (tiles.at[r], hbm) if to_pool else (hbm,
                                                            tiles.at[r])
@@ -456,9 +537,9 @@ def _write_kernel(blk_ref, off_ref, k_ref, v_ref, pool_k_in, pool_v_in,
     def merge(r):
         for copy in copies(r, to_pool=False):
             copy.wait()
-        at = jax.lax.broadcasted_iota(jnp.int32, k_tiles.shape[1:], 0) \
-            == off_ref[r] % sublanes
-        for tiles, step in ((k_tiles, k_ref), (v_tiles, v_ref)):
+        at = jax.lax.broadcasted_iota(jnp.int32, tiles_of[0].shape[1:],
+                                      0) == off_ref[r] % sublanes
+        for tiles, step in zip(tiles_of, steps):
             # Select in float32 (a 16-bit select asks Mosaic for a
             # relayout it refuses); the round trip is exact.
             row = jnp.broadcast_to(step[r].astype(jnp.float32),
@@ -477,6 +558,32 @@ def _write_kernel(blk_ref, off_ref, k_ref, v_ref, pool_k_in, pool_v_in,
     each_live_row(land)
 
 
+def _write_rows(pools, steps, blocks, offsets, interpret: bool):
+    """`_write_kernel` on `pools` of one shape and dtype, a [B, width]
+    array of `steps` each; returns the written pools."""
+    n = len(pools)
+    rows, hd = steps[0].shape
+    sublanes = _sublanes(pools[0].dtype)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    step = pl.BlockSpec((rows, 1, hd), lambda i, blk, off: (0, 0, 0))
+    tiles = pltpu.VMEM((rows, sublanes, hd), pools[0].dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(1,),
+        in_specs=[step] * n + [hbm] * n, out_specs=[hbm] * n,
+        scratch_shapes=[tiles] * n + [pltpu.SemaphoreType.DMA((n, rows))])
+    kernel = functools.partial(_write_kernel, rows=rows,
+                               sublanes=sublanes)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype)
+                   for pool in pools],
+        input_output_aliases={2 + n + i: i for i in range(n)},
+        interpret=interpret,
+    )(blocks.astype(jnp.int32), offsets.astype(jnp.int32),
+      *(x.astype(pool.dtype).reshape(rows, 1, hd)
+        for x, pool in zip(steps, pools)), *pools)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_write_tpu(pool_k, pool_v, k_step, v_step, blocks, offsets,
                     interpret: bool = False):
@@ -487,26 +594,14 @@ def paged_write_tpu(pool_k, pool_v, k_step, v_step, blocks, offsets,
     write them where they are.  Left to XLA, a scatter into a pool that
     fits VMEM (gpt2-large's 47 MB) has the whole pool prefetched there
     and copied back every step."""
-    rows, hd = k_step.shape
-    sublanes = _sublanes(pool_k.dtype)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    step = pl.BlockSpec((rows, 1, hd), lambda i, blk, off: (0, 0, 0))
-    tiles = pltpu.VMEM((rows, sublanes, hd), pool_k.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(1,),
-        in_specs=[step, step, hbm, hbm], out_specs=[hbm, hbm],
-        scratch_shapes=[tiles, tiles,
-                        pltpu.SemaphoreType.DMA((2, rows))])
-    kernel = functools.partial(_write_kernel, rows=rows,
-                               sublanes=sublanes)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
-                   jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype)],
-        input_output_aliases={4: 0, 5: 1}, interpret=interpret,
-    )(blocks.astype(jnp.int32), offsets.astype(jnp.int32),
-      k_step.astype(pool_k.dtype).reshape(rows, 1, hd),
-      v_step.astype(pool_v.dtype).reshape(rows, 1, hd), pool_k, pool_v)
+    return _write_rows((pool_k, pool_v), (k_step, v_step), blocks, offsets,
+                       interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def latent_write_tpu(pool, step, blocks, offsets, interpret: bool = False):
+    """`paged_write_tpu` for a latent pool: one row [B, W] a step."""
+    return _write_rows((pool,), (step,), blocks, offsets, interpret)[0]
 
 
 def _kernels_serve(block_size: int, heads: int, head_dim: int,
@@ -642,6 +737,24 @@ def paged_attention_xla(q, pool_k, pool_v, block_table, lengths,
     return _masked_attention(q, k, v, mask[:, None, None, :])
 
 
+def _write_targets(block_table, positions, block_size: int, window=None):
+    """(block, offset within it, whether the write drops) of `positions`
+    [B] or [B, L] through `block_table`, by `paged_write`'s rules."""
+    mb = block_table.shape[1]
+    block_idx = positions // block_size
+    offs = positions % block_size
+    rows = jnp.arange(block_table.shape[0])
+    if positions.ndim == 2:
+        rows = rows[:, None]
+    if window is None:
+        blocks = block_table[rows, jnp.minimum(block_idx, mb - 1)]
+        dropped = (blocks < 0) | (block_idx >= mb)
+    else:
+        blocks = block_table[rows, block_idx % mb]
+        dropped = blocks < 0
+    return blocks, offs, dropped
+
+
 def paged_write(pool_k, pool_v, k_step, v_step, block_table,
                 positions, window=None):
     """Scatter a step's k/v into the pools at each slot's positions.
@@ -669,19 +782,9 @@ def paged_write(pool_k, pool_v, k_step, v_step, block_table,
     column (p // BS) % MB, no position is past the table, and a row
     that holds no request is dropped by its table's -1s alone."""
     bs = pool_k.shape[1]
-    mb = block_table.shape[1]
     chunked = positions.ndim == 2
-    block_idx = positions // bs
-    offs = positions % bs
-    rows = jnp.arange(block_table.shape[0])
-    if chunked:
-        rows = rows[:, None]
-    if window is None:
-        blocks = block_table[rows, jnp.minimum(block_idx, mb - 1)]
-        dropped = (blocks < 0) | (block_idx >= mb)
-    else:
-        blocks = block_table[rows, block_idx % mb]
-        dropped = blocks < 0
+    blocks, offs, dropped = _write_targets(block_table, positions, bs,
+                                           window)
     if not chunked and _kernels_serve(bs, *k_step.shape[1:]):
         return paged_write_sharded(pool_k, pool_v, k_step, v_step,
                                    jnp.where(dropped, -1, blocks), offs)
@@ -750,3 +853,98 @@ def paged_insert(pool_k, pool_v, k_new, v_new, dest_blocks, lengths):
     pool_v = pool_v.at[flat_dest].set(
         v_new.reshape(blocks).astype(pool_v.dtype), mode="drop")
     return pool_k, pool_v
+
+
+# -- a latent pool: one row a token, key and value at once -------------------
+# Latent attention (models/deepseek_v3.py) keeps, a token a layer, the
+# normed compression c [rank] and the rotated shared key k_pe side by side,
+# W = rank + rope columns: pool [NB, BS, W], tabled, walked, written and
+# inserted by position as a K/V pool is.  Every query head reads the whole
+# row as its key (the absorbed query is W wide) and the row's first `rank`
+# columns as its value.  The pool's rows are whole lane tiles, the columns
+# past W zeros (576 stored as 640): the chip's own layout of a [.., 576]
+# bfloat16 array is 640 wide in HBM whatever is declared, and a Mosaic
+# copy takes whole tiles of it alone ("Slice shape along dimension 2 must
+# be aligned to tiling (128), but is 576"), so the padding costs no byte
+# that a pool declared 576 wide would not.
+
+
+def latent_pool_shape(num_blocks: int, block_size: int, width: int):
+    """Shape of one latent layer's pool for rows of `width` columns."""
+    return (num_blocks, block_size, -(-width // 128) * 128)
+
+
+def _pool_wide(x, pool):
+    """x [.., W] with zeros up to the pool's columns."""
+    pad = pool.shape[-1] - x.shape[-1]
+    return x if pad == 0 else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _latent_kernels_serve(block_size: int) -> bool:
+    """The Pallas kernels on a latent pool: a TPU, no mesh (one row is
+    all heads', so there is nothing of it to shard on heads), blocks of
+    whole lane tiles."""
+    return (attention._tpu_backend() and block_size % 128 == 0
+            and jax.sharding.get_abstract_mesh().empty)
+
+
+def latent_write(pool, rows, block_table, positions):
+    """`paged_write` for a latent pool: rows [B, W] at positions [B] (a
+    decode step) or [B, L, W] at [B, L] (a chunk, a verify)."""
+    blocks, offs, dropped = _write_targets(block_table, positions,
+                                           pool.shape[1])
+    rows = _pool_wide(rows, pool)
+    if positions.ndim == 1 and _latent_kernels_serve(pool.shape[1]):
+        return latent_write_tpu(pool, rows, jnp.where(dropped, -1, blocks),
+                                offs)
+    blocks = jnp.where(dropped, pool.shape[0], blocks)
+    return pool.at[blocks, offs].set(rows.astype(pool.dtype), mode="drop")
+
+
+def latent_insert(pool, new, dest_blocks):
+    """`paged_insert` for a latent pool: a prefill's rows [B, L, W]."""
+    b, l = new.shape[:2]
+    chunks = l // pool.shape[1]
+    assert chunks * pool.shape[1] == l, "prefill bucket must be block-aligned"
+    new = _pool_wide(new, pool)
+    dest = jnp.where(dest_blocks < 0, pool.shape[0], dest_blocks)
+    return pool.at[dest.reshape(b * chunks)].set(
+        new.reshape((b * chunks,) + pool.shape[1:]).astype(pool.dtype),
+        mode="drop")
+
+
+def latent_attention_xla(q, pool, block_table, q_positions, rank: int,
+                         scale: float):
+    """q [B, Lq, H, W] at absolute `q_positions` [B, Lq] over the
+    gathered rows of each sequence, the query at p seeing the rows at
+    positions <= p (its own is written): float32, [B, Lq, H, rank]."""
+    b, mb = block_table.shape
+    rows = pool[jnp.maximum(block_table, 0)].reshape(
+        b, mb * pool.shape[1], -1).astype(jnp.float32)
+    scores = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32),
+                        rows) * scale
+    seen = (jnp.arange(rows.shape[1])[None, None, :]
+            <= q_positions[:, :, None])                     # [B, Lq, K]
+    scores = jnp.where(seen[:, None], scores, jnp.finfo(jnp.float32).min)
+    out = jnp.einsum("bhqk,bkr->bqhr", jax.nn.softmax(scores, axis=-1),
+                     rows[..., :rank])
+    return out.astype(q.dtype)
+
+
+def latent_attention(q, pool, block_table, q_positions, *, rank: int,
+                     scale: float):
+    """Dispatcher, at trace time: the Pallas kernel for a decode step
+    where `_latent_kernels_serve`, XLA's gather otherwise (the CPU; Lq >
+    1: a chunk or a verify against the pool)."""
+    use_kernel = q.shape[1] == 1 and _latent_kernels_serve(pool.shape[1])
+    q = _pool_wide(q, pool)
+    attention.log_dispatch(
+        "pallas_latent" if use_kernel else "xla_latent", q=q.shape,
+        pool=pool.shape, table=block_table.shape)
+    if use_kernel:
+        return latent_attention_tpu(q, pool, block_table,
+                                    q_positions[:, 0] + 1, rank=rank,
+                                    scale=scale)
+    return latent_attention_xla(q, pool, block_table, q_positions, rank,
+                                scale)

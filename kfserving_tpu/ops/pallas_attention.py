@@ -9,8 +9,11 @@ sequence lengths BASELINE.json config #3 targets the score tensor is what
 turns attention HBM-bandwidth-bound.
 
 Layout contract matches kfserving_tpu.ops.attention: [B, L, H, D] in, same
-out.  D must be a multiple of 64 (64 pads the 128-lane width but measured
-34 TF/s on v5e; attention.py gates eligibility); L needs a power-of-two
+out; the values may have a width of their own, [B, L, H, Dv], which is then
+the output's (latent attention's expanded prefill: keys of 192, values of
+128, scores scaled by the keys' width).  D must be a multiple of 64 (64
+pads the 128-lane width but measured 34 TF/s on v5e; attention.py gates
+eligibility); L needs a power-of-two
 block divisor >= 8 — block sizes adapt downward (512/256/.../8) to divide
 any such L, so every legal seq bucket keeps the flash path (128-multiples
 get full-width blocks; smaller divisors trade MXU efficiency for
@@ -165,7 +168,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     assert window is None or causal, "a window is a causal band"
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[3]
     # Blocks shrink to the largest power-of-two divisor <= the requested
     # size, so L=640 runs with 128-blocks instead of losing the kernel.
     block_q = _fit_block(block_q, Lq)
@@ -180,7 +183,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # per program keeps BlockSpecs 3-D and index maps trivial.
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, Dv)
 
     num_k = Lk // block_k
     if window is not None:
@@ -206,9 +209,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     scratch_shapes = [
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q, Dv), jnp.float32),
     ]
-    out_shape = jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype)
+    out_shape = jax.ShapeDtypeStruct((B * H, Lq, Dv), q.dtype)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -234,10 +237,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 pl.BlockSpec((1, block_q, D),
                              lambda bh, i, j, lens: (bh, i, 0)),
                 pl.BlockSpec((1, block_k, D), kv_index),
-                pl.BlockSpec((1, block_k, D), kv_index),
+                pl.BlockSpec((1, block_k, Dv), kv_index),
             ],
             out_specs=pl.BlockSpec(
-                (1, block_q, D), lambda bh, i, j, lens: (bh, i, 0)),
+                (1, block_q, Dv), lambda bh, i, j, lens: (bh, i, 0)),
             scratch_shapes=scratch_shapes,
         )
         out = pl.pallas_call(
@@ -254,12 +257,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, block_k, D), kv_block),
-                pl.BlockSpec((1, block_k, D), kv_block),
+                pl.BlockSpec((1, block_k, Dv), kv_block),
             ],
-            out_specs=pl.BlockSpec((1, block_q, D),
+            out_specs=pl.BlockSpec((1, block_q, Dv),
                                    lambda bh, i, j: (bh, i, 0)),
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
             compiler_params=params,
         )(qt, kt, vt)
-    return out.reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)

@@ -33,9 +33,26 @@ config.json schema:
                                                     #   renormalised
                                                     #   experts (models/
                                                     #   mellum.py)
+                    | "falcon_h1" | "falcon_h1_tiny"  # attention and
+                                                    #   Mamba-2 side by
+                                                    #   side in a layer
+                                                    #   (models/
+                                                    #   falcon_h1.py)
+                    | "deepseek_v3" | "deepseek_v3_tiny"  # latent
+                                                    #   attention: one
+                                                    #   compressed row a
+                                                    #   token a layer in
+                                                    #   a pool of its
+                                                    #   own kind, sigmoid
+                                                    #   experts + a
+                                                    #   shared one
+                                                    #   (models/
+                                                    #   deepseek_v3.py;
+                                                    #   refuses
+                                                    #   host_tier_blocks)
                     | <registered>,                 # all: same engine,
-                                                    #   pool and decode
-                                                    #   kernel
+                                                    #   pools and decode
+                                                    #   kernels
       "arch_kwargs": {...},
       "max_slots": 8,              # continuous-batching slot count
       "max_seq": 512,              # KV-cache capacity per slot
@@ -444,6 +461,7 @@ class GenerativeConfig:
                  speculative: Optional[Dict[str, Any]] = None,
                  prefill_rows: Optional[int] = None,
                  ignore_eos: bool = False,
+                 exit_with_parent: bool = False,
                  mesh: Optional[Dict[str, int]] = None,
                  **_ignored):
         self.architecture = architecture
@@ -501,6 +519,9 @@ class GenerativeConfig:
         # the tokenizer's EOS id is a row of the vocabulary like any
         # other and a load test asks for answers of given lengths.
         self.ignore_eos = bool(ignore_eos)
+        # The server ends when the process that started it does, however
+        # that one ended (`startup.exit_with_parent`).
+        self.exit_with_parent = bool(exit_with_parent)
         self.mesh = mesh or {}
 
     @classmethod
@@ -554,6 +575,8 @@ class GenerativeModel(Model):
                 os.path.join(local, "config.json"),
                 overrides=self.config_overrides)
             self.config = cfg
+        if cfg.exit_with_parent:
+            startup.exit_with_parent()
         self.tokenizer = build_tokenizer(cfg.tokenizer)
 
         spec = create_model(cfg.architecture, **cfg.arch_kwargs)

@@ -116,3 +116,21 @@ def device() -> Optional[Dict[str, Any]]:
                            for d in devices],
             "hbm_peak": [device_hbm_stat("peak_bytes_in_use", d)
                          for d in devices]}
+
+
+def exit_with_parent() -> None:
+    """From here on this process is sent SIGTERM when the process that
+    started it ends, however that one ended (Linux's PR_SET_PDEATHSIG; a
+    no-op elsewhere): for a replica under a harness or a supervisor that
+    does not reap its children, where a server that outlives a killed
+    parent keeps the chip.  A parent that is gone already ends it now."""
+    import ctypes
+    import signal
+
+    try:
+        libc = ctypes.CDLL("libc.so.6", use_errno=True)
+    except OSError:
+        return
+    libc.prctl(1, signal.SIGTERM)  # PR_SET_PDEATHSIG
+    if os.getppid() == 1:
+        os.kill(os.getpid(), signal.SIGTERM)

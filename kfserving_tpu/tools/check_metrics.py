@@ -204,7 +204,7 @@ async def smoke() -> List[str]:
         model="metrics-probe").inc(61000)
     obs.generator_decode_kv_walk_iterations_total().labels(
         model="metrics-probe").inc(200)
-    for pool in ("global", "window"):
+    for pool in ("global", "window", "latent"):
         obs.generator_decode_kv_pool_walk_iterations_total().labels(
             model="metrics-probe", pool=pool).inc(180)
         obs.generator_decode_kv_pool_blocks_walked_total().labels(
@@ -215,6 +215,8 @@ async def smoke() -> List[str]:
             model="metrics-probe", pool=pool).set(576)
         obs.generator_kv_pool_fill_ratio().labels(
             model="metrics-probe", pool=pool).set(0.8)
+        obs.generator_kv_pool_bytes().labels(
+            model="metrics-probe", pool=pool).set(4.2e9)
     obs.generator_window_blocks_recycled_total().labels(
         model="metrics-probe").inc(12)
     for program in ("decode", "prefill"):

@@ -938,6 +938,58 @@ def test_falcon_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
     assert _program_bytes(memory) + 2.83e9 < 15.75 * 2**30, memory
 
 
+def _moonlight_serving() -> dict:
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "moonlight-16b-a3b-7l.json")) as f:
+        return json.load(f)["serving"]
+
+
+def test_moonlight_decode_program_fits_the_described_v5e(v5e, monkeypatch):
+    """The 16-step decode program of `moonlight-16b-a3b-7l` (128 slots,
+    4,096 blocks of 128 latent rows): its arguments are the parameters
+    (8.53 GB) and seven latent pools, one array a layer, 576 numbers a
+    row held 640 wide (4.70 GB); every layer reads its pool through the
+    latent kernel, which copies a block once (one pool operand, one set
+    of blocks), and writes it through the one-row write; no pool is
+    copied."""
+    compiled, pool, shapes = _decode_program(v5e, monkeypatch,
+                                             _moonlight_serving())
+    assert pool == (4096, 128, 640)
+    assert len(_mosaic_calls(compiled, "latent_attention_tpu")) == 7
+    assert len(_mosaic_calls(compiled, "latent_write_tpu")) == 7
+    assert _mosaic_calls(compiled, "paged_attention_tpu") == []
+    assert _pool_copies(compiled, pool) == []
+    kernel = next(line for line in compiled.as_text().splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line
+                  and "latent_attention_tpu" in line.split("=")[0])
+    assert kernel.count("bf16[4096,128,640]") == 1, kernel[:400]
+    stored = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    assert 8.52e9 < stored < 8.54e9                 # 4.263 B, bfloat16
+    memory = compiled.memory_analysis()
+    print(f"moonlight-16b-a3b-7l decode program: {memory}")
+    assert 13.2e9 < memory.argument_size_in_bytes < 13.3e9
+    assert memory.temp_size_in_bytes < 0.5e9, memory
+    assert _program_bytes(memory) < 15.75 * 2**30, memory
+    _assert_the_tail_is_lean(compiled, 128, 163840, "lm_head/dot_general")
+
+
+def test_moonlight_prefill_program_fits_the_described_v5e(v5e, monkeypatch):
+    """Its (1, 6144) prefill, the largest (`prefill_rows` 1): expanded
+    attention with keys of 192 and values of 128 through the flash
+    kernel, the grouped experts; parameters, temporaries and outputs
+    (seven layers' latent rows) fit beside the 4.70 GB of pools that the
+    program does not see."""
+    serving = _moonlight_serving()
+    assert serving["prefill_rows"] == 1
+    assert max(serving["prefill_buckets"]) == 6144
+    compiled = _prefill_program(v5e, monkeypatch, serving, 1)
+    assert len(_mosaic_calls(compiled, "flash_attention")) == 7
+    memory = compiled.memory_analysis()
+    print(f"moonlight-16b-a3b-7l (1, 6144) prefill program: {memory}")
+    assert 8.52e9 < memory.argument_size_in_bytes < 8.54e9
+    assert _program_bytes(memory) + 4.70e9 < 15.75 * 2**30, memory
+
+
 # -- the sampler's tail, as the chip's compiler leaves it -----------------------
 _CALLED = re.compile(
     r"(?:calls|to_apply|body|condition)=%([\w.\-]+)"

@@ -1125,3 +1125,55 @@ async def test_params_resident_bytes_on_metrics_and_startup_phases(
         assert float(value) == want
     finally:
         await server.stop_async()
+
+
+def test_exit_with_parent_ends_a_server_whose_parent_was_killed(tmp_path):
+    """`"exit_with_parent": true` in the model's config
+    (`startup.exit_with_parent`): a parent that is killed outright, with
+    no chance to stop what it started, takes the child with it; without
+    the call the child lives on."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    import time
+
+    assert GenerativeConfig("decoder_tiny").exit_with_parent is False
+    assert GenerativeConfig(
+        "decoder_tiny", exit_with_parent=True).exit_with_parent is True
+    child = ("import sys, time; from kfserving_tpu import startup; "
+             "sys.argv[1] == 'tied' and startup.exit_with_parent(); "
+             "print('up', flush=True); time.sleep(120)")
+    parent = ("import subprocess, sys, time; "
+              f"p = subprocess.Popen([sys.executable, '-c', {child!r}, "
+              "sys.argv[1]], stdout=subprocess.PIPE); p.stdout.readline(); "
+              "print(p.pid, flush=True); time.sleep(120)")
+    alive = {}
+    for mode in ("tied", "free"):
+        proc = subprocess.Popen([sys.executable, "-c", parent, mode],
+                                stdout=subprocess.PIPE, text=True,
+                                cwd=os.path.dirname(os.path.dirname(
+                                    os.path.abspath(__file__))))
+        pid = int(proc.stdout.readline())
+        proc.kill()
+        proc.wait()
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            try:
+                os.kill(pid, 0)
+            except ProcessLookupError:
+                break
+            with open(f"/proc/{pid}/stat") as f:
+                if f.read().split(")")[1].split()[0] == "Z":
+                    break
+            if mode == "free":
+                break
+            time.sleep(0.1)
+        try:
+            os.kill(pid, 0)
+            with open(f"/proc/{pid}/stat") as f:
+                alive[mode] = f.read().split(")")[1].split()[0] != "Z"
+            os.kill(pid, signal.SIGKILL)
+        except (ProcessLookupError, FileNotFoundError):
+            alive[mode] = False
+    assert alive == {"tied": False, "free": True}

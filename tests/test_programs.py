@@ -131,7 +131,8 @@ def test_the_layout_books_both_pools_of_a_window_model():
     for setting in settings])
 def test_refusal_names_the_setting_the_model_and_the_kind(kind, setting):
     architecture = {"recurrent state": "nemotron_h_tiny",
-                    "sliding-window layers": "mellum_tiny"}[kind]
+                    "sliding-window layers": "mellum_tiny",
+                    "latent rows": "deepseek_v3_tiny"}[kind]
     module = create_model(architecture).module
     layout = programs.CacheLayout(module.config, "m", **SIZES)
     off = dict.fromkeys(programs.UNSERVED[kind], False)
