@@ -26,7 +26,8 @@ is the TPU-first design for that:
   a jitted scatter inserts them into the slot's blocks.  Decode then costs
   O(1) tokens per step.  The arrivals that wait at a bucket ride one
   program of power-of-two rows; where every cached layer is whole-
-  context K/V a row carries as many prompts as its blocks hold, each
+  context K/V or a state whose recurrence starts again at a block
+  boundary, a row carries as many prompts as its blocks hold, each
   from a block boundary and masked to itself (`lay_rows`,
   `programs.packs_prompts`).
 - **continuous batching, fully asynchronous**: admission enqueues
@@ -364,7 +365,8 @@ class GenerationEngine:
         # hold (`programs.packs_prompts`), one where it carries one.
         self._row_entries = {
             b: (b // self.block_size
-                if programs.packs_prompts(layout.kinds, b) else 1)
+                if programs.packs_prompts(layout.kinds, b,
+                                          self.block_size) else 1)
             for b in buckets}
         # Sliding-window layers keep a ring of blocks a sequence in a
         # pool of their own kind; None and 0 for a model without any.

@@ -301,21 +301,27 @@ class CacheLayout:
         return None
 
 
-def packs_prompts(cache_kinds, bucket: int) -> bool:
+def packs_prompts(cache_kinds, bucket: int, block_size: int) -> bool:
     """Whether a row of the (rows, `bucket`) prefill program carries as
     many prompts as its blocks hold (`build`'s `prefill_fn`), by what the
-    model declares and the bucket is: every cached layer keeps whole-
-    context K/V and nothing else, and the bucket's attention is XLA's,
-    which takes any mask.  A recurrence's state and its convolution
-    would have to start again at each prompt, a ring be inserted a
+    model declares and the sizes are: every cached layer keeps whole-
+    context K/V, a state whose recurrence runs in chunks that divide the
+    block (a prompt that starts at a block boundary then starts a chunk,
+    from a zero state: ops/ssm.py) or both, and the bucket's attention is
+    XLA's, which takes any mask.  A ring would have to be inserted a
     prompt at a time, the flash kernel know of segments, and a latent
     layer's expanded prefill take them: those models and buckets keep
     one prompt a row."""
     from kfserving_tpu.ops.attention import masked_prefill_takes_xla
 
+    def packs(kind) -> bool:
+        kv, state = parts(kind)
+        return ((kv is None
+                 or isinstance(kv, KVCache) and kv.window is None)
+                and (state is None or block_size % state.chunk == 0))
+
     return masked_prefill_takes_xla(bucket) and all(
-        isinstance(kind, KVCache) and kind.window is None
-        for kind in cache_kinds if kind is not None)
+        packs(kind) for kind in cache_kinds if kind is not None)
 
 
 def derive_block_size(max_seq: int, prefill_buckets: List[int]) -> int:
@@ -617,16 +623,20 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         the prompt that starts at block p, any column where none
         does), and `lengths` and the sampling arrays are [B * P], an
         entry a block, read where a prompt starts.  The model masks a
-        prompt's queries to its own keys (`decoder.cached_attention`),
-        the head and the sampler run over all B * P entries (the head
-        is a weight stream: its rows cost nothing that can be read), and
-        what comes back for an entry no prompt starts at is thrown
-        away.  A lone prompt is a row with one segment: the packed
-        program is the (rows, bucket) program, not one beside it.
+        prompt's queries to its own keys (`decoder.cached_attention`)
+        and starts a recurrence and its convolution again where a
+        prompt starts (ops/ssm.py), the head and the sampler run over
+        all B * P entries (the head is a weight stream: its rows cost
+        nothing that can be read), and what comes back for an entry no
+        prompt starts at is thrown away.  A lone prompt is a row with
+        one segment: the packed program is the (rows, bucket) program,
+        not one beside it.
 
-        Either way the caches leave as [B, L, H*D], a row's blocks in
-        the row's order, so `insert_fn` takes them block by block with
-        no notion of whose block is whose."""
+        Either way K/V leave as [B, L, H*D], a row's blocks in the
+        row's order, so `insert_fn` takes them block by block with no
+        notion of whose block is whose.  A state leaves a row, [B, ...],
+        or packed a prompt, [B * P, ...], an entry for entry of
+        `lengths`."""
         # logit_positions: the LM head runs only on each prompt's
         # last real token — sampling never needs the [B, L, V]
         # logits cube, and at a 4096 bucket the full-cube head
@@ -748,9 +758,11 @@ def build(module, cache_kinds, steps_per_call: int, logprob_topk: int,
         padding rows, and prefix-cache hits whose shared blocks
         already hold the data); for a model with sliding-window
         layers a pair of them, the second the rings', which take
-        a prompt's last blocks alone.  A state's rows go to their
-        slots whole (`slots` [B] int32, past-the-end for a padding
-        row, which drops); a layer that keeps both takes both."""
+        a prompt's last blocks alone.  A state's entries go to their
+        slots whole (`slots` int32, an entry for entry of what the
+        prefill returned: [B], or [B * P] from a packed one;
+        past-the-end for a padding row and for an entry no prompt
+        starts at, which drop); a layer that keeps both takes both."""
         def blocks(kv, pools, new):
             if isinstance(kv, LatentCache):
                 return (paged_attention.latent_insert(

@@ -82,6 +82,9 @@ class LatentCache(NamedTuple):
 class StateCache(NamedTuple):
     # ((shape without the slot axis, dtype), ...), one per array
     arrays: Tuple[Tuple[Tuple[int, ...], Any], ...]
+    # The tokens a chunk of the recurrence's prefill holds: a prompt that
+    # starts at a multiple of it starts from a zero state (ops/ssm.py).
+    chunk: int
 
 
 class BothCaches(NamedTuple):

@@ -157,7 +157,8 @@ class FalconH1Config:
             StateCache((
                 ((self.mamba_heads, self.mamba_head_dim, self.ssm_state),
                  jnp.dtype(jnp.float32)),
-                ((self.conv_kernel - 1, self.conv_width), self.dtype))))
+                ((self.conv_kernel - 1, self.conv_width), self.dtype)),
+                self.chunk_size))
         return [both] * self.num_layers
 
 
@@ -166,7 +167,7 @@ class AttentionMixer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, positions, rotary, *, kv_lengths=None,
-                 cache=None):
+                 cache=None, segments=None):
         cfg = self.config
 
         def proj(name, heads):
@@ -183,7 +184,8 @@ class AttentionMixer(nn.Module):
             out, new_cache = cached_attention(
                 q, k, v, cache=cache,
                 positions=None if cache is None else positions,
-                kv_lengths=kv_lengths, attn_fn=cfg.attn_fn)
+                kv_lengths=kv_lengths, attn_fn=cfg.attn_fn,
+                segments=segments)
             out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1),
                                   use_bias=False, dtype=cfg.dtype,
                                   param_dtype=cfg.param_dtype,
@@ -219,10 +221,11 @@ class FalconH1Block(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, positions, rotary, *, kv_lengths=None,
-                 cache=None):
+                 cache=None, packed=None):
         """cache None, or the layer's pair ((pool_k, pool_v, table),
         (S, conv)); returns its pair without the table (prefill:
-        ((k, v), (S, conv)))."""
+        ((k, v), (S, conv)); a packed one, `packed` as
+        `nemotron_h.MambaMixer` takes it: (S, conv) a prompt)."""
         cfg = self.config
 
         def norm(name):
@@ -232,12 +235,13 @@ class FalconH1Block(nn.Module):
         x = norm("norm")(hidden)
         rows, state = (None, None) if cache is None else cache
         attended, rows = AttentionMixer(cfg, name="attention")(
-            x, positions, rotary, kv_lengths=kv_lengths, cache=rows)
+            x, positions, rotary, kv_lengths=kv_lengths, cache=rows,
+            segments=None if packed is None else packed[0])
         mixed, state = MambaMixer(
             cfg, in_scale=cfg.ssm_in_multiplier,
             segment_scales=cfg.ssm_multipliers,
             out_scale=cfg.ssm_out_multiplier, name="mamba")(
-                x, kv_lengths=kv_lengths, cache=state)
+                x, kv_lengths=kv_lengths, cache=state, packed=packed)
         hidden = hidden + (mixed + attended)
         hidden = hidden + GatedMLP(cfg, name="mlp")(norm("mlp_norm")(hidden))
         return hidden, (rows, state)
@@ -246,7 +250,10 @@ class FalconH1Block(nn.Module):
 class FalconH1LM(nn.Module):
     """Token ids -> next-token logits; arguments and returns as
     `decoder.DecoderLM` (which documents the modes), every layer's cache
-    the pair the module's docstring describes."""
+    the pair the module's docstring describes.  A packed prefill
+    (`segments`, restarted `positions`, which the rotary reads,
+    `logit_positions` [B, P]) returns a layer's K/V by row and its
+    (S, conv) a prompt, as `nemotron_h.NemotronHLM`'s does."""
 
     config: FalconH1Config
 
@@ -255,13 +262,16 @@ class FalconH1LM(nn.Module):
                  kv_cache: Optional[Any] = None,
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
-                 logit_positions: Optional[Any] = None):
+                 logit_positions: Optional[Any] = None,
+                 segments: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         if positions is None:
             pos = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
         else:
             pos = positions.reshape(b, -1)
+        packed = (None if segments is None
+                  else (segments, pos, logit_positions))
         hidden = scaled(
             nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="wte")(input_ids),
@@ -271,7 +281,8 @@ class FalconH1LM(nn.Module):
         for i in range(cfg.num_layers):
             hidden, new_cache = FalconH1Block(cfg, name=f"layer_{i}")(
                 hidden, pos, rotary, kv_lengths=kv_lengths,
-                cache=None if kv_cache is None else kv_cache[i])
+                cache=None if kv_cache is None else kv_cache[i],
+                packed=packed)
             caches.append(new_cache)
         if logit_positions is not None:
             hidden = jnp.take_along_axis(

@@ -146,7 +146,8 @@ class NemotronHConfig:
         state = StateCache((
             ((self.mamba_heads, self.mamba_head_dim, self.ssm_state),
              jnp.dtype(jnp.float32)),
-            ((self.conv_kernel - 1, self.conv_width), self.dtype)))
+            ((self.conv_kernel - 1, self.conv_width), self.dtype)),
+            self.chunk_size)
         kinds = {"M": state, "E": None,
                  "*": KVCache(self.num_kv_heads, self.head_dim)}
         return [kinds[c] for c in self.pattern]
@@ -235,10 +236,14 @@ class MambaMixer(nn.Module):
     out_scale: Optional[float] = None
 
     @nn.compact
-    def __call__(self, hidden, *, kv_lengths=None, cache=None):
+    def __call__(self, hidden, *, kv_lengths=None, cache=None, packed=None):
         """hidden [B, L, H].  cache None: the sequences start here, and
         the returned cache is each row's (S, conv) after `kv_lengths`
-        tokens.  cache (S, conv): L == 1, one step of every row."""
+        tokens; or, with `packed` (`segments` [B, L], `positions` [B, L],
+        `last` [B, P]: a row carries several prompts, as
+        `NemotronHLM.__call__` is told them), each prompt's, [B * P, ...],
+        an entry a place a prompt could start.  cache (S, conv): L == 1,
+        one step of every row."""
         cfg = self.config
         b, l, _ = hidden.shape
         heads, p = cfg.mamba_heads, cfg.mamba_head_dim
@@ -274,7 +279,19 @@ class MambaMixer(nn.Module):
             return (x.reshape(lead + (heads, p)), bb.reshape(lead + (g, n)),
                     cc.reshape(lead + (g, n)))
 
-        if cache is None:
+        if cache is None and packed is not None:
+            segments, positions, last = packed
+            act, conv_state = ssm.causal_conv(xbc, weight, bias,
+                                              packed=(positions, last))
+            x, bb, cc = parts(act)
+            y, states = ssm.ssd_prefill(x, delta, a, bb, cc, d,
+                                        chunk=cfg.chunk_size,
+                                        packed=(segments, positions))
+            # An entry a place a prompt could start: a block's first
+            # chunk.
+            every = states.shape[1] // last.shape[1]
+            state = states[:, ::every].reshape((-1,) + states.shape[2:])
+        elif cache is None:
             act, conv_state = ssm.causal_conv(xbc, weight, bias, kv_lengths)
             x, bb, cc = parts(act)
             y, state = ssm.ssd_prefill(x, delta, a, bb, cc, d, kv_lengths,
@@ -311,7 +328,8 @@ class AttentionMixer(nn.Module):
     config: NemotronHConfig
 
     @nn.compact
-    def __call__(self, hidden, positions, *, kv_lengths=None, cache=None):
+    def __call__(self, hidden, positions, *, kv_lengths=None, cache=None,
+                 segments=None):
         cfg = self.config
 
         def proj(name, heads):
@@ -326,7 +344,8 @@ class AttentionMixer(nn.Module):
             out, new_cache = cached_attention(
                 q, k, v, cache=cache,
                 positions=None if cache is None else positions,
-                kv_lengths=kv_lengths, attn_fn=cfg.attn_fn)
+                kv_lengths=kv_lengths, attn_fn=cfg.attn_fn,
+                segments=segments)
             out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1),
                                   use_bias=False, dtype=cfg.dtype,
                                   param_dtype=cfg.param_dtype,
@@ -394,16 +413,17 @@ class NemotronHBlock(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, positions, *, kv_lengths=None, cache=None,
-                 valid=None):
+                 valid=None, packed=None):
         cfg = self.config
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                     name="norm")(hidden)
         if self.kind == "M":
             out, new_cache = MambaMixer(cfg, name="mixer")(
-                x, kv_lengths=kv_lengths, cache=cache)
+                x, kv_lengths=kv_lengths, cache=cache, packed=packed)
         elif self.kind == "*":
             out, new_cache = AttentionMixer(cfg, name="mixer")(
-                x, positions, kv_lengths=kv_lengths, cache=cache)
+                x, positions, kv_lengths=kv_lengths, cache=cache,
+                segments=None if packed is None else packed[0])
         else:
             out, new_cache = ExpertMixer(cfg, name="mixer")(x, valid), ()
         return hidden + out, new_cache
@@ -412,7 +432,12 @@ class NemotronHBlock(nn.Module):
 class NemotronHLM(nn.Module):
     """Token ids -> next-token logits; arguments and returns as
     `decoder.DecoderLM` (which documents the modes), the caches by layer
-    kind as the module's docstring says."""
+    kind as the module's docstring says.  A packed prefill (`segments`,
+    restarted `positions`, `logit_positions` [B, P]) returns a Mamba
+    layer's (S, conv) a prompt, [B * P, ...], entry p of a row the prompt
+    that starts at its block p and ends at `logit_positions[:, p]`: it
+    needs every prompt to start at a multiple of `chunk_size`, which the
+    engine sees to (`programs.packs_prompts`)."""
 
     config: NemotronHConfig
 
@@ -436,7 +461,8 @@ class NemotronHLM(nn.Module):
                  kv_lengths: Optional[Any] = None,
                  return_cache: bool = False,
                  logit_positions: Optional[Any] = None,
-                 valid: Optional[Any] = None):
+                 valid: Optional[Any] = None,
+                 segments: Optional[Any] = None):
         cfg = self.config
         b, l = input_ids.shape
         if positions is None:
@@ -444,13 +470,17 @@ class NemotronHLM(nn.Module):
         else:
             pos = positions.reshape(b, -1)
         # A prefill bucket's padding is given to no expert (and, by
-        # `kv_lengths`, leaves no mark on a state), nor are the rows of
-        # a decode step that the engine says are not `valid` ([B, 1]
-        # bool: past their token budget; their state goes on stepping,
-        # and the insert that admits the slot's next request overwrites
-        # it).
+        # `kv_lengths` or `segments`, leaves no mark on a state), nor are
+        # the rows of a decode step that the engine says are not `valid`
+        # ([B, 1] bool: past their token budget; their state goes on
+        # stepping, and the insert that admits the slot's next request
+        # overwrites it).
+        packed = None
         if kv_lengths is not None:
             valid = jnp.arange(l)[None, :] < kv_lengths[:, None]
+        elif segments is not None:
+            valid = segments >= 0
+            packed = (segments, pos, logit_positions)
         hidden = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                           param_dtype=cfg.param_dtype,
                           name="wte")(input_ids)
@@ -459,7 +489,7 @@ class NemotronHLM(nn.Module):
             hidden, new_cache = NemotronHBlock(cfg, kind, name=f"layer_{i}")(
                 hidden, pos, kv_lengths=kv_lengths,
                 cache=None if kv_cache is None else kv_cache[i],
-                valid=valid)
+                valid=valid, packed=packed)
             caches.append(new_cache)
         if logit_positions is not None:
             hidden = jnp.take_along_axis(

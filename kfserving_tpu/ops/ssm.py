@@ -22,6 +22,16 @@ thousand tokens, nothing beside the experts.  A row's tokens past its
 `lengths` get Δ = 0: decay 1, input 0, so the state passes them unchanged
 and the final state is the one after the row's last real token.
 
+A row may carry several prompts, each from a chunk boundary (`packed`: the
+engine's packed prefill, engine/programs.py `prefill_fn`).  Nothing inside
+a chunk then knows of prompts beyond what it knows of padding; the two sums
+that cross a boundary start again there: the state that enters a chunk at
+which a prompt starts is zero, and a tap of the convolution that reaches
+back before its prompt's first token reads a zero.  What a prompt leaves is
+read where it ends: the state after the chunk its last token lies in, which
+the padding behind it passes on unchanged to where the next prompt starts
+or the row ends, and the K-1 rows before its own end.
+
 Scopes (`jax.named_scope`): `ssm.conv`, `ssm.scan`; the model puts its two
 projections under `ssm.in_proj` and `ssm.out`.
 """
@@ -40,11 +50,19 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # chip and raises nothing, so on a TPU the engine refuses at load whatever
 # was not run (`unproven_on_chip`), until the cause is found.  A shape is
 # added here by whoever has run it there, alone, with a timeout.
+# Since PR 55 the programs behind these pairs carry several prompts a row
+# (`packed`), and each of the eight was run again as the engine builds it
+# (`engine/programs.build`'s `prefill_fn` at the benchmark's two
+# configurations, parameters seeded on the device), alone in a child under
+# a timeout, five calls with rows of 2 to 5 prompts and five with a lone
+# prompt a row: no stall, and a prompt's K/V, state and conv rows bit for
+# bit what it leaves alone in a row (PERF.md §6 PR 55 has the times).
 CHIP_PROVEN = frozenset(
     # 64 x 64 x 128, 8 groups (PR 31; 3 rows of it too, which no dispatch
-    # has: the engine pads a group's rows to a power of two)
+    # has: the engine pads a group's rows to a power of two; PR 55, packed,
+    # with Nemotron-H's experts)
     [(rows, 1024) for rows in (1, 2, 4, 8)]
-    # 32 x 128 x 256, 2 groups (PR 51)
+    # 32 x 128 x 256, 2 groups (PR 51; PR 55, packed)
     + [(rows, 512) for rows in (1, 2, 4, 8)])
 
 
@@ -63,27 +81,54 @@ def unproven_on_chip(prefill_rows: Optional[int], buckets) -> Optional[str]:
     return None
 
 
-def causal_conv(xbc, weight, bias, lengths: Optional[jax.Array] = None):
+def causal_conv(xbc, weight, bias, lengths: Optional[jax.Array] = None,
+                packed=None):
     """Depthwise causal convolution and silu over a sequence that starts
     here (zeros before it).  xbc [B, L, C]; weight [C, K]; bias [C].
     Returns (activated [B, L, C] in xbc's dtype, the last K-1
     pre-activation rows before each row's `lengths` [B, K-1, C]: what
-    `conv_step` continues from)."""
+    `conv_step` continues from).
+
+    `packed` (in place of `lengths`) is (`positions` [B, L], from 0 again
+    in each prompt of a row; `last` [B, P], the column of a prompt's last
+    token, an entry a place a prompt could start): a prompt's first K-1
+    tokens read zeros before it, not its neighbour's rows, and the rows
+    come back a prompt, [B * P, K-1, C], zeros where the prompt is
+    shorter than K-1 (an entry no prompt starts at gives rows nobody
+    reads)."""
     bsz, l, ch = xbc.shape
     k = weight.shape[1]
     with jax.named_scope("ssm.conv"):
         window = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
         w = weight.astype(jnp.float32)
+        taps = [window[:, j:j + l].astype(jnp.float32) for j in range(k)]
+        if packed is not None:
+            positions, last = packed
+            # Tap j of position t is row t - (K-1-j): of its prompt where
+            # the prompt has that many tokens before t (the last tap is t
+            # itself).
+            taps = [jnp.where((positions >= k - 1 - j)[:, :, None], tap, 0.0)
+                    for j, tap in enumerate(taps[:-1])] + taps[-1:]
         out = bias.astype(jnp.float32) + sum(
-            window[:, j:j + l].astype(jnp.float32) * w[:, j]
-            for j in range(k))
-        if lengths is None:
+            tap * w[:, j] for j, tap in enumerate(taps))
+        if packed is None and lengths is None:
             lengths = jnp.full((bsz,), l, jnp.int32)
         # Row t of xbc is row t + K-1 of the window: the K-1 rows that
         # end at lengths - 1 start at window row `lengths`.
-        at = lengths[:, None] + jnp.arange(k - 1)[None, :]
-        state = jnp.take_along_axis(window, at[:, :, None], axis=1)
-        return jax.nn.silu(out).astype(xbc.dtype), state
+        ends = lengths[:, None] if packed is None else last + 1
+        at = ends[..., None] + jnp.arange(k - 1)             # [B, P, K-1]
+        state = jnp.take_along_axis(
+            window, at.reshape(bsz, -1, 1), axis=1).reshape(
+                at.shape + (ch,))
+        if packed is not None:
+            # The row i before a prompt's last is its own where the last
+            # token's position is at least i.
+            before = (k - 2 - jnp.arange(k - 1))[None, None, :]
+            ours = before <= jnp.take_along_axis(positions, last,
+                                                 axis=1)[..., None]
+            state = jnp.where(ours[..., None], state, 0)
+        return (jax.nn.silu(out).astype(xbc.dtype),
+                state.reshape(-1, k - 1, ch))
 
 
 def conv_step(xbc, state, weight, bias):
@@ -99,18 +144,33 @@ def conv_step(xbc, state, weight, bias):
 
 
 def ssd_prefill(x, dt, a, b, c, d, lengths: Optional[jax.Array] = None,
-                chunk: int = 128):
+                chunk: int = 128, packed=None):
     """The recurrence over whole sequences from a zero state.
     x [B, L, H, P]; dt [B, L, H] (Δ, after its softplus); a [H] (< 0);
     b, c [B, L, G, N]; d [H]; lengths optional [B].  Returns
     (y [B, L, H, P] float32, final state [B, H, P, N] float32).  L need
-    not be a multiple of `chunk`."""
+    not be a multiple of `chunk`.
+
+    `packed` (in place of `lengths`) is (`segments` [B, L], the prompt of
+    its row a position belongs to, -1 for padding; `positions` [B, L],
+    from 0 again in each prompt), every prompt from a chunk boundary: a
+    prompt's sums start from a zero state, and the second return is
+    [B, chunks, H, P, N] float32, for every chunk the state that the
+    prompt it belongs to leaves (read at the chunk a prompt starts at;
+    a chunk of padding holds that of the prompt before it)."""
     bsz, l, h, p = x.shape
     g, n = b.shape[2:]
     r = h // g
     with jax.named_scope("ssm.scan"):
         x, dt, b, c = (t.astype(jnp.float32) for t in (x, dt, b, c))
-        if lengths is not None:
+        starts = None
+        if packed is not None:
+            segments, positions = packed
+            dt = jnp.where((segments >= 0)[:, :, None], dt, 0.0)
+            # [B, chunks]: a prompt starts at the chunk's first column.
+            starts = (segments[:, ::chunk] >= 0) & (positions[:, ::chunk]
+                                                    == 0)
+        elif lengths is not None:
             real = jnp.arange(l)[None, :] < lengths[:, None]
             dt = jnp.where(real[:, :, None], dt, 0.0)
         pad = -l % chunk
@@ -150,17 +210,34 @@ def ssd_prefill(x, dt, a, b, c, d, lengths: Optional[jax.Array] = None,
         # the v5e hung at 4 rows of 1024 (and at no other row count: PERF.md,
         # PR 31), and the loop buys nothing at this length.
         state = jnp.zeros((bsz, g, r, p, n), jnp.float32)
-        entering = []
+        entering, after = [], []
         for i in range(nc):
+            if starts is not None and i:
+                state = jnp.where(starts[:, i, None, None, None, None], 0.0,
+                                  state)
             entering.append(state)
             state = kept[:, i, :, :, None, None] * state + added[:, i]
-        final = state
+            after.append(state)
         entering = jnp.stack(entering, axis=1)           # [B,nc,G,R,P,N]
         y = y + jnp.einsum("bcgqn,bcgrpn->bcgrqp", cc, entering,
                            precision=_HIGHEST) * jnp.exp(cum)[..., None]
         y = y.transpose(0, 1, 4, 2, 3, 5).reshape(bsz, l + pad, h, p)[:, :l]
         y = y + d.astype(jnp.float32)[:, None] * x[:, :l]
-        return y, final.reshape(bsz, h, p, n)
+        if packed is None:
+            return y, state.reshape(bsz, h, p, n)
+        # A prompt leaves the state after its last chunk, and padding
+        # passes that on bit for bit (decay 1, input 0): so what the
+        # prompt of chunk i leaves is what stands before the next start.
+        # Selects over [B, ...] that fuse into the one write of the
+        # result; the same rows taken by a gather at each prompt's end
+        # compile, for the v5e, to a `while` over 2-MB slices (and a loop
+        # in these programs is what hung the chip once: PERF.md, N6).
+        left = [state] * nc
+        for i in reversed(range(nc - 1)):
+            left[i] = jnp.where(
+                starts[:, i + 1, None, None, None, None], after[i],
+                left[i + 1])
+        return y, jnp.stack(left, axis=1).reshape(bsz, nc, h, p, n)
 
 
 def ssd_step(state, x, dt, a, b, c, d):

@@ -12,6 +12,7 @@
 
 import functools
 import json
+import math
 import os
 import re
 import subprocess
@@ -858,6 +859,47 @@ def test_packed_prefill_program_keeps_the_temporaries_of_a_prompt_a_row(
     assert 0 <= (memory.output_size_in_bytes
                 - before.output_size_in_bytes) < 2**14
     assert _parameter_converts(packed) == []
+
+
+@pytest.mark.parametrize("config, rows, beside", [
+    ("nemotron-3-nano-16l-ep2", 8, 1.16e9), ("falcon-h1-34b-6l", 8, 2.83e9)])
+def test_packed_state_model_prefill_program_beside_a_prompt_a_row(
+        v5e, monkeypatch, config, rows, beside):
+    """A state model's widest program with its rows carrying a prompt a
+    block, against the program that lays one prompt in a row (the
+    parent's): it returns a state and its conv rows for every block of
+    every row where that one returned them a row, the same K/V, and with
+    them it fits beside the state and the pools it does not see."""
+    from kfserving_tpu.engine import programs
+    from kfserving_tpu.models import create_model
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           f"{config}.json")) as f:
+        serving = json.load(f)["serving"]
+    bucket = max(serving["prefill_buckets"])
+    blocks = bucket // serving["block_size"]
+    packed = _prefill_program(v5e, monkeypatch, serving, rows)
+    alone = _prefill_program(v5e, monkeypatch, serving, rows,
+                             a_prompt_a_row=True)
+    memory, before = packed.memory_analysis(), alone.memory_analysis()
+    print(f"{config} ({rows}, {bucket}) prefill program, packed: {memory}\n"
+          f"a prompt a row: {before}")
+    kinds = create_model(serving["architecture"],
+                         **serving["arch_kwargs"]).module.config.cache_layers()
+    assert programs.packs_prompts(kinds, bucket, serving["block_size"])
+    state = sum(math.prod(shape) * jnp.dtype(dtype).itemsize
+                for kind in kinds if programs.parts(kind)[1] is not None
+                for shape, dtype in programs.parts(kind)[1].arrays)
+    more = rows * (blocks - 1) * state
+    grown = memory.output_size_in_bytes - before.output_size_in_bytes
+    # the conv rows leave in tiles of 8 or 16 sublanes where they are 3
+    assert more <= grown < 1.1 * more + 2**20, (grown, more)
+    vocabulary = serving["arch_kwargs"]["vocab_size"]
+    logits = 4 * rows * blocks * vocabulary
+    assert memory.temp_size_in_bytes \
+        <= before.temp_size_in_bytes + logits + more, (memory, before)
+    assert _parameter_converts(packed) == []
+    assert _program_bytes(memory) + beside < 15.75 * 2**30, memory
 
 
 def _falcon_serving() -> dict:

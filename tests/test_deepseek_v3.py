@@ -393,7 +393,7 @@ def test_the_cache_layout_at_the_published_shapes():
     assert layout.cache_bytes == layout.pool_bytes["latent"] \
         == 7 * 4096 * 128 * 640 * 2
     assert layout.walk_chunks[0] == 4 and layout.blocks_per_slot == 64
-    assert not any(programs.packs_prompts(layout.kinds, b)
+    assert not any(programs.packs_prompts(layout.kinds, b, layout.block_size)
                    for b in (1024, 6144))
     for (pool,) in shapes:
         assert pool.shape == (4096, 128, 640) and pool.dtype == jnp.bfloat16

@@ -1620,12 +1620,15 @@ async def test_padded_rows_share_is_read_from_the_scrapes(tiny):
 # ------------------------- a prefill row that carries several prompts
 # One bucket of four blocks: a prompt takes the blocks it fills, and the
 # next one of the run starts at the row's next block where the model's
-# programs pack (`programs.packs_prompts`: the dense decoder and OLMoE
-# of `FAMILIES`; Nemotron-H's state and Mellum's rings keep a prompt a
-# row).
+# programs pack (`programs.packs_prompts`: the dense decoder, OLMoE and,
+# its recurrence in chunks of a block, Nemotron-H of `FAMILIES`;
+# Mellum's rings keep a prompt a row).
 PACK_BUCKET = 64
-PACKS = {"decoder": True, "olmoe": True, "nemotron_h": False,
+PACKS = {"decoder": True, "olmoe": True, "nemotron_h": True,
          "mellum": False}
+# Falcon-H1 (K/V and a state in every layer) beside them, for the tests
+# that build their own engine of a family that packs.
+PACK_FAMILIES = {**FAMILIES, "falcon_h1": ("falcon_h1_tiny", {})}
 
 
 def packing_engine(family, **kw):
@@ -1687,9 +1690,9 @@ async def test_a_burst_of_mixed_lengths_is_served_as_each_alone(
         family, mixed_alone, packs):
     """Eight arrivals of 1 to 64 tokens before the scheduler wakes: one
     take, one dispatch.  Where the programs pack they lie in 4 rows (the
-    blocks 4, 3+1, 2+2, 2+1+1) where they filled 8; a state model and a
-    ring model dispatch a prompt a row as before.  Every stream, sampled
-    ones too, is what the request gets served alone."""
+    blocks 4, 3+1, 2+2, 2+1+1) where they filled 8; a ring model
+    dispatches a prompt a row as before.  Every stream, sampled ones too,
+    is what the request gets served alone."""
     eng = packing_engine(family)
     asked = []
     rule = eng._prefill_rows_to_take
@@ -1742,12 +1745,14 @@ def test_lay_rows_gives_each_prompt_consecutive_entries_of_one_row(
         assert at == list(range(len(sizes)))
 
 
-@pytest.mark.parametrize("member", ["decoder", "olmoe"])
+@pytest.mark.parametrize("member", ["decoder", "olmoe", "nemotron_h",
+                                    "falcon_h1"])
 async def test_a_packed_group_with_a_prefix_hit_a_cancel_and_a_short_pool(
         member):
     """Through one engine of a family that packs: (1) a prompt whose first
     block another request left in the prefix index rides a row with two
-    others, its first chunk a -1 the insert drops; (2) one request of a
+    others, its first chunk a -1 the insert drops (a model with a state
+    shares no prefix: its rows are laid the same); (2) one request of a
     packed row is cancelled between dispatch and fetch, and its row's
     others get their streams; (3) the pool stops a take of five short at
     five prompts of two blocks, which lie in 3 rows: the 4 that fill 2
@@ -1757,7 +1762,7 @@ async def test_a_packed_group_with_a_prefix_hit_a_cancel_and_a_short_pool(
     from kfserving_tpu.models import create_model, init_params
     from kfserving_tpu.observability import REGISTRY
 
-    name, sizes = FAMILIES[member]
+    name, sizes = PACK_FAMILIES[member]
     spec = create_model(name, max_seq=PARKED_SEQ, **sizes)
     family = spec.module, init_params(spec, seed=3)
     shared = _prompt(PARKED_BS, 3)
@@ -1779,7 +1784,7 @@ async def test_a_packed_group_with_a_prefix_hit_a_cancel_and_a_short_pool(
         assert after["prefill_rows_dispatched"] \
             - before["prefill_rows_dispatched"] == 2   # blocks 2 + 1, 2
         assert after["paged"]["prefix_hits"] \
-            - before["paged"]["prefix_hits"] == 1
+            - before["paged"]["prefix_hits"] == int(eng._shares_prefixes)
         # (2)
         orig, reqs = eng._enqueue_prefill_group, []
 

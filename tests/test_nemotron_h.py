@@ -29,9 +29,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import nemotron_h_reference as reference  # noqa: E402
 
+from kfserving_tpu.engine import programs  # noqa: E402
 from kfserving_tpu.engine.generator import GenerationEngine  # noqa: E402
 from kfserving_tpu.models import create_model, init_params  # noqa: E402
-from kfserving_tpu.models.decoder import KVCache, StateCache  # noqa: E402
+from kfserving_tpu.models.decoder import (  # noqa: E402
+    BothCaches,
+    KVCache,
+    StateCache,
+)
 from kfserving_tpu.models.nemotron_h import (  # noqa: E402
     NemotronHConfig,
     NemotronHLM,
@@ -448,8 +453,9 @@ async def test_prefill_rows_bounds_a_dispatch_without_a_sync(tiny):
 
 
 async def test_a_split_group_inserts_every_rows_state(tiny):
-    """Seven arrivals once the 1-, 2-, 4- and 8-row programs are warm go
-    as 4 + 2 + 1, each piece an insert of its own into its rows' slots:
+    """Seven arrivals of two blocks, a row of the bucket each, once the
+    1-, 2-, 4- and 8-row programs are warm go as 4 + 2 + 1, each piece
+    an insert of its own into its rows' slots:
     every request's conv and scan state (and K/V) is its own, so eight
     served tokens each match the reference."""
     prompts = [prompt_of(18 + i, 3 + i) for i in range(7)]
@@ -467,7 +473,7 @@ async def test_a_split_group_inserts_every_rows_state(tiny):
     try:
         for n in (1, 2, 4, 8):
             await asyncio.wait_for(asyncio.gather(*[
-                served(engine, prompt_of(5 + i), 2) for i in range(n)]),
+                served(engine, prompt_of(17 + i), 2) for i in range(n)]),
                 timeout=300)
         assert rows == [1, 2, 4, 8]
         results = await asyncio.wait_for(asyncio.gather(*[
@@ -500,3 +506,73 @@ def test_the_older_models_declare_the_cache_the_engine_built_before():
         assert engine._attn_flops_coeff == 4.0 * 4 * 128
     finally:
         engine.shutdown_nowait()
+
+
+# -- a prefill row that carries several prompts ------------------------------
+# name -> the prompts of one row as (tokens, the block of 16 it starts at)
+LAID = {
+    "alone": [(37, 0)],
+    "first": [(20, 0), (9, 2), (16, 3)],
+    "last": [(23, 2), (32, 0)],
+    "between": [(21, 1), (7, 0), (33, 3)],
+    "one-block-and-shorter-than-the-taps": [(16, 2), (32, 0), (2, 3)],
+    "the-bucket": [(64, 0)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAID))
+def test_a_packed_row_gives_each_prompt_the_references_logits(tiny, case):
+    """`segments`, restarted `positions` and each prompt's last column:
+    a prompt's logits are the reference's over the prompt alone, and a
+    state layer returns a state and conv rows for every place a prompt
+    could start (tests/test_programs.py holds each to the prompt's own
+    alone in a row)."""
+    module, variables, _ = tiny
+    laid = LAID[case]
+    bucket = BS * max(block - (-n // BS) for n, block in laid)
+    ids = np.zeros((1, bucket), np.int32)
+    segments = np.full((1, bucket), -1, np.int32)
+    positions = np.zeros((1, bucket), np.int32)
+    last = np.zeros((1, bucket // BS), np.int32)
+    prompts = [prompt_of(n, 3 + 2 * i) for i, (n, _) in enumerate(laid)]
+    for prompt, (n, block) in zip(prompts, laid):
+        at = slice(block * BS, block * BS + n)
+        ids[0, at], segments[0, at] = prompt, block
+        positions[0, at], last[0, block] = np.arange(n), at.stop - 1
+    logits, caches = module.apply(
+        variables, jnp.asarray(ids), positions=jnp.asarray(positions),
+        segments=jnp.asarray(segments), logit_positions=jnp.asarray(last),
+        return_cache=True)
+    for kind, layer in zip(module.config.cache_layers(), caches):
+        _, state = programs.parts(kind)
+        if state is not None:
+            arrays = layer[1] if isinstance(kind, BothCaches) else layer
+            assert [(x.shape, x.dtype) for x in arrays] == [
+                ((bucket // BS,) + shape, dtype)
+                for shape, dtype in state.arrays]
+    for prompt, (n, block) in zip(prompts, laid):
+        np.testing.assert_allclose(np.asarray(logits[0, block]),
+                                   ref_logits(tiny, prompt)[-1], atol=TOL,
+                                   rtol=0)
+
+
+async def test_decode_continues_from_each_packed_prompts_own_state(tiny):
+    """Seven arrivals of one to four blocks lie in rows of four blocks;
+    each then decodes 32 tokens from the state, the conv rows and the K/V
+    its prompt left, on the reference's logits throughout."""
+    prompts = [prompt_of(n, stride) for n, stride in (
+        (16, 3), (5, 5), (33, 7), (64, 11), (21, 13), (1, 17), (40, 19))]
+    engine = engine_of(tiny, max_slots=8, prefill_buckets=[64],
+                       steps_per_call=8)
+    try:
+        results = await asyncio.wait_for(asyncio.gather(*[
+            served(engine, p, 32) for p in prompts]), timeout=600)
+        stats = engine.stats()
+    finally:
+        await engine.close()
+    assert stats["prefill_requests"] == 7
+    assert stats["prefill_prompts_per_row"] > 1.0
+    assert engine._prefill_refusals == 0
+    for prompt, (tokens, chosen, top) in zip(prompts, results):
+        assert len(tokens) == 32
+        assert_matches_reference(tiny, prompt, tokens, chosen, top)

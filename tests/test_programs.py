@@ -433,18 +433,47 @@ BUCKET, BS = 32, 8
 PER_ROW = BUCKET // BS
 
 
-@pytest.mark.parametrize("architecture,bucket,packs", [
-    ("decoder_tiny", 32, True), ("olmoe_tiny", 32, True),
-    ("decoder_tiny", 1024, True),
-    ("decoder_tiny", 2048, False),        # the flash kernel's, on a chip
-    ("nemotron_h_tiny", 32, False),       # a state starts once a row
-    ("mellum_tiny", 32, False),           # a ring is inserted a prompt
-    ("falcon_h1_tiny", 32, False),        # K/V and a state in one layer
+@pytest.mark.parametrize("architecture,bucket,block,packs", [
+    ("decoder_tiny", 32, 8, True), ("olmoe_tiny", 32, 8, True),
+    ("decoder_tiny", 1024, 128, True),
+    ("decoder_tiny", 2048, 128, False),   # the flash kernel's, on a chip
+    ("mellum_tiny", 32, 8, False),        # a ring is inserted a prompt
+    ("deepseek_v3_tiny", 32, 8, False),   # a latent row's expanded prefill
+    # A state starts again where a chunk of its recurrence does (16 in
+    # the tiny models): at every block boundary, or not at each.
+    ("nemotron_h_tiny", 32, 16, True), ("nemotron_h_tiny", 64, 32, True),
+    ("nemotron_h_tiny", 32, 8, False), ("nemotron_h_tiny", 2048, 128, False),
+    ("falcon_h1_tiny", 32, 16, True),     # K/V and a state in one layer:
+    ("falcon_h1_tiny", 48, 24, False),    # where both halves do
+    ("falcon_h1_tiny", 2048, 16, False),
 ])
-def test_which_programs_pack_follows_from_the_layers_and_the_bucket(
-        architecture, bucket, packs):
+def test_which_programs_pack_follows_from_the_layers_and_the_sizes(
+        architecture, bucket, block, packs):
     kinds = create_model(architecture).module.config.cache_layers()
-    assert programs.packs_prompts(kinds, bucket) is packs
+    assert programs.packs_prompts(kinds, bucket, block) is packs
+
+
+@pytest.mark.parametrize("config", ["nemotron-3-nano-16l-ep2",
+                                    "falcon-h1-34b-6l"])
+def test_the_served_state_models_pack_at_the_sizes_they_are_served_at(
+        config):
+    """The chunk and the block size as the benchmark's configurations
+    state them: every bucket they serve packs."""
+    import json
+    import os
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "chipbench", "configs",
+                           f"{config}.json")) as f:
+        served = json.load(f)
+    serving = served["serving"]
+    chunk = serving["arch_kwargs"]["chunk_size"]
+    assert serving["block_size"] % chunk == 0
+    arch = create_model(serving["architecture"] + "_tiny",
+                        chunk_size=chunk).module.config
+    for bucket in serving["prefill_buckets"]:
+        assert programs.packs_prompts(arch.cache_layers(), bucket,
+                                      serving["block_size"])
 
 
 def _prompt(n: int, salt: int):
@@ -478,32 +507,53 @@ def _packed_args(placed, rows: int):
              jnp.asarray(last))]
 
 
-@pytest.fixture(scope="module", params=["decoder_tiny", "olmoe_tiny"])
+@pytest.fixture(scope="module", params=[
+    ("decoder_tiny", {}), ("olmoe_tiny", {}),
+    # The two state models, their recurrence in chunks of a block.
+    ("nemotron_h_tiny", {"chunk_size": 8}),
+    ("falcon_h1_tiny", {"chunk_size": 8})], ids=lambda p: p[0])
 def packing(request):
-    """(the prefill program of a model that packs, its variables)."""
-    module = create_model(request.param).module
+    """(the prefill program of a model that packs, its variables, what
+    its layers keep)."""
+    name, sizes = request.param
+    module = create_model(name, **sizes).module
     assert module.config.dtype == jnp.float32
     layout = programs.CacheLayout(module.config, "m", **PACKED)
-    assert programs.packs_prompts(layout.kinds, BUCKET)
+    assert programs.packs_prompts(layout.kinds, BUCKET, BS)
     built = programs.build(module, layout.kinds, 4, 5,
                            jax.random.PRNGKey(9))
     variables = module.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 16), jnp.int32))
-    return built.prefill, variables
+    return built.prefill, variables, layout.kinds
 
 
-def _read(out, row: int, block: int, n: int):
+def _kept(kinds, caches, rows: int, row: int, columns: slice, entry: int):
+    """What a prefill of `rows` rows left a prompt, a layer: its K/V over
+    the `columns` of its `row` (the positions it owns: what lies behind it
+    in its last block is never read), its state at its `entry`."""
+    def kv(_, arrays):
+        assert all(x.shape[:2] == (rows, BUCKET) for x in arrays)
+        return tuple(x[row, columns] for x in arrays)
+
+    def state(kind, arrays):
+        assert [x.shape[1:] for x in arrays] == [
+            shape for shape, _ in kind.arrays]
+        return tuple(x[entry] for x in arrays)
+
+    return [np.asarray(x) for kind, layer in zip(kinds, caches)
+            for x in jax.tree.leaves(programs.by_part(kind, kv, state,
+                                                      layer))]
+
+
+def _read(out, kinds, row: int, block: int, n: int):
     """What a prompt of n tokens placed at (row, block) got: its first
-    token, its log-probabilities, and its K/V a layer, chunk for chunk
-    (the positions it owns: what lies behind it in its last block is
-    never read)."""
+    token, its log-probabilities, and what every layer keeps of it."""
     at, start = row * PER_ROW + block, block * BS
     firsts, caches, chosen, top_ids, top_lps = out[:5]
-    kv = [np.asarray(x[row, start:start + n])
-          for layer in caches for x in layer]
-    assert caches[0][0].shape[:2] == (firsts.shape[0] // PER_ROW, BUCKET)
     return (int(firsts[at]), float(chosen[at]), np.asarray(top_ids[at]),
-            np.asarray(top_lps[at]), kv)
+            np.asarray(top_lps[at]),
+            _kept(kinds, caches, firsts.shape[0] // PER_ROW, row,
+                  slice(start, start + n), at))
 
 
 def _same(got, want):
@@ -535,9 +585,10 @@ def test_a_packed_prompt_gets_what_it_gets_alone_in_a_row(packing, case,
                                                           temp):
     """Whatever shares its row: the first token (a sampled one drawn by
     the prompt's own length and seed), the chosen and the top 5
-    log-probabilities, and K/V, against the same prompt through the
-    one-prompt-a-row form of the program (`lengths`, no segments)."""
-    prefill, variables = packing
+    log-probabilities, K/V and a recurrence's state and conv rows,
+    against the same prompt through the one-prompt-a-row form of the
+    program (`lengths`, no segments)."""
+    prefill, variables, kinds = packing
     n, row, block, others, rows = BESIDE[case]
     prompt = _prompt(n, 1)
     padded = np.zeros((1, BUCKET), np.int32)
@@ -549,19 +600,19 @@ def test_a_packed_prompt_gets_what_it_gets_alone_in_a_row(packing, case,
                     jnp.asarray([77], jnp.int32), jnp.asarray(True))
     want = (int(alone[0][0]), float(alone[2][0]), np.asarray(alone[3][0]),
             np.asarray(alone[4][0]),
-            [np.asarray(x[0, :n]) for layer in alone[1] for x in layer])
+            _kept(kinds, alone[1], 1, 0, slice(0, n), 0))
     placed = [(prompt, row, block, temp, 77)] + [
         (_prompt(m, 2 + i), r, b, 1.3, 5 + i)
         for i, (m, r, b) in enumerate(others)]
     out = prefill(variables, *_packed_args(placed, rows))
-    _same(_read(out, row, block, n), want)
+    _same(_read(out, kinds, row, block, n), want)
 
 
 def test_unused_entries_change_nothing(packing):
     """An entry no prompt starts at may name any column as its last
     token: what the prompts of the row get does not move, and neither
     does it for a padding row beside them."""
-    prefill, variables = packing
+    prefill, variables, kinds = packing
     placed = [(_prompt(11, 1), 0, 1, 0.0, 3), (_prompt(5, 2), 0, 3, 0.7, 4)]
     args = _packed_args(placed, 2)
     base = prefill(variables, *args)
@@ -570,5 +621,5 @@ def test_unused_entries_change_nothing(packing):
         jnp.asarray([3, 30, 17, 8], jnp.int32))
     other = prefill(variables, *args[:-1], (segments, positions, moved))
     for prompt, row, block, _, _ in placed:
-        _same(_read(other, row, block, len(prompt)),
-              _read(base, row, block, len(prompt)))
+        _same(_read(other, kinds, row, block, len(prompt)),
+              _read(base, kinds, row, block, len(prompt)))

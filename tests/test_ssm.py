@@ -136,3 +136,99 @@ def test_conv_steps_continue_from_each_rows_own_length(lengths):
 def test_the_prefill_shapes_that_have_run_on_the_chip(rows, buckets, served):
     reason = ssm.unproven_on_chip(rows, buckets)
     assert (reason is None) == served
+
+
+# -- a row that carries several prompts, each from a chunk boundary --------------
+BLOCK = CHUNK
+# name -> (the bucket, [(the prompt's tokens, the block it starts at)]),
+# the first of them the prompt that is looked at.
+PACKED = {
+    "alone": (64, [(37, 0)]),
+    "first": (64, [(20, 0), (9, 2), (16, 3)]),
+    "last": (64, [(23, 2), (32, 0)]),
+    "between": (96, [(21, 1), (7, 0), (33, 3)]),
+    "one-block": (64, [(16, 2), (32, 0), (5, 3)]),
+    "the-bucket": (64, [(64, 0)]),
+    "shorter-than-the-taps": (64, [(2, 1), (16, 0), (1, 2), (3, 3)]),
+    "a-chunk-multiple": (64, [(32, 1), (11, 0), (16, 3)]),
+    "not-a-chunk-multiple": (80, [(45, 1), (3, 0), (1, 4)]),
+}
+
+
+def _laid(bucket, prompts):
+    """(segments [1, bucket], positions [1, bucket], last [1, blocks]) of
+    `prompts` as (tokens, first block) in one row."""
+    segments = np.full((1, bucket), -1, np.int32)
+    positions = np.zeros((1, bucket), np.int32)
+    last = np.zeros((1, bucket // BLOCK), np.int32)
+    for n, block in prompts:
+        start = block * BLOCK
+        assert (segments[0, start:start + n] == -1).all()
+        segments[0, start:start + n] = block
+        positions[0, start:start + n] = np.arange(n)
+        last[0, block] = start + n - 1
+    return jnp.asarray(segments), jnp.asarray(positions), jnp.asarray(last)
+
+
+@pytest.mark.parametrize("case", sorted(PACKED))
+def test_a_packed_prompts_scan_is_its_scan_alone_from_a_zero_state(case):
+    """y over the prompt's positions and the state it leaves (the one
+    after the chunk its last token lies in, read at the chunk it starts
+    at), for every prompt of the row, against the prompt alone in a row
+    with `lengths`."""
+    bucket, prompts = PACKED[case]
+    x, dt, a, b, c, d = inputs(1, bucket, seed=len(case))
+    segments, positions, last = _laid(bucket, prompts)
+    y, states = ssm.ssd_prefill(x, dt, a, b, c, d, chunk=CHUNK,
+                                packed=(segments, positions))
+    assert states.shape == (1, -(-bucket // CHUNK), H, P, N)
+    assert y.dtype == states.dtype == jnp.float32
+    for n, block in prompts:
+        at = slice(block * BLOCK, block * BLOCK + n)
+        want_y, want_s = ssm.ssd_prefill(
+            x[:, at], dt[:, at], a, b[:, at], c[:, at], d, chunk=CHUNK)
+        np.testing.assert_allclose(np.asarray(y[:, at]), np.asarray(want_y),
+                                   atol=1e-5, rtol=0)
+        # read at any chunk of the prompt, its first among them
+        for chunk in (block * BLOCK // CHUNK, int(last[0, block]) // CHUNK):
+            np.testing.assert_allclose(np.asarray(states[0, chunk]),
+                                       np.asarray(want_s[0]), atol=1e-5,
+                                       rtol=0)
+
+
+@pytest.mark.parametrize("case", sorted(PACKED))
+def test_a_packed_prompts_convolution_is_its_convolution_alone(case):
+    """The activations over the prompt's positions and the K-1 rows
+    before its own end (zeros where it is shorter), entry for entry."""
+    bucket, prompts = PACKED[case]
+    xbc, w, bias = conv_inputs(1, bucket)
+    _, positions, last = _laid(bucket, prompts)
+    got, rows = ssm.causal_conv(xbc, w, bias, packed=(positions, last))
+    assert rows.shape == (bucket // BLOCK, K - 1, 10)
+    assert rows.dtype == xbc.dtype
+    for n, block in prompts:
+        at = slice(block * BLOCK, block * BLOCK + n)
+        want, want_rows = ssm.causal_conv(xbc[:, at], w, bias)
+        np.testing.assert_allclose(np.asarray(got[:, at]), np.asarray(want),
+                                   atol=1e-5, rtol=0)
+        padded = jnp.pad(want_rows, ((0, 0), (max(0, K - 1 - n), 0), (0, 0)))
+        np.testing.assert_allclose(np.asarray(rows[block]),
+                                   np.asarray(padded[0, -(K - 1):]),
+                                   atol=1e-5, rtol=0)
+
+
+def test_rows_of_a_packed_batch_are_laid_independently():
+    """Two rows, other prompts in each: a row is what it is alone."""
+    x, dt, a, b, c, d = inputs(2, 64, seed=21)
+    laid = [_laid(64, PACKED[name][1]) for name in ("first", "last")]
+    segments, positions, _ = (jnp.concatenate(t) for t in zip(*laid))
+    y, states = ssm.ssd_prefill(x, dt, a, b, c, d, chunk=CHUNK,
+                                packed=(segments, positions))
+    for r, (seg, pos, _) in enumerate(laid):
+        want_y, want_s = ssm.ssd_prefill(
+            x[r:r + 1], dt[r:r + 1], a, b[r:r + 1], c[r:r + 1], d,
+            chunk=CHUNK, packed=(seg, pos))
+        np.testing.assert_allclose(np.asarray(y[r]), np.asarray(want_y[0]),
+                                   atol=1e-5, rtol=0)
+        np.testing.assert_allclose(np.asarray(states[r]),
+                                   np.asarray(want_s[0]), atol=1e-5, rtol=0)
