@@ -73,7 +73,7 @@ from kfserving_tpu.engine import inflight as inflight_table
 from kfserving_tpu.engine.buckets import pow2_buckets
 from kfserving_tpu.observability import attribution
 from kfserving_tpu.observability import metrics as obs
-from kfserving_tpu.observability.profiling import TIMELINE
+from kfserving_tpu.observability.profiling import HEARTBEAT, TIMELINE
 from kfserving_tpu.observability.profiling.timeline import HOST, LAUNCH
 from kfserving_tpu.parallel.mesh import mesh_scope
 from kfserving_tpu.protocol.errors import InferenceError, InvalidInput
@@ -629,10 +629,11 @@ class GenerationEngine:
             max_workers=1,
             thread_name_prefix=f"generator-enq-{name}")
         # Every launch's row until its fetch returns (engine/inflight.py),
-        # and the loop's once-a-second look at it while a fetch is out.
+        # and the process heartbeat's look at it, eight times a second
+        # from the pipeline's first start to close().
         self._inflight = inflight_table.InflightTable(
             name, (f"generator-enq-{name}_", f"generator-{name}_"))
-        self._stall_timer: Optional[asyncio.TimerHandle] = None
+        self._heartbeat = None
         self._slots: List[Optional[_Active]] = [None] * self.max_slots
         self._pending: deque = deque()
         # Growth starvation: a decodable slot's table cannot cover the
@@ -922,9 +923,6 @@ class GenerationEngine:
 
     async def close(self):
         self._closed = True
-        if self._stall_timer is not None:
-            self._stall_timer.cancel()
-            self._stall_timer = None
         if self._loop_task is not None:
             if self._wakeup is not None:
                 self._wakeup.set()
@@ -932,6 +930,7 @@ class GenerationEngine:
                 await self._loop_task
             except asyncio.CancelledError:
                 pass
+        self._unwatch()
         self._executor.shutdown(wait=True)
         self._enqueue_executor.shutdown(wait=True)
         if self.kv_tier is not None:
@@ -944,10 +943,15 @@ class GenerationEngine:
         self._closed = True
         if self._wakeup is not None:
             self._wakeup.set()
+        self._unwatch()
         self._executor.shutdown(wait=False)
         self._enqueue_executor.shutdown(wait=False)
         if self.kv_tier is not None:
             self.kv_tier.close()
+
+    def _unwatch(self) -> None:
+        watch, self._heartbeat = self._heartbeat, None
+        HEARTBEAT.unwatch(watch)
 
     def load_gauges(self) -> Dict[str, int]:
         """Instantaneous saturation signal for the autoscaler: a
@@ -1017,6 +1021,7 @@ class GenerationEngine:
             "prefill_wait_s": round(self._prefill_wait_s, 4),
             "prefill_device_s": round(self._prefill_device_s, 4),
             "inflight": self._inflight.rows(),
+            "device_starved_s": self._inflight.starved_s(),
         }
         # -- roofline block (promoted to registry gauges by
         # observability/profiling/roofline.py; keys must stay in sync
@@ -2495,6 +2500,12 @@ class GenerationEngine:
         # 2-worker fetch executor); awaiting in FIFO order preserves
         # delivery order.
         inflight: deque = deque()
+        self._inflight.loop_ident = threading.get_ident()
+        if self._heartbeat is None and not self._closed:
+            self._heartbeat = HEARTBEAT.watch(
+                loop, beat=self._inflight.check, rows=self._inflight.rows)
+        # A loop that ended for want of work stood waiting until now.
+        self._inflight.waiting(False)
         try:
             # KFS_SANITIZE=1: jax.transfer_guard("disallow") armed on
             # this (the scheduler's) thread for the pipeline's whole
@@ -2711,6 +2722,8 @@ class GenerationEngine:
                     self._wakeup.clear()
                     if admitted:
                         continue
+                    # The starved clock books this wait to `no_work`.
+                    self._inflight.waiting(True)
                     try:
                         # Held across the await: the loop thread runs
                         # other tasks meanwhile, and the trace's
@@ -2722,7 +2735,10 @@ class GenerationEngine:
                     except asyncio.TimeoutError:
                         if not self._pending and not any(
                                 s is not None for s in self._slots):
-                            return  # idle: let the loop die; resubmit restarts
+                            # idle: let the loop die; resubmit restarts
+                            # (and ends the wait)
+                            return
+                    self._inflight.waiting(False)
                 continue
             # Paged mode: every active slot's table must cover the
             # positions the next pipeline_depth waves can reach.  A
@@ -2884,7 +2900,6 @@ class GenerationEngine:
                 self._process_deferred_frees(force=True)
                 continue
             kind, fut, meta, t0, seq = inflight.popleft()
-            self._watch_inflight(loop)
             t_await = time.perf_counter()
             try:
                 # Held across the await, like engine.wait.request.
@@ -3173,22 +3188,6 @@ class GenerationEngine:
         with self._inflight.fetch(seq, program) as joined:
             fetched, lp = fetch(*handles)
         return fetched, lp, joined.done_t
-
-    def _watch_inflight(self, loop) -> None:
-        """Before the loop awaits a fetch: have the in-flight table
-        looked at once a second until no fetch is outstanding
-        (`InflightTable.check`: the oldest-age gauge, and a stall
-        counted and reported once).  A timer on the loop, so it runs
-        whichever await the loop is parked at, and cancels nothing."""
-        if self._stall_timer is None and not self._closed:
-            self._stall_timer = loop.call_later(
-                inflight_table.STALL_CHECK_S, self._look_at_inflight,
-                loop)
-
-    def _look_at_inflight(self, loop) -> None:
-        self._stall_timer = None
-        if self._inflight.check():
-            self._watch_inflight(loop)
 
     def _fetch_wave(self, toks_h, lp_h):
         """Runs on the executor thread: the D2H fetch that joins the

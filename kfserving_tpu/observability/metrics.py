@@ -152,8 +152,43 @@ def generator_inflight_oldest_age_s():
     return REGISTRY.gauge(
         "kfserving_tpu_generator_inflight_oldest_age_s",
         "Seconds since the launch of the oldest program whose fetch "
-        "has not returned, as of the scheduler loop's last look (once "
-        "a second while a fetch is outstanding); 0 with none in flight")
+        "has not returned, as of the heartbeat thread's last look (8 a "
+        "second while the engine is open); 0 with none in flight")
+
+
+def generator_device_starved_seconds_total():
+    return REGISTRY.counter(
+        "kfserving_tpu_generator_device_starved_seconds_total",
+        "Seconds in which the device had nothing from this engine: from "
+        "the retirement that left no program in flight to the return of "
+        "the next launch call (engine/inflight.py).  cause=no_work: the "
+        "scheduler loop stood in engine.wait.request, no slot active and "
+        "nothing pending; cause=host: the rest, the loop admitting or "
+        "delivering, the launching thread preparing or launching.  A "
+        "lower bound of the device's idle time, exact in what it "
+        "attributes")
+
+
+def process_held_ms():
+    return REGISTRY.histogram(
+        "kfserving_tpu_process_held_ms",
+        "How late the process's heartbeat ran, 8 times a second "
+        "(observability/profiling/heartbeat.py).  what=loop: from "
+        "posting a tick to the serving event loop to the loop running "
+        "it, so a handler that holds the loop shows here; "
+        "what=interpreter: how late the heartbeat thread itself woke, "
+        "so a pause that holds every thread (a collection, a C call "
+        "that keeps the interpreter lock) shows here too",
+        buckets=DELIVER_LAG_BUCKETS_MS)
+
+
+def process_gc_pause_ms():
+    return REGISTRY.histogram(
+        "kfserving_tpu_process_gc_pause_ms",
+        "One garbage collection of the interpreter from its start to "
+        "its stop (gc.callbacks), by generation; every thread is held "
+        "for its length",
+        buckets=DELIVER_LAG_BUCKETS_MS)
 
 
 def llm_inter_token_ms():

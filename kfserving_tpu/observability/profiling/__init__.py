@@ -4,6 +4,11 @@ The profiling layer turns ROADMAP item 1's "re-derive the arithmetic
 at real step times" from a one-off offline exercise into something the
 running server exposes continuously:
 
+- `heartbeat`    — the process's one heartbeat thread: how late the
+                   serving loop and the interpreter ran, each
+                   collection's pause, one `process paused:` line a
+                   pause of 250 ms or more, and the engines' stall
+                   watch called from a thread that is not the loop's;
 - `timeline`     — the bounded, allocation-light engine event ring
                    (decode waves, prefill chunks, preemptions,
                    growth-HOLD windows, compile-cache misses, device
@@ -24,6 +29,7 @@ Import discipline (observability package contract): nothing from
 record *into* this layer, never the reverse.
 """
 
+from kfserving_tpu.observability.profiling.heartbeat import HEARTBEAT
 from kfserving_tpu.observability.profiling.timeline import (
     TIMELINE,
     EngineTimeline,
@@ -34,5 +40,5 @@ from kfserving_tpu.observability.profiling.trace_export import (
     to_chrome_trace,
 )
 
-__all__ = ["TIMELINE", "EngineTimeline", "to_chrome_trace",
+__all__ = ["HEARTBEAT", "TIMELINE", "EngineTimeline", "to_chrome_trace",
            "merge_traces", "summarize"]

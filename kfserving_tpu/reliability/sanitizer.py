@@ -23,10 +23,12 @@ computed at runtime):
   compilation from that source is a ``recompile`` violation: the
   bucket grid was supposed to be closed, and a post-warmup compile is
   a recompile storm's first drop.
-- **event-loop stall watchdog** — a heartbeat thread posts
-  ``call_soon_threadsafe`` ticks at the configured loop; a tick the
-  loop fails to run within ``KFS_SANITIZE_STALL_MS`` (default 250)
-  is a ``loop_stall`` violation with the observed stall attached.
+- **event-loop stall watchdog** — the process heartbeat
+  (``observability/profiling/heartbeat.py``, the thread the engines'
+  stall watch runs on too) posts ``call_soon_threadsafe`` ticks at
+  the configured loop; a tick the loop fails to run within
+  ``KFS_SANITIZE_STALL_MS`` (default 250) is a ``loop_stall``
+  violation with the observed stall attached.
 
 Violations land in ``kfserving_tpu_sanitizer_violations_total{kind}``
 and, when a flight recorder is attached (the server wires its
@@ -35,13 +37,12 @@ evidence that survives the healthy traffic after the incident.
 
 ``KFS_SANITIZE`` unset/0 is a true no-op: every hook degrades to a
 dict lookup or a null context manager, jax is never imported from
-here, and no thread starts.
+here, and nothing here watches a loop.
 """
 
 import contextlib
 import os
 import threading
-import time
 from typing import Any, Dict, Optional
 
 ENV_VAR = "KFS_SANITIZE"
@@ -233,72 +234,44 @@ def _stall_threshold_ms() -> float:
 
 
 class LoopStallWatchdog:
-    """Heartbeat thread: posts a tick onto the watched loop every
-    ``interval_s`` and measures how long the loop takes to run it.
-    A tick older than the threshold when it finally lands (or still
-    pending past the threshold at the next check) is one
-    ``loop_stall`` violation per stall episode — the dynamic
-    counterpart of kfslint's ``spin-loop``/``async-blocking``."""
+    """A subscriber of the process heartbeat: told, eight times a
+    second, how late the watched loop ran the tick posted to it, or
+    for how long the tick that is out has waited.  Past the threshold
+    that is one ``loop_stall`` violation per stall episode, recorded
+    while the loop is still held — the dynamic counterpart of
+    kfslint's ``spin-loop``/``async-blocking``."""
 
-    def __init__(self, loop, threshold_ms: Optional[float] = None,
-                 interval_s: Optional[float] = None):
+    def __init__(self, loop, threshold_ms: Optional[float] = None):
         self.loop = loop
-        self.threshold_s = (threshold_ms
-                            if threshold_ms is not None
-                            else _stall_threshold_ms()) / 1000.0
-        self.interval_s = interval_s or max(0.05,
-                                            self.threshold_s / 2.0)
-        self._sent_at: Optional[float] = None
+        self.threshold_ms = (threshold_ms if threshold_ms is not None
+                             else _stall_threshold_ms())
         self._stalled = False  # one violation per episode
-        self._stop = threading.Event()
-        self._thread = threading.Thread(
-            target=self._run, name="kfs-sanitize-watchdog",
-            daemon=True)
+        self._watch = None
         self.stalls = 0
 
     def start(self) -> "LoopStallWatchdog":
-        self._thread.start()
+        from kfserving_tpu.observability.profiling import HEARTBEAT
+
+        self._watch = HEARTBEAT.watch(self.loop, held=self._held)
         return self
 
     def stop(self) -> None:
-        self._stop.set()
-        self._thread.join(timeout=2.0)
+        from kfserving_tpu.observability.profiling import HEARTBEAT
 
-    def _beat(self, sent_at: float) -> None:
-        # Runs ON the loop: the tick landed.
-        stall_s = time.perf_counter() - sent_at
-        self._sent_at = None
-        if stall_s > self.threshold_s:
-            self._record(stall_s)
-        else:
+        watch, self._watch = self._watch, None
+        HEARTBEAT.unwatch(watch)
+
+    def _held(self, ms: float) -> None:
+        # Runs on the heartbeat thread.
+        if ms <= self.threshold_ms:
             self._stalled = False
-
-    def _record(self, stall_s: float) -> None:
-        if self._stalled:
-            return  # same episode
-        self._stalled = True
-        self.stalls += 1
-        record_violation("loop_stall", {
-            "stall_ms": round(stall_s * 1000.0, 1),
-            "threshold_ms": round(self.threshold_s * 1000.0, 1),
-        })
-
-    def _run(self) -> None:
-        while not self._stop.wait(self.interval_s):
-            pending = self._sent_at
-            if pending is not None:
-                stall_s = time.perf_counter() - pending
-                if stall_s > self.threshold_s:
-                    # The loop hasn't run our tick yet: it is stalled
-                    # RIGHT NOW — record without waiting for release.
-                    self._record(stall_s)
-                continue
-            sent = time.perf_counter()
-            self._sent_at = sent
-            try:
-                self.loop.call_soon_threadsafe(self._beat, sent)
-            except RuntimeError:
-                return  # loop closed
+        elif not self._stalled:
+            self._stalled = True
+            self.stalls += 1
+            record_violation("loop_stall", {
+                "stall_ms": round(ms, 1),
+                "threshold_ms": round(self.threshold_ms, 1),
+            })
 
 
 def start_watchdog(loop) -> Optional[LoopStallWatchdog]:

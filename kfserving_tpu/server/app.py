@@ -1481,6 +1481,12 @@ class ModelServer:
             if tier is not None:
                 tier.attach_flight_recorder(
                     self.monitoring.flight_recorder)
+        # A pause the process heartbeat names (`process paused:`) is
+        # pinned here, by the first server of the process to ask.
+        from kfserving_tpu.observability.profiling import HEARTBEAT
+
+        if HEARTBEAT.recorder is None:
+            HEARTBEAT.recorder = self.monitoring.flight_recorder
         # Device-discipline sanitizer (KFS_SANITIZE=1): violations
         # pin into this server's flight recorder, and the stall
         # watchdog heartbeats the serving loop.  Disabled: two env
@@ -1550,8 +1556,11 @@ class ModelServer:
         return False
 
     async def stop_async(self) -> None:
+        from kfserving_tpu.observability.profiling import HEARTBEAT
         from kfserving_tpu.reliability import sanitizer
 
+        if HEARTBEAT.recorder is self.monitoring.flight_recorder:
+            HEARTBEAT.recorder = None
         if getattr(self, "_owns_sanitizer_watchdog", False):
             sanitizer.stop_watchdog()
             # Detach our recorder too: a stopped server's buffer has

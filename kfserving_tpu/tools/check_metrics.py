@@ -378,6 +378,17 @@ async def smoke() -> List[str]:
     obs.generator_deliver_lag_ms().observe(0.4)
     obs.generator_inflight_oldest_age_s().labels(
         model="metrics-probe").set(0.2)
+    # The starved clock and the process heartbeat (ISSUE 56): seconds
+    # the device had nothing from the engine by cause, how late the
+    # loop and the interpreter ran, each collection's pause.
+    for cause in ("host", "no_work"):
+        obs.generator_device_starved_seconds_total().labels(
+            model="metrics-probe", cause=cause).inc(0.004)
+    for what in ("loop", "interpreter"):
+        obs.process_held_ms().labels(what=what).observe(0.3)
+    for generation in range(3):
+        obs.process_gc_pause_ms().labels(
+            generation=generation).observe(1.2)
     problems: List[str] = []
     if resp.status != 200:
         problems.append(

@@ -197,7 +197,18 @@ class ProfilerControl:
     written into the profiler's trace: `start` hands
     `jax.profiler.TraceAnnotation` to `TIMELINE.annotate` and `stop`
     takes it away, so outside a capture the engines call nothing of
-    `jax.profiler`."""
+    `jax.profiler`.
+
+    Both run on the caller's thread, for the debug endpoints the
+    serving loop's, and on a chip `stop_trace` writes the capture for
+    11-93 s with the loop held (the heartbeat's `process paused:` line
+    names it, `what="loop"`, frames ending in `stop_trace`).  An
+    executor thread does not mend that while the Python tracer is on:
+    measured on the v5e (PERF.md §6, PR 56), a stop beside a serving
+    loop shares the interpreter lock with it, opens with 2-3 s in
+    which no thread runs, records on past the moment it was asked to
+    end, and in `gpt2-large.chat` took over 120 s where the held loop
+    gave it 84."""
 
     def __init__(self):
         self._active_dir: Optional[str] = None

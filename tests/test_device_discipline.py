@@ -559,8 +559,7 @@ async def test_loop_stall_watchdog(monkeypatch, recorder):
     from kfserving_tpu.reliability import sanitizer
     monkeypatch.setenv("KFS_SANITIZE", "1")
     wd = sanitizer.LoopStallWatchdog(
-        asyncio.get_running_loop(), threshold_ms=80,
-        interval_s=0.03).start()
+        asyncio.get_running_loop(), threshold_ms=80).start()
     try:
         await asyncio.sleep(0.1)     # healthy beats first
         before = wd.stalls           # ~0; a loaded CI box may tick it

@@ -30,6 +30,7 @@ INFLIGHT_MS = "kfserving_tpu_generator_program_inflight_ms"
 DELIVER_LAG_MS = "kfserving_tpu_generator_deliver_lag_ms"
 STALLS = "kfserving_tpu_generator_program_stalls_total"
 OLDEST_AGE = "kfserving_tpu_generator_inflight_oldest_age_s"
+STARVED = "kfserving_tpu_generator_device_starved_seconds_total"
 MAX_SEQ = 64
 
 
@@ -242,6 +243,208 @@ def test_launch_and_fetch_carry_the_same_seq_in_ring_and_annotation():
     assert {e["args"]["seq"] for e in rendered} == {row.seq}
 
 
+# ----------------------------------------------------- the starved clock
+
+
+class Clock:
+    """`time`, as the table reads it, moved by hand."""
+
+    def __init__(self):
+        self.t = 1000.0
+
+    def perf_counter(self):
+        return self.t
+
+    def time(self):
+        return 1.7e9 + self.t
+
+    def pass_ms(self, ms):
+        self.t += ms / 1e3
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    clock = Clock()
+    monkeypatch.setattr(inflight, "time", clock)
+    return clock
+
+
+def starved_ms(table, cause):
+    """By the counter and by `stats()`: they are one number."""
+    counted = value(STARVED, model=table.model, cause=cause)
+    assert table.starved_s()[cause] == pytest.approx(counted, abs=1e-6)
+    return counted * 1e3
+
+
+def fetched(table, row):
+    with table.fetch(row.seq, row.program):
+        pass
+
+
+def test_the_starved_clock_reads_zero_from_the_start(clock):
+    table = InflightTable("m")
+    assert {(have["model"], have["cause"]): child.value
+            for have, child in children(STARVED)} == {
+        ("m", "host"): 0.0, ("m", "no_work"): 0.0}
+    assert table.starved_s() == {"host": 0.0, "no_work": 0.0}
+    # nothing ever launched: nothing was starved of this engine's work
+    clock.pass_ms(500)
+    first = launched(table, "decode")
+    assert starved_ms(table, "host") == starved_ms(table, "no_work") == 0
+    assert not ring("engine.starved")
+    fetched(table, first)
+
+
+def test_both_causes_to_the_millisecond(clock):
+    table = InflightTable("m")
+    first = launched(table, "decode", rows=4, steps=2)
+    clock.pass_ms(10)
+    fetched(table, first)        # the device has nothing from here on
+    clock.pass_ms(3)             # the loop delivers, finds nothing to do
+    table.waiting(True)
+    clock.pass_ms(500)           # engine.wait.request
+    table.waiting(False)
+    clock.pass_ms(2)             # admits
+    with table.launch("prefill", rows=1, bucket=16) as second:
+        clock.pass_ms(5)         # prepares and launches, nothing behind it
+    assert starved_ms(table, "no_work") == pytest.approx(500.0)
+    assert starved_ms(table, "host") == pytest.approx(10.0)
+    (event,) = ring("engine.starved")
+    assert event[2] == "device" and event[1] == pytest.approx(0.510)
+    assert event[0] + event[1] == pytest.approx(clock.time())
+    assert event[6] == {"cause": "no_work", "seconds": 0.51,
+                        "no_work_s": 0.5, "seq": second.seq}
+    # with work in flight nothing is starved, however long it takes
+    clock.pass_ms(700)
+    third = launched(table, "decode")
+    clock.pass_ms(700)
+    fetched(table, second)
+    clock.pass_ms(700)
+    assert starved_ms(table, "host") == pytest.approx(10.0)
+    # and the host's part alone: 4 ms from the last fetch to the next
+    # launch's return, a hole that /debug/profile shows as the host's
+    fetched(table, third)
+    clock.pass_ms(1)
+    with table.launch("decode") as fourth:
+        clock.pass_ms(3)
+    assert starved_ms(table, "host") == pytest.approx(14.0)
+    assert starved_ms(table, "no_work") == pytest.approx(500.0)
+    assert ring("engine.starved")[1][6] == {
+        "cause": "host", "seconds": 0.004, "no_work_s": 0.0,
+        "seq": fourth.seq}
+
+
+def test_two_workers_retiring_out_of_order_starve_at_the_last(clock):
+    table = InflightTable("m")
+    prefill = launched(table, "prefill", rows=2, bucket=32)
+    decode = launched(table, "decode", rows=4, steps=2)
+    clock.pass_ms(20)
+    fetched(table, decode)       # the later program's fetch returns first
+    clock.pass_ms(30)            # the prefill is still out: not starved
+    fetched(table, prefill)
+    clock.pass_ms(6)
+    launched(table, "decode")
+    assert starved_ms(table, "host") == pytest.approx(6.0)
+
+
+def test_insert_and_feed_keep_the_device_fed_until_they_go(clock):
+    table = InflightTable("m")
+    prefill = launched(table, "prefill", rows=1, bucket=16)
+    launched(table, "insert", rows=1)
+    launched(table, "feed", rows=1)
+    clock.pass_ms(10)
+    fetched(table, prefill)      # launched after it: still tabled
+    clock.pass_ms(40)
+    table.settle()               # at rest: they go, and the clock starts
+    clock.pass_ms(2)
+    table.waiting(True)
+    clock.pass_ms(100)
+    table.waiting(False)
+    clock.pass_ms(1)
+    decode = launched(table, "decode")
+    assert starved_ms(table, "host") == pytest.approx(3.0)
+    assert starved_ms(table, "no_work") == pytest.approx(100.0)
+    # a feed behind a fetched wave goes with it, and the clock starts there
+    launched(table, "feed", rows=1)
+    clock.pass_ms(5)
+    later = launched(table, "decode")
+    fetched(table, decode)
+    clock.pass_ms(5)
+    fetched(table, later)
+    assert table.rows() == []
+    clock.pass_ms(7)
+    launched(table, "decode")
+    assert starved_ms(table, "host") == pytest.approx(10.0)
+
+
+def test_a_launch_that_raises_feeds_nothing(clock):
+    table = InflightTable("m")
+    fetched(table, launched(table, "decode"))
+    clock.pass_ms(2)
+    with pytest.raises(RuntimeError):
+        with table.launch("prefill", rows=1, bucket=16):
+            clock.pass_ms(3)
+            raise RuntimeError("out of memory")
+    assert starved_ms(table, "host") == 0.0   # still open
+    clock.pass_ms(4)
+    with table.launch("prefill", rows=1, bucket=16):
+        clock.pass_ms(1)
+    assert starved_ms(table, "host") == pytest.approx(10.0)
+
+
+def test_a_launch_under_way_when_the_last_row_retires_is_the_hosts(clock):
+    table = InflightTable("m")
+    first = launched(table, "decode")
+    with table.launch("decode"):          # compiles, say
+        clock.pass_ms(50)
+        fetched(table, first)             # nothing is on the device now
+        clock.pass_ms(200)
+    assert starved_ms(table, "host") == pytest.approx(200.0)
+    assert ring("engine.starved")[0][6]["cause"] == "host"
+
+
+def test_a_waiting_loop_is_booked_beat_by_beat_and_a_dead_one_too(clock):
+    """A scrape must not find, after a quiet hour, the hour arriving in
+    one second: the heartbeat's look books a wait as it goes."""
+    table = InflightTable("m")
+    fetched(table, launched(table, "decode"))
+    clock.pass_ms(1)
+    table.waiting(True)
+    for beat in range(1, 9):
+        clock.pass_ms(125)
+        assert not table.check()
+        assert starved_ms(table, "no_work") == pytest.approx(125.0 * beat)
+    assert starved_ms(table, "host") == pytest.approx(1.0)
+    # the loop ends there for want of work (it does not say so), and
+    # starts again with a request: it was waiting all the while
+    clock.pass_ms(60_000)
+    table.check()
+    table.waiting(False)
+    clock.pass_ms(2)
+    launched(table, "prefill", rows=1, bucket=16)
+    assert starved_ms(table, "no_work") == pytest.approx(61_000.0)
+    assert starved_ms(table, "host") == pytest.approx(3.0)
+    assert ring("engine.starved")[0][6]["cause"] == "no_work"
+    # a look with work in flight books nothing
+    clock.pass_ms(300)
+    assert table.check()
+    assert starved_ms(table, "host") == pytest.approx(3.0)
+
+
+def test_the_launch_retire_pair_costs_microseconds():
+    """What the starved clock adds to a launch and a retirement: printed
+    for PERF.md, and held to a generous ceiling."""
+    table = InflightTable("m")
+    n = 2000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        fetched(table, launched(table, "decode"))
+    pair_us = (time.perf_counter() - t0) / n * 1e6
+    print(f"launch + fetch + retire, starved every time: {pair_us:.1f} us")
+    assert value(STARVED, model="m", cause="host") > 0
+    assert pair_us < 2000
+
+
 # ------------------------------------------------------------- a stall
 
 
@@ -249,7 +452,6 @@ def test_launch_and_fetch_carry_the_same_seq_in_ring_and_annotation():
 def quick_stalls(monkeypatch):
     """A floor a test can wait for; the factor as it is."""
     monkeypatch.setattr(inflight, "STALL_FLOOR_S", 0.05)
-    monkeypatch.setattr(inflight, "STALL_CHECK_S", 0.02)
 
 
 def test_a_stall_is_counted_once_and_reported_once(quick_stalls, caplog):
@@ -517,9 +719,16 @@ async def test_an_engines_fetches_pair_with_its_launches(tiny):
 
 
 async def test_a_held_fetch_is_reported_once_with_its_real_shape(
-        tiny, quick_stalls, monkeypatch, caplog):
+        tiny, monkeypatch, caplog):
     caplog.set_level(logging.INFO, logger="kfserving_tpu.engine.inflight")
     eng = make_engine(tiny)
+    # The heartbeat looks from the pipeline's start, not from the loop's
+    # first await of a fetch: a first request compiles `insert` with its
+    # prefill in flight.  Every shape first, then a floor to wait for.
+    await eng.complete(prompt_of(9), max_new_tokens=4)
+    TIMELINE.clear()
+    eng._inflight._mean.clear()   # a prefill does not take that long
+    monkeypatch.setattr(inflight, "STALL_FLOOR_S", 0.05)
     hold, entered = threading.Event(), threading.Event()
     fetch_wave = eng._fetch_wave
 
@@ -591,7 +800,6 @@ async def test_a_held_fetch_is_reported_once_with_its_real_shape(
 async def test_a_compile_inside_a_launch_is_no_stall(
         tiny, monkeypatch, caplog):
     monkeypatch.setattr(inflight, "STALL_FLOOR_S", 0.3)
-    monkeypatch.setattr(inflight, "STALL_CHECK_S", 0.02)
     eng = make_engine(tiny)
     decode, slow = eng._decode, []
     seen = []
@@ -619,3 +827,102 @@ async def test_a_compile_inside_a_launch_is_no_stall(
     assert max(r["age_s"] for r in seen) > 0.3   # older than the floor
     assert value(STALLS) == 0 and not reports(caplog)
     assert not ring("engine.stall")
+
+
+# ------------------------------------- the heartbeat that looks at the table
+
+
+def beating():
+    return [t for t in threading.enumerate() if t.name == "kfs-heartbeat"]
+
+
+async def test_an_idle_engine_is_starved_of_work_not_by_the_host(tiny):
+    eng = make_engine(tiny)
+    try:
+        assert eng.stats()["device_starved_s"] == {
+            "host": 0.0, "no_work": 0.0}
+        t0 = time.perf_counter()
+        await eng.complete(prompt_of(9), max_new_tokens=4)
+        await until(lambda: eng.stats()["inflight"] == [])
+        await asyncio.sleep(0.4)            # no slot active, nothing pending
+        await eng.complete(prompt_of(9), max_new_tokens=4)
+        wall = time.perf_counter() - t0
+        starved = eng.stats()["device_starved_s"]
+    finally:
+        await eng.close()
+    assert starved["no_work"] >= 0.35
+    assert 0.0 < starved["host"] < wall - starved["no_work"]
+    # (the counter went on while the engine closed)
+    assert value(STARVED, model=eng.name, cause="no_work") \
+        >= starved["no_work"] - 1e-5
+    # the hole is on the device track, where the waves are
+    holes = [e for e in ring("engine.starved")
+             if e[6]["cause"] == "no_work"]
+    assert holes and holes[-1][2] == "device"
+    assert holes[-1][1] >= 0.35
+    launches = {e[6]["seq"] for e in TIMELINE.snapshot()
+                if e[3].startswith("engine.launch.")}
+    assert holes[-1][6]["seq"] in launches
+
+
+async def test_one_heartbeat_for_two_engines_and_none_after_close(tiny):
+    from kfserving_tpu.observability.profiling import HEARTBEAT
+
+    assert beating() == [] and HEARTBEAT.watching() == 0
+    one, two = make_engine(tiny), make_engine(tiny)
+    try:
+        assert beating() == []   # no pipeline has started on a loop yet
+        await asyncio.gather(
+            one.complete(prompt_of(9), max_new_tokens=3),
+            two.complete(prompt_of(5), max_new_tokens=3))
+        assert len(beating()) == 1 and HEARTBEAT.watching() == 2
+        # an engine whose loop ended for want of work is watched still
+        await until(lambda: one._loop_task.done(), timeout=10.0)
+        await one.complete(prompt_of(9), max_new_tokens=3)
+        assert len(beating()) == 1 and HEARTBEAT.watching() == 2
+        await one.close()
+        assert len(beating()) == 1 and HEARTBEAT.watching() == 1
+    finally:
+        await one.close()
+        await two.close()
+    assert beating() == [] and HEARTBEAT.watching() == 0
+
+
+async def test_a_stall_is_reported_while_the_loop_is_still_held(
+        tiny, monkeypatch, caplog):
+    caplog.set_level(logging.INFO, logger="kfserving_tpu.engine.inflight")
+    eng = make_engine(tiny)
+    await eng.complete(prompt_of(9), max_new_tokens=4)   # every shape
+    eng._inflight._mean.clear()   # whose compiles no mean should hold
+    monkeypatch.setattr(inflight, "STALL_FLOOR_S", 0.05)
+    hold, entered = threading.Event(), threading.Event()
+    fetch_wave = eng._fetch_wave
+
+    def held_fetch(toks_h, lp_h):
+        if not entered.is_set():
+            entered.set()
+            assert hold.wait(timeout=60)
+        return fetch_wave(toks_h, lp_h)
+
+    eng._fetch_wave = held_fetch
+    try:
+        answer = asyncio.ensure_future(
+            eng.complete(prompt_of(9), max_new_tokens=4))
+        await until(entered.is_set)
+        before = value(STALLS)
+        time.sleep(0.5)   # a handler holds the loop; the fetch is out
+        # not one await since: the look was another thread's
+        counted, seen = value(STALLS), reports(caplog)
+        monkeypatch.setattr(inflight, "STALL_FLOOR_S", 60.0)
+        hold.set()
+        tokens, _ = await answer
+        assert len(tokens) == 4
+    finally:
+        hold.set()
+        await eng.close()
+    assert before == 0 and counted == 1
+    (report,) = seen
+    assert report["stalled"]["program"] == "prefill"
+    assert any(frame.endswith(
+        " test_a_stall_is_reported_while_the_loop_is_still_held")
+        for frame in report["stacks"]["loop"])
